@@ -25,8 +25,10 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     from ps_pytorch_tpu.parallel import dist
-    dist.initialize_from_env()  # platform override / multi-host env contract
+    dist.initialize_from_env()  # multi-host env contract
     from ps_pytorch_tpu.runtime import Evaluator
+    from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     ev = Evaluator(args.train_dir, poll_s=args.poll_s)
     if args.once is not None:

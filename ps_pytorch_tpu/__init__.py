@@ -25,38 +25,4 @@ authority) with no gradient round-trip.
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # Older jax: shard_map lives in jax.experimental and its
-    # replication-check kwarg is spelled check_rep, not check_vma.
-    # Install a keyword-compatible alias so every call site can use the
-    # current jax.shard_map(..., check_vma=...) spelling unconditionally.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs,
-                          check_vma=True, **kw):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
-try:
-    # Sharding-invariant RNG: legacy (non-partitionable) threefry generates
-    # DIFFERENT bits when an init is jitted with sharded out_shardings (the
-    # row-parallel TP/PP param inits), so sharded and unsharded inits of the
-    # same seed diverged. The partitionable generator — the default on newer
-    # jax — produces identical bits under any sharding.
-    _jax.config.update("jax_threefry_partitionable", True)
-except Exception:
-    pass  # newer jax removed the flag (always partitionable)
-
-if not hasattr(_jax.lax, "axis_size"):
-    # Older jax: no lax.axis_size. psum of a unit is the standard spelling
-    # and constant-folds to the mesh axis size under shard_map/pjit.
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
 from ps_pytorch_tpu.config import TrainConfig  # noqa: F401

@@ -43,10 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # Older jax spells it TPUCompilerParams; same fields.
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 from ps_pytorch_tpu.ops._backend import interpret_default as _interpret_default
 
 NEG_INF = -1e30
@@ -54,7 +50,7 @@ NEG_INF = -1e30
 
 def _pick_block(s: int, requested: int) -> int:
     """Largest power-of-two block <= requested that divides ``s`` (min 8,
-    the f32 sublane tile); 0 = no aligned block exists (caller falls back)."""
+    the f32 sublane tile); 0 = no aligned block exists (caller raises)."""
     b = 1
     while b * 2 <= min(requested, s):
         b *= 2
@@ -299,8 +295,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Fused attention over [B, H, S, D] tensors; drop-in for
     ``ring.full_attention`` (same signature semantics, same output).
 
-    Falls back to the materializing path when S has no power-of-two block
-    divisor >= 8 (never the case for the model geometries here).
+    Raises ValueError when S has no power-of-two block divisor >= 8: a
+    caller that asked for the fused kernel is told it cannot have it.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -308,8 +304,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bq = _pick_block(s, min(block_q, s))
     bkv = _pick_block(s, min(block_kv, s))
     if not bq or not bkv:
-        from ps_pytorch_tpu.parallel.ring import full_attention
-        return full_attention(q, k, v, causal=causal, scale=scale)
+        raise ValueError(
+            f"flash_attention needs a power-of-two block >= 8 dividing the "
+            f"sequence length; S={s} has none (use attention 'full')")
     if scale is None:
         scale = float(d) ** -0.5
     q3 = q.reshape(b * h, s, d)

@@ -180,15 +180,12 @@ def create_pp_train_state(model, tx: optax.GradientTransformation,
     shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                              is_leaf=lambda x: isinstance(x, P))
     with mesh:
-        # Init REPLICATED, then place: jitting the init with sharded
-        # out_shardings lets GSPMD partition the per-block RNG draws that
-        # stack_stage_params stacks, and on jax 0.4.37 the partitioned
-        # draws produce different bits than the unsharded oracle init
-        # (sharding-dependent params break every PP-vs-unsharded parity
-        # pin). Init is one-time, so the replicated materialization is an
-        # acceptable cost for bitwise-identical weights at any mesh shape.
-        state = jax.jit(init_fn)(rng)
-        return jax.device_put(state, shardings)
+        # Init straight into the stage-sharded layout: the partitionable
+        # threefry generator draws the same bits under any sharding, so the
+        # weights equal the unsharded oracle init bitwise (the PP-vs-
+        # unsharded parity tests pin it) and no device ever holds the whole
+        # model.
+        return jax.jit(init_fn, out_shardings=shardings)(rng)
 
 
 def make_pp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,

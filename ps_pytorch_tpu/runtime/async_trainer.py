@@ -34,6 +34,7 @@ from ps_pytorch_tpu import resilience
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.datasets import DataLoader, load_arrays, sample_shape
 from ps_pytorch_tpu.models import build_model
+from ps_pytorch_tpu.ops._backend import announce_kernels, cnn_kernels
 from ps_pytorch_tpu.optim import build_optimizer
 from ps_pytorch_tpu.parallel.async_dp import StaleGradientAggregator
 from ps_pytorch_tpu.parallel.dp import apply_optimizer, make_eval_step
@@ -70,6 +71,7 @@ class AsyncTrainer:
         self.model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype,
                                  conv_impl=cfg.conv_impl)
         self.tx = build_optimizer(cfg)
+        announce_kernels(cnn_kernels(cfg))
 
         shape = (1,) + sample_shape(cfg.dataset)
         variables = self.model.init(jax.random.key(cfg.seed),

@@ -80,16 +80,12 @@ class DistributedKV(KVStore):
 
     def __init__(self):
         super().__init__()
-        from jax._src import distributed
-        client = distributed.global_state.client
-        if client is None:
+        import jax
+        if not jax.distributed.is_initialized():
             raise RuntimeError("jax.distributed not initialized")
-        self._client = client
-        # jax 0.4.x clients predate key_value_try_get; emulate the
-        # non-blocking read with a directory scan (key_value_dir_get), which
-        # every vintage ships. Control-plane keys are tiny and GC'd (mask
-        # window, per-replica beats), so the scan stays O(few keys).
-        self._has_try_get = hasattr(self._client, "key_value_try_get")
+        # jax 0.9 has no public handle on the coordination-service client.
+        from jax._src import distributed
+        self._client = distributed.global_state.client
 
     def set(self, key: str, value: str) -> None:
         # Coordination-service keys are write-once by default; control-plane
@@ -97,46 +93,12 @@ class DistributedKV(KVStore):
         self._client.key_value_set(key, value, allow_overwrite=True)
 
     def get(self, key: str, default: Optional[str] = None) -> Optional[str]:
-        if not self._has_try_get:
-            return self._dir_get(key, default)
         try:
             return self._client.key_value_try_get(key)
         except Exception as e:
             # Only "key not published yet" maps to the default; a dead or
             # unreachable coordination service must surface, not be polled.
             if "NOT_FOUND" in str(e):
-                return default
-            raise
-
-    def _dir_get(self, key: str, default: Optional[str]) -> Optional[str]:
-        """try_get emulation: list the key's directory and pick it out. The
-        service reports listed keys with a leading '/', so match both."""
-        prefix = key.rsplit("/", 1)[0] if "/" in key else key
-        try:
-            entries = self._client.key_value_dir_get(prefix)
-        except Exception as e:
-            msg = str(e)
-            if "NOT_FOUND" in msg:
-                return default
-            if "RESOURCE_EXHAUSTED" in msg or "larger than max" in msg:
-                # The directory holds more than one gRPC message of payload
-                # (e.g. wire chunks orphaned by a killed process share the
-                # prefix of a tiny control key). Fetch just the one key with
-                # a short blocking get instead of listing its siblings.
-                return self._blocking_probe(key, default)
-            raise
-        for k, v in entries:
-            if k == key or k == "/" + key:
-                return v
-        return default
-
-    def _blocking_probe(self, key: str, default: Optional[str],
-                        timeout_ms: int = 50) -> Optional[str]:
-        try:
-            return self._client.blocking_key_value_get(key, timeout_ms)
-        except Exception as e:
-            msg = str(e)
-            if "DEADLINE_EXCEEDED" in msg or "NOT_FOUND" in msg:
                 return default
             raise
 

@@ -35,6 +35,7 @@ from ps_pytorch_tpu.data.text import TokenLoader
 from ps_pytorch_tpu.models.transformer import (
     TransformerLM, migrate_packed_qkv,
 )
+from ps_pytorch_tpu.ops._backend import announce_kernels
 from ps_pytorch_tpu.optim import build_schedule
 from ps_pytorch_tpu.optim.sgd import sgd
 from ps_pytorch_tpu.parallel import dist
@@ -45,10 +46,11 @@ from ps_pytorch_tpu.runtime import checkpoint as ckpt
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
 from ps_pytorch_tpu.telemetry import (
     FlightRecorder, HealthMonitor, MetricsExporter, Registry, Tracer,
-    aggregate_peak_flops, declare_resilience_metrics,
+    declare_resilience_metrics,
     declare_training_metrics, derive_step_record,
-    device_memory_record, host_rss_bytes, set_default_tracer, step_flops_of,
+    device_memory_record, host_rss_bytes, set_default_tracer,
 )
+from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
 
 class LMTrainer:
@@ -163,6 +165,8 @@ class LMTrainer:
             self.eval_fn = None
         else:  # unreachable: TrainConfig.__post_init__ validates
             raise ValueError(self.mode)
+        if self.model.attention_impl == "flash":
+            announce_kernels(["flash_attention"])
 
         # Checkpoints are self-describing: record the model family and the
         # RESOLVED mesh degree (lm_model_axis=0 means "all devices", which
@@ -187,7 +191,7 @@ class LMTrainer:
         self._prev_tracer = set_default_tracer(self.tracer)
         self._flops_per_step: Optional[int] = None
         self._n_chips = n
-        self._peak_per_chip = aggregate_peak_flops(devices)
+        self._peak_per_chip = peak_flops_bf16(devices[0].device_kind)
         self.start_step = 0
         # Fault plane (same spec/grammar as the CNN trainer): step-keyed
         # crashes + post-commit checkpoint corruption for resilience drills.
@@ -362,9 +366,11 @@ class LMTrainer:
             self.maybe_resume()
         step = self.start_step
         halted = False
+        t_sync, n_unsynced = time.monotonic(), 0
         try:
             while step < cfg.max_steps:
                 step += 1
+                n_unsynced += 1
                 if self.injector is not None:
                     self.injector.maybe_crash(step)
                 t0 = time.monotonic()
@@ -379,25 +385,29 @@ class LMTrainer:
                 tok_g = dist.globalize_replicated(self.mesh, tokens,
                                                   spec=self._token_spec())
                 if self._flops_per_step is None:
-                    self._flops_per_step = step_flops_of(
-                        self.step_fn, self.state, tok_g) or -1
+                    self._flops_per_step = forward_flops(
+                        self.step_fn, self.state, tok_g)
                 with self.tracer.span("host_dispatch", step=step):
                     self.state, m = self.step_fn(self.state, tok_g)
-                # Dispatch-time wall clock: what a non-blocking iteration
-                # costs. The metrics_sync below (loss materialization) is
-                # deliberately NOT folded in, matching trainer.py.
+                # Dispatch is asynchronous: between syncs this is only
+                # what a non-blocking iteration costs the host.
                 t_step = time.monotonic() - t0
                 loss = None
                 if step % cfg.log_every == 0 or step == cfg.max_steps:
                     with self.tracer.span("metrics_sync", step=step):
                         loss = float(m["loss"])
+                    # The loss read drained every step dispatched since the
+                    # last sync, so the wall time over them is a true
+                    # per-step duration (dispatch time alone reads as an
+                    # MFU above 1 on a chip).
+                    now = time.monotonic()
+                    t_step = (now - t_sync) / n_unsynced
+                    t_sync, n_unsynced = now, 0
                     derived = derive_step_record(
                         step_time_s=t_step, data_time_s=t_data,
                         examples=cfg.batch_size,
                         tokens=cfg.batch_size * cfg.lm_seq_len,
-                        flops_per_step=(self._flops_per_step
-                                        if self._flops_per_step and
-                                        self._flops_per_step > 0 else None),
+                        flops_per_step=self._flops_per_step,
                         peak_flops_per_chip=self._peak_per_chip,
                         n_chips=self._n_chips)
                     self.metrics.log_step(
@@ -420,6 +430,7 @@ class LMTrainer:
                 if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
                     with self.tracer.span("checkpoint", step=step):
                         self._checkpoint(step)
+                    t_sync, n_unsynced = time.monotonic(), 0
             jax.block_until_ready(self.state.params)
             if not halted and cfg.eval_freq > 0 and step % cfg.eval_freq != 0:
                 with self.tracer.span("checkpoint", step=step):

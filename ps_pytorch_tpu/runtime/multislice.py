@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.datasets import sample_shape
 from ps_pytorch_tpu.models import build_model
+from ps_pytorch_tpu.ops._backend import announce_kernels, cnn_kernels
 from ps_pytorch_tpu.optim import build_optimizer
 from ps_pytorch_tpu.parallel.async_dp import StaleGradientAggregator
 from ps_pytorch_tpu.parallel.dp import make_loss_fn, apply_optimizer
@@ -92,6 +93,7 @@ class MultiSliceTrainer:
         self.model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype,
                                  conv_impl=cfg.conv_impl)
         self.tx = build_optimizer(cfg)
+        announce_kernels(cnn_kernels(cfg))
 
         shape = (1,) + sample_shape(cfg.dataset)
         variables = self.model.init(jax.random.key(cfg.seed),
@@ -105,10 +107,14 @@ class MultiSliceTrainer:
         self.opt_state = self.tx.init(variables["params"])
         self.has_bn = "batch_stats" in variables
         bs0 = variables.get("batch_stats", {})
-        # Per-slice replica-local BN stats (reference keeps BN per worker).
-        self._bs = [jax.tree.map(
-            lambda a: jnp.tile(a[None], (per,) + (1,) * a.ndim), bs0)
-            for _ in range(n_slices)]
+        # Per-slice replica-local BN stats (reference keeps BN per worker),
+        # placed where the slice's step returns them: stats left on the
+        # default device make every slice's step compile twice, once for
+        # tick 1's placement and once for its own outputs'.
+        self._bs = [jax.device_put(
+            jax.tree.map(
+                lambda a: jnp.tile(a[None], (per,) + (1,) * a.ndim), bs0),
+            NamedSharding(m, P("data"))) for m in self.meshes]
 
         if cfg.sync_topology == "hier":
             # 2-tier multi-hop aggregation (parallel/hierarchy.py) behind
@@ -191,7 +197,10 @@ class MultiSliceTrainer:
 
     def _slice_batch(self, s: int):
         x, y = self.train_loaders[s].next_batch()
-        return jnp.asarray(x), jnp.asarray(y)
+        # Host -> this slice's own devices (jnp.asarray would stage every
+        # slice's batch on the default device before its shard_map pulls it).
+        sharding = NamedSharding(self.meshes[s], P("data"))
+        return jax.device_put(x, sharding), jax.device_put(y, sharding)
 
     def tick(self) -> dict:
         """One global tick: scheduled slices compute+submit; the canonical
@@ -302,7 +311,8 @@ class MultiSliceTrainer:
         state, meta, _, step = got
         self.params = jax.device_put(state.params)
         self.opt_state = jax.device_put(state.opt_state)
-        self._bs[0] = jax.device_put(state.batch_stats)
+        self._bs[0] = jax.device_put(
+            state.batch_stats, NamedSharding(self.meshes[0], P("data")))
         self.step = int(meta["step"])
         self._slice_params = [self.params] * self.n_slices
         self._slice_version = [self.step] * self.n_slices

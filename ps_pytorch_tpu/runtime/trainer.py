@@ -24,6 +24,7 @@ from ps_pytorch_tpu import resilience
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data import prepare_data
 from ps_pytorch_tpu.models import build_model
+from ps_pytorch_tpu.ops._backend import announce_kernels, cnn_kernels
 from ps_pytorch_tpu.optim import build_optimizer
 from ps_pytorch_tpu.parallel import (
     create_train_state, make_eval_step, make_train_step, make_mesh,
@@ -38,12 +39,13 @@ from ps_pytorch_tpu.runtime.coordinator import Coordinator
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
 from ps_pytorch_tpu.telemetry import (
     FlightRecorder, HealthMonitor, MetricsExporter, Registry,
-    TelemetryAggregator, Tracer, aggregate_peak_flops,
+    TelemetryAggregator, Tracer,
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_training_metrics,
     derive_step_record, device_memory_record, host_rss_bytes,
-    set_default_tracer, step_flops_of,
+    set_default_tracer,
 )
+from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
 from ps_pytorch_tpu.data.datasets import sample_shape
 
@@ -58,6 +60,7 @@ class Trainer:
         self.model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype,
                                  conv_impl=cfg.conv_impl)
         self.tx = build_optimizer(cfg)
+        announce_kernels(cnn_kernels(cfg))
         host_id, num_hosts = local_data_shard()
         self.train_loader, self.test_loader = prepare_data(
             cfg, host_id=host_id, num_hosts=num_hosts, download=download)
@@ -249,8 +252,8 @@ class Trainer:
         # off-TPU -> mfu reported as null, never a fiction).
         self._flops_per_step: Optional[int] = None
         self._n_chips = int(self.mesh.devices.size)
-        self._peak_per_chip = aggregate_peak_flops(
-            list(self.mesh.devices.flat))
+        self._peak_per_chip = peak_flops_bf16(
+            self.mesh.devices.flat[0].device_kind)
         # Cross-host step telemetry over the control-plane KV: every process
         # publishes per-step durations + phase summaries; the leader drains
         # them into ONE merged per-replica timeline JSONL.
@@ -527,9 +530,11 @@ class Trainer:
         preempted = False
         halted = False
         self._preempt.install()
+        t_sync, n_unsynced = time.monotonic(), 0
         try:
             while step < last_step:
                 step += 1
+                n_unsynced += 1
                 if self.injector is not None:
                     # Before any KV/device work for this step: the crash
                     # models a process dying BETWEEN steps, so the last
@@ -583,10 +588,9 @@ class Trainer:
                     self.mesh, key, spec=jax.sharding.PartitionSpec())
                 if self._flops_per_step is None:
                     # One abstract trace of the full fwd+bwd+update program
-                    # (nothing executes); -1 = "tried, uncountable" so a
-                    # failure is not retried every step.
-                    self._flops_per_step = step_flops_of(
-                        self.step_fn, self.state, xg, yg, mg, kg) or -1
+                    # (nothing executes).
+                    self._flops_per_step = forward_flops(
+                        self.step_fn, self.state, xg, yg, mg, kg)
                 with self.tracer.span("host_dispatch", step=step):
                     new_state, m = self.step_fn(self.state, xg, yg, mg, kg)
                 self.state = new_state
@@ -634,22 +638,25 @@ class Trainer:
                     self._telemetry.publish_step(step, rec)
                     self._telemetry.drain_to_file()  # no-op off-leader
                 if step % cfg.log_every == 0 or step == last_step:
-                    # Materializing metrics fully syncs the device — in its
-                    # own span, and the REPORTED step_time stays the pre-sync
-                    # duration computed above (the one the coordinator's
-                    # policies see), so logged and policy-visible durations
-                    # agree instead of silently folding this sync in.
+                    # Materializing metrics fully syncs the device, in its
+                    # own span. t_step above (what the coordinator's policies
+                    # see) ends before this sync; once every step logs, the
+                    # device wait lands here and t_step is only the dispatch,
+                    # which reads as an MFU above 1 on a chip. The LOGGED
+                    # duration is therefore the wall time since the last full
+                    # sync over the steps dispatched since it.
                     with self.tracer.span("metrics_sync", step=step):
                         loss = float(m["loss"])
                         acc = float(m["accuracy"])
                         part = float(m["participating"])
+                    now = time.monotonic()
+                    t_logged = (now - t_sync) / n_unsynced
+                    t_sync, n_unsynced = now, 0
                     epoch = (step - 1) // steps_per_epoch
                     derived = derive_step_record(
-                        step_time_s=t_step, data_time_s=t_data,
+                        step_time_s=t_logged, data_time_s=t_data,
                         examples=cfg.batch_size,
-                        flops_per_step=(self._flops_per_step
-                                        if self._flops_per_step and
-                                        self._flops_per_step > 0 else None),
+                        flops_per_step=self._flops_per_step,
                         peak_flops_per_chip=self._peak_per_chip,
                         n_chips=self._n_chips)
                     extra = dict(derived)
@@ -657,11 +664,12 @@ class Trainer:
                         extra.update(self.resilience_stats())
                     self.metrics.log_step(
                         step, epoch, loss=loss, acc=acc, participating=part,
-                        step_time=t_step, data_time=t_data,
+                        step_time=t_logged, data_time=t_data,
                         phases=self.tracer.step_summary(step), **extra)
                 if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
                     with self.tracer.span("checkpoint", step=step):
                         self._checkpoint(step)
+                    t_sync, n_unsynced = time.monotonic(), 0
                 if self._preempt.triggered:
                     # SIGTERM (preemption notice): commit an emergency
                     # checkpoint at this step boundary and leave cleanly so

@@ -1,6 +1,6 @@
 """Synthetic load generation + latency accounting for the serving engine.
 
-Two drive modes, per the usual serving-bench taxonomy:
+Two drive modes, the usual split for a serving bench:
 
 - **closed loop** (``run_closed_loop``): all requests present at t0, the
   engine drains them as fast as slots allow — measures aggregate decode

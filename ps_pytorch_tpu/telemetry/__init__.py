@@ -23,12 +23,11 @@ from ps_pytorch_tpu.telemetry.registry import (  # noqa: F401
     INTEGRITY_GAUGES, KVREP_COUNTERS, KVREP_GAUGES, RESILIENCE_COUNTERS,
     SERVING_COUNTERS, SERVING_GAUGES,
     SERVING_HISTOGRAMS, TRAINING_COUNTERS, TRAINING_GAUGES,
-    TRAINING_HISTOGRAMS, MetricSpec, Registry, aggregate_peak_flops,
-    compute_mfu, data_stall_fraction, declare_elastic_metrics,
+    TRAINING_HISTOGRAMS, MetricSpec, Registry, compute_mfu, data_stall_fraction, declare_elastic_metrics,
     declare_hierarchy_metrics, declare_integrity_metrics,
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_serving_metrics, declare_training_metrics, derive_step_record,
-    device_memory_record, host_rss_bytes, step_flops_of,
+    device_memory_record, host_rss_bytes,
 )
 from ps_pytorch_tpu.telemetry.slo import (  # noqa: F401
     SLOObjective, SLOTracker, WindowPercentile, check_slo, parse_slo_spec,

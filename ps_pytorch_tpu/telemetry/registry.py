@@ -533,20 +533,22 @@ def data_stall_fraction(data_time_s: float,
 
 def device_memory_record(device=None) -> dict:
     """{"device_mem_peak_bytes", "device_mem_bytes"} via the backend's
-    memory_stats(); {} when the backend has none (CPU) — additive fields,
-    absent rather than null, so CPU JSONL stays compact."""
-    try:
-        if device is None:
-            import jax
-            device = jax.local_devices()[0]
-        stats = device.memory_stats() or {}
-    except Exception:
-        return {}
+    memory_stats(): the given device's, or the fullest of this process's
+    local devices (device 0 alone hides a lopsided placement). {} when the
+    backend has none (CPU) — additive fields, absent rather than null, so
+    CPU JSONL stays compact."""
+    if device is None:
+        import jax
+        devices = jax.local_devices()
+    else:
+        devices = [device]
+    stats = [s for s in (d.memory_stats() for d in devices) if s]
     out = {}
-    if stats.get("peak_bytes_in_use") is not None:
-        out["device_mem_peak_bytes"] = int(stats["peak_bytes_in_use"])
-    if stats.get("bytes_in_use") is not None:
-        out["device_mem_bytes"] = int(stats["bytes_in_use"])
+    for field, key in (("device_mem_peak_bytes", "peak_bytes_in_use"),
+                       ("device_mem_bytes", "bytes_in_use")):
+        vals = [s[key] for s in stats if s.get(key) is not None]
+        if vals:
+            out[field] = int(max(vals))
     return out
 
 
@@ -577,27 +579,3 @@ def derive_step_record(*, step_time_s: float, data_time_s: float = 0.0,
     if with_memory:
         rec.update(device_memory_record(device))
     return rec
-
-
-def step_flops_of(fn, *args) -> Optional[int]:
-    """Analytic FLOPs of one call of ``fn(*args)`` (utils/flops.py jaxpr
-    traversal — recurses through the pjit wrapper of a jitted step), or
-    None when the trace fails. Trace once, divide every step."""
-    try:
-        from ps_pytorch_tpu.utils.flops import forward_flops
-        return forward_flops(fn, *args)
-    except Exception:
-        return None
-
-
-def aggregate_peak_flops(devices=None) -> Optional[float]:
-    """Per-chip peak for the devices' kind (utils/flops.peak_flops_bf16);
-    None off-TPU."""
-    try:
-        if devices is None:
-            import jax
-            devices = jax.devices()
-        from ps_pytorch_tpu.utils.flops import peak_flops_bf16
-        return peak_flops_bf16(devices[0].device_kind)
-    except Exception:
-        return None

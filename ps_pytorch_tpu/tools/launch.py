@@ -15,8 +15,10 @@ The TPU-native replacement for the reference's launch stack:
 
 Host modes:
 - ``--simulate N``: N local processes, each given ``--devices-per-host``
-  fake CPU devices — the standard JAX multi-host test rig; how CI exercises
-  the full DCN bootstrap + sharded-input + KV-control path on one machine.
+  fake CPU devices through ``JAX_PLATFORMS=cpu`` + ``JAX_NUM_CPU_DEVICES``
+  in its environment — the standard JAX multi-host test rig; how CI
+  exercises the full DCN bootstrap + sharded-input + KV-control path on one
+  machine.
 - ``--hostfile FILE``: one host per line (the reference's ``hosts_address``
   format); processes are started over ``ssh`` (TPU pod VMs, where this
   script runs on every worker VM against its local chips).
@@ -58,13 +60,10 @@ def _env_for(rank: int, n: int, coordinator: str, platform: str,
     env[dist.ENV_NPROC] = str(n)
     env[dist.ENV_PID] = str(rank)
     if platform:
-        env[dist.ENV_PLATFORM] = platform
+        # Plain JAX env vars, read by the child at import.
+        env["JAX_PLATFORMS"] = platform
         if platform == "cpu":
-            env[dist.ENV_LOCAL_DEVICES] = str(devices_per_host)
-            flags = [f for f in env.get("XLA_FLAGS", "").split()
-                     if not f.startswith("--xla_force_host_platform_device_count")]
-            flags.append(f"--xla_force_host_platform_device_count={devices_per_host}")
-            env["XLA_FLAGS"] = " ".join(flags)
+            env["JAX_NUM_CPU_DEVICES"] = str(devices_per_host)
     return env
 
 

@@ -73,11 +73,28 @@ def _sub_jaxprs(eqn):
                 yield x
 
 
-def count_jaxpr_flops(jaxpr) -> int:
-    """Matmul+conv FLOPs of a jaxpr, recursing into nested call jaxprs.
+def _trips(eqn) -> int:
+    """How many times an eqn's nested jaxpr runs per call of the eqn: a
+    ``scan`` body once per iteration, a ``shard_map`` body once on every
+    device of its manual axes (its shapes are per-shard), a ``pallas_call``
+    kernel once per grid point (its shapes are one block's)."""
+    name = eqn.primitive.name
+    if name == "scan":
+        return int(eqn.params.get("length", 1))
+    if name == "shard_map":
+        mesh = eqn.params["mesh"]
+        return _prod(mesh.shape[a] for a in eqn.params["manual_axes"])
+    if name == "pallas_call":
+        return _prod(g for g in eqn.params["grid_mapping"].grid
+                     if isinstance(g, int))
+    return 1
 
-    ``scan``/``while`` bodies are counted ONCE per trip the jaxpr encodes
-    (length is a param for scan): scan's trip count multiplies the body.
+
+def count_jaxpr_flops(jaxpr) -> int:
+    """Matmul+conv FLOPs of a jaxpr across ALL devices it runs on, recursing
+    into nested call jaxprs with each body multiplied by its trip count
+    (``_trips``). Both branches of a ``cond`` count, so a kernel that skips
+    causally-dead tiles is charged the dense S x S, like ``full_attention``.
     """
     total = 0
     for eqn in jaxpr.eqns:
@@ -87,9 +104,7 @@ def count_jaxpr_flops(jaxpr) -> int:
         elif name == "conv_general_dilated":
             total += _conv_flops(eqn)
         else:
-            trips = 1
-            if name == "scan":
-                trips = int(eqn.params.get("length", 1))
+            trips = _trips(eqn)
             for sub in _sub_jaxprs(eqn):
                 total += trips * count_jaxpr_flops(sub)
     return total
@@ -149,10 +164,15 @@ _PEAK_BF16 = (
 
 
 def peak_flops_bf16(device_kind: str) -> Optional[float]:
-    """Peak bf16 FLOPs/sec for a jax device_kind; None when unknown (e.g.
-    CPU) — callers should then report MFU as null rather than a fiction."""
+    """Peak bf16 FLOPs/sec for a jax device_kind. A TPU kind missing from
+    the table is an error (a silent None would read as "MFU n/a" on the
+    very machine MFU is for); anything else (CPU) is None — callers then
+    report MFU as null rather than a fiction."""
     kind = (device_kind or "").lower()
     for sub, peak in _PEAK_BF16:
         if sub in kind:
             return peak
+    if "tpu" in kind:
+        raise ValueError(f"no peak bf16 FLOP/s known for TPU device_kind "
+                         f"{device_kind!r}; add it to utils/flops._PEAK_BF16")
     return None

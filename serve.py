@@ -49,8 +49,8 @@ def main(argv=None) -> int:
     except ValueError as e:
         p.error(str(e))
 
-    from ps_pytorch_tpu.parallel.dist import _apply_platform_overrides
-    _apply_platform_overrides()
+    from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from ps_pytorch_tpu.models.transformer import migrate_packed_qkv
     from ps_pytorch_tpu.runtime import checkpoint as ckpt

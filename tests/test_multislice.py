@@ -108,8 +108,7 @@ def test_async_cli_mode(tmp_path):
     import subprocess, sys, os
     from pathlib import Path
     REPO = Path(__file__).resolve().parents[1]
-    env = dict(os.environ, PS_TPU_PLATFORM="cpu", PS_TPU_LOCAL_DEVICES="8",
-               JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_NUM_CPU_DEVICES="8")
     out = subprocess.run(
         [sys.executable, str(REPO / "train.py"), "--mode", "async",
          "--async-slices", "2", "--network", "LeNet", "--dataset",

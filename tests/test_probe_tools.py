@@ -1,7 +1,7 @@
 """Unit tests for the evidence harnesses' parent logic (no device, no
 subprocesses): memory_probe's artifact/delta bookkeeping and
-accuracy_run's contract parsing. The device-side halves run in the TPU
-batch scripts; these tests pin everything that can break without a chip.
+accuracy_run's contract parsing. The device-side halves need the chip;
+these tests pin everything that can break without one.
 """
 
 import json
@@ -113,7 +113,7 @@ def test_accuracy_run_contract_parse(tmp_path, monkeypatch):
         return types.SimpleNamespace(stdout=out, stderr="", returncode=0)
 
     monkeypatch.setattr(accuracy_run, "_run_child", fake_child)
-    monkeypatch.setattr(accuracy_run, "_probe_platform",
+    monkeypatch.setattr(accuracy_run, "_platform",
                         lambda: ("tpu", "TPU v5 lite"))
     out = tmp_path / "ACC.json"
     r = accuracy_run.run(["--out", str(out), "--max-steps", "1200"])

@@ -693,57 +693,38 @@ def test_lease_throttle_state_does_not_leak_across_epochs():
     assert (follower.epoch, follower.owner) == (3, 0)
 
 
-def test_dir_get_falls_back_to_blocking_probe_on_oversized_dir():
-    # A killed process can orphan megabytes of wire chunks under the run
-    # prefix; the try_get emulation's directory scan then exceeds the gRPC
-    # message cap. The KV must fall back to a single-key blocking get
-    # instead of surfacing RESOURCE_EXHAUSTED to the retry layer.
+def _distributed_kv(client):
     from ps_pytorch_tpu.runtime.coordinator import DistributedKV
+    kv = DistributedKV.__new__(DistributedKV)
+    kv._client = client
+    return kv
 
+
+def test_distributed_kv_get_maps_not_found_to_default():
     class FakeClient:
-        def __init__(self):
-            self.store = {}
-            self.dir_calls = 0
-            self.probe_calls = 0
+        store = {}
 
-        def key_value_dir_get(self, prefix):
-            self.dir_calls += 1
-            raise RuntimeError(
-                "RESOURCE_EXHAUSTED: Received message larger than max "
-                "(10787499 vs. 4194304)")
-
-        def blocking_key_value_get(self, key, timeout_in_ms):
-            self.probe_calls += 1
+        def key_value_try_get(self, key):
             if key in self.store:
                 return self.store[key]
-            raise RuntimeError("DEADLINE_EXCEEDED: timed out")
+            raise RuntimeError(f"NOT_FOUND: key {key} not found")
 
-    kv = DistributedKV.__new__(DistributedKV)
-    kv._client = FakeClient()
-    kv._has_try_get = False
-
-    # Absent key -> default, via the probe (deadline maps to default).
+    kv = _distributed_kv(FakeClient())
     assert kv.get("run/adone", None) is None
+    assert kv.get("run/adone", "dflt") == "dflt"
     kv._client.store["run/adone"] = "1"
     assert kv.get("run/adone") == "1"
-    assert kv._client.dir_calls == 2 and kv._client.probe_calls == 2
 
 
-def test_dir_get_oversized_fallback_reraises_other_errors():
-    from ps_pytorch_tpu.runtime.coordinator import DistributedKV
-
+def test_distributed_kv_get_reraises_other_errors():
+    # A dead coordination service must surface to the retry layer, not be
+    # read as "key not published yet".
     class FakeClient:
-        def key_value_dir_get(self, prefix):
-            raise RuntimeError("RESOURCE_EXHAUSTED: larger than max")
-
-        def blocking_key_value_get(self, key, timeout_in_ms):
+        def key_value_try_get(self, key):
             raise RuntimeError("UNAVAILABLE: coordination service down")
 
-    kv = DistributedKV.__new__(DistributedKV)
-    kv._client = FakeClient()
-    kv._has_try_get = False
     with pytest.raises(RuntimeError, match="UNAVAILABLE"):
-        kv.get("run/adone")
+        _distributed_kv(FakeClient()).get("run/adone")
 
 
 # ---- resilience counters on the scrape endpoint ----

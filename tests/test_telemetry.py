@@ -17,7 +17,6 @@ from ps_pytorch_tpu.runtime.metrics import (
 from ps_pytorch_tpu.telemetry import (
     TelemetryAggregator, Tracer, compute_mfu, data_stall_fraction,
     derive_step_record, read_timeline, set_default_tracer, span,
-    step_flops_of,
 )
 from ps_pytorch_tpu.telemetry.registry import MetricSpec, Registry
 
@@ -191,15 +190,6 @@ def test_compute_mfu_hand_arithmetic():
     assert compute_mfu(100, 0.0, 200e9, 4) is None
     assert compute_mfu(100, 0.25, None, 4) is None
     assert compute_mfu(-1, 0.25, 200e9, 4) is None
-
-
-def test_step_flops_matches_hand_count():
-    # One [8,16]x[16,32] matmul = 2*8*16*32 FLOPs, traced via the jaxpr.
-    a = np.zeros((8, 16), np.float32)
-    b = np.zeros((16, 32), np.float32)
-    assert step_flops_of(lambda x, y: x @ y, a, b) == 2 * 8 * 16 * 32
-    # Untraceable callables degrade to None, not an exception.
-    assert step_flops_of(lambda: (_ for _ in ()).throw(ValueError())) is None
 
 
 def test_mfu_vs_lenet_training_step():

@@ -152,8 +152,7 @@ def test_analyze_codec_summary_and_cli(tmp_path, capsys):
 
 TRAIN_ARGS = ["--network", "LeNet", "--dataset", "synthetic_mnist",
               "--batch-size", "64", "--eval-freq", "0", "--resume", "false"]
-CPU_ENV = {"PS_TPU_PLATFORM": "cpu", "PS_TPU_LOCAL_DEVICES": "1",
-           "JAX_PLATFORMS": "cpu"}
+CPU_ENV = {"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1"}
 
 
 def test_sweep_trial_and_best(tmp_path):

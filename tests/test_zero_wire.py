@@ -431,8 +431,7 @@ def test_sigkill_then_resume_restores_sharded_state(tmp_path):
     from ps_pytorch_tpu.runtime import checkpoint as ckpt
     from ps_pytorch_tpu.runtime.multislice import MultiSliceTrainer
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_NUM_CPU_DEVICES="8")
     proc = subprocess.Popen(
         [sys.executable, "-c", _SIGKILL_DRIVER, str(tmp_path)],
         cwd=str(REPO), env=env,

@@ -22,6 +22,9 @@ def main(argv=None) -> int:
     from ps_pytorch_tpu.parallel import dist
     from ps_pytorch_tpu.runtime import Trainer
 
+    from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     # Multi-host bootstrap (no mpirun): tools/launch.py exports the env
     # contract; single-process runs skip this.
     if dist.initialize_from_env():

@@ -21,6 +21,9 @@ def main(argv=None) -> int:
     from ps_pytorch_tpu.parallel import dist
     from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
 
+    from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if dist.initialize_from_env():
         import jax
         print(f"DIST process {jax.process_index()}/{jax.process_count()}")
