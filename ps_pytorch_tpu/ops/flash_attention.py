@@ -148,6 +148,7 @@ def _fwd_call(q3, k3, v3, causal, scale, bq, bkv, interpret):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3)
     return o, lse
@@ -231,6 +232,7 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, bq, bkv, interpret):
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
 
@@ -259,6 +261,7 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, bq, bkv, interpret):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(k3, v3, q3, do3, lse, delta)
     return dq, dk, dv

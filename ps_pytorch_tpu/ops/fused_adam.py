@@ -79,6 +79,7 @@ def _fused_update_padded(bufs, step_size, *, b1, b2, eps, weight_decay,
         out_shape=[shape] * n_out,
         # p, m, v(, vh) update in place; operand 0 is step_size, g is last.
         input_output_aliases={i + 1: i for i in range(n_out)},
+        name="fused_adam",
         interpret=interpret,
     )(jnp.reshape(step_size.astype(jnp.float32), (1, 1)), *bufs)
 

@@ -65,6 +65,7 @@ def _fused_update_padded(p2d, b2d, g2d, lr, first, *, momentum, dampening,
         out_shape=[jax.ShapeDtypeStruct(p2d.shape, jnp.float32),
                    jax.ShapeDtypeStruct(b2d.shape, jnp.float32)],
         input_output_aliases={2: 0, 3: 1},   # p, buf update in place
+        name="fused_sgd",
         interpret=interpret,
     )(jnp.reshape(lr.astype(jnp.float32), (1, 1)),
       jnp.reshape(first.astype(jnp.int32), (1, 1)),

@@ -93,6 +93,7 @@ def _conv3x3(x, w, block_n, interpret, variant):
                                lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h, wd, c_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_n * h * wd, c_out), jnp.float32)],
+        name="conv3x3",
         interpret=interpret,
     )(xp, w2)
 

@@ -88,6 +88,7 @@ def _quantize_padded(x2d, noise, interpret):
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.int8),
+        name="quantize_int8",
         interpret=interpret,
     )(scales, x2d, noise)
     return values, scales.reshape(nblk, 1)

@@ -49,7 +49,7 @@ from ps_pytorch_tpu.telemetry import (
     declare_hierarchy_metrics, declare_integrity_metrics,
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_training_metrics, device_memory_record, host_rss_bytes,
-    set_default_tracer,
+    set_default_tracer, set_device_memory_gauges,
 )
 
 
@@ -990,10 +990,8 @@ class AsyncTrainer:
                 self.registry.set("train_step_time_s",
                                   time.monotonic() - t0)
                 self.registry.set("host_rss_bytes", float(host_rss_bytes()))
-                mem = device_memory_record()
-                for k in ("device_mem_peak_bytes", "device_mem_bytes"):
-                    if k in mem:
-                        self.registry.set(k, float(mem[k]))
+                set_device_memory_gauges(self.registry,
+                                         device_memory_record())
                 wire = self.transport.wire_stats()
                 extra = {}
                 if self.election is not None:

@@ -45,10 +45,11 @@ from ps_pytorch_tpu.parallel.sp import (
 from ps_pytorch_tpu.runtime import checkpoint as ckpt
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
 from ps_pytorch_tpu.telemetry import (
-    FlightRecorder, HealthMonitor, MetricsExporter, Registry, Tracer,
-    declare_resilience_metrics,
+    FlightRecorder, HealthMonitor, MetricsExporter, ProfileWindow, Registry,
+    Tracer, declare_resilience_metrics,
     declare_training_metrics, derive_step_record,
     device_memory_record, host_rss_bytes, set_default_tracer,
+    set_device_memory_gauges,
 )
 from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
@@ -189,6 +190,8 @@ class LMTrainer:
         # analyze tooling must read vision and LM runs identically).
         self.tracer = Tracer(pid=jax.process_index())
         self._prev_tracer = set_default_tracer(self.tracer)
+        # --profile-dir / --profile-steps: the same window as the CNN Trainer.
+        self._profile = ProfileWindow(cfg.profile_dir, cfg.profile_steps)
         self._flops_per_step: Optional[int] = None
         self._n_chips = n
         self._peak_per_chip = peak_flops_bf16(devices[0].device_kind)
@@ -264,10 +267,7 @@ class LMTrainer:
             r.set("train_examples_per_sec", self.cfg.batch_size / step_time)
         if data_time is not None:
             r.set("train_data_time_s", data_time)
-        mem = device_memory_record()
-        if mem:
-            r.set("device_mem_peak_bytes", mem.get("device_mem_peak_bytes", 0))
-            r.set("device_mem_bytes", mem.get("device_mem_bytes", 0))
+        set_device_memory_gauges(r, device_memory_record())
         r.set("host_rss_bytes", host_rss_bytes())
         if self.flightrec is not None:
             self.flightrec.record_step(step, loss=loss, step_time=step_time,
@@ -366,15 +366,21 @@ class LMTrainer:
             self.maybe_resume()
         step = self.start_step
         halted = False
+        tracer = self.tracer
         t_sync, n_unsynced = time.monotonic(), 0
         try:
             while step < cfg.max_steps:
                 step += 1
                 n_unsynced += 1
+                self._profile.on_step(step)
+                # The iteration's root span, as in runtime/trainer.py: the
+                # phases below are its children, its self time is what no
+                # span explains; the finally closes it on any other exit.
+                tracer.begin_step(step)
                 if self.injector is not None:
                     self.injector.maybe_crash(step)
                 t0 = time.monotonic()
-                with self.tracer.span("data_wait", step=step):
+                with tracer.span("data_wait"):
                     tokens = self.train_loader.next_batch()
                 t_data = time.monotonic() - t0
                 # Every process generates the identical shared-seed batch; the
@@ -382,19 +388,21 @@ class LMTrainer:
                 # host-local committed array can't feed a multi-host
                 # shard_map). SP shards the SEQUENCE axis; tp/pp/ep shard the
                 # batch axis.
-                tok_g = dist.globalize_replicated(self.mesh, tokens,
-                                                  spec=self._token_spec())
+                with tracer.span("batch_put", bytes=tokens.nbytes):
+                    tok_g = dist.globalize_replicated(
+                        self.mesh, tokens, spec=self._token_spec())
                 if self._flops_per_step is None:
-                    self._flops_per_step = forward_flops(
-                        self.step_fn, self.state, tok_g)
-                with self.tracer.span("host_dispatch", step=step):
+                    with tracer.span("flops_trace"):
+                        self._flops_per_step = forward_flops(
+                            self.step_fn, self.state, tok_g)
+                with tracer.span("host_dispatch"):
                     self.state, m = self.step_fn(self.state, tok_g)
                 # Dispatch is asynchronous: between syncs this is only
                 # what a non-blocking iteration costs the host.
                 t_step = time.monotonic() - t0
                 loss = None
                 if step % cfg.log_every == 0 or step == cfg.max_steps:
-                    with self.tracer.span("metrics_sync", step=step):
+                    with tracer.span("metrics_sync"):
                         loss = float(m["loss"])
                     # The loss read drained every step dispatched since the
                     # last sync, so the wall time over them is a true
@@ -403,22 +411,26 @@ class LMTrainer:
                     now = time.monotonic()
                     t_step = (now - t_sync) / n_unsynced
                     t_sync, n_unsynced = now, 0
-                    derived = derive_step_record(
-                        step_time_s=t_step, data_time_s=t_data,
-                        examples=cfg.batch_size,
-                        tokens=cfg.batch_size * cfg.lm_seq_len,
-                        flops_per_step=self._flops_per_step,
-                        peak_flops_per_chip=self._peak_per_chip,
-                        n_chips=self._n_chips)
-                    self.metrics.log_step(
-                        step, self.train_loader._epoch,
-                        loss=loss, acc=0.0, participating=1.0,
-                        step_time=t_step, data_time=t_data,
-                        phases=self.tracer.step_summary(step), **derived)
-                self._ops_step(step, loss=loss, step_time=t_step,
-                               data_time=t_data)
+                    # The record's phases are the spans closed so far: this
+                    # span itself is not among them.
+                    with tracer.span("log_write"):
+                        derived = derive_step_record(
+                            step_time_s=t_step, data_time_s=t_data,
+                            examples=cfg.batch_size,
+                            tokens=cfg.batch_size * cfg.lm_seq_len,
+                            flops_per_step=self._flops_per_step,
+                            peak_flops_per_chip=self._peak_per_chip,
+                            n_chips=self._n_chips)
+                        self.metrics.log_step(
+                            step, self.train_loader._epoch,
+                            loss=loss, acc=0.0, participating=1.0,
+                            step_time=t_step, data_time=t_data,
+                            phases=tracer.step_summary(step), **derived)
+                with tracer.span("ops_step"):
+                    self._ops_step(step, loss=loss, step_time=t_step,
+                                   data_time=t_data)
                 if self.health is not None and self.health.should_halt:
-                    with self.tracer.span("checkpoint", step=step):
+                    with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     if self.flightrec is not None:
                         self.flightrec.dump(
@@ -428,9 +440,11 @@ class LMTrainer:
                     halted = True
                     break
                 if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
-                    with self.tracer.span("checkpoint", step=step):
+                    with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     t_sync, n_unsynced = time.monotonic(), 0
+                tracer.end_step()
+            tracer.end_step()       # an iteration left by break
             jax.block_until_ready(self.state.params)
             if not halted and cfg.eval_freq > 0 and step % cfg.eval_freq != 0:
                 with self.tracer.span("checkpoint", step=step):
@@ -442,6 +456,8 @@ class LMTrainer:
                 self.flightrec.dump(f"crash:{type(e).__name__}")
             raise
         finally:
+            tracer.end_step()       # an iteration left by an exception
+            self._profile.close()
             if self.exporter is not None:
                 self.exporter.stop()
             self.metrics.close()

@@ -38,12 +38,12 @@ from ps_pytorch_tpu.runtime import checkpoint as ckpt
 from ps_pytorch_tpu.runtime.coordinator import Coordinator
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
 from ps_pytorch_tpu.telemetry import (
-    FlightRecorder, HealthMonitor, MetricsExporter, Registry,
+    FlightRecorder, HealthMonitor, MetricsExporter, ProfileWindow, Registry,
     TelemetryAggregator, Tracer,
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_training_metrics,
     derive_step_record, device_memory_record, host_rss_bytes,
-    set_default_tracer,
+    set_default_tracer, set_device_memory_gauges,
 )
 from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
@@ -269,11 +269,7 @@ class Trainer:
                 self._telemetry.open_timeline(timeline)
         # jax.profiler trace window (SURVEY §5.1: the reference's hand-rolled
         # timers + our structured lines, plus real profiler integration).
-        self._profile_range = None
-        self._trace_active = False
-        if cfg.profile_dir:
-            lo, _, hi = cfg.profile_steps.partition("-")
-            self._profile_range = (int(lo), int(hi or lo))
+        self._profile = ProfileWindow(cfg.profile_dir, cfg.profile_steps)
         self.start_step = 0
         if cfg.resume:
             self._maybe_resume()
@@ -371,12 +367,7 @@ class Trainer:
         """HBM/RSS watermarks into the registry — called per step AND as an
         exporter collect hook, so a scrape between steps still sees fresh
         memory pressure."""
-        mem = device_memory_record()
-        if mem:
-            self.registry.set("device_mem_peak_bytes",
-                              mem.get("device_mem_peak_bytes", 0))
-            self.registry.set("device_mem_bytes",
-                              mem.get("device_mem_bytes", 0))
+        set_device_memory_gauges(self.registry, device_memory_record())
         self.registry.set("host_rss_bytes", host_rss_bytes())
 
     def _pump_resilience_metrics(self) -> None:
@@ -529,36 +520,33 @@ class Trainer:
         m_prev = None
         preempted = False
         halted = False
+        tracer = self.tracer
         self._preempt.install()
         t_sync, n_unsynced = time.monotonic(), 0
         try:
             while step < last_step:
                 step += 1
                 n_unsynced += 1
+                self._profile.on_step(step)
+                # The iteration's root span: every phase below is its child,
+                # so its self time is what no span explains. It closes at
+                # the iteration's end, or in the finally on any other exit.
+                tracer.begin_step(step)
                 if self.injector is not None:
                     # Before any KV/device work for this step: the crash
                     # models a process dying BETWEEN steps, so the last
                     # committed checkpoint is the recovery point.
                     self.injector.maybe_crash(step)
-                if self._profile_range:
-                    lo, hi = self._profile_range
-                    # Window-membership, not step equality: a resumed run may
-                    # enter the loop past `lo` (or never reach `hi`).
-                    if not self._trace_active and lo <= step <= hi:
-                        jax.profiler.start_trace(self.cfg.profile_dir)
-                        self._trace_active = True
-                    elif self._trace_active and step > hi:
-                        jax.profiler.stop_trace()
-                        self._trace_active = False
-                        self._profile_range = None
-                self.coordinator.announce_step(step)
-                if self.heartbeat is not None:
-                    self.heartbeat.beat(step)
+                with tracer.span("coordinator"):
+                    self.coordinator.announce_step(step)
+                    if self.heartbeat is not None:
+                        self.heartbeat.beat(step)
                 t0 = time.monotonic()
-                with self.tracer.span("data_wait", step=step):
+                with tracer.span("data_wait"):
                     x, y = self.train_loader.next_batch()
                 t_data = time.monotonic() - t0
-                mask = self.coordinator.participation_mask(step)
+                with tracer.span("coordinator"):
+                    mask = self.coordinator.participation_mask(step)
                 if self.injector is not None:
                     # Role-addressed kill AFTER the mask decision: the
                     # leader dies with this step's mask already published,
@@ -579,21 +567,28 @@ class Trainer:
                             "fault_grad_nan", {"step": step})
                 # Legacy uint32[2] key: globalizable as a plain replicated array
                 # (typed key dtypes can't cross make_array_from_callback).
-                key = np.asarray(jax.random.PRNGKey(cfg.seed * 100003 + step))
-                xg = dist.globalize_batch(self.mesh, np.asarray(x))
-                yg = dist.globalize_batch(self.mesh, np.asarray(y))
-                mg = dist.globalize_replicated(self.mesh,
-                                               np.asarray(mask, np.float32))
-                kg = dist.globalize_replicated(
-                    self.mesh, key, spec=jax.sharding.PartitionSpec())
+                with tracer.span("rng_key"):
+                    key = np.asarray(
+                        jax.random.PRNGKey(cfg.seed * 100003 + step))
+                x, y = np.asarray(x), np.asarray(y)
+                with tracer.span("batch_put", bytes=x.nbytes + y.nbytes):
+                    xg = dist.globalize_batch(self.mesh, x)
+                    yg = dist.globalize_batch(self.mesh, y)
+                    mg = dist.globalize_replicated(
+                        self.mesh, np.asarray(mask, np.float32))
+                    kg = dist.globalize_replicated(
+                        self.mesh, key, spec=jax.sharding.PartitionSpec())
                 if self._flops_per_step is None:
                     # One abstract trace of the full fwd+bwd+update program
                     # (nothing executes).
-                    self._flops_per_step = forward_flops(
-                        self.step_fn, self.state, xg, yg, mg, kg)
-                with self.tracer.span("host_dispatch", step=step):
-                    new_state, m = self.step_fn(self.state, xg, yg, mg, kg)
-                self.state = new_state
+                    with tracer.span("flops_trace"):
+                        self._flops_per_step = forward_flops(
+                            self.step_fn, self.state, xg, yg, mg, kg)
+                with tracer.span("host_dispatch"):
+                    # Rebinding the state releases the donated buffers'
+                    # handles (0.2 ms on one chip, 0.75 ms on four): part of
+                    # the dispatch, as in runtime/lm_trainer.py.
+                    self.state, m = self.step_fn(self.state, xg, yg, mg, kg)
                 if cfg.inject_step_delay > 0 and \
                         jax.process_index() == cfg.inject_delay_process:
                     # Fault injection (tests/ops drills): make THIS host a
@@ -607,7 +602,7 @@ class Trainer:
                 # telemetry was gated on log_every; the reference timed every
                 # worker step, distributed_worker.py:169-173).
                 prev = None
-                with self.tracer.span("device_sync", step=step):
+                with tracer.span("device_sync"):
                     if m_prev is not None:
                         # The previous step's metrics materialize here either
                         # way; reading three scalars from the same (already
@@ -618,25 +613,27 @@ class Trainer:
                             prev["grad_norm"] = float(m_prev["grad_norm"])
                         if "nonfinite" in m_prev:
                             prev["nonfinite"] = float(m_prev["nonfinite"])
-                m_prev = m
+                    m_prev = m      # frees the scalars just read
                 t_step = time.monotonic() - t0
-                for r in self._local_replicas:
-                    self.coordinator.report_duration(r, step, t_step)
-                self._ops_step(step, step_time=t_step, data_time=t_data,
-                               **(prev or {}))
+                with tracer.span("ops_step"):
+                    for r in self._local_replicas:
+                        self.coordinator.report_duration(r, step, t_step)
+                    self._ops_step(step, step_time=t_step, data_time=t_data,
+                                   **(prev or {}))
                 if self.health is not None and self.health.should_halt:
                     self._halt_for_health(step)
                     halted = True
                     break
                 if self._telemetry is not None:
-                    rec = {
-                        "step_time": round(t_step, 6),
-                        "data_time": round(t_data, 6),
-                        "phases": self.tracer.step_summary(step)}
-                    if self._resilience_active():
-                        rec["resilience"] = self.resilience_stats()
-                    self._telemetry.publish_step(step, rec)
-                    self._telemetry.drain_to_file()  # no-op off-leader
+                    with tracer.span("telemetry_publish"):
+                        rec = {
+                            "step_time": round(t_step, 6),
+                            "data_time": round(t_data, 6),
+                            "phases": tracer.step_summary(step)}
+                        if self._resilience_active():
+                            rec["resilience"] = self.resilience_stats()
+                        self._telemetry.publish_step(step, rec)
+                        self._telemetry.drain_to_file()  # no-op off-leader
                 if step % cfg.log_every == 0 or step == last_step:
                     # Materializing metrics fully syncs the device, in its
                     # own span. t_step above (what the coordinator's policies
@@ -645,7 +642,7 @@ class Trainer:
                     # which reads as an MFU above 1 on a chip. The LOGGED
                     # duration is therefore the wall time since the last full
                     # sync over the steps dispatched since it.
-                    with self.tracer.span("metrics_sync", step=step):
+                    with tracer.span("metrics_sync"):
                         loss = float(m["loss"])
                         acc = float(m["accuracy"])
                         part = float(m["participating"])
@@ -653,34 +650,41 @@ class Trainer:
                     t_logged = (now - t_sync) / n_unsynced
                     t_sync, n_unsynced = now, 0
                     epoch = (step - 1) // steps_per_epoch
-                    derived = derive_step_record(
-                        step_time_s=t_logged, data_time_s=t_data,
-                        examples=cfg.batch_size,
-                        flops_per_step=self._flops_per_step,
-                        peak_flops_per_chip=self._peak_per_chip,
-                        n_chips=self._n_chips)
-                    extra = dict(derived)
-                    if self._resilience_active():
-                        extra.update(self.resilience_stats())
-                    self.metrics.log_step(
-                        step, epoch, loss=loss, acc=acc, participating=part,
-                        step_time=t_logged, data_time=t_data,
-                        phases=self.tracer.step_summary(step), **extra)
+                    # The record's phases are the spans closed so far: this
+                    # span itself (and a checkpoint after it) is not among
+                    # them.
+                    with tracer.span("log_write"):
+                        derived = derive_step_record(
+                            step_time_s=t_logged, data_time_s=t_data,
+                            examples=cfg.batch_size,
+                            flops_per_step=self._flops_per_step,
+                            peak_flops_per_chip=self._peak_per_chip,
+                            n_chips=self._n_chips)
+                        extra = dict(derived)
+                        if self._resilience_active():
+                            extra.update(self.resilience_stats())
+                        self.metrics.log_step(
+                            step, epoch, loss=loss, acc=acc,
+                            participating=part, step_time=t_logged,
+                            data_time=t_data,
+                            phases=tracer.step_summary(step), **extra)
                 if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
-                    with self.tracer.span("checkpoint", step=step):
+                    with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     t_sync, n_unsynced = time.monotonic(), 0
                 if self._preempt.triggered:
                     # SIGTERM (preemption notice): commit an emergency
                     # checkpoint at this step boundary and leave cleanly so
                     # auto-resume (or the next scheduling) restores here.
-                    with self.tracer.span("checkpoint", step=step):
+                    with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     print(f"PREEMPT emergency checkpoint at step {step}")
                     if self.flightrec is not None:
                         self.flightrec.dump("sigterm", extra={"step": step})
                     preempted = True
                     break
+                tracer.end_step()
+            tracer.end_step()       # an iteration left by break
             jax.block_until_ready(self.state.params)
             if m_prev is not None and self.health is not None and not halted:
                 # The loop's sync point trails by one step: check the LAST
@@ -716,9 +720,8 @@ class Trainer:
                 self.exporter.stop()
             # Telemetry sinks close on ANY exit — a trainer exception must
             # not leak the JSONL handle or lose the trace collected so far.
-            if self._trace_active:
-                jax.profiler.stop_trace()
-                self._trace_active = False
+            tracer.end_step()       # an iteration left by an exception
+            self._profile.close()
             self.metrics.close()
             if cfg.trace_file:
                 path = cfg.trace_file

@@ -27,11 +27,12 @@ from ps_pytorch_tpu.telemetry.registry import (  # noqa: F401
     declare_hierarchy_metrics, declare_integrity_metrics,
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_serving_metrics, declare_training_metrics, derive_step_record,
-    device_memory_record, host_rss_bytes,
+    device_memory_record, host_rss_bytes, set_device_memory_gauges,
 )
 from ps_pytorch_tpu.telemetry.slo import (  # noqa: F401
     SLOObjective, SLOTracker, WindowPercentile, check_slo, parse_slo_spec,
 )
 from ps_pytorch_tpu.telemetry.trace import (  # noqa: F401
-    Tracer, get_default_tracer, set_default_tracer, span,
+    ProfileWindow, Tracer, get_default_tracer, latest_tracer, self_times,
+    set_default_tracer, span,
 )

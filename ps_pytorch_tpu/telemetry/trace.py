@@ -21,19 +21,49 @@ Two ways in:
 Spans tagged with ``step=`` additionally feed a per-step phase accumulator
 (``step_summary``), which is what the MetricsLogger v2 record and the
 cross-host aggregator publish (telemetry/aggregate.py).
+
+What a span records (the tracing contract every reader relies on): ``id``,
+the ``parent`` id from the opening thread's stack (None at top level),
+``name``, ``t0`` / ``dur`` (``time.monotonic`` seconds), ``tid`` and the
+``step`` the spans of one iteration share (inherited from the parent when
+not given). A trainer's iteration is one ROOT span (``begin_step`` /
+``end_step``, named ``train_step``) whose children are the iteration's
+phases, so the root's self time (``self_times``) is the part of the
+iteration no span explains. Every span is also a ``jax.profiler``
+``TraceAnnotation`` (the root a ``StepTraceAnnotation``), so a profile taken
+with the host tracer on carries the program's spans on the device trace's
+own clock. Where the host tracer is off, ``wall_ns`` does: every top-level
+span (a root among them) reads ``time.time_ns()`` beside its monotonic
+start, and each recorded span carries its own start on the Unix clock,
+converted through that anchor. The xplane counts from its session's start,
+which it records as Unix ns (plane ``Task Environment``, stat
+``profile_start_time``).
 """
 
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional
+
+import jax.profiler
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # Chrome trace_event "complete" events need ph/ts/dur/pid/tid/name; ts and
 # dur are MICROseconds. https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
 _US = 1e6
+
+
+ROOT_SPAN = "train_step"      # the root span of one trainer iteration
+
+
+class _Frame:
+    """One open span on its thread's stack."""
+    __slots__ = ("id", "parent", "top", "name", "step", "args", "root",
+                 "t0", "wall_ns", "children_s", "annotation")
 
 
 class Tracer:
@@ -53,6 +83,7 @@ class Tracer:
         self._buf: deque = deque(maxlen=self.capacity)
         self._lock = threading.Lock()
         self._tls = threading.local()
+        self._ids = itertools.count(1)
         self._step_window = max(int(step_window), 1)
         self._step_totals: Dict[int, Dict[str, float]] = {}
         self._totals: Dict[str, List[float]] = {}  # name -> [count, total_s]
@@ -64,49 +95,104 @@ class Tracer:
             st = self._tls.stack = []
         return st
 
+    def _open(self, name: str, step: Optional[int], args: dict,
+              root: bool = False) -> _Frame:
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        f = _Frame()
+        f.id = next(self._ids)
+        f.parent = parent.id if parent else None
+        f.top = parent.top if parent else f
+        f.name, f.args, f.root = name, args, root
+        # The spans of one iteration share its step.
+        f.step = step if step is not None or parent is None else parent.step
+        f.children_s = 0.0
+        if root:
+            f.annotation = StepTraceAnnotation(name, step_num=f.step)
+        elif f.step is None:
+            f.annotation = TraceAnnotation(name)
+        else:
+            f.annotation = TraceAnnotation(name, step=f.step)
+        f.annotation.__enter__()
+        stack.append(f)
+        # A top-level span anchors itself on the Unix clock; what it holds
+        # converts through it (one clock pair a tree, read back to back).
+        f.wall_ns = time.time_ns() if parent is None else None
+        f.t0 = time.monotonic()
+        return f
+
+    def _close(self, f: _Frame) -> None:
+        t1 = time.monotonic()
+        f.annotation.__exit__(None, None, None)
+        stack = self._stack()
+        while stack and stack.pop() is not f:
+            pass                        # frames an exception left above f
+        dur = t1 - f.t0
+        if stack:
+            stack[-1].children_s += dur
+        self._record(f, dur)
+
     @contextmanager
     def span(self, name: str, step: Optional[int] = None, **args):
-        """Nestable timed region. Nesting depth is carried implicitly by
-        start/end containment (Perfetto stacks overlapping same-tid spans).
+        """Nestable timed region; its parent is the span open on this thread.
 
         Yields the span's mutable args dict — recorded at EXIT, so code
         inside the region can attach facts it only learns mid-span
         (``sargs["corr"] = ...`` for cross-process stitching, byte counts,
         versions) without a second recording API."""
-        stack = self._stack()
-        stack.append(name)
-        t0 = time.monotonic()
+        f = self._open(name, step, args)
         try:
             yield args
         finally:
-            t1 = time.monotonic()
-            stack.pop()
-            self._record(name, t0, t1, step, args)
+            self._close(f)
 
-    def _record(self, name, t0, t1, step, args) -> None:
-        dur = t1 - t0
-        ev = {"name": name, "t0": t0, "dur": dur,
-              "tid": threading.get_ident()}
-        if step is not None:
-            ev["step"] = int(step)
-        if args:
-            ev["args"] = args
+    def begin_step(self, step: int) -> None:
+        """Open the root span of one trainer iteration on this thread (the
+        trace's ``StepTraceAnnotation``). The spans opened until ``end_step``
+        are its descendants; an iteration left open is closed first."""
+        self.end_step()
+        self._tls.root = self._open(ROOT_SPAN, step, {}, root=True)
+
+    def end_step(self) -> None:
+        """Close this thread's open root span, if any, and whatever an
+        exception or a ``break`` left open under it."""
+        root = getattr(self._tls, "root", None)
+        if root is not None:
+            self._tls.root = None
+            self._close(root)
+
+    def _record(self, f: _Frame, dur: float) -> None:
+        top = f.top
+        ev = {"id": f.id, "parent": f.parent, "name": f.name, "t0": f.t0,
+              "dur": dur, "tid": threading.get_ident(),
+              "wall_ns": top.wall_ns + int(round((f.t0 - top.t0) * 1e9))}
+        if f.step is not None:
+            ev["step"] = int(f.step)
+        if f.root:
+            ev["root"] = True
+        if f.args:
+            ev["args"] = f.args
         with self._lock:
             if len(self._buf) == self.capacity:
                 self.dropped += 1
             self._buf.append(ev)
-            c = self._totals.setdefault(name, [0, 0.0])
+            c = self._totals.setdefault(f.name, [0, 0.0])
             c[0] += 1
             c[1] += dur
-            if step is not None:
-                acc = self._step_totals.setdefault(int(step), {})
-                acc[name] = acc.get(name, 0.0) + dur
+            if f.step is not None and not f.root:
+                # A phase is the SELF time of its spans: the phases of a
+                # step add up to the time under spans once, however the
+                # spans nest. The root is the iteration, not a phase.
+                acc = self._step_totals.setdefault(int(f.step), {})
+                acc[f.name] = acc.get(f.name, 0.0) + dur - f.children_s
                 if len(self._step_totals) > self._step_window:
                     self._step_totals.pop(min(self._step_totals), None)
 
     # ---- summaries ----
     def step_summary(self, step: int, pop: bool = False) -> Dict[str, float]:
-        """{phase name: total seconds} of spans tagged with ``step``."""
+        """{phase name: seconds} of the spans of ``step`` closed so far, each
+        at its self time (children's time is under the children's names);
+        the iteration's root span is not a phase."""
         with self._lock:
             acc = (self._step_totals.pop(int(step), {}) if pop
                    else dict(self._step_totals.get(int(step), {})))
@@ -161,23 +247,93 @@ class Tracer:
         return path
 
 
+# ---- self time (choosing-metrics: duration minus what the children cover) ----
+
+def self_times(spans: Iterable[dict]) -> Dict[int, float]:
+    """{span id: its duration minus the union of its children's intervals,
+    clipped to it}, over recorded spans (``Tracer.spans()``). Children are
+    found by ``parent`` id; one whose parent left the ring buffer counts for
+    nothing."""
+    spans = list(spans)
+    kids: Dict[int, List[dict]] = {}
+    for ev in spans:
+        if ev.get("parent") is not None:
+            kids.setdefault(ev["parent"], []).append(ev)
+    out = {}
+    for ev in spans:
+        lo, hi = ev["t0"], ev["t0"] + ev["dur"]
+        covered, edge = 0.0, lo
+        for k in sorted(kids.get(ev["id"], ()), key=lambda k: k["t0"]):
+            s, e = max(k["t0"], edge), min(k["t0"] + k["dur"], hi)
+            if e > s:
+                covered += e - s
+                edge = e
+        out[ev["id"]] = ev["dur"] - covered
+    return out
+
+
+# ---- the profiler window of a trainer's loop ----
+
+class ProfileWindow:
+    """``--profile-dir D --profile-steps lo-hi``: a ``jax.profiler`` trace
+    over steps lo..hi of a trainer's loop. ``on_step`` goes at the loop's
+    head, before ``Tracer.begin_step``, so that the first traced iteration's
+    annotation is whole; ``close`` goes in the loop's ``finally``."""
+
+    def __init__(self, profile_dir: str, profile_steps: str):
+        self.dir = profile_dir
+        self.range = None
+        self.active = False
+        if profile_dir:
+            lo, _, hi = profile_steps.partition("-")
+            self.range = (int(lo), int(hi or lo))
+
+    def on_step(self, step: int) -> None:
+        if self.range is None:
+            return
+        lo, hi = self.range
+        # Window-membership, not step equality: a resumed run may enter the
+        # loop past `lo` (or never reach `hi`).
+        if not self.active and lo <= step <= hi:
+            jax.profiler.start_trace(self.dir)
+            self.active = True
+        elif self.active and step > hi:
+            self.close()
+
+    def close(self) -> None:
+        if self.active:
+            jax.profiler.stop_trace()
+            self.active = False
+            self.range = None
+
+
 # ---- ambient tracer (library-layer instrumentation without API churn) ----
 _default: Optional[Tracer] = None
+_latest: Optional[Tracer] = None
 _default_lock = threading.Lock()
 
 
 def set_default_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
     """Install (or clear, with None) the process-wide default tracer used by
     the module-level ``span``. Returns the previous one."""
-    global _default
+    global _default, _latest
     with _default_lock:
         prev = _default
         _default = tracer
+        if tracer is not None:
+            _latest = tracer
     return prev
 
 
 def get_default_tracer() -> Optional[Tracer]:
     return _default
+
+
+def latest_tracer() -> Optional[Tracer]:
+    """The tracer most recently installed as the default, still reachable
+    after its trainer's ``train()`` has restored the previous default: what
+    a caller that holds no trainer reads a finished run's spans from."""
+    return _latest
 
 
 @contextmanager

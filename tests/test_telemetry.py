@@ -4,6 +4,8 @@ cross-host KV aggregation, analyze timeline mode, and the trainer smoke
 that ties them together (the ISSUE's CPU acceptance run, in-process)."""
 
 import json
+import threading
+import time
 
 import jax
 import numpy as np
@@ -15,8 +17,10 @@ from ps_pytorch_tpu.runtime.metrics import (
     MetricsLogger, format_line, parse_line,
 )
 from ps_pytorch_tpu.telemetry import (
-    TelemetryAggregator, Tracer, compute_mfu, data_stall_fraction,
-    derive_step_record, read_timeline, set_default_tracer, span,
+    ProfileWindow, TelemetryAggregator, Tracer, compute_mfu,
+    data_stall_fraction, declare_training_metrics, derive_step_record,
+    device_memory_record, get_default_tracer, latest_tracer, read_timeline,
+    self_times, set_default_tracer, set_device_memory_gauges, span,
 )
 from ps_pytorch_tpu.telemetry.registry import MetricSpec, Registry
 
@@ -439,3 +443,397 @@ def test_lm_trainer_schema_parity(tmp_path, capsys):
         names = {e["name"] for e in json.load(f)["traceEvents"]
                  if e["ph"] == "X"}
     assert {"data_wait", "host_dispatch", "metrics_sync"} <= names
+
+
+# ---- trace.py: parents, self time, the iteration's root, the Unix anchor ----
+
+def _by_name(spans):
+    return {e["name"]: e for e in spans}
+
+
+def test_parent_ids_and_self_time_nested_and_siblings():
+    tr = Tracer()
+    with tr.span("outer"):
+        with tr.span("a"):
+            with tr.span("a_inner"):
+                time.sleep(0.002)
+        with tr.span("b"):
+            time.sleep(0.001)
+    ev = _by_name(tr.spans())
+    assert len({e["id"] for e in ev.values()}) == 4
+    assert ev["outer"]["parent"] is None
+    assert ev["a"]["parent"] == ev["b"]["parent"] == ev["outer"]["id"]
+    assert ev["a_inner"]["parent"] == ev["a"]["id"]
+    st = self_times(tr.spans())
+    # a leaf's self time is its duration; a parent's is its duration less its
+    # children's (siblings on one thread do not overlap: the union is the sum)
+    assert st[ev["a_inner"]["id"]] == ev["a_inner"]["dur"]
+    assert st[ev["a"]["id"]] == pytest.approx(
+        ev["a"]["dur"] - ev["a_inner"]["dur"])
+    assert st[ev["outer"]["id"]] == pytest.approx(
+        ev["outer"]["dur"] - ev["a"]["dur"] - ev["b"]["dur"])
+    assert all(v >= 0 for v in st.values())
+
+
+def test_self_time_is_duration_minus_the_union_of_children():
+    # hand-built: two children that overlap each other and one that sticks
+    # out of the parent cover 0-6 and 8-10 of a parent 0-10
+    spans = [dict(id=1, parent=None, name="p", t0=0.0, dur=10.0),
+             dict(id=2, parent=1, name="c", t0=0.0, dur=4.0),
+             dict(id=3, parent=1, name="c", t0=3.0, dur=3.0),
+             dict(id=4, parent=1, name="c", t0=8.0, dur=5.0),
+             dict(id=5, parent=99, name="orphan", t0=1.0, dur=1.0)]
+    st = self_times(spans)
+    assert st[1] == pytest.approx(2.0)
+    assert st[2] == 4.0 and st[5] == 1.0
+
+
+def test_parents_are_per_thread():
+    tr = Tracer()
+    seen = {}
+
+    def worker():
+        with tr.span("w_outer"):
+            with tr.span("w_inner"):
+                seen["ok"] = True
+
+    with tr.span("main_outer"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive() and seen["ok"]
+    ev = _by_name(tr.spans())
+    # the worker's spans are not children of what the main thread had open
+    assert ev["w_outer"]["parent"] is None
+    assert ev["w_inner"]["parent"] == ev["w_outer"]["id"]
+    assert ev["main_outer"]["parent"] is None
+    assert ev["w_outer"]["tid"] != ev["main_outer"]["tid"]
+    st = self_times(tr.spans())
+    assert st[ev["main_outer"]["id"]] == ev["main_outer"]["dur"]
+
+
+def test_root_is_not_a_phase_and_nested_spans_are_counted_once():
+    tr = Tracer()
+    tr.begin_step(7)
+    with tr.span("coordinator"):
+        with tr.span("coordinator_mask", step=7):
+            time.sleep(0.002)
+    with tr.span("data_wait"):
+        pass
+    with tr.span("coordinator"):        # a second span of the name adds up
+        time.sleep(0.001)
+    tr.end_step()
+    ev = tr.spans()
+    root = [e for e in ev if e.get("root")]
+    assert len(root) == 1 and root[0]["name"] == "train_step"
+    assert root[0]["step"] == 7 and root[0]["parent"] is None
+    # children inherit the iteration's step
+    assert all(e["step"] == 7 for e in ev)
+    phases = tr.step_summary(7)
+    assert set(phases) == {"coordinator", "coordinator_mask", "data_wait"}
+    # each phase is self time, so the phases add up to the time under spans
+    # once: the root's duration less its own self time
+    st = self_times(ev)
+    assert sum(phases.values()) == pytest.approx(
+        root[0]["dur"] - st[root[0]["id"]], abs=5e-6)
+    mask = [e for e in ev if e["name"] == "coordinator_mask"][0]
+    coord = sum(e["dur"] for e in ev if e["name"] == "coordinator")
+    assert phases["coordinator"] == pytest.approx(coord - mask["dur"], abs=5e-6)
+    # totals() stays whole durations over the tracer's lifetime
+    assert tr.totals()["coordinator"]["count"] == 2
+    assert tr.totals()["train_step"]["count"] == 1
+
+
+def test_begin_step_closes_what_was_left_open():
+    tr = Tracer()
+    tr.begin_step(1)
+    try:
+        with tr.span("data_wait"):
+            raise KeyError("the loop left the iteration by an exception")
+    except KeyError:
+        pass
+    tr.begin_step(2)        # closes step 1's root first
+    tr.end_step()
+    tr.end_step()           # no root open: nothing happens
+    roots = [e for e in tr.spans() if e.get("root")]
+    assert [r["step"] for r in roots] == [1, 2]
+    child = [e for e in tr.spans() if e["name"] == "data_wait"][0]
+    assert child["parent"] == roots[0]["id"]
+    with tr.span("after"):      # the stack is clean: a top-level span again
+        pass
+    assert tr.spans()[-1]["parent"] is None
+
+
+def test_anchored_start_is_on_the_unix_clock():
+    tr = Tracer()
+    before = time.time_ns()
+    tr.begin_step(1)
+    time.sleep(0.003)
+    with tr.span("late_child"):
+        mid = time.time_ns()
+    tr.end_step()
+    with tr.span("top_level"):      # every top-level span anchors itself
+        pass
+    after = time.time_ns()
+    ev = _by_name(tr.spans())
+    assert abs(ev["train_step"]["wall_ns"] - before) < 1_000_000
+    assert abs(ev["late_child"]["wall_ns"] - mid) < 1_000_000
+    assert ev["late_child"]["wall_ns"] - ev["train_step"]["wall_ns"] == \
+        pytest.approx((ev["late_child"]["t0"] - ev["train_step"]["t0"]) * 1e9,
+                      abs=2)
+    assert before <= ev["top_level"]["wall_ns"] <= after
+
+
+def test_span_records_normally_without_a_profiler_session():
+    # every span is also a jax.profiler TraceAnnotation; with no session
+    # running that is a no-op and the span is recorded all the same
+    from jax._src import profiler as _jp
+    assert _jp._profile_state.profile_session is None
+    tr = Tracer()
+    tr.begin_step(3)
+    with tr.span("host_dispatch", bytes=12) as sargs:
+        sargs["late"] = True
+    tr.end_step()
+    ev = _by_name(tr.spans())
+    assert ev["host_dispatch"]["args"] == {"bytes": 12, "late": True}
+    assert ev["host_dispatch"]["dur"] >= 0 and ev["train_step"]["step"] == 3
+    doc = tr.chrome_events()
+    assert {e["name"] for e in doc if e["ph"] == "X"} == \
+        {"train_step", "host_dispatch"}
+
+
+def test_latest_tracer_outlives_the_default():
+    a, b = Tracer(), Tracer()
+    prev = set_default_tracer(a)
+    try:
+        assert latest_tracer() is a
+        set_default_tracer(b)
+        set_default_tracer(None)
+        assert get_default_tracer() is None and latest_tracer() is b
+    finally:
+        set_default_tracer(prev)
+
+
+def test_profile_window_membership(monkeypatch):
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
+    off = ProfileWindow("", "10-12")
+    for step in range(1, 20):
+        off.on_step(step)
+    off.close()
+    assert calls == []
+    w = ProfileWindow("/tmp/p", "3-4")
+    for step in range(1, 8):
+        w.on_step(step)
+    w.close()           # already stopped: nothing more
+    assert calls == [("start", "/tmp/p"), ("stop",)]
+    # a resumed run that enters the loop inside the window, and ends in it
+    del calls[:]
+    w = ProfileWindow("/tmp/q", "3-9")
+    w.on_step(5)
+    w.on_step(6)
+    assert calls == [("start", "/tmp/q")] and w.active
+    w.close()
+    assert calls[-1] == ("stop",) and not w.active
+    # a single step
+    del calls[:]
+    w = ProfileWindow("/tmp/r", "2")
+    for step in (1, 2, 3):
+        w.on_step(step)
+    assert calls == [("start", "/tmp/r"), ("stop",)]
+
+
+# ---- registry: device memory with a faked memory_stats() ----
+
+class _FakeDevice:
+    def __init__(self, id_, stats):
+        self.id, self._stats = id_, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_device_memory_record_reports_reserved_peak_and_each_device(monkeypatch):
+    # the TPU's shape (chip run, PR 22: ResNet-18 b=4096): live buffers
+    # 0.16 GB, temporaries reserved apart 7.15 GB
+    devs = [_FakeDevice(0, {"peak_bytes_in_use": 159_000_000,
+                            "bytes_in_use": 140_000_000,
+                            "peak_bytes_reserved": 7_153_000_000}),
+            _FakeDevice(1, {"peak_bytes_in_use": 161_000_000,
+                            "bytes_in_use": 120_000_000,
+                            "peak_bytes_reserved": 7_100_000_000}),
+            _FakeDevice(2, None)]
+    monkeypatch.setattr(jax, "local_devices", lambda: devs)
+    rec = device_memory_record()
+    # existing fields unchanged: the fullest device's live buffers
+    assert rec["device_mem_peak_bytes"] == 161_000_000
+    assert rec["device_mem_bytes"] == 140_000_000
+    assert rec["device_mem_reserved_peak_bytes"] == 7_153_000_000
+    assert rec["device_mem_per_device"]["1"] == {
+        "device_mem_peak_bytes": 161_000_000, "device_mem_bytes": 120_000_000,
+        "device_mem_reserved_peak_bytes": 7_100_000_000}
+    assert set(rec["device_mem_per_device"]) == {"0", "1"}
+    json.dumps(rec)
+    one = device_memory_record(devs[0])
+    assert one["device_mem_peak_bytes"] == 159_000_000
+    # a backend without the reserved counter, and one without stats at all
+    old = device_memory_record(_FakeDevice(0, {"peak_bytes_in_use": 5,
+                                               "bytes_in_use": 3}))
+    assert "device_mem_reserved_peak_bytes" not in old
+    assert old["device_mem_peak_bytes"] == 5
+    assert device_memory_record(devs[2]) == {}
+    reg = declare_training_metrics(Registry())
+    set_device_memory_gauges(reg, rec)
+    set_device_memory_gauges(reg, rec)          # declared once, set again
+    assert reg.get("device_mem_reserved_peak_bytes") == 7_153_000_000
+    assert reg.get("device_mem_peak_bytes") == 161_000_000
+    assert reg.get("device_mem_reserved_peak_bytes_d1") == 7_100_000_000
+    assert reg.get("device_mem_bytes_d0") == 140_000_000
+    set_device_memory_gauges(reg, {})           # CPU: nothing to set
+    assert reg.get("device_mem_peak_bytes") == 161_000_000
+
+
+# ---- the trainers' iterations under the root span ----
+
+CNN_LEAVES = {"coordinator", "data_wait", "rng_key", "batch_put",
+              "host_dispatch", "device_sync", "ops_step",
+              "telemetry_publish", "metrics_sync", "log_write"}
+LM_LEAVES = {"data_wait", "batch_put", "host_dispatch", "metrics_sync",
+             "log_write", "ops_step"}
+
+
+@pytest.fixture(scope="module")
+def traced_runs(tmp_path_factory):
+    """One tiny Trainer run and one tiny LMTrainer run -> {kind: (spans,
+    JSONL records, steps, the trainer's tracer, latest_tracer() after
+    train())}."""
+    from ps_pytorch_tpu.runtime import Trainer
+    from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
+
+    out = {}
+    tmp = tmp_path_factory.mktemp("cnn")
+    cfg = _tiny_cfg(tmp, metrics_file=str(tmp / "m.jsonl"), eval_freq=2,
+                    timeline_file=str(tmp / "run.timeline"))
+    tmp_lm = tmp_path_factory.mktemp("lm")
+    lm_cfg = TrainConfig(
+        lm_vocab=64, lm_d_model=32, lm_layers=1, lm_heads=2, lm_seq_len=64,
+        lm_corpus_tokens=4096, batch_size=8, max_steps=4, eval_freq=2,
+        log_every=1, lr=0.01, train_dir=str(tmp_lm / "ckpt"),
+        metrics_file=str(tmp_lm / "m.jsonl"), resume=False, seed=0)
+    for kind, cls, c in (("cnn", Trainer, cfg), ("lm", LMTrainer, lm_cfg)):
+        t = cls(c)
+        t.train()
+        with open(c.metrics_file) as f:
+            recs = [json.loads(line) for line in f]
+        out[kind] = (t.tracer.spans(), recs, c.max_steps, t.tracer,
+                     latest_tracer())
+        assert get_default_tracer() is None
+    return out
+
+
+@pytest.mark.parametrize("kind, leaves", [("cnn", CNN_LEAVES),
+                                          ("lm", LM_LEAVES)])
+def test_every_iteration_is_one_root_with_its_phases_as_children(
+        traced_runs, kind, leaves):
+    spans, _, steps, _, _ = traced_runs[kind]
+    roots = [e for e in spans if e.get("root")]
+    assert [r["step"] for r in roots] == list(range(1, steps + 1))
+    assert all(r["name"] == "train_step" and r["parent"] is None
+               and r["wall_ns"] > 0 for r in roots)
+    for r in roots:
+        kids = {e["name"] for e in spans if e["parent"] == r["id"]}
+        want = leaves | ({"flops_trace"} if r["step"] == 1 else set()) \
+            | ({"checkpoint"} if r["step"] % 2 == 0 else set())
+        assert kids == want, (r["step"], kids ^ want)
+        assert all(e["step"] == r["step"] for e in spans
+                   if e["parent"] == r["id"])
+
+
+@pytest.mark.parametrize("kind", ["cnn", "lm"])
+def test_root_self_time_is_duration_minus_children(traced_runs, kind):
+    spans = traced_runs[kind][0]
+    st = self_times(spans)
+    for r in (e for e in spans if e.get("root")):
+        kids = [e for e in spans if e["parent"] == r["id"]]
+        assert st[r["id"]] >= 0
+        assert st[r["id"]] == pytest.approx(
+            r["dur"] - sum(k["dur"] for k in kids), abs=1e-9)
+        # the children lie inside the root, in order, without overlap
+        kids.sort(key=lambda k: k["t0"])
+        assert kids[0]["t0"] >= r["t0"]
+        assert kids[-1]["t0"] + kids[-1]["dur"] <= r["t0"] + r["dur"] + 1e-9
+        assert all(a["t0"] + a["dur"] <= b["t0"] + 1e-9
+                   for a, b in zip(kids, kids[1:]))
+
+
+def test_ambient_spans_nest_under_the_trainers_phases(traced_runs):
+    spans = traced_runs["cnn"][0]
+    by_id = {e["id"]: e for e in spans}
+    masks = [e for e in spans if e["name"] == "coordinator_mask"]
+    assert len(masks) == 4
+    assert all(by_id[m["parent"]]["name"] == "coordinator" for m in masks)
+    writes = [e for e in spans if e["name"] == "checkpoint_write"]
+    assert writes and all(by_id[w["parent"]]["name"] == "checkpoint"
+                          for w in writes)
+    # the loader's producer thread: its own top-level spans, no step
+    made = [e for e in spans if e["name"] == "loader_assemble"]
+    assert made and all(m["parent"] is None and "step" not in m for m in made)
+    assert {m["args"]["batch"] for m in made} >= {0, 1}
+    assert all(m["tid"] != masks[0]["tid"] for m in made)
+
+
+@pytest.mark.parametrize("kind", ["cnn", "lm"])
+def test_jsonl_phases_keep_their_keys_and_leave_the_root_out(traced_runs, kind):
+    _, recs, steps, _, _ = traced_runs[kind]
+    assert len(recs) == steps
+    for rec in recs:
+        phases = rec["phases"]
+        assert "train_step" not in phases
+        assert {"data_wait", "host_dispatch", "metrics_sync"} <= set(phases)
+        assert "batch_put" in phases            # a new leaf is a new key
+        assert all(v >= 0 for v in phases.values())
+        # nothing counted twice: the phases fit into the step's wall time
+        assert sum(phases.values()) <= rec["step_time"] * 1.05 + 1e-3 \
+            or rec["step"] == 1
+    if kind == "cnn":
+        assert all("coordinator_mask" in r["phases"] and
+                   "coordinator" in r["phases"] and
+                   "device_sync" in r["phases"] for r in recs)
+
+
+@pytest.mark.parametrize("kind", ["cnn", "lm"])
+def test_latest_tracer_survives_train(traced_runs, kind):
+    _, _, _, tracer, latest = traced_runs[kind]
+    assert latest is tracer
+
+
+def test_lm_trainer_profile_window_writes_the_programs_spans(tmp_path):
+    """train_lm.py's --profile-dir/--profile-steps: the xplane of the traced
+    steps carries the program's spans as the profiler's own events."""
+    import glob
+    from jax.profiler import ProfileData
+    from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
+
+    cfg = TrainConfig(
+        lm_vocab=64, lm_d_model=32, lm_layers=1, lm_heads=2, lm_seq_len=64,
+        lm_corpus_tokens=4096, batch_size=8, max_steps=5, eval_freq=0,
+        log_every=1, lr=0.01, train_dir=str(tmp_path / "ckpt"),
+        profile_dir=str(tmp_path / "prof"), profile_steps="3-4",
+        resume=False, seed=0)
+    LMTrainer(cfg).train()
+    found = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    assert len(found) == 1
+    steps, names = [], set()
+    for plane in ProfileData.from_file(found[0]).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    names.add(ev.name)
+                    if ev.name == "train_step":
+                        steps.append(dict(ev.stats)["step_num"])
+    assert {"train_step", "batch_put", "host_dispatch", "data_wait"} <= names
+    assert sorted(steps) == [3, 4]
