@@ -14,6 +14,7 @@ checkpoint. The Coordinator supplies the per-step participation mask
 
 import os
 import time
+from collections import deque
 from typing import Optional
 
 import jax
@@ -48,6 +49,15 @@ from ps_pytorch_tpu.telemetry import (
 from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
 from ps_pytorch_tpu.data.datasets import sample_shape
+
+
+def host_prng_key(seed: int) -> np.ndarray:
+    """``np.asarray(jax.random.PRNGKey(seed))`` without a device program:
+    the legacy threefry key is the seed's high and low 32 bits, and with
+    ``jax_enable_x64`` off the seed is first cut to 32 bits, so the high
+    word is 0 (tests/test_trainer_pipeline.py pins both settings)."""
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
 
 
 class Trainer:
@@ -463,7 +473,8 @@ class Trainer:
                         "chunk": plan.chunk, "step": step})
 
     def _ops_step(self, step: int, *, loss=None, grad_norm=None,
-                  nonfinite=None, step_time=None, data_time=None) -> None:
+                  nonfinite=None, step_time=None, data_time=None,
+                  dispatch_ahead: int = 0) -> None:
         """One step's worth of live-ops bookkeeping: registry gauges, memory
         watermarks, flight-recorder step record, and the health watchdogs.
         loss/grad_norm/nonfinite are the PREVIOUS step's values — already on
@@ -471,6 +482,7 @@ class Trainer:
         device round-trip."""
         r = self.registry
         r.inc("train_steps")
+        r.inc("dispatch_ahead_steps", dispatch_ahead)
         r.set("train_step", step)
         if loss is not None:
             r.set("train_loss", loss)
@@ -511,7 +523,14 @@ class Trainer:
     def train(self):
         """Run to max_steps (or epochs * steps-per-epoch, whichever is
         smaller — reference semantics: both bounds live on the CLI,
-        ``distributed_nn.py:34-36``)."""
+        ``distributed_nn.py:34-36``).
+
+        The loop has one rule: nothing between two dispatches waits for the
+        device, so one step is always queued behind the running one and the
+        host's work (loader, put, dispatch, bookkeeping) hides under the
+        device's. Its one wait, ``device_sync``, reads the PREVIOUS step's
+        loss with this step already queued; a step's record is written once
+        the next step is queued, and the per-step key is made on the host."""
         cfg = self.cfg
         steps_per_epoch = max(len(self.train_loader), 1)
         epoch_budget = cfg.epochs * steps_per_epoch if cfg.epochs > 0 else cfg.max_steps
@@ -522,11 +541,52 @@ class Trainer:
         halted = False
         tracer = self.tracer
         self._preempt.install()
-        t_sync, n_unsynced = time.monotonic(), 0
+        # Logged steps whose record (STEP line, JSONL) is not written yet: a
+        # step's is written once the NEXT step is queued, from metrics that
+        # device_sync has already seen finished. What drains the device
+        # anyway writes the waiting records first: a checkpoint, the loop's
+        # last step, an exception on its way out.
+        unwritten: deque = deque()
+        # When records' scalars were last read, and up to which step: the
+        # records read in one go share the wall time since, per step since.
+        # With a step queued the reads follow the device's pace, not the
+        # host's, and the step_times of a run add up to its wall time.
+        t_read, read_step = time.monotonic(), step
+
+        def log_through(upto: int) -> None:
+            nonlocal t_read, read_step
+            due = []
+            while unwritten and unwritten[0]["step"] <= upto:
+                due.append(unwritten.popleft())
+            if not due:
+                return
+            with tracer.span("metrics_sync"):
+                scalars = [(float(rec["m"]["loss"]),
+                            float(rec["m"]["accuracy"]),
+                            float(rec["m"]["participating"])) for rec in due]
+            now = time.monotonic()
+            t_logged = (now - t_read) / (due[-1]["step"] - read_step)
+            t_read, read_step = now, due[-1]["step"]
+            with tracer.span("log_write"):
+                for rec, (loss, acc, part) in zip(due, scalars):
+                    extra = derive_step_record(
+                        step_time_s=t_logged, data_time_s=rec["data_time"],
+                        examples=cfg.batch_size,
+                        flops_per_step=self._flops_per_step,
+                        peak_flops_per_chip=self._peak_per_chip,
+                        n_chips=self._n_chips)
+                    if self._resilience_active():
+                        extra.update(self.resilience_stats())
+                    self.metrics.log_step(
+                        rec["step"], (rec["step"] - 1) // steps_per_epoch,
+                        loss=loss, acc=acc, participating=part,
+                        step_time=t_logged, data_time=rec["data_time"],
+                        dispatch_ahead=rec["dispatch_ahead"],
+                        phases=tracer.step_summary(rec["step"]), **extra)
+
         try:
             while step < last_step:
                 step += 1
-                n_unsynced += 1
                 self._profile.on_step(step)
                 # The iteration's root span: every phase below is its child,
                 # so its self time is what no span explains. It closes at
@@ -568,8 +628,7 @@ class Trainer:
                 # Legacy uint32[2] key: globalizable as a plain replicated array
                 # (typed key dtypes can't cross make_array_from_callback).
                 with tracer.span("rng_key"):
-                    key = np.asarray(
-                        jax.random.PRNGKey(cfg.seed * 100003 + step))
+                    key = host_prng_key(cfg.seed * 100003 + step)
                 x, y = np.asarray(x), np.asarray(y)
                 with tracer.span("batch_put", bytes=x.nbytes + y.nbytes):
                     xg = dist.globalize_batch(self.mesh, x)
@@ -589,25 +648,30 @@ class Trainer:
                     # handles (0.2 ms on one chip, 0.75 ms on four): part of
                     # the dispatch, as in runtime/lm_trainer.py.
                     self.state, m = self.step_fn(self.state, xg, yg, mg, kg)
+                    # Was the chip still busy with the previous step when
+                    # this one was queued? (A query, not a wait.)
+                    ahead = int(m_prev is not None
+                                and not m_prev["loss"].is_ready())
+                if step % cfg.log_every == 0 or step == last_step:
+                    unwritten.append({"step": step, "m": m,
+                                      "data_time": t_data,
+                                      "dispatch_ahead": ahead})
                 if cfg.inject_step_delay > 0 and \
                         jax.process_index() == cfg.inject_delay_process:
                     # Fault injection (tests/ops drills): make THIS host a
                     # straggler. The reference had no fault injection at all
                     # (SURVEY §5.3); its stragglers were organic EC2 noise.
                     time.sleep(cfg.inject_step_delay)
-                # 1-deep pipeline: completing step-1 before dispatching step+1
-                # keeps device/host overlap while making the per-iteration wall
-                # time a TRUE per-step duration — reported EVERY step, so the
-                # kofn/deadline policies never act on stale numbers (the round-1
-                # telemetry was gated on log_every; the reference timed every
-                # worker step, distributed_worker.py:169-173).
+                # The loop's one wait for the device: the PREVIOUS step's
+                # loss, read with this step already queued. So the
+                # per-iteration wall time is a true per-step duration —
+                # reported EVERY step, so the kofn/deadline policies never act
+                # on stale numbers (the reference timed every worker step,
+                # distributed_worker.py:169-173) — and the watchdogs and the
+                # previous step's record get their values at no further sync.
                 prev = None
                 with tracer.span("device_sync"):
                     if m_prev is not None:
-                        # The previous step's metrics materialize here either
-                        # way; reading three scalars from the same (already
-                        # synced) device buffer is free — this is where the
-                        # watchdogs get their values at zero extra syncs.
                         prev = {"loss": float(m_prev["loss"])}
                         if "grad_norm" in m_prev:
                             prev["grad_norm"] = float(m_prev["grad_norm"])
@@ -619,8 +683,9 @@ class Trainer:
                     for r in self._local_replicas:
                         self.coordinator.report_duration(r, step, t_step)
                     self._ops_step(step, step_time=t_step, data_time=t_data,
-                                   **(prev or {}))
+                                   dispatch_ahead=ahead, **(prev or {}))
                 if self.health is not None and self.health.should_halt:
+                    log_through(step)
                     self._halt_for_health(step)
                     halted = True
                     break
@@ -634,48 +699,22 @@ class Trainer:
                             rec["resilience"] = self.resilience_stats()
                         self._telemetry.publish_step(step, rec)
                         self._telemetry.drain_to_file()  # no-op off-leader
-                if step % cfg.log_every == 0 or step == last_step:
-                    # Materializing metrics fully syncs the device, in its
-                    # own span. t_step above (what the coordinator's policies
-                    # see) ends before this sync; once every step logs, the
-                    # device wait lands here and t_step is only the dispatch,
-                    # which reads as an MFU above 1 on a chip. The LOGGED
-                    # duration is therefore the wall time since the last full
-                    # sync over the steps dispatched since it.
-                    with tracer.span("metrics_sync"):
-                        loss = float(m["loss"])
-                        acc = float(m["accuracy"])
-                        part = float(m["participating"])
-                    now = time.monotonic()
-                    t_logged = (now - t_sync) / n_unsynced
-                    t_sync, n_unsynced = now, 0
-                    epoch = (step - 1) // steps_per_epoch
-                    # The record's phases are the spans closed so far: this
-                    # span itself (and a checkpoint after it) is not among
-                    # them.
-                    with tracer.span("log_write"):
-                        derived = derive_step_record(
-                            step_time_s=t_logged, data_time_s=t_data,
-                            examples=cfg.batch_size,
-                            flops_per_step=self._flops_per_step,
-                            peak_flops_per_chip=self._peak_per_chip,
-                            n_chips=self._n_chips)
-                        extra = dict(derived)
-                        if self._resilience_active():
-                            extra.update(self.resilience_stats())
-                        self.metrics.log_step(
-                            step, epoch, loss=loss, acc=acc,
-                            participating=part, step_time=t_logged,
-                            data_time=t_data,
-                            phases=tracer.step_summary(step), **extra)
-                if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
+                # The previous step's record: its metrics finished under
+                # device_sync above, so these reads wait for nothing. Where
+                # the device is drained anyway (a checkpoint, the last step)
+                # this step's record goes with it, in one read.
+                saves = cfg.eval_freq > 0 and step % cfg.eval_freq == 0
+                drains = saves or step == last_step or self._preempt.triggered
+                log_through(step if drains else step - 1)
+                if saves:
                     with tracer.span("checkpoint"):
                         self._checkpoint(step)
-                    t_sync, n_unsynced = time.monotonic(), 0
+                    t_read, read_step = time.monotonic(), step
                 if self._preempt.triggered:
                     # SIGTERM (preemption notice): commit an emergency
                     # checkpoint at this step boundary and leave cleanly so
                     # auto-resume (or the next scheduling) restores here.
+                    log_through(step)   # a notice that came this moment
                     with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     print(f"PREEMPT emergency checkpoint at step {step}")
@@ -706,6 +745,13 @@ class Trainer:
                 with self.tracer.span("checkpoint", step=step):
                     self._checkpoint(step)
         except BaseException as e:
+            # The waiting records are written on the way out, best effort: a
+            # crashed or interrupted run keeps the log of every step it
+            # dispatched, and a failure here must not mask the real error.
+            try:
+                log_through(step)
+            except Exception as err:
+                print(f"LOG a waiting step record was not written: {err!r}")
             # The flight dump happens while the exception is in flight so a
             # crash post-mortem exists even when nothing catches it upstream;
             # dump() itself never raises (it must not mask the real error).

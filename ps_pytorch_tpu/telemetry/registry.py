@@ -462,6 +462,9 @@ def declare_router_metrics(registry: Registry) -> Registry:
 # vocabulary.
 TRAINING_COUNTERS = (
     ("train_steps", "steps", "training steps completed"),
+    ("dispatch_ahead_steps", "steps",
+     "steps queued while the device still ran the step before (Trainer: "
+     "the host loop keeps ahead of the chip)"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

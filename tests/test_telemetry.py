@@ -747,6 +747,11 @@ def test_every_iteration_is_one_root_with_its_phases_as_children(
         kids = {e["name"] for e in spans if e["parent"] == r["id"]}
         want = leaves | ({"flops_trace"} if r["step"] == 1 else set()) \
             | ({"checkpoint"} if r["step"] % 2 == 0 else set())
+        if kind == "cnn" and r["step"] % 2 == 1:
+            # Trainer writes a step's record once the next step is queued,
+            # or before the checkpoint that follows it: with eval_freq=2,
+            # iteration 2k writes records 2k-1 and 2k, iteration 2k+1 none.
+            want -= {"metrics_sync", "log_write"}
         assert kids == want, (r["step"], kids ^ want)
         assert all(e["step"] == r["step"] for e in spans
                    if e["parent"] == r["id"])
@@ -787,17 +792,23 @@ def test_ambient_spans_nest_under_the_trainers_phases(traced_runs):
 
 @pytest.mark.parametrize("kind", ["cnn", "lm"])
 def test_jsonl_phases_keep_their_keys_and_leave_the_root_out(traced_runs, kind):
-    _, recs, steps, _, _ = traced_runs[kind]
+    spans, recs, steps, _, _ = traced_runs[kind]
     assert len(recs) == steps
+    wall = {e["step"]: e["dur"] for e in spans if e.get("root")}
     for rec in recs:
         phases = rec["phases"]
         assert "train_step" not in phases
-        assert {"data_wait", "host_dispatch", "metrics_sync"} <= set(phases)
+        assert {"data_wait", "host_dispatch"} <= set(phases)
+        # Trainer's records hold their whole iteration, the write of the
+        # record before them included (here: in the even iterations).
+        assert ("metrics_sync" in phases) == \
+            (kind == "lm" or rec["step"] % 2 == 0)
         assert "batch_put" in phases            # a new leaf is a new key
         assert all(v >= 0 for v in phases.values())
-        # nothing counted twice: the phases fit into the step's wall time
-        assert sum(phases.values()) <= rec["step_time"] * 1.05 + 1e-3 \
-            or rec["step"] == 1
+        # nothing counted twice: the phases fit into their iteration's wall
+        # time (a record's step_time is the time between two reads of the
+        # device, which in Trainer trail the iteration by one step)
+        assert sum(phases.values()) <= wall[rec["step"]] + 1e-5
     if kind == "cnn":
         assert all("coordinator_mask" in r["phases"] and
                    "coordinator" in r["phases"] and
