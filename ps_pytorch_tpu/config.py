@@ -21,6 +21,12 @@ from dataclasses import dataclass, field
 from typing import Any, Optional
 
 
+# The rows of models/transformer.py:ARCHS (kept here as names only, so that
+# building a config imports no model code; tests/test_olmoe.py holds the two
+# lists equal).
+LM_ARCHS = ("gpt2", "olmoe")
+
+
 @dataclass
 class TrainConfig:
     # -- model / data (reference: distributed_nn.py:30-49) --
@@ -107,12 +113,14 @@ class TrainConfig:
     lm_seq_len: int = 1024           # sharded over the mesh (ring attention)
     lm_corpus_tokens: int = 1_000_000
     lm_corpus_file: str = ""         # byte-level REAL corpus from any local file ("" = synthetic Markov stream)
-    lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE experts)
+    lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE model, experts sharded over 'data'; also how an MoE model is chosen on ONE chip)
+    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) — models/transformer.py ARCHS
+    lm_ffn_dim: int = 0              # FFN / expert width (0 = 4 * lm_d_model)
     lm_attention: str = "auto"       # auto | full | flash (fused Pallas kernel). full/flash are sequence-local: sp over >1 device requires auto (ring)
     lm_model_axis: int = 0           # tp/pp: size of the 'model' mesh axis (0 = all devices)
     lm_microbatches: int = 4         # pp: GPipe microbatch count
     lm_experts: int = 8              # ep: expert count (divisible by device count)
-    lm_moe_top_k: int = 1            # ep: 1 = switch routing, 2 = GShard top-2
+    lm_moe_top_k: int = 1            # ep: experts per token. gpt2 arch (capacity routing): 1 = switch, 2 = GShard top-2; olmoe arch (dropless): 1..lm_experts
 
     # -- fault injection (tests / straggler drills; SURVEY §5.3: the
     #    reference had none) --
@@ -197,11 +205,27 @@ class TrainConfig:
         if self.lm_attention not in ("auto", "full", "flash"):
             raise ValueError(f"unknown lm_attention "
                              f"{self.lm_attention!r} (auto | full | flash)")
-        if self.lm_moe_top_k not in (1, 2):
-            # 1 = switch, 2 = GShard; k>2 would otherwise surface as an
-            # opaque trace-time shape error inside MoEMLP.
-            raise ValueError(f"lm_moe_top_k={self.lm_moe_top_k} (must be 1 "
-                             "[switch] or 2 [GShard top-2])")
+        if self.lm_arch not in LM_ARCHS:
+            raise ValueError(f"unknown lm_arch {self.lm_arch!r} "
+                             f"({' | '.join(LM_ARCHS)})")
+        if self.lm_arch == "olmoe":
+            if self.lm_parallelism != "ep":
+                raise ValueError("lm_arch=olmoe is an MoE model: pass "
+                                 "lm_parallelism=ep (on one chip too)")
+            if not 1 <= self.lm_moe_top_k <= self.lm_experts:
+                raise ValueError(f"lm_moe_top_k={self.lm_moe_top_k} (dropless "
+                                 f"routing: must be in 1..lm_experts="
+                                 f"{self.lm_experts})")
+        elif self.lm_moe_top_k not in (1, 2):
+            # The capacity path: 1 = switch, 2 = GShard; k>2 would otherwise
+            # surface as an opaque trace-time shape error inside MoEMLP.
+            raise ValueError(f"lm_moe_top_k={self.lm_moe_top_k} (capacity "
+                             "routing: must be 1 [switch] or 2 [GShard "
+                             "top-2]; lm_arch=olmoe routes dropless with any "
+                             "k up to lm_experts)")
+        if self.lm_ffn_dim < 0:
+            raise ValueError(f"lm_ffn_dim={self.lm_ffn_dim} (must be >= 0; "
+                             "0 = 4 * lm_d_model)")
         if self.lm_microbatches < 1:
             # 0 reaches the pp step as a division by zero mid-trace.
             raise ValueError(f"lm_microbatches={self.lm_microbatches} "

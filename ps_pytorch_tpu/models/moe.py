@@ -1,4 +1,6 @@
-"""Mixture-of-Experts transformer LM (switch top-1 or GShard top-2 routing).
+"""Mixture-of-Experts transformer LM: capacity routing (switch top-1, GShard
+top-2; ``MoEMLP``) or dropless top-k routing by sort and grouped matmul
+(``DroplessMoE``, the ``olmoe`` arch). The arch picks one per model.
 
 Beyond-parity model family backing expert parallelism (``parallel/ep.py``;
 the reference has no MoE or EP anywhere, SURVEY §2.5). Design points:
@@ -26,9 +28,17 @@ the reference has no MoE or EP anywhere, SURVEY §2.5). Design points:
   mean_prob_e)) returned alongside the output; the LM sums it over layers
   and the train step adds ``aux_coef`` times it to the CE loss.
 
-The dense (non-MoE) parts mirror ``models/transformer.py``'s Block exactly
-(same attention path, LayerNorm/Dense layout), so MoE slots into the same
-runtime contracts.
+The attention half of a block IS ``models/transformer.py``'s
+(``attention_sublayer``: one q/k/v/o path, norm, RoPE and q/k norm for both
+LM classes), so MoE slots into the same runtime contracts.
+
+**Why two routers stay.** The capacity path's tests pin rank-priority
+dispatch per group, bit-identical between the sharded step and the
+unsharded oracle, through the dense ``[g, tg, e, cap]`` one-hot tensors; the
+dropless path holds no tensor with an expert and a capacity axis and its work
+grows with tokens x top_k only. The capacity path would fall out of the
+sorted one with a keep mask ``pos < cap`` (PERF.md, Findings PR 25: not done,
+with the reason).
 """
 
 import math
@@ -38,9 +48,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ps_pytorch_tpu.models.transformer import cached_attention
-from ps_pytorch_tpu.ops.flash_attention import flash_attention
-from ps_pytorch_tpu.parallel.ring import full_attention
+from ps_pytorch_tpu.models.transformer import (
+    ARCHS, attention_sublayer, embed_tokens, make_norm,
+)
+from ps_pytorch_tpu.ops.grouped_matmul import gmm
+
+# What a dropless model returns beside its logits (and the ep step passes
+# on), with how each is taken over the layers.
+_OVER_LAYERS = {"aux": jnp.mean, "z_loss": jnp.mean,
+                "expert_load_max_over_mean": jnp.max, "moe_dropped": jnp.sum}
+DROPLESS_STATS = tuple(_OVER_LAYERS)
 
 
 class MoEMLP(nn.Module):
@@ -175,6 +192,83 @@ class MoEMLP(nn.Module):
         return y, aux
 
 
+class DroplessMoE(nn.Module):
+    """Dropless top-k MoE FFN with SiLU-gated (SwiGLU) experts, as OLMoE's.
+
+        r = h Wr (float32, highest precision)    p = softmax(r)
+        I = top-k of p,  g_i = p_i               (gates NOT renormalised)
+        y = sum_{i in I} g_i * (silu(h Wgate_i) * (h Wup_i)) Wdown_i
+
+    The T*k assignments are sorted by expert (stable: inside an expert the
+    order is the token order), the rows gathered, the three matmuls run as
+    grouped matmuls over the ``n_experts`` ragged groups
+    (``ops/grouped_matmul.gmm``), scaled by the gates and scatter-added
+    back. Nothing here is sized T x E x anything but the router's own logits
+    and probabilities, and no assignment is ever dropped.
+
+    Returns ``(y, stats)`` with ``stats`` keyed by ``DROPLESS_STATS``:
+    ``aux`` = E * sum_e f_e P_e over ALL k choices (f_e = assignments to e /
+    T, so sum_e f_e = k; P_e the mean router probability — what HF's
+    ``load_balancing_loss_func`` computes), ``z_loss`` = mean
+    logsumexp(r)^2, ``expert_load_max_over_mean`` = busiest expert's
+    assignments / (T*k/E), ``moe_dropped`` = assignments whose output was
+    not added (counted from the scatter's own indices; 0 by construction).
+    """
+    n_experts: int
+    d_model: int
+    d_hidden: int
+    top_k: int = 8
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        e, k, f = self.n_experts, self.top_k, self.d_hidden
+        if not 1 <= k <= e:
+            raise ValueError(f"top_k={k} must be in 1..n_experts={e}")
+        tokens = x.reshape(-1, d)                     # [T, D]
+        t = tokens.shape[0]
+        # The router is d x E: float32 under `highest` costs nothing, and a
+        # bf16-grade pass flips ties between the k-th and (k+1)-th expert.
+        router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST,
+                          name="router")(tokens.astype(jnp.float32))
+        probs = jax.nn.softmax(router, axis=-1)       # [T, E] float32
+        gates, idx = jax.lax.top_k(probs, k)          # [T, k]
+
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("experts_gate", init, (e, d, f))
+        w_up = self.param("experts_up", init, (e, d, f))
+        w_down = self.param("experts_down", init, (e, f, d))
+
+        # Assignment a = token * k + choice; sorted by expert, stable.
+        flat_e = idx.reshape(-1)                      # [T*k]
+        order = jnp.argsort(flat_e, stable=True)
+        tok = order // k                              # source row of each sorted assignment
+        group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        xs = tokens[tok].astype(self.dtype)           # gather [T*k, D]
+        h = nn.silu(gmm(xs, w_gate.astype(self.dtype), group_sizes)) * \
+            gmm(xs, w_up.astype(self.dtype), group_sizes)
+        out = gmm(h, w_down.astype(self.dtype), group_sizes)
+        out = out.astype(jnp.float32) * gates.reshape(-1)[order][:, None]
+        y = jnp.zeros((t, d), jnp.float32).at[tok].add(out)
+
+        # Counters, off the gradient path. Rows the grouped matmul covered
+        # are the first sum(group_sizes); each adds one to its token's count.
+        covered = jnp.arange(t * k) < jnp.sum(group_sizes)
+        added = jnp.zeros((t,), jnp.int32).at[tok].add(
+            covered.astype(jnp.int32))
+        load = group_sizes.astype(jnp.float32)
+        stats = {
+            "aux": e * jnp.sum((load / t) * jnp.mean(probs, axis=0)),
+            "z_loss": jnp.mean(jax.nn.logsumexp(router, axis=-1) ** 2),
+            "expert_load_max_over_mean": jnp.max(load) * e / (t * k),
+            "moe_dropped": jnp.float32(t * k) - jnp.sum(added).astype(
+                jnp.float32),
+        }
+        return y.reshape(b, s, d).astype(x.dtype), stats
+
+
 class MoEBlock(nn.Module):
     """transformer.Block with the dense MLP swapped for MoEMLP."""
     n_heads: int
@@ -198,41 +292,42 @@ class MoEBlock(nn.Module):
     # training forward exactly when that forward dropped nothing.
     decode: bool = False
     decode_cache_len: int = 0
+    arch: str = "gpt2"                # ARCHS row: attention half AND which MoE FFN
+    ffn_dim: int = 0                  # expert width (0 = 4 * d_model)
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, positions=None):
         b, s, d = x.shape
-        h = self.n_heads
-        hd = d // h
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        q = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        k = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        v = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        to_heads = lambda t: t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
-        if self.decode:
-            o = cached_attention(self, q, k, v, self.decode_cache_len)
-        elif self.attention_impl == "flash":
-            o = flash_attention(q, k, v, causal=True)
+        x = attention_sublayer(
+            self, x, positions, arch=self.arch, n_heads=self.n_heads,
+            dtype=self.dtype, attention_impl=self.attention_impl,
+            decode=self.decode, decode_cache_len=self.decode_cache_len)
+        y = make_norm(self.arch, self.dtype)(x)
+        if ARCHS[self.arch].dropless:
+            m, aux = DroplessMoE(self.n_experts, self.d_model,
+                                 self.ffn_dim or 4 * self.d_model,
+                                 top_k=self.top_k, dtype=self.dtype,
+                                 name="moe")(y)
         else:
-            o = full_attention(q, k, v, causal=True)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-        x = x + nn.Dense(d, use_bias=False, dtype=self.dtype)(o)
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        m, aux = MoEMLP(self.n_experts, self.d_model, 4 * self.d_model,
-                        capacity_factor=self.capacity_factor,
-                        n_groups=(b * s) if self.decode else self.n_groups,
-                        ep_axis=self.ep_axis,
-                        n_local_experts=self.n_local_experts,
-                        top_k=self.top_k, dtype=self.dtype, name="moe")(y)
+            m, aux = MoEMLP(self.n_experts, self.d_model,
+                            self.ffn_dim or 4 * self.d_model,
+                            capacity_factor=self.capacity_factor,
+                            n_groups=(b * s) if self.decode else self.n_groups,
+                            ep_axis=self.ep_axis,
+                            n_local_experts=self.n_local_experts,
+                            top_k=self.top_k, dtype=self.dtype,
+                            name="moe")(y)
         return x + m, aux
 
 
 class MoETransformerLM(nn.Module):
     """Decoder-only LM with an MoE MLP in every block.
 
-    Returns (logits [B, S, V] float32, aux scalar = summed load-balance
-    losses)."""
+    Returns (logits [B, S, V] float32, aux): for a capacity arch the
+    scalar sum of the layers' load-balance losses; for a dropless arch a dict
+    keyed by ``DROPLESS_STATS`` (``aux`` and ``z_loss`` averaged over layers,
+    the busiest layer's ``expert_load_max_over_mean``, ``moe_dropped``
+    summed)."""
     vocab_size: int = 256
     n_layers: int = 2
     n_heads: int = 4
@@ -243,8 +338,10 @@ class MoETransformerLM(nn.Module):
     max_seq_len: int = 2048
     ep_axis: Optional[str] = None
     n_local_experts: Optional[int] = None
-    top_k: int = 1                    # 1 = switch, 2 = GShard
+    top_k: int = 1                    # 1 = switch, 2 = GShard; dropless: 1..n_experts
     attention_impl: str = "full"      # "full" | "flash"
+    arch: str = "gpt2"                # ARCHS row (models/transformer.py)
+    ffn_dim: int = 0                  # expert width (0 = 4 * d_model)
     # Per-block remat (see models/transformer.py TransformerLM.remat); the
     # recompute replays the block's all_to_alls, which is SPMD-legal.
     remat: bool = False
@@ -257,13 +354,12 @@ class MoETransformerLM(nn.Module):
     def __call__(self, tokens, positions: Optional[jax.Array] = None):
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     name="tok_embed")(tokens)
-        x = x + nn.Embed(self.max_seq_len, self.d_model, dtype=self.dtype,
-                         name="pos_embed")(positions)[None]
+        x = embed_tokens(tokens, positions, arch=self.arch,
+                         vocab_size=self.vocab_size, d_model=self.d_model,
+                         max_seq_len=self.max_seq_len, dtype=self.dtype)
         Blk = nn.remat(MoEBlock) if (self.remat and not self.decode) \
             else MoEBlock
-        aux_total = jnp.float32(0.0)
+        per_layer = []
         for i in range(self.n_layers):
             x, aux = Blk(self.n_heads, self.d_model, self.n_experts,
                          capacity_factor=self.capacity_factor,
@@ -273,9 +369,17 @@ class MoETransformerLM(nn.Module):
                          attention_impl=self.attention_impl,
                          dtype=self.dtype, decode=self.decode,
                          decode_cache_len=self.decode_cache_len,
-                         name=f"block_{i}")(x)
-            aux_total = aux_total + aux
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
+                         arch=self.arch, ffn_dim=self.ffn_dim,
+                         name=f"block_{i}")(x, positions)
+            per_layer.append(aux)
+        if ARCHS[self.arch].dropless:
+            aux_total = {k: over(jnp.stack([a[k] for a in per_layer]))
+                         for k, over in _OVER_LAYERS.items()}
+        else:
+            aux_total = jnp.float32(0.0)
+            for aux in per_layer:
+                aux_total = aux_total + aux
+        x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
                           name="lm_head")(x)
         return logits.astype(jnp.float32), aux_total

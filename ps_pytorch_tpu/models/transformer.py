@@ -12,7 +12,7 @@ crosses shards. Learned positional embeddings are indexed by GLOBAL token
 position, passed in by the caller (the sp step knows each shard's offset).
 """
 
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -20,6 +20,122 @@ import jax.numpy as jnp
 
 from ps_pytorch_tpu.ops.flash_attention import flash_attention
 from ps_pytorch_tpu.parallel.ring import full_attention, ring_attention
+
+
+class Arch(NamedTuple):
+    """What varies between the decoder blocks this repo runs; everything
+    else (residual layout, separate bias-free q/k/v/o projections, the
+    attention kernels, the untied head) is common. One ``arch`` name picks a
+    row for both LM classes (``--lm-arch``)."""
+    rms_norm: bool = False      # RMSNorm(eps) | LayerNorm (flax eps 1e-6)
+    norm_eps: float = 1e-6
+    rope_theta: float = 0.0     # 0: learned position table | RoPE base
+    qk_norm: bool = False       # norm over all d features of q and k, before the heads split
+    dropless: bool = False      # MoE FFN: routed SwiGLU, sort + grouped matmul | capacity GELU
+    embed_std: float = 0.0      # token embedding init: normal(std) | flax's default (1/sqrt(d))
+    z_loss_coef: float = 0.0    # router z-loss in the ep step's loss
+
+
+ARCHS = {
+    "gpt2": Arch(),
+    # OLMoE-1B-7B (arXiv:2409.02060; allenai/OLMoE-1B-7B-0125-Instruct
+    # config.json): rms_norm_eps 1e-5, rope_theta 10000, q/k norm, top-k
+    # gates not renormalised, z-loss 0.001 (the paper's).
+    # embed_std 1: with flax's 1/sqrt(d) embeddings the residual stream of a
+    # freshly initialised model is the running mean of attention, nearly the
+    # same vector at every position, and the router sends 97% of tokens to the
+    # same 8 experts (max over mean 7.5 of a possible 8 on the chip, PR 25).
+    # At std 1 the stream carries the token's identity through the first
+    # RMSNorm and a seeded model routes near balance, as a trained one does.
+    "olmoe": Arch(rms_norm=True, norm_eps=1e-5, rope_theta=10000.0,
+                  qk_norm=True, dropless=True, z_loss_coef=0.001,
+                  embed_std=1.0),
+}
+
+
+def make_norm(arch: str, dtype, name: Optional[str] = None) -> nn.Module:
+    """The block's normalisation, one definition for every use (input,
+    post-attention, q/k, final). Unnamed, flax numbers it in its caller's
+    scope, which is what keeps GPT-2's ``LayerNorm_0..1``."""
+    a = ARCHS[arch]
+    if a.rms_norm:
+        return nn.RMSNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
+    return nn.LayerNorm(dtype=dtype, name=name)
+
+
+def rope(x, positions, theta: float):
+    """Rotary position embedding, rotate-half pairing (feature i with
+    i + hd/2, as HF's ``rotate_half``). x: [B, h, S, hd]; positions: [S]
+    GLOBAL token positions (a decode step passes its cache offset)."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * inv_freq[None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
+                       n_heads: int, dtype, attention_impl: str,
+                       axis_name: str = "data", decode: bool = False,
+                       decode_cache_len: int = 0):
+    """``x + Wo . attention(norm(x))``: the one q/k/v/o path of both
+    ``Block`` and ``models/moe.MoEBlock``, called from their ``@nn.compact``
+    body, so its sub-modules are numbered in the CALLER's scope (GPT-2:
+    ``LayerNorm_0``, ``Dense_0..3``, the tree
+    ``benchmark/reference/gpt2_medium.py`` reads)."""
+    a = ARCHS[arch]
+    b, s, d = x.shape
+    hd = d // n_heads
+    if positions is None:
+        positions = jnp.arange(s)
+    y = make_norm(arch, dtype)(x)
+    # Separate q/k/v projections (not one packed Dense(3d)): under
+    # tensor parallelism each kernel's OUTPUT dim is sharded over
+    # 'model', and with per-projection kernels a shard's slice is
+    # head-aligned (d = heads*hd), so attention can stay shard-local; a
+    # packed qkv kernel puts shard boundaries inside q/k/v
+    # (parallel/tp.py layout table).
+    q = nn.Dense(d, use_bias=False, dtype=dtype)(y)
+    k = nn.Dense(d, use_bias=False, dtype=dtype)(y)
+    v = nn.Dense(d, use_bias=False, dtype=dtype)(y)
+    if a.qk_norm:
+        q = make_norm(arch, dtype, name="q_norm")(q)
+        k = make_norm(arch, dtype, name="k_norm")(k)
+    to_heads = lambda t: t.reshape(b, s, n_heads, hd).transpose(0, 2, 1, 3)
+    q, k, v = to_heads(q), to_heads(k), to_heads(v)
+    if a.rope_theta:
+        q, k = rope(q, positions, a.rope_theta), rope(k, positions,
+                                                      a.rope_theta)
+    if decode:
+        o = cached_attention(mod, q, k, v, decode_cache_len)
+    elif attention_impl == "ring":
+        o = ring_attention(q, k, v, axis_name, causal=True)
+    elif attention_impl == "flash":
+        # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
+        # materialization — the single-chip long-context path.
+        o = flash_attention(q, k, v, causal=True)
+    else:
+        o = full_attention(q, k, v, causal=True)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+    return x + nn.Dense(d, use_bias=False, dtype=dtype)(o)
+
+
+def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
+                 d_model: int, max_seq_len: int, dtype):
+    """Token embedding, plus the learned position table where the arch has
+    one (RoPE archs carry position inside attention). Called from the LM
+    classes' ``@nn.compact`` body."""
+    a = ARCHS[arch]
+    init = {"embedding_init": nn.initializers.normal(a.embed_std)} \
+        if a.embed_std else {}
+    x = nn.Embed(vocab_size, d_model, dtype=dtype, name="tok_embed",
+                 **init)(tokens)
+    if not a.rope_theta:
+        x = x + nn.Embed(max_seq_len, d_model, dtype=dtype,
+                         name="pos_embed")(positions)[None]
+    return x
 
 
 def cached_attention(mod: nn.Module, q, k, v, length: int):
@@ -66,41 +182,21 @@ class Block(nn.Module):
     decode: bool = False
     decode_cache_len: int = 0
 
-    def _cached_attention(self, q, k, v):
-        return cached_attention(self, q, k, v, self.decode_cache_len)
+    arch: str = "gpt2"                # ARCHS row (norm, positions, q/k norm)
+    ffn_dim: int = 0                  # dense FFN width (0 = 4 * d_model)
 
     @nn.compact
-    def __call__(self, x):
-        # x: [B, S_local, D]
-        b, s, d = x.shape
-        h = self.n_heads
-        hd = d // h
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        # Separate q/k/v projections (not one packed Dense(3d)): under
-        # tensor parallelism each kernel's OUTPUT dim is sharded over
-        # 'model', and with per-projection kernels a shard's slice is
-        # head-aligned (d = heads*hd), so attention can stay shard-local; a
-        # packed qkv kernel puts shard boundaries inside q/k/v
-        # (parallel/tp.py layout table).
-        q = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        k = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        v = nn.Dense(d, use_bias=False, dtype=self.dtype)(y)
-        to_heads = lambda t: t.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
-        if self.decode:
-            o = self._cached_attention(q, k, v)
-        elif self.attention_impl == "ring":
-            o = ring_attention(q, k, v, self.axis_name, causal=True)
-        elif self.attention_impl == "flash":
-            # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
-            # materialization — the single-chip long-context path.
-            o = flash_attention(q, k, v, causal=True)
-        else:
-            o = full_attention(q, k, v, causal=True)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-        x = x + nn.Dense(d, use_bias=False, dtype=self.dtype)(o)
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        y = nn.Dense(4 * d, dtype=self.dtype)(y)
+    def __call__(self, x, positions=None):
+        # x: [B, S_local, D]; positions: [S_local] global token positions
+        # (read by RoPE archs only; None = 0..S-1)
+        d = x.shape[-1]
+        x = attention_sublayer(
+            self, x, positions, arch=self.arch, n_heads=self.n_heads,
+            dtype=self.dtype, attention_impl=self.attention_impl,
+            axis_name=self.axis_name, decode=self.decode,
+            decode_cache_len=self.decode_cache_len)
+        y = make_norm(self.arch, self.dtype)(x)
+        y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
         y = nn.gelu(y)
         x = x + nn.Dense(d, dtype=self.dtype)(y)
         return x
@@ -125,6 +221,8 @@ class TransformerLM(nn.Module):
     # fixed-length k/v caches. Same param tree as training.
     decode: bool = False
     decode_cache_len: int = 0
+    arch: str = "gpt2"                # ARCHS row; see Block
+    ffn_dim: int = 0
 
     @nn.compact
     def __call__(self, tokens, positions: Optional[jax.Array] = None,
@@ -133,18 +231,18 @@ class TransformerLM(nn.Module):
         # (defaults to 0..S-1 — correct only when unsharded).
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     name="tok_embed")(tokens)
-        x = x + nn.Embed(self.max_seq_len, self.d_model, dtype=self.dtype,
-                         name="pos_embed")(positions)[None]
+        x = embed_tokens(tokens, positions, arch=self.arch,
+                         vocab_size=self.vocab_size, d_model=self.d_model,
+                         max_seq_len=self.max_seq_len, dtype=self.dtype)
         Blk = nn.remat(Block) if (self.remat and not self.decode) else Block
         for i in range(self.n_layers):
             x = Blk(self.n_heads, self.d_model, self.dtype,
                     self.attention_impl, self.axis_name,
                     decode=self.decode,
                     decode_cache_len=self.decode_cache_len,
-                    name=f"block_{i}")(x)
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_f")(x)
+                    arch=self.arch, ffn_dim=self.ffn_dim,
+                    name=f"block_{i}")(x, positions)
+        x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
                           name="lm_head")(x)
         return logits.astype(jnp.float32)

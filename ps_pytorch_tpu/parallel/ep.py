@@ -31,10 +31,16 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import keystr, tree_flatten_with_path
 
+from ps_pytorch_tpu.models.moe import DROPLESS_STATS
+from ps_pytorch_tpu.models.transformer import ARCHS
 from ps_pytorch_tpu.parallel.dp import TrainState
 from ps_pytorch_tpu.parallel.tp import _opt_state_specs
 
 _EXPERT_KEY = "experts_"   # models/moe.py stacked expert param names
+# How each of the model's routing statistics crosses the data axis.
+_STAT_REDUCE = {"aux": jax.lax.pmean, "z_loss": jax.lax.pmean,
+                "expert_load_max_over_mean": jax.lax.pmax,
+                "moe_dropped": jax.lax.psum}
 
 
 def ep_param_specs(params, axis: str = "data"):
@@ -89,7 +95,10 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                        state: TrainState, *, axis: str = "data",
                        aux_coef: float = 0.01, remat: bool = False,
                        donate: bool = True) -> Callable:
-    """-> step_fn(state, tokens) -> (state, {'loss', 'aux'}).
+    """-> step_fn(state, tokens) -> (state, {'loss', 'aux'}); a dropless
+    arch (``olmoe``) adds the rest of ``DROPLESS_STATS``: 'z_loss',
+    'expert_load_max_over_mean', 'moe_dropped', and its loss the arch's
+    z-loss term, scaled by the token count as ``aux`` is.
 
     tokens [B, S] int32, batch sharded over ``axis``. ``model`` must be
     built with ``ep_axis=axis`` and ``n_groups=1`` (each device dispatches
@@ -99,6 +108,13 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         raise ValueError(f"model.ep_axis={model.ep_axis!r} != step axis "
                          f"{axis!r} — build the model with ep_axis={axis!r}")
     n = mesh.shape[axis]
+    arch = ARCHS[model.arch]
+    if arch.dropless and n > 1:
+        # The capacity path's all_to_all moves fixed [E, C, D] slots; ragged
+        # groups need a ragged all-to-all, which is the four-chip cell's PR.
+        raise NotImplementedError(
+            f"dropless routing across chips: not built (lm_arch="
+            f"{model.arch!r} over {n} devices); see PERF.md §7")
     if model.n_experts % n:
         raise ValueError(f"{model.n_experts} experts not divisible over "
                          f"{n} devices")
@@ -113,13 +129,16 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     def local_step(state, tokens):
         def loss_fn(params):
             logits, aux = model.apply({"params": params}, tokens)
+            stats = aux if arch.dropless else {"aux": aux}
             per = optax.softmax_cross_entropy_with_integer_labels(
                 logits[:, :-1], tokens[:, 1:])
+            reg = aux_coef * stats["aux"] \
+                + arch.z_loss_coef * stats.get("z_loss", 0.0)
             # LOCAL sums; collectives on the grads, not in the loss.
-            return per.sum() + aux_coef * aux * per.size, \
-                (jnp.float32(per.size), per.sum(), aux)
+            return per.sum() + reg * per.size, \
+                (jnp.float32(per.size), per.sum(), stats)
 
-        (_, (count, ce_sum, aux)), grads = jax.value_and_grad(
+        (_, (count, ce_sum, stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         total = jax.lax.psum(count, axis)
 
@@ -134,16 +153,19 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         grads = jax.tree_util.tree_unflatten(
             treedef, [reduce_grad(p, g) for p, g in paths])
         loss = jax.lax.psum(ce_sum, axis) / total
-        aux = jax.lax.pmean(aux, axis)
+        metrics = {"loss": loss,
+                   **{k: _STAT_REDUCE[k](v, axis)
+                      for k, v in stats.items()}}
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
         return state.replace(step=state.step + 1, params=new_params,
-                             opt_state=new_opt), {"loss": loss, "aux": aux}
+                             opt_state=new_opt), metrics
 
     specs = ep_state_specs(jax.eval_shape(lambda s: s, state), axis)
     sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, P(axis, None)),
-        out_specs=(specs, {"loss": P(), "aux": P()}),
+        out_specs=(specs, {k: P() for k in ("loss",) + (
+            DROPLESS_STATS if arch.dropless else ("aux",))}),
         check_vma=False)
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

@@ -30,6 +30,22 @@ def lm_geometry(cfg) -> dict:
                 max_seq_len=cfg.lm_seq_len)
 
 
+def build_lm_model(cfg, **kw):
+    """The model a config describes — ONE construction for the trainer, the
+    oracle and the checkpoint template, so a field that changes the forward
+    with identical param shapes (top_k, arch) can never be left out of one of
+    them. ``cfg.network`` decides the family where a checkpoint recorded it;
+    a live config says it through ``lm_parallelism=ep``. ``kw`` adds what
+    only the caller knows (attention_impl, ep_axis, axis_name)."""
+    geo = dict(lm_geometry(cfg), arch=cfg.lm_arch, ffn_dim=cfg.lm_ffn_dim)
+    if cfg.network == "MoETransformerLM" or cfg.lm_parallelism == "ep":
+        from ps_pytorch_tpu.models.moe import MoETransformerLM
+        return MoETransformerLM(n_experts=cfg.lm_experts,
+                                top_k=cfg.lm_moe_top_k, **geo, **kw)
+    from ps_pytorch_tpu.models.transformer import TransformerLM
+    return TransformerLM(**geo, **kw)
+
+
 def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
     """-> (loss_fn(params, tokens) jitted, to_tree(saved_params)).
 
@@ -37,20 +53,11 @@ def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
     (pp checkpoints store stage-stacked blocks). EP note: the oracle
     dispatches in ONE capacity group, while EP training grouped per device
     — only WHICH overflow tokens drop can differ (models/moe.py)."""
-    from ps_pytorch_tpu.models.transformer import TransformerLM
-
-    geo = lm_geometry(cfg)
     to_tree = lambda p: p
+    model = build_lm_model(cfg)
     if cfg.network == "MoETransformerLM":
-        from ps_pytorch_tpu.models.moe import MoETransformerLM
-        # top_k changes the forward (gates, second-expert contributions)
-        # with IDENTICAL param shapes — omitting it here would silently
-        # evaluate a top-2-trained checkpoint with top-1 routing.
-        model = MoETransformerLM(n_experts=cfg.lm_experts,
-                                 top_k=cfg.lm_moe_top_k, **geo)
         apply = lambda p, t: model.apply({"params": p}, t)[0]
     else:
-        model = TransformerLM(**geo)
         apply = lambda p, t: model.apply({"params": p}, t)
     if cfg.lm_parallelism == "pp":
         if cfg.lm_model_axis <= 0:
@@ -78,18 +85,11 @@ def build_lm_template(cfg):
     stays with ``build_lm_oracle``'s to_tree — one source of truth."""
     import jax.numpy as jnp
 
-    from ps_pytorch_tpu.models.transformer import TransformerLM
     from ps_pytorch_tpu.optim import build_schedule
     from ps_pytorch_tpu.optim.sgd import sgd
     from ps_pytorch_tpu.parallel.dp import TrainState
 
-    geo = lm_geometry(cfg)
-    if cfg.network == "MoETransformerLM":
-        from ps_pytorch_tpu.models.moe import MoETransformerLM
-        model = MoETransformerLM(n_experts=cfg.lm_experts,
-                                 top_k=cfg.lm_moe_top_k, **geo)
-    else:
-        model = TransformerLM(**geo)
+    model = build_lm_model(cfg)
     init_len = min(cfg.lm_seq_len, 128)
     params = model.init(jax.random.key(0),
                         jnp.zeros((1, init_len), jnp.int32),

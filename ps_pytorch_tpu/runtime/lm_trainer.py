@@ -11,8 +11,9 @@ held-out next-token-loss oracle):
   composed with DP over 'data' (``parallel/tp.py``).
 - ``pp``: GPipe pipeline over the 'model' axis with ``--lm-microbatches``
   (``parallel/pp.py``).
-- ``ep``: switch-MoE model with experts sharded over 'data'
-  (``models/moe.py`` + ``parallel/ep.py``).
+- ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
+  + ``parallel/ep.py``); also how an MoE model is run on one chip.
+  ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe).
 
 The reference has no LM surface at all — this is the §5.7 long-context
 capability expressed as a first-class entry point (``train_lm.py``), not
@@ -32,9 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ps_pytorch_tpu import resilience
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.text import TokenLoader
-from ps_pytorch_tpu.models.transformer import (
-    TransformerLM, migrate_packed_qkv,
-)
+from ps_pytorch_tpu.models.transformer import ARCHS, migrate_packed_qkv
 from ps_pytorch_tpu.ops._backend import announce_kernels
 from ps_pytorch_tpu.optim import build_schedule
 from ps_pytorch_tpu.optim.sgd import sgd
@@ -43,6 +42,7 @@ from ps_pytorch_tpu.parallel.sp import (
     create_lm_train_state, make_sp_eval_fn, make_sp_train_step,
 )
 from ps_pytorch_tpu.runtime import checkpoint as ckpt
+from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
 from ps_pytorch_tpu.telemetry import (
     FlightRecorder, HealthMonitor, MetricsExporter, ProfileWindow, Registry,
@@ -66,9 +66,6 @@ class LMTrainer:
                       weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
         self.mode = cfg.lm_parallelism
         key = jax.random.key(cfg.seed)
-        lm_kw = dict(vocab_size=cfg.lm_vocab, d_model=cfg.lm_d_model,
-                     n_layers=cfg.lm_layers, n_heads=cfg.lm_heads,
-                     max_seq_len=cfg.lm_seq_len)
 
         # Resolve the attention kernel (--lm-attention). "flash" (the fused
         # Pallas kernel, ops/flash_attention.py) is sequence-LOCAL: legal
@@ -94,8 +91,8 @@ class LMTrainer:
                 raise ValueError(f"lm_seq_len {cfg.lm_seq_len} not "
                                  f"divisible by {n} devices (sequence "
                                  f"sharding)")
-            self.model = TransformerLM(attention_impl=impl,
-                                       axis_name="data", **lm_kw)
+            self.model = build_lm_model(cfg, attention_impl=impl,
+                                        axis_name="data")
             self.state = create_lm_train_state(
                 self.model, self.tx, self.mesh,
                 (cfg.batch_size, cfg.lm_seq_len), key)
@@ -120,7 +117,7 @@ class LMTrainer:
                 raise ValueError("lm_attention='flash' is not supported "
                                  "under tp (GSPMD cannot partition the "
                                  "fused kernel over heads); use full")
-            self.model = TransformerLM(attention_impl=local_impl, **lm_kw)
+            self.model = build_lm_model(cfg, attention_impl=local_impl)
             if self.mode == "tp":
                 from ps_pytorch_tpu.parallel.tp import (
                     create_tp_train_state, make_tp_train_step,
@@ -147,16 +144,13 @@ class LMTrainer:
                     remat=cfg.remat, donate=cfg.donate)
             self.eval_fn = None   # oracle eval (see evaluate())
         elif self.mode == "ep":
-            from ps_pytorch_tpu.models.moe import MoETransformerLM
             from ps_pytorch_tpu.parallel.ep import (
                 create_ep_train_state, make_ep_train_step,
             )
             from ps_pytorch_tpu.parallel.mesh import make_mesh
             self.mesh = make_mesh(data=n, model=1, devices=devices)
-            self.model = MoETransformerLM(n_experts=cfg.lm_experts,
-                                          top_k=cfg.lm_moe_top_k,
-                                          attention_impl=local_impl,
-                                          ep_axis="data", **lm_kw)
+            self.model = build_lm_model(cfg, attention_impl=local_impl,
+                                        ep_axis="data")
             self.state = create_ep_train_state(
                 self.model, self.tx, self.mesh,
                 (cfg.batch_size, cfg.lm_seq_len), key)
@@ -166,8 +160,12 @@ class LMTrainer:
             self.eval_fn = None
         else:  # unreachable: TrainConfig.__post_init__ validates
             raise ValueError(self.mode)
+        kernels = []
         if self.model.attention_impl == "flash":
-            announce_kernels(["flash_attention"])
+            kernels.append("flash_attention")
+        if ARCHS[cfg.lm_arch].dropless:
+            kernels.append("grouped_matmul")
+        announce_kernels(kernels)
 
         # Checkpoints are self-describing: record the model family and the
         # RESOLVED mesh degree (lm_model_axis=0 means "all devices", which
@@ -298,9 +296,44 @@ class LMTrainer:
         if self.cfg.ckpt_keep > 0:
             ckpt.prune_checkpoints(self.cfg.train_dir, self.cfg.ckpt_keep)
 
+    def _check_saved_config(self, config_json) -> None:
+        """Refuse a checkpoint whose recorded model differs from this run's."""
+        try:
+            saved = json.loads(config_json)
+        except (TypeError, ValueError):
+            saved = {}
+        # lm_model_axis matters for pp: blocks are stacked per stage, and a
+        # different stage count would restore without shape validation and
+        # silently drop layers inside the step's per-stage slicing. A saved
+        # value of 0 predates resolved recording ("all devices at save
+        # time") and cannot be compared — skip rather than spuriously
+        # reject.
+        for k in ("lm_arch", "lm_vocab", "lm_d_model", "lm_layers",
+                  "lm_heads", "lm_ffn_dim", "lm_parallelism", "lm_experts",
+                  "lm_model_axis", "lm_moe_top_k"):
+            if k == "lm_model_axis" and saved.get(k) == 0:
+                continue
+            if k in saved and saved[k] != getattr(self.cfg, k):
+                raise ValueError(
+                    f"checkpoint in {self.cfg.train_dir} was written with "
+                    f"{k}={saved[k]} but this run uses "
+                    f"{getattr(self.cfg, k)} — wrong train_dir, or pass "
+                    f"--no-resume / a fresh --train-dir")
+
     def maybe_resume(self) -> bool:
         if ckpt.latest_step(self.cfg.train_dir) is None:
             return False
+        # A checkpoint of another model (a CNN's, another arch's) would fail
+        # deep inside deserialization with a msgpack key error; check the
+        # newest checkpoint's recorded config first and fail with an
+        # actionable message instead.
+        try:
+            with open(os.path.join(ckpt.checkpoint_path(
+                    self.cfg.train_dir, ckpt.latest_step(self.cfg.train_dir)),
+                    "config.json")) as f:
+                self._check_saved_config(f.read())
+        except OSError:
+            pass
         # Collective gather for the restore template, mirroring
         # _checkpoint: tp/pp/ep shard state across hosts, where a plain
         # device_get raises on non-addressable shards.
@@ -325,30 +358,9 @@ class LMTrainer:
         if got is None:
             return False
         state, meta, config_json, _ = got
-        # A CNN checkpoint in the same train_dir would fail deep inside
-        # deserialization; check the saved config's model geometry first
-        # and fail with an actionable message instead.
-        try:
-            saved = json.loads(config_json)
-        except (TypeError, ValueError):
-            saved = {}
-        # lm_model_axis matters for pp: blocks are stacked per stage, and a
-        # different stage count would restore without shape validation and
-        # silently drop layers inside the step's per-stage slicing. A saved
-        # value of 0 predates resolved recording ("all devices at save
-        # time") and cannot be compared — skip rather than spuriously
-        # reject.
-        for k in ("lm_vocab", "lm_d_model", "lm_layers", "lm_heads",
-                  "lm_parallelism", "lm_experts", "lm_model_axis",
-                  "lm_moe_top_k"):
-            if k == "lm_model_axis" and saved.get(k) == 0:
-                continue
-            if k in saved and saved[k] != getattr(self.cfg, k):
-                raise ValueError(
-                    f"checkpoint in {self.cfg.train_dir} was written with "
-                    f"{k}={saved[k]} but this run uses "
-                    f"{getattr(self.cfg, k)} — wrong train_dir, or pass "
-                    f"--no-resume / a fresh --train-dir")
+        # The checkpoint actually restored may be an older one than the
+        # newest (a corrupt latest is skipped): hold its config to the same.
+        self._check_saved_config(config_json)
         # Re-place every leaf with the sharding the live state was built
         # with (stage/expert-sharded for pp/ep, TP-sharded kernels, or
         # plain replication) — a bare device_put would leave host-local
@@ -404,6 +416,11 @@ class LMTrainer:
                 if step % cfg.log_every == 0 or step == cfg.max_steps:
                     with tracer.span("metrics_sync"):
                         loss = float(m["loss"])
+                        # The ep step's routing statistics (aux; a dropless
+                        # arch's z_loss, expert_load_max_over_mean,
+                        # moe_dropped) come with the loss, in the one wait.
+                        routing = {k: float(v) for k, v in m.items()
+                                   if k != "loss"}
                     # The loss read drained every step dispatched since the
                     # last sync, so the wall time over them is a true
                     # per-step duration (dispatch time alone reads as an
@@ -425,7 +442,10 @@ class LMTrainer:
                             step, self.train_loader._epoch,
                             loss=loss, acc=0.0, participating=1.0,
                             step_time=t_step, data_time=t_data,
-                            phases=tracer.step_summary(step), **derived)
+                            phases=tracer.step_summary(step), **routing,
+                            **derived)
+                    for k, v in routing.items():
+                        self.registry.set(k, v)
                 with tracer.span("ops_step"):
                     self._ops_step(step, loss=loss, step_time=t_step,
                                    data_time=t_data)

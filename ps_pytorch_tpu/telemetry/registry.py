@@ -466,6 +466,19 @@ TRAINING_COUNTERS = (
      "steps queued while the device still ran the step before (Trainer: "
      "the host loop keeps ahead of the chip)"),
 )
+# An MoE step's routing statistics (parallel/ep.py: aux; a dropless arch adds
+# the other three), set by LMTrainer on every logged step under the names the
+# JSONL record gives them.
+ROUTING_GAUGES = (
+    ("aux", "", "MoE load-balance loss, experts * sum_e(share of assignments "
+     "* mean router probability)"),
+    ("z_loss", "", "router z-loss, mean of logsumexp(router logits)^2 over "
+     "tokens and layers"),
+    ("expert_load_max_over_mean", "", "busiest expert's assignments over "
+     "tokens*top_k/experts, worst layer"),
+    ("moe_dropped", "assignments", "token-to-expert assignments whose output "
+     "was not added (a dropless router must read 0)"),
+)
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),
     ("train_loss", "", "last step's training loss"),
@@ -481,7 +494,7 @@ TRAINING_GAUGES = (
      "device HBM peak bytes reserved for running programs' temporaries "
      "(0 when the backend has no stats)"),
     ("host_rss_bytes", "bytes", "host process peak RSS watermark"),
-)
+) + ROUTING_GAUGES
 TRAINING_HISTOGRAMS = (
     ("train_step_latency_s", "s", "per-step wall-time distribution"),
 )

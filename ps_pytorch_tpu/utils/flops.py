@@ -6,7 +6,8 @@ module is the framework's own bar: count the matmul/conv FLOPs of any jitted
 function (forward, or the full value_and_grad training step) and divide by
 the chip's peak to get MFU.
 
-Counting is exact for ``dot_general`` and exact-up-to-boundary-effects for
+Counting is exact for ``dot_general`` and the grouped matmuls (the routed rows
+only) and exact-up-to-boundary-effects for
 ``conv_general_dilated`` (useful MACs only — taps on lhs_dilation-inserted
 zeros are excluded, which matters for the grad-input convs of strided
 layers); elementwise/reduction traffic is deliberately ignored (it is
@@ -38,6 +39,19 @@ def _dot_general_flops(eqn) -> int:
     n = _prod(rhs.shape[i] for i in range(len(rhs.shape))
               if i not in set(rc) | set(rb))
     return 2 * batch * m * k * n
+
+
+def _routed_matmul_flops(eqn) -> int:
+    """The grouped-matmul kernels of ``ops/grouped_matmul.py``. Their grid is
+    the static worst case of row-tile visits and the kernel skips the ones
+    past the real count, so grid x body would charge up to twice the work;
+    what they do is the routed work, 2 * rows * K * N, read from the two
+    array operands: ([M, K], [E, K, N]), ([M, N], [E, K, N] read transposed)
+    or ([M, K], [M, N])."""
+    a, b = (v.aval.shape for v in eqn.invars[-2:])
+    if len(b) == 3:
+        return 2 * a[0] * b[1] * b[2]
+    return 2 * a[0] * a[1] * b[1]
 
 
 def _conv_flops(eqn) -> int:
@@ -103,6 +117,9 @@ def count_jaxpr_flops(jaxpr) -> int:
             total += _dot_general_flops(eqn)
         elif name == "conv_general_dilated":
             total += _conv_flops(eqn)
+        elif name == "pallas_call" and \
+                eqn.params.get("name", "").startswith("moe_gmm_"):
+            total += _routed_matmul_flops(eqn)
         else:
             trips = _trips(eqn)
             for sub in _sub_jaxprs(eqn):

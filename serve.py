@@ -76,7 +76,9 @@ def main(argv=None) -> int:
         # The engine's slot decode reuses Block.decode's fixed-length KV
         # cache, which the MoE blocks don't implement.
         p.error(f"serve.py decodes TransformerLM checkpoints; this one is "
-                f"{cfg.network} (use generate.py for one-shot MoE decode)")
+                f"{cfg.network}, lm_arch={cfg.lm_arch} (generate.py decodes "
+                f"a gpt2-arch MoE checkpoint one-shot; decoding the olmoe "
+                f"arch is not built)")
     template = build_lm_template(cfg)
     _, to_tree = build_lm_oracle(cfg)
     got = ckpt.load_latest_valid(args.train_dir, template,

@@ -322,3 +322,83 @@ def test_legacy_packed_qkv_checkpoint_migrates(tmp_path):
     # rewrite a current checkpoint by accident.
     _, n_modern = migrate_packed_qkv(raw)
     assert n_modern == 0
+
+
+# ---- the olmoe arch through the same contract (dropless MoE: one device) ----
+
+_OLMOE = dict(lm_arch="olmoe", lm_parallelism="ep", lm_experts=8,
+              lm_moe_top_k=4, lm_ffn_dim=32, lm_vocab=97, lm_seq_len=32,
+              batch_size=4, lr=0.05, log_every=1, lm_corpus_tokens=20_000)
+
+
+def _one_device(monkeypatch):
+    """Dropless routing across chips is not built: give the trainer one of
+    the test mesh's eight devices, as a one-chip machine would."""
+    import jax
+    one = jax.devices()[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+
+
+def test_olmoe_trains_saves_resumes_and_evaluates(tmp_path, monkeypatch):
+    """--lm-arch olmoe through LMTrainer: 3 steps, a checkpoint that
+    describes itself, a resume, the in-trainer eval and the standalone
+    lm_eval oracle agreeing on the restored weights, and the routing counters
+    in the JSONL record and the registry."""
+    import json
+
+    import jax.numpy as jnp
+
+    import generate
+    from ps_pytorch_tpu.runtime import checkpoint as ckpt
+    from ps_pytorch_tpu.runtime.lm_eval import (
+        build_lm_oracle, build_lm_template,
+    )
+    from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
+
+    _one_device(monkeypatch)
+    metrics = tmp_path / "metrics.jsonl"
+    cfg = _cfg(tmp_path, max_steps=3, eval_freq=3, metrics_file=str(metrics),
+               **_OLMOE)
+    LMTrainer(cfg).train()
+    t2 = LMTrainer(cfg.replace(max_steps=4))
+    t2.train()
+    assert t2.start_step == 3 and int(t2.state.step) == 4
+    r = t2.evaluate(max_batches=2)
+    assert np.isfinite(r["loss"]) and r["batches"] == 2
+
+    records = [json.loads(line) for line in metrics.read_text().splitlines()]
+    assert [rec["step"] for rec in records] == [1, 2, 3, 4]
+    for rec in records:
+        assert rec["moe_dropped"] == 0.0
+        assert 1.0 <= rec["expert_load_max_over_mean"] <= 2.0   # 8 experts, top-4
+        assert np.isfinite(rec["z_loss"]) and np.isfinite(rec["aux"])
+    assert t2.registry.get("moe_dropped") == 0.0
+    assert t2.registry.get("expert_load_max_over_mean") == \
+        records[-1]["expert_load_max_over_mean"]
+
+    with open(f"{ckpt.checkpoint_path(str(tmp_path), 4)}/config.json") as f:
+        saved = TrainConfig.from_json(f.read())
+    assert (saved.lm_arch, saved.lm_ffn_dim, saved.network) == \
+        ("olmoe", 32, "MoETransformerLM")
+    state, _, _ = ckpt.load_checkpoint(str(tmp_path), 4,
+                                       build_lm_template(saved))
+    loss_fn, to_tree = build_lm_oracle(saved)
+    tokens = TokenLoader(t2.val_tokens, 4, 32, seed=0,
+                         shuffle=False).next_batch()
+    np.testing.assert_allclose(
+        float(loss_fn(to_tree(state.params), jnp.asarray(tokens))),
+        t2._oracle_eval_fn()(jnp.asarray(tokens)), rtol=1e-6)
+
+    with pytest.raises(SystemExit):     # decoding this arch: one clear refusal
+        generate.main(["--train-dir", str(tmp_path), "--prompt", "a"])
+
+
+def test_olmoe_checkpoint_refuses_to_resume_under_gpt2(tmp_path, monkeypatch):
+    from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
+
+    _one_device(monkeypatch)
+    cfg = _cfg(tmp_path, max_steps=1, eval_freq=1, **_OLMOE)
+    LMTrainer(cfg).train()
+    other = cfg.replace(lm_arch="gpt2", lm_moe_top_k=2, max_steps=2)
+    with pytest.raises(ValueError, match="lm_arch=olmoe"):
+        LMTrainer(other).train()
