@@ -16,27 +16,50 @@ Reference counterpart: the reference has no attention at all (CNN zoo,
 §5.7) the TPU build treats as first-class.
 
 Design notes
-- grid (B*H, S/bq, S/bkv), kv innermost with ``arbitrary`` semantics; the
-  output/accumulator block index is independent of the kv step (the
-  standard revisited-output accumulation pattern).
-- Causal blocks strictly above the diagonal are compute-skipped with
-  ``pl.when`` (the score tile is never formed); masking uses a finite
-  -1e30 so fully-masked rows stay NaN-free.
-- Softmax statistics are carried as [bq, 1] f32 VMEM scratch; the saved
-  residual is one LSE row-vector per query ([B*H, S, 1] f32), not the
-  score matrix — backward recomputes p per tile from q, k and LSE.
-- Backward = two kernels over the same tiling: dq accumulates over kv
-  blocks; dk/dv accumulate over q blocks (multi-output pallas_call).
-  ``delta = rowsum(dO * O)`` is a cheap XLA elementwise pass outside.
-- Matmuls run with ``preferred_element_type=f32`` (bf16 inputs hit the
-  MXU natively, accumulate in f32); the probability tile is cast to the
-  value dtype for the PV product.
+- What a grid step holds is a schedule computed from the shape alone:
+  ``flash_schedule(bh, s, d, itemsize, causal)``. On a v5e a 256x256x64
+  tile alone in a grid step costs 1.4 us however the grid is cut (the MXU's
+  and the reductions' latencies with nothing to overlap them), so a step
+  holds many tiles and a tile is large: ``g`` heads a step (a leading
+  dimension of every block, ``block_h`` of them batched through each
+  product so that independent chains interleave), all of a head's K/V rows
+  (``block_kv_major``; halved only where one head does not fit the stated
+  VMEM budget) and, in the backward pass, all of its q/dO rows
+  (``block_q_major``). The compute tile is the whole row of scores for
+  S <= 1024 (a plain softmax: no running statistics at all), else 512x512.
+- Forward: grid (bh/g, S/block_q, S/block_kv_major), kv innermost with
+  ``arbitrary`` semantics. Inside a step a ``fori_loop`` walks the compute
+  tiles of the K/V block up to the causal limit of the q block: tiles
+  wholly under the diagonal first, without a mask, then the ones the
+  diagonal crosses, masked with a finite -1e30 (fully masked rows stay
+  NaN-free). A dead tile costs nothing; where the grid still has a kv axis
+  the K/V index map clamps a dead block to the last live one, so it is not
+  fetched either, and m/l/acc cross its steps in VMEM scratch (otherwise
+  they are loop carries).
+- The saved residual is one LSE per query, not the score matrix, stored
+  lane-dense as [bh, S/block_q, 1, block_q]: a [.., S, 1] array pads every
+  float to a 128-lane row in VMEM and HBM.
+- Backward = ONE kernel, grid (bh/g, S/block_kv_major, S/block_q_major),
+  q innermost. Per (kv tile, q tile) pair the score tile, p = exp(s - lse)
+  and dO.V^T are formed once and feed dV, dK and dQ: five products. The
+  tile is held transposed ([block_kv, block_q]) so that LSE and delta
+  broadcast along sublanes and only the dQ product contracts over its
+  leading dimension. dK/dV sum over the q tiles in loop carries (and over
+  q blocks, where the grid has several, in f32 scratch); dQ sums over the
+  kv tiles of a step in its f32 output block (in scratch for narrower
+  outputs) and leaves once per (kv block, q block): with one kv block
+  (every shape whose head fits the budget) that is dQ itself, otherwise
+  f32 partials that XLA sums. ``delta = rowsum(dO * O)`` is a cheap XLA
+  elementwise pass outside.
+- Matmuls run with ``preferred_element_type=f32``; q, k, v, dO are cast to
+  f32 before the products and the probability tile to the value dtype for
+  the PV product (under Mosaic an f32 operand takes one bf16 MXU pass).
 - Compiled on TPU, Pallas interpreter elsewhere — the CPU test mesh runs
   identical semantics (same pattern as ``ops/quantize.py``).
 """
 
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +69,22 @@ from jax.experimental.pallas import tpu as pltpu
 from ps_pytorch_tpu.ops._backend import interpret_default as _interpret_default
 
 NEG_INF = -1e30
+
+# What the blocks of one grid step may take of VMEM (v5e: 128 MiB physical,
+# 16 MiB scoped by default), and the limit handed to Mosaic: the budget plus
+# room for the compiler's own temporaries.
+VMEM_BUDGET_BYTES = 40 * 2 ** 20
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+# Live score elements a step should hold before more heads stop paying, and
+# the steps a call keeps so that the pipeline's first fetch and last
+# write-back (which nothing hides) stay a small share.
+_STEP_WORK = 4 * 2 ** 20
+_MIN_STEPS = 8
+# The compute tile: a whole row of scores up to _ROW_TILE keys, else _TILE x
+# _TILE; heads are batched through a tile while their f32 scores stay under
+# _TILE_BYTES (v5e A/B of PR 26, PERF.md section 6).
+_ROW_TILE, _TILE = 1024, 512
+_TILE_BYTES = 2 ** 19
 
 
 def _pick_block(s: int, requested: int) -> int:
@@ -61,93 +100,310 @@ def _pick_block(s: int, requested: int) -> int:
     return 0
 
 
-def _score_tile(q_ref, k_ref, i, j, bq, bkv, scale, causal):
-    """Masked f32 score tile for block (i, j) — shared by all three kernels
-    so forward and backward can never disagree on scaling or masking.
-    Returns (scaled q, scores)."""
-    q = q_ref[0].astype(jnp.float32) * scale
-    s = _dot(q, k_ref[0].astype(jnp.float32), trans_b=True)     # [bq, bkv]
-    if causal:
-        q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-        k_pos = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    return q, s
+def _next_smaller(s: int, rows: int, tile: int) -> int:
+    """The next block under ``rows`` that divides ``s`` in whole tiles."""
+    n = s // rows + 1
+    while s % n or (s // n) % tile:
+        n += 1
+    return s // n
 
 
-def _dot(a, b, *, trans_a=False, trans_b=False):
-    """2-D matmul with f32 accumulation, optional transposes folded into
-    dimension numbers (no materialized transpose ops in the kernel)."""
-    ca = 0 if trans_a else 1
-    cb = 1 if trans_b else 0
+class FlashSchedule(NamedTuple):
+    """What one grid step of the kernels holds; static per shape."""
+    g: int                  # heads a step (divides bh), both passes
+    block_h: int            # heads a compute tile (divides g)
+    block_q: int            # compute tile, q rows (forward: the q block)
+    block_kv: int           # compute tile, k/v rows
+    block_kv_major: int     # k/v rows a step keeps in VMEM
+    block_q_major: int      # backward: q/dO rows a step keeps in VMEM
+    grid: Tuple[int, int, int]        # forward
+    steps: int                        # forward grid steps a call
+    live: int                         # ... of which not causally dead
+    bwd_grid: Tuple[int, int, int]
+    bwd_steps: int
+    bwd_live: int
+    vmem_bytes: int                   # the larger pass's blocks
+
+    @property
+    def dead(self) -> int:
+        return self.steps - self.live
+
+    def describe(self) -> str:
+        return (f"g={self.g}/{self.block_h} bq={self.block_q} "
+                f"kv={self.block_kv}/{self.block_kv_major} "
+                f"steps={self.steps} live={self.live} "
+                f"bwd_q={self.block_q}/{self.block_q_major} "
+                f"bwd_steps={self.bwd_steps} bwd_live={self.bwd_live}")
+
+
+def _vmem_bytes(g, bq, bkv, bkv_major, bq_major, s, d, itemsize):
+    """VMEM of one step's blocks, the larger of the two passes: inputs and
+    outputs double-buffered, the f32 accumulators, the score tiles. The
+    last dimension of a block pads to 128 lanes, an LSE row to 8 sublanes,
+    a [rows, 1] statistic to 128 lanes."""
+    dp = -(-d // 128) * 128
+    tiles = 4 * max(bq * bkv * 4, _TILE_BYTES)
+    kv_axis, q_axis = s > bkv_major, s > bq_major
+    fwd = (2 * g * (2 * bq + 2 * bkv_major) * dp * itemsize    # q o k v
+           + 2 * g * 8 * bq * 4                                # lse
+           + kv_axis * g * bq * (dp + 2 * 128) * 4             # acc, m, l
+           + tiles)
+    dq_size = 4 if kv_axis else itemsize
+    bwd = (2 * g * (2 * bq_major + 2 * bkv_major) * dp * itemsize  # q dO k v
+           + 2 * g * (bq_major * dq_size + 2 * bkv_major * itemsize) * dp
+           + 2 * 2 * g * 8 * bq_major * 4                      # lse, delta
+           + (dq_size < 4 and bkv_major > bkv) * g * bq_major * dp * 4
+           + q_axis * 2 * g * bkv_major * dp * 4               # dk, dv sums
+           + tiles)
+    return max(fwd, bwd)
+
+
+def _live_blocks(s, q_rows, kv_rows, causal):
+    """Of the (q block, kv block) pairs of an S x S grid of blocks: how many
+    there are, and how many are not causally dead (the kv block's first key
+    <= the q block's last query)."""
+    n_q, n_kv = s // q_rows, s // kv_rows
+    live = sum((not causal) or kj * kv_rows <= qi * q_rows + q_rows - 1
+               for qi in range(n_q) for kj in range(n_kv))
+    return n_q * n_kv, live
+
+
+def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
+                   block_q: Optional[int] = None,
+                   block_kv: Optional[int] = None,
+                   block_kv_major: Optional[int] = None) -> FlashSchedule:
+    """The schedule of both kernels for [bh, s, d] inputs of ``itemsize``
+    bytes: pure, from the shape alone. ``block_q`` / ``block_kv`` /
+    ``block_kv_major`` are upper bounds (the tests' override). Raises
+    ValueError when S has no power-of-two block divisor >= 8."""
+    # The compute tile: a whole row of scores where that is at most
+    # _ROW_TILE wide (a plain softmax, no running statistics), else
+    # _TILE x _TILE (measured on a v5e: PERF.md, PR 26).
+    tile = _ROW_TILE if s <= _ROW_TILE else _TILE
+    bq = _pick_block(s, block_q or tile)
+    bkv = _pick_block(s, min(block_kv or tile, block_kv_major or s))
+    if not bq or not bkv:
+        raise ValueError(
+            f"flash_attention needs a power-of-two block >= 8 dividing the "
+            f"sequence length; S={s} has none (use attention 'full')")
+
+    def fits(g, kvm, qm):
+        return _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize) \
+            <= VMEM_BUDGET_BYTES
+
+    # K/V rows (and the backward's q/dO rows) a step keeps: all of S, halved
+    # until one head fits the budget; never under a compute tile.
+    kvm = _pick_block(s, block_kv_major) if block_kv_major else s
+    qm = s
+    while not fits(1, kvm, qm) and (kvm > bkv or qm > bq):
+        if qm >= kvm and qm > bq:
+            qm = _next_smaller(s, qm, bq)
+        else:
+            kvm = _next_smaller(s, kvm, bkv)
+    # Heads a step: the largest divisor of bh that fits, leaves the call
+    # _MIN_STEPS steps where bh allows, and is not past the point where a
+    # step already holds _STEP_WORK live score elements.
+    per_head = (s * s // 2 if causal else s * s) // ((s // kvm) * (s // qm))
+    g = 1
+    for cand in range(2, bh + 1):
+        if bh % cand:
+            continue
+        if not fits(cand, kvm, qm) or bh // cand < min(bh, _MIN_STEPS):
+            break
+        g = cand
+        if cand * per_head >= _STEP_WORK:
+            break
+    return _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm)
+
+
+def _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm, hb=None):
+    """The grids and step counts that follow from the block sizes."""
+    if hb is None:
+        # heads a compute tile: independent chains for the scheduler to
+        # interleave, while the f32 score tiles stay around _TILE_BYTES
+        hb = max(c for c in range(1, g + 1)
+                 if g % c == 0 and (c == 1 or c * bq * bkv * 4 <= _TILE_BYTES))
+    steps, live = _live_blocks(s, bq, kvm, causal)
+    bwd_steps, bwd_live = _live_blocks(s, qm, kvm, causal)
+    n = bh // g
+    return FlashSchedule(
+        g, hb, bq, bkv, kvm, qm, (n, s // bq, s // kvm), n * steps, n * live,
+        (n, s // kvm, s // qm), n * bwd_steps, n * bwd_live,
+        _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize))
+
+
+def _bdot(a, b, ca, cb):
+    """Matmul batched over the leading (head) dimension with f32
+    accumulation, contracting ``a``'s dimension ``ca`` with ``b``'s ``cb``:
+    transposes are folded into the dimension numbers (no materialized
+    transpose ops in the kernel)."""
     return jax.lax.dot_general(
-        a, b, (((ca,), (cb,)), ((), ())),
+        a, b, (((ca,), (cb,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, q0, k0, q_axis):
+    """Mask a [heads, ., .] score tile whose queries start at ``q0`` and keys
+    at ``k0``; queries run along ``q_axis`` — shared by both kernels so
+    forward and backward can never disagree on masking."""
+    shape = (1,) + s.shape[1:]
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 3 - q_axis)
+    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _rows(start, size):
+    return pl.ds(pl.multiple_of(start, size), size)
+
+
+def _div(x, n: int):
+    """``max(x, 0) // n`` of a traced int32. Not jnp's ``//``: its floor
+    correction goes through ``sign``, whose Mosaic lowering traces a helper
+    function each time (24 ms a call on the chip's host: with eight to twelve
+    a kernel, 10 s of a GPT-2 step's first dispatch)."""
+    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc,
-                *, causal, scale, bq, bkv):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                causal, scale, sched):
+    g, hb, bq, bkv, kvm = (sched.g, sched.block_h, sched.block_q,
+                           sched.block_kv, sched.block_kv_major)
+    d = q_ref.shape[-1]
+    jm = pl.program_id(2)
+    q0 = pl.program_id(1) * bq          # first query of this step
+    k0 = jm * kvm                       # first key of this step's K/V block
+    n_tiles = kvm // bkv
+    single = sched.grid[2] == 1         # no kv axis: nothing carried over
 
-    @pl.when(j == 0)
-    def _init():
-        acc[:] = jnp.zeros_like(acc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+    if causal:
+        # tiles whose first key <= the last query are live; those whose
+        # last key <= the first query need no mask
+        n_live = jnp.minimum(_div(q0 + bq - k0 + bkv - 1, bkv), n_tiles)
+        n_full = jnp.minimum(_div(q0 + 1 - k0, bkv), n_live)
+    else:
+        n_live = n_full = n_tiles
 
-    # causal: the kv block is dead unless its first key is <= the last query
-    needed = (j * bkv <= i * bq + bq - 1) if causal else (j <= j)
+    def _scores(hs, q, t, masked):
+        s = _bdot(q, k_ref[hs, _rows(t * bkv, bkv), :].astype(jnp.float32),
+                  2, 2)                                 # [hb, bq, bkv]
+        return _causal_mask(s, q0, k0 + t * bkv, 1) if masked else s
 
-    @pl.when(needed)
-    def _tile():
-        _, s = _score_tile(q_ref, k_ref, i, j, bq, bkv, scale, causal)
-        m_prev, l_prev = m_sc[:], l_sc[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    def _pv(hs, p, t):
+        v = v_ref[hs, _rows(t * bkv, bkv), :]
+        return _bdot(p.astype(v.dtype), v, 2, 1)        # [hb, bq, d]
+
+    def _tile(t, carry, *, hs, q, masked):
+        m_prev, l_prev, acc = carry
+        s = _scores(hs, q, t, masked)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_sc[:] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        m_sc[:] = m_new
-        pv = _dot(p.astype(v_ref.dtype), v_ref[0])
-        acc[:] = acc[:] * alpha + pv
+        l_new = alpha * l_prev + jnp.sum(p, axis=2, keepdims=True)
+        return m_new, l_new, acc * alpha + _pv(hs, p, t)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    def _emit(hs, m, l, acc):
+        l = jnp.maximum(l, 1e-30)
+        o_ref[hs] = (acc / l).astype(o_ref.dtype)
+        lse = m + jnp.log(l)                            # [hb, bq, 1]
+        # columns -> lane-dense rows through one aligned transpose a head
+        for h in range(hb):
+            lse_ref[hs.start + h, 0] = jnp.broadcast_to(
+                lse[h], (bq, 128)).T[:1]
+
+    def _heads(hg, _):
+        hs = pl.ds(hg * hb, hb)
+        q = q_ref[hs].astype(jnp.float32) * scale
+        if single and n_tiles == 1:
+            # the whole row in one tile: a plain softmax, nothing online
+            s = _scores(hs, q, 0, causal)
+            m = jnp.max(s, axis=2, keepdims=True)
+            p = jnp.exp(s - m)
+            _emit(hs, m, jnp.sum(p, axis=2, keepdims=True), _pv(hs, p, 0))
+            return
+        if single:
+            carry = (jnp.full((hb, bq, 1), NEG_INF, jnp.float32),
+                     jnp.zeros((hb, bq, 1), jnp.float32),
+                     jnp.zeros((hb, bq, d), jnp.float32))
+        else:
+            carry = tuple(ref[hs] for ref in scratch)
+        carry = jax.lax.fori_loop(
+            0, n_full, partial(_tile, hs=hs, q=q, masked=False), carry)
+        if causal:
+            carry = jax.lax.fori_loop(
+                n_full, n_live, partial(_tile, hs=hs, q=q, masked=True),
+                carry)
+        if single:
+            _emit(hs, *carry)
+        else:
+            for ref, val in zip(scratch, carry):
+                ref[hs] = val
+
+    if single:
+        jax.lax.fori_loop(0, g // hb, _heads, None)
+        return
+
+    m_sc, l_sc, acc_sc = scratch
+
+    @pl.when(jm == 0)
+    def _init():
+        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+
+    @pl.when(n_live > 0)
+    def _run():
+        jax.lax.fori_loop(0, g // hb, _heads, None)
+
+    @pl.when(jm == pl.num_programs(2) - 1)
     def _finalize():
-        l = jnp.maximum(l_sc[:], 1e-30)
-        o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_sc[:] + jnp.log(l)
+        def _fin(hg, _):
+            hs = pl.ds(hg * hb, hb)
+            _emit(hs, m_sc[hs], l_sc[hs], acc_sc[hs])
+        jax.lax.fori_loop(0, g // hb, _fin, None)
 
 
-def _fwd_call(q3, k3, v3, causal, scale, bq, bkv, interpret):
+def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
-    grid = (bh, s // bq, s // bkv)
-    kern = partial(_fwd_kernel, causal=causal, scale=scale, bq=bq, bkv=bkv)
+    g, bq, kvm = sched.g, sched.block_q, sched.block_kv_major
+
+    def kv_map(b, i, jm):
+        if causal:  # a dead block is not fetched: keep the last live one
+            jm = jnp.minimum(jm, _div(i * bq + bq - 1, kvm))
+        return (b, jm, 0)
+
     o, lse = pl.pallas_call(
-        kern,
-        grid=grid,
+        partial(_fwd_kernel, causal=causal, scale=scale, sched=sched),
+        grid=sched.grid,
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
+            pl.BlockSpec((g, kvm, d), kv_map),
+            pl.BlockSpec((g, kvm, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
+            pl.BlockSpec((g, 1, 1, bq), lambda b, i, jm: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, s // bq, 1, bq), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+        # m, l, acc between the steps of a kv axis
+        scratch_shapes=[] if sched.grid[2] == 1 else [
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((g, bq, 1), jnp.float32),
+            pltpu.VMEM((g, bq, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(),
         name="flash_fwd",
         interpret=interpret,
     )(q3, k3, v3)
@@ -158,112 +414,157 @@ def _fwd_call(q3, k3, v3, causal, scale, bq, bkv, interpret):
 # backward
 # --------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_acc, *, causal, scale, bq, bkv):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    needed = (j * bkv <= i * bq + bq - 1) if causal else (j <= j)
-
-    @pl.when(needed)
-    def _tile():
-        _, s = _score_tile(q_ref, k_ref, i, j, bq, bkv, scale, causal)
-        p = jnp.exp(s - lse_ref[0])                             # [bq, bkv]
-        do = do_ref[0].astype(jnp.float32)
-        dov = _dot(do, v_ref[0].astype(jnp.float32), trans_b=True)
-        ds = p * (dov - delta_ref[0])
-        dq_acc[:] = dq_acc[:] + _dot(ds, k_ref[0].astype(jnp.float32)) * scale
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+def _bwd_scratch(sched, dq_dtype):
+    """Which f32 accumulators the backward step needs beyond its output
+    blocks: dQ's when the output block cannot hold the running sum itself
+    (not f32, and more than one kv tile adds to it), dK/dV's when they
+    build up over several q blocks (steps)."""
+    return (dq_dtype != jnp.float32
+            and sched.block_kv_major > sched.block_kv,
+            sched.bwd_grid[2] > 1)
 
 
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, causal, scale, bq, bkv):
-    j = pl.program_id(1)          # kv block (parallel)
-    i = pl.program_id(2)          # q block (innermost, accumulated)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, *scratch, causal, scale, sched):
+    g, hb, bq, bkv, kvm, qm = (sched.g, sched.block_h, sched.block_q,
+                               sched.block_kv, sched.block_kv_major,
+                               sched.block_q_major)
+    im = pl.program_id(2)
+    k0 = pl.program_id(1) * kvm         # first key of this step's K/V block
+    q0 = im * qm                        # first query of this step's q block
+    n_q, n_kv = qm // bq, kvm // bkv
+    d = q_ref.shape[-1]
+    dq_scratch, dkv_scratch = _bwd_scratch(sched, dq_ref.dtype)
+    scratch = list(scratch)
+    # dQ sums over the kv tiles of a step: in the output block where that is
+    # f32, else in scratch; one pair a step writes its product directly
+    one_pair = n_q == 1 and n_kv == 1
+    dq_sum = scratch.pop(0) if dq_scratch else dq_ref
+    # dK/dV sum over the q tiles of a step in loop carries, and over the q
+    # blocks (steps), where there are several, in scratch
+    dk_acc, dv_acc = scratch if dkv_scratch else (None, None)
+    # the step is dead when its last query comes before its first key
+    live = (q0 + qm - 1 >= k0) if causal else True
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+    if dk_acc is not None:
+        @pl.when(im == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    needed = (j * bkv <= i * bq + bq - 1) if causal else (j <= j)
+    if not one_pair:
+        dq_sum[:] = jnp.zeros_like(dq_sum)
 
-    @pl.when(needed)
-    def _tile():
-        q, s = _score_tile(q_ref, k_ref, i, j, bq, bkv, scale, causal)
-        p = jnp.exp(s - lse_ref[0])
-        do = do_ref[0].astype(jnp.float32)
-        dv_acc[:] = dv_acc[:] + _dot(p, do, trans_a=True)
-        dov = _dot(do, v_ref[0].astype(jnp.float32), trans_b=True)
-        ds = p * (dov - delta_ref[0])
-        dk_acc[:] = dk_acc[:] + _dot(ds, q, trans_a=True)
+    def _pair(t, carry, *, hs, k, v, ks, masked):
+        dk, dv = carry
+        rows = _rows(t * bq, bq)
+        q = q_ref[hs, rows, :].astype(jnp.float32) * scale
+        do = do_ref[hs, rows, :].astype(jnp.float32)
+        s = _bdot(k, q, 2, 2)                               # [hb, bkv, bq]
+        if masked:
+            s = _causal_mask(s, q0 + t * bq, ks, 2)
+        p = jnp.exp(s - lse_ref[hs, t])
+        dv = dv + _bdot(p, do, 2, 1)
+        ds = p * (_bdot(v, do, 2, 2) - delta_ref[hs, t])
+        dk = dk + _bdot(ds, q, 2, 1)
+        dq = _bdot(ds, k * scale, 1, 1)                     # [hb, bq, d]
+        if one_pair:
+            dq_ref[hs, rows, :] = dq.astype(dq_ref.dtype)
+        else:
+            dq_sum[hs, rows, :] += dq
+        return dk, dv
 
-    @pl.when(i == pl.num_programs(2) - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    def _kv_tile(j, _, *, hs):
+        rows = _rows(j * bkv, bkv)
+        ks = k0 + j * bkv               # first key of the tile
+        k = k_ref[hs, rows, :].astype(jnp.float32)
+        v = v_ref[hs, rows, :].astype(jnp.float32)
+        if causal:
+            # q tiles whose last query >= the first key are live; those
+            # whose first query >= the last key need no mask
+            t_live = jnp.minimum(_div(ks - q0, bq), n_q)
+            t_full = jnp.clip(_div(ks + bkv - 1 - q0 + bq - 1, bq), t_live,
+                              n_q)
+        else:
+            t_live = t_full = 0
+        kw = dict(hs=hs, k=k, v=v, ks=ks)
+        carry = (jnp.zeros((hb, bkv, d), jnp.float32),) * 2
+        if causal:
+            carry = jax.lax.fori_loop(
+                t_live, t_full, partial(_pair, masked=True, **kw), carry)
+        dk, dv = jax.lax.fori_loop(
+            t_full, n_q, partial(_pair, masked=False, **kw), carry)
+        if dk_acc is None:
+            dk_ref[hs, rows, :] = dk.astype(dk_ref.dtype)
+            dv_ref[hs, rows, :] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[hs, rows, :] += dk
+            dv_acc[hs, rows, :] += dv
+
+    def _heads(hg, _):
+        jax.lax.fori_loop(0, n_kv, partial(_kv_tile, hs=pl.ds(hg * hb, hb)),
+                          None)
+
+    @pl.when(live)
+    def _run():
+        jax.lax.fori_loop(0, g // hb, _heads, None)
+
+    if one_pair and causal:
+        @pl.when(jnp.logical_not(live))
+        def _dead():
+            dq_ref[:] = jnp.zeros_like(dq_ref)
+
+    if dq_sum is not dq_ref:
+        dq_ref[:] = dq_sum[:].astype(dq_ref.dtype)
+
+    if dk_acc is not None:
+        @pl.when(im == pl.num_programs(2) - 1)
+        def _finalize():
+            dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, bq, bkv, interpret):
+def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
+    g, bq, kvm, qm = (sched.g, sched.block_q, sched.block_kv_major,
+                      sched.block_q_major)
+    n_kvm = s // kvm
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1, keepdims=True)                     # [bh, s, 1]
+                    axis=-1).reshape(lse.shape)         # [bh, s/bq, 1, bq]
 
-    dq = pl.pallas_call(
-        partial(_dq_kernel, causal=causal, scale=scale, bq=bq, bkv=bkv),
-        grid=(bh, s // bq, s // bkv),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="flash_bwd_dq",
-        interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
+    def q_map(b, jm, im):
+        if causal:  # a dead block is not fetched: take the first live one
+            im = jnp.maximum(im, _div(jm * kvm, qm))
+        return (b, im, 0)
 
-    dk, dv = pl.pallas_call(
-        partial(_dkv_kernel, causal=causal, scale=scale, bq=bq, bkv=bkv),
-        grid=(bh, s // bkv, s // bq),
-        in_specs=[
-            pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ],
+    q_spec = pl.BlockSpec((g, qm, d), q_map)
+    kv_spec = pl.BlockSpec((g, kvm, d), lambda b, jm, im: (b, jm, 0))
+    row_spec = pl.BlockSpec((g, qm // bq, 1, bq),
+                            lambda b, jm, im: q_map(b, jm, im) + (0,))
+    # one kv block: the step's dQ is dQ; several: f32 partials, summed below
+    dq_dtype = q3.dtype if n_kvm == 1 else jnp.float32
+    dq_scratch, dkv_scratch = _bwd_scratch(sched, dq_dtype)
+    dq, dk, dv = pl.pallas_call(
+        partial(_bwd_kernel, causal=causal, scale=scale, sched=sched),
+        grid=sched.bwd_grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
-            pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((None, g, qm, d), lambda b, jm, im: (jm, b, im, 0)),
+            kv_spec, kv_spec,
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((n_kvm, bh, s, d), dq_dtype),
             jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v3.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in
+                        dq_scratch * [(g, qm, d)]
+                        + dkv_scratch * [(g, kvm, d), (g, kvm, d)]],
+        compiler_params=_compiler_params(),
         name="flash_bwd_dkv",
         interpret=interpret,
-    )(k3, v3, q3, do3, lse, delta)
+    )(q3, k3, v3, do3, lse, delta)
+    dq = dq[0] if n_kvm == 1 else jnp.sum(dq, axis=0).astype(q3.dtype)
     return dq, dk, dv
 
 
@@ -271,20 +572,20 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, bq, bkv, interpret):
 # custom-vjp wrapper
 # --------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, causal, scale, bq, bkv, interpret):
-    o, _ = _fwd_call(q3, k3, v3, causal, scale, bq, bkv, interpret)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q3, k3, v3, causal, scale, sched, interpret):
+    o, _ = _fwd_call(q3, k3, v3, causal, scale, sched, interpret)
     return o
 
 
-def _flash_fwd(q3, k3, v3, causal, scale, bq, bkv, interpret):
-    o, lse = _fwd_call(q3, k3, v3, causal, scale, bq, bkv, interpret)
+def _flash_fwd(q3, k3, v3, causal, scale, sched, interpret):
+    o, lse = _fwd_call(q3, k3, v3, causal, scale, sched, interpret)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash_bwd(causal, scale, bq, bkv, interpret, res, do3):
+def _flash_bwd(causal, scale, sched, interpret, res, do3):
     q3, k3, v3, o3, lse = res
-    return _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, bq, bkv,
+    return _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched,
                      interpret)
 
 
@@ -293,10 +594,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, scale: Optional[float] = None,
-                    block_q: int = 256, block_kv: int = 256,
+                    block_q: Optional[int] = None,
+                    block_kv: Optional[int] = None,
+                    block_kv_major: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused attention over [B, H, S, D] tensors; drop-in for
-    ``ring.full_attention`` (same signature semantics, same output).
+    ``ring.full_attention`` (same signature semantics, same output). The
+    schedule comes from ``flash_schedule`` at the inputs' shape; the block
+    arguments bound it from above (the tests' override).
 
     Raises ValueError when S has no power-of-two block divisor >= 8: a
     caller that asked for the fused kernel is told it cannot have it.
@@ -304,16 +609,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if interpret is None:
         interpret = _interpret_default()
     b, h, s, d = q.shape
-    bq = _pick_block(s, min(block_q, s))
-    bkv = _pick_block(s, min(block_kv, s))
-    if not bq or not bkv:
-        raise ValueError(
-            f"flash_attention needs a power-of-two block >= 8 dividing the "
-            f"sequence length; S={s} has none (use attention 'full')")
+    sched = flash_schedule(b * h, s, d, q.dtype.itemsize, causal,
+                           block_q, block_kv, block_kv_major)
     if scale is None:
         scale = float(d) ** -0.5
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h, s, d)
     v3 = v.reshape(b * h, s, d)
-    o3 = _flash(q3, k3, v3, causal, float(scale), bq, bkv, bool(interpret))
+    o3 = _flash(q3, k3, v3, causal, float(scale), sched, bool(interpret))
     return o3.reshape(b, h, s, d)

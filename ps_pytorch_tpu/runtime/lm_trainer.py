@@ -35,6 +35,7 @@ from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.text import TokenLoader
 from ps_pytorch_tpu.models.transformer import ARCHS, migrate_packed_qkv
 from ps_pytorch_tpu.ops._backend import announce_kernels
+from ps_pytorch_tpu.ops.flash_attention import flash_schedule
 from ps_pytorch_tpu.optim import build_schedule
 from ps_pytorch_tpu.optim.sgd import sgd
 from ps_pytorch_tpu.parallel import dist
@@ -162,7 +163,14 @@ class LMTrainer:
             raise ValueError(self.mode)
         kernels = []
         if self.model.attention_impl == "flash":
-            kernels.append("flash_attention")
+            # the schedule is static per shape: the line is its record
+            calls = cfg.lm_microbatches if self.mode == "pp" else 1
+            rows = cfg.batch_size // (self.mesh.shape["data"] * calls)
+            sched = flash_schedule(
+                max(rows, 1) * cfg.lm_heads, cfg.lm_seq_len,
+                cfg.lm_d_model // cfg.lm_heads,
+                jnp.dtype(self.model.dtype).itemsize, True)
+            kernels.append(f"flash_attention[{sched.describe()}]")
         if ARCHS[cfg.lm_arch].dropless:
             kernels.append("grouped_matmul")
         announce_kernels(kernels)

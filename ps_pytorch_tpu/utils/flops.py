@@ -7,7 +7,8 @@ function (forward, or the full value_and_grad training step) and divide by
 the chip's peak to get MFU.
 
 Counting is exact for ``dot_general`` and the grouped matmuls (the routed rows
-only) and exact-up-to-boundary-effects for
+only), dense S x S for the flash-attention kernels, and
+exact-up-to-boundary-effects for
 ``conv_general_dilated`` (useful MACs only — taps on lhs_dilation-inserted
 zeros are excluded, which matters for the grad-input convs of strided
 layers); elementwise/reduction traffic is deliberately ignored (it is
@@ -52,6 +53,17 @@ def _routed_matmul_flops(eqn) -> int:
     if len(b) == 3:
         return 2 * a[0] * b[1] * b[2]
     return 2 * a[0] * a[1] * b[1]
+
+
+def _flash_flops(eqn) -> int:
+    """The kernels of ``ops/flash_attention.py``. They walk the live tiles in
+    ``fori_loop``s whose trip counts depend on the grid position, which grid x
+    body cannot count; they are charged the dense S x S products, like
+    ``full_attention``: two in ``flash_fwd``, five in ``flash_bwd_dkv``
+    (q is the first [bh, S, D] operand)."""
+    bh, s, d = eqn.invars[0].aval.shape
+    products = 2 if eqn.params["name"] == "flash_fwd" else 5
+    return products * 2 * bh * s * s * d
 
 
 def _conv_flops(eqn) -> int:
@@ -120,6 +132,9 @@ def count_jaxpr_flops(jaxpr) -> int:
         elif name == "pallas_call" and \
                 eqn.params.get("name", "").startswith("moe_gmm_"):
             total += _routed_matmul_flops(eqn)
+        elif name == "pallas_call" and \
+                eqn.params.get("name", "").startswith("flash_"):
+            total += _flash_flops(eqn)
         else:
             trips = _trips(eqn)
             for sub in _sub_jaxprs(eqn):

@@ -7,12 +7,17 @@ Runs in Pallas interpreter mode on the CPU mesh; the TPU path compiles the
 identical kernels under Mosaic.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ps_pytorch_tpu.ops.flash_attention import flash_attention
+from ps_pytorch_tpu.ops.flash_attention import (
+    VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, _flash, _schedule, flash_attention,
+    flash_schedule,
+)
 from ps_pytorch_tpu.parallel.ring import full_attention
 
 
@@ -74,11 +79,181 @@ def test_bf16_forward_close():
                                rtol=2e-2, atol=2e-2)
 
 
+# What the schedule adds (PR 26): several heads a step, a K/V block of several
+# compute tiles, a grid that keeps a kv axis with causally dead blocks, the
+# single backward pass at each. (b, h, s, d, causal, block kwargs)
+SCHEDULE_CASES = {
+    "g3_bh_not_pow2": (3, 8, 64, 64, True, dict(block_q=32, block_kv=32)),
+    "kv_block_of_4_tiles": (1, 2, 256, 64, True,
+                            dict(block_q=64, block_kv=64)),
+    "kv_axis_dead_blocks": (1, 4, 256, 64, True,
+                            dict(block_q=64, block_kv=32,
+                                 block_kv_major=64)),
+    "kv_axis_noncausal": (1, 2, 128, 64, False,
+                          dict(block_q=64, block_kv=32, block_kv_major=64)),
+    "s8": (2, 3, 8, 64, True, {}),
+    "noncausal_default": (2, 2, 128, 64, False, {}),
+    "head_dim_128": (1, 2, 128, 128, True, dict(block_q=64, block_kv=64)),
+    "tall_q_wide_kv": (1, 2, 256, 64, True, dict(block_q=128, block_kv=32)),
+    "wide_q_tall_kv": (1, 2, 256, 64, True, dict(block_q=32, block_kv=128)),
+}
+
+
+def _case(name):
+    b, h, s, d, causal, kw = SCHEDULE_CASES[name]
+    return _qkv(b=b, h=h, s=s, d=d, seed=3), causal, kw
+
+
+@pytest.mark.parametrize("name", SCHEDULE_CASES)
+def test_schedule_case_is_what_it_says(name):
+    (q, _, _), causal, kw = _case(name)
+    b, h, s, d = q.shape
+    sc = flash_schedule(b * h, s, d, 4, causal, **kw)
+    assert (b * h) % sc.g == 0 and s % sc.block_kv_major == 0
+    if name == "g3_bh_not_pow2":
+        assert sc.g == 3
+    if name == "kv_block_of_4_tiles":
+        assert sc.block_kv_major == 4 * sc.block_kv
+    if name.startswith("kv_axis"):
+        assert sc.grid[2] == s // 64 and sc.bwd_grid[1] == s // 64
+        assert (sc.dead > 0) == causal
+
+
+@pytest.mark.parametrize("name", SCHEDULE_CASES)
+def test_schedule_forward_matches_oracle(name):
+    (q, k, v), causal, kw = _case(name)
+    got = flash_attention(q, k, v, causal=causal, **kw)
+    want = full_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("name", SCHEDULE_CASES)
+def test_schedule_gradients_match_oracle(name):
+    (q, k, v), causal, kw = _case(name)
+    w = jax.random.normal(jax.random.key(9), q.shape, jnp.float32)
+    f = lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=causal, **kw) * w)
+    g = lambda q, k, v: jnp.sum(full_attention(q, k, v, causal=causal) * w)
+    got = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
+    for a, b, leaf in zip(got, want, "qkv"):
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4,
+                                   err_msg=f"{name} d{leaf}")
+
+
+@pytest.mark.parametrize("name", ["g3_bh_not_pow2", "kv_axis_dead_blocks"])
+def test_schedule_bf16_close(name):
+    (q, k, v), causal, kw = _case(name)
+    qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    got = flash_attention(qb, kb, vb, causal=causal, **kw)
+    want = full_attention(*(t.astype(jnp.float32) for t in (qb, kb, vb)),
+                          causal=causal)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(jnp.float32), want,
+                               rtol=2e-2, atol=2e-2)
+    # gradients come back in the input dtype (dQ through its f32 scratch,
+    # or through f32 partials where the grid keeps a kv axis)
+    loss = lambda fn: lambda q, k, v: jnp.sum(
+        fn(q, k, v, causal=causal).astype(jnp.float32))
+    got = jax.grad(loss(partial(flash_attention, **kw)),
+                   argnums=(0, 1, 2))(qb, kb, vb)
+    want = jax.grad(loss(full_attention), argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (qb, kb, vb)))
+    for a, b, leaf in zip(got, want, "qkv"):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b, rtol=3e-2,
+            atol=3e-2 * float(jnp.abs(b).max()), err_msg=f"{name} d{leaf}")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_backward_with_q_and_kv_axes(dtype):
+    # What only a sequence too long for the VMEM budget reaches through
+    # flash_schedule: the backward grid keeps a q axis too, so whole steps
+    # are causally dead, dK/dV build up over steps and dQ comes as partials.
+    q, k, v = (t.astype(dtype) for t in _qkv(b=1, h=4, s=256, seed=5))
+    for bq, bkv, kvm, qm in ((64, 64, 64, 64), (32, 64, 128, 64)):
+        sc = _schedule(4, 256, 64, q.dtype.itemsize, True, 2, bq, bkv,
+                          kvm, qm)
+        assert sc.bwd_live < sc.bwd_steps and sc.live < sc.steps
+
+        def flash(q, k, v):
+            r = lambda t: t.reshape(4, 256, 64)
+            return _flash(r(q), r(k), r(v), True, 0.125, sc,
+                             True).reshape(q.shape).astype(jnp.float32)
+        f32 = tuple(t.astype(jnp.float32) for t in (q, k, v))
+        tol = 5e-4 if dtype == jnp.float32 else 3e-2
+        got = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2),
+                       argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(
+            full_attention(*a, causal=True) ** 2), argnums=(0, 1, 2))(*f32)
+        for a, b, leaf in zip(got, want, "qkv"):
+            assert a.dtype == dtype
+            np.testing.assert_allclose(
+                a.astype(jnp.float32), b, rtol=tol,
+                atol=tol * max(1.0, float(jnp.abs(b).max())),
+                err_msg=f"{(bq, bkv, kvm, qm)} d{leaf}")
+
+
+# The three LM cells of BENCHMARK.json, float32: (bh, s, d) -> what a later
+# change must not quietly bring back (1,024 / 512 / 4,096 steps a call).
+CELL_SHAPES = {
+    "gpt2m_s1024": (64, 1024, 64),
+    "gpt2m_s128": (512, 128, 64),
+    "olmoe_s4096": (16, 4096, 128),
+}
+CELL_STEPS = {  # forward steps, backward steps
+    "gpt2m_s1024": (32, 32),
+    "gpt2m_s128": (16, 16),
+    "olmoe_s4096": (128, 16),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_schedule_of_the_cells(cell):
+    bh, s, d = CELL_SHAPES[cell]
+    sc = flash_schedule(bh, s, d, 4, True)
+    assert bh % sc.g == 0
+    for block in (sc.block_q, sc.block_kv, sc.block_kv_major,
+                  sc.block_q_major):
+        assert s % block == 0
+    assert sc.block_kv_major % sc.block_kv == 0
+    assert sc.block_q_major % sc.block_q == 0
+    assert sc.vmem_bytes <= VMEM_BUDGET_BYTES < VMEM_LIMIT_BYTES
+    assert sc.grid == (bh // sc.g, s // sc.block_q, s // sc.block_kv_major)
+    assert sc.steps == sc.grid[0] * sc.grid[1] * sc.grid[2]
+    assert (sc.steps, sc.bwd_steps) == CELL_STEPS[cell]
+    assert sc.live == sc.steps and sc.bwd_live == sc.bwd_steps
+    assert sc.describe().startswith(f"g={sc.g}/{sc.block_h} bq={sc.block_q} ")
+
+
+def test_schedule_counts_dead_steps_and_halves_to_fit():
+    # a forced kv axis: S/bq x S/kvm blocks, the ones above the diagonal dead
+    sc = flash_schedule(4, 1024, 64, 4, True, block_q=256,
+                        block_kv_major=256)
+    assert sc.grid[1:] == (4, 4) and sc.steps == 16 * sc.grid[0]
+    assert sc.live == 10 * sc.grid[0] and sc.dead == 6 * sc.grid[0]
+    assert flash_schedule(4, 1024, 64, 4, False, block_q=256,
+                          block_kv_major=256).dead == 0
+    # a sequence whose one head does not fit whole: blocks halve, g stays 1
+    big = flash_schedule(8, 32768, 128, 4, True)
+    assert big.g == 1 and big.vmem_bytes <= VMEM_BUDGET_BYTES
+    assert big.block_kv_major < 32768 and big.bwd_live < big.bwd_steps
+
+
+def test_schedule_is_pure_and_adapts_to_itemsize():
+    a = flash_schedule(64, 1024, 64, 4, True)
+    assert a == flash_schedule(64, 1024, 64, 4, True)
+    assert flash_schedule(64, 1024, 64, 2, True).g >= a.g
+
+
 def test_unalignable_seq_raises():
     # S with no power-of-two block divisor >= 8: no quiet materializing path
     q, k, v = _qkv(s=36, d=64)
     with pytest.raises(ValueError, match="S=36"):
         flash_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="S=36"):
+        flash_schedule(4, 36, 64, 4, True)
 
 
 def test_pp_flash_matches_pp_full():

@@ -94,6 +94,13 @@ def test_pallas_kernel_counts_once_per_grid_point():
                                   block_kv=128), q)
     n_full = forward_flops(lambda q: full_attention(q, q, q, causal=True), q)
     assert n_flash == n_full == 2 * (2 * 2 * 2 * 256 * 256 * 64)
+    # whatever the schedule (the kernels' loops over live tiles have trip
+    # counts the walker cannot see); the one backward pass is five products
+    assert forward_flops(
+        lambda q: flash_attention(q, q, q, causal=True), q) == n_full
+    n_grad = forward_flops(jax.grad(
+        lambda q: flash_attention(q, q, q, causal=True).sum()), q)
+    assert n_grad == n_full // 2 * 7
 
 
 def test_strided_conv_backward_multiple_is_sane():
