@@ -97,8 +97,8 @@ def _crop_flip(x: np.ndarray, rng: np.random.Generator, pad: int,
                mode: str) -> np.ndarray:
     """Random crop + hflip via per-image strided copies.
 
-    Measured at b=1024/32px uint8 on the build host (2026-07, also in
-    bench_suite input_pipeline): strided-slice memcpy 9.2 ms/batch vs 29.6
+    Measured at b=1024/32px uint8 on the build host (2026-07):
+    strided-slice memcpy 9.2 ms/batch vs 29.6
     ms for the batched fancy-index gather — 3.2x faster (contiguous row
     copies beat elementwise index arithmetic; the round-1 concern about
     per-image Python only bites at small batches). Draw order (ys, xs, flip)
@@ -380,9 +380,9 @@ def norm_constants_for(dataset: str):
         return SVHN_MEAN, SVHN_STD
     if dataset in ("ImageNet", "synthetic_imagenet_rrc"):
         # Standard ImageNet constants (the reference's Normalize stack).
-        # Plain `synthetic_imagenet` intentionally stays None so the
-        # augment-free input_pipeline_imagenet bench row keeps measuring
-        # the bare gather path it always has.
+        # Plain `synthetic_imagenet` intentionally stays None: it is the
+        # augment-free set, and its loader stays on the bare gather
+        # path.
         return IMAGENET_MEAN, IMAGENET_STD
     return None
 
@@ -397,8 +397,8 @@ def augment_train(x: np.ndarray, dataset: str, rng: np.random.Generator,
     normalize rides the chip's spare VPU cycles instead of host numpy.
 
     ``synthetic_cifar10`` runs the full CIFAR augment stack on synthetic
-    data — the loader-throughput bench's way of exercising the real hot
-    path without dataset files (bench_suite.bench_input_pipeline)."""
+    data — how the ResNet benchmark cells exercise the real hot path
+    without dataset files."""
     crop = CROP_STACKS.get(dataset)
     ms = norm_constants_for(dataset)
     if crop is not None:

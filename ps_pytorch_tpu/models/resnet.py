@@ -53,8 +53,8 @@ class PallasConv3x3(nn.Module):
 def pallas_variant(conv_impl: str) -> str:
     """MXU schedule for a ``pallas*`` conv_impl: ``pallas`` -> taps9,
     ``pallas_im2col`` -> im2col. One mapping for ResNet and VGG, so an
-    im2col schedule accepted by the A/B row is adoptable from config alone
-    (ADVICE r5 #4)."""
+    im2col schedule accepted by an A/B on the chip is adoptable from config
+    alone."""
     return "im2col" if conv_impl == "pallas_im2col" else "taps9"
 
 

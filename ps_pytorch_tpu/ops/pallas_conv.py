@@ -10,10 +10,10 @@ that reads each input byte once has headroom ~1.4x on ~35% of the step —
 IF its MXU schedule doesn't give the advantage back (Cout=64 fills only
 half the 128-lane MXU tile; that waste is intrinsic to the geometry). This
 module is the accept/reject experiment: correctness is pinned here and in
-``tests/test_pallas_conv.py`` (interpret mode off-TPU, same semantics), and
-``bench_suite.py``'s ``pallas_conv_ab`` row measures it against
-``lax.conv_general_dilated`` on the chip. The decision is made on that
-row's ratio, not on this docstring.
+``tests/test_pallas_conv.py`` (interpret mode off-TPU, same semantics).
+The A/B against ``lax.conv_general_dilated`` in a benchmark cell on the
+chip has not been run (ROADMAP Speed 3); the decision is made on that
+number, not on this docstring.
 
 Scope (deliberately the trace's hot geometry, not a general conv):
 NHWC, 3x3, stride 1, SAME padding, C_in/C_out free (lane-efficient when
@@ -105,8 +105,8 @@ def effective_block_n(n: int, block_n: int = 4,
     to stay under the double-buffering budget — halved BEFORE the
     divisibility shrink (halving afterwards could yield a block_n that no
     longer divides N, and grid = N // block_n would then silently leave the
-    tail batch rows unwritten). Exposed so the bench A/B records the tile
-    each variant really used (ADVICE r5 #3) with one source of truth."""
+    tail batch rows unwritten). Exposed so an A/B can record the tile each
+    variant really used, with one source of truth."""
     if variant == "im2col":
         block_n = max(block_n // 2, 1)
     while n % block_n:

@@ -571,7 +571,7 @@ class HierarchicalKVTransport:
     them failure-domain-aware.
 
     Key namespaces (one PER LINK, which is what makes ``link_jitter``'s
-    prefix scoping and the bench's per-prefix latency classes work):
+    prefix scoping work):
 
     - ``{run}/hgrad/{gid}/{sid}``   member -> group aggregator (fast link)
     - ``{run}/hagg/{gid}``          group aggregator -> root (slow link)

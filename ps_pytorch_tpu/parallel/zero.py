@@ -15,10 +15,10 @@ optimizer state and applying the full weight update,
 
 Communication volume equals the plain allreduce (reduce-scatter + all-gather
 IS the ring allreduce, split around the update), so the step pays nothing on
-the wire BY CONSTRUCTION — byte counts, not a measured claim. What IS
-measured (PERF.md §2): the single-chip bench row costs −7% throughput vs the
-replicated update (on-chip reshard/ravel work with no memory win to buy it);
-the feature exists for memory at scale, not speed. K-of-N participation
+the wire BY CONSTRUCTION — byte counts, not a measured claim. On one chip
+there is no replica to shard over: the step pays reshard/ravel work with no
+memory win to buy it (how much is not measured in any cell); the feature
+exists for memory at scale, not speed. K-of-N participation
 masks work unchanged: contributions are weighted before the scatter and the
 all-zero-mask no-op guard applies to the slice update.
 

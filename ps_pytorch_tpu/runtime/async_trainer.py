@@ -370,7 +370,8 @@ class AsyncTrainer:
         integrity delegate to ``agg`` untouched; the update itself runs
         host-side per bucket-edge-snapped shard and publishes per-shard
         params over the KV. Single-owner here (the leader owns every
-        shard); the bench exercises the symmetric multi-owner topology."""
+        shard); tests/test_zero_wire.py drives the symmetric multi-owner
+        topology."""
         cfg = self.cfg
         if not cfg.shard_wire:
             return agg

@@ -259,9 +259,8 @@ class ReplicatedKV:
         otherwise idle for one RTT anyway), and completion is collected
         via ``Future.exception()`` — which blocks per future — rather
         than an explicit ``wait()``, whose waiter setup costs more than
-        the whole fan-out tax budget; together these keep the per-op
-        replication cost inside the <5% budget the kvrep bench row
-        asserts."""
+        the whole fan-out; together these keep the per-op replication
+        cost small (what it is on a real link is not measured)."""
         if not backends:
             return []
         submit = self._pool.submit

@@ -4,18 +4,17 @@ Two drive modes, the usual split for a serving bench:
 
 - **closed loop** (``run_closed_loop``): all requests present at t0, the
   engine drains them as fast as slots allow — measures aggregate decode
-  THROUGHPUT (tokens/sec) and is deterministic, so bench_suite.py uses it
-  for the batched-vs-sequential win row (same seeds → sha256 over tokens
-  proves slot-count invariance inside the artifact).
+  THROUGHPUT (tokens/sec) and is deterministic (same seeds → the same
+  tokens at any slot count, pinned in tests/test_serving.py).
 - **open loop** (``run_open_loop``): Poisson arrivals submitted through an
   ``AdmissionQueue`` while a ``serve_loop`` thread drains it — measures
   LATENCY under load including queueing (TTFT/p50/p99) and exercises
   backpressure/shedding. Wall-clock heavy, so its soak test is ``slow``.
 
-``summarize`` turns resolved requests into the stats dict both modes (and
-bench_suite rows) report. ``run_slo_sweep`` stacks open-loop rungs into a
+``summarize`` turns resolved requests into the stats dict both modes
+report. ``run_slo_sweep`` stacks open-loop rungs into a
 rising-offered-load ladder judged against an ``--slo-spec`` and reports
-the knee + goodput-under-SLO (PERF.md §13's methodology).
+the knee + goodput-under-SLO.
 """
 
 import threading

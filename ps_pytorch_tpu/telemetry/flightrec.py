@@ -10,9 +10,8 @@ checkpoint writer) joining them with the tracer's span tail and a final
 registry snapshot. ``analyze.py flight`` renders the artifact as a
 post-mortem.
 
-Recording is O(1) appends on bounded deques — cheap enough for every
-step (the bench_suite ops-overhead row holds the whole ops plane,
-recorder included, under 2%).
+Recording is O(1) appends on bounded deques, so it runs on every step;
+in a benchmark cell it is inside the ``ops_step`` span.
 """
 
 import json

@@ -116,7 +116,7 @@ def render(registry: Registry,
 
 def parse_exposition(text: str) -> Dict[str, float]:
     """Minimal exposition parser: {"name{labels}": value} for every sample
-    line. Used by tests and the regression tooling; raises on lines that are
+    line. Used by tests; raises on lines that are
     neither comments nor valid samples, so malformed output can't pass."""
     out: Dict[str, float] = {}
     for line in text.splitlines():

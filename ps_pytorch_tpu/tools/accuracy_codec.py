@@ -5,8 +5,8 @@ Trains the SAME async task (LeNet / synthetic_mnist, 2 equal-rate
 slices) once per grad codec and
 reports each lossy codec's eval-loss/precision delta against the lossless
 baseline, with and without sender-side error feedback. This is the
-evidence row behind --grad-codec: the wire bench (BENCH_WIRE_r*) prices
-the bytes, this artifact prices the accuracy.
+evidence row behind --grad-codec: tests/test_wire_counts.py counts the
+bytes, this artifact prices the accuracy.
 
 The baseline is --compress-grad with the lossless blosc codec — the
 leader's decode-then-average path the homomorphic family replaces. int8lat

@@ -537,72 +537,6 @@ def zero_main(args, parser) -> int:
     return 0
 
 
-# ---- serving mode (BENCH_SERVE artifact summary) ----
-
-def serving_summary(rows: List[dict]) -> dict:
-    """BENCH_SERVE JSON-lines -> {"rows": [engine rows], "wins": [derived
-    serve_batch_win_* rows], "errors": [...]}. Keeps the artifact's own
-    verdicts (ok / bitwise_identical) — analysis reads them back, it does
-    not re-decide them."""
-    engine = [r for r in rows
-              if "slots" in r and "tokens_per_sec" in r and "error" not in r]
-    wins = [r for r in rows
-            if str(r.get("config", "")).startswith("serve_batch_win")]
-    errors = [r for r in rows if "error" in r]
-    if not engine and not wins:
-        raise ValueError("no serving rows")
-    return {"rows": engine, "wins": wins, "errors": errors}
-
-
-def serving_markdown(summary: dict) -> str:
-    lines = ["| config | slots | tokens/s | ttft p50/p99 (ms) "
-             "| latency p50/p99 (ms) |", "|---|---|---|---|---|"]
-    for r in summary["rows"]:
-        lines.append(
-            f"| {r['config']} | {r['slots']} | {r['tokens_per_sec']} "
-            f"| {r['ttft_p50_ms']} / {r['ttft_p99_ms']} "
-            f"| {r['latency_p50_ms']} / {r['latency_p99_ms']} |")
-    for w in summary["wins"]:
-        lines.append(
-            f"\n{w['config']}: {w['ratio']}x tokens/s vs sequential, "
-            f"bitwise_identical={w['bitwise_identical']}, ok={w['ok']}")
-    for e in summary["errors"]:
-        lines.append(f"\nERROR {e.get('config', '?')}: {e['error'][:80]}")
-    return "\n".join(lines)
-
-
-def read_json_lines(path: str) -> List[dict]:
-    """Bench-artifact JSON-lines -> list of dicts (non-JSON lines skipped;
-    read_records is for STEP-schema logs and drops bench rows)."""
-    rows = []
-    with open(path) as f:
-        for line in f:
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec, dict):
-                rows.append(rec)
-    return rows
-
-
-def serving_main(args, parser) -> int:
-    files: List[str] = []
-    for pattern in args.runs:
-        files.extend(sorted(glob.glob(pattern)) or
-                     parser.error(f"no files match {pattern!r}") or [])
-    rows = [r for path in files for r in read_json_lines(path)]
-    try:
-        summary = serving_summary(rows)
-    except ValueError as e:
-        parser.error(f"{e} in {files}")
-    if args.json:
-        print(json.dumps(summary))
-    else:
-        print(serving_markdown(summary))
-    return 0
-
-
 # ---- faults mode (resilience counter summary) ----
 
 def fault_summary(rows: List[dict]) -> dict:
@@ -1030,9 +964,6 @@ def main(argv=None) -> int:
     if args.runs[0] == "zero":
         args.runs = args.runs[1:] or p.error("zero mode needs FILE...")
         return zero_main(args, p)
-    if args.runs[0] == "serving":
-        args.runs = args.runs[1:] or p.error("serving mode needs FILE...")
-        return serving_main(args, p)
     if args.runs[0] == "membership":
         args.runs = args.runs[1:] or p.error("membership mode needs FILE...")
         return membership_main(args, p)

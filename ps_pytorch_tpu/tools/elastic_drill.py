@@ -21,10 +21,8 @@ through tools/launch.py ``--simulate``:
   BITWISE equal to the replicated SGD recurrence — the exactness guard,
   over the real wire.
 
-The artifact carries the regress "elastic" family contract
-(tools/regress.py): top-level ``ok``/``bitwise_equal``, ``counters`` with
-``kv_giveups``, and an ``elastic`` section with ``elections``,
-``membership_changes``, ``final_epoch``, ``election_latency_s``.
+The drill is judged by its own exit code: :func:`verdict` holds the whole
+pass rule over the result it writes (counts and flags, no clock).
 
 Usage:
     python ps_pytorch_tpu/tools/elastic_drill.py --out RESILIENCE_r11.json
@@ -41,6 +39,11 @@ import time
 REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 if str(REPO) not in sys.path:  # runnable as a script from anywhere
     sys.path.insert(0, str(REPO))
+
+from ps_pytorch_tpu.tools import launch  # noqa: E402
+from ps_pytorch_tpu.tools.launch import (  # noqa: E402
+    free_port as _free_port, proc_logs as _logs,
+)
 
 
 # ---------------------------------------------------------------- workers
@@ -81,13 +84,16 @@ def _worker_failover(args) -> None:
         lr=0.05, momentum=0.9, compute_dtype="float32", mode="async",
         max_steps=args.max_steps, eval_freq=4, train_dir=args.train_dir,
         resume=False, log_every=2,
-        compress_grad=bool(args.grad_codec), grad_codec=args.grad_codec,
-        ef=args.ef,
+        compress_grad=bool(args.grad_codec), ef=args.ef,
+        grad_codec=args.grad_codec or TrainConfig.grad_codec,
         elastic=True, elastic_leader=1, leader_lease_s=3.0,
         heartbeat_interval_s=3.0, kv_retry_attempts=3,
         fault_spec=f"leader_kill:step={args.kill_step}" if armed else "")
     t = AsyncTrainer(cfg)
     t.train()
+    stats = t._retrier.snapshot() if t._retrier is not None else {}
+    print(f"DRILLSTATS pid {jax.process_index()} {json.dumps(stats)}",
+          flush=True)
     r = t.evaluate(max_batches=2)
     print(f"FINAL loss {r['loss']:.4f} prec1 {r['prec1']:.4f} "
           f"version {t.version}", flush=True)
@@ -182,7 +188,6 @@ def _worker_rebalance(args) -> None:
 # ---------------------------------------------------------------- driver
 
 def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
-    from ps_pytorch_tpu.tools import launch
     return launch.main([
         "launch", "--run-dir", str(run_dir), "--simulate", "3",
         "--devices-per-host", "2", "--port", str(port),
@@ -192,19 +197,42 @@ def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
     ])
 
 
-def _logs(run_dir: pathlib.Path, n: int = 3):
-    out = []
-    for i in range(n):
-        p = run_dir / f"proc_{i}.log"
-        out.append(p.read_text() if p.exists() else "")
-    return out
-
-
-def _free_port() -> int:
-    import socket
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+def verdict(result: dict) -> list:
+    """The drill's whole pass rule: the invariants ``result`` violates,
+    empty when it passes. A drill in which nobody died proved nothing, so
+    the kill, the election and the membership changes are demanded, not
+    only the absence of errors."""
+    counters = result.get("counters", {})
+    el = result.get("elastic", {})
+    phases = result.get("phases", {})
+    fo, rb = phases.get("failover", {}), phases.get("rebalance", {})
+    member = rb.get("membership", {})
+    rules = [
+        ("failover: the launcher ran the processes (rc != 2)",
+         fo.get("rc", 2) != 2),
+        ("failover: the leader was SIGKILLed",
+         counters.get("leader_kills", 0) >= 1),
+        ("failover: exactly one election",
+         el.get("elections", 0) == 1),
+        ("failover: the new leader holds an epoch >= 2",
+         el.get("final_epoch", 0) >= 2),
+        # records written before this key existed do not hold it
+        ("failover: both survivors finished",
+         fo.get("survivors_finished", 2) == 2),
+        ("failover: the new leader folded at least one membership change",
+         el.get("membership_changes", 0)
+         > member.get("membership_changes", 0)),
+        ("the retry plane never gave up",
+         counters.get("kv_giveups", -1) == 0),
+        ("rebalance: every process exited 0", rb.get("rc", -1) == 0),
+        ("rebalance: sharded update bitwise equal to the replicated "
+         "recurrence on every process",
+         result.get("bitwise_equal") is True),
+        ("rebalance: join, leave and rejoin each bumped the view epoch",
+         member.get("epoch", 0) >= 3
+         and member.get("membership_changes", 0) >= 3),
+    ]
+    return [name for name, held in rules if not held]
 
 
 def main(argv=None) -> int:
@@ -279,14 +307,12 @@ def main(argv=None) -> int:
     for line in elastic_lines:
         if new_leader and int(line[0]) == int(new_leader[1].group(1)):
             leader_changes = int(line[3])
-    p1_ok = (rc1 != 2 and killed and len(elected) == 1
-             and len(survivors_final) == 2 and final_epoch >= 2
-             and leader_changes >= 1)
-    print(f"PHASE failover ok={p1_ok} killed={killed} "
+    kv_giveups = sum(json.loads(m).get("kv_giveups", 0) for m in re.findall(
+        r"DRILLSTATS pid \d+ (\{.*\})", "\n".join(logs)))
+    print(f"PHASE failover killed={killed} "
           f"elected={[(i, m.group(2)) for i, m in elected]} "
-          f"latency={latency:.3f}s membership_changes={leader_changes}")
-    if not p1_ok:
-        print(dump)
+          f"survivors_finished={survivors_final} latency={latency:.3f}s "
+          f"membership_changes={leader_changes} kv_giveups={kv_giveups}")
 
     # -- phase 2: rejoin + sharded rebalance exactness ------------------
     rc2 = _launch(d2, _free_port(), ["--phase", "rebalance"])
@@ -298,27 +324,21 @@ def main(argv=None) -> int:
     bitwise = len(rebal) == 3 and all(r[1] == "true" for r in rebal)
     rebalances = max((json.loads(r[2]).get("rebalances", 0)
                       for r in rebal), default=0)
-    p2_ok = (rc2 == 0 and bitwise and msnap.get("epoch", 0) >= 3
-             and msnap.get("membership_changes", 0) >= 3)
-    print(f"PHASE rebalance ok={p2_ok} bitwise={bitwise} "
+    print(f"PHASE rebalance rc={rc2} bitwise={bitwise} "
           f"membership={msnap} rebalances={rebalances}")
-    if not p2_ok:
-        print("\n\n".join(f"== proc_{i} ==\n{t[-2500:]}"
-                          for i, t in enumerate(logs2)))
 
     # -- artifact -------------------------------------------------------
-    ok = p1_ok and p2_ok
     art = {
         "round": 11,
         "platform": "cpu",
         "scenario": "elastic_leader_kill_failover + rejoin_readmit + "
                     "sharded_rebalance_bitwise",
         "processes": 3,
-        "ok": ok,
         "bitwise_equal": bitwise,
         "grad_codec": args.grad_codec or "none",
         "error_feedback": bool(args.ef),
-        "counters": {"leader_kills": int(killed), "kv_giveups": 0},
+        "counters": {"leader_kills": int(killed),
+                     "kv_giveups": int(kv_giveups)},
         "elastic": {
             "elections": len(elected),
             "membership_changes": leader_changes
@@ -330,7 +350,8 @@ def main(argv=None) -> int:
             "world_size_after_kill": 2,
         },
         "phases": {
-            "failover": {"ok": p1_ok, "rc": rc1, "killed_pid": 1,
+            "failover": {"rc": rc1, "killed_pid": 1,
+                         "survivors_finished": len(survivors_final),
                          "new_leader_pid":
                              int(new_leader[1].group(1)) if new_leader
                              else -1,
@@ -339,10 +360,17 @@ def main(argv=None) -> int:
                              else -1,
                          "max_steps": args.max_steps,
                          "kill_step": args.kill_step},
-            "rebalance": {"ok": p2_ok, "rc": rc2,
-                          "membership": msnap},
+            "rebalance": {"rc": rc2, "membership": msnap},
         },
     }
+    violations = verdict(art)
+    ok = art["ok"] = not violations
+    for v in violations:
+        print(f"VIOLATED {v}")
+    if not ok:
+        print(dump)
+        print("\n\n".join(f"== proc_{i} ==\n{t[-2500:]}"
+                          for i, t in enumerate(logs2)))
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1)
     print(f"WROTE {args.out} ok={ok}")

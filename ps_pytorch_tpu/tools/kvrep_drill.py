@@ -2,7 +2,7 @@
 """Quorum-replicated coordination-plane chaos drill -> RESILIENCE_r17.json.
 
 The acceptance drill for ReplicatedKV (ps_pytorch_tpu/runtime/kvrep.py):
-the KV ITSELF is the victim. Four phases:
+the KV ITSELF is the victim. Three phases:
 
 - **train**: 3 REAL ``python -m ps_pytorch_tpu.runtime.kvrep`` backend
   server processes; 3 REAL elastic async-training processes (tools/launch
@@ -24,13 +24,9 @@ the KV ITSELF is the victim. Four phases:
   faults armed on one backend and a client restart mid-sequence that
   resumes from a quorum read. The final vector must be BITWISE equal to
   the pure-numpy oracle — the exactness guard for resume-through-quorum.
-- **overhead**: the wire bench's publish+read, single LatencyKV backend
-  vs ReplicatedKV over 3 at the same RTT (bench_suite.py
-  ``kvrep_overhead``); the replication tax must stay under 5%.
 
-The artifact carries the ``resilience`` family contract (top-level
-``ok``/``bitwise_equal``, ``counters.kv_giveups == 0``) plus the new
-``kvrep`` section gated by tools/regress.py's ``kvrep`` family.
+The drill is judged by its own exit code: :func:`verdict` holds the whole
+pass rule over the result it writes (counts and flags, no clock).
 
 Usage:
     python ps_pytorch_tpu/tools/kvrep_drill.py --out RESILIENCE_r17.json
@@ -53,15 +49,14 @@ REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 if str(REPO) not in sys.path:  # runnable as a script from anywhere
     sys.path.insert(0, str(REPO))
 
-FLEET = "drill"
-V, D, L, H, S = 61, 32, 2, 2, 96     # tests/test_serving.py geometry
-
-
-def _free_port() -> int:
-    import socket
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+# The serve phase's fleet is router_drill's: same tiny LM, same checkpoint.
+from ps_pytorch_tpu.tools import launch  # noqa: E402
+from ps_pytorch_tpu.tools.launch import (  # noqa: E402
+    free_port as _free_port, proc_logs as _logs,
+)
+from ps_pytorch_tpu.tools.router_drill import (  # noqa: E402
+    FLEET, V, _wait_ready, _write_checkpoint,
+)
 
 
 # ---------------------------------------------------------------- workers
@@ -164,7 +159,6 @@ class KVBackend:
 
 
 def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
-    from ps_pytorch_tpu.tools import launch
     return launch.main([
         "launch", "--run-dir", str(run_dir), "--simulate", "3",
         "--devices-per-host", "2", "--port", str(port),
@@ -172,14 +166,6 @@ def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
         "--cwd", str(REPO), "--wait", "--timeout", "420",
         "--", *worker_args,
     ])
-
-
-def _logs(run_dir: pathlib.Path, n: int = 3):
-    out = []
-    for i in range(n):
-        p = run_dir / f"proc_{i}.log"
-        out.append(p.read_text() if p.exists() else "")
-    return out
 
 
 def _phase_train(args, base: pathlib.Path) -> dict:
@@ -261,12 +247,9 @@ def _phase_train(args, base: pathlib.Path) -> dict:
     for b in backends:
         b.stop()
 
-    ok = (rc == 0 and len(finals) == 3 and evidence["killed"]
-          and evidence["wiped"] and giveups == 0 and rejoins >= 1
-          and resyncs >= 1 and tag_equal
-          and max(versions, default=0) >= args.max_steps)
-    out = {"ok": ok, "rc": rc, "procs": 3, "backends": 3,
+    out = {"rc": rc, "procs": 3, "backends": 3,
            "finals": len(finals), "max_version": max(versions, default=0),
+           "max_steps": args.max_steps,
            "giveups": giveups, "ejections": ejections,
            "rejoins": rejoins, "resyncs": resyncs,
            "healthy_at_exit": healthy_end,
@@ -274,39 +257,13 @@ def _phase_train(args, base: pathlib.Path) -> dict:
            "kill_at_s": evidence["kill_at_s"],
            "resync_tag_equal": tag_equal, "keys_compared": len(tags0),
            "driver_resync": driver_resync}
-    print(f"PHASE train ok={ok} finals={len(finals)} giveups={giveups} "
+    print(f"PHASE train rc={rc} finals={len(finals)} giveups={giveups} "
           f"rejoins={rejoins} resyncs={resyncs} tag_equal={tag_equal} "
           f"keys={len(tags0)}", flush=True)
-    if not ok:
+    if _train_violations(out):
         print("\n\n".join(f"== proc_{i} ==\n{t[-2500:]}"
                           for i, t in enumerate(logs)))
     return out
-
-
-def _lm_cfg(train_dir: str):
-    from ps_pytorch_tpu.config import TrainConfig
-    return TrainConfig(network="TransformerLM", lm_vocab=V, lm_d_model=D,
-                       lm_layers=L, lm_heads=H, lm_seq_len=S,
-                       train_dir=train_dir)
-
-
-def _write_checkpoint(train_dir: str, step: int, seed: int) -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from ps_pytorch_tpu.models.transformer import TransformerLM
-    from ps_pytorch_tpu.runtime import checkpoint as ckpt
-    from ps_pytorch_tpu.runtime.lm_eval import build_lm_template
-
-    cfg = _lm_cfg(train_dir)
-    model = TransformerLM(vocab_size=V, d_model=D, n_layers=L, n_heads=H,
-                          max_seq_len=S)
-    params = model.init(jax.random.key(seed),
-                        jnp.zeros((1, 8), jnp.int32),
-                        positions=jnp.arange(8))["params"]
-    template = build_lm_template(cfg)
-    ckpt.save_checkpoint(train_dir, step, template.replace(params=params),
-                         config_json=cfg.to_json())
 
 
 class Replica:
@@ -344,16 +301,6 @@ class Replica:
             self.proc.wait(timeout=10)
 
 
-def _wait_ready(view, n: int, timeout_s: float = 120.0) -> list:
-    deadline = time.monotonic() + timeout_s
-    while time.monotonic() < deadline:
-        ready = view.poll()
-        if len(ready) >= n:
-            return ready
-        time.sleep(0.25)
-    raise TimeoutError(f"only {len(view.poll())} of {n} replicas ready")
-
-
 def _phase_serve(args, base: pathlib.Path) -> dict:
     """Backend wipe under live fleet serving: the router's fleet view and
     client availability must not notice one KV backend losing its data."""
@@ -386,7 +333,7 @@ def _phase_serve(args, base: pathlib.Path) -> dict:
     router = Router(view, registry=declare_router_metrics(Registry()),
                     retries=3, backoff_s=0.05, hedge_s=0.0,
                     request_timeout_s=30.0, refresh_s=0.25)
-    out = {"ok": False}
+    out = {}
     try:
         router.start()
         _wait_ready(view, 3)
@@ -438,13 +385,10 @@ def _phase_serve(args, base: pathlib.Path) -> dict:
                 break
             time.sleep(0.5)
         availability = load_out.get("availability")
-        ok = (availability == 1.0
-              and load_out.get("failed_5xx", -1) == 0
-              and load_out.get("requests", 0) >= args.serve_requests
-              and min_view["n"] == 3 and wiped_files > 0 and repop > 0)
-        out = {"ok": ok, "availability": availability,
+        out = {"availability": availability,
                "availability_floor": 1.0,
                "failed_5xx": load_out.get("failed_5xx", -1),
+               "offered": args.serve_requests,
                "requests": load_out.get("requests", 0),
                "completed": load_out.get("completed", 0),
                "status_counts": load_out.get("status_counts", {}),
@@ -453,10 +397,10 @@ def _phase_serve(args, base: pathlib.Path) -> dict:
                "wiped_keys": wiped_files, "repopulated_keys": repop,
                "read_repairs": rkv.counters["kvrep_read_repairs"],
                "wipes": 1}
-        print(f"PHASE serve ok={ok} availability={availability} "
+        print(f"PHASE serve availability={availability} "
               f"5xx={load_out.get('failed_5xx')} min_view={min_view['n']} "
               f"repopulated={repop}", flush=True)
-        if not ok:
+        if _serve_violations(out):
             for rep in replicas:
                 print(f"== replica_{rep.rid} ==\n{rep.log()[-2000:]}")
     finally:
@@ -540,12 +484,7 @@ def _phase_bitwise() -> dict:
     tags0 = reader.backend_tags(0)
     tag_equal = bool(tags0) and tags0 == reader.backend_tags(2)
     counters = inj.snapshot()
-    ok = (bitwise and tag_equal and resumed_at == 6
-          and counters.get("kv_backend_kills", 0) >= 1
-          and counters.get("kv_backend_wipes", 0) >= 1
-          and snap.get("kvrep_rejoins", 0) >= 1
-          and snap.get("kvrep_resyncs", 0) >= 1)
-    out = {"ok": ok, "bitwise_equal": bitwise, "resumed_at_step": resumed_at,
+    out = {"bitwise_equal": bitwise, "resumed_at_step": resumed_at,
            "steps": len(grads), "resync_tag_equal": tag_equal,
            "kills": counters.get("kv_backend_kills", 0),
            "wipes": counters.get("kv_backend_wipes", 0),
@@ -554,26 +493,76 @@ def _phase_bitwise() -> dict:
            "resyncs": snap.get("kvrep_resyncs", 0),
            "read_repairs": snap.get("kvrep_read_repairs", 0),
            "ejections": snap.get("kvrep_ejections", 0)}
-    print(f"PHASE bitwise ok={ok} bitwise_equal={bitwise} "
+    print(f"PHASE bitwise bitwise_equal={bitwise} "
           f"kills={out['kills']} wipes={out['wipes']} "
           f"rejoins={out['rejoins']} tag_equal={tag_equal}", flush=True)
     return out
 
 
-def _phase_overhead() -> dict:
-    """The committed replication-tax row: wire-bench publish+read, one
-    backend vs the 3-way quorum at the same RTT (<5% budget)."""
-    import bench_suite
-    row = bench_suite.bench_kvrep_overhead("kvrep_overhead", 3)
-    out = {"ok": bool(row["ok"]),
-           "overhead_frac": row["overhead_frac"],
-           "single_s": row["single_s"], "replicated_s": row["replicated_s"],
-           "payload_mb": row["payload_mb"], "rtt_ms": row["rtt_ms"],
-           "n_backends": row["n_backends"], "budget": 0.05}
-    print(f"PHASE overhead ok={out['ok']} frac={out['overhead_frac']} "
-          f"single={out['single_s']}s replicated={out['replicated_s']}s",
-          flush=True)
-    return out
+def _train_violations(train: dict) -> list:
+    rules = [
+        ("train: every process exited 0 and finished",
+         train.get("rc", -1) == 0
+         and train.get("finals", 0) == train.get("procs", 3)),
+        ("train: a backend was SIGKILLed and restarted empty",
+         train.get("kills", 0) >= 1 and train.get("wipes", 0) >= 1),
+        ("train: zero retry give-ups", train.get("giveups", -1) == 0),
+        ("train: the clients rejoined and resynced the reborn backend",
+         train.get("rejoins", 0) >= 1 and train.get("resyncs", 0) >= 1),
+        ("train: the reborn backend is tag-equal key by key",
+         train.get("resync_tag_equal") is True),
+        # records written before max_steps was kept do not hold it
+        ("train: every version was completed",
+         train.get("max_version", 0) >= train.get("max_steps", 1)),
+    ]
+    return [name for name, held in rules if not held]
+
+
+def _serve_violations(serve: dict) -> list:
+    avail = serve.get("availability")
+    rules = [
+        ("serve: availability 1.00 through the wipe", avail == 1.0),
+        ("serve: zero client 5xx", serve.get("failed_5xx", -1) == 0),
+        # records written before the offered count was kept do not hold it
+        ("serve: every offered request was sent",
+         serve.get("requests", 0) >= serve.get("offered", 1)),
+        ("serve: the router's fleet view never lost a replica",
+         serve.get("min_fleet_view", 0) == 3),
+        ("serve: a backend directory was wiped and repopulated",
+         serve.get("wiped_keys", 0) > 0
+         and serve.get("repopulated_keys", 0) > 0),
+    ]
+    return [name for name, held in rules if not held]
+
+
+def verdict(result: dict) -> list:
+    """The drill's whole pass rule: the invariants ``result`` violates,
+    empty when it passes. A backend must really have been killed AND wiped,
+    and the quorum must have masked it end to end: training with zero
+    give-ups and a resynced backend, serving with zero 5xx, and a resume
+    through a quorum read that is bitwise the oracle's."""
+    kvrep = result.get("kvrep", {})
+    bitw = kvrep.get("bitwise", {})
+    rules = [
+        (f"{key} >= 1", kvrep.get(key, 0) >= 1)
+        for key in ("backend_kills", "backend_wipes", "rejoins", "resyncs")
+    ] + [
+        ("the retry plane never gave up",
+         result.get("counters", {}).get("kv_giveups", -1) == 0),
+        ("bitwise: the resumed recurrence equals the oracle bit for bit",
+         result.get("bitwise_equal") is True
+         and bitw.get("bitwise_equal") is True),
+        ("bitwise: the fresh client resumed mid-outage from a quorum read",
+         bitw.get("resumed_at_step", -1) == 6),
+        ("bitwise: kill and wipe fired, the client rejoined and resynced "
+         "to tag equality",
+         bitw.get("kills", 0) >= 1 and bitw.get("wipes", 0) >= 1
+         and bitw.get("rejoins", 0) >= 1 and bitw.get("resyncs", 0) >= 1
+         and bitw.get("resync_tag_equal") is True),
+    ]
+    return ([name for name, held in rules if not held]
+            + _train_violations(kvrep.get("train", {}))
+            + _serve_violations(kvrep.get("serve", {})))
 
 
 def main(argv=None) -> int:
@@ -607,18 +596,14 @@ def main(argv=None) -> int:
     train = _phase_train(args, base)
     serve = _phase_serve(args, base)
     bitwise = _phase_bitwise()
-    overhead = _phase_overhead()
 
-    ok = bool(train["ok"] and serve["ok"] and bitwise["ok"]
-              and overhead["ok"])
     art = {
         "round": 17,
         "platform": "cpu",
         "scenario": "kv_backend_kill_wipe_quorum: elastic_train + "
-                    "fleet_serve + bitwise_resume + replication_overhead",
+                    "fleet_serve + bitwise_resume",
         "processes": 3,
         "backends": 3,
-        "ok": ok,
         "bitwise_equal": bool(bitwise["bitwise_equal"]),
         "counters": {
             "kv_giveups": int(train["giveups"]),
@@ -635,9 +620,12 @@ def main(argv=None) -> int:
             "train": train,
             "serve": serve,
             "bitwise": bitwise,
-            "overhead": overhead,
         },
     }
+    violations = verdict(art)
+    ok = art["ok"] = not violations
+    for v in violations:
+        print(f"VIOLATED {v}")
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1)
     print(f"WROTE {args.out} ok={ok}")

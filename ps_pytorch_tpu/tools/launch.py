@@ -31,6 +31,7 @@ import json
 import os
 import shlex
 import signal
+import socket
 import subprocess
 import sys
 import time
@@ -39,6 +40,13 @@ from typing import List, Optional
 from ps_pytorch_tpu.parallel import dist
 
 PROCS_FILE = "procs.json"
+
+
+def free_port() -> int:
+    """A TCP port free on this host now (for ``--simulate`` coordinators)."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
 
 
 def _read_hostfile(path: str) -> List[str]:
@@ -126,6 +134,19 @@ def cmd_launch(args, train_argv: List[str]) -> int:
 def _load_procs(run_dir: str) -> dict:
     with open(os.path.join(run_dir, PROCS_FILE)) as f:
         return json.load(f)
+
+
+def proc_logs(run_dir) -> List[str]:
+    """Every launched process's log so far, by rank ([] before a launch)."""
+    try:
+        procs = _load_procs(str(run_dir))["procs"]
+    except (FileNotFoundError, ValueError):   # not launched yet, or mid-write
+        return []
+    out = []
+    for rec in procs:
+        with open(rec["log"]) as f:
+            out.append(f.read())
+    return out
 
 
 def _alive(pid: int) -> bool:

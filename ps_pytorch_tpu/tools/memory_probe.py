@@ -9,7 +9,7 @@ is a process-lifetime high-water mark — in-process sequential measurement
 would only ever report the max so far) and writes one JSON artifact.
 
 Modes
-  lm_base / lm_remat          TransformerLM b=8 S=2048 (suite geometry):
+  lm_base / lm_remat          TransformerLM b=8 S=2048, d_model 512, 8 layers:
                               per-block remat drops every block's
                               intermediates (incl. the [B,H,S,S] attention
                               matrix) from the backward's saved set.
@@ -41,20 +41,16 @@ import time
 MODES = ("lm_base", "lm_remat", "lm_flash", "lm_pp_m1", "lm_pp_m8",
          "cnn_base", "cnn_remat", "cnn_zero1")
 
-LM_GEOM = dict(batch=8, seq_len=2048, d_model=512, n_layers=8, n_heads=8,
-               vocab=32000)
-
-
-def _lm_step(mode):
+def _lm_step(mode, *, batch=8, seq_len=2048, d_model=512, n_layers=8,
+             n_heads=8, vocab=32000):
     import jax
     from ps_pytorch_tpu.config import TrainConfig
     from ps_pytorch_tpu.models.transformer import TransformerLM
     from ps_pytorch_tpu.optim import build_optimizer
     from ps_pytorch_tpu.parallel.mesh import make_mesh
 
-    g = LM_GEOM
     cfg = TrainConfig(dataset="synthetic", network="LeNet",
-                      batch_size=g["batch"], lr=0.01, momentum=0.9)
+                      batch_size=batch, lr=0.01, momentum=0.9)
     tx = build_optimizer(cfg)
     if mode.startswith("lm_pp"):
         from ps_pytorch_tpu.parallel.pp import (
@@ -62,11 +58,11 @@ def _lm_step(mode):
         )
         mesh = make_mesh(data=1, model=len(jax.devices()))
         n_stages = mesh.shape["model"]
-        model = TransformerLM(vocab_size=g["vocab"], d_model=g["d_model"],
-                              n_layers=g["n_layers"], n_heads=g["n_heads"],
-                              max_seq_len=g["seq_len"], attention_impl="full")
+        model = TransformerLM(vocab_size=vocab, d_model=d_model,
+                              n_layers=n_layers, n_heads=n_heads,
+                              max_seq_len=seq_len, attention_impl="full")
         state = create_pp_train_state(model, tx, mesh, n_stages,
-                                      (g["batch"], g["seq_len"]))
+                                      (batch, seq_len))
         m = int(mode.rsplit("_m", 1)[1])
         step = make_pp_train_step(model, tx, mesh, state, num_microbatches=m)
     else:
@@ -84,28 +80,60 @@ def _lm_step(mode):
         else:
             mesh = make_mesh(data=len(jax.devices()))
             impl = "ring" if len(jax.devices()) > 1 else "full"
-        model = TransformerLM(vocab_size=g["vocab"], d_model=g["d_model"],
-                              n_layers=g["n_layers"], n_heads=g["n_heads"],
-                              max_seq_len=g["seq_len"], attention_impl=impl,
+        model = TransformerLM(vocab_size=vocab, d_model=d_model,
+                              n_layers=n_layers, n_heads=n_heads,
+                              max_seq_len=seq_len, attention_impl=impl,
                               axis_name="data")
-        state = create_lm_train_state(model, tx, mesh,
-                                      (g["batch"], g["seq_len"]))
+        state = create_lm_train_state(model, tx, mesh, (batch, seq_len))
         step = make_sp_train_step(model, tx, mesh,
                                   remat=mode.endswith("remat"))
     import numpy as np
     import jax.numpy as jnp
     tokens = jnp.asarray(np.random.default_rng(0).integers(
-        0, g["vocab"], size=(g["batch"], g["seq_len"])), jnp.int32)
+        0, vocab, size=(batch, seq_len)), jnp.int32)
     return state, lambda st, i: step(st, tokens)
 
 
-def _cnn_step(mode):
+def _cnn_step(mode, *, network="ResNet18", dataset="Cifar10",
+              per_device_batch=1024):
     import jax
-    from bench_suite import _build
-    state, step_fn, x, y, mask = _build(
-        "ResNet18", "Cifar10", 1024 * len(jax.devices()),
-        remat=mode.endswith("remat"), shard_update=mode.endswith("zero1"))
-    return state, lambda st, i: step_fn(st, x, y, mask, jax.random.key(i))
+    import jax.numpy as jnp
+    import numpy as np
+    from ps_pytorch_tpu.config import TrainConfig
+    from ps_pytorch_tpu.data.datasets import DATASET_SHAPES
+    from ps_pytorch_tpu.models import build_model
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel import (
+        create_train_state, make_mesh, make_train_step,
+    )
+    from ps_pytorch_tpu.parallel.zero import (
+        create_zero_train_state, make_zero_train_step,
+    )
+
+    remat = mode.endswith("remat")
+    batch = per_device_batch * len(jax.devices())
+    cfg = TrainConfig(dataset=dataset, network=network, batch_size=batch,
+                      lr=0.1, momentum=0.9, weight_decay=1e-4, remat=remat,
+                      shard_update=mode.endswith("zero1"))
+    mesh = make_mesh(data=len(jax.devices()))
+    model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype)
+    tx = build_optimizer(cfg)
+    h, w, c, ncls, _ = DATASET_SHAPES[dataset]
+    if cfg.shard_update:
+        state = create_zero_train_state(model, tx, mesh, (1, h, w, c),
+                                        jax.random.key(0))
+        step = make_zero_train_step(model, tx, mesh, state, remat=remat,
+                                    donate=True)
+    else:
+        state = create_train_state(model, tx, mesh, (1, h, w, c),
+                                   jax.random.key(0))
+        step = make_train_step(model, tx, mesh, state, remat=remat,
+                               donate=True)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(batch, h, w, c)).astype(np.float32))
+    y = jnp.asarray(rng.integers(0, ncls, batch).astype(np.int32))
+    mask = jnp.ones(mesh.shape["data"], jnp.float32)
+    return state, lambda st, i: step(st, x, y, mask, jax.random.key(i))
 
 
 def child_main(mode: str) -> int:

@@ -28,9 +28,9 @@ The acceptance drill for the end-to-end gradient-integrity plane
   ledger-free control aggregator fed EXACTLY the admitted sets reaches a
   BITWISE-equal parameter vector (screening out a contributor is
   indistinguishable from that contributor never submitting).
-- **bench**: the integrity_overhead row (bench_suite) — per-step digest +
-  screen cost for a 4-contributor round, gated < 2% by the regress
-  "integrity" family.
+
+The drill is judged by its own exit code: :func:`verdict` holds the whole
+pass rule over the result it writes (counts and flags, no clock).
 
 Usage:
     python ps_pytorch_tpu/tools/poison_drill.py --out RESILIENCE_r16.json
@@ -47,6 +47,11 @@ import time
 REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 if str(REPO) not in sys.path:  # runnable as a script from anywhere
     sys.path.insert(0, str(REPO))
+
+from ps_pytorch_tpu.tools import launch  # noqa: E402
+from ps_pytorch_tpu.tools.launch import (  # noqa: E402
+    free_port as _free_port, proc_logs as _logs,
+)
 
 
 # ---------------------------------------------------------------- workers
@@ -180,28 +185,14 @@ def _phase_bitwise(total_steps: int = 24) -> dict:
         control.drop_older_than(t)
     bitwise = bool(np.array_equal(p, p_ctl))
     snap = gi.snapshot()
-    return {"ok": bitwise and snap["integrity_quarantines"] >= 1
-            and snap["integrity_readmissions"] >= 1
-            and snap["integrity_outlier_rejects"] >= 3
-            and snap["integrity_quarantined"] == 0,
-            "bitwise_equal": bitwise, "total_steps": total_steps,
+    return {"bitwise_equal": bitwise, "total_steps": total_steps,
             "rejected_rounds": rejected_rounds, "counters": snap,
             "events": [list(e) for e in events]}
-
-
-def _phase_bench() -> dict:
-    """The integrity_overhead row at drill scale: per-step digest + screen
-    cost for a 4-contributor LeNet round, gated < 2% by the regress
-    family."""
-    import bench_suite
-    return bench_suite.bench_integrity_overhead(
-        "poison_drill_bench", 20, reps=2)
 
 
 # ---------------------------------------------------------------- driver
 
 def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
-    from ps_pytorch_tpu.tools import launch
     return launch.main([
         "launch", "--run-dir", str(run_dir), "--simulate", "4",
         "--devices-per-host", "1", "--port", str(port),
@@ -209,21 +200,6 @@ def _launch(run_dir: pathlib.Path, port: int, worker_args) -> int:
         "--cwd", str(REPO), "--wait", "--timeout", "420",
         "--", *worker_args,
     ])
-
-
-def _logs(run_dir: pathlib.Path, n: int = 4):
-    out = []
-    for i in range(n):
-        p = run_dir / f"proc_{i}.log"
-        out.append(p.read_text() if p.exists() else "")
-    return out
-
-
-def _free_port() -> int:
-    import socket
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def _final_losses(logs):
@@ -249,6 +225,72 @@ def _run_leg(base, name, args, fault_spec="", no_integrity=False,
         worker_args += ["--ef", "--ef-clip", str(args.ef_clip)]
     rc = _launch(d, _free_port(), worker_args)
     return rc, _logs(d)
+
+
+def _all_finished(phase: dict, n: int, below: float = float("inf")) -> bool:
+    """Every one of the n processes printed a finite FINAL loss < below."""
+    finals = list(phase.get("finals", {}).values())
+    return (phase.get("rc", 2) != 2 and len(finals) == n
+            and all(l == l and l < below for l in finals))
+
+
+def verdict(result: dict) -> list:
+    """The drill's whole pass rule: the invariants ``result`` violates,
+    empty when it passes. The poison must have been strong enough to matter
+    (the no-screen control diverged), the screen must have caught it
+    (quarantine, probation readmission, a flipped chunk refused) and nobody
+    may crash: every reject demotes to "absent this round"."""
+    integ = result.get("integrity", {})
+    counters = result.get("counters", {})
+    phases = result.get("phases", {})
+    poison, bitw = phases.get("poison", {}), phases.get("bitwise", {})
+    replay = bitw.get("counters", {})
+    n = result.get("processes", 4)
+    gap = integ.get("loss_gap", float("nan"))
+    rules = [
+        ("clean: every process finished with a finite loss < 10",
+         _all_finished(phases.get("clean", {}), n, below=10)),
+        ("poison: every process finished with a finite loss",
+         _all_finished(poison, n)),
+        ("poison: nobody crashed", integ.get("crashes", -1) == 0),
+        ("poison: contributor 2 was quarantined",
+         poison.get("quarantined_at_version", -1) >= 0
+         and integ.get("quarantines", 0) >= 1),
+        ("poison: contributor 2 was readmitted on probation",
+         poison.get("readmitted_at_version", -1) >= 0
+         and integ.get("readmissions", 0) >= 1),
+        ("poison: the screen rejected >= 3 payloads",
+         integ.get("screen_rejects", 0) >= 3),
+        ("poison: the wire digests caught >= 1 flipped chunk",
+         integ.get("wire_integrity_failures", 0) >= 1),
+        ("poison: the fault plane poisoned >= 3 gradients and flipped "
+         ">= 1 chunk",
+         counters.get("grad_poisons", 0) >= 3
+         and counters.get("payload_bitflips", 0) >= 1),
+        ("poison: final loss within 0.75 of the clean baseline",
+         gap == gap and gap < 0.75),
+        ("control: without the screen the run diverged",
+         phases.get("control", {}).get("rc", 2) != 2
+         and integ.get("control_diverged") is True),
+        ("bitwise: screening a contributor out equals its never "
+         "submitting, bit for bit",
+         result.get("bitwise_equal") is True
+         and bitw.get("bitwise_equal") is True),
+        ("bitwise: the replay quarantined, readmitted and ended with "
+         "nobody quarantined",
+         replay.get("integrity_quarantines", 0) >= 1
+         and replay.get("integrity_readmissions", 0) >= 1
+         and replay.get("integrity_outlier_rejects", 0) >= 3
+         and replay.get("integrity_quarantined", -1) == 0),
+    ]
+    if "poison_ef" in phases:   # RESILIENCE_r16 predates this leg
+        ef = phases["poison_ef"]
+        rules.append(
+            ("poison_ef: with error feedback on, still quarantined and "
+             "every process finished finite",
+             _all_finished(ef, n)
+             and ef.get("quarantined_at_version", -1) >= 0))
+    return [name for name, held in rules if not held]
 
 
 def main(argv=None) -> int:
@@ -295,9 +337,7 @@ def main(argv=None) -> int:
     # -- phase 1: clean baseline ----------------------------------------
     rc_c, logs_c = _run_leg(base, "clean", args)
     finals_c = _final_losses(logs_c)
-    p1_ok = rc_c != 2 and len(finals_c) == 4 and all(
-        l == l and l < 10 for l in finals_c.values())
-    print(f"PHASE clean ok={p1_ok} finals={finals_c}")
+    print(f"PHASE clean rc={rc_c} finals={finals_c}")
 
     # -- phase 2: poisoned contributor + bit-flipped wire, screen ON ----
     rc_p, logs_p = _run_leg(base, "poison", args, fault_spec=poison_spec)
@@ -323,21 +363,12 @@ def main(argv=None) -> int:
     loss_clean = finals_c.get(0, float("nan"))
     loss_poison = finals_p.get(0, float("nan"))
     loss_gap = abs(loss_poison - loss_clean)
-    p2_ok = (rc_p != 2 and len(finals_p) == 4
-             and all(l == l for l in finals_p.values())
-             and quarantined is not None and readmitted is not None
-             and s_quar >= 1 and s_readmit >= 1 and s_rejects >= 3
-             and s_wire >= 1 and poisons >= 3 and bitflips >= 1
-             and loss_gap == loss_gap and loss_gap < 0.75)
-    print(f"PHASE poison ok={p2_ok} quarantined={bool(quarantined)} "
+    print(f"PHASE poison rc={rc_p} quarantined={bool(quarantined)} "
           f"readmitted={bool(readmitted)} finals={finals_p} "
           f"screen_rejects={s_rejects} quarantines={s_quar} "
           f"readmissions={s_readmit} wire_failures={s_wire} "
           f"grad_poisons={poisons} bitflips={bitflips} "
           f"loss_gap={loss_gap:.4f}")
-    if not p2_ok:
-        print("\n\n".join(f"== proc_{i} ==\n{t[-3000:]}"
-                          for i, t in enumerate(logs_p)))
 
     # -- phase 2b: same poison with EF RE-ENABLED (+ --ef-clip) ---------
     # The PR 13 gap: unclamped EF turned one poisoned window into many
@@ -349,15 +380,9 @@ def main(argv=None) -> int:
     finals_e = _final_losses(logs_e)
     quarantined_ef = re.search(
         r"INTEGRITY quarantine contributor 2 at version (\d+)", logs_e[0])
-    p2b_ok = (rc_e != 2 and len(finals_e) == 4
-              and all(l == l for l in finals_e.values())
-              and quarantined_ef is not None)
-    print(f"PHASE poison_ef ok={p2b_ok} "
+    print(f"PHASE poison_ef rc={rc_e} "
           f"quarantined={bool(quarantined_ef)} finals={finals_e} "
           f"ef_clip={args.ef_clip}")
-    if not p2b_ok:
-        print("\n\n".join(f"== proc_{i} ==\n{t[-3000:]}"
-                          for i, t in enumerate(logs_e)))
 
     # -- phase 3: same poison, screen OFF — must diverge ----------------
     rc_n, logs_n = _run_leg(base, "control", args, fault_spec=poison_spec,
@@ -367,30 +392,22 @@ def main(argv=None) -> int:
     # Divergence = non-finite loss or an order of magnitude off baseline.
     control_diverged = bool(ctl_loss != ctl_loss or
                             abs(ctl_loss) > 10 * max(loss_clean, 0.1))
-    p3_ok = rc_n != 2 and control_diverged
-    print(f"PHASE control ok={p3_ok} diverged={control_diverged} "
+    print(f"PHASE control rc={rc_n} diverged={control_diverged} "
           f"finals={finals_n}")
 
     # -- phase 4: deterministic bitwise exclusion -----------------------
     p4 = _phase_bitwise()
-    print(f"PHASE bitwise ok={p4['ok']} bitwise_equal="
+    print(f"PHASE bitwise bitwise_equal="
           f"{p4['bitwise_equal']} counters={p4['counters']}")
 
-    # -- phase 5: digest + screen overhead ------------------------------
-    bench = _phase_bench()
-    p5_ok = bench["ok"]
-    print(f"PHASE bench ok={p5_ok} overhead_frac={bench['overhead_frac']}")
-
     # -- artifact -------------------------------------------------------
-    ok = bool(p1_ok and p2_ok and p2b_ok and p3_ok and p4["ok"] and p5_ok)
     art = {
         "round": 16,
         "platform": "cpu",
         "scenario": "poisoned_contributor_quarantine_readmit + "
                     "bitflip_wire_digests + no_screen_divergence_control "
-                    "+ bitwise_exclusion + integrity_overhead_bench",
+                    "+ bitwise_exclusion",
         "processes": 4,
-        "ok": ok,
         "bitwise_equal": p4["bitwise_equal"],
         "counters": {"grad_poisons": int(poisons),
                      "payload_bitflips": int(bitflips)},
@@ -405,14 +422,10 @@ def main(argv=None) -> int:
             "loss_poisoned": loss_poison,
             "loss_gap": round(loss_gap, 4),
             "control_diverged": control_diverged,
-            "overhead_frac": bench["overhead_frac"],
-            "bench": {"baseline_s": bench["baseline_s"],
-                      "integrity_s": bench["integrity_s"],
-                      "overhead_frac": bench["overhead_frac"]},
         },
         "phases": {
-            "clean": {"ok": p1_ok, "rc": rc_c, "finals": finals_c},
-            "poison": {"ok": p2_ok, "rc": rc_p, "finals": finals_p,
+            "clean": {"rc": rc_c, "finals": finals_c},
+            "poison": {"rc": rc_p, "finals": finals_p,
                        "poison_step": args.poison_step,
                        "poison_steps": args.poison_steps,
                        "max_steps": args.max_steps,
@@ -421,17 +434,24 @@ def main(argv=None) -> int:
                        "readmitted_at_version":
                            int(readmitted.group(1)) if readmitted else -1,
                        "per_process_stats": stats},
-            "poison_ef": {"ok": p2b_ok, "rc": rc_e, "finals": finals_e,
+            "poison_ef": {"rc": rc_e, "finals": finals_e,
                           "ef_clip": args.ef_clip,
                           "quarantined_at_version":
                               int(quarantined_ef.group(1))
                               if quarantined_ef else -1},
-            "control": {"ok": p3_ok, "rc": rc_n, "finals": finals_n,
+            "control": {"rc": rc_n, "finals": finals_n,
                         "diverged": control_diverged},
             "bitwise": p4,
-            "bench": bench,
         },
     }
+    violations = verdict(art)
+    ok = art["ok"] = not violations
+    for v in violations:
+        print(f"VIOLATED {v}")
+    if not ok:
+        for name, leg in (("poison", logs_p), ("poison_ef", logs_e)):
+            print("\n\n".join(f"== {name} proc_{i} ==\n{t[-3000:]}"
+                              for i, t in enumerate(leg)))
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1)
     print(f"WROTE {args.out} ok={ok}")

@@ -26,10 +26,10 @@ Bitwise evidence: the same seeded request routed repeatedly (landing on
 different replicas) must return identical tokens — cross-replica decode
 determinism, the serving twin of the trainers' bitwise-equality drills.
 
-The artifact carries BOTH regress contracts over RESILIENCE_r*.json:
-the ``resilience`` family's (top-level ``ok``/``bitwise_equal``,
-``counters.kv_giveups == 0``) and the new ``router`` family's (see
-tools/regress.py _check_router).
+The drill is judged by its own exit code: :func:`verdict` holds the whole
+pass rule over the result it writes. The hedge phase compares two of the
+drill's own runs under a stall it injects; no rule reads a clock against a
+budget from outside.
 
 Usage:
     python ps_pytorch_tpu/tools/router_drill.py --out RESILIENCE_r15.json
@@ -154,6 +154,42 @@ def _bitwise_probe(router_url: str, tries: int = 4) -> bool:
     return all(t == outs[0] for t in outs) and outs[0] is not None
 
 
+def verdict(result: dict) -> list:
+    """The drill's whole pass rule: the invariants ``result`` violates,
+    empty when it passes. A replica must really have died under load with
+    no client the wiser, the rolling reload must fail no request and leave
+    every replica on the new step, and hedging must beat no hedging under
+    the injected straggler."""
+    router = result.get("router", {})
+    kill, reload_, hedge = (router.get(k, {})
+                            for k in ("kill", "reload", "hedge"))
+    n = router.get("replicas", 3)
+    avail, ratio = kill.get("availability"), hedge.get("p99_ratio")
+    rules = [
+        ("bitwise: one seeded request returns the same tokens from any "
+         "replica", result.get("bitwise_equal") is True),
+        ("kill: a replica was SIGKILLed under load",
+         kill.get("replica_kills", 0) >= 1),
+        ("kill: zero client 5xx", kill.get("failed_5xx", -1) == 0),
+        ("kill: availability at or above its floor",
+         avail is not None and avail >= AVAILABILITY_FLOOR),
+        ("reload: requests flowed during the roll and none failed",
+         reload_.get("failed_5xx", -1) == 0
+         and reload_.get("requests", 0) > 0),
+        ("reload: at least 3 replicas, every one of them rolled",
+         n >= 3 and reload_.get("replicas_rolled", 0) == n),
+        ("reload: every replica serves the new model step",
+         reload_.get("model_step_advanced") is True),
+        ("hedge: hedged p99 below un-hedged p99 under the pulsed straggler",
+         ratio is not None and ratio < 1.0),
+        ("hedge: at least one hedge fired", hedge.get("hedges", 0) >= 1),
+        # records written before this key existed do not hold it
+        ("hedge: zero client 5xx with hedging on",
+         hedge.get("failed_5xx", 0) == 0),
+    ]
+    return [name for name, held in rules if not held]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--out", default="RESILIENCE_r15.json")
@@ -204,8 +240,8 @@ def main(argv=None) -> int:
     art = {"round": 15, "platform": "cpu",
            "scenario": "router_replica_kill_failover + rolling_reload + "
                        "hedged_tail_latency",
-           "processes": n, "ok": False, "bitwise_equal": False,
-           "counters": {"kv_giveups": 0, "replica_kills": 0},
+           "processes": n, "bitwise_equal": False,
+           "counters": {"replica_kills": 0},
            "router": {"replicas": n}}
     try:
         router.start()
@@ -228,11 +264,8 @@ def main(argv=None) -> int:
         killed = (victim_rc == -signal.SIGKILL
                   or "FAULT replica_kill" in victim.log())
         art["counters"]["replica_kills"] = int(killed)
-        kill_ok = (killed and stats_kill["failed_5xx"] == 0
-                   and stats_kill["availability"] is not None
-                   and stats_kill["availability"] >= AVAILABILITY_FLOOR)
         art["router"]["kill"] = {
-            "ok": kill_ok, "replica_kills": int(killed),
+            "replica_kills": int(killed),
             "victim": victim_id, "victim_rc": victim_rc,
             "availability": stats_kill["availability"],
             "availability_floor": AVAILABILITY_FLOOR,
@@ -243,7 +276,7 @@ def main(argv=None) -> int:
             "retries": router.counters["retries"],
             "latency_p99_ms": stats_kill["latency_p99_ms"],
         }
-        print(f"PHASE kill ok={kill_ok} killed={killed} "
+        print(f"PHASE kill killed={killed} "
               f"availability={stats_kill['availability']:.4f} "
               f"5xx={stats_kill['failed_5xx']} "
               f"retries={router.counters['retries']}", flush=True)
@@ -271,12 +304,7 @@ def main(argv=None) -> int:
         for b in view.poll():
             steps[b.id] = _healthz(b.url).get("model_step")
         advanced = len(steps) == n and all(s == 2 for s in steps.values())
-        reload_ok = (load_out.get("failed_5xx", -1) == 0
-                     and load_out.get("requests", 0) > 0
-                     and sum(r.get("ok", False) for r in roll) == n
-                     and advanced)
         art["router"]["reload"] = {
-            "ok": reload_ok,
             "replicas_rolled": sum(r.get("ok", False) for r in roll),
             "model_step_advanced": advanced,
             "steps_after": steps, "from_step": 1, "to_step": 2,
@@ -286,7 +314,7 @@ def main(argv=None) -> int:
             "status_counts": load_out.get("status_counts", {}),
             "results": roll,
         }
-        print(f"PHASE reload ok={reload_ok} rolled={roll} steps={steps} "
+        print(f"PHASE reload rolled={roll} steps={steps} "
               f"load_5xx={load_out.get('failed_5xx')}", flush=True)
 
         # -- phase C: hedged vs un-hedged p99 under a pulsing straggler --
@@ -326,25 +354,23 @@ def main(argv=None) -> int:
         p99_yes = hedged["latency_p99_ms"]
         ratio = (p99_yes / p99_no
                  if p99_no and p99_yes and p99_no > 0 else None)
-        hedge_ok = (ratio is not None and ratio < 1.0 and hedges >= 1
-                    and hedged["failed_5xx"] == 0)
         art["router"]["hedge"] = {
-            "ok": hedge_ok, "hedge_s": args.hedge_s,
+            "hedge_s": args.hedge_s,
             "p99_no_hedge_ms": p99_no, "p99_hedge_ms": p99_yes,
             "p99_ratio": None if ratio is None else round(ratio, 4),
             "hedges": hedges,
+            "failed_5xx": hedged["failed_5xx"],
             "hedge_wins": router.counters["hedge_wins"],
             "hedge_cancelled": router.counters["hedge_cancelled"],
             "no_hedge_availability": no_hedge["availability"],
             "hedge_availability": hedged["availability"],
         }
-        print(f"PHASE hedge ok={hedge_ok} p99 {p99_no}ms -> {p99_yes}ms "
+        print(f"PHASE hedge p99 {p99_no}ms -> {p99_yes}ms "
               f"ratio={ratio} hedges={hedges}", flush=True)
 
         art["counters"].update(
             {f"router_{k}": v for k, v in router.counters.items()})
         art["counters"]["backend_ejections"] = view.ejections
-        art["ok"] = bool(bitwise and kill_ok and reload_ok and hedge_ok)
     finally:
         try:
             router.stop()
@@ -352,6 +378,10 @@ def main(argv=None) -> int:
             pass
         for rep in replicas.values():
             rep.stop()
+    violations = verdict(art)
+    art["ok"] = not violations
+    for v in violations:
+        print(f"VIOLATED {v}")
     with open(args.out, "w") as f:
         json.dump(art, f, indent=1)
     print(f"WROTE {args.out} ok={art['ok']}")

@@ -32,7 +32,6 @@ m4.2xlarge tables.
 import argparse
 import json
 import os
-import socket
 import sys
 import tempfile
 import time
@@ -42,12 +41,6 @@ from ps_pytorch_tpu.tools import analyze as analyze_mod
 from ps_pytorch_tpu.tools import launch as launch_mod
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def _train_argv(mode: str, n: int, args) -> List[str]:
@@ -108,7 +101,7 @@ def run_cell(mode: str, n: int, args, work: str):
     cell_t0 = time.time()
     rc = launch_mod.main([
         "launch", "--run-dir", run_dir, "--simulate", str(n),
-        "--devices-per-host", "1", "--port", str(_free_port()),
+        "--devices-per-host", "1", "--port", str(launch_mod.free_port()),
         "--entry", os.path.join(REPO, "train.py"), "--cwd", REPO,
         "--wait", "--timeout", str(args.timeout),
         "--",

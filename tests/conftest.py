@@ -44,10 +44,16 @@ def rng():
     return np.random.default_rng(0)
 
 
-def free_port() -> int:
-    """An OS-assigned free TCP port for launch-driven multi-process tests
-    (single definition — was copy-pasted per test file)."""
-    import socket
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+@pytest.fixture()
+def committed_record():
+    """-> load(name): a committed root-level JSON record, e.g. a drill's
+    RESILIENCE_r*.json, parsed afresh on every call."""
+    import json
+    import pathlib
+    root = pathlib.Path(__file__).resolve().parents[1]
+    return lambda name: json.loads((root / name).read_text())
+
+
+# An OS-assigned free TCP port for launch-driven multi-process tests: the
+# launcher's own, imported by the test files from here.
+from ps_pytorch_tpu.tools.launch import free_port  # noqa: E402,F401

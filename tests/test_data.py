@@ -61,11 +61,23 @@ def test_random_crop_vectorized_matches_loop(rng):
 
 
 def test_loader_throughput_probe():
-    """bench_suite's loader-only bench runs and reports a positive rate."""
-    import bench_suite
-    r = bench_suite.bench_input_pipeline("input_pipeline", "synthetic", 64,
-                                         steps=5)
-    assert r["loader_images_per_sec"] > 0
+    """The persistent loader keeps handing out whole augmented batches across
+    epoch turnovers: shapes, dtypes and the labels' range, no rate."""
+    from ps_pytorch_tpu.data.augment import input_norm_for
+    from ps_pytorch_tpu.data.datasets import load_arrays
+    cfg = TrainConfig(dataset="synthetic", network="ResNet18", batch_size=512)
+    x, y = load_arrays(cfg.dataset, cfg.data_dir, train=True, seed=0)
+    loader = DataLoader(x[:2048], y[:2048], cfg.batch_size, cfg.dataset, train=True,
+                        seed=0,
+                        device_normalize=input_norm_for(cfg) is not None)
+    steps = 2 * len(loader) + 1          # crosses two epoch boundaries
+    seen = 0
+    for _ in range(steps):
+        xb, yb = loader.next_batch()
+        assert xb.shape == (512, 32, 32, 3) and yb.shape == (512,)
+        assert yb.dtype == np.int32 and 0 <= yb.min() and yb.max() < 10
+        seen += len(xb)
+    assert seen == steps * 512
 
 
 def test_uint8_normalize_matches_float_path():

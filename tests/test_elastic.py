@@ -505,16 +505,14 @@ def test_analyze_membership_mode(tmp_path, capsys):
     assert out["summary"]["counts"]["elected"] == 1
 
 
-def test_regress_elastic_family():
-    from ps_pytorch_tpu.tools.regress import compare
-    good = {"scenario": "elastic_drill", "ok": True, "bitwise_equal": True,
-            "counters": {"kv_giveups": 0},
-            "elastic": {"elections": 1, "membership_changes": 2,
-                        "final_epoch": 2}}
-    assert compare("elastic", None, good)["ok"]
-    assert not compare("elastic", None,
-                       dict(good, elastic={"elections": 0}))["ok"]
-    assert not compare("elastic", None, {"ok": True})["ok"]   # no section
+def test_elastic_drill_verdict(committed_record):
+    from ps_pytorch_tpu.tools.elastic_drill import verdict
+    good = committed_record("RESILIENCE_r11.json")
+    assert verdict(good) == []
+    # a drill in which nobody was elected proved nothing
+    assert verdict(dict(good, elastic={"elections": 0}))
+    # no sections: a result's own "ok" is not believed
+    assert verdict({"ok": True})
 
 
 def test_checkpoint_meta_carries_leader_epoch(tmp_path):

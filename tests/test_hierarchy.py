@@ -595,34 +595,31 @@ def test_multislice_hier_topology_trains_and_checkpoints(tmp_path):
     t2.aggregator.load_ef_state(extra["ef"])   # shape-compatible reload
 
 
-# ---- regress family ----
+# ---- the drill's verdict ----
 
-def test_regress_hierarchy_family():
-    from ps_pytorch_tpu.tools.regress import compare
-    good = {"scenario": "hierarchy_drill", "ok": True, "bitwise_equal": True,
-            "hierarchy": {"partitions": 1, "regrafts": 1, "degraded_steps": 3,
-                          "bench": {"speedup": 1.5}}}
-    assert compare("hierarchy", None, good)["ok"]
-    # every lifecycle floor gates independently
+def test_hierarchy_drill_verdict(committed_record):
+    from ps_pytorch_tpu.tools.hierarchy_drill import verdict
+    good = committed_record("RESILIENCE_r14.json")
+    assert verdict(good) == []
+    # every lifecycle floor is demanded independently
     for key in ("partitions", "regrafts", "degraded_steps"):
         bad = dict(good, hierarchy=dict(good["hierarchy"], **{key: 0}))
-        assert not compare("hierarchy", None, bad)["ok"]
-    # a tree that fails to beat the flat star is a regression, not a wash
+        assert verdict(bad) == [f"partition: {key} >= 1"]
+    # a CPU clock is no part of the rule: a tree that did not beat the flat
+    # star in the record's old bench section is no violation
     tied = dict(good, hierarchy=dict(good["hierarchy"],
                                      bench={"speedup": 1.0}))
-    assert not compare("hierarchy", None, tied)["ok"]
-    assert not compare("hierarchy", None, dict(good, bitwise_equal=False))["ok"]
-    assert not compare("hierarchy", None, {"ok": True})["ok"]   # no section
+    assert verdict(tied) == []
+    assert verdict(dict(good, bitwise_equal=False))
+    assert verdict({"ok": True})   # no sections; its own "ok" is not believed
 
 
-def test_regress_gates_committed_hierarchy_artifact():
-    """The committed round-14 artifact must hold the line under its own
-    family gate — the drill's partition/degrade/regraft evidence plus the
-    bench speedup are load-bearing."""
-    import os
-
-    from ps_pytorch_tpu.tools.regress import run_gate
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    art = os.path.join(repo, "RESILIENCE_r14.json")
-    out = run_gate("hierarchy", art, repo=repo)
-    assert out["ok"], out
+def test_hierarchy_drill_verdict_passes_committed_record(committed_record):
+    """The committed round-14 record must pass the verdict of the drill that
+    wrote it, with or without its old bench sections."""
+    from ps_pytorch_tpu.tools.hierarchy_drill import verdict
+    rec = committed_record("RESILIENCE_r14.json")
+    assert "bench" in rec["hierarchy"] and "bench" in rec["phases"]
+    assert verdict(rec) == []
+    del rec["hierarchy"]["bench"], rec["phases"]["bench"]
+    assert verdict(rec) == []

@@ -352,50 +352,41 @@ def test_faulty_kv_truncate_and_prefix_scope():
     assert inj.counters["payload_truncates"] == 1
 
 
-# ---- regress family: integrity gate ----
+# ---- the drill's verdict ----
 
-def _good_integrity_artifact():
-    return {"scenario": "poison_drill", "ok": True, "bitwise_equal": True,
-            "integrity": {"quarantines": 1, "readmissions": 1,
-                          "screen_rejects": 5, "wire_integrity_failures": 2,
-                          "crashes": 0, "control_diverged": True,
-                          "overhead_frac": 0.004}}
-
-
-def test_regress_integrity_family():
-    from ps_pytorch_tpu.tools.regress import compare
-    good = _good_integrity_artifact()
-    assert compare("integrity", None, good)["ok"]
-    # every lifecycle floor gates independently
+def test_poison_drill_verdict(committed_record):
+    from ps_pytorch_tpu.tools.poison_drill import verdict
+    good = committed_record("RESILIENCE_r16.json")
+    assert verdict(good) == []
+    # every lifecycle floor is demanded independently
     for key in ("quarantines", "readmissions", "screen_rejects",
                 "wire_integrity_failures"):
         bad = dict(good, integrity=dict(good["integrity"], **{key: 0}))
-        assert not compare("integrity", None, bad)["ok"]
+        assert len(verdict(bad)) == 1, key
     # a crash is never an acceptable way to reject a payload
     crashed = dict(good, integrity=dict(good["integrity"], crashes=1))
-    assert not compare("integrity", None, crashed)["ok"]
+    assert verdict(crashed) == ["poison: nobody crashed"]
     # a control run that did NOT diverge means the poison proved nothing
     weak = dict(good, integrity=dict(good["integrity"],
                                      control_diverged=False))
-    assert not compare("integrity", None, weak)["ok"]
-    # the digest+screen budget is absolute, not relative
+    assert verdict(weak) == ["control: without the screen the run diverged"]
+    # a CPU clock is no part of the rule: the record's old overhead is ignored
     slow = dict(good, integrity=dict(good["integrity"], overhead_frac=0.05))
-    assert not compare("integrity", None, slow)["ok"]
-    assert not compare("integrity", None, dict(good, ok=False))["ok"]
-    assert not compare("integrity", None, {"ok": True})["ok"]  # no section
+    assert verdict(slow) == []
+    assert verdict({"ok": True})   # no sections; its own "ok" is not believed
 
 
-def test_regress_gates_committed_integrity_artifact():
-    """The committed round-16 artifact must hold the line under its own
-    family gate — quarantine + readmission + wire-digest evidence, the
-    diverging no-screen control, and the <2% overhead are load-bearing."""
-    import os
-
-    from ps_pytorch_tpu.tools.regress import run_gate
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    art = os.path.join(repo, "RESILIENCE_r16.json")
-    out = run_gate("integrity", art, repo=repo)
-    assert out["ok"], out
+def test_poison_drill_verdict_passes_committed_record(committed_record):
+    """The committed round-16 record must pass the verdict of the drill that
+    wrote it, with or without its old bench sections (it predates the
+    poison_ef leg, which the verdict judges only where it was run)."""
+    from ps_pytorch_tpu.tools.poison_drill import verdict
+    rec = committed_record("RESILIENCE_r16.json")
+    assert "bench" in rec["integrity"] and "poison_ef" not in rec["phases"]
+    assert verdict(rec) == []
+    del rec["integrity"]["bench"], rec["integrity"]["overhead_frac"]
+    del rec["phases"]["bench"]
+    assert verdict(rec) == []
 
 
 def test_poison_drill_bitwise_phase():
@@ -405,7 +396,6 @@ def test_poison_drill_bitwise_phase():
     bitwise-identical parameters."""
     from ps_pytorch_tpu.tools.poison_drill import _phase_bitwise
     r = _phase_bitwise()
-    assert r["ok"], r
     assert r["bitwise_equal"]
     kinds = [e[0] for e in r["events"]]
     assert "quarantine" in kinds and "readmit" in kinds

@@ -544,59 +544,43 @@ def test_trainer_runs_over_replicated_kv(tmp_path):
     assert t._kvrep.healthy_count() == 3
 
 
-# ---- regress family: kvrep gate ----
+# ---- the drill's verdict ----
 
-def _good_kvrep_artifact():
-    return {"scenario": "kv_backend_kill_wipe_quorum", "ok": True,
-            "bitwise_equal": True,
-            "kvrep": {"backend_kills": 2, "backend_wipes": 3,
-                      "rejoins": 4, "resyncs": 4,
-                      "train": {"giveups": 0, "resync_tag_equal": True},
-                      "serve": {"availability": 1.0,
-                                "availability_floor": 1.0, "failed_5xx": 0},
-                      "overhead": {"overhead_frac": 0.011}}}
+def test_kvrep_drill_verdict(committed_record):
+    from ps_pytorch_tpu.tools.kvrep_drill import verdict
+    good = committed_record("RESILIENCE_r17.json")
+    assert verdict(good) == []
 
+    def phase(name, **kw):
+        return dict(good, kvrep=dict(
+            good["kvrep"], **{name: dict(good["kvrep"][name], **kw)}))
 
-def test_regress_kvrep_family():
-    from ps_pytorch_tpu.tools.regress import compare
-    good = _good_kvrep_artifact()
-    assert compare("kvrep", None, good)["ok"]
-    # every lifecycle floor gates independently
+    # every lifecycle floor is demanded independently
     for key in ("backend_kills", "backend_wipes", "rejoins", "resyncs"):
         bad = dict(good, kvrep=dict(good["kvrep"], **{key: 0}))
-        assert not compare("kvrep", None, bad)["ok"]
+        assert verdict(bad) == [f"{key} >= 1"]
     # a retry giveup means the quorum failed to mask the outage
-    gave = dict(good, kvrep=dict(
-        good["kvrep"], train=dict(good["kvrep"]["train"], giveups=1)))
-    assert not compare("kvrep", None, gave)["ok"]
+    assert verdict(phase("train", giveups=1)) == [
+        "train: zero retry give-ups"]
     # the reborn backend must come back to key-by-key tag equality
-    lag = dict(good, kvrep=dict(
-        good["kvrep"],
-        train=dict(good["kvrep"]["train"], resync_tag_equal=False)))
-    assert not compare("kvrep", None, lag)["ok"]
-    # serving availability gates against the floor the artifact recorded
-    dip = dict(good, kvrep=dict(
-        good["kvrep"],
-        serve=dict(good["kvrep"]["serve"], availability=0.99)))
-    assert not compare("kvrep", None, dip)["ok"]
-    err = dict(good, kvrep=dict(
-        good["kvrep"], serve=dict(good["kvrep"]["serve"], failed_5xx=2)))
-    assert not compare("kvrep", None, err)["ok"]
-    # the replication budget is absolute, not relative
-    slow = dict(good, kvrep=dict(
-        good["kvrep"], overhead={"overhead_frac": 0.05}))
-    assert not compare("kvrep", None, slow)["ok"]
-    assert not compare("kvrep", None, dict(good, ok=False))["ok"]
-    assert not compare("kvrep", None, {"ok": True})["ok"]  # no section
+    assert verdict(phase("train", resync_tag_equal=False)) == [
+        "train: the reborn backend is tag-equal key by key"]
+    # serving availability stays 1.00 through the wipe, with zero 5xx
+    assert verdict(phase("serve", availability=0.99)) == [
+        "serve: availability 1.00 through the wipe"]
+    assert verdict(phase("serve", failed_5xx=2)) == [
+        "serve: zero client 5xx"]
+    # a CPU clock is no part of the rule: the record's old overhead is ignored
+    assert verdict(phase("overhead", overhead_frac=0.05)) == []
+    assert verdict({"ok": True})   # no sections; its own "ok" is not believed
 
 
-def test_regress_gates_committed_kvrep_artifact():
-    """The committed round-17 artifact must hold the line under its own
-    family gate — the backend kill+wipe happened, every client rejoined
-    and resynced it, training/serving stayed clean, and the wire-bench
-    replication overhead is under the 5% budget."""
-    from ps_pytorch_tpu.tools.regress import run_gate
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    art = os.path.join(repo, "RESILIENCE_r17.json")
-    out = run_gate("kvrep", art, repo=repo)
-    assert out["ok"], out
+def test_kvrep_drill_verdict_passes_committed_record(committed_record):
+    """The committed round-17 record must pass the verdict of the drill that
+    wrote it, with or without its old overhead section."""
+    from ps_pytorch_tpu.tools.kvrep_drill import verdict
+    rec = committed_record("RESILIENCE_r17.json")
+    assert "overhead" in rec["kvrep"]
+    assert verdict(rec) == []
+    del rec["kvrep"]["overhead"]
+    assert verdict(rec) == []

@@ -2,8 +2,8 @@
 HTTP roundtrip, training-health watchdog grammar and detectors, flight
 recorder dump/load + analyze flight, the trainer E2E (injected NaN gradient
 -> watchdog halt -> checkpoint + flight dump within one step), cross-process
-trace stitching (wire corr -> Chrome flow events), serving /healthz +
-/metrics through the real HTTP stack, and the bench regression gate."""
+trace stitching (wire corr -> Chrome flow events), and serving /healthz +
+/metrics through the real HTTP stack."""
 
 import json
 import math
@@ -451,136 +451,3 @@ def test_serving_healthz_and_metrics_http(tmp_path):
     assert samples["serve_queue_wait_s_count"] >= 1
     assert samples["slo_violations_total"] == 0
     assert "slo_compliance" in samples and "slo_burn_rate" in samples
-
-
-# ---- tools/regress.py: the bench regression gate ----
-
-def _wire_rows(publish_s):
-    return [{"config": "wire_overlapped_8mb", "publish_s": publish_s,
-             "read_s": 0.10, "total_s": publish_s + 0.10}]
-
-
-def _write(path, rows):
-    with open(path, "w") as f:
-        if isinstance(rows, dict):
-            json.dump(rows, f)
-        else:
-            f.write("\n".join(json.dumps(r) for r in rows) + "\n")
-
-
-def test_regress_gate_pass_and_fail(tmp_path):
-    from ps_pytorch_tpu.tools.regress import main as regress_main, run_gate
-
-    base = tmp_path / "BENCH_WIRE_r01.json"
-    _write(base, _wire_rows(0.100))
-    ok_cand = tmp_path / "cand_ok.json"
-    _write(ok_cand, _wire_rows(0.110))          # +10% < 20% tol
-    bad_cand = tmp_path / "cand_bad.json"
-    _write(bad_cand, _wire_rows(0.150))         # +50% regression
-
-    v = run_gate("wire", str(ok_cand), repo=str(tmp_path))
-    assert v["ok"] is True and v["baseline"] == "BENCH_WIRE_r01.json"
-    v = run_gate("wire", str(bad_cand), repo=str(tmp_path))
-    assert v["ok"] is False
-    m = v["configs"]["wire_overlapped_8mb"]["metrics"]["publish_s"]
-    assert m["ok"] is False and m["ratio"] == pytest.approx(1.5)
-    # Non-zero exit is the gate's contract.
-    assert regress_main(["wire", str(bad_cand),
-                         "--repo", str(tmp_path)]) == 1
-    out = tmp_path / "REGRESS_r02.json"
-    assert regress_main(["wire", str(ok_cand), "--repo", str(tmp_path),
-                         "--out", str(out)]) == 0
-    assert json.load(open(out))["ok"] is True
-
-
-def test_regress_missing_config_and_higher_better(tmp_path):
-    from ps_pytorch_tpu.tools.regress import run_gate
-
-    base = tmp_path / "BENCH_SERVE_r01.json"
-    _write(base, [{"config": "serve_batched_8", "tokens_per_sec": 1000.0,
-                   "ttft_p99_ms": 50.0, "latency_p99_ms": 80.0}])
-    # Dropping a baseline config from the candidate is a failure.
-    cand = tmp_path / "cand.json"
-    _write(cand, [{"config": "serve_other", "tokens_per_sec": 1000.0}])
-    v = run_gate("serve", str(cand), repo=str(tmp_path))
-    assert v["ok"] is False
-    assert v["configs"]["serve_batched_8"]["ok"] is False
-    assert v["configs"]["serve_other"]["note"].startswith("new config")
-    # tokens_per_sec is higher-is-better: a 50% drop fails, a rise passes.
-    _write(cand, [{"config": "serve_batched_8", "tokens_per_sec": 500.0,
-                   "ttft_p99_ms": 50.0, "latency_p99_ms": 80.0}])
-    assert run_gate("serve", str(cand), repo=str(tmp_path))["ok"] is False
-    _write(cand, [{"config": "serve_batched_8", "tokens_per_sec": 2000.0,
-                   "ttft_p99_ms": 50.0, "latency_p99_ms": 80.0}])
-    assert run_gate("serve", str(cand), repo=str(tmp_path))["ok"] is True
-
-
-def test_regress_wire_codec_family(tmp_path):
-    """wire_codec family: gates the homomorphic-codec win rows on their own
-    ok bits, the topk wire-bytes floor, and int8lat bitwise identity — no
-    prior round needed (the bars travel in the artifact)."""
-    from ps_pytorch_tpu.tools.regress import run_gate
-
-    def rows(topk_ratio=45.0, int8_bitwise=True, int8_ok=True):
-        return [
-            {"config": "wire_codec_blosc_24mb", "wire_mb": 90.0},
-            {"config": "wire_codec_win_topk_24mb", "wire_ratio": topk_ratio,
-             "bitwise_identical": True, "ok": topk_ratio >= 2.0},
-            {"config": "wire_codec_win_int8lat_24mb", "wire_ratio": 3.5,
-             "bitwise_identical": int8_bitwise, "ok": int8_ok},
-        ]
-
-    cand = tmp_path / "cand.json"
-    _write(cand, rows())
-    assert run_gate("wire_codec", str(cand), repo=str(tmp_path))["ok"]
-    # topk below the 2x wire floor fails even with its own ok forced true.
-    bad = rows(topk_ratio=1.5)
-    bad[1]["ok"] = True
-    _write(cand, bad)
-    v = run_gate("wire_codec", str(cand), repo=str(tmp_path))
-    assert not v["ok"]
-    m = v["configs"]["wire_codec_win_topk_24mb"]["metrics"]["wire_ratio"]
-    assert m["ok"] is False and m["floor"] == 2.0
-    # A lossy "lossless" int8lat path is a broken path.
-    _write(cand, rows(int8_bitwise=False, int8_ok=False))
-    v = run_gate("wire_codec", str(cand), repo=str(tmp_path))
-    assert not v["ok"]
-    assert v["configs"]["wire_codec_win_int8lat_24mb"]["metrics"][
-        "bitwise_identical"]["ok"] is False
-    # An artifact without codec win rows cannot pass this family.
-    _write(cand, [{"config": "wire_overlapped_8mb", "publish_s": 0.1}])
-    assert not run_gate("wire_codec", str(cand), repo=str(tmp_path))["ok"]
-
-
-def test_regress_resilience_and_ops_families(tmp_path):
-    from ps_pytorch_tpu.tools.regress import run_gate
-
-    res = tmp_path / "RESILIENCE_r01.json"
-    _write(res, {"bitwise_equal": True, "ok": True,
-                 "counters": {"kv_giveups": 0}})
-    assert run_gate("resilience", str(res), repo=str(tmp_path))["ok"]
-    _write(res, {"bitwise_equal": True, "ok": True,
-                 "counters": {"kv_giveups": 2}})
-    assert not run_gate("resilience", str(res), repo=str(tmp_path))["ok"]
-
-    ops = tmp_path / "BENCH_OPS_r01.json"
-    _write(ops, [{"config": "ops_overhead", "overhead_frac": 0.009,
-                  "ok": True}])
-    assert run_gate("ops", str(ops), repo=str(tmp_path))["ok"]
-    _write(ops, [{"config": "ops_overhead", "overhead_frac": 0.05,
-                  "ok": False}])
-    assert not run_gate("ops", str(ops), repo=str(tmp_path))["ok"]
-
-
-def test_regress_all_on_committed_artifacts(tmp_path):
-    from ps_pytorch_tpu.tools.regress import run_all
-
-    # Two wire rounds within tolerance + a resilience artifact -> ok.
-    _write(tmp_path / "BENCH_WIRE_r01.json", _wire_rows(0.100))
-    _write(tmp_path / "BENCH_WIRE_r02.json", _wire_rows(0.105))
-    _write(tmp_path / "RESILIENCE_r01.json",
-           {"bitwise_equal": True, "ok": True, "counters": {}})
-    verdict = run_all(repo=str(tmp_path))
-    assert verdict["ok"] is True
-    assert verdict["families"]["wire"]["baseline"] == "BENCH_WIRE_r01.json"
-    assert "skipped" in verdict["families"]["serve"]["note"]

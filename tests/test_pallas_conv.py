@@ -135,13 +135,29 @@ def test_bottleneck_pallas_param_tree_matches_xla():
 
 @pytest.mark.slow
 def test_pallas_conv_under_8dev_spmd_step():
-    """The resnet18_pallas_conv suite row's exact path: conv3x3_op's
-    custom VJP inside the jitted masked-psum SPMD train step over the
-    8-device mesh (shard_map + donate + optimizer). A failure here would
-    otherwise first surface as a burned row budget on the chip."""
-    import bench_suite
-    state, step_fn, x, y, mask = bench_suite._build(
-        "ResNet18", "synthetic", 16, conv_impl="pallas", dtype="float32")
+    """conv3x3_op's custom VJP inside the jitted masked-psum SPMD train
+    step over the 8-device mesh (shard_map + donate + optimizer): the path
+    ``--conv-impl pallas`` takes through train.py."""
+    from ps_pytorch_tpu.config import TrainConfig
+    from ps_pytorch_tpu.models import build_model
+    from ps_pytorch_tpu.optim import build_optimizer
+    from ps_pytorch_tpu.parallel import (
+        create_train_state, make_mesh, make_train_step,
+    )
+    cfg = TrainConfig(dataset="synthetic", network="ResNet18", batch_size=16,
+                      lr=0.1, momentum=0.9, compute_dtype="float32",
+                      conv_impl="pallas")
+    mesh = make_mesh(data=len(jax.devices()))
+    model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype,
+                        conv_impl=cfg.conv_impl)
+    tx = build_optimizer(cfg)
+    state = create_train_state(model, tx, mesh, (1, 32, 32, 3),
+                               jax.random.key(0))
+    step_fn = make_train_step(model, tx, mesh, state, donate=True)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(16, 32, 32, 3)).astype(np.float32))
+    y = jnp.asarray(rng.integers(0, 10, 16).astype(np.int32))
+    mask = jnp.ones(mesh.shape["data"], jnp.float32)
     for i in range(2):
         state, m = step_fn(state, x, y, mask, jax.random.key(i))
     jax.block_until_ready(state.params)

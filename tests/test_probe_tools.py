@@ -1,7 +1,8 @@
-"""Unit tests for the evidence harnesses' parent logic (no device, no
-subprocesses): memory_probe's artifact/delta bookkeeping and
-accuracy_run's contract parsing. The device-side halves need the chip;
-these tests pin everything that can break without one.
+"""Unit tests for the evidence harnesses' parent logic (no subprocesses):
+memory_probe's artifact/delta bookkeeping and accuracy_run's contract
+parsing, and memory_probe's eight builders at tiny geometry on the CPU mesh.
+The numbers need the chip; these tests pin everything that can break
+without one.
 """
 
 import json
@@ -84,6 +85,26 @@ def test_memory_probe_timeout_row(tmp_path, monkeypatch):
                        "--out", str(out)])
     doc = json.loads(out.read_text())
     assert doc["rows"][0]["error"] == "timeout 5s"
+
+
+@pytest.mark.parametrize("mode", memory_probe.MODES)
+def test_memory_probe_mode_builds_and_ticks(mode):
+    """Every mode's step builds from the package's own parallel/ modules
+    and runs: the child's measurement loop, minus the chip."""
+    import numpy as np
+
+    if mode.startswith("lm"):
+        # The pp modes lay one stage on each of the 8 CPU devices.
+        state, tick = memory_probe._lm_step(
+            mode, batch=8, seq_len=128, d_model=32, n_heads=2, vocab=61,
+            n_layers=8 if "_pp_" in mode else 1)
+    else:
+        state, tick = memory_probe._cnn_step(
+            mode, network="LeNet", dataset="synthetic_mnist",
+            per_device_batch=2)
+    for i in range(2):
+        state, metrics = tick(state, i)
+    assert np.isfinite(float(metrics["loss"]))
 
 
 # ------------------------------------------------------------ accuracy_run --

@@ -546,17 +546,6 @@ def test_analyze_faults_clean_run(tmp_path, capsys):
     assert json.loads(capsys.readouterr().out)["clean"] is True
 
 
-def test_report_resilience_family(tmp_path):
-    art = {"round": 1, "platform": "cpu", "scenario": "kv_drop_smoke",
-           "counters": {"crashes": 1, "kv_retries": 9}, "ok": True}
-    (tmp_path / "RESILIENCE_r01.json").write_text(json.dumps(art))
-    from ps_pytorch_tpu.tools.report import collect
-    fams = {e["family"]: e for e in collect(str(tmp_path))}
-    assert "resilience" in fams
-    e = fams["resilience"]
-    assert e["ok"] is True and e["crashes"] == 1 and e["kv_retries"] == 9
-
-
 # ---- leader lease (LeaderLost detection) ----
 
 def _lease_pair(clock, interval=1.0, kv=None, **follower_kw):

@@ -5,8 +5,7 @@ the exact phase partition (queue_wait + prefill + decode + stream_out ==
 latency) on real engine runs WITH bitwise generate() parity preserved,
 queue shed-on-submit/reap, summarize hardening, the SLO sweep ladder,
 analyze's requests mode + request↔engine stitch flows, the /slo and
-/debug/requests HTTP routes, the health steptime watchdog, and the
-regress slo family gate.
+/debug/requests HTTP routes, and the health steptime watchdog.
 """
 
 import json
@@ -646,43 +645,3 @@ def test_health_steptime_rising_edge_latch():
         events += h.observe_step(91 + i, loss=1.0, step_time=1.0,
                                  now=clk.now)
     assert len([e for e in events if e.detector == "steptime"]) == 1
-
-
-# ---- tools/regress.py: the slo family gate ----
-
-def _slo_rows(knee=8.0, bar=1.0, frac=0.005, bitwise=True, ok=True):
-    return [
-        {"config": "slo_sweep", "knee_rps": knee, "knee_bar": bar,
-         "goodput_under_slo_tps": 100.0, "ok": ok},
-        {"config": "serve_reqtrace_overhead", "overhead_frac": frac,
-         "bitwise_identical": bitwise, "ok": ok},
-    ]
-
-
-def test_regress_slo_family(tmp_path):
-    from ps_pytorch_tpu.tools.regress import run_gate
-
-    good = tmp_path / "SLO_r98.json"
-    good.write_text("\n".join(json.dumps(r) for r in _slo_rows()))
-    v = run_gate("slo", str(good), repo=str(tmp_path))
-    assert v["ok"] is True
-    assert v["configs"]["slo_sweep"]["metrics"]["knee_rps"]["ok"] is True
-    for rows, why in (
-            (_slo_rows(knee=0.5), "knee below the recorded bar"),
-            (_slo_rows(knee=None), "no knee found"),
-            (_slo_rows(frac=0.05), "overhead over budget"),
-            (_slo_rows(bitwise=False), "tokens diverged"),
-            ([_slo_rows()[0]], "missing overhead row")):
-        bad = tmp_path / "SLO_r99.json"
-        bad.write_text("\n".join(json.dumps(r) for r in rows))
-        v = run_gate("slo", str(bad), repo=str(tmp_path))
-        assert v["ok"] is False, why
-
-
-def test_committed_slo_artifact_passes_gate():
-    import os
-    from ps_pytorch_tpu.tools.regress import run_gate
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(repo, "SLO_r12.json")
-    assert os.path.exists(path), "SLO_r12.json must be committed"
-    assert run_gate("slo", path, repo=repo)["ok"] is True
