@@ -17,7 +17,9 @@ from ps_pytorch_tpu.models.vgg import (
     VGG11, VGG13, VGG16, VGG19, VGG11_BN, VGG13_BN, VGG16_BN, VGG19_BN,
 )
 
-_DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
+# --compute-dtype name -> dtype: the one table, for the CNN zoo below and for
+# the LM classes (runtime/lm_eval.build_lm_model).
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
 
 # Name -> constructor, mirroring the reference registry (util.py:8-19) but
 # covering the full family the reference defines (resnet.py:100-113,
@@ -53,7 +55,7 @@ def build_model(model_name: str, num_classes: int = 10,
     resnet.pallas_variant); other families (LeNet's 5x5s) ignore it.
     """
     if isinstance(compute_dtype, str):
-        compute_dtype = _DTYPES[compute_dtype]
+        compute_dtype = DTYPES[compute_dtype]
     try:
         ctor = _REGISTRY[model_name]
     except KeyError:

@@ -246,10 +246,13 @@ class DroplessMoE(nn.Module):
         order = jnp.argsort(flat_e, stable=True)
         tok = order // k                              # source row of each sorted assignment
         group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        # The float32 expert weights go to the kernels as they are: a tile is
+        # cast to the rows' dtype in VMEM, and the weight gradient comes back
+        # float32 from the float32 accumulator (ops/grouped_matmul.py).
         xs = tokens[tok].astype(self.dtype)           # gather [T*k, D]
-        h = nn.silu(gmm(xs, w_gate.astype(self.dtype), group_sizes)) * \
-            gmm(xs, w_up.astype(self.dtype), group_sizes)
-        out = gmm(h, w_down.astype(self.dtype), group_sizes)
+        h = nn.silu(gmm(xs, w_gate, group_sizes)) * \
+            gmm(xs, w_up, group_sizes)
+        out = gmm(h, w_down, group_sizes)
         out = out.astype(jnp.float32) * gates.reshape(-1)[order][:, None]
         y = jnp.zeros((t, d), jnp.float32).at[tok].add(out)
 
@@ -323,7 +326,8 @@ class MoEBlock(nn.Module):
 class MoETransformerLM(nn.Module):
     """Decoder-only LM with an MoE MLP in every block.
 
-    Returns (logits [B, S, V] float32, aux): for a capacity arch the
+    Returns (logits [B, S, V] in ``dtype``; the loss casts them to float32,
+    aux): for a capacity arch the
     scalar sum of the layers' load-balance losses; for a dropless arch a dict
     keyed by ``DROPLESS_STATS`` (``aux`` and ``z_loss`` averaged over layers,
     the busiest layer's ``expert_load_max_over_mean``, ``moe_dropped``
@@ -382,4 +386,4 @@ class MoETransformerLM(nn.Module):
         x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
                           name="lm_head")(x)
-        return logits.astype(jnp.float32), aux_total
+        return logits, aux_total

@@ -122,6 +122,23 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     return x + nn.Dense(d, use_bias=False, dtype=dtype)(o)
 
 
+class EmbedRows(nn.Module):
+    """``nn.Embed`` (same parameter ``embedding``, same default init) that
+    gathers its rows from the float32 table and casts the rows: ``nn.Embed``
+    of a narrower dtype casts the whole [V, d] table every step, and its
+    gradient is scatter-added in that dtype."""
+    num_embeddings: int
+    features: int
+    dtype: Any = jnp.float32
+    embedding_init: Any = nn.linear.default_embed_init
+
+    @nn.compact
+    def __call__(self, ids):
+        table = self.param("embedding", self.embedding_init,
+                           (self.num_embeddings, self.features))
+        return jnp.take(table, ids, axis=0).astype(self.dtype)
+
+
 def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
                  d_model: int, max_seq_len: int, dtype):
     """Token embedding, plus the learned position table where the arch has
@@ -130,11 +147,11 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
     a = ARCHS[arch]
     init = {"embedding_init": nn.initializers.normal(a.embed_std)} \
         if a.embed_std else {}
-    x = nn.Embed(vocab_size, d_model, dtype=dtype, name="tok_embed",
-                 **init)(tokens)
+    x = EmbedRows(vocab_size, d_model, dtype=dtype, name="tok_embed",
+                  **init)(tokens)
     if not a.rope_theta:
-        x = x + nn.Embed(max_seq_len, d_model, dtype=dtype,
-                         name="pos_embed")(positions)[None]
+        x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
+                          name="pos_embed")(positions)[None]
     return x
 
 
@@ -159,12 +176,14 @@ def cached_attention(mod: nn.Module, q, k, v, length: int):
     cv.value = jax.lax.dynamic_update_slice(cv.value, v, (0, 0, i, 0))
     idx.value = i + s
     scale = hd ** -0.5
-    att = jnp.einsum("bhqd,bhkd->bhqk", q * scale, ck.value)
+    att = jnp.einsum("bhqd,bhkd->bhqk", q * scale, ck.value,
+                     preferred_element_type=jnp.float32)
     q_pos = i + jnp.arange(s)                                   # [S]
     ok = jnp.arange(length)[None, :] <= q_pos[:, None]          # [S, length]
     att = jnp.where(ok[None, None], att, -jnp.inf)
-    p = jax.nn.softmax(att, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, cv.value)
+    p = jax.nn.softmax(att, axis=-1)            # float32 for any q.dtype
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(cv.value.dtype), cv.value,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 class Block(nn.Module):
@@ -243,9 +262,11 @@ class TransformerLM(nn.Module):
                     arch=self.arch, ffn_dim=self.ffn_dim,
                     name=f"block_{i}")(x, positions)
         x = make_norm(self.arch, self.dtype, name="ln_f")(x)
-        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        # Logits in ``dtype``, like every other output of the model: the loss
+        # that consumes them casts to float32 (parallel/{sp,tp,pp,ep}.py,
+        # runtime/lm_eval.py), so under float32 nothing changes.
+        return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
+                        name="lm_head")(x)
 
 
 def migrate_packed_qkv(tree):

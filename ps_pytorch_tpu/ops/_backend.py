@@ -23,9 +23,11 @@ def cnn_kernels(cfg) -> List[str]:
     return names
 
 
-def announce_kernels(names: List[str]) -> None:
+def announce_kernels(names: List[str], dtype=None) -> None:
     """One line per trainer naming the enabled kernels and the mode they run
-    in, so an interpreted kernel on a machine meant to have a chip is seen."""
+    in, so an interpreted kernel on a machine meant to have a chip is seen;
+    ``dtype`` (the LM trainer's) is the dtype the built model feeds them."""
     if names:
         mode = "interpret" if interpret_default() else "mosaic"
-        print(f"KERNELS {' '.join(names)} mode={mode}")
+        fed = f" dtype={jax.numpy.dtype(dtype).name}" if dtype is not None else ""
+        print(f"KERNELS {' '.join(names)} mode={mode}{fed}")

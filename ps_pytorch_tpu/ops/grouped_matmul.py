@@ -24,10 +24,21 @@ three uses)
   ``moe_gmm_dlhs`` is the same kernel reading ``rhs`` transposed (the
   contraction runs over its last axis): no [E, N, K] copy of the weights is
   ever made, which is what ``jax.lax.ragged_dot`` pays for that gradient.
+  The kernel streams ``rhs`` itself: a group's [K, tn] weights come in by
+  one DMA started on the first visit of the group before it (two buffers),
+  because the automatic pipeline fetches one grid step ahead and a float32
+  weight tile takes longer than a visit of a few bfloat16 rows computes
+  (v5e, PR 28: 16.5 -> 15.6 ms a layer's three matmuls forward and back).
   ``moe_gmm_drhs``: grid (K tiles, N tiles, visits), per group
   ``lhs^T dout`` accumulated over the group's visits with the other groups'
   rows masked to zero; a group with no rows is visited once so that its
   gradient is written as zeros.
+- ``rhs`` may be wider than ``lhs``: a bfloat16 model hands its float32
+  expert parameters over as they are, the kernel casts a group's weights to
+  the rows' dtype in VMEM once a group, and ``moe_gmm_drhs`` writes the
+  weight gradient in ``rhs.dtype`` straight from its float32 accumulator.
+  Casting outside instead costs six XLA passes over 805M parameters a step
+  and holds six bfloat16 copies for the backward (v5e: 18.1 against 15.6 ms).
 - Matmuls run with ``preferred_element_type=f32`` (bf16 inputs hit the MXU
   natively; f32 inputs take the default single bf16 pass, as every other
   matmul of the LM step does).
@@ -47,9 +58,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ps_pytorch_tpu.ops._backend import interpret_default
 
-# (tm, tk, tn) targets by input itemsize: what fits the 16 MiB of scoped VMEM
-# double-buffered, from the chip sweep (PERF.md, Findings PR 25).
-_TILES = {4: (512, 1024, 512), 2: (512, 1024, 1024)}
+# (tm, tk, tn) targets by the rows' itemsize, from chip sweeps at the OLMoE
+# cell's shape (PERF.md, Findings PR 25 for 4 bytes, PR 28 for 2). Few rows a
+# tile: a tile that straddles groups is multiplied once a group. All of K and
+# wide N: a group's weights are then fetched once a call.
+_TILES = {4: (512, 1024, 512), 2: (256, 2048, 2048)}
+# What the two weight buffers (and the cast copy) of ``moe_gmm_fwd|dlhs`` may
+# take of VMEM (they hold all of K for one N tile), and the limit handed to
+# Mosaic (v5e: 128 MiB physical, 16 MiB scoped by default).
+WEIGHTS_VMEM_BYTES = 40 * 2 ** 20
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
 
 
 def _fit(dim: int, target: int, align: int) -> int:
@@ -65,8 +83,15 @@ def _fit(dim: int, target: int, align: int) -> int:
                      f"divides {dim}")
 
 
-def _tiles(m: int, k: int, n: int, dtype):
+def _tiles(m: int, k: int, n: int, dtype, weights_dtype=None):
+    """(tm, tk, tn) for [m, k] rows of ``dtype``; with ``weights_dtype``, tn
+    also keeps two [k, tn] weight buffers of that dtype, and their copy in
+    ``dtype`` where the two differ, inside WEIGHTS_VMEM_BYTES."""
     tm, tk, tn = _TILES[jnp.dtype(dtype).itemsize]
+    if weights_dtype is not None:
+        rows, w = jnp.dtype(dtype), jnp.dtype(weights_dtype)
+        column = k * (2 * w.itemsize + (w != rows) * rows.itemsize)
+        tn = min(tn, max(WEIGHTS_VMEM_BYTES // column // 128, 1) * 128)
     return _fit(m, tm, 8), _fit(k, tk, 128), _fit(n, tn, 128)
 
 
@@ -108,10 +133,60 @@ def _rows_of_group(offs_ref, gid_ref, tid_ref, i, shape, tm):
     return g, (rows >= offs_ref[g]) & (rows < offs_ref[g + 1])
 
 
-def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, lhs_ref, rhs_ref,
-                out_ref, acc, *, tm, n_groups, trans_rhs):
-    i, k = pl.program_id(1), pl.program_id(2)
+def _weight_turns(group_ids, n_visits):
+    """For the kernels that stream ``rhs`` themselves: per visit -> (slot [V]
+    of the two weight buffers its group uses, next [V] the group visited after
+    it, or a number past every group when there is none), int32."""
+    v = group_ids.shape[0]
+    i = jnp.arange(v, dtype=jnp.int32)
+    change = (i == 0) | (group_ids != jnp.roll(group_ids, 1))
+    slot = (jnp.cumsum(change) - 1) % 2
+    at = jax.lax.cummin(jnp.where(change, i, v), reverse=True)
+    nxt = jnp.concatenate([at[1:], jnp.full((1,), v, jnp.int32)])
+    nxt_group = jnp.where(nxt < n_visits[0],
+                          group_ids[jnp.minimum(nxt, v - 1)],
+                          jnp.iinfo(jnp.int32).max)
+    return slot.astype(jnp.int32), nxt_group.astype(jnp.int32)
+
+
+def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, slot_ref, next_ref,
+                lhs_ref, rhs_hbm, out_ref, acc, wbuf, sem, *cast,
+                tm, tk, tn, n_groups, trans_rhs):
+    ni, i, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     live = i < nv_ref[0]
+    g = gid_ref[i]
+    s = slot_ref[i]
+    first = (i == 0) | (g != gid_ref[jnp.maximum(i - 1, 0)])
+    w = cast[0] if cast else None   # the group's weights in the rows' dtype
+
+    def k_tile(ref, c):
+        rows = pl.ds(pl.multiple_of(c * tk, tk), tk)
+        return ref[:, rows] if trans_rhs else ref[rows, :]
+
+    def fetch(group, to):
+        cols = pl.ds(pl.multiple_of(ni * tn, tn), tn)
+        src = rhs_hbm.at[group, cols, :] if trans_rhs \
+            else rhs_hbm.at[group, :, cols]
+        return pltpu.make_async_copy(src, wbuf.at[to], sem.at[to])
+
+    # A group's weights (all of K, this N tile) come in by one DMA that is
+    # started on the first visit of the group BEFORE it, so that it has that
+    # group's every visit to arrive in; the automatic pipeline looks one
+    # grid step ahead, which a float32 tile under a few rows of bfloat16
+    # outlasts. Rows past the groups (group n_groups) store zeros: no weights.
+    @pl.when(live & first & (k == 0) & (g < n_groups))
+    def _weights():
+        @pl.when(i == 0)
+        def _():
+            fetch(g, s).start()
+        fetch(g, s).wait()
+
+        @pl.when(next_ref[i] < n_groups)
+        def _():
+            fetch(next_ref[i], 1 - s).start()
+        if cast:    # float32 weights under narrower rows: cast once a group
+            for c in range(w.shape[0]):
+                w[c] = k_tile(wbuf.at[s], c).astype(w.dtype)
 
     @pl.when(live & (k == 0))
     def _init():
@@ -119,56 +194,67 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, lhs_ref, rhs_ref,
 
     @pl.when(live)
     def _tile():
-        acc[:] += _dot(lhs_ref[...], rhs_ref[0], trans_b=trans_rhs)
+        rhs = w[k] if cast else k_tile(wbuf.at[s], k)
+        acc[:] += _dot(lhs_ref[...], rhs, trans_b=trans_rhs)
 
     @pl.when(live & (k == pl.num_programs(2) - 1))
     def _store():
-        g, mine = _rows_of_group(offs_ref, gid_ref, tid_ref, i, acc.shape, tm)
+        _, mine = _rows_of_group(offs_ref, gid_ref, tid_ref, i, acc.shape, tm)
         val = jnp.where(g == n_groups, 0.0, acc[:])    # rows past the groups
         out_ref[...] = jnp.where(
             mine, val, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
 
 
-def _gmm_call(lhs, rhs, group_sizes, *, trans_rhs: bool, name: str):
-    """lhs [M, K] x rhs [E, K, N] (or [E, N, K] read transposed) -> [M, N]."""
+# The calls are jitted on their static arguments: a training run traces its
+# model five times (init, the dtype probe, a forward, the FLOPs count, the
+# step) and a layer calls like shapes twice, and tracing a kernel body costs
+# 0.1 s on a chip's host. A trace is reused wherever the signature repeats.
+@partial(jax.jit, static_argnames=("tiles", "trans_rhs", "name", "interpret"))
+def _gmm_call(lhs, rhs, group_sizes, *, tiles, trans_rhs: bool, name: str,
+              interpret: bool):
+    """lhs [M, K] x rhs [E, K, N] (or [E, N, K] read transposed) -> [M, N]
+    in ``lhs.dtype``; ``rhs`` may be wider than ``lhs`` (float32 parameters
+    under bfloat16 rows) and is then cast in VMEM, once a group."""
     m, k = lhs.shape
     e = rhs.shape[0]
     n = rhs.shape[1] if trans_rhs else rhs.shape[2]
-    tm, tk, tn = _tiles(m, k, n, lhs.dtype)
-    sched = _visits(group_sizes, m, tm, remainder=True, visit_empty=False)
-    last_group, last_k = e - 1, k // tk - 1
+    tm, tk, tn = tiles
+    offs, gid, tid, nv = _visits(group_sizes, m, tm, remainder=True,
+                                 visit_empty=False)
+    last_k = k // tk - 1
 
-    def k_block(i, ki, nv):
+    def lhs_map(ni, i, ki, offs, gid, tid, nv, slot, nxt):
         # A skipped visit must not move data: keep the last real visit's
         # final K block instead of walking K again.
-        return jnp.where(i < nv[0], ki, last_k)
+        return tid[i], jnp.where(i < nv[0], ki, last_k)
 
-    def lhs_map(ni, i, ki, offs, gid, tid, nv):
-        return tid[i], k_block(i, ki, nv)
-
-    def rhs_map(ni, i, ki, offs, gid, tid, nv):
-        g, kb = jnp.minimum(gid[i], last_group), k_block(i, ki, nv)
-        return (g, ni, kb) if trans_rhs else (g, kb, ni)
-
-    rhs_block = (1, tn, tk) if trans_rhs else (1, tk, tn)
+    w_tile = (tn, tk) if trans_rhs else (tk, tn)
+    w_block = (tn, k) if trans_rhs else (k, tn)
+    scratch = [pltpu.VMEM((tm, tn), jnp.float32),
+               pltpu.VMEM((2,) + w_block, rhs.dtype),
+               pltpu.SemaphoreType.DMA((2,))]
+    if rhs.dtype != lhs.dtype:
+        scratch.append(pltpu.VMEM((k // tk,) + w_tile, lhs.dtype))
     return pl.pallas_call(
-        partial(_gmm_kernel, tm=tm, n_groups=e, trans_rhs=trans_rhs),
+        partial(_gmm_kernel, tm=tm, tk=tk, tn=tn, n_groups=e,
+                trans_rhs=trans_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=6,
             grid=(n // tn, m // tm + e, k // tk),
             in_specs=[pl.BlockSpec((tm, tk), lhs_map),
-                      pl.BlockSpec(rhs_block, rhs_map)],
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((tm, tn),
-                                   lambda ni, i, ki, offs, gid, tid, nv:
-                                   (tid[i], ni)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+                                   lambda ni, i, ki, offs, gid, tid, nv,
+                                   slot, nxt: (tid[i], ni)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name=name,
-        interpret=interpret_default(),
-    )(*sched, lhs, rhs)
+        interpret=interpret,
+    )(offs, gid, tid, nv, *_weight_turns(gid, nv), lhs, rhs)
 
 
 def _drhs_kernel(offs_ref, gid_ref, tid_ref, nv_ref, lhs_ref, dout_ref,
@@ -196,12 +282,13 @@ def _drhs_kernel(offs_ref, gid_ref, tid_ref, nv_ref, lhs_ref, dout_ref,
         out_ref[0] = acc[:].astype(out_ref.dtype)
 
 
-def _drhs_call(lhs, dout, group_sizes, out_dtype):
+@partial(jax.jit, static_argnames=("tiles", "out_dtype", "interpret"))
+def _drhs_call(lhs, dout, group_sizes, *, tiles, out_dtype, interpret: bool):
     """Per group ``lhs[rows]^T dout[rows]``: [M, K], [M, N] -> [E, K, N]."""
     m, k = lhs.shape
     n = dout.shape[1]
     e = group_sizes.shape[0]
-    tm, tk, tn = _tiles(m, k, n, lhs.dtype)
+    tm, tk, tn = tiles
     sched = _visits(group_sizes, m, tm, remainder=False, visit_empty=True)
     return pl.pallas_call(
         partial(_drhs_kernel, tm=tm),
@@ -223,9 +310,10 @@ def _drhs_call(lhs, dout, group_sizes, out_dtype):
         ),
         out_shape=jax.ShapeDtypeStruct((e, k, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="moe_gmm_drhs",
-        interpret=interpret_default(),
+        interpret=interpret,
     )(*sched, lhs, dout)
 
 
@@ -242,10 +330,14 @@ def _check(lhs, rhs, group_sizes):
 @jax.custom_vjp
 def gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
     """Rows of ``lhs`` grouped by ``group_sizes`` times their group's
-    ``rhs[e]``; float32 accumulation, result in ``lhs.dtype``."""
+    ``rhs[e]``; float32 accumulation, result and the gradient to the rows in
+    ``lhs.dtype``, the gradient to ``rhs`` in ``rhs.dtype``."""
     _check(lhs, rhs, group_sizes)
-    return _gmm_call(lhs, rhs, group_sizes, trans_rhs=False,
-                     name="moe_gmm_fwd")
+    (m, k), n = lhs.shape, rhs.shape[2]
+    return _gmm_call(lhs, rhs, group_sizes,
+                     tiles=_tiles(m, k, n, lhs.dtype, rhs.dtype),
+                     trans_rhs=False, name="moe_gmm_fwd",
+                     interpret=interpret_default())
 
 
 def _gmm_fwd(lhs, rhs, group_sizes):
@@ -254,9 +346,14 @@ def _gmm_fwd(lhs, rhs, group_sizes):
 
 def _gmm_bwd(res, dout):
     lhs, rhs, group_sizes = res
-    dlhs = _gmm_call(dout, rhs, group_sizes, trans_rhs=True,
-                     name="moe_gmm_dlhs")
-    drhs = _drhs_call(lhs, dout, group_sizes, rhs.dtype)
+    (m, k), n = lhs.shape, rhs.shape[2]
+    interpret = interpret_default()
+    dlhs = _gmm_call(dout, rhs, group_sizes,
+                     tiles=_tiles(m, n, k, dout.dtype, rhs.dtype),
+                     trans_rhs=True, name="moe_gmm_dlhs", interpret=interpret)
+    drhs = _drhs_call(lhs, dout, group_sizes,
+                      tiles=_tiles(m, k, n, lhs.dtype),
+                      out_dtype=jnp.dtype(rhs.dtype), interpret=interpret)
     return dlhs, drhs, None
 
 

@@ -131,7 +131,7 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             logits, aux = model.apply({"params": params}, tokens)
             stats = aux if arch.dropless else {"aux": aux}
             per = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], tokens[:, 1:])
+                logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
             reg = aux_coef * stats["aux"] \
                 + arch.z_loss_coef * stats.get("z_loss", 0.0)
             # LOCAL sums; collectives on the grads, not in the loss.

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ps_pytorch_tpu.models.transformer import Block
+from ps_pytorch_tpu.models.transformer import Block, EmbedRows
 from ps_pytorch_tpu.parallel.dp import TrainState
 
 
@@ -89,8 +89,8 @@ def unstack_stage_params(pp_params: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 def _embed(model, params, tokens):
-    tok = nn.Embed(model.vocab_size, model.d_model, dtype=model.dtype)
-    pos = nn.Embed(model.max_seq_len, model.d_model, dtype=model.dtype)
+    tok = EmbedRows(model.vocab_size, model.d_model, dtype=model.dtype)
+    pos = EmbedRows(model.max_seq_len, model.d_model, dtype=model.dtype)
     x = tok.apply({"params": params["tok_embed"]}, tokens)
     p = pos.apply({"params": params["pos_embed"]},
                   jnp.arange(tokens.shape[1]))

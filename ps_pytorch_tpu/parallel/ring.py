@@ -30,15 +30,21 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 def _block_attn(q, k, v, mask_bias):
     """One attention block: q [B,H,Sq,D] x k,v [B,H,Sk,D] -> (scores-stats,
-    weighted values) with numerically safe online-softmax pieces."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) + mask_bias   # [B,H,Sq,Sk]
+    weighted values) with numerically safe online-softmax pieces. Scores,
+    max, sum and the weighted values are float32 whatever the inputs' dtype
+    (a bfloat16 score is good to 0.03 at 8: 3% of its exponential); the
+    probabilities enter the PV product in ``v.dtype``, as in the flash
+    kernels."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) + mask_bias
     m = jnp.max(s, axis=-1)                                # [B,H,Sq]
     # Fully masked row (m = -inf, e.g. a whole future block under causal
     # masking): exp(s - (-inf)) would be NaN; substitute 0 so p = exp(-inf)=0.
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     p = jnp.exp(s - m_safe[..., None])
     l = jnp.sum(p, axis=-1)                                # [B,H,Sq]
-    o = jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
     return m, l, o
 
 
@@ -50,14 +56,18 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
       q, k, v: [B, H, S_local, D] — the sequence axis is sharded over
         ``axis_name``; shard i holds tokens [i*S_local, (i+1)*S_local).
       causal: apply a causal mask over the GLOBAL sequence positions.
-    Returns: [B, H, S_local, D] attention output for the local queries.
+    Returns: [B, H, S_local, D] attention output for the local queries, in
+    ``q.dtype``; the running max, sum and weighted values cross the hops in
+    float32.
     """
     n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[2]
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    out_dtype = q.dtype
     q = q * scale
+    stats = q.shape[:3]
 
     q_pos = idx * s_local + jax.lax.broadcasted_iota(
         jnp.int32, (s_local, 1), 0).squeeze(-1)            # global q positions
@@ -68,11 +78,11 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
 
     def mask_bias_for(src_idx):
         if not causal:
-            return jnp.zeros((1, 1, s_local, s_local), q.dtype)
+            return jnp.zeros((1, 1, s_local, s_local), jnp.float32)
         ok = q_pos[:, None] >= kv_positions(src_idx)[None, :]
-        return jnp.where(ok, 0.0, -jnp.inf)[None, None].astype(q.dtype)
+        return jnp.where(ok, 0.0, -jnp.inf)[None, None]     # float32
 
-    neg_inf = jnp.full(q.shape[:3], -jnp.inf, q.dtype)
+    neg_inf = jnp.full(stats, -jnp.inf, jnp.float32)
 
     def block_or_skip(k_cur, v_cur, t):
         """Attention block for the K/V currently held (arrived from shard
@@ -85,8 +95,8 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
         return jax.lax.cond(
             src <= idx,
             lambda: _block_attn(q, k_cur, v_cur, mask_bias_for(src)),
-            lambda: (neg_inf, jnp.zeros(q.shape[:3], q.dtype),
-                     jnp.zeros_like(q)))
+            lambda: (neg_inf, jnp.zeros(stats, jnp.float32),
+                     jnp.zeros(q.shape, jnp.float32)))
 
     def merge(m_run, l_run, o_run, m_blk, l_blk, o_blk):
         # Online softmax merge (flash-attention update rule).
@@ -110,8 +120,8 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
 
     # n-1 hops: the scan permutes while computing blocks 0..n-2; the last
     # received block is consumed outside the loop with no further hop.
-    init = (k, v, neg_inf, jnp.zeros(q.shape[:3], q.dtype),
-            jnp.zeros_like(q))
+    init = (k, v, neg_inf, jnp.zeros(stats, jnp.float32),
+            jnp.zeros(q.shape, jnp.float32))
     (k_f, v_f, m_run, l_run, o_run), _ = jax.lax.scan(
         step, init, jnp.arange(n - 1), length=n - 1)
     m_f, l_f, o_f = merge(
@@ -119,7 +129,7 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
     # Fully-masked rows (can't happen for causal with local queries, but keep
     # the kernel total): avoid 0/0.
     l_safe = jnp.where(l_f == 0, 1.0, l_f)
-    return o_f / l_safe[..., None]
+    return (o_f / l_safe[..., None]).astype(out_dtype)
 
 
 def make_ring_attention(mesh: Mesh, axis_name: str = "data",
@@ -142,13 +152,16 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "data",
 def full_attention(q, k, v, *, causal: bool = False,
                    scale: Optional[float] = None):
     """Unsharded reference implementation (materializes [S, S]) — the oracle
-    ring_attention is tested against."""
+    ring_attention is tested against. Scores and softmax are float32 for any
+    input dtype; the output is ``q.dtype``."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
+                   preferred_element_type=jnp.float32)
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         ok = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
         s = jnp.where(ok[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)

@@ -68,7 +68,8 @@ def _local_nexttoken_loss(model, axis_name: str, params, tokens):
     idx = jax.lax.axis_index(axis_name)
     s_local = tokens.shape[1]
     positions = idx * s_local + jnp.arange(s_local)
-    logits = model.apply({"params": params}, tokens, positions=positions)
+    logits = model.apply({"params": params}, tokens,
+                         positions=positions).astype(jnp.float32)
     # Next-token targets: local shift; the boundary target (first token of
     # the next shard) arrives via one ppermute hop.
     perm = [(j, (j - 1) % n) for j in range(n)]

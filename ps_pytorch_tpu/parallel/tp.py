@@ -168,7 +168,7 @@ def make_tp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         def loss_fn(params):
             logits = model.apply({"params": params}, tokens)
             per = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1], tokens[:, 1:])
+                logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
             return per.mean()
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)

@@ -36,8 +36,12 @@ def build_lm_model(cfg, **kw):
     with identical param shapes (top_k, arch) can never be left out of one of
     them. ``cfg.network`` decides the family where a checkpoint recorded it;
     a live config says it through ``lm_parallelism=ep``. ``kw`` adds what
-    only the caller knows (attention_impl, ep_axis, axis_name)."""
-    geo = dict(lm_geometry(cfg), arch=cfg.lm_arch, ffn_dim=cfg.lm_ffn_dim)
+    only the caller knows (attention_impl, ep_axis, axis_name). The compute
+    dtype is ``cfg.compute_dtype`` (activations and matmul inputs; parameters
+    stay float32), as for the CNNs."""
+    from ps_pytorch_tpu.models import DTYPES
+    geo = dict(lm_geometry(cfg), arch=cfg.lm_arch, ffn_dim=cfg.lm_ffn_dim,
+               dtype=DTYPES[cfg.compute_dtype])
     if cfg.network == "MoETransformerLM" or cfg.lm_parallelism == "ep":
         from ps_pytorch_tpu.models.moe import MoETransformerLM
         return MoETransformerLM(n_experts=cfg.lm_experts,
@@ -70,7 +74,7 @@ def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
 
     @jax.jit
     def loss_fn(params, tokens):
-        logits = apply(params, tokens)
+        logits = apply(params, tokens).astype(jnp.float32)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :-1], tokens[:, 1:]).mean()
 

@@ -173,7 +173,10 @@ class LMTrainer:
             kernels.append(f"flash_attention[{sched.describe()}]")
         if ARCHS[cfg.lm_arch].dropless:
             kernels.append("grouped_matmul")
-        announce_kernels(kernels)
+        # What the run really computes in is read from the built model, not
+        # from the flag: the line here, the first JSONL record and the gauge.
+        self.compute_dtype = jnp.dtype(self.model.dtype)
+        announce_kernels(kernels, dtype=self.compute_dtype)
 
         # Checkpoints are self-describing: record the model family and the
         # RESOLVED mesh degree (lm_model_axis=0 means "all devices", which
@@ -225,6 +228,7 @@ class LMTrainer:
                 flight_path = f"{flight_path}.p{jax.process_index()}"
             self.flightrec = FlightRecorder(flight_path, tracer=self.tracer,
                                             registry=self.registry)
+        self.registry.set("compute_dtype", self.compute_dtype.itemsize)
         self.exporter: Optional[MetricsExporter] = None
         if cfg.metrics_port > 0:
             collect = []
@@ -388,6 +392,8 @@ class LMTrainer:
         halted = False
         tracer = self.tracer
         t_sync, n_unsynced = time.monotonic(), 0
+        # only this run's first record says what it computes in
+        once = {"compute_dtype": self.compute_dtype.name}
         try:
             while step < cfg.max_steps:
                 step += 1
@@ -451,7 +457,8 @@ class LMTrainer:
                             loss=loss, acc=0.0, participating=1.0,
                             step_time=t_step, data_time=t_data,
                             phases=tracer.step_summary(step), **routing,
-                            **derived)
+                            **derived, **once)
+                        once = {}
                     for k, v in routing.items():
                         self.registry.set(k, v)
                 with tracer.span("ops_step"):
@@ -524,7 +531,7 @@ class LMTrainer:
             def loss_fn(params, tokens):  # noqa: F811 — ep refinement
                 logits, _ = oracle.apply({"params": params}, tokens)
                 return optax.softmax_cross_entropy_with_integer_labels(
-                    logits[:, :-1], tokens[:, 1:]).mean()
+                    logits[:, :-1].astype(jnp.float32), tokens[:, 1:]).mean()
 
         # all_replicated, not device_get: tp/pp/ep leaves are sharded over
         # devices that can span hosts.

@@ -494,6 +494,9 @@ TRAINING_GAUGES = (
      "device HBM peak bytes reserved for running programs' temporaries "
      "(0 when the backend has no stats)"),
     ("host_rss_bytes", "bytes", "host process peak RSS watermark"),
+    ("compute_dtype", "bytes", "item size of the dtype the built LM computes "
+     "in (2 = bfloat16, 4 = float32; set by LMTrainer); the JSONL field of "
+     "the same name, on a run's first record, holds the dtype's name"),
 ) + ROUTING_GAUGES
 TRAINING_HISTOGRAMS = (
     ("train_step_latency_s", "s", "per-step wall-time distribution"),

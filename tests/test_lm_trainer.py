@@ -233,8 +233,11 @@ def test_lm_remat_is_numerically_identical(tmp_path, mode, extra):
 
     losses = {}
     for remat in (False, True):
+        # float32: in bfloat16 the recomputed block rounds where XLA fuses
+        # differently, and the two runs part by 1e-4
         t = LMTrainer(_cfg(tmp_path / f"r{remat}", lm_parallelism=mode,
-                           max_steps=4, remat=remat, **extra))
+                           max_steps=4, remat=remat, compute_dtype="float32",
+                           **extra))
         t.train()
         losses[remat] = t.evaluate(max_batches=1)["loss"]
     np.testing.assert_allclose(losses[True], losses[False], rtol=1e-5)

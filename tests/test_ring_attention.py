@@ -5,11 +5,15 @@ it numerically with the sequence sharded 8 ways — including causal masking
 across shard boundaries and gradient flow through the ppermute ring.
 """
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
+
+from ps_pytorch_tpu.models.transformer import cached_attention
+from ps_pytorch_tpu.parallel.ring import full_attention, make_ring_attention
 
 B, H, S, D = 2, 4, 64, 16
 
@@ -128,3 +132,41 @@ def test_sp_train_step_matches_single_device(rng):
                     jax.tree.leaves(want_params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ---- bfloat16 inputs: float32 statistics in the paths no cell runs ----
+
+class _Cached(nn.Module):
+    length: int
+
+    @nn.compact
+    def __call__(self, q, k, v):
+        return cached_attention(self, q, k, v, self.length)
+
+
+def _bf16_attention(path, q, k, v):
+    if path == "full":
+        return full_attention(q, k, v, causal=True)
+    if path == "ring":
+        mesh = Mesh(np.array(jax.devices()), ("data",))
+        return make_ring_attention(mesh, "data", causal=True)(q, k, v)
+    out, _ = _Cached(q.shape[2]).apply({}, q, k, v, mutable=["cache"])
+    return out
+
+
+@pytest.mark.parametrize("path", ["full", "ring", "cached"])
+def test_bfloat16_inputs_stay_within_one_rounding_of_float32(path):
+    """bfloat16 q, k, v at S=1024: scores, max, sum and the running values
+    are float32, so the output (in ``q.dtype``) is within one bfloat16 ulp at
+    the output's scale of the float32 result on the same rounded inputs.
+    With scores and the softmax in bfloat16 (before PR 28) the error was 3.5
+    times that: a score near 8 is good to 0.03, 3% of its exponential."""
+    ks = jax.random.split(jax.random.key(0), 3)
+    q, k, v = ((jax.random.normal(kk, (1, 2, 1024, 64)) * 1.5)
+               .astype(jnp.bfloat16) for kk in ks)
+    want = full_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                          causal=True)
+    got = _bf16_attention(path, q, k, v)
+    assert got.dtype == jnp.bfloat16
+    err = float(jnp.abs(got.astype(jnp.float32) - want).max())
+    assert err <= float(jnp.abs(want).max()) * 2 ** -8, err
