@@ -54,10 +54,16 @@ def main(argv=None) -> int:
         cfg = TrainConfig.from_json(f.read())
     if cfg.lm_arch != "gpt2":
         # RoPE at the cache offset and dropless decode are not pinned by a
-        # parity test yet; serving the olmoe arch is a later issue.
+        # parity test yet, nor a cache for window layers or grouped-query heads;
+        # serving the olmoe and smallthinker archs is a later issue.
         p.error(f"generate.py decodes lm_arch=gpt2 checkpoints; this one is "
                 f"lm_arch={cfg.lm_arch} (train and evaluate it through "
                 f"train_lm.py; decoding it is not built)")
+    if cfg.lm_kv_heads not in (0, cfg.lm_heads) or cfg.lm_head_dim:
+        p.error(f"generate.py decodes equal head counts of d / heads; this "
+                f"checkpoint has lm_kv_heads={cfg.lm_kv_heads}, lm_head_dim="
+                f"{cfg.lm_head_dim} (decoding grouped-query heads is not "
+                f"built)")
     moe = cfg.network == "MoETransformerLM"
     template = build_lm_template(cfg)
     _, to_tree = build_lm_oracle(cfg)

@@ -24,7 +24,9 @@ from typing import Any, Optional
 # The rows of models/transformer.py:ARCHS (kept here as names only, so that
 # building a config imports no model code; tests/test_olmoe.py holds the two
 # lists equal).
-LM_ARCHS = ("gpt2", "olmoe")
+LM_ARCHS = ("gpt2", "olmoe", "smallthinker")
+# ... of which those that route dropless (an MoE model: lm_parallelism=ep).
+_DROPLESS_ARCHS = ("olmoe", "smallthinker")
 
 
 @dataclass
@@ -114,13 +116,16 @@ class TrainConfig:
     lm_corpus_tokens: int = 1_000_000
     lm_corpus_file: str = ""         # byte-level REAL corpus from any local file ("" = synthetic Markov stream)
     lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE model, experts sharded over 'data'; also how an MoE model is chosen on ONE chip)
-    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) — models/transformer.py ARCHS
+    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) — models/transformer.py ARCHS
+    lm_kv_heads: int = 0             # key/value heads, each serving lm_heads / lm_kv_heads query heads (0 = lm_heads); sp on one device or ep, attention full | flash
+    lm_head_dim: int = 0             # head size (0 = lm_d_model / lm_heads)
     lm_ffn_dim: int = 0              # FFN / expert width (0 = 4 * lm_d_model)
     lm_attention: str = "auto"       # auto | full | flash (fused Pallas kernel). full/flash are sequence-local: sp over >1 device requires auto (ring)
     lm_model_axis: int = 0           # tp/pp: size of the 'model' mesh axis (0 = all devices)
     lm_microbatches: int = 4         # pp: GPipe microbatch count
     lm_experts: int = 8              # ep: expert count (divisible by device count)
-    lm_moe_top_k: int = 1            # ep: experts per token. gpt2 arch (capacity routing): 1 = switch, 2 = GShard top-2; olmoe arch (dropless): 1..lm_experts
+    lm_moe_top_k: int = 1            # ep: experts per token. gpt2 arch (capacity routing): 1 = switch, 2 = GShard top-2; olmoe / smallthinker arch (dropless): 1..lm_experts
+    lm_experts_held: int = 0         # dropless archs: experts this model holds of each layer's lm_experts, the first block of that many (0 = all); the router stays lm_experts wide and the layer computes its own experts' part of the result (one chip of an expert-parallel deployment, without the exchange)
 
     # -- fault injection (tests / straggler drills; SURVEY §5.3: the
     #    reference had none) --
@@ -208,10 +213,10 @@ class TrainConfig:
         if self.lm_arch not in LM_ARCHS:
             raise ValueError(f"unknown lm_arch {self.lm_arch!r} "
                              f"({' | '.join(LM_ARCHS)})")
-        if self.lm_arch == "olmoe":
+        if self.lm_arch in _DROPLESS_ARCHS:
             if self.lm_parallelism != "ep":
-                raise ValueError("lm_arch=olmoe is an MoE model: pass "
-                                 "lm_parallelism=ep (on one chip too)")
+                raise ValueError(f"lm_arch={self.lm_arch} is an MoE model: "
+                                 f"pass lm_parallelism=ep (on one chip too)")
             if not 1 <= self.lm_moe_top_k <= self.lm_experts:
                 raise ValueError(f"lm_moe_top_k={self.lm_moe_top_k} (dropless "
                                  f"routing: must be in 1..lm_experts="
@@ -223,6 +228,30 @@ class TrainConfig:
                              "routing: must be 1 [switch] or 2 [GShard "
                              "top-2]; lm_arch=olmoe routes dropless with any "
                              "k up to lm_experts)")
+        if self.lm_kv_heads < 0 or self.lm_heads % (self.lm_kv_heads
+                                                    or self.lm_heads):
+            raise ValueError(f"lm_kv_heads={self.lm_kv_heads} (must be 0 = "
+                             f"lm_heads, or divide lm_heads="
+                             f"{self.lm_heads})")
+        if self.lm_head_dim < 0 or (self.lm_head_dim or 2) % 2:
+            raise ValueError(f"lm_head_dim={self.lm_head_dim} (must be 0 = "
+                             "lm_d_model / lm_heads, or even)")
+        if (self.lm_kv_heads not in (0, self.lm_heads) or self.lm_head_dim) \
+                and self.lm_parallelism in ("tp", "pp"):
+            raise ValueError("lm_kv_heads / lm_head_dim: tp and pp are built "
+                             "for equal head counts of lm_d_model / "
+                             "lm_heads; use lm_parallelism sp or ep")
+        if self.lm_experts_held:
+            if self.lm_arch not in _DROPLESS_ARCHS:
+                raise ValueError(
+                    f"lm_experts_held={self.lm_experts_held} needs a "
+                    f"dropless arch ({' | '.join(_DROPLESS_ARCHS)}): the "
+                    f"capacity path shards its experts over the mesh")
+            if self.lm_experts_held < 0 \
+                    or self.lm_experts % self.lm_experts_held:
+                raise ValueError(f"lm_experts_held={self.lm_experts_held} "
+                                 f"(must divide lm_experts="
+                                 f"{self.lm_experts})")
         if self.lm_ffn_dim < 0:
             raise ValueError(f"lm_ffn_dim={self.lm_ffn_dim} (must be >= 0; "
                              "0 = 4 * lm_d_model)")

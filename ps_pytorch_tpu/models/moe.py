@@ -1,6 +1,7 @@
 """Mixture-of-Experts transformer LM: capacity routing (switch top-1, GShard
 top-2; ``MoEMLP``) or dropless top-k routing by sort and grouped matmul
-(``DroplessMoE``, the ``olmoe`` arch). The arch picks one per model.
+(``DroplessMoE``, the ``olmoe`` and ``smallthinker`` archs; it can hold a share
+of its experts). The arch picks one per model.
 
 Beyond-parity model family backing expert parallelism (``parallel/ep.py``;
 the reference has no MoE or EP anywhere, SURVEY §2.5). Design points:
@@ -49,14 +50,15 @@ import jax
 import jax.numpy as jnp
 
 from ps_pytorch_tpu.models.transformer import (
-    ARCHS, attention_sublayer, embed_tokens, make_norm,
+    ARCHS, attention_sublayer, embed_tokens, make_norm, remat_block,
 )
 from ps_pytorch_tpu.ops.grouped_matmul import gmm
 
 # What a dropless model returns beside its logits (and the ep step passes
 # on), with how each is taken over the layers.
 _OVER_LAYERS = {"aux": jnp.mean, "z_loss": jnp.mean,
-                "expert_load_max_over_mean": jnp.max, "moe_dropped": jnp.sum}
+                "expert_load_max_over_mean": jnp.max, "moe_dropped": jnp.sum,
+                "moe_held_share": jnp.mean}
 DROPLESS_STATS = tuple(_OVER_LAYERS)
 
 
@@ -192,26 +194,80 @@ class MoEMLP(nn.Module):
         return y, aux
 
 
+# Rows the held experts' part of a layer is sized for, over what they get at
+# balance (T * k * held / E): a block of experts that draws more than this
+# takes the overflow path below, which is slower and drops nothing.
+HELD_ROWS_SLACK = 1.5
+HELD_ROWS_TILE = 512      # ... in whole multiples of this many rows
+_ACTS = {"silu": nn.silu, "relu": nn.relu}
+
+
+def _when(pred, fn, args, ints):
+    """``fn(*args, *ints)`` where ``pred``, else zeros of its shape, with
+    neither pass run nor any residual kept where it is false: the backward
+    pass is a ``cond`` of its own over ``jax.vjp(fn)`` in ``args`` (float
+    arrays; ``ints`` carry no gradient), from the arguments alone. (A
+    ``cond`` differentiated by JAX keeps both branches' residuals, zeros for
+    the one not taken: the full-size buffers this exists to avoid.)"""
+    @jax.custom_vjp
+    def run(pred, args, ints):
+        out = jax.eval_shape(fn, *args, *ints)
+        return jax.lax.cond(
+            pred, lambda: fn(*args, *ints),
+            lambda: jnp.zeros(out.shape, out.dtype))
+
+    def fwd(pred, args, ints):
+        return run(pred, args, ints), (pred, args, ints)
+
+    def bwd(res, ct):
+        pred, args, ints = res
+        grads = jax.lax.cond(
+            pred,
+            lambda: jax.vjp(lambda *a: fn(*a, *ints), *args)[1](ct),
+            lambda: tuple(jnp.zeros_like(a) for a in args))
+        return None, grads, None
+
+    run.defvjp(fwd, bwd)
+    return run(pred, tuple(args), tuple(ints))
+
+
 class DroplessMoE(nn.Module):
-    """Dropless top-k MoE FFN with SiLU-gated (SwiGLU) experts, as OLMoE's.
+    """Dropless top-k MoE FFN with gated experts, OLMoE's by default.
 
         r = h Wr (float32, highest precision)    p = softmax(r)
-        I = top-k of p,  g_i = p_i               (gates NOT renormalised)
-        y = sum_{i in I} g_i * (silu(h Wgate_i) * (h Wup_i)) Wdown_i
+        I = top-k of p,  g_i = p_i     (``gate_norm``: g_i = p_i / sum_I p)
+        y = sum_{i in I} g_i * (act(h Wgate_i) * (h Wup_i)) Wdown_i
+
+    with ``act`` SiLU (SwiGLU) or ReLU, and the router reading ``router_x``
+    where the caller hands one (an arch whose router sits before attention)
+    in place of ``h``.
 
     The T*k assignments are sorted by expert (stable: inside an expert the
     order is the token order), the rows gathered, the three matmuls run as
-    grouped matmuls over the ``n_experts`` ragged groups
-    (``ops/grouped_matmul.gmm``), scaled by the gates and scatter-added
-    back. Nothing here is sized T x E x anything but the router's own logits
-    and probabilities, and no assignment is ever dropped.
+    grouped matmuls over the ragged groups (``ops/grouped_matmul.gmm``),
+    scaled by the gates and scatter-added back. Nothing here is sized T x E x
+    anything but the router's own logits and probabilities, and no assignment
+    is ever dropped.
+
+    **A share of the experts** (``n_held`` < ``n_experts``; expert
+    parallelism's layer, here without its exchange): the module holds the
+    contiguous block ``share * n_held .. (share + 1) * n_held - 1``. The
+    router, its softmax, the top-k and the gates stay ``n_experts`` wide; an
+    assignment to an expert not held sorts past the last held group, is not
+    multiplied and adds nothing, so ``y`` is this block's part of the layer's
+    result (the shares' parts add up to the whole). Gather, grouped matmuls
+    and scatter-add run over the first ``HELD_ROWS_SLACK * T*k * n_held /
+    n_experts`` sorted rows; where the block drew more, the rows past them go
+    through the same three steps under a ``cond`` (``_when``): nothing held is
+    dropped, whatever the router does.
 
     Returns ``(y, stats)`` with ``stats`` keyed by ``DROPLESS_STATS``:
-    ``aux`` = E * sum_e f_e P_e over ALL k choices (f_e = assignments to e /
-    T, so sum_e f_e = k; P_e the mean router probability — what HF's
-    ``load_balancing_loss_func`` computes), ``z_loss`` = mean
-    logsumexp(r)^2, ``expert_load_max_over_mean`` = busiest expert's
-    assignments / (T*k/E), ``moe_dropped`` = assignments whose output was
+    ``aux`` = E * sum_e f_e P_e over ALL k choices and all E router outputs
+    (f_e = assignments to e / T, so sum_e f_e = k; P_e the mean router
+    probability — what HF's ``load_balancing_loss_func`` computes),
+    ``z_loss`` = mean logsumexp(r)^2, ``expert_load_max_over_mean`` = busiest
+    HELD expert's assignments / (T*k/E), ``moe_held_share`` = assignments to
+    held experts / (T*k), ``moe_dropped`` = held assignments whose output was
     not added (counted from the scatter's own indices; 0 by construction).
     """
     n_experts: int
@@ -219,55 +275,103 @@ class DroplessMoE(nn.Module):
     d_hidden: int
     top_k: int = 8
     dtype: Any = jnp.float32
+    act: str = "silu"
+    gate_norm: bool = False
+    n_held: int = 0                   # experts held here (0 = all)
+    share: int = 0                    # which block of n_held, 0-based
+    down_std: float = 0.0             # experts_down init: normal(std) | lecun_normal
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
         b, s, d = x.shape
         e, k, f = self.n_experts, self.top_k, self.d_hidden
+        held = self.n_held or e
         if not 1 <= k <= e:
             raise ValueError(f"top_k={k} must be in 1..n_experts={e}")
+        if e % held or not 0 <= self.share < e // held:
+            raise ValueError(f"n_held={held} must divide n_experts={e} and "
+                             f"share={self.share} name one of its blocks")
         tokens = x.reshape(-1, d)                     # [T, D]
         t = tokens.shape[0]
         # The router is d x E: float32 under `highest` costs nothing, and a
         # bf16-grade pass flips ties between the k-th and (k+1)-th expert.
+        router_in = tokens if router_x is None else router_x.reshape(-1, d)
         router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           precision=jax.lax.Precision.HIGHEST,
-                          name="router")(tokens.astype(jnp.float32))
+                          name="router")(router_in.astype(jnp.float32))
         probs = jax.nn.softmax(router, axis=-1)       # [T, E] float32
         gates, idx = jax.lax.top_k(probs, k)          # [T, k]
+        if self.gate_norm:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (e, d, f))
-        w_up = self.param("experts_up", init, (e, d, f))
-        w_down = self.param("experts_down", init, (e, f, d))
+        w_gate = self.param("experts_gate", init, (held, d, f))
+        w_up = self.param("experts_up", init, (held, d, f))
+        w_down = self.param(
+            "experts_down", nn.initializers.normal(self.down_std)
+            if self.down_std else init, (held, f, d))
+        act = _ACTS[self.act]
 
-        # Assignment a = token * k + choice; sorted by expert, stable.
+        # Assignment a = token * k + choice; sorted by expert, stable. One
+        # to an expert not held takes the key past the last held group.
         flat_e = idx.reshape(-1)                      # [T*k]
-        order = jnp.argsort(flat_e, stable=True)
-        tok = order // k                              # source row of each sorted assignment
-        group_sizes = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
-        # The float32 expert weights go to the kernels as they are: a tile is
-        # cast to the rows' dtype in VMEM, and the weight gradient comes back
-        # float32 from the float32 accumulator (ops/grouped_matmul.py).
-        xs = tokens[tok].astype(self.dtype)           # gather [T*k, D]
-        h = nn.silu(gmm(xs, w_gate, group_sizes)) * \
-            gmm(xs, w_up, group_sizes)
-        out = gmm(h, w_down, group_sizes)
-        out = out.astype(jnp.float32) * gates.reshape(-1)[order][:, None]
-        y = jnp.zeros((t, d), jnp.float32).at[tok].add(out)
+        load = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        first = self.share * held
+        group_sizes = load[first:first + held]
+        key = flat_e if held == e else jnp.where(
+            (flat_e >= first) & (flat_e < first + held), flat_e - first, held)
+        order = jnp.argsort(key, stable=True)
+        flat_gates = gates.reshape(-1)
+        n_held_rows = jnp.sum(group_sizes)
+
+        def part(tokens, flat_gates, w_gate, w_up, w_down, order, sizes):
+            """The rows ``order`` (sorted assignments), the first
+            ``sum(sizes)`` of which the groups cover: gathered, through the
+            experts, gated, scatter-added to their tokens."""
+            tok = order // k              # source row of each sorted assignment
+            # The float32 expert weights go to the kernels as they are: a
+            # tile is cast to the rows' dtype in VMEM, and the weight gradient
+            # comes back float32 from the float32 accumulator
+            # (ops/grouped_matmul.py).
+            xs = tokens[tok].astype(self.dtype)       # gather [rows, D]
+            h = act(gmm(xs, w_gate, sizes)) * gmm(xs, w_up, sizes)
+            out = gmm(h, w_down, sizes)
+            out = out.astype(jnp.float32) * flat_gates[order][:, None]
+            return jnp.zeros((t, d), jnp.float32).at[tok].add(out)
+
+        # Rows the main part is sized for: all of them, or the held block's
+        # balanced share with HELD_ROWS_SLACK, in whole row tiles.
+        rows = t * k if held == e else min(
+            t * k, -(-int(HELD_ROWS_SLACK * t * k * held / e)
+                     // HELD_ROWS_TILE) * HELD_ROWS_TILE)
+        weights = (w_gate, w_up, w_down)
+        if rows == t * k:
+            sizes_main = group_sizes
+            y = part(tokens, flat_gates, *weights, order, group_sizes)
+        else:
+            ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
+            sizes_main = jnp.diff(ends, prepend=0)
+            y = part(tokens, flat_gates, *weights, order[:rows], sizes_main)
+            y = y + _when(n_held_rows > rows, part,
+                          (tokens, flat_gates, *weights),
+                          (order[rows:], group_sizes - sizes_main))
 
         # Counters, off the gradient path. Rows the grouped matmul covered
-        # are the first sum(group_sizes); each adds one to its token's count.
-        covered = jnp.arange(t * k) < jnp.sum(group_sizes)
-        added = jnp.zeros((t,), jnp.int32).at[tok].add(
+        # are the first sum(sizes) of a part; each adds one to its token's
+        # count (the overflow part's, run or not run as a whole, by their
+        # number).
+        covered = jnp.arange(rows) < jnp.sum(sizes_main)
+        added = jnp.zeros((t,), jnp.int32).at[order[:rows] // k].add(
             covered.astype(jnp.int32))
-        load = group_sizes.astype(jnp.float32)
+        added = jnp.sum(added) + jnp.maximum(n_held_rows - rows, 0)
         stats = {
-            "aux": e * jnp.sum((load / t) * jnp.mean(probs, axis=0)),
+            "aux": e * jnp.sum((load.astype(jnp.float32) / t)
+                               * jnp.mean(probs, axis=0)),
             "z_loss": jnp.mean(jax.nn.logsumexp(router, axis=-1) ** 2),
-            "expert_load_max_over_mean": jnp.max(load) * e / (t * k),
-            "moe_dropped": jnp.float32(t * k) - jnp.sum(added).astype(
-                jnp.float32),
+            "expert_load_max_over_mean":
+                jnp.max(group_sizes).astype(jnp.float32) * e / (t * k),
+            "moe_dropped": (n_held_rows - added).astype(jnp.float32),
+            "moe_held_share": n_held_rows.astype(jnp.float32) / (t * k),
         }
         return y.reshape(b, s, d).astype(x.dtype), stats
 
@@ -297,20 +401,31 @@ class MoEBlock(nn.Module):
     decode_cache_len: int = 0
     arch: str = "gpt2"                # ARCHS row: attention half AND which MoE FFN
     ffn_dim: int = 0                  # expert width (0 = 4 * d_model)
+    layer: int = 0                    # index in the stack (the layer's kind)
+    kv_heads: int = 0                 # key/value heads (0 = n_heads)
+    head_dim: int = 0                 # 0 = d_model / n_heads
+    experts_held: int = 0             # dropless: experts held here (0 = all)
+    experts_share: int = 0            # ... which block of them, 0-based
 
     @nn.compact
     def __call__(self, x, positions=None):
         b, s, d = x.shape
-        x = attention_sublayer(
+        a = ARCHS[self.arch]
+        x, normed = attention_sublayer(
             self, x, positions, arch=self.arch, n_heads=self.n_heads,
             dtype=self.dtype, attention_impl=self.attention_impl,
-            decode=self.decode, decode_cache_len=self.decode_cache_len)
+            decode=self.decode, decode_cache_len=self.decode_cache_len,
+            layer=self.layer, kv_heads=self.kv_heads, head_dim=self.head_dim)
         y = make_norm(self.arch, self.dtype)(x)
-        if ARCHS[self.arch].dropless:
+        if a.dropless:
             m, aux = DroplessMoE(self.n_experts, self.d_model,
                                  self.ffn_dim or 4 * self.d_model,
                                  top_k=self.top_k, dtype=self.dtype,
-                                 name="moe")(y)
+                                 act=a.expert_act, gate_norm=a.gate_norm,
+                                 n_held=self.experts_held,
+                                 share=self.experts_share,
+                                 down_std=a.expert_down_std, name="moe")(
+                y, normed if a.early_router else None)
         else:
             m, aux = MoEMLP(self.n_experts, self.d_model,
                             self.ffn_dim or 4 * self.d_model,
@@ -331,7 +446,7 @@ class MoETransformerLM(nn.Module):
     scalar sum of the layers' load-balance losses; for a dropless arch a dict
     keyed by ``DROPLESS_STATS`` (``aux`` and ``z_loss`` averaged over layers,
     the busiest layer's ``expert_load_max_over_mean``, ``moe_dropped``
-    summed)."""
+    summed, ``moe_held_share`` averaged)."""
     vocab_size: int = 256
     n_layers: int = 2
     n_heads: int = 4
@@ -346,6 +461,10 @@ class MoETransformerLM(nn.Module):
     attention_impl: str = "full"      # "full" | "flash"
     arch: str = "gpt2"                # ARCHS row (models/transformer.py)
     ffn_dim: int = 0                  # expert width (0 = 4 * d_model)
+    kv_heads: int = 0                 # key/value heads (0 = n_heads)
+    head_dim: int = 0                 # 0 = d_model / n_heads
+    experts_held: int = 0             # dropless: experts held here (0 = all)
+    experts_share: int = 0            # ... which block of them, 0-based
     # Per-block remat (see models/transformer.py TransformerLM.remat); the
     # recompute replays the block's all_to_alls, which is SPMD-legal.
     remat: bool = False
@@ -361,7 +480,7 @@ class MoETransformerLM(nn.Module):
         x = embed_tokens(tokens, positions, arch=self.arch,
                          vocab_size=self.vocab_size, d_model=self.d_model,
                          max_seq_len=self.max_seq_len, dtype=self.dtype)
-        Blk = nn.remat(MoEBlock) if (self.remat and not self.decode) \
+        Blk = remat_block(MoEBlock) if (self.remat and not self.decode) \
             else MoEBlock
         per_layer = []
         for i in range(self.n_layers):
@@ -373,7 +492,10 @@ class MoETransformerLM(nn.Module):
                          attention_impl=self.attention_impl,
                          dtype=self.dtype, decode=self.decode,
                          decode_cache_len=self.decode_cache_len,
-                         arch=self.arch, ffn_dim=self.ffn_dim,
+                         arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
+                         kv_heads=self.kv_heads, head_dim=self.head_dim,
+                         experts_held=self.experts_held,
+                         experts_share=self.experts_share,
                          name=f"block_{i}")(x, positions)
             per_layer.append(aux)
         if ARCHS[self.arch].dropless:

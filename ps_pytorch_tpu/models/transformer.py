@@ -12,13 +12,13 @@ crosses shards. Learned positional embeddings are indexed by GLOBAL token
 position, passed in by the caller (the sp step knows each shard's offset).
 """
 
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ps_pytorch_tpu.ops.flash_attention import flash_attention
+from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES, flash_attention
 from ps_pytorch_tpu.parallel.ring import full_attention, ring_attention
 
 
@@ -34,6 +34,26 @@ class Arch(NamedTuple):
     dropless: bool = False      # MoE FFN: routed SwiGLU, sort + grouped matmul | capacity GELU
     embed_std: float = 0.0      # token embedding init: normal(std) | flax's default (1/sqrt(d))
     z_loss_coef: float = 0.0    # router z-loss in the ep step's loss
+    # Layers of several kinds: layer l is of kind l % len(pattern).
+    window: int = 0             # keys a window layer's query sees, itself included
+    window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
+    rope_layers: Tuple[int, ...] = ()     # per layer of the period: 1 = RoPE | 0 = no position encoding; (): every layer
+    # The dropless expert layer.
+    gate_norm: bool = False     # top-k gates renormalised to sum to 1
+    expert_act: str = "silu"    # the gate projection's activation: silu | relu
+    early_router: bool = False  # the router reads the block's first norm (before attention), not the second
+    expert_down_std: float = 0.0    # experts' down projection init: normal(std) | flax's lecun_normal
+
+    def layer_window(self, layer: int) -> Optional[int]:
+        if self.window_layers and \
+                self.window_layers[layer % len(self.window_layers)]:
+            return self.window
+        return None
+
+    def layer_rope(self, layer: int) -> bool:
+        return bool(self.rope_theta) and (
+            not self.rope_layers
+            or bool(self.rope_layers[layer % len(self.rope_layers)]))
 
 
 ARCHS = {
@@ -50,6 +70,26 @@ ARCHS = {
     "olmoe": Arch(rms_norm=True, norm_eps=1e-5, rope_theta=10000.0,
                   qk_norm=True, dropless=True, z_loss_coef=0.001,
                   embed_std=1.0),
+    # SmallThinker-21BA3B-Instruct (PowerInfer/SmallThinker-21BA3B-Instruct
+    # config.json): rms_norm_eps 1e-6, sliding_window_size 4096 with RoPE
+    # (theta 1.5e6) on three layers of four, full causal attention without
+    # position encoding on the fourth (sliding_window_layout = rope_layout =
+    # 0 1 1 1), top-6 gates renormalised (norm_topk_prob), ReLU-gated
+    # experts, the router on the pre-attention norm; no q/k norm, no z-loss.
+    # embed_std as olmoe's, for the same reason. expert_down_std: HF's
+    # initializer_range 0.02 over sqrt(2 x 52 layers), GPT-2's rule for a
+    # projection into the residual stream at the published depth. Under
+    # lecun_normal one expert's output is 70% of the stream's norm, a gate is
+    # 1/6 of it (renormalised), and a near-tie between the 6th and 7th expert
+    # (600 of 16384 tokens between a bfloat16 model and a float32 reference)
+    # moves a logit by 0.8-1.0 of 6: the chip could not tell bfloat16 from
+    # float8 by the logits (PERF.md, PR 29). At 0.002 an expert is 4% of the
+    # stream, as in a trained model.
+    "smallthinker": Arch(rms_norm=True, norm_eps=1e-6, rope_theta=1.5e6,
+                         dropless=True, embed_std=1.0, window=4096,
+                         window_layers=(0, 1, 1, 1), rope_layers=(0, 1, 1, 1),
+                         gate_norm=True, expert_act="relu",
+                         early_router=True, expert_down_std=0.002),
 }
 
 
@@ -79,15 +119,22 @@ def rope(x, positions, theta: float):
 def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
                        n_heads: int, dtype, attention_impl: str,
                        axis_name: str = "data", decode: bool = False,
-                       decode_cache_len: int = 0):
-    """``x + Wo . attention(norm(x))``: the one q/k/v/o path of both
-    ``Block`` and ``models/moe.MoEBlock``, called from their ``@nn.compact``
-    body, so its sub-modules are numbered in the CALLER's scope (GPT-2:
-    ``LayerNorm_0``, ``Dense_0..3``, the tree
-    ``benchmark/reference/gpt2_medium.py`` reads)."""
+                       decode_cache_len: int = 0, layer: int = 0,
+                       kv_heads: int = 0, head_dim: int = 0):
+    """``x + Wo . attention(norm(x))`` and ``norm(x)`` (an early router's
+    input): the one q/k/v/o path of both ``Block`` and
+    ``models/moe.MoEBlock``, called from their ``@nn.compact`` body, so its
+    sub-modules are numbered in the CALLER's scope (GPT-2: ``LayerNorm_0``,
+    ``Dense_0..3``, the tree ``benchmark/reference/gpt2_medium.py`` reads).
+    ``layer`` picks the layer's kind where the arch mixes kinds (window or
+    not, RoPE or not); ``kv_heads`` (0 = ``n_heads``) key/value heads of
+    ``head_dim`` (0 = ``d / n_heads``) serve ``n_heads / kv_heads`` query
+    heads each."""
     a = ARCHS[arch]
     b, s, d = x.shape
-    hd = d // n_heads
+    hd = head_dim or d // n_heads
+    n_kv = kv_heads or n_heads
+    window = a.layer_window(layer)
     if positions is None:
         positions = jnp.arange(s)
     y = make_norm(arch, dtype)(x)
@@ -97,29 +144,54 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     # head-aligned (d = heads*hd), so attention can stay shard-local; a
     # packed qkv kernel puts shard boundaries inside q/k/v
     # (parallel/tp.py layout table).
-    q = nn.Dense(d, use_bias=False, dtype=dtype)(y)
-    k = nn.Dense(d, use_bias=False, dtype=dtype)(y)
-    v = nn.Dense(d, use_bias=False, dtype=dtype)(y)
+    q = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype)(y)
+    k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
+    v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
     if a.qk_norm:
         q = make_norm(arch, dtype, name="q_norm")(q)
         k = make_norm(arch, dtype, name="k_norm")(k)
-    to_heads = lambda t: t.reshape(b, s, n_heads, hd).transpose(0, 2, 1, 3)
+    to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
     q, k, v = to_heads(q), to_heads(k), to_heads(v)
-    if a.rope_theta:
+    if a.layer_rope(layer):
         q, k = rope(q, positions, a.rope_theta), rope(k, positions,
                                                       a.rope_theta)
     if decode:
-        o = cached_attention(mod, q, k, v, decode_cache_len)
+        o = cached_attention(mod, q, k, v, decode_cache_len, window=window)
     elif attention_impl == "ring":
-        o = ring_attention(q, k, v, axis_name, causal=True)
+        o = ring_attention(q, k, v, axis_name, causal=True, window=window)
     elif attention_impl == "flash":
         # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
         # materialization — the single-chip long-context path.
-        o = flash_attention(q, k, v, causal=True)
+        o = flash_attention(q, k, v, causal=True, window=window)
     else:
-        o = full_attention(q, k, v, causal=True)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-    return x + nn.Dense(d, use_bias=False, dtype=dtype)(o)
+        o = full_attention(q, k, v, causal=True, window=window)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
+    return x + nn.Dense(d, use_bias=False, dtype=dtype)(o), y
+
+
+def remat_block(block_cls):
+    """Per-block rematerialisation for both LM classes: the backward pass
+    keeps a block's input and recomputes its interior, except the flash
+    forward kernel's output and log-sum-exp (0.25 KiB a query head and token
+    at head dim 128), which are kept: recomputing them is the most expensive
+    part of the block at long sequences and the cheapest to save."""
+    return nn.remat(block_cls, policy=jax.checkpoint_policies
+                    .save_only_these_names(*SAVED_NAMES))
+
+
+def refuse_head_kinds(model, where: str) -> None:
+    """``parallel/tp.py`` and ``pp.py`` lay out and rebuild the block for
+    equal head counts of ``d / heads`` and one causal mask."""
+    a = ARCHS[getattr(model, "arch", "gpt2")]
+    kv = getattr(model, "kv_heads", 0) or model.n_heads
+    if kv != model.n_heads or getattr(model, "head_dim", 0) \
+            or a.window_layers:
+        raise ValueError(
+            f"{where} is not built for grouped-query heads, a head size "
+            f"apart from d / heads, or window layers (kv_heads={kv} of "
+            f"{model.n_heads}, head_dim={getattr(model, 'head_dim', 0)}, "
+            f"lm_arch={getattr(model, 'arch', 'gpt2')}): train those under "
+            f"lm_parallelism sp (one device) or ep")
 
 
 class EmbedRows(nn.Module):
@@ -155,7 +227,8 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
     return x
 
 
-def cached_attention(mod: nn.Module, q, k, v, length: int):
+def cached_attention(mod: nn.Module, q, k, v, length: int,
+                     window: Optional[int] = None):
     """Causal attention over a running k/v cache, shared by the dense
     Block and MoEBlock decode paths (the cache variables live in the
     CALLING module's "cache" collection).
@@ -166,7 +239,14 @@ def cached_attention(mod: nn.Module, q, k, v, length: int):
     steps). Queries at cache offset i..i+S-1 attend causally: query t sees
     cache slots <= i+t. Mirrors full_attention's numerics (scale, -inf
     mask, softmax) so decode logits match the training forward bit-for-bit
-    up to reduction order (tests/test_generate.py pins the parity)."""
+    up to reduction order (tests/test_generate.py pins the parity). Fewer
+    key/value heads than query heads, or a ``window``, are refused: the cache
+    row and its mask know neither."""
+    if window is not None or k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"decode is not built for grouped-query heads or a window (kv "
+            f"heads {k.shape[1]} of {q.shape[1]}, window {window}): the "
+            f"cache holds one row a query head and every key before it")
     b, h, s, hd = q.shape
     ck = mod.variable("cache", "k", jnp.zeros, (b, h, length, hd), q.dtype)
     cv = mod.variable("cache", "v", jnp.zeros, (b, h, length, hd), q.dtype)
@@ -203,17 +283,21 @@ class Block(nn.Module):
 
     arch: str = "gpt2"                # ARCHS row (norm, positions, q/k norm)
     ffn_dim: int = 0                  # dense FFN width (0 = 4 * d_model)
+    layer: int = 0                    # index in the stack: the layer's kind, where the arch mixes kinds
+    kv_heads: int = 0                 # key/value heads (0 = n_heads)
+    head_dim: int = 0                 # 0 = d_model / n_heads
 
     @nn.compact
     def __call__(self, x, positions=None):
         # x: [B, S_local, D]; positions: [S_local] global token positions
         # (read by RoPE archs only; None = 0..S-1)
         d = x.shape[-1]
-        x = attention_sublayer(
+        x, _ = attention_sublayer(
             self, x, positions, arch=self.arch, n_heads=self.n_heads,
             dtype=self.dtype, attention_impl=self.attention_impl,
             axis_name=self.axis_name, decode=self.decode,
-            decode_cache_len=self.decode_cache_len)
+            decode_cache_len=self.decode_cache_len, layer=self.layer,
+            kv_heads=self.kv_heads, head_dim=self.head_dim)
         y = make_norm(self.arch, self.dtype)(x)
         y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
         y = nn.gelu(y)
@@ -242,6 +326,8 @@ class TransformerLM(nn.Module):
     decode_cache_len: int = 0
     arch: str = "gpt2"                # ARCHS row; see Block
     ffn_dim: int = 0
+    kv_heads: int = 0
+    head_dim: int = 0
 
     @nn.compact
     def __call__(self, tokens, positions: Optional[jax.Array] = None,
@@ -253,13 +339,15 @@ class TransformerLM(nn.Module):
         x = embed_tokens(tokens, positions, arch=self.arch,
                          vocab_size=self.vocab_size, d_model=self.d_model,
                          max_seq_len=self.max_seq_len, dtype=self.dtype)
-        Blk = nn.remat(Block) if (self.remat and not self.decode) else Block
+        Blk = remat_block(Block) if (self.remat and not self.decode) \
+            else Block
         for i in range(self.n_layers):
             x = Blk(self.n_heads, self.d_model, self.dtype,
                     self.attention_impl, self.axis_name,
                     decode=self.decode,
                     decode_cache_len=self.decode_cache_len,
-                    arch=self.arch, ffn_dim=self.ffn_dim,
+                    arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
+                    kv_heads=self.kv_heads, head_dim=self.head_dim,
                     name=f"block_{i}")(x, positions)
         x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         # Logits in ``dtype``, like every other output of the model: the loss

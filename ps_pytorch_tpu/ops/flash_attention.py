@@ -17,7 +17,7 @@ Reference counterpart: the reference has no attention at all (CNN zoo,
 
 Design notes
 - What a grid step holds is a schedule computed from the shape alone:
-  ``flash_schedule(bh, s, d, itemsize, causal)``. On a v5e a 256x256x64
+  ``flash_schedule(bh, s, d, itemsize, causal, window=, bh_kv=)``. On a v5e a 256x256x64
   tile alone in a grid step costs 1.4 us however the grid is cut (the MXU's
   and the reductions' latencies with nothing to overlap them), so a step
   holds many tiles and a tile is large: ``g`` heads a step (a leading
@@ -51,6 +51,17 @@ Design notes
   (every shape whose head fits the budget) that is dQ itself, otherwise
   f32 partials that XLA sums. ``delta = rowsum(dO * O)`` is a cheap XLA
   elementwise pass outside.
+- Grouped-query heads and a sliding window are parameters of the same two
+  kernels. With fewer K/V heads than query heads (``group`` query heads a
+  K/V head) a step holds whole groups: its K/V blocks are the ``g // group``
+  heads that ``index // group`` addresses, so K and V are never repeated in
+  HBM, one fetch serves the group, and dK/dV sum over the group's query
+  heads in the f32 scratch that also sums them over q blocks. With a window
+  ``W`` (query i sees keys ``i-W < j <= i``) the tile loops start at the
+  band's first tile and mask its lower edge as they mask the diagonal, grid
+  steps wholly outside the band are dead as causally dead ones are, and the
+  index maps clamp to the band from both sides; such calls are named
+  ``flash_win_*`` so that a trace tells the two kinds of layer apart.
 - Matmuls run with ``preferred_element_type=f32``; q, k, v, dO are cast to
   f32 before the products and the probability tile to the value dtype for
   the PV product (under Mosaic an f32 operand takes one bf16 MXU pass).
@@ -63,6 +74,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -123,47 +135,59 @@ class FlashSchedule(NamedTuple):
     bwd_steps: int
     bwd_live: int
     vmem_bytes: int                   # the larger pass's blocks
+    group: int = 1          # query heads a K/V head (a step holds g // group K/V heads)
+    window: int = 0         # keys a query sees, itself included; 0: all before it
+    tiles: int = 0          # (q tile, kv tile) pairs a call's mask covers, either pass
+    live_tiles: int = 0     # ... of which the tile loops visit
 
     @property
     def dead(self) -> int:
         return self.steps - self.live
 
     def describe(self) -> str:
+        kind = (f" kv_heads={self.g // self.group}" if self.group > 1 else "") \
+            + (f" window={self.window}" if self.window else "")
         return (f"g={self.g}/{self.block_h} bq={self.block_q} "
                 f"kv={self.block_kv}/{self.block_kv_major} "
                 f"steps={self.steps} live={self.live} "
                 f"bwd_q={self.block_q}/{self.block_q_major} "
-                f"bwd_steps={self.bwd_steps} bwd_live={self.bwd_live}")
+                f"bwd_steps={self.bwd_steps} bwd_live={self.bwd_live}"
+                f"{kind} tiles={self.live_tiles}/{self.tiles}")
 
 
-def _vmem_bytes(g, bq, bkv, bkv_major, bq_major, s, d, itemsize):
+def _vmem_bytes(g, bq, bkv, bkv_major, bq_major, s, d, itemsize, group=1):
     """VMEM of one step's blocks, the larger of the two passes: inputs and
     outputs double-buffered, the f32 accumulators, the score tiles. The
     last dimension of a block pads to 128 lanes, an LSE row to 8 sublanes,
-    a [rows, 1] statistic to 128 lanes."""
+    a [rows, 1] statistic to 128 lanes. A step's K/V side holds ``g //
+    group`` heads."""
     dp = -(-d // 128) * 128
+    gk = g // group
     tiles = 4 * max(bq * bkv * 4, _TILE_BYTES)
     kv_axis, q_axis = s > bkv_major, s > bq_major
-    fwd = (2 * g * (2 * bq + 2 * bkv_major) * dp * itemsize    # q o k v
+    fwd = (2 * (g * 2 * bq + gk * 2 * bkv_major) * dp * itemsize   # q o k v
            + 2 * g * 8 * bq * 4                                # lse
            + kv_axis * g * bq * (dp + 2 * 128) * 4             # acc, m, l
            + tiles)
     dq_size = 4 if kv_axis else itemsize
-    bwd = (2 * g * (2 * bq_major + 2 * bkv_major) * dp * itemsize  # q dO k v
-           + 2 * g * (bq_major * dq_size + 2 * bkv_major * itemsize) * dp
+    bwd = (2 * (g * 2 * bq_major + gk * 2 * bkv_major) * dp * itemsize  # q dO k v
+           + 2 * (g * bq_major * dq_size + gk * 2 * bkv_major * itemsize) * dp
            + 2 * 2 * g * 8 * bq_major * 4                      # lse, delta
            + (dq_size < 4 and bkv_major > bkv) * g * bq_major * dp * 4
-           + q_axis * 2 * g * bkv_major * dp * 4               # dk, dv sums
+           + (q_axis or group > 1) * 2 * gk * bkv_major * dp * 4   # dk, dv sums
            + tiles)
     return max(fwd, bwd)
 
 
-def _live_blocks(s, q_rows, kv_rows, causal):
+def _live_blocks(s, q_rows, kv_rows, causal, window=0):
     """Of the (q block, kv block) pairs of an S x S grid of blocks: how many
-    there are, and how many are not causally dead (the kv block's first key
-    <= the q block's last query)."""
+    there are, and how many are not dead: the kv block's first key <= the q
+    block's last query and, under a window, its last key inside the window
+    of the q block's first query."""
     n_q, n_kv = s // q_rows, s // kv_rows
-    live = sum((not causal) or kj * kv_rows <= qi * q_rows + q_rows - 1
+    live = sum(((not causal) or kj * kv_rows <= qi * q_rows + q_rows - 1)
+               and (not window
+                    or qi * q_rows - (kj * kv_rows + kv_rows - 1) < window)
                for qi in range(n_q) for kj in range(n_kv))
     return n_q * n_kv, live
 
@@ -171,11 +195,16 @@ def _live_blocks(s, q_rows, kv_rows, causal):
 def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
                    block_q: Optional[int] = None,
                    block_kv: Optional[int] = None,
-                   block_kv_major: Optional[int] = None) -> FlashSchedule:
-    """The schedule of both kernels for [bh, s, d] inputs of ``itemsize``
-    bytes: pure, from the shape alone. ``block_q`` / ``block_kv`` /
+                   block_kv_major: Optional[int] = None, *,
+                   window: Optional[int] = None,
+                   bh_kv: Optional[int] = None) -> FlashSchedule:
+    """The schedule of both kernels for [bh, s, d] queries of ``itemsize``
+    bytes over [bh_kv, s, d] keys and values (``None``: as many heads as the
+    queries), under a ``window`` of keys (``None`` or >= s: every key before
+    the query): pure, from the shape alone. ``block_q`` / ``block_kv`` /
     ``block_kv_major`` are upper bounds (the tests' override). Raises
     ValueError when S has no power-of-two block divisor >= 8."""
+    group, window = _group(bh, bh_kv), _window(window, s, causal)
     # The compute tile: a whole row of scores where that is at most
     # _ROW_TILE wide (a plain softmax, no running statistics), else
     # _TILE x _TILE (measured on a v5e: PERF.md, PR 26).
@@ -188,48 +217,75 @@ def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
             f"sequence length; S={s} has none (use attention 'full')")
 
     def fits(g, kvm, qm):
-        return _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize) \
+        return _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group) \
             <= VMEM_BUDGET_BYTES
 
     # K/V rows (and the backward's q/dO rows) a step keeps: all of S, halved
-    # until one head fits the budget; never under a compute tile.
+    # until one head (one K/V head and its group of query heads) fits the
+    # budget; never under a compute tile. A q row is ``group`` heads deep.
     kvm = _pick_block(s, block_kv_major) if block_kv_major else s
     qm = s
-    while not fits(1, kvm, qm) and (kvm > bkv or qm > bq):
-        if qm >= kvm and qm > bq:
+    while not fits(group, kvm, qm) and (kvm > bkv or qm > bq):
+        if group * qm >= kvm and qm > bq or kvm == bkv:
             qm = _next_smaller(s, qm, bq)
         else:
             kvm = _next_smaller(s, kvm, bkv)
-    # Heads a step: the largest divisor of bh that fits, leaves the call
-    # _MIN_STEPS steps where bh allows, and is not past the point where a
-    # step already holds _STEP_WORK live score elements.
+    # Heads a step: the largest divisor of bh (in whole groups) that fits,
+    # leaves the call _MIN_STEPS steps where bh allows, and is not past the
+    # point where a step already holds _STEP_WORK live score elements.
     per_head = (s * s // 2 if causal else s * s) // ((s // kvm) * (s // qm))
-    g = 1
-    for cand in range(2, bh + 1):
-        if bh % cand:
+    g = group
+    for cand in range(group + 1, bh + 1):
+        if bh % cand or cand % group:
             continue
         if not fits(cand, kvm, qm) or bh // cand < min(bh, _MIN_STEPS):
             break
         g = cand
         if cand * per_head >= _STEP_WORK:
             break
-    return _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm)
+    return _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm,
+                     group=group, window=window)
 
 
-def _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm, hb=None):
+def _group(bh, bh_kv):
+    """Query heads a K/V head."""
+    if bh_kv is None or bh_kv == bh:
+        return 1
+    if bh_kv < 1 or bh % bh_kv:
+        raise ValueError(f"{bh} query heads do not divide over {bh_kv} "
+                         f"key/value heads")
+    return bh // bh_kv
+
+
+def _window(window, s, causal):
+    """0 where the window never closes (none given, or >= S)."""
+    if window is None or window >= s:
+        return 0
+    if window < 1 or not causal:
+        raise ValueError(f"window={window}: a window is >= 1 key and needs "
+                         f"causal=True")
+    return int(window)
+
+
+def _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm, hb=None, *,
+              group=1, window=0):
     """The grids and step counts that follow from the block sizes."""
     if hb is None:
         # heads a compute tile: independent chains for the scheduler to
-        # interleave, while the f32 score tiles stay around _TILE_BYTES
+        # interleave, while the f32 score tiles stay around _TILE_BYTES; one
+        # where heads share K/V (a tile then reads one K/V head)
         hb = max(c for c in range(1, g + 1)
-                 if g % c == 0 and (c == 1 or c * bq * bkv * 4 <= _TILE_BYTES))
-    steps, live = _live_blocks(s, bq, kvm, causal)
-    bwd_steps, bwd_live = _live_blocks(s, qm, kvm, causal)
+                 if g % c == 0 and (c == 1 or group == 1
+                                    and c * bq * bkv * 4 <= _TILE_BYTES))
+    steps, live = _live_blocks(s, bq, kvm, causal, window)
+    bwd_steps, bwd_live = _live_blocks(s, qm, kvm, causal, window)
+    tiles, live_tiles = _live_blocks(s, bq, bkv, causal, window)
     n = bh // g
     return FlashSchedule(
         g, hb, bq, bkv, kvm, qm, (n, s // bq, s // kvm), n * steps, n * live,
         (n, s // kvm, s // qm), n * bwd_steps, n * bwd_live,
-        _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize))
+        _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group),
+        group, window, bh * tiles, bh * live_tiles)
 
 
 def _bdot(a, b, ca, cb):
@@ -242,14 +298,18 @@ def _bdot(a, b, ca, cb):
         preferred_element_type=jnp.float32)
 
 
-def _causal_mask(s, q0, k0, q_axis):
+def _mask(s, q0, k0, q_axis, window):
     """Mask a [heads, ., .] score tile whose queries start at ``q0`` and keys
     at ``k0``; queries run along ``q_axis`` — shared by both kernels so
-    forward and backward can never disagree on masking."""
+    forward and backward can never disagree on masking. A query sees the keys
+    at or before it, the last ``window`` of them where there is a window."""
     shape = (1,) + s.shape[1:]
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 3 - q_axis)
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+    ok = q_pos >= k_pos
+    if window:
+        ok = jnp.logical_and(ok, q_pos - k_pos < window)
+    return jnp.where(ok, s, NEG_INF)
 
 
 def _rows(start, size):
@@ -278,6 +338,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 causal, scale, sched):
     g, hb, bq, bkv, kvm = (sched.g, sched.block_h, sched.block_q,
                            sched.block_kv, sched.block_kv_major)
+    group, window = sched.group, sched.window
     d = q_ref.shape[-1]
     jm = pl.program_id(2)
     q0 = pl.program_id(1) * bq          # first query of this step
@@ -292,14 +353,28 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         n_full = jnp.minimum(_div(q0 + 1 - k0, bkv), n_live)
     else:
         n_live = n_full = n_tiles
+    t_lo = 0
+    if window:
+        # the band's lower edge: tiles whose last key the first query still
+        # sees are live; those whose first key the last query sees need no
+        # mask there
+        t_lo = jnp.minimum(_div(q0 - window + 1 - k0, bkv), n_live)
+        t_in = jnp.clip(_div(q0 + bq - window - k0 + bkv - 1, bkv), t_lo,
+                        n_live)
+        n_full = jnp.clip(n_full, t_in, n_live)
+
+    def _kv(hs):
+        """The K/V head(s) of query heads ``hs``: their own, or the one a
+        group shares (a tile then holds one query head)."""
+        return hs if group == 1 else pl.ds(_div(hs.start, group), 1)
 
     def _scores(hs, q, t, masked):
-        s = _bdot(q, k_ref[hs, _rows(t * bkv, bkv), :].astype(jnp.float32),
-                  2, 2)                                 # [hb, bq, bkv]
-        return _causal_mask(s, q0, k0 + t * bkv, 1) if masked else s
+        s = _bdot(q, k_ref[_kv(hs), _rows(t * bkv, bkv), :]
+                  .astype(jnp.float32), 2, 2)           # [hb, bq, bkv]
+        return _mask(s, q0, k0 + t * bkv, 1, window) if masked else s
 
     def _pv(hs, p, t):
-        v = v_ref[hs, _rows(t * bkv, bkv), :]
+        v = v_ref[_kv(hs), _rows(t * bkv, bkv), :]
         return _bdot(p.astype(v.dtype), v, 2, 1)        # [hb, bq, d]
 
     def _tile(t, carry, *, hs, q, masked):
@@ -336,8 +411,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                      jnp.zeros((hb, bq, d), jnp.float32))
         else:
             carry = tuple(ref[hs] for ref in scratch)
+        if window:
+            carry = jax.lax.fori_loop(
+                t_lo, t_in, partial(_tile, hs=hs, q=q, masked=True), carry)
         carry = jax.lax.fori_loop(
-            0, n_full, partial(_tile, hs=hs, q=q, masked=False), carry)
+            t_in if window else 0, n_full,
+            partial(_tile, hs=hs, q=q, masked=False), carry)
         if causal:
             carry = jax.lax.fori_loop(
                 n_full, n_live, partial(_tile, hs=hs, q=q, masked=True),
@@ -360,7 +439,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    @pl.when(n_live > 0)
+    @pl.when(n_live > t_lo)
     def _run():
         jax.lax.fori_loop(0, g // hb, _heads, None)
 
@@ -375,10 +454,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
     g, bq, kvm = sched.g, sched.block_q, sched.block_kv_major
+    gk, window = g // sched.group, sched.window
 
     def kv_map(b, i, jm):
-        if causal:  # a dead block is not fetched: keep the last live one
+        # a dead block is not fetched: keep the nearest live one
+        if causal:
             jm = jnp.minimum(jm, _div(i * bq + bq - 1, kvm))
+        if window:
+            jm = jnp.maximum(jm, _div(i * bq - window + 1, kvm))
         return (b, jm, 0)
 
     o, lse = pl.pallas_call(
@@ -386,8 +469,8 @@ def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
         grid=sched.grid,
         in_specs=[
             pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
-            pl.BlockSpec((g, kvm, d), kv_map),
-            pl.BlockSpec((g, kvm, d), kv_map),
+            pl.BlockSpec((gk, kvm, d), kv_map),
+            pl.BlockSpec((gk, kvm, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
@@ -404,7 +487,7 @@ def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
             pltpu.VMEM((g, bq, d), jnp.float32),
         ],
         compiler_params=_compiler_params(),
-        name="flash_fwd",
+        name="flash_win_fwd" if window else "flash_fwd",
         interpret=interpret,
     )(q3, k3, v3)
     return o, lse
@@ -418,10 +501,10 @@ def _bwd_scratch(sched, dq_dtype):
     """Which f32 accumulators the backward step needs beyond its output
     blocks: dQ's when the output block cannot hold the running sum itself
     (not f32, and more than one kv tile adds to it), dK/dV's when they
-    build up over several q blocks (steps)."""
+    build up over several q blocks (steps) or over a group's query heads."""
     return (dq_dtype != jnp.float32
             and sched.block_kv_major > sched.block_kv,
-            sched.bwd_grid[2] > 1)
+            sched.bwd_grid[2] > 1 or sched.group > 1)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -429,6 +512,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     g, hb, bq, bkv, kvm, qm = (sched.g, sched.block_h, sched.block_q,
                                sched.block_kv, sched.block_kv_major,
                                sched.block_q_major)
+    group, window = sched.group, sched.window
     im = pl.program_id(2)
     k0 = pl.program_id(1) * kvm         # first key of this step's K/V block
     q0 = im * qm                        # first query of this step's q block
@@ -441,10 +525,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     one_pair = n_q == 1 and n_kv == 1
     dq_sum = scratch.pop(0) if dq_scratch else dq_ref
     # dK/dV sum over the q tiles of a step in loop carries, and over the q
-    # blocks (steps), where there are several, in scratch
+    # blocks (steps) and a group's query heads, where there are several, in
+    # scratch
     dk_acc, dv_acc = scratch if dkv_scratch else (None, None)
-    # the step is dead when its last query comes before its first key
+    # the step is dead when its last query comes before its first key, or
+    # its first query after the window of its last key
     live = (q0 + qm - 1 >= k0) if causal else True
+    if window:
+        live = jnp.logical_and(live, q0 - (k0 + kvm - 1) < window)
 
     if dk_acc is not None:
         @pl.when(im == 0)
@@ -462,7 +550,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[hs, rows, :].astype(jnp.float32)
         s = _bdot(k, q, 2, 2)                               # [hb, bkv, bq]
         if masked:
-            s = _causal_mask(s, q0 + t * bq, ks, 2)
+            s = _mask(s, q0 + t * bq, ks, 2, window)
         p = jnp.exp(s - lse_ref[hs, t])
         dv = dv + _bdot(p, do, 2, 1)
         ds = p * (_bdot(v, do, 2, 2) - delta_ref[hs, t])
@@ -474,11 +562,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_sum[hs, rows, :] += dq
         return dk, dv
 
-    def _kv_tile(j, _, *, hs):
+    def _kv_tile(j, _, *, kv, hs):
+        """One kv tile of K/V head(s) ``kv`` against the q tiles of their
+        query heads: ``hs`` itself, or the ``group`` heads from ``hs`` on
+        that share the one K/V head (its tile is loaded and cast once for
+        all of them, and dK/dV leave once)."""
         rows = _rows(j * bkv, bkv)
         ks = k0 + j * bkv               # first key of the tile
-        k = k_ref[hs, rows, :].astype(jnp.float32)
-        v = v_ref[hs, rows, :].astype(jnp.float32)
+        k = k_ref[kv, rows, :].astype(jnp.float32)
+        v = v_ref[kv, rows, :].astype(jnp.float32)
+        t_hi = n_q
         if causal:
             # q tiles whose last query >= the first key are live; those
             # whose first query >= the last key need no mask
@@ -487,27 +580,53 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               n_q)
         else:
             t_live = t_full = 0
-        kw = dict(hs=hs, k=k, v=v, ks=ks)
-        carry = (jnp.zeros((hb, bkv, d), jnp.float32),) * 2
-        if causal:
+        if window:
+            # the band's far edge: q tiles whose first query still sees the
+            # last key are live; those whose last query sees the first key
+            # need no mask there
+            t_hi = jnp.minimum(_div(ks + bkv - 1 + window - q0 + bq - 1, bq),
+                               n_q)
+            t_live = jnp.minimum(t_live, t_hi)
+            t_full = jnp.minimum(t_full, t_hi)
+            t_in = jnp.clip(_div(ks + window - q0, bq), t_full, t_hi)
+
+        def _q_tiles(hs, carry):
+            kw = dict(hs=hs, k=k, v=v, ks=ks)
+            if causal:
+                carry = jax.lax.fori_loop(
+                    t_live, t_full, partial(_pair, masked=True, **kw), carry)
             carry = jax.lax.fori_loop(
-                t_live, t_full, partial(_pair, masked=True, **kw), carry)
-        dk, dv = jax.lax.fori_loop(
-            t_full, n_q, partial(_pair, masked=False, **kw), carry)
-        if dk_acc is None:
-            dk_ref[hs, rows, :] = dk.astype(dk_ref.dtype)
-            dv_ref[hs, rows, :] = dv.astype(dv_ref.dtype)
+                t_full, t_in if window else n_q,
+                partial(_pair, masked=False, **kw), carry)
+            if window:
+                carry = jax.lax.fori_loop(
+                    t_in, t_hi, partial(_pair, masked=True, **kw), carry)
+            return carry
+
+        carry = (jnp.zeros((hb, bkv, d), jnp.float32),) * 2
+        if group == 1:
+            dk, dv = _q_tiles(hs, carry)
         else:
-            dk_acc[hs, rows, :] += dk
-            dv_acc[hs, rows, :] += dv
+            dk, dv = jax.lax.fori_loop(
+                0, group, lambda h, c: _q_tiles(pl.ds(hs.start + h, 1), c),
+                carry)
+        if dk_acc is None:
+            dk_ref[kv, rows, :] = dk.astype(dk_ref.dtype)
+            dv_ref[kv, rows, :] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[kv, rows, :] += dk
+            dv_acc[kv, rows, :] += dv
 
     def _heads(hg, _):
-        jax.lax.fori_loop(0, n_kv, partial(_kv_tile, hs=pl.ds(hg * hb, hb)),
-                          None)
+        # a tile's query heads with their own K/V, or one K/V head with its
+        # group of query heads
+        hs = pl.ds(hg * hb * group, hb)
+        kv = hs if group == 1 else pl.ds(hg, 1)
+        jax.lax.fori_loop(0, n_kv, partial(_kv_tile, kv=kv, hs=hs), None)
 
     @pl.when(live)
     def _run():
-        jax.lax.fori_loop(0, g // hb, _heads, None)
+        jax.lax.fori_loop(0, g // (hb * group), _heads, None)
 
     if one_pair and causal:
         @pl.when(jnp.logical_not(live))
@@ -528,17 +647,21 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
     g, bq, kvm, qm = (sched.g, sched.block_q, sched.block_kv_major,
                       sched.block_q_major)
+    gk, window = g // sched.group, sched.window
     n_kvm = s // kvm
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)         # [bh, s/bq, 1, bq]
 
     def q_map(b, jm, im):
-        if causal:  # a dead block is not fetched: take the first live one
+        # a dead block is not fetched: take the nearest live one
+        if causal:
             im = jnp.maximum(im, _div(jm * kvm, qm))
+        if window:
+            im = jnp.minimum(im, _div(jm * kvm + kvm + window - 2, qm))
         return (b, im, 0)
 
     q_spec = pl.BlockSpec((g, qm, d), q_map)
-    kv_spec = pl.BlockSpec((g, kvm, d), lambda b, jm, im: (b, jm, 0))
+    kv_spec = pl.BlockSpec((gk, kvm, d), lambda b, jm, im: (b, jm, 0))
     row_spec = pl.BlockSpec((g, qm // bq, 1, bq),
                             lambda b, jm, im: q_map(b, jm, im) + (0,))
     # one kv block: the step's dQ is dQ; several: f32 partials, summed below
@@ -554,14 +677,14 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_kvm, bh, s, d), dq_dtype),
-            jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v3.dtype),
+            jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+            jax.ShapeDtypeStruct(v3.shape, v3.dtype),
         ],
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in
                         dq_scratch * [(g, qm, d)]
-                        + dkv_scratch * [(g, kvm, d), (g, kvm, d)]],
+                        + dkv_scratch * [(gk, kvm, d), (gk, kvm, d)]],
         compiler_params=_compiler_params(),
-        name="flash_bwd_dkv",
+        name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
     dq = dq[0] if n_kvm == 1 else jnp.sum(dq, axis=0).astype(q3.dtype)
@@ -578,8 +701,17 @@ def _flash(q3, k3, v3, causal, scale, sched, interpret):
     return o
 
 
+# What the forward kernel leaves for the backward, by name: a remat policy
+# that saves these (``jax.checkpoint_policies.save_only_these_names``) spares
+# the recomputed block its forward kernel at one [bh, S, D] output and one
+# float32 row a query.
+SAVED_NAMES = ("flash_o", "flash_lse")
+
+
 def _flash_fwd(q3, k3, v3, causal, scale, sched, interpret):
     o, lse = _fwd_call(q3, k3, v3, causal, scale, sched, interpret)
+    o = checkpoint_name(o, SAVED_NAMES[0])
+    lse = checkpoint_name(lse, SAVED_NAMES[1])
     return o, (q3, k3, v3, o, lse)
 
 
@@ -593,15 +725,18 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = False, scale: Optional[float] = None,
+                    causal: bool = False, window: Optional[int] = None,
+                    scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_kv: Optional[int] = None,
                     block_kv_major: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Fused attention over [B, H, S, D] tensors; drop-in for
-    ``ring.full_attention`` (same signature semantics, same output). The
-    schedule comes from ``flash_schedule`` at the inputs' shape; the block
-    arguments bound it from above (the tests' override).
+    """Fused attention over q [B, H, S, D] and k, v [B, Hkv, S, D], H a
+    multiple of Hkv (query head h reads key/value head ``h // (H / Hkv)``);
+    drop-in for ``ring.full_attention`` (same signature semantics, same
+    output). ``window``: query i sees keys ``i - window < j <= i`` (needs
+    ``causal``). The schedule comes from ``flash_schedule`` at the inputs'
+    shape; the block arguments bound it from above (the tests' override).
 
     Raises ValueError when S has no power-of-two block divisor >= 8: a
     caller that asked for the fused kernel is told it cannot have it.
@@ -609,12 +744,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if interpret is None:
         interpret = _interpret_default()
     b, h, s, d = q.shape
+    h_kv = k.shape[1]
     sched = flash_schedule(b * h, s, d, q.dtype.itemsize, causal,
-                           block_q, block_kv, block_kv_major)
+                           block_q, block_kv, block_kv_major,
+                           window=window, bh_kv=b * h_kv)
     if scale is None:
         scale = float(d) ** -0.5
     q3 = q.reshape(b * h, s, d)
-    k3 = k.reshape(b * h, s, d)
-    v3 = v.reshape(b * h, s, d)
+    k3 = k.reshape(b * h_kv, s, d)
+    v3 = v.reshape(b * h_kv, s, d)
     o3 = _flash(q3, k3, v3, causal, float(scale), sched, bool(interpret))
     return o3.reshape(b, h, s, d)

@@ -40,7 +40,7 @@ _EXPERT_KEY = "experts_"   # models/moe.py stacked expert param names
 # How each of the model's routing statistics crosses the data axis.
 _STAT_REDUCE = {"aux": jax.lax.pmean, "z_loss": jax.lax.pmean,
                 "expert_load_max_over_mean": jax.lax.pmax,
-                "moe_dropped": jax.lax.psum}
+                "moe_dropped": jax.lax.psum, "moe_held_share": jax.lax.pmean}
 
 
 def ep_param_specs(params, axis: str = "data"):
@@ -96,9 +96,11 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                        aux_coef: float = 0.01, remat: bool = False,
                        donate: bool = True) -> Callable:
     """-> step_fn(state, tokens) -> (state, {'loss', 'aux'}); a dropless
-    arch (``olmoe``) adds the rest of ``DROPLESS_STATS``: 'z_loss',
-    'expert_load_max_over_mean', 'moe_dropped', and its loss the arch's
-    z-loss term, scaled by the token count as ``aux`` is.
+    arch (``olmoe``, ``smallthinker``) adds the rest of ``DROPLESS_STATS``:
+    'z_loss', 'expert_load_max_over_mean', 'moe_dropped', 'moe_held_share',
+    and its loss the arch's z-loss term, scaled by the token count as ``aux``
+    is. A model that holds a share of its experts (``experts_held``) trains
+    that share's part of each layer, on one device: there is no exchange.
 
     tokens [B, S] int32, batch sharded over ``axis``. ``model`` must be
     built with ``ep_axis=axis`` and ``n_groups=1`` (each device dispatches

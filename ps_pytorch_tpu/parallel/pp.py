@@ -39,7 +39,9 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ps_pytorch_tpu.models.transformer import Block, EmbedRows
+from ps_pytorch_tpu.models.transformer import (
+    Block, EmbedRows, refuse_head_kinds,
+)
 from ps_pytorch_tpu.parallel.dp import TrainState
 
 
@@ -202,6 +204,7 @@ def make_pp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         # ring needs a sequence mesh axis; full/flash are sequence-local
         # and run fine inside the per-stage shard_map.
         raise ValueError("PP step requires attention_impl='full'|'flash'")
+    refuse_head_kinds(model, "pipeline parallelism")
     n_stages = mesh.shape[axis_name]
     M = num_microbatches
     stacked = jax.tree.leaves(state.params["blocks"])[0].shape[0]

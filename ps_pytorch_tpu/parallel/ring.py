@@ -49,6 +49,7 @@ def _block_attn(q, k, v, mask_bias):
 
 
 def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
+                   window: Optional[int] = None,
                    scale: Optional[float] = None):
     """Blockwise ring attention over mesh axis ``axis_name``.
 
@@ -58,8 +59,14 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
       causal: apply a causal mask over the GLOBAL sequence positions.
     Returns: [B, H, S_local, D] attention output for the local queries, in
     ``q.dtype``; the running max, sum and weighted values cross the hops in
-    float32.
+    float32. Fewer key/value heads than query heads, or a ``window``, are
+    refused: the hops know equal head counts and the one causal mask.
     """
+    if window is not None or k.shape[1] != q.shape[1]:
+        raise ValueError(
+            f"ring attention is not built for grouped-query heads or a "
+            f"window (kv heads {k.shape[1]} of {q.shape[1]}, window "
+            f"{window}): use lm_attention full or flash on one device")
     n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[2]
@@ -150,18 +157,27 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "data",
 
 
 def full_attention(q, k, v, *, causal: bool = False,
+                   window: Optional[int] = None,
                    scale: Optional[float] = None):
     """Unsharded reference implementation (materializes [S, S]) — the oracle
     ring_attention is tested against. Scores and softmax are float32 for any
-    input dtype; the output is ``q.dtype``."""
+    input dtype; the output is ``q.dtype``. ``k`` and ``v`` may hold fewer
+    heads than ``q`` (query head h reads head ``h // (H / Hkv)``: repeated
+    here, plainly); ``window``: query i sees keys ``i - window < j <= i``."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k,
                    preferred_element_type=jnp.float32)
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
-        ok = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        dist = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        ok = dist >= 0 if window is None else (dist >= 0) & (dist < window)
         s = jnp.where(ok[None, None], s, -jnp.inf)
+    elif window is not None:
+        raise ValueError("a window needs causal=True")
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)

@@ -43,6 +43,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import keystr, tree_flatten_with_path
 
+from ps_pytorch_tpu.models.transformer import refuse_head_kinds
 from ps_pytorch_tpu.parallel.dp import TrainState
 
 # flax auto-names the Block's Dense layers in call order
@@ -157,6 +158,7 @@ def make_tp_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     if getattr(model, "attention_impl", "full") != "full":
         raise ValueError("TP step requires attention_impl='full' "
                          "(ring attention shards sequence, not heads)")
+    refuse_head_kinds(model, "tensor parallelism")
 
     # Per-block remat (TransformerLM.remat): checkpointing the whole loss
     # instead would save no peak memory (the recompute holds all residuals

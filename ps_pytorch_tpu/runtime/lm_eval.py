@@ -41,11 +41,13 @@ def build_lm_model(cfg, **kw):
     stay float32), as for the CNNs."""
     from ps_pytorch_tpu.models import DTYPES
     geo = dict(lm_geometry(cfg), arch=cfg.lm_arch, ffn_dim=cfg.lm_ffn_dim,
+               kv_heads=cfg.lm_kv_heads, head_dim=cfg.lm_head_dim,
                dtype=DTYPES[cfg.compute_dtype])
     if cfg.network == "MoETransformerLM" or cfg.lm_parallelism == "ep":
         from ps_pytorch_tpu.models.moe import MoETransformerLM
         return MoETransformerLM(n_experts=cfg.lm_experts,
-                                top_k=cfg.lm_moe_top_k, **geo, **kw)
+                                top_k=cfg.lm_moe_top_k,
+                                experts_held=cfg.lm_experts_held, **geo, **kw)
     from ps_pytorch_tpu.models.transformer import TransformerLM
     return TransformerLM(**geo, **kw)
 

@@ -13,7 +13,8 @@ held-out next-token-loss oracle):
   (``parallel/pp.py``).
 - ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
   + ``parallel/ep.py``); also how an MoE model is run on one chip.
-  ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe).
+  ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe,
+  smallthinker; ``--lm-experts-held`` trains one chip's share of the experts).
 
 The reference has no LM surface at all — this is the §5.7 long-context
 capability expressed as a first-class entry point (``train_lm.py``), not
@@ -163,14 +164,20 @@ class LMTrainer:
             raise ValueError(self.mode)
         kernels = []
         if self.model.attention_impl == "flash":
-            # the schedule is static per shape: the line is its record
+            # the schedule is static per shape: the line is its record, one
+            # for each kind of layer the arch mixes (window or not)
             calls = cfg.lm_microbatches if self.mode == "pp" else 1
-            rows = cfg.batch_size // (self.mesh.shape["data"] * calls)
-            sched = flash_schedule(
-                max(rows, 1) * cfg.lm_heads, cfg.lm_seq_len,
-                cfg.lm_d_model // cfg.lm_heads,
-                jnp.dtype(self.model.dtype).itemsize, True)
-            kernels.append(f"flash_attention[{sched.describe()}]")
+            rows = max(cfg.batch_size // (self.mesh.shape["data"] * calls), 1)
+            arch = ARCHS[cfg.lm_arch]
+            scheds = {flash_schedule(
+                rows * cfg.lm_heads, cfg.lm_seq_len,
+                cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads,
+                jnp.dtype(self.model.dtype).itemsize, True,
+                window=arch.layer_window(i),
+                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads))
+                for i in range(cfg.lm_layers)}
+            kernels += [f"flash_attention[{sched.describe()}]"
+                        for sched in sorted(scheds, key=lambda sc: sc.window)]
         if ARCHS[cfg.lm_arch].dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not
@@ -321,7 +328,8 @@ class LMTrainer:
         # time") and cannot be compared — skip rather than spuriously
         # reject.
         for k in ("lm_arch", "lm_vocab", "lm_d_model", "lm_layers",
-                  "lm_heads", "lm_ffn_dim", "lm_parallelism", "lm_experts",
+                  "lm_heads", "lm_kv_heads", "lm_head_dim", "lm_ffn_dim",
+                  "lm_parallelism", "lm_experts", "lm_experts_held",
                   "lm_model_axis", "lm_moe_top_k"):
             if k == "lm_model_axis" and saved.get(k) == 0:
                 continue
@@ -429,12 +437,16 @@ class LMTrainer:
                 loss = None
                 if step % cfg.log_every == 0 or step == cfg.max_steps:
                     with tracer.span("metrics_sync"):
-                        loss = float(m["loss"])
                         # The ep step's routing statistics (aux; a dropless
                         # arch's z_loss, expert_load_max_over_mean,
-                        # moe_dropped) come with the loss, in the one wait.
-                        routing = {k: float(v) for k, v in m.items()
-                                   if k != "loss"}
+                        # moe_dropped, moe_held_share) come with the loss, in
+                        # the one wait and ONE read: every scalar's copy to
+                        # the host is started before any is waited for (a
+                        # float() each is a round trip each, 0.2-0.8 ms on
+                        # the chip's host).
+                        routing = {k: float(v) for k, v in
+                                   jax.device_get(m).items()}
+                        loss = routing.pop("loss")
                     # The loss read drained every step dispatched since the
                     # last sync, so the wall time over them is a true
                     # per-step duration (dispatch time alone reads as an

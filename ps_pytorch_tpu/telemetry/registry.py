@@ -478,6 +478,8 @@ ROUTING_GAUGES = (
      "tokens*top_k/experts, worst layer"),
     ("moe_dropped", "assignments", "token-to-expert assignments whose output "
      "was not added (a dropless router must read 0)"),
+    ("moe_held_share", "", "assignments to the experts held here over "
+     "tokens*top_k, mean over layers (1 where every expert is held)"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

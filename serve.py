@@ -78,7 +78,14 @@ def main(argv=None) -> int:
         p.error(f"serve.py decodes TransformerLM checkpoints; this one is "
                 f"{cfg.network}, lm_arch={cfg.lm_arch} (generate.py decodes "
                 f"a gpt2-arch MoE checkpoint one-shot; decoding the olmoe "
-                f"arch is not built)")
+                f"and smallthinker archs is not built)")
+    if cfg.lm_arch != "gpt2" or cfg.lm_kv_heads not in (0, cfg.lm_heads) \
+            or cfg.lm_head_dim:
+        p.error(f"serve.py decodes lm_arch=gpt2 checkpoints with equal head "
+                f"counts of d / heads; this one is lm_arch={cfg.lm_arch}, "
+                f"lm_kv_heads={cfg.lm_kv_heads}, lm_head_dim="
+                f"{cfg.lm_head_dim} (a cache for RoPE, window layers or "
+                f"grouped-query heads is not built)")
     template = build_lm_template(cfg)
     _, to_tree = build_lm_oracle(cfg)
     got = ckpt.load_latest_valid(args.train_dir, template,

@@ -1,5 +1,6 @@
-"""The OLMoE cell's grouped matmuls compile under Mosaic for a described v5e
-(no chip): what the Pallas interpreter cannot show — VMEM over the limit, a
+"""The OLMoE cell's grouped matmuls and the SmallThinker cell's flash kernels
+compile under Mosaic for a described v5e (no chip): what the Pallas
+interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses. One file, one fixture: only
 the worker that runs it loads the TPU compiler (on-chip-measurement guide,
 section 2)."""
@@ -61,6 +62,33 @@ def test_expert_ffn_forward_and_backward_compile_at_the_cells_shape(
         assert name in text
     grads = compiled.output_shardings     # one per argument differentiated
     assert len(grads) == 4
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_grouped_query_window_compiles_at_the_cells_shape(one_chip,
+                                                                 window):
+    """smallthinker_s16384_1chip: 28 query heads over 4 key/value heads of
+    128 at S=16384, bfloat16, forward and the one backward kernel, with and
+    without the window; dK and dV come back at the 4 heads."""
+    from ps_pytorch_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       interpret=False).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(28), arg(4), arg(4)).compile()
+    text = compiled.as_text()
+    names = ("flash_win_fwd", "flash_win_bwd_dkv") if window else \
+        ("flash_fwd", "flash_bwd_dkv")
+    for name in names:      # a bare kernel's call is named jvp_<name>_
+        assert f"{name}_" in text
+    assert ("flash_win_" in text) == bool(window)
+    assert "bf16[4,16384,128]" in text and "bf16[28,16384,128]" in text
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():
