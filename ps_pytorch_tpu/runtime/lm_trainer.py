@@ -46,6 +46,7 @@ from ps_pytorch_tpu.parallel.sp import (
 from ps_pytorch_tpu.runtime import checkpoint as ckpt
 from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
+from ps_pytorch_tpu.runtime.step_queue import QueuedSteps
 from ps_pytorch_tpu.telemetry import (
     FlightRecorder, HealthMonitor, MetricsExporter, ProfileWindow, Registry,
     Tracer, declare_resilience_metrics,
@@ -272,9 +273,13 @@ class LMTrainer:
         return body
 
     def _ops_step(self, step: int, *, loss=None, step_time=None,
-                  data_time=None) -> None:
+                  data_time=None, dispatch_ahead: int = 0) -> None:
+        """One step's worth of live-ops bookkeeping. ``loss`` is the PREVIOUS
+        step's — already on the host via the loop's one sync, so this adds no
+        device round-trip (as in runtime/trainer.py)."""
         r = self.registry
         r.inc("train_steps")
+        r.inc("dispatch_ahead_steps", dispatch_ahead)
         r.set("train_step", step)
         if loss is not None:
             r.set("train_loss", loss)
@@ -289,12 +294,26 @@ class LMTrainer:
         if self.flightrec is not None:
             self.flightrec.record_step(step, loss=loss, step_time=step_time,
                                        data_time=data_time)
-        if self.health is not None:
-            for ev in self.health.observe_step(step, loss=loss,
-                                               step_time=step_time):
-                if self.flightrec is not None:
-                    self.flightrec.record_health(ev)
-                print(f"HEALTH {ev.detector} ({ev.action}): {ev.message}")
+        self._watch(step, loss=loss, step_time=step_time)
+
+    def _watch(self, step: int, **values) -> None:
+        """The health watchdogs on one step's values."""
+        if self.health is None:
+            return
+        for ev in self.health.observe_step(step, **values):
+            if self.flightrec is not None:
+                self.flightrec.record_health(ev)
+            print(f"HEALTH {ev.detector} ({ev.action}): {ev.message}")
+
+    def _halt_for_health(self, step: int) -> None:
+        """The checkpoint-and-halt action: commit an emergency checkpoint,
+        dump the flight recorder, leave the loop (caller breaks)."""
+        ev = self.health.halt_event
+        with self.tracer.span("checkpoint", step=step):
+            self._checkpoint(step)
+        if self.flightrec is not None:
+            self.flightrec.dump(f"watchdog:{ev.detector}")
+        print(f"HEALTH halt at step {step}: {ev.message}")
 
     # ---- checkpoint/resume (same on-disk contract as the CNN Trainer) ----
     def _checkpoint(self, step: int) -> None:
@@ -393,19 +412,60 @@ class LMTrainer:
         return True
 
     def train(self):
+        """Run to ``max_steps``, under whichever ``--lm-parallelism``.
+
+        The loop has one rule, ``Trainer.train``'s: nothing between two
+        dispatches waits for the device, so one step is always queued behind
+        the running one and the host's work (loader, put, dispatch,
+        bookkeeping) hides under the device's. Its one wait, ``device_sync``,
+        reads the PREVIOUS step's scalars (one ``device_get`` of all of them)
+        with this step already queued. What trails by a step, therefore: a
+        logged step's record (JSONL and STEP line) is written once the next
+        step is queued, or before what drains the device anyway (a
+        checkpoint, the last step, a halt, an exception on its way out); the
+        registry's ``train_loss``, the flight recorder and the health
+        watchdogs see step n-1's loss in iteration n, so a halt is noticed one
+        step late and its checkpoint is of the state one step later (the last
+        step's loss is checked after the loop). The bookkeeping is
+        runtime/step_queue.py's, shared with ``Trainer``."""
         cfg = self.cfg
         if cfg.resume:
             self.maybe_resume()
         step = self.start_step
         halted = False
         tracer = self.tracer
-        t_sync, n_unsynced = time.monotonic(), 0
         # only this run's first record says what it computes in
         once = {"compute_dtype": self.compute_dtype.name}
+
+        def write_record(step, own, *, step_time, data_time, dispatch_ahead,
+                         epoch):
+            # The ep step's routing statistics (aux; a dropless arch's
+            # z_loss, expert_load_max_over_mean, moe_dropped,
+            # moe_held_share) come with the loss.
+            loss = own.pop("loss")
+            derived = derive_step_record(
+                step_time_s=step_time, data_time_s=data_time,
+                examples=cfg.batch_size,
+                tokens=cfg.batch_size * cfg.lm_seq_len,
+                flops_per_step=self._flops_per_step,
+                peak_flops_per_chip=self._peak_per_chip,
+                n_chips=self._n_chips)
+            self.metrics.log_step(
+                step, epoch, loss=loss, acc=0.0, participating=1.0,
+                step_time=step_time, data_time=data_time,
+                dispatch_ahead=dispatch_ahead,
+                phases=tracer.step_summary(step), **own, **derived, **once)
+            once.clear()
+            for k, v in own.items():
+                self.registry.set(k, v)
+
+        # A record holds, and the watchdogs see, every scalar the step
+        # returns: one read of the whole dict.
+        queued = QueuedSteps(tracer, step, write_record)
+        t_sync = time.monotonic()
         try:
             while step < cfg.max_steps:
                 step += 1
-                n_unsynced += 1
                 self._profile.on_step(step)
                 # The iteration's root span, as in runtime/trainer.py: the
                 # phases below are its children, its self time is what no
@@ -431,72 +491,58 @@ class LMTrainer:
                             self.step_fn, self.state, tok_g)
                 with tracer.span("host_dispatch"):
                     self.state, m = self.step_fn(self.state, tok_g)
-                # Dispatch is asynchronous: between syncs this is only
-                # what a non-blocking iteration costs the host.
-                t_step = time.monotonic() - t0
-                loss = None
-                if step % cfg.log_every == 0 or step == cfg.max_steps:
-                    with tracer.span("metrics_sync"):
-                        # The ep step's routing statistics (aux; a dropless
-                        # arch's z_loss, expert_load_max_over_mean,
-                        # moe_dropped, moe_held_share) come with the loss, in
-                        # the one wait and ONE read: every scalar's copy to
-                        # the host is started before any is waited for (a
-                        # float() each is a round trip each, 0.2-0.8 ms on
-                        # the chip's host).
-                        routing = {k: float(v) for k, v in
-                                   jax.device_get(m).items()}
-                        loss = routing.pop("loss")
-                    # The loss read drained every step dispatched since the
-                    # last sync, so the wall time over them is a true
-                    # per-step duration (dispatch time alone reads as an
-                    # MFU above 1 on a chip).
-                    now = time.monotonic()
-                    t_step = (now - t_sync) / n_unsynced
-                    t_sync, n_unsynced = now, 0
-                    # The record's phases are the spans closed so far: this
-                    # span itself is not among them.
-                    with tracer.span("log_write"):
-                        derived = derive_step_record(
-                            step_time_s=t_step, data_time_s=t_data,
-                            examples=cfg.batch_size,
-                            tokens=cfg.batch_size * cfg.lm_seq_len,
-                            flops_per_step=self._flops_per_step,
-                            peak_flops_per_chip=self._peak_per_chip,
-                            n_chips=self._n_chips)
-                        self.metrics.log_step(
-                            step, self.train_loader._epoch,
-                            loss=loss, acc=0.0, participating=1.0,
-                            step_time=t_step, data_time=t_data,
-                            phases=tracer.step_summary(step), **routing,
-                            **derived, **once)
-                        once = {}
-                    for k, v in routing.items():
-                        self.registry.set(k, v)
+                    ahead = queued.ahead()
+                last = step == cfg.max_steps
+                if step % cfg.log_every == 0 or last:
+                    queued.log_later(step, m, data_time=t_data,
+                                     dispatch_ahead=ahead,
+                                     epoch=self.train_loader._epoch)
+                # The loop's one wait: the previous step's scalars, EVERY
+                # step (with log_every > 1 too: a read that waits for nothing
+                # the chip is not already doing), so the host never runs more
+                # than one step ahead and the wall time between two syncs is
+                # a true per-step duration (dispatch time alone reads as an
+                # MFU above 1 on a chip).
+                prev = queued.sync(m)
+                now = time.monotonic()
+                t_step, t_sync = now - t_sync, now
                 with tracer.span("ops_step"):
-                    self._ops_step(step, loss=loss, step_time=t_step,
-                                   data_time=t_data)
+                    self._ops_step(step, loss=prev.get("loss"),
+                                   step_time=t_step, data_time=t_data,
+                                   dispatch_ahead=ahead)
                 if self.health is not None and self.health.should_halt:
-                    with tracer.span("checkpoint"):
-                        self._checkpoint(step)
-                    if self.flightrec is not None:
-                        self.flightrec.dump(
-                            f"watchdog:{self.health.halt_event.detector}")
-                    print(f"HEALTH halt at step {step}: "
-                          f"{self.health.halt_event.message}")
+                    queued.log_through(step)
+                    self._halt_for_health(step)
                     halted = True
                     break
-                if cfg.eval_freq > 0 and step % cfg.eval_freq == 0:
+                # The previous step's record: its metrics finished under
+                # device_sync above, so the read waits for nothing. Where the
+                # device is drained anyway (a checkpoint, the last step) this
+                # step's record goes with it, in one read.
+                saves = cfg.eval_freq > 0 and step % cfg.eval_freq == 0
+                queued.log_through(step if saves or last else step - 1)
+                if saves:
                     with tracer.span("checkpoint"):
                         self._checkpoint(step)
-                    t_sync, n_unsynced = time.monotonic(), 0
+                    queued.restart_clock(step)
+                    t_sync = time.monotonic()
                 tracer.end_step()
             tracer.end_step()       # an iteration left by break
             jax.block_until_ready(self.state.params)
+            final = queued.last_watched() \
+                if self.health is not None and not halted else {}
+            if final:
+                # The loop's sync trails by one step: check the LAST step's
+                # loss too, so a NaN on the final step still trips.
+                self._watch(step, loss=final["loss"])
+                if self.health.should_halt:
+                    self._halt_for_health(step)
+                    halted = True
             if not halted and cfg.eval_freq > 0 and step % cfg.eval_freq != 0:
                 with self.tracer.span("checkpoint", step=step):
                     self._checkpoint(step)
         except BaseException as e:
+            queued.log_on_the_way_out()
             if self.flightrec is not None:
                 self.flightrec.record_event(
                     "exception", {"type": type(e).__name__, "message": str(e)})

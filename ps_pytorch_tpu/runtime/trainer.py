@@ -14,7 +14,6 @@ checkpoint. The Coordinator supplies the per-step participation mask
 
 import os
 import time
-from collections import deque
 from typing import Optional
 
 import jax
@@ -38,6 +37,7 @@ from ps_pytorch_tpu.parallel.mesh import local_data_shard
 from ps_pytorch_tpu.runtime import checkpoint as ckpt
 from ps_pytorch_tpu.runtime.coordinator import Coordinator
 from ps_pytorch_tpu.runtime.metrics import MetricsLogger
+from ps_pytorch_tpu.runtime.step_queue import QueuedSteps
 from ps_pytorch_tpu.telemetry import (
     FlightRecorder, HealthMonitor, MetricsExporter, ProfileWindow, Registry,
     TelemetryAggregator, Tracer,
@@ -501,13 +501,17 @@ class Trainer:
             self.flightrec.record_step(step, loss=loss, grad_norm=grad_norm,
                                        step_time=step_time,
                                        data_time=data_time)
-        if self.health is not None:
-            for ev in self.health.observe_step(
-                    step, loss=loss, grad_norm=grad_norm,
-                    nonfinite=nonfinite, step_time=step_time):
-                if self.flightrec is not None:
-                    self.flightrec.record_health(ev)
-                print(f"HEALTH {ev.detector} ({ev.action}): {ev.message}")
+        self._watch(step, loss=loss, grad_norm=grad_norm,
+                    nonfinite=nonfinite, step_time=step_time)
+
+    def _watch(self, step: int, **values) -> None:
+        """The health watchdogs on one step's values."""
+        if self.health is None:
+            return
+        for ev in self.health.observe_step(step, **values):
+            if self.flightrec is not None:
+                self.flightrec.record_health(ev)
+            print(f"HEALTH {ev.detector} ({ev.action}): {ev.message}")
 
     def _halt_for_health(self, step: int) -> None:
         """The checkpoint-and-halt action: commit an emergency checkpoint,
@@ -530,59 +534,45 @@ class Trainer:
         host's work (loader, put, dispatch, bookkeeping) hides under the
         device's. Its one wait, ``device_sync``, reads the PREVIOUS step's
         loss with this step already queued; a step's record is written once
-        the next step is queued, and the per-step key is made on the host."""
+        the next step is queued, and the per-step key is made on the host.
+        What trails by a step, therefore: records, the watchdogs, and a
+        halt's checkpoint (of the state one step later). The bookkeeping is
+        runtime/step_queue.py's, shared with ``LMTrainer``."""
         cfg = self.cfg
         steps_per_epoch = max(len(self.train_loader), 1)
         epoch_budget = cfg.epochs * steps_per_epoch if cfg.epochs > 0 else cfg.max_steps
         last_step = min(cfg.max_steps, epoch_budget)
         step = self.start_step
-        m_prev = None
         preempted = False
         halted = False
         tracer = self.tracer
         self._preempt.install()
-        # Logged steps whose record (STEP line, JSONL) is not written yet: a
-        # step's is written once the NEXT step is queued, from metrics that
-        # device_sync has already seen finished. What drains the device
-        # anyway writes the waiting records first: a checkpoint, the loop's
-        # last step, an exception on its way out.
-        unwritten: deque = deque()
-        # When records' scalars were last read, and up to which step: the
-        # records read in one go share the wall time since, per step since.
-        # With a step queued the reads follow the device's pace, not the
-        # host's, and the step_times of a run add up to its wall time.
-        t_read, read_step = time.monotonic(), step
+        # A logged step's record (STEP line, JSONL) is written once the NEXT
+        # step is queued, or where the device is drained anyway (a
+        # checkpoint, the loop's last step, an exception on its way out):
+        # runtime/step_queue.py, shared with LMTrainer.
 
-        def log_through(upto: int) -> None:
-            nonlocal t_read, read_step
-            due = []
-            while unwritten and unwritten[0]["step"] <= upto:
-                due.append(unwritten.popleft())
-            if not due:
-                return
-            with tracer.span("metrics_sync"):
-                scalars = [(float(rec["m"]["loss"]),
-                            float(rec["m"]["accuracy"]),
-                            float(rec["m"]["participating"])) for rec in due]
-            now = time.monotonic()
-            t_logged = (now - t_read) / (due[-1]["step"] - read_step)
-            t_read, read_step = now, due[-1]["step"]
-            with tracer.span("log_write"):
-                for rec, (loss, acc, part) in zip(due, scalars):
-                    extra = derive_step_record(
-                        step_time_s=t_logged, data_time_s=rec["data_time"],
-                        examples=cfg.batch_size,
-                        flops_per_step=self._flops_per_step,
-                        peak_flops_per_chip=self._peak_per_chip,
-                        n_chips=self._n_chips)
-                    if self._resilience_active():
-                        extra.update(self.resilience_stats())
-                    self.metrics.log_step(
-                        rec["step"], (rec["step"] - 1) // steps_per_epoch,
-                        loss=loss, acc=acc, participating=part,
-                        step_time=t_logged, data_time=rec["data_time"],
-                        dispatch_ahead=rec["dispatch_ahead"],
-                        phases=tracer.step_summary(rec["step"]), **extra)
+        def write_record(step, own, *, step_time, data_time, dispatch_ahead):
+            extra = derive_step_record(
+                step_time_s=step_time, data_time_s=data_time,
+                examples=cfg.batch_size,
+                flops_per_step=self._flops_per_step,
+                peak_flops_per_chip=self._peak_per_chip,
+                n_chips=self._n_chips)
+            if self._resilience_active():
+                extra.update(self.resilience_stats())
+            self.metrics.log_step(
+                step, (step - 1) // steps_per_epoch,
+                loss=own["loss"], acc=own["accuracy"],
+                participating=own["participating"],
+                step_time=step_time, data_time=data_time,
+                dispatch_ahead=dispatch_ahead,
+                phases=tracer.step_summary(step), **extra)
+
+        queued = QueuedSteps(
+            tracer, step, write_record,
+            record=("loss", "accuracy", "participating"),
+            watch=("loss", "grad_norm", "nonfinite"))
 
         try:
             while step < last_step:
@@ -648,14 +638,10 @@ class Trainer:
                     # handles (0.2 ms on one chip, 0.75 ms on four): part of
                     # the dispatch, as in runtime/lm_trainer.py.
                     self.state, m = self.step_fn(self.state, xg, yg, mg, kg)
-                    # Was the chip still busy with the previous step when
-                    # this one was queued? (A query, not a wait.)
-                    ahead = int(m_prev is not None
-                                and not m_prev["loss"].is_ready())
+                    ahead = queued.ahead()
                 if step % cfg.log_every == 0 or step == last_step:
-                    unwritten.append({"step": step, "m": m,
-                                      "data_time": t_data,
-                                      "dispatch_ahead": ahead})
+                    queued.log_later(step, m, data_time=t_data,
+                                     dispatch_ahead=ahead)
                 if cfg.inject_step_delay > 0 and \
                         jax.process_index() == cfg.inject_delay_process:
                     # Fault injection (tests/ops drills): make THIS host a
@@ -669,23 +655,15 @@ class Trainer:
                 # on stale numbers (the reference timed every worker step,
                 # distributed_worker.py:169-173) — and the watchdogs and the
                 # previous step's record get their values at no further sync.
-                prev = None
-                with tracer.span("device_sync"):
-                    if m_prev is not None:
-                        prev = {"loss": float(m_prev["loss"])}
-                        if "grad_norm" in m_prev:
-                            prev["grad_norm"] = float(m_prev["grad_norm"])
-                        if "nonfinite" in m_prev:
-                            prev["nonfinite"] = float(m_prev["nonfinite"])
-                    m_prev = m      # frees the scalars just read
+                prev = queued.sync(m)
                 t_step = time.monotonic() - t0
                 with tracer.span("ops_step"):
                     for r in self._local_replicas:
                         self.coordinator.report_duration(r, step, t_step)
                     self._ops_step(step, step_time=t_step, data_time=t_data,
-                                   dispatch_ahead=ahead, **(prev or {}))
+                                   dispatch_ahead=ahead, **prev)
                 if self.health is not None and self.health.should_halt:
-                    log_through(step)
+                    queued.log_through(step)
                     self._halt_for_health(step)
                     halted = True
                     break
@@ -705,16 +683,16 @@ class Trainer:
                 # this step's record goes with it, in one read.
                 saves = cfg.eval_freq > 0 and step % cfg.eval_freq == 0
                 drains = saves or step == last_step or self._preempt.triggered
-                log_through(step if drains else step - 1)
+                queued.log_through(step if drains else step - 1)
                 if saves:
                     with tracer.span("checkpoint"):
                         self._checkpoint(step)
-                    t_read, read_step = time.monotonic(), step
+                    queued.restart_clock(step)
                 if self._preempt.triggered:
                     # SIGTERM (preemption notice): commit an emergency
                     # checkpoint at this step boundary and leave cleanly so
                     # auto-resume (or the next scheduling) restores here.
-                    log_through(step)   # a notice that came this moment
+                    queued.log_through(step)   # a notice that came this moment
                     with tracer.span("checkpoint"):
                         self._checkpoint(step)
                     print(f"PREEMPT emergency checkpoint at step {step}")
@@ -725,18 +703,12 @@ class Trainer:
                 tracer.end_step()
             tracer.end_step()       # an iteration left by break
             jax.block_until_ready(self.state.params)
-            if m_prev is not None and self.health is not None and not halted:
+            final = queued.last_watched() \
+                if self.health is not None and not halted else {}
+            if final:
                 # The loop's sync point trails by one step: check the LAST
                 # step's metrics too, so a NaN on the final step still trips.
-                final = {"loss": float(m_prev["loss"])}
-                if "grad_norm" in m_prev:
-                    final["grad_norm"] = float(m_prev["grad_norm"])
-                if "nonfinite" in m_prev:
-                    final["nonfinite"] = float(m_prev["nonfinite"])
-                for ev in self.health.observe_step(step, **final):
-                    if self.flightrec is not None:
-                        self.flightrec.record_health(ev)
-                    print(f"HEALTH {ev.detector} ({ev.action}): {ev.message}")
+                self._watch(step, **final)
                 if self.health.should_halt and not preempted:
                     self._halt_for_health(step)
                     halted = True
@@ -745,13 +717,7 @@ class Trainer:
                 with self.tracer.span("checkpoint", step=step):
                     self._checkpoint(step)
         except BaseException as e:
-            # The waiting records are written on the way out, best effort: a
-            # crashed or interrupted run keeps the log of every step it
-            # dispatched, and a failure here must not mask the real error.
-            try:
-                log_through(step)
-            except Exception as err:
-                print(f"LOG a waiting step record was not written: {err!r}")
+            queued.log_on_the_way_out()
             # The flight dump happens while the exception is in flight so a
             # crash post-mortem exists even when nothing catches it upstream;
             # dump() itself never raises (it must not mask the real error).

@@ -442,7 +442,8 @@ def test_lm_trainer_schema_parity(tmp_path, capsys):
     with open(tmp_path / "lm_trace.json") as f:
         names = {e["name"] for e in json.load(f)["traceEvents"]
                  if e["ph"] == "X"}
-    assert {"data_wait", "host_dispatch", "metrics_sync"} <= names
+    assert {"data_wait", "host_dispatch", "device_sync",
+            "metrics_sync"} <= names
 
 
 # ---- trace.py: parents, self time, the iteration's root, the Unix anchor ----
@@ -701,8 +702,8 @@ def test_device_memory_record_reports_reserved_peak_and_each_device(monkeypatch)
 CNN_LEAVES = {"coordinator", "data_wait", "rng_key", "batch_put",
               "host_dispatch", "device_sync", "ops_step",
               "telemetry_publish", "metrics_sync", "log_write"}
-LM_LEAVES = {"data_wait", "batch_put", "host_dispatch", "metrics_sync",
-             "log_write", "ops_step"}
+LM_LEAVES = {"data_wait", "batch_put", "host_dispatch", "device_sync",
+             "ops_step", "metrics_sync", "log_write"}
 
 
 @pytest.fixture(scope="module")
@@ -747,10 +748,11 @@ def test_every_iteration_is_one_root_with_its_phases_as_children(
         kids = {e["name"] for e in spans if e["parent"] == r["id"]}
         want = leaves | ({"flops_trace"} if r["step"] == 1 else set()) \
             | ({"checkpoint"} if r["step"] % 2 == 0 else set())
-        if kind == "cnn" and r["step"] % 2 == 1:
-            # Trainer writes a step's record once the next step is queued,
-            # or before the checkpoint that follows it: with eval_freq=2,
-            # iteration 2k writes records 2k-1 and 2k, iteration 2k+1 none.
+        if r["step"] % 2 == 1:
+            # Both trainers write a step's record once the next step is
+            # queued, or before the checkpoint that follows it: with
+            # eval_freq=2, iteration 2k writes records 2k-1 and 2k,
+            # iteration 2k+1 none.
             want -= {"metrics_sync", "log_write"}
         assert kids == want, (r["step"], kids ^ want)
         assert all(e["step"] == r["step"] for e in spans
@@ -799,20 +801,19 @@ def test_jsonl_phases_keep_their_keys_and_leave_the_root_out(traced_runs, kind):
         phases = rec["phases"]
         assert "train_step" not in phases
         assert {"data_wait", "host_dispatch"} <= set(phases)
-        # Trainer's records hold their whole iteration, the write of the
-        # record before them included (here: in the even iterations).
-        assert ("metrics_sync" in phases) == \
-            (kind == "lm" or rec["step"] % 2 == 0)
+        # A record holds its whole iteration, the write of the record
+        # before it included (here: in the even iterations).
+        assert ("metrics_sync" in phases) == (rec["step"] % 2 == 0)
         assert "batch_put" in phases            # a new leaf is a new key
         assert all(v >= 0 for v in phases.values())
         # nothing counted twice: the phases fit into their iteration's wall
         # time (a record's step_time is the time between two reads of the
-        # device, which in Trainer trail the iteration by one step)
+        # device, which trail the iteration by one step)
         assert sum(phases.values()) <= wall[rec["step"]] + 1e-5
+    assert all("device_sync" in r["phases"] for r in recs)
     if kind == "cnn":
         assert all("coordinator_mask" in r["phases"] and
-                   "coordinator" in r["phases"] and
-                   "device_sync" in r["phases"] for r in recs)
+                   "coordinator" in r["phases"] for r in recs)
 
 
 @pytest.mark.parametrize("kind", ["cnn", "lm"])
