@@ -24,9 +24,9 @@ from typing import Any, Optional
 # The rows of models/transformer.py:ARCHS (kept here as names only, so that
 # building a config imports no model code; tests/test_olmoe.py holds the two
 # lists equal).
-LM_ARCHS = ("gpt2", "olmoe", "smallthinker")
+LM_ARCHS = ("gpt2", "olmoe", "smallthinker", "trinity")
 # ... of which those that route dropless (an MoE model: lm_parallelism=ep).
-_DROPLESS_ARCHS = ("olmoe", "smallthinker")
+_DROPLESS_ARCHS = ("olmoe", "smallthinker", "trinity")
 
 
 @dataclass
@@ -116,10 +116,12 @@ class TrainConfig:
     lm_corpus_tokens: int = 1_000_000
     lm_corpus_file: str = ""         # byte-level REAL corpus from any local file ("" = synthetic Markov stream)
     lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE model, experts sharded over 'data'; also how an MoE model is chosen on ONE chip)
-    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) — models/transformer.py ARCHS
+    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) | trinity (RMSNorm on each sublayer's input and output; three window-2048 RoPE layers to one full-causal layer without position encoding; q/k norm a head; gated attention output; embedding times sqrt(d); lm_dense_layers dense SwiGLU layers, then dropless sigmoid-scored top-k SwiGLU experts chosen under a bias the step moves against the load, gates renormalised and scaled, one shared expert; needs lm_parallelism=ep) — models/transformer.py ARCHS
     lm_kv_heads: int = 0             # key/value heads, each serving lm_heads / lm_kv_heads query heads (0 = lm_heads); sp on one device or ep, attention full | flash
     lm_head_dim: int = 0             # head size (0 = lm_d_model / lm_heads)
     lm_ffn_dim: int = 0              # FFN / expert width (0 = 4 * lm_d_model)
+    lm_dense_layers: int = 0         # dropless archs: the first N blocks have a dense gated FFN (SwiGLU under the arch's activation, no biases) in place of experts
+    lm_dense_ffn_dim: int = 0        # ... of this width (0 = 4 * lm_d_model)
     lm_attention: str = "auto"       # auto | full | flash (fused Pallas kernel). full/flash are sequence-local: sp over >1 device requires auto (ring)
     lm_model_axis: int = 0           # tp/pp: size of the 'model' mesh axis (0 = all devices)
     lm_microbatches: int = 4         # pp: GPipe microbatch count
@@ -255,6 +257,19 @@ class TrainConfig:
         if self.lm_ffn_dim < 0:
             raise ValueError(f"lm_ffn_dim={self.lm_ffn_dim} (must be >= 0; "
                              "0 = 4 * lm_d_model)")
+        if self.lm_dense_layers or self.lm_dense_ffn_dim:
+            if self.lm_arch not in _DROPLESS_ARCHS:
+                raise ValueError(
+                    f"lm_dense_layers={self.lm_dense_layers} / "
+                    f"lm_dense_ffn_dim={self.lm_dense_ffn_dim} need a "
+                    f"dropless lm_arch ({' | '.join(_DROPLESS_ARCHS)}): "
+                    f"leading dense layers are built for those")
+            if not 0 <= self.lm_dense_layers <= self.lm_layers \
+                    or self.lm_dense_ffn_dim < 0:
+                raise ValueError(
+                    f"lm_dense_layers={self.lm_dense_layers} (must be in "
+                    f"0..lm_layers={self.lm_layers}), lm_dense_ffn_dim="
+                    f"{self.lm_dense_ffn_dim} (must be >= 0)")
         if self.lm_microbatches < 1:
             # 0 reaches the pp step as a division by zero mid-trace.
             raise ValueError(f"lm_microbatches={self.lm_microbatches} "

@@ -1,7 +1,10 @@
 """Mixture-of-Experts transformer LM: capacity routing (switch top-1, GShard
 top-2; ``MoEMLP``) or dropless top-k routing by sort and grouped matmul
-(``DroplessMoE``, the ``olmoe`` and ``smallthinker`` archs; it can hold a share
-of its experts). The arch picks one per model.
+(``DroplessMoE``, the ``olmoe``, ``smallthinker`` and ``trinity`` archs; it can
+hold a share of its experts, score by softmax or sigmoid, and choose under a
+bias that the train step moves against the load). The arch picks one per
+model; a dropless model may start with dense gated layers and pass every token
+through shared experts beside the routed ones (``GatedFFN``).
 
 Beyond-parity model family backing expert parallelism (``parallel/ep.py``;
 the reference has no MoE or EP anywhere, SURVEY §2.5). Design points:
@@ -60,6 +63,33 @@ _OVER_LAYERS = {"aux": jnp.mean, "z_loss": jnp.mean,
                 "expert_load_max_over_mean": jnp.max, "moe_dropped": jnp.sum,
                 "moe_held_share": jnp.mean}
 DROPLESS_STATS = tuple(_OVER_LAYERS)
+# The collection of what no gradient moves: ``expert_bias`` [experts] in each
+# expert layer whose arch chooses under a bias. It travels in
+# ``TrainState.batch_stats`` ({} for every other arch) and is saved with it.
+MOE_STATE = "moe_state"
+# Such a model also returns, under this key, each layer's assignments to every
+# router output, as a tree of the collection's shape.
+EXPERT_COUNTS = "expert_counts"
+# ... from which the ep step derives these two beside DROPLESS_STATS.
+BIAS_STATS = ("moe_bias_abs_max", "moe_load_all_max_over_mean")
+
+
+def lm_variables(params, moe_state=None):
+    """What ``apply`` takes: the parameters and, where the model has one, its
+    ``MOE_STATE`` collection."""
+    return {"params": params, **({MOE_STATE: moe_state} if moe_state else {})}
+
+
+def update_expert_bias(bias, counts, rate: float):
+    """One step of the balancing that needs no auxiliary loss (torchtitan's,
+    whose ``load_balance_coeff`` is ``rate``): an expert that drew fewer
+    assignments than the mean is chosen a little more readily next step, one
+    that drew more a little less, every expert by the same ``rate``; the
+    steps are centred, so the bias keeps its mean. ``counts``: assignments to
+    each of ALL the router's outputs in the step, over every device."""
+    counts = counts.astype(jnp.float32)
+    delta = rate * jnp.sign(jnp.mean(counts) - counts)
+    return bias + (delta - jnp.mean(delta))
 
 
 class MoEMLP(nn.Module):
@@ -200,6 +230,7 @@ class MoEMLP(nn.Module):
 HELD_ROWS_SLACK = 1.5
 HELD_ROWS_TILE = 512      # ... in whole multiples of this many rows
 _ACTS = {"silu": nn.silu, "relu": nn.relu}
+_GATE_EPS = 1e-20         # under the sum of sigmoid gates (torchtitan's)
 
 
 def _when(pred, fn, args, ints):
@@ -240,7 +271,12 @@ class DroplessMoE(nn.Module):
 
     with ``act`` SiLU (SwiGLU) or ReLU, and the router reading ``router_x``
     where the caller hands one (an arch whose router sits before attention)
-    in place of ``h``.
+    in place of ``h``. ``score`` "sigmoid": p = sigmoid(r), each output by
+    itself. ``select_bias``: I = top-k of p + b, with b the layer's
+    ``expert_bias`` [E] in the ``MOE_STATE`` collection: it chooses and does
+    not weigh (g stays p's), no gradient reaches it, and the train step moves
+    it (``update_expert_bias``) from the counts this layer hands out under
+    ``EXPERT_COUNTS``. ``route_scale`` multiplies the gates.
 
     The T*k assignments are sorted by expert (stable: inside an expert the
     order is the token order), the rows gathered, the three matmuls run as
@@ -280,6 +316,9 @@ class DroplessMoE(nn.Module):
     n_held: int = 0                   # experts held here (0 = all)
     share: int = 0                    # which block of n_held, 0-based
     down_std: float = 0.0             # experts_down init: normal(std) | lecun_normal
+    score: str = "softmax"            # softmax | sigmoid
+    select_bias: bool = False         # top-k of score + expert_bias (MOE_STATE)
+    route_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, router_x=None):
@@ -299,10 +338,24 @@ class DroplessMoE(nn.Module):
         router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
                           precision=jax.lax.Precision.HIGHEST,
                           name="router")(router_in.astype(jnp.float32))
-        probs = jax.nn.softmax(router, axis=-1)       # [T, E] float32
-        gates, idx = jax.lax.top_k(probs, k)          # [T, k]
+        if self.score == "sigmoid":
+            probs = jax.nn.sigmoid(router)            # [T, E] float32
+        else:
+            probs = jax.nn.softmax(router, axis=-1)
+        if self.select_bias:
+            bias = self.variable(MOE_STATE, "expert_bias", jnp.zeros, (e,),
+                                 jnp.float32).value
+            _, idx = jax.lax.top_k(probs + bias, k)
+            gates = jnp.take_along_axis(probs, idx, axis=-1)
+        else:
+            gates, idx = jax.lax.top_k(probs, k)      # [T, k]
         if self.gate_norm:
-            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+            total = jnp.sum(gates, axis=-1, keepdims=True)
+            if self.score == "sigmoid":
+                total = total + _GATE_EPS     # the scores do not sum to 1
+            gates = gates / total
+        if self.route_scale != 1.0:
+            gates = gates * self.route_scale
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         w_gate = self.param("experts_gate", init, (held, d, f))
@@ -373,11 +426,33 @@ class DroplessMoE(nn.Module):
             "moe_dropped": (n_held_rows - added).astype(jnp.float32),
             "moe_held_share": n_held_rows.astype(jnp.float32) / (t * k),
         }
+        if self.select_bias:
+            stats[EXPERT_COUNTS] = {"expert_bias": load}
         return y.reshape(b, s, d).astype(x.dtype), stats
 
 
+class GatedFFN(nn.Module):
+    """``(act(x Wgate) * (x Wup)) Wdown`` without biases: a dropless model's
+    dense layer and its shared experts (SwiGLU under ``silu``)."""
+    d_hidden: int
+    dtype: Any = jnp.float32
+    act: str = "silu"
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         name=name)
+        h = _ACTS[self.act](dense(self.d_hidden, "gate")(x)) \
+            * dense(self.d_hidden, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
 class MoEBlock(nn.Module):
-    """transformer.Block with the dense MLP swapped for MoEMLP."""
+    """transformer.Block with the dense MLP swapped for an expert layer
+    (``MoEMLP`` or ``DroplessMoE``, by the arch; with the arch's shared experts
+    beside the routed part), or, where ``dense_ffn_dim`` is set, for a
+    ``GatedFFN`` of that width: one of a dropless model's leading dense layers,
+    whose ``aux`` is None."""
     n_heads: int
     d_model: int
     n_experts: int
@@ -406,6 +481,7 @@ class MoEBlock(nn.Module):
     head_dim: int = 0                 # 0 = d_model / n_heads
     experts_held: int = 0             # dropless: experts held here (0 = all)
     experts_share: int = 0            # ... which block of them, 0-based
+    dense_ffn_dim: int = 0            # > 0: no experts here, a GatedFFN of this width
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -417,15 +493,27 @@ class MoEBlock(nn.Module):
             decode=self.decode, decode_cache_len=self.decode_cache_len,
             layer=self.layer, kv_heads=self.kv_heads, head_dim=self.head_dim)
         y = make_norm(self.arch, self.dtype)(x)
-        if a.dropless:
-            m, aux = DroplessMoE(self.n_experts, self.d_model,
-                                 self.ffn_dim or 4 * self.d_model,
+        width = self.ffn_dim or 4 * self.d_model
+        if self.dense_ffn_dim:
+            m, aux = GatedFFN(self.dense_ffn_dim, self.dtype, a.expert_act,
+                              name="mlp")(y), None
+        elif a.dropless:
+            m, aux = DroplessMoE(self.n_experts, self.d_model, width,
                                  top_k=self.top_k, dtype=self.dtype,
                                  act=a.expert_act, gate_norm=a.gate_norm,
                                  n_held=self.experts_held,
                                  share=self.experts_share,
-                                 down_std=a.expert_down_std, name="moe")(
+                                 down_std=a.expert_down_std,
+                                 score=a.router_score,
+                                 select_bias=a.router_bias_rate > 0,
+                                 route_scale=a.route_scale, name="moe")(
                 y, normed if a.early_router else None)
+            if EXPERT_COUNTS in aux:
+                aux[EXPERT_COUNTS] = {"moe": aux[EXPERT_COUNTS]}
+            if a.shared_experts:
+                # every token's, whatever share of the routed experts is held
+                m = m + GatedFFN(a.shared_experts * width, self.dtype,
+                                 a.expert_act, name="shared")(y)
         else:
             m, aux = MoEMLP(self.n_experts, self.d_model,
                             self.ffn_dim or 4 * self.d_model,
@@ -435,18 +523,24 @@ class MoEBlock(nn.Module):
                             n_local_experts=self.n_local_experts,
                             top_k=self.top_k, dtype=self.dtype,
                             name="moe")(y)
+        if a.post_norm:
+            m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(m)
         return x + m, aux
 
 
 class MoETransformerLM(nn.Module):
-    """Decoder-only LM with an MoE MLP in every block.
+    """Decoder-only LM with an MoE MLP in every block, after ``dense_layers``
+    leading blocks with a dense gated feed-forward of ``dense_ffn_dim``
+    (dropless archs).
 
     Returns (logits [B, S, V] in ``dtype``; the loss casts them to float32,
     aux): for a capacity arch the
     scalar sum of the layers' load-balance losses; for a dropless arch a dict
-    keyed by ``DROPLESS_STATS`` (``aux`` and ``z_loss`` averaged over layers,
-    the busiest layer's ``expert_load_max_over_mean``, ``moe_dropped``
-    summed, ``moe_held_share`` averaged)."""
+    keyed by ``DROPLESS_STATS`` over the expert layers (``aux`` and ``z_loss``
+    averaged over layers, the busiest layer's ``expert_load_max_over_mean``,
+    ``moe_dropped`` summed, ``moe_held_share`` averaged) and, where the arch
+    chooses under a bias, ``EXPERT_COUNTS``: each layer's assignments to every
+    router output, a tree of the ``MOE_STATE`` collection's shape."""
     vocab_size: int = 256
     n_layers: int = 2
     n_heads: int = 4
@@ -465,6 +559,8 @@ class MoETransformerLM(nn.Module):
     head_dim: int = 0                 # 0 = d_model / n_heads
     experts_held: int = 0             # dropless: experts held here (0 = all)
     experts_share: int = 0            # ... which block of them, 0-based
+    dense_layers: int = 0             # leading blocks with a dense gated FFN
+    dense_ffn_dim: int = 0            # ... of this width (0 = 4 * d_model)
     # Per-block remat (see models/transformer.py TransformerLM.remat); the
     # recompute replays the block's all_to_alls, which is SPMD-legal.
     remat: bool = False
@@ -496,14 +592,20 @@ class MoETransformerLM(nn.Module):
                          kv_heads=self.kv_heads, head_dim=self.head_dim,
                          experts_held=self.experts_held,
                          experts_share=self.experts_share,
+                         dense_ffn_dim=(self.dense_ffn_dim or 4 * self.d_model)
+                         if i < self.dense_layers else 0,
                          name=f"block_{i}")(x, positions)
-            per_layer.append(aux)
+            if aux is not None:
+                per_layer.append((f"block_{i}", aux))
         if ARCHS[self.arch].dropless:
-            aux_total = {k: over(jnp.stack([a[k] for a in per_layer]))
+            aux_total = {k: over(jnp.stack([a[k] for _, a in per_layer]))
                          for k, over in _OVER_LAYERS.items()}
+            if ARCHS[self.arch].router_bias_rate:
+                aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
+                                            for name, a in per_layer}
         else:
             aux_total = jnp.float32(0.0)
-            for aux in per_layer:
+            for _, aux in per_layer:
                 aux_total = aux_total + aux
         x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,

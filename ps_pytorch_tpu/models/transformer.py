@@ -31,9 +31,14 @@ class Arch(NamedTuple):
     norm_eps: float = 1e-6
     rope_theta: float = 0.0     # 0: learned position table | RoPE base
     qk_norm: bool = False       # norm over all d features of q and k, before the heads split
+    head_qk_norm: bool = False  # norm over ONE head's features of q and k, after the split (one scale [head_dim] for every head)
+    attn_gate: bool = False     # attention's output times sigmoid(norm(x) Wg), elementwise, before Wo
+    post_norm: bool = False     # a norm on each sublayer's OUTPUT as well: x + norm(sublayer(norm(x))), four norms a block
     dropless: bool = False      # MoE FFN: routed SwiGLU, sort + grouped matmul | capacity GELU
     embed_std: float = 0.0      # token embedding init: normal(std) | flax's default (1/sqrt(d))
+    embed_scale: bool = False   # the embedded token times sqrt(d)
     z_loss_coef: float = 0.0    # router z-loss in the ep step's loss
+    aux_coef: float = 0.01      # load-balance loss in the ep step's loss
     # Layers of several kinds: layer l is of kind l % len(pattern).
     window: int = 0             # keys a window layer's query sees, itself included
     window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
@@ -43,6 +48,11 @@ class Arch(NamedTuple):
     expert_act: str = "silu"    # the gate projection's activation: silu | relu
     early_router: bool = False  # the router reads the block's first norm (before attention), not the second
     expert_down_std: float = 0.0    # experts' down projection init: normal(std) | flax's lecun_normal
+    router_score: str = "softmax"   # scores of the router's logits: softmax over the experts | sigmoid of each
+    router_bias_rate: float = 0.0   # > 0: a bias [experts] is added to the scores for the top-k's CHOICE only (the gates
+    #                                 are the scores), and the ep step moves it by this much a step against the load
+    route_scale: float = 1.0        # the gates times this
+    shared_experts: int = 0         # experts of the routed ones' width that every token passes, beside the routed part
 
     def layer_window(self, layer: int) -> Optional[int]:
         if self.window_layers and \
@@ -90,6 +100,37 @@ ARCHS = {
                          window_layers=(0, 1, 1, 1), rope_layers=(0, 1, 1, 1),
                          gate_norm=True, expert_act="relu",
                          early_router=True, expert_down_std=0.002),
+    # Trinity-Mini (arcee-ai/Trinity-Mini config.json, model_type afmoe):
+    # rms_norm_eps 1e-5; sliding_window 2048 with RoPE (theta 10000) on three
+    # layers of four, full causal attention without position encoding on the
+    # fourth (layer_types); q/k norm over each head's 128 features;
+    # attention's output gated by sigmoid(a Wg); a norm on each sublayer's
+    # output too; the embedding times sqrt(d) (mup_enabled); leading dense
+    # SwiGLU layers (--lm-dense-layers), then expert layers: sigmoid scores,
+    # top-8 chosen by score + bias, gates the scores over their sum times
+    # route_scale 2.826, one shared expert; no auxiliary loss: the balancing
+    # is the bias, moved 0.001 a step (load_balance_coeff). embed_std: every
+    # sublayer's output passes a norm, so it has unit RMS whatever its
+    # weights are, and the embedding's scale alone says how much of the
+    # stream, and so of the logits, the blocks are. At HF's initializer_range
+    # 0.02 (RMS 0.9 after the multiplier) a freshly initialised attention
+    # layer's output, nearly one vector at every position, is most of what
+    # the router reads: the busiest of the 128 outputs draws 4 times the mean
+    # at step 1 and 11 times by step 40, and one flip of the 8th and 9th
+    # expert between bfloat16 and float32 moves a logit by over 1 (PR 31,
+    # chip). At 0.5 (RMS 23; the ten sublayers are a seventh of the stream)
+    # the busiest output draws 1.26 and falls, and the reference check keeps
+    # a limit between the program and float8 parameters under which 13 of 16
+    # planted mistakes in the block fail; at 0.25 there is no room for a
+    # limit, at 1.0 ten fail: benchmark/configs/trinity_mini.json
+    # reference_check.why, benchmark/controls/.
+    "trinity": Arch(rms_norm=True, norm_eps=1e-5, rope_theta=10000.0,
+                    head_qk_norm=True, attn_gate=True, post_norm=True,
+                    dropless=True, embed_std=0.5, embed_scale=True,
+                    aux_coef=0.0, window=2048, window_layers=(1, 1, 1, 0),
+                    rope_layers=(1, 1, 1, 0), gate_norm=True,
+                    router_score="sigmoid", router_bias_rate=0.001,
+                    route_scale=2.826, shared_experts=1),
 }
 
 
@@ -122,7 +163,9 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
                        decode_cache_len: int = 0, layer: int = 0,
                        kv_heads: int = 0, head_dim: int = 0):
     """``x + Wo . attention(norm(x))`` and ``norm(x)`` (an early router's
-    input): the one q/k/v/o path of both ``Block`` and
+    input); with the arch's ``attn_gate`` the attention's output is gated by
+    ``sigmoid(norm(x) Wg)`` before ``Wo``, with ``post_norm`` the sum is ``x +
+    norm(Wo . ...)``. The one q/k/v/o path of both ``Block`` and
     ``models/moe.MoEBlock``, called from their ``@nn.compact`` body, so its
     sub-modules are numbered in the CALLER's scope (GPT-2: ``LayerNorm_0``,
     ``Dense_0..3``, the tree ``benchmark/reference/gpt2_medium.py`` reads).
@@ -152,6 +195,9 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
         k = make_norm(arch, dtype, name="k_norm")(k)
     to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
     q, k, v = to_heads(q), to_heads(k), to_heads(v)
+    if a.head_qk_norm:
+        q = make_norm(arch, dtype, name="q_norm")(q)
+        k = make_norm(arch, dtype, name="k_norm")(k)
     if a.layer_rope(layer):
         q, k = rope(q, positions, a.rope_theta), rope(k, positions,
                                                       a.rope_theta)
@@ -166,7 +212,13 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     else:
         o = full_attention(q, k, v, causal=True, window=window)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
-    return x + nn.Dense(d, use_bias=False, dtype=dtype)(o), y
+    if a.attn_gate:
+        o = o * nn.sigmoid(nn.Dense(n_heads * hd, use_bias=False, dtype=dtype,
+                                    name="gate")(y))
+    o = nn.Dense(d, use_bias=False, dtype=dtype)(o)
+    if a.post_norm:
+        o = make_norm(arch, dtype, name="post_attn_norm")(o)
+    return x + o, y
 
 
 def remat_block(block_cls):
@@ -221,6 +273,8 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
         if a.embed_std else {}
     x = EmbedRows(vocab_size, d_model, dtype=dtype, name="tok_embed",
                   **init)(tokens)
+    if a.embed_scale:
+        x = x * jnp.asarray(d_model ** 0.5, dtype)
     if not a.rope_theta:
         x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
                           name="pos_embed")(positions)[None]

@@ -31,7 +31,10 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import keystr, tree_flatten_with_path
 
-from ps_pytorch_tpu.models.moe import DROPLESS_STATS
+from ps_pytorch_tpu.models.moe import (
+    BIAS_STATS, DROPLESS_STATS, EXPERT_COUNTS, MOE_STATE, lm_variables,
+    update_expert_bias,
+)
 from ps_pytorch_tpu.models.transformer import ARCHS
 from ps_pytorch_tpu.parallel.dp import TrainState
 from ps_pytorch_tpu.parallel.tp import _opt_state_specs
@@ -59,7 +62,8 @@ def ep_state_specs(state_shapes: TrainState, axis: str = "data") -> TrainState:
         params=pspecs,
         opt_state=_opt_state_specs(state_shapes.opt_state,
                                    state_shapes.params, pspecs),
-        batch_stats={},
+        # the model's MOE_STATE collection ({} for most archs): replicated
+        batch_stats=jax.tree.map(lambda _: P(), state_shapes.batch_stats),
     )
 
 
@@ -81,7 +85,8 @@ def create_ep_train_state(model, tx: optax.GradientTransformation,
             positions=jnp.arange(init_len))
         params = variables["params"]
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                          opt_state=tx.init(params), batch_stats={})
+                          opt_state=tx.init(params),
+                          batch_stats=variables.get(MOE_STATE, {}))
 
     shapes = jax.eval_shape(init_fn, rng)
     specs = ep_state_specs(shapes, axis)
@@ -93,14 +98,24 @@ def create_ep_train_state(model, tx: optax.GradientTransformation,
 
 def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                        state: TrainState, *, axis: str = "data",
-                       aux_coef: float = 0.01, remat: bool = False,
-                       donate: bool = True) -> Callable:
+                       remat: bool = False, donate: bool = True) -> Callable:
     """-> step_fn(state, tokens) -> (state, {'loss', 'aux'}); a dropless
-    arch (``olmoe``, ``smallthinker``) adds the rest of ``DROPLESS_STATS``:
-    'z_loss', 'expert_load_max_over_mean', 'moe_dropped', 'moe_held_share',
-    and its loss the arch's z-loss term, scaled by the token count as ``aux``
-    is. A model that holds a share of its experts (``experts_held``) trains
-    that share's part of each layer, on one device: there is no exchange.
+    arch adds the rest of ``DROPLESS_STATS``: 'z_loss',
+    'expert_load_max_over_mean', 'moe_dropped', 'moe_held_share', and its
+    loss the arch's z-loss term, scaled by the token count as ``aux`` is.
+    The load-balance term's coefficient is the arch's too
+    (``Arch.aux_coef``). A model that holds a share of its
+    experts (``experts_held``) trains that share's part of each layer, on one
+    device: there is no exchange.
+
+    Where the arch chooses its experts under a bias (``router_bias_rate``),
+    the bias is ``state.batch_stats`` (the model's ``MOE_STATE`` collection):
+    no gradient, no momentum and no weight decay reach it. After the
+    optimizer's update the step moves it by ``update_expert_bias`` from the
+    step's assignments to every router output, summed over ``axis``, and adds
+    ``BIAS_STATS`` to the metrics: 'moe_bias_abs_max' (the largest |bias|
+    after the move) and 'moe_load_all_max_over_mean' (the busiest of ALL the
+    router's outputs over the mean, worst layer).
 
     tokens [B, S] int32, batch sharded over ``axis``. ``model`` must be
     built with ``ep_axis=axis`` and ``n_groups=1`` (each device dispatches
@@ -127,14 +142,16 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     # SPMD-legal since every shard recomputes the same program.
     model = model.clone(n_local_experts=model.n_experts // n, n_groups=1,
                         remat=remat)
+    biased = arch.router_bias_rate > 0
 
     def local_step(state, tokens):
         def loss_fn(params):
-            logits, aux = model.apply({"params": params}, tokens)
+            logits, aux = model.apply(
+                lm_variables(params, state.batch_stats), tokens)
             stats = aux if arch.dropless else {"aux": aux}
             per = optax.softmax_cross_entropy_with_integer_labels(
                 logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
-            reg = aux_coef * stats["aux"] \
+            reg = arch.aux_coef * stats["aux"] \
                 + arch.z_loss_coef * stats.get("z_loss", 0.0)
             # LOCAL sums; collectives on the grads, not in the loss.
             return per.sum() + reg * per.size, \
@@ -142,6 +159,7 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
 
         (_, (count, ce_sum, stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
+        counts = stats.pop(EXPERT_COUNTS, None)
         total = jax.lax.psum(count, axis)
 
         def reduce_grad(path, g):
@@ -160,14 +178,28 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                       for k, v in stats.items()}}
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
-        return state.replace(step=state.step + 1, params=new_params,
-                             opt_state=new_opt), metrics
+        state = state.replace(step=state.step + 1, params=new_params,
+                              opt_state=new_opt)
+        if biased:
+            counts = jax.tree.map(lambda c: jax.lax.psum(c, axis), counts)
+            bias = jax.tree.map(
+                lambda b, c: update_expert_bias(b, c, arch.router_bias_rate),
+                state.batch_stats, counts)
+            state = state.replace(batch_stats=bias)
+            # every layer's leaf is [experts]: one [layers, experts] array each
+            stacked = lambda tree: jnp.stack(jax.tree.leaves(tree))
+            metrics["moe_bias_abs_max"] = jnp.max(jnp.abs(stacked(bias)))
+            load = stacked(counts).astype(jnp.float32)
+            metrics["moe_load_all_max_over_mean"] = jnp.max(
+                jnp.max(load, axis=-1) / jnp.mean(load, axis=-1))
+        return state, metrics
 
     specs = ep_state_specs(jax.eval_shape(lambda s: s, state), axis)
     sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, P(axis, None)),
         out_specs=(specs, {k: P() for k in ("loss",) + (
-            DROPLESS_STATS if arch.dropless else ("aux",))}),
+            DROPLESS_STATS if arch.dropless else ("aux",))
+            + (BIAS_STATS if biased else ())}),
         check_vma=False)
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

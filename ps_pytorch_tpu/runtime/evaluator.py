@@ -132,7 +132,8 @@ class Evaluator:
                                                 spec=P(None, "data"))
                 losses.append(float(eval_fn(params, tok)))
             else:
-                losses.append(float(self._lm_loss(params, jnp.asarray(t))))
+                losses.append(float(self._lm_loss(params, jnp.asarray(t),
+                                                  state.batch_stats)))
         loss = sum(losses) / max(len(losses), 1)
         result = {"step": step, "loss": loss, "perplexity": perplexity(loss)}
         self.printer(EVAL_LM_LINE.format(**result))

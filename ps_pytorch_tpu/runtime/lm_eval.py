@@ -47,13 +47,18 @@ def build_lm_model(cfg, **kw):
         from ps_pytorch_tpu.models.moe import MoETransformerLM
         return MoETransformerLM(n_experts=cfg.lm_experts,
                                 top_k=cfg.lm_moe_top_k,
-                                experts_held=cfg.lm_experts_held, **geo, **kw)
+                                experts_held=cfg.lm_experts_held,
+                                dense_layers=cfg.lm_dense_layers,
+                                dense_ffn_dim=cfg.lm_dense_ffn_dim, **geo, **kw)
     from ps_pytorch_tpu.models.transformer import TransformerLM
     return TransformerLM(**geo, **kw)
 
 
 def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
-    """-> (loss_fn(params, tokens) jitted, to_tree(saved_params)).
+    """-> (loss_fn(params, tokens, moe_state=None) jitted,
+    to_tree(saved_params)); ``moe_state`` is the saved state's
+    ``batch_stats`` where the model keeps a ``models/moe.MOE_STATE``
+    collection (an arch that chooses its experts under a bias).
 
     ``to_tree`` maps the checkpoint's param layout to the plain model tree
     (pp checkpoints store stage-stacked blocks). EP note: the oracle
@@ -62,9 +67,10 @@ def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
     to_tree = lambda p: p
     model = build_lm_model(cfg)
     if cfg.network == "MoETransformerLM":
-        apply = lambda p, t: model.apply({"params": p}, t)[0]
+        from ps_pytorch_tpu.models.moe import lm_variables
+        apply = lambda p, t, ms: model.apply(lm_variables(p, ms), t)[0]
     else:
-        apply = lambda p, t: model.apply({"params": p}, t)
+        apply = lambda p, t, ms: model.apply({"params": p}, t)
     if cfg.lm_parallelism == "pp":
         if cfg.lm_model_axis <= 0:
             raise ValueError(
@@ -75,8 +81,8 @@ def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
         to_tree = unstack_stage_params
 
     @jax.jit
-    def loss_fn(params, tokens):
-        logits = apply(params, tokens).astype(jnp.float32)
+    def loss_fn(params, tokens, moe_state=None):
+        logits = apply(params, tokens, moe_state).astype(jnp.float32)
         return optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :-1], tokens[:, 1:]).mean()
 
@@ -91,19 +97,23 @@ def build_lm_template(cfg):
     stays with ``build_lm_oracle``'s to_tree — one source of truth."""
     import jax.numpy as jnp
 
+    from ps_pytorch_tpu.models.moe import MOE_STATE
     from ps_pytorch_tpu.optim import build_schedule
     from ps_pytorch_tpu.optim.sgd import sgd
     from ps_pytorch_tpu.parallel.dp import TrainState
 
     model = build_lm_model(cfg)
     init_len = min(cfg.lm_seq_len, 128)
-    params = model.init(jax.random.key(0),
-                        jnp.zeros((1, init_len), jnp.int32),
-                        positions=jnp.arange(init_len))["params"]
+    variables = model.init(jax.random.key(0),
+                           jnp.zeros((1, init_len), jnp.int32),
+                           positions=jnp.arange(init_len))
+    params = variables["params"]
     if cfg.lm_parallelism == "pp":
         from ps_pytorch_tpu.parallel.pp import stack_stage_params
         params = stack_stage_params(params, cfg.lm_model_axis)
     tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
              weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
+    # batch_stats: an MoE model's MOE_STATE collection, {} for most archs
     return TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                      opt_state=tx.init(params), batch_stats={})
+                      opt_state=tx.init(params),
+                      batch_stats=variables.get(MOE_STATE, {}))

@@ -14,7 +14,8 @@ held-out next-token-loss oracle):
 - ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
   + ``parallel/ep.py``); also how an MoE model is run on one chip.
   ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe,
-  smallthinker; ``--lm-experts-held`` trains one chip's share of the experts).
+  smallthinker, trinity; ``--lm-experts-held`` trains one chip's share of the
+  experts, ``--lm-dense-layers`` starts the stack with dense layers).
 
 The reference has no LM surface at all — this is the §5.7 long-context
 capability expressed as a first-class entry point (``train_lm.py``), not
@@ -348,6 +349,7 @@ class LMTrainer:
         # reject.
         for k in ("lm_arch", "lm_vocab", "lm_d_model", "lm_layers",
                   "lm_heads", "lm_kv_heads", "lm_head_dim", "lm_ffn_dim",
+                  "lm_dense_layers", "lm_dense_ffn_dim",
                   "lm_parallelism", "lm_experts", "lm_experts_held",
                   "lm_model_axis", "lm_moe_top_k"):
             if k == "lm_model_axis" and saved.get(k) == 0:
@@ -441,7 +443,8 @@ class LMTrainer:
                          epoch):
             # The ep step's routing statistics (aux; a dropless arch's
             # z_loss, expert_load_max_over_mean, moe_dropped,
-            # moe_held_share) come with the loss.
+            # moe_held_share; under a selection bias moe_bias_abs_max and
+            # moe_load_all_max_over_mean) come with the loss.
             loss = own.pop("loss")
             derived = derive_step_record(
                 step_time_s=step_time, data_time_s=data_time,
@@ -585,9 +588,13 @@ class LMTrainer:
                                       n_groups=self.mesh.shape["data"],
                                       n_local_experts=None)
 
+            from ps_pytorch_tpu.models.moe import lm_variables
+            moe_state = dist.all_replicated(self.mesh, self.state.batch_stats)
+
             @jax.jit
             def loss_fn(params, tokens):  # noqa: F811 — ep refinement
-                logits, _ = oracle.apply({"params": params}, tokens)
+                logits, _ = oracle.apply(lm_variables(params, moe_state),
+                                         tokens)
                 return optax.softmax_cross_entropy_with_integer_labels(
                     logits[:, :-1].astype(jnp.float32), tokens[:, 1:]).mean()
 

@@ -467,7 +467,7 @@ TRAINING_COUNTERS = (
      "the host loop keeps ahead of the chip)"),
 )
 # An MoE step's routing statistics (parallel/ep.py: aux; a dropless arch adds
-# the other three), set by LMTrainer on every logged step under the names the
+# the next four, one that chooses under a bias the last two), set by LMTrainer on every logged step under the names the
 # JSONL record gives them.
 ROUTING_GAUGES = (
     ("aux", "", "MoE load-balance loss, experts * sum_e(share of assignments "
@@ -480,6 +480,12 @@ ROUTING_GAUGES = (
      "was not added (a dropless router must read 0)"),
     ("moe_held_share", "", "assignments to the experts held here over "
      "tokens*top_k, mean over layers (1 where every expert is held)"),
+    ("moe_bias_abs_max", "", "largest |expert_bias| over layers and experts "
+     "after the step's move: how far the balancing has shifted the top-k's "
+     "choice (archs that choose under a bias)"),
+    ("moe_load_all_max_over_mean", "", "busiest of ALL router outputs' "
+     "assignments over tokens*top_k/experts, worst layer: what the bias acts "
+     "on (archs that choose under a bias)"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

@@ -59,7 +59,7 @@ def _our_flags():
              for f in dataclasses.fields(TrainConfig)}
     sources = [p for p in REPO.glob("*.py")]
     sources += list((REPO / "ps_pytorch_tpu").rglob("*.py"))
-    sources += list((REPO / "benchmark").glob("*.py"))
+    sources += list((REPO / "benchmark").rglob("*.py"))
     for src in sources:
         flags.update(re.findall(r"[\"'](--[a-z][a-z0-9-]+)[\"']",
                                 src.read_text()))

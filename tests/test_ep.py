@@ -120,8 +120,7 @@ def test_ep_step_matches_unsharded(n_dev, top_k):
     rng = jax.random.key(7)
     batch, seq = 8, 32
     state = create_ep_train_state(ep_model, tx, mesh, (batch, seq), rng)
-    step_fn = make_ep_train_step(ep_model, tx, mesh, state,
-                                 aux_coef=0.01, donate=False)
+    step_fn = make_ep_train_step(ep_model, tx, mesh, state, donate=False)
 
     params = oracle_model.init(
         rng, jnp.zeros((batch, seq), jnp.int32),

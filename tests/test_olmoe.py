@@ -5,7 +5,6 @@ GPT-2's tree and numbers against golden values taken from the parent commit."""
 
 import hashlib
 import importlib.util
-import inspect
 import json
 import pathlib
 
@@ -145,8 +144,7 @@ def test_the_ep_step_descends_the_reference_loss(tiny):
     from ps_pytorch_tpu.parallel.dp import TrainState
 
     model, variables, tokens = tiny
-    assert inspect.signature(ep.make_ep_train_step).parameters[
-        "aux_coef"].default == PUBLISHED["load_balance_coef_as_run"]
+    assert ARCHS["olmoe"].aux_coef == PUBLISHED["load_balance_coef_as_run"]
     assert ARCHS["olmoe"].z_loss_coef == PUBLISHED["z_loss_coef_as_run"]
     lr = 0.5
     tx = optax.sgd(lr)
