@@ -36,6 +36,7 @@ from ps_pytorch_tpu.models.moe import (
     update_expert_bias,
 )
 from ps_pytorch_tpu.models.transformer import ARCHS
+from ps_pytorch_tpu.ops.next_token_loss import next_token_loss
 from ps_pytorch_tpu.parallel.dp import TrainState
 from ps_pytorch_tpu.parallel.tp import _opt_state_specs
 
@@ -149,13 +150,17 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             logits, aux = model.apply(
                 lm_variables(params, state.batch_stats), tokens)
             stats = aux if arch.dropless else {"aux": aux}
-            per = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :-1].astype(jnp.float32), tokens[:, 1:])
+            # The last position has no target: weight 0 under a filler (the
+            # sequence's first token), so the logits keep all S rows.
+            seq = tokens.shape[1]
+            weights = jnp.broadcast_to(jnp.arange(seq) < seq - 1,
+                                       tokens.shape).astype(jnp.float32)
+            ce_sum, count = next_token_loss(
+                logits, jnp.roll(tokens, -1, axis=1), weights)
             reg = arch.aux_coef * stats["aux"] \
                 + arch.z_loss_coef * stats.get("z_loss", 0.0)
             # LOCAL sums; collectives on the grads, not in the loss.
-            return per.sum() + reg * per.size, \
-                (jnp.float32(per.size), per.sum(), stats)
+            return ce_sum + reg * count, (count, ce_sum, stats)
 
         (_, (count, ce_sum, stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ps_pytorch_tpu.ops.next_token_loss import next_token_loss
 from ps_pytorch_tpu.parallel.dp import TrainState
 
 
@@ -68,18 +69,16 @@ def _local_nexttoken_loss(model, axis_name: str, params, tokens):
     idx = jax.lax.axis_index(axis_name)
     s_local = tokens.shape[1]
     positions = idx * s_local + jnp.arange(s_local)
-    logits = model.apply({"params": params}, tokens,
-                         positions=positions).astype(jnp.float32)
+    logits = model.apply({"params": params}, tokens, positions=positions)
     # Next-token targets: local shift; the boundary target (first token of
     # the next shard) arrives via one ppermute hop.
     perm = [(j, (j - 1) % n) for j in range(n)]
     first_next = jax.lax.ppermute(tokens[:, :1], axis_name, perm)
     targets = jnp.concatenate([tokens[:, 1:], first_next], axis=1)
-    per_tok = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
     # The global last token has no target: weight it out.
     is_global_last = positions == (n * s_local - 1)
-    w = jnp.where(is_global_last, 0.0, 1.0)[None, :]
-    return jnp.sum(per_tok * w), jnp.sum(w) * tokens.shape[0]
+    w = jnp.broadcast_to(jnp.where(is_global_last, 0.0, 1.0), tokens.shape)
+    return next_token_loss(logits, targets, w)
 
 
 def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",

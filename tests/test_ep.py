@@ -178,3 +178,62 @@ def test_ep_rejects_bad_config():
     with pytest.raises(ValueError, match="divisible"):
         make_ep_train_step(_moe_lm(ep_axis="data", n_experts=6), tx, mesh,
                            None)
+
+
+# ---- PR 32: the step with ops/next_token_loss.py against the step it replaced ----
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("arch", ["olmoe", "smallthinker", "trinity"])
+def test_ep_step_is_the_one_with_the_loss_spelled_the_parents_way(arch, dtype):
+    """One momentum-SGD step of ``make_ep_train_step`` on one device, each
+    dropless arch at a tiny size, against the same step with the loss as
+    ``parallel/ep.py`` spelled it until PR 32 (slice, astype, optax; kept in
+    ``test_next_token_loss.parent_loss`` as the oracle): ``loss``, ``aux``,
+    ``z_loss`` and every updated parameter."""
+    from ps_pytorch_tpu.models.moe import lm_variables
+    from ps_pytorch_tpu.models.transformer import ARCHS
+    from test_next_token_loss import (
+        arch_case, one_device_ep_step, parent_loss,
+    )
+
+    model, variables, tokens = arch_case(arch, batch=2,
+                                         dtype=jnp.dtype(dtype))
+    tx = sgd(lr=0.1, momentum=0.9, weight_decay=1e-4)
+    step, state = one_device_ep_step(model, variables, tx)
+    got_state, got = step(state, tokens)
+
+    row = ARCHS[arch]
+
+    @jax.jit
+    def parent_step(state):
+        def loss_fn(params):
+            logits, stats = model.apply(
+                lm_variables(params, state.batch_stats), tokens)
+            ce_sum, count = parent_loss(logits, tokens)
+            reg = row.aux_coef * stats["aux"] \
+                + row.z_loss_coef * stats["z_loss"]
+            return ce_sum + reg * count, (ce_sum, count, stats)
+        (_, (ce_sum, count, stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params)
+        grads = jax.tree.map(lambda g: g / count, grads)
+        updates, _ = tx.update(grads, state.opt_state, state.params)
+        return optax.apply_updates(state.params, updates), \
+            {"loss": ce_sum / count, "aux": stats["aux"],
+             "z_loss": stats["z_loss"]}
+
+    want_params, want = parent_step(state)
+    for k in want:
+        np.testing.assert_allclose(float(got[k]), float(want[k]), rtol=1e-6)
+    # float32: the two softmaxes differ in the last bit. bfloat16: dlogits is
+    # the same rounding of it (test_next_token_loss.py); what is left is the
+    # order XLA sums in. A parameter moves by lr * gradient = 1e-3..1e-2, and
+    # a norm's scale near 1 has an ulp of 1.2e-7.
+    atol = 5e-7 if dtype == "float32" else 2e-6
+    flat = jax.tree_util.tree_flatten_with_path(got_state.params)[0]
+    for (path, a), b in zip(flat, jax.tree.leaves(want_params)):
+        np.testing.assert_allclose(a, b, atol=atol, rtol=0,
+                                   err_msg=jax.tree_util.keystr(path))
+    moved = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()),
+                         got_state.params, state.params)
+    assert min(jax.tree.leaves(moved)) > 0 and \
+        max(jax.tree.leaves(moved)) > 1e-3

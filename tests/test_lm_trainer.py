@@ -405,3 +405,59 @@ def test_olmoe_checkpoint_refuses_to_resume_under_gpt2(tmp_path, monkeypatch):
     other = cfg.replace(lm_arch="gpt2", lm_moe_top_k=2, max_steps=2)
     with pytest.raises(ValueError, match="lm_arch=olmoe"):
         LMTrainer(other).train()
+
+
+# ---- PR 32: the sp step with ops/next_token_loss.py against the loss it replaced ----
+
+@pytest.mark.parametrize("n_dev,dtype", [(1, "float32"), (1, "bfloat16"),
+                                         (8, "float32"), (8, "bfloat16")])
+def test_sp_step_is_the_one_with_the_loss_spelled_the_parents_way(
+        monkeypatch, n_dev, dtype):
+    """One momentum-SGD step of ``make_sp_train_step`` (one device; the ring
+    over eight, where a shard's last target comes from the next shard)
+    against the same step with ``parallel/sp.py``'s loss as it was spelled
+    before PR 32: the whole logits cast to float32, optax, the weights."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import Mesh
+
+    from ps_pytorch_tpu.models.transformer import TransformerLM
+    from ps_pytorch_tpu.optim.sgd import sgd
+    from ps_pytorch_tpu.parallel import sp
+
+    def parent_loss(logits, targets, weights):
+        per_tok = optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), targets)
+        return jnp.sum(per_tok * weights), jnp.sum(weights)
+
+    seq, vocab = 64, 97
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("data",))
+    model = TransformerLM(
+        vocab_size=vocab, n_layers=2, n_heads=4, d_model=32, max_seq_len=seq,
+        dtype=jnp.dtype(dtype), axis_name="data",
+        attention_impl="ring" if n_dev > 1 else "full")
+    tokens = jnp.asarray(
+        np.random.default_rng(2).integers(0, vocab, (2, seq)), jnp.int32)
+    tx = sgd(lr=0.1, momentum=0.9, weight_decay=1e-4)
+    state = sp.create_lm_train_state(model, tx, mesh, (2, seq),
+                                     jax.random.key(3))
+    got_state, got = sp.make_sp_train_step(model, tx, mesh, donate=False)(
+        state, tokens)
+    monkeypatch.setattr(sp, "next_token_loss", parent_loss)
+    want_state, want = sp.make_sp_train_step(model, tx, mesh, donate=False)(
+        state, tokens)
+
+    np.testing.assert_allclose(float(got["loss"]), float(want["loss"]),
+                               rtol=1e-6)
+    # float32: the two softmaxes differ in the last bit; bfloat16: dlogits is
+    # the same rounding (test_next_token_loss.py), the sums' order is XLA's,
+    # and over the ring XLA also fuses the bfloat16 hops another way round
+    # the other loss (1.7e-5 on parameters that move by up to 1e-2)
+    atol = {"float32": 5e-7, "bfloat16": 2e-6 if n_dev == 1 else 5e-5}[dtype]
+    moved = 0.0
+    for a, b, c in zip(*(jax.tree.leaves(jax.device_get(t.params))
+                         for t in (got_state, want_state, state))):
+        np.testing.assert_allclose(a, b, atol=atol, rtol=0)
+        moved = max(moved, float(np.abs(a - c).max()))
+    assert moved > 1e-3
