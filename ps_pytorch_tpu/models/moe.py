@@ -56,6 +56,7 @@ from ps_pytorch_tpu.models.transformer import (
     ARCHS, attention_sublayer, embed_tokens, make_norm, remat_block,
 )
 from ps_pytorch_tpu.ops.grouped_matmul import gmm
+from ps_pytorch_tpu.telemetry.trace import device_scope
 
 # What a dropless model returns beside its logits (and the ep step passes
 # on), with how each is taken over the layers.
@@ -115,7 +116,8 @@ class MoEMLP(nn.Module):
         # x: [B, S, D] (the local shard when under shard_map)
         b, s, d = x.shape
         e = self.n_experts
-        tokens = x.reshape(-1, d)                     # [T, D]
+        with device_scope("moe_route"):
+            tokens = x.reshape(-1, d)                 # [T, D]
         t = tokens.shape[0]
         if self.ep_axis is not None and self.n_groups != 1:
             raise ValueError("under expert parallelism each device is one "
@@ -137,42 +139,45 @@ class MoEMLP(nn.Module):
 
         if self.top_k not in (1, 2):
             raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
-        router = nn.Dense(e, use_bias=False, dtype=self.dtype,
-                          name="router")(tokens)      # [T, E]
-        probs = jax.nn.softmax(router.astype(jnp.float32), axis=-1)
-        top_gates, top_idx = jax.lax.top_k(probs, self.top_k)  # [T, k]
-        if self.top_k > 1:
-            # GShard: gates renormalized over the selected experts. (For
-            # top_k=1 the raw probability is kept — normalizing would make
-            # every gate 1.0 and change switch semantics.)
-            top_gates = top_gates / jnp.sum(top_gates, axis=-1,
-                                            keepdims=True)
+        with device_scope("moe_route"):
+            router = nn.Dense(e, use_bias=False, dtype=self.dtype,
+                              name="router")(tokens)      # [T, E]
+            probs = jax.nn.softmax(router.astype(jnp.float32), axis=-1)
+            top_gates, top_idx = jax.lax.top_k(probs, self.top_k)  # [T, k]
+            if self.top_k > 1:
+                # GShard: gates renormalized over the selected experts. (For
+                # top_k=1 the raw probability is kept — normalizing would
+                # make every gate 1.0 and change switch semantics.)
+                top_gates = top_gates / jnp.sum(top_gates, axis=-1,
+                                                keepdims=True)
 
         # Per-group dispatch with RANK PRIORITY: rank-0 (first-choice)
         # assignments claim each expert's capacity slots before rank-1, so
         # overflow drops second choices first (GShard's ordering). Each
         # rank's queue positions are offset by the counts the earlier
         # ranks already enqueued.
-        xg = tokens.reshape(g, tg, d)
-        counts = jnp.zeros((g, 1, e), jnp.float32)    # slots used so far
-        disp = jnp.zeros((g, tg, e, cap), jnp.float32)
-        combine = jnp.zeros((g, tg, e, cap), jnp.float32)
-        oh0_g = None
-        for r in range(self.top_k):
-            oh = jax.nn.one_hot(top_idx[:, r], e, dtype=jnp.float32)
-            oh_g = oh.reshape(g, tg, e)
-            if r == 0:
-                oh0_g = oh_g
-            pos = jnp.cumsum(oh_g, axis=1) - oh_g + counts  # [G, TG, E]
-            pos_tok = jnp.sum(pos * oh_g, axis=-1)          # [G, TG]
-            keep = pos_tok < cap
-            slot = jax.nn.one_hot(pos_tok.astype(jnp.int32), cap,
-                                  dtype=jnp.float32)        # [G, TG, C]
-            d_r = (oh_g * keep[..., None])[..., None] * slot[:, :, None, :]
-            disp = disp + d_r
-            combine = combine + d_r * top_gates[:, r].reshape(g, tg, 1, 1)
-            counts = counts + jnp.sum(oh_g, axis=1, keepdims=True)
-        expert_in = jnp.einsum("gtec,gtd->gecd", disp, xg)  # [G, E, C, D]
+        with device_scope("moe_dispatch"):
+            xg = tokens.reshape(g, tg, d)
+            counts = jnp.zeros((g, 1, e), jnp.float32)    # slots used so far
+            disp = jnp.zeros((g, tg, e, cap), jnp.float32)
+            combine = jnp.zeros((g, tg, e, cap), jnp.float32)
+            oh0_g = None
+            for r in range(self.top_k):
+                oh = jax.nn.one_hot(top_idx[:, r], e, dtype=jnp.float32)
+                oh_g = oh.reshape(g, tg, e)
+                if r == 0:
+                    oh0_g = oh_g
+                pos = jnp.cumsum(oh_g, axis=1) - oh_g + counts  # [G, TG, E]
+                pos_tok = jnp.sum(pos * oh_g, axis=-1)          # [G, TG]
+                keep = pos_tok < cap
+                slot = jax.nn.one_hot(pos_tok.astype(jnp.int32), cap,
+                                      dtype=jnp.float32)        # [G, TG, C]
+                d_r = (oh_g * keep[..., None])[..., None] \
+                    * slot[:, :, None, :]
+                disp = disp + d_r
+                combine = combine + d_r * top_gates[:, r].reshape(g, tg, 1, 1)
+                counts = counts + jnp.sum(oh_g, axis=1, keepdims=True)
+            expert_in = jnp.einsum("gtec,gtd->gecd", disp, xg)  # [G,E,C,D]
 
         # Stacked expert FFNs. Under EP the leading axis is the LOCAL
         # expert slice; all_to_all swaps the grouping from
@@ -201,26 +206,33 @@ class MoEMLP(nn.Module):
             if el * n != e:
                 raise ValueError(f"n_local_experts={el} x {n} devices != "
                                  f"{e} experts")
-            ein = expert_in[0]                        # [E, C, D]
-            ein = jax.lax.all_to_all(ein, self.ep_axis, split_axis=0,
-                                     concat_axis=1, tiled=True)
-            out = ffn(ein, w1, b1, w2, b2)            # [E/n, n*C, D]
-            out = jax.lax.all_to_all(out, self.ep_axis, split_axis=1,
-                                     concat_axis=0, tiled=True)
-            expert_out = out[None]                    # [1, E, C, D]
+            with device_scope("moe_dispatch"):
+                ein = expert_in[0]                    # [E, C, D]
+                ein = jax.lax.all_to_all(ein, self.ep_axis, split_axis=0,
+                                         concat_axis=1, tiled=True)
+            with device_scope("moe_experts"):
+                out = ffn(ein, w1, b1, w2, b2)        # [E/n, n*C, D]
+            with device_scope("moe_dispatch"):
+                out = jax.lax.all_to_all(out, self.ep_axis, split_axis=1,
+                                         concat_axis=0, tiled=True)
+                expert_out = out[None]                # [1, E, C, D]
         else:
-            expert_out = jax.vmap(ffn, in_axes=(0, None, None, None, None))(
-                expert_in, w1, b1, w2, b2)            # [G, E, C, D]
+            with device_scope("moe_experts"):
+                expert_out = jax.vmap(
+                    ffn, in_axes=(0, None, None, None, None))(
+                    expert_in, w1, b1, w2, b2)        # [G, E, C, D]
 
-        y = jnp.einsum("gtec,gecd->gtd", combine,
-                       expert_out.astype(jnp.float32))
-        y = y.reshape(b, s, d).astype(x.dtype)
+        with device_scope("moe_dispatch"):
+            y = jnp.einsum("gtec,gecd->gtd", combine,
+                           expert_out.astype(jnp.float32))
+            y = y.reshape(b, s, d).astype(x.dtype)
 
         # Load-balance loss over FIRST choices (switch eq. 4; GShard uses
         # the same first-choice fractions), per group then averaged.
-        frac_tokens = jnp.mean(oh0_g, axis=1)         # [G, E]
-        frac_probs = jnp.mean(probs.reshape(g, tg, e), axis=1)
-        aux = e * jnp.mean(jnp.sum(frac_tokens * frac_probs, axis=-1))
+        with device_scope("moe_route"):
+            frac_tokens = jnp.mean(oh0_g, axis=1)     # [G, E]
+            frac_probs = jnp.mean(probs.reshape(g, tg, e), axis=1)
+            aux = e * jnp.mean(jnp.sum(frac_tokens * frac_probs, axis=-1))
         return y, aux
 
 
@@ -239,23 +251,28 @@ def _when(pred, fn, args, ints):
     pass is a ``cond`` of its own over ``jax.vjp(fn)`` in ``args`` (float
     arrays; ``ints`` carry no gradient), from the arguments alone. (A
     ``cond`` differentiated by JAX keeps both branches' residuals, zeros for
-    the one not taken: the full-size buffers this exists to avoid.)"""
+    the one not taken: the full-size buffers this exists to avoid.) Both
+    ``cond`` ops and their zeros (which XLA hoists out of the branch) stand
+    for a part's scatter-add and are under its device scope; the scopes ``fn``
+    opens inside are the innermost: the one place where scopes nest."""
     @jax.custom_vjp
     def run(pred, args, ints):
         out = jax.eval_shape(fn, *args, *ints)
-        return jax.lax.cond(
-            pred, lambda: fn(*args, *ints),
-            lambda: jnp.zeros(out.shape, out.dtype))
+        with device_scope("moe_dispatch"):
+            return jax.lax.cond(
+                pred, lambda: fn(*args, *ints),
+                lambda: jnp.zeros(out.shape, out.dtype))
 
     def fwd(pred, args, ints):
         return run(pred, args, ints), (pred, args, ints)
 
     def bwd(res, ct):
         pred, args, ints = res
-        grads = jax.lax.cond(
-            pred,
-            lambda: jax.vjp(lambda *a: fn(*a, *ints), *args)[1](ct),
-            lambda: tuple(jnp.zeros_like(a) for a in args))
+        with device_scope("moe_dispatch"):
+            grads = jax.lax.cond(
+                pred,
+                lambda: jax.vjp(lambda *a: fn(*a, *ints), *args)[1](ct),
+                lambda: tuple(jnp.zeros_like(a) for a in args))
         return None, grads, None
 
     run.defvjp(fwd, bwd)
@@ -330,32 +347,34 @@ class DroplessMoE(nn.Module):
         if e % held or not 0 <= self.share < e // held:
             raise ValueError(f"n_held={held} must divide n_experts={e} and "
                              f"share={self.share} name one of its blocks")
-        tokens = x.reshape(-1, d)                     # [T, D]
-        t = tokens.shape[0]
         # The router is d x E: float32 under `highest` costs nothing, and a
         # bf16-grade pass flips ties between the k-th and (k+1)-th expert.
-        router_in = tokens if router_x is None else router_x.reshape(-1, d)
-        router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
-                          precision=jax.lax.Precision.HIGHEST,
-                          name="router")(router_in.astype(jnp.float32))
-        if self.score == "sigmoid":
-            probs = jax.nn.sigmoid(router)            # [T, E] float32
-        else:
-            probs = jax.nn.softmax(router, axis=-1)
-        if self.select_bias:
-            bias = self.variable(MOE_STATE, "expert_bias", jnp.zeros, (e,),
-                                 jnp.float32).value
-            _, idx = jax.lax.top_k(probs + bias, k)
-            gates = jnp.take_along_axis(probs, idx, axis=-1)
-        else:
-            gates, idx = jax.lax.top_k(probs, k)      # [T, k]
-        if self.gate_norm:
-            total = jnp.sum(gates, axis=-1, keepdims=True)
+        with device_scope("moe_route"):
+            tokens = x.reshape(-1, d)                 # [T, D]
+            t = tokens.shape[0]
+            router_in = tokens if router_x is None \
+                else router_x.reshape(-1, d)
+            router = nn.Dense(e, use_bias=False, dtype=jnp.float32,
+                              precision=jax.lax.Precision.HIGHEST,
+                              name="router")(router_in.astype(jnp.float32))
             if self.score == "sigmoid":
-                total = total + _GATE_EPS     # the scores do not sum to 1
-            gates = gates / total
-        if self.route_scale != 1.0:
-            gates = gates * self.route_scale
+                probs = jax.nn.sigmoid(router)        # [T, E] float32
+            else:
+                probs = jax.nn.softmax(router, axis=-1)
+            if self.select_bias:
+                bias = self.variable(MOE_STATE, "expert_bias", jnp.zeros,
+                                     (e,), jnp.float32).value
+                _, idx = jax.lax.top_k(probs + bias, k)
+                gates = jnp.take_along_axis(probs, idx, axis=-1)
+            else:
+                gates, idx = jax.lax.top_k(probs, k)  # [T, k]
+            if self.gate_norm:
+                total = jnp.sum(gates, axis=-1, keepdims=True)
+                if self.score == "sigmoid":
+                    total = total + _GATE_EPS   # the scores do not sum to 1
+                gates = gates / total
+            if self.route_scale != 1.0:
+                gates = gates * self.route_scale
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         w_gate = self.param("experts_gate", init, (held, d, f))
@@ -367,30 +386,35 @@ class DroplessMoE(nn.Module):
 
         # Assignment a = token * k + choice; sorted by expert, stable. One
         # to an expert not held takes the key past the last held group.
-        flat_e = idx.reshape(-1)                      # [T*k]
-        load = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
-        first = self.share * held
-        group_sizes = load[first:first + held]
-        key = flat_e if held == e else jnp.where(
-            (flat_e >= first) & (flat_e < first + held), flat_e - first, held)
-        order = jnp.argsort(key, stable=True)
-        flat_gates = gates.reshape(-1)
-        n_held_rows = jnp.sum(group_sizes)
+        with device_scope("moe_route"):
+            flat_e = idx.reshape(-1)                  # [T*k]
+            load = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+            first = self.share * held
+            group_sizes = load[first:first + held]
+            key = flat_e if held == e else jnp.where(
+                (flat_e >= first) & (flat_e < first + held),
+                flat_e - first, held)
+            order = jnp.argsort(key, stable=True)
+            flat_gates = gates.reshape(-1)
+            n_held_rows = jnp.sum(group_sizes)
 
         def part(tokens, flat_gates, w_gate, w_up, w_down, order, sizes):
             """The rows ``order`` (sorted assignments), the first
             ``sum(sizes)`` of which the groups cover: gathered, through the
             experts, gated, scatter-added to their tokens."""
-            tok = order // k              # source row of each sorted assignment
+            with device_scope("moe_dispatch"):
+                tok = order // k          # source row of each sorted assignment
+                xs = tokens[tok].astype(self.dtype)   # gather [rows, D]
             # The float32 expert weights go to the kernels as they are: a
             # tile is cast to the rows' dtype in VMEM, and the weight gradient
             # comes back float32 from the float32 accumulator
             # (ops/grouped_matmul.py).
-            xs = tokens[tok].astype(self.dtype)       # gather [rows, D]
-            h = act(gmm(xs, w_gate, sizes)) * gmm(xs, w_up, sizes)
-            out = gmm(h, w_down, sizes)
-            out = out.astype(jnp.float32) * flat_gates[order][:, None]
-            return jnp.zeros((t, d), jnp.float32).at[tok].add(out)
+            with device_scope("moe_experts"):
+                h = act(gmm(xs, w_gate, sizes)) * gmm(xs, w_up, sizes)
+                out = gmm(h, w_down, sizes)
+            with device_scope("moe_dispatch"):
+                out = out.astype(jnp.float32) * flat_gates[order][:, None]
+                return jnp.zeros((t, d), jnp.float32).at[tok].add(out)
 
         # Rows the main part is sized for: all of them, or the held block's
         # balanced share with HELD_ROWS_SLACK, in whole row tiles.
@@ -402,33 +426,42 @@ class DroplessMoE(nn.Module):
             sizes_main = group_sizes
             y = part(tokens, flat_gates, *weights, order, group_sizes)
         else:
-            ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
-            sizes_main = jnp.diff(ends, prepend=0)
-            y = part(tokens, flat_gates, *weights, order[:rows], sizes_main)
-            y = y + _when(n_held_rows > rows, part,
-                          (tokens, flat_gates, *weights),
-                          (order[rows:], group_sizes - sizes_main))
+            with device_scope("moe_route"):
+                ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
+                sizes_main = jnp.diff(ends, prepend=0)
+                main = order[:rows]
+            y = part(tokens, flat_gates, *weights, main, sizes_main)
+            with device_scope("moe_route"):
+                overflows = n_held_rows > rows
+                over, sizes_over = order[rows:], group_sizes - sizes_main
+            extra = _when(overflows, part, (tokens, flat_gates, *weights),
+                          (over, sizes_over))
+            with device_scope("moe_dispatch"):
+                y = y + extra
 
         # Counters, off the gradient path. Rows the grouped matmul covered
         # are the first sum(sizes) of a part; each adds one to its token's
         # count (the overflow part's, run or not run as a whole, by their
         # number).
-        covered = jnp.arange(rows) < jnp.sum(sizes_main)
-        added = jnp.zeros((t,), jnp.int32).at[order[:rows] // k].add(
-            covered.astype(jnp.int32))
-        added = jnp.sum(added) + jnp.maximum(n_held_rows - rows, 0)
-        stats = {
-            "aux": e * jnp.sum((load.astype(jnp.float32) / t)
-                               * jnp.mean(probs, axis=0)),
-            "z_loss": jnp.mean(jax.nn.logsumexp(router, axis=-1) ** 2),
-            "expert_load_max_over_mean":
-                jnp.max(group_sizes).astype(jnp.float32) * e / (t * k),
-            "moe_dropped": (n_held_rows - added).astype(jnp.float32),
-            "moe_held_share": n_held_rows.astype(jnp.float32) / (t * k),
-        }
+        with device_scope("moe_route"):
+            covered = jnp.arange(rows) < jnp.sum(sizes_main)
+            added = jnp.zeros((t,), jnp.int32).at[order[:rows] // k].add(
+                covered.astype(jnp.int32))
+            added = jnp.sum(added) + jnp.maximum(n_held_rows - rows, 0)
+            stats = {
+                "aux": e * jnp.sum((load.astype(jnp.float32) / t)
+                                   * jnp.mean(probs, axis=0)),
+                "z_loss": jnp.mean(jax.nn.logsumexp(router, axis=-1) ** 2),
+                "expert_load_max_over_mean":
+                    jnp.max(group_sizes).astype(jnp.float32) * e / (t * k),
+                "moe_dropped": (n_held_rows - added).astype(jnp.float32),
+                "moe_held_share": n_held_rows.astype(jnp.float32) / (t * k),
+            }
         if self.select_bias:
             stats[EXPERT_COUNTS] = {"expert_bias": load}
-        return y.reshape(b, s, d).astype(x.dtype), stats
+        with device_scope("moe_dispatch"):
+            y = y.reshape(b, s, d).astype(x.dtype)
+        return y, stats
 
 
 class GatedFFN(nn.Module):
@@ -492,11 +525,18 @@ class MoEBlock(nn.Module):
             dtype=self.dtype, attention_impl=self.attention_impl,
             decode=self.decode, decode_cache_len=self.decode_cache_len,
             layer=self.layer, kv_heads=self.kv_heads, head_dim=self.head_dim)
-        y = make_norm(self.arch, self.dtype)(x)
+        # The block's second half is a dense layer's scope or an expert
+        # layer's three: the norms and the residual sum go to the first and
+        # the last of them.
+        enter, leave = ("ffn", "ffn") if self.dense_ffn_dim \
+            else ("moe_route", "moe_dispatch")
+        with device_scope(enter):
+            y = make_norm(self.arch, self.dtype)(x)
         width = self.ffn_dim or 4 * self.d_model
         if self.dense_ffn_dim:
-            m, aux = GatedFFN(self.dense_ffn_dim, self.dtype, a.expert_act,
-                              name="mlp")(y), None
+            with device_scope("ffn"):
+                m, aux = GatedFFN(self.dense_ffn_dim, self.dtype,
+                                  a.expert_act, name="mlp")(y), None
         elif a.dropless:
             m, aux = DroplessMoE(self.n_experts, self.d_model, width,
                                  top_k=self.top_k, dtype=self.dtype,
@@ -512,8 +552,9 @@ class MoEBlock(nn.Module):
                 aux[EXPERT_COUNTS] = {"moe": aux[EXPERT_COUNTS]}
             if a.shared_experts:
                 # every token's, whatever share of the routed experts is held
-                m = m + GatedFFN(a.shared_experts * width, self.dtype,
-                                 a.expert_act, name="shared")(y)
+                with device_scope("moe_shared"):
+                    m = m + GatedFFN(a.shared_experts * width, self.dtype,
+                                     a.expert_act, name="shared")(y)
         else:
             m, aux = MoEMLP(self.n_experts, self.d_model,
                             self.ffn_dim or 4 * self.d_model,
@@ -523,9 +564,11 @@ class MoEBlock(nn.Module):
                             n_local_experts=self.n_local_experts,
                             top_k=self.top_k, dtype=self.dtype,
                             name="moe")(y)
-        if a.post_norm:
-            m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(m)
-        return x + m, aux
+        with device_scope(leave):
+            if a.post_norm:
+                m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(m)
+            x = x + m
+        return x, aux
 
 
 class MoETransformerLM(nn.Module):
@@ -597,17 +640,19 @@ class MoETransformerLM(nn.Module):
                          name=f"block_{i}")(x, positions)
             if aux is not None:
                 per_layer.append((f"block_{i}", aux))
-        if ARCHS[self.arch].dropless:
-            aux_total = {k: over(jnp.stack([a[k] for _, a in per_layer]))
-                         for k, over in _OVER_LAYERS.items()}
-            if ARCHS[self.arch].router_bias_rate:
-                aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
-                                            for name, a in per_layer}
-        else:
-            aux_total = jnp.float32(0.0)
-            for _, aux in per_layer:
-                aux_total = aux_total + aux
-        x = make_norm(self.arch, self.dtype, name="ln_f")(x)
-        logits = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                          name="lm_head")(x)
+        with device_scope("moe_route"):     # the layers' statistics as one
+            if ARCHS[self.arch].dropless:
+                aux_total = {k: over(jnp.stack([a[k] for _, a in per_layer]))
+                             for k, over in _OVER_LAYERS.items()}
+                if ARCHS[self.arch].router_bias_rate:
+                    aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
+                                                for name, a in per_layer}
+            else:
+                aux_total = jnp.float32(0.0)
+                for _, aux in per_layer:
+                    aux_total = aux_total + aux
+        with device_scope("head"):
+            x = make_norm(self.arch, self.dtype, name="ln_f")(x)
+            logits = nn.Dense(self.vocab_size, use_bias=False,
+                              dtype=self.dtype, name="lm_head")(x)
         return logits, aux_total

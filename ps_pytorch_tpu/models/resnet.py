@@ -19,6 +19,8 @@ from typing import Any, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ps_pytorch_tpu.telemetry.trace import device_scope
+
 Conv = partial(nn.Conv, use_bias=False)
 
 
@@ -84,23 +86,28 @@ class BasicBlock(nn.Module):
         # explicit names keep xla/pallas checkpoints interchangeable.
         norm = partial(nn.BatchNorm, use_running_average=not train,
                        momentum=0.9, epsilon=1e-5, dtype=self.dtype)
-        if self.stride == 1:
+        with device_scope("conv"):
+            if self.stride == 1:
+                out = _conv3(self.planes, self.dtype, self.conv_impl,
+                             name="Conv_0")(x)
+            else:
+                out = Conv(self.planes, (3, 3),
+                           strides=(self.stride, self.stride),
+                           padding=1, dtype=self.dtype, name="Conv_0")(x)
+        with device_scope("batchnorm"):
+            out = nn.relu(norm()(out))
+        with device_scope("conv"):
             out = _conv3(self.planes, self.dtype, self.conv_impl,
-                         name="Conv_0")(x)
-        else:
-            out = Conv(self.planes, (3, 3),
-                       strides=(self.stride, self.stride),
-                       padding=1, dtype=self.dtype, name="Conv_0")(x)
-        out = nn.relu(norm()(out))
-        out = _conv3(self.planes, self.dtype, self.conv_impl,
-                     name="Conv_1")(out)
-        out = norm()(out)
-        if self.stride != 1 or x.shape[-1] != self.planes * self.expansion:
-            x = Conv(self.planes * self.expansion, (1, 1),
-                     strides=(self.stride, self.stride), dtype=self.dtype,
-                     name="Conv_2")(x)
-            x = norm()(x)
-        return nn.relu(out + x)
+                         name="Conv_1")(out)
+        with device_scope("batchnorm"):
+            out = norm()(out)
+        with device_scope("shortcut"):
+            if self.stride != 1 or x.shape[-1] != self.planes * self.expansion:
+                x = Conv(self.planes * self.expansion, (1, 1),
+                         strides=(self.stride, self.stride), dtype=self.dtype,
+                         name="Conv_2")(x)
+                x = norm()(x)
+            return nn.relu(out + x)
 
 
 class Bottleneck(nn.Module):
@@ -115,25 +122,33 @@ class Bottleneck(nn.Module):
         # Explicit legacy names — see BasicBlock.
         norm = partial(nn.BatchNorm, use_running_average=not train,
                        momentum=0.9, epsilon=1e-5, dtype=self.dtype)
-        out = nn.relu(norm()(Conv(self.planes, (1, 1), dtype=self.dtype,
-                                  name="Conv_0")(x)))
-        if self.stride == 1:
-            out = _conv3(self.planes, self.dtype, self.conv_impl,
-                         name="Conv_1")(out)
-        else:
-            out = Conv(self.planes, (3, 3),
-                       strides=(self.stride, self.stride),
-                       padding=1, dtype=self.dtype, name="Conv_1")(out)
-        out = nn.relu(norm()(out))
-        out = Conv(self.planes * self.expansion, (1, 1), dtype=self.dtype,
-                   name="Conv_2")(out)
-        out = norm()(out)
-        if self.stride != 1 or x.shape[-1] != self.planes * self.expansion:
-            x = Conv(self.planes * self.expansion, (1, 1),
-                     strides=(self.stride, self.stride), dtype=self.dtype,
-                     name="Conv_3")(x)
-            x = norm()(x)
-        return nn.relu(out + x)
+        with device_scope("conv"):
+            out = Conv(self.planes, (1, 1), dtype=self.dtype,
+                       name="Conv_0")(x)
+        with device_scope("batchnorm"):
+            out = nn.relu(norm()(out))
+        with device_scope("conv"):
+            if self.stride == 1:
+                out = _conv3(self.planes, self.dtype, self.conv_impl,
+                             name="Conv_1")(out)
+            else:
+                out = Conv(self.planes, (3, 3),
+                           strides=(self.stride, self.stride),
+                           padding=1, dtype=self.dtype, name="Conv_1")(out)
+        with device_scope("batchnorm"):
+            out = nn.relu(norm()(out))
+        with device_scope("conv"):
+            out = Conv(self.planes * self.expansion, (1, 1), dtype=self.dtype,
+                       name="Conv_2")(out)
+        with device_scope("batchnorm"):
+            out = norm()(out)
+        with device_scope("shortcut"):
+            if self.stride != 1 or x.shape[-1] != self.planes * self.expansion:
+                x = Conv(self.planes * self.expansion, (1, 1),
+                         strides=(self.stride, self.stride), dtype=self.dtype,
+                         name="Conv_3")(x)
+                x = norm()(x)
+            return nn.relu(out + x)
 
 
 class ResNet(nn.Module):
@@ -151,29 +166,35 @@ class ResNet(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = True):
         # x: [B, H, W, 3] NHWC (32px CIFAR or 224px ImageNet)
-        x = x.astype(self.dtype)
-        if self.imagenet_stem:
-            x = Conv(64, (7, 7), strides=(2, 2), padding=3, dtype=self.dtype,
-                     name="conv1")(x)
-        else:
-            x = Conv(64, (3, 3), padding=1, dtype=self.dtype, name="conv1")(x)
-        x = nn.relu(nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                                 epsilon=1e-5, dtype=self.dtype, name="bn1")(x))
-        if self.imagenet_stem:
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        with device_scope("conv"):
+            x = x.astype(self.dtype)
+            if self.imagenet_stem:
+                x = Conv(64, (7, 7), strides=(2, 2), padding=3,
+                         dtype=self.dtype, name="conv1")(x)
+            else:
+                x = Conv(64, (3, 3), padding=1, dtype=self.dtype,
+                         name="conv1")(x)
+        with device_scope("batchnorm"):
+            x = nn.relu(nn.BatchNorm(use_running_average=not train,
+                                     momentum=0.9, epsilon=1e-5,
+                                     dtype=self.dtype, name="bn1")(x))
+            if self.imagenet_stem:
+                x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                                padding=((1, 1), (1, 1)))
         for stage, (planes, n, stride) in enumerate(
                 zip((64, 128, 256, 512), self.num_blocks, (1, 2, 2, 2))):
             for i in range(n):
                 x = self.block(planes, stride if i == 0 else 1,
                                dtype=self.dtype,
                                conv_impl=self.conv_impl)(x, train=train)
-        if self.imagenet_stem:
-            x = x.mean(axis=(1, 2))          # global average pool (7x7 -> 1)
-        else:
-            x = nn.avg_pool(x, (4, 4), strides=(4, 4))  # reference :95
-            x = x.reshape((x.shape[0], -1))
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="linear")(x)
-        return x.astype(jnp.float32)
+        with device_scope("head"):
+            if self.imagenet_stem:
+                x = x.mean(axis=(1, 2))      # global average pool (7x7 -> 1)
+            else:
+                x = nn.avg_pool(x, (4, 4), strides=(4, 4))  # reference :95
+                x = x.reshape((x.shape[0], -1))
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="linear")(x)
+            return x.astype(jnp.float32)
 
 
 def ResNet18(num_classes=10, dtype=jnp.float32, conv_impl="xla"):

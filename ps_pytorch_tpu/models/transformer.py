@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES, flash_attention
 from ps_pytorch_tpu.parallel.ring import full_attention, ring_attention
+from ps_pytorch_tpu.telemetry.trace import device_scope
 
 
 class Arch(NamedTuple):
@@ -180,45 +181,55 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     window = a.layer_window(layer)
     if positions is None:
         positions = jnp.arange(s)
-    y = make_norm(arch, dtype)(x)
     # Separate q/k/v projections (not one packed Dense(3d)): under
     # tensor parallelism each kernel's OUTPUT dim is sharded over
     # 'model', and with per-projection kernels a shard's slice is
     # head-aligned (d = heads*hd), so attention can stay shard-local; a
     # packed qkv kernel puts shard boundaries inside q/k/v
     # (parallel/tp.py layout table).
-    q = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype)(y)
-    k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
-    v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
-    if a.qk_norm:
-        q = make_norm(arch, dtype, name="q_norm")(q)
-        k = make_norm(arch, dtype, name="k_norm")(k)
-    to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
-    q, k, v = to_heads(q), to_heads(k), to_heads(v)
-    if a.head_qk_norm:
-        q = make_norm(arch, dtype, name="q_norm")(q)
-        k = make_norm(arch, dtype, name="k_norm")(k)
-    if a.layer_rope(layer):
-        q, k = rope(q, positions, a.rope_theta), rope(k, positions,
-                                                      a.rope_theta)
-    if decode:
-        o = cached_attention(mod, q, k, v, decode_cache_len, window=window)
-    elif attention_impl == "ring":
-        o = ring_attention(q, k, v, axis_name, causal=True, window=window)
-    elif attention_impl == "flash":
-        # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
-        # materialization — the single-chip long-context path.
-        o = flash_attention(q, k, v, causal=True, window=window)
-    else:
-        o = full_attention(q, k, v, causal=True, window=window)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
+    with device_scope("attn_proj"):
+        y = make_norm(arch, dtype)(x)
+        q = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype)(y)
+        k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
+        v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
+    with device_scope("attn_pos"):
+        if a.qk_norm:
+            q = make_norm(arch, dtype, name="q_norm")(q)
+            k = make_norm(arch, dtype, name="k_norm")(k)
+        to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
+        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        if a.head_qk_norm:
+            q = make_norm(arch, dtype, name="q_norm")(q)
+            k = make_norm(arch, dtype, name="k_norm")(k)
+        if a.layer_rope(layer):
+            q, k = rope(q, positions, a.rope_theta), rope(k, positions,
+                                                          a.rope_theta)
+    with device_scope("attn_core"):
+        if decode:
+            o = cached_attention(mod, q, k, v, decode_cache_len,
+                                 window=window)
+        elif attention_impl == "ring":
+            o = ring_attention(q, k, v, axis_name, causal=True, window=window)
+        elif attention_impl == "flash":
+            # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
+            # materialization — the single-chip long-context path.
+            o = flash_attention(q, k, v, causal=True, window=window)
+        else:
+            o = full_attention(q, k, v, causal=True, window=window)
+    with device_scope("attn_pos"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
     if a.attn_gate:
-        o = o * nn.sigmoid(nn.Dense(n_heads * hd, use_bias=False, dtype=dtype,
-                                    name="gate")(y))
-    o = nn.Dense(d, use_bias=False, dtype=dtype)(o)
-    if a.post_norm:
-        o = make_norm(arch, dtype, name="post_attn_norm")(o)
-    return x + o, y
+        with device_scope("attn_proj"):
+            gate = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype,
+                            name="gate")(y)
+        with device_scope("attn_pos"):
+            o = o * nn.sigmoid(gate)
+    with device_scope("attn_proj"):
+        o = nn.Dense(d, use_bias=False, dtype=dtype)(o)
+        if a.post_norm:
+            o = make_norm(arch, dtype, name="post_attn_norm")(o)
+        x = x + o
+    return x, y
 
 
 def remat_block(block_cls):
@@ -271,13 +282,14 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
     a = ARCHS[arch]
     init = {"embedding_init": nn.initializers.normal(a.embed_std)} \
         if a.embed_std else {}
-    x = EmbedRows(vocab_size, d_model, dtype=dtype, name="tok_embed",
-                  **init)(tokens)
-    if a.embed_scale:
-        x = x * jnp.asarray(d_model ** 0.5, dtype)
-    if not a.rope_theta:
-        x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
-                          name="pos_embed")(positions)[None]
+    with device_scope("embed"):
+        x = EmbedRows(vocab_size, d_model, dtype=dtype, name="tok_embed",
+                      **init)(tokens)
+        if a.embed_scale:
+            x = x * jnp.asarray(d_model ** 0.5, dtype)
+        if not a.rope_theta:
+            x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
+                              name="pos_embed")(positions)[None]
     return x
 
 
@@ -352,10 +364,11 @@ class Block(nn.Module):
             axis_name=self.axis_name, decode=self.decode,
             decode_cache_len=self.decode_cache_len, layer=self.layer,
             kv_heads=self.kv_heads, head_dim=self.head_dim)
-        y = make_norm(self.arch, self.dtype)(x)
-        y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
-        y = nn.gelu(y)
-        x = x + nn.Dense(d, dtype=self.dtype)(y)
+        with device_scope("ffn"):
+            y = make_norm(self.arch, self.dtype)(x)
+            y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
+            y = nn.gelu(y)
+            x = x + nn.Dense(d, dtype=self.dtype)(y)
         return x
 
 
@@ -403,12 +416,13 @@ class TransformerLM(nn.Module):
                     arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
                     kv_heads=self.kv_heads, head_dim=self.head_dim,
                     name=f"block_{i}")(x, positions)
-        x = make_norm(self.arch, self.dtype, name="ln_f")(x)
         # Logits in ``dtype``, like every other output of the model: the loss
         # that consumes them casts to float32 (parallel/{sp,tp,pp,ep}.py,
         # runtime/lm_eval.py), so under float32 nothing changes.
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                        name="lm_head")(x)
+        with device_scope("head"):
+            x = make_norm(self.arch, self.dtype, name="ln_f")(x)
+            return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
+                            name="lm_head")(x)
 
 
 def migrate_packed_qkv(tree):

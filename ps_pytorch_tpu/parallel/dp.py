@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ps_pytorch_tpu.telemetry.trace import device_scope
+
 
 class TrainState(flax.struct.PyTreeNode):
     step: jnp.ndarray              # int32 scalar, replicated
@@ -100,7 +102,8 @@ def make_loss_fn(model, has_bn: bool, input_norm=None):
 
     def loss_fn(params, bs_local, x, y, rng):
         if input_norm is not None:
-            x = x * scale - shift
+            with device_scope("conv"):   # XLA fuses it into the first conv
+                x = x * scale - shift
         variables = {"params": params}
         if has_bn:
             variables["batch_stats"] = bs_local
@@ -112,8 +115,10 @@ def make_loss_fn(model, has_bn: bool, input_norm=None):
         else:
             logits = model.apply(variables, x, **kw)
             new_bs = bs_local
-        loss = optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
-        acc = jnp.mean(jnp.argmax(logits, -1) == y)
+        with device_scope("loss"):
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, y).mean()
+            acc = jnp.mean(jnp.argmax(logits, -1) == y)
         return loss, (new_bs, acc)
 
     return loss_fn
@@ -122,10 +127,11 @@ def make_loss_fn(model, has_bn: bool, input_norm=None):
 def apply_optimizer(tx, params, opt_state, grads):
     """update+apply for optax transforms, or the fused single-pass kernel
     when the optimizer exposes ``apply`` (ops/fused_sgd.FusedSGD)."""
-    if hasattr(tx, "apply"):
-        return tx.apply(params, opt_state, grads)
-    updates, new_opt = tx.update(grads, opt_state, params)
-    return optax.apply_updates(params, updates), new_opt
+    with device_scope("optimizer"):
+        if hasattr(tx, "apply"):
+            return tx.apply(params, opt_state, grads)
+        updates, new_opt = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), new_opt
 
 
 def masked_metrics(loss, acc, m, denom, msum):
@@ -203,37 +209,44 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         m = mask[0]
         # Masked mean over participating replicas == "aggregate the first K
         # arrivals then divide by K" (sync_replicas_master_nn.py:179,204-208).
-        msum = jax.lax.psum(m, "data")
-        denom = jnp.maximum(msum, 1.0)
-        gavg = jax.tree.map(
-            lambda g: jax.lax.psum(g * m, "data") / denom, grads)
-        # Global gradient norm over the averaged (post-psum) tree: every
-        # replica computes the identical scalar, so it doubles as the
-        # health plane's NaN/Inf sentinel at zero extra collectives.
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                             for g in jax.tree.leaves(gavg)))
+        with device_scope("grad_reduce"):
+            msum = jax.lax.psum(m, "data")
+            denom = jnp.maximum(msum, 1.0)
+            gavg = jax.tree.map(
+                lambda g: jax.lax.psum(g * m, "data") / denom, grads)
+            # Global gradient norm over the averaged (post-psum) tree: every
+            # replica computes the identical scalar, so it doubles as the
+            # health plane's NaN/Inf sentinel at zero extra collectives.
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                 for g in jax.tree.leaves(gavg)))
         new_params, new_opt = apply_optimizer(
             tx, state.params, state.opt_state, gavg)
         # An all-zero mask must be a true no-op: the reference master never
         # steps without K gradients (sync_replicas_master_nn.py:179,204-208);
         # without this guard momentum decay/step counters would still move.
-        stepped = msum > 0
-        if skip_nonfinite:
-            stepped = jnp.logical_and(stepped, jnp.isfinite(gnorm))
-        new_params = jax.tree.map(
-            lambda new, old: jnp.where(stepped, new, old), new_params, state.params)
-        new_opt = jax.tree.map(
-            lambda new, old: jnp.where(stepped, new, old), new_opt, state.opt_state)
-        if has_bn and sync_batchnorm:
-            # Masked mean: replicas excluded by K-of-N must not contaminate
-            # the synced stats (same discipline as the gradient path).
-            new_bs = jax.tree.map(
-                lambda a: jax.lax.psum(a * m, "data") / denom, new_bs)
-        metrics = health_metrics(masked_metrics(loss, acc, m, denom, msum),
-                                 gnorm)
-        new_state = state.replace(
-            step=state.step + 1, params=new_params, opt_state=new_opt,
-            batch_stats=jax.tree.map(lambda a: a[None], new_bs))
+        with device_scope("optimizer"):
+            stepped = msum > 0
+            if skip_nonfinite:
+                stepped = jnp.logical_and(stepped, jnp.isfinite(gnorm))
+            new_params = jax.tree.map(
+                lambda new, old: jnp.where(stepped, new, old),
+                new_params, state.params)
+            new_opt = jax.tree.map(
+                lambda new, old: jnp.where(stepped, new, old),
+                new_opt, state.opt_state)
+        with device_scope("grad_reduce"):
+            if has_bn and sync_batchnorm:
+                # Masked mean: replicas excluded by K-of-N must not
+                # contaminate the synced stats (same discipline as the
+                # gradient path).
+                new_bs = jax.tree.map(
+                    lambda a: jax.lax.psum(a * m, "data") / denom, new_bs)
+            metrics = health_metrics(
+                masked_metrics(loss, acc, m, denom, msum), gnorm)
+        with device_scope("optimizer"):
+            new_state = state.replace(
+                step=state.step + 1, params=new_params, opt_state=new_opt,
+                batch_stats=jax.tree.map(lambda a: a[None], new_bs))
         return new_state, metrics
 
     specs = state_specs(state)

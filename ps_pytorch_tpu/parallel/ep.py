@@ -39,6 +39,7 @@ from ps_pytorch_tpu.models.transformer import ARCHS
 from ps_pytorch_tpu.ops.next_token_loss import next_token_loss
 from ps_pytorch_tpu.parallel.dp import TrainState
 from ps_pytorch_tpu.parallel.tp import _opt_state_specs
+from ps_pytorch_tpu.telemetry.trace import device_scope
 
 _EXPERT_KEY = "experts_"   # models/moe.py stacked expert param names
 # How each of the model's routing statistics crosses the data axis.
@@ -153,19 +154,19 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # The last position has no target: weight 0 under a filler (the
             # sequence's first token), so the logits keep all S rows.
             seq = tokens.shape[1]
-            weights = jnp.broadcast_to(jnp.arange(seq) < seq - 1,
-                                       tokens.shape).astype(jnp.float32)
-            ce_sum, count = next_token_loss(
-                logits, jnp.roll(tokens, -1, axis=1), weights)
-            reg = arch.aux_coef * stats["aux"] \
-                + arch.z_loss_coef * stats.get("z_loss", 0.0)
-            # LOCAL sums; collectives on the grads, not in the loss.
-            return ce_sum + reg * count, (count, ce_sum, stats)
+            with device_scope("loss"):
+                weights = jnp.broadcast_to(jnp.arange(seq) < seq - 1,
+                                           tokens.shape).astype(jnp.float32)
+                ce_sum, count = next_token_loss(
+                    logits, jnp.roll(tokens, -1, axis=1), weights)
+                reg = arch.aux_coef * stats["aux"] \
+                    + arch.z_loss_coef * stats.get("z_loss", 0.0)
+                # LOCAL sums; collectives on the grads, not in the loss.
+                return ce_sum + reg * count, (count, ce_sum, stats)
 
         (_, (count, ce_sum, stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         counts = stats.pop(EXPERT_COUNTS, None)
-        total = jax.lax.psum(count, axis)
 
         def reduce_grad(path, g):
             # Expert leaves are device-owned: the all_to_all transpose
@@ -174,29 +175,34 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 return g / total
             return jax.lax.psum(g, axis) / total
 
-        paths, treedef = tree_flatten_with_path(grads)
-        grads = jax.tree_util.tree_unflatten(
-            treedef, [reduce_grad(p, g) for p, g in paths])
-        loss = jax.lax.psum(ce_sum, axis) / total
-        metrics = {"loss": loss,
-                   **{k: _STAT_REDUCE[k](v, axis)
-                      for k, v in stats.items()}}
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        state = state.replace(step=state.step + 1, params=new_params,
-                              opt_state=new_opt)
+        with device_scope("grad_reduce"):
+            total = jax.lax.psum(count, axis)
+            paths, treedef = tree_flatten_with_path(grads)
+            grads = jax.tree_util.tree_unflatten(
+                treedef, [reduce_grad(p, g) for p, g in paths])
+            loss = jax.lax.psum(ce_sum, axis) / total
+            metrics = {"loss": loss,
+                       **{k: _STAT_REDUCE[k](v, axis)
+                          for k, v in stats.items()}}
+        with device_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            state = state.replace(step=state.step + 1, params=new_params,
+                                  opt_state=new_opt)
         if biased:
-            counts = jax.tree.map(lambda c: jax.lax.psum(c, axis), counts)
-            bias = jax.tree.map(
-                lambda b, c: update_expert_bias(b, c, arch.router_bias_rate),
-                state.batch_stats, counts)
-            state = state.replace(batch_stats=bias)
-            # every layer's leaf is [experts]: one [layers, experts] array each
-            stacked = lambda tree: jnp.stack(jax.tree.leaves(tree))
-            metrics["moe_bias_abs_max"] = jnp.max(jnp.abs(stacked(bias)))
-            load = stacked(counts).astype(jnp.float32)
-            metrics["moe_load_all_max_over_mean"] = jnp.max(
-                jnp.max(load, axis=-1) / jnp.mean(load, axis=-1))
+            with device_scope("router_bias"):
+                counts = jax.tree.map(lambda c: jax.lax.psum(c, axis), counts)
+                bias = jax.tree.map(
+                    lambda b, c: update_expert_bias(
+                        b, c, arch.router_bias_rate),
+                    state.batch_stats, counts)
+                state = state.replace(batch_stats=bias)
+                # every layer's leaf is [experts]: one [layers, experts] each
+                stacked = lambda tree: jnp.stack(jax.tree.leaves(tree))
+                metrics["moe_bias_abs_max"] = jnp.max(jnp.abs(stacked(bias)))
+                load = stacked(counts).astype(jnp.float32)
+                metrics["moe_load_all_max_over_mean"] = jnp.max(
+                    jnp.max(load, axis=-1) / jnp.mean(load, axis=-1))
         return state, metrics
 
     specs = ep_state_specs(jax.eval_shape(lambda s: s, state), axis)

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ps_pytorch_tpu.ops.next_token_loss import next_token_loss
 from ps_pytorch_tpu.parallel.dp import TrainState
+from ps_pytorch_tpu.telemetry.trace import device_scope
 
 
 def create_lm_train_state(model, tx, mesh: Mesh, sample_tokens,
@@ -73,12 +74,14 @@ def _local_nexttoken_loss(model, axis_name: str, params, tokens):
     # Next-token targets: local shift; the boundary target (first token of
     # the next shard) arrives via one ppermute hop.
     perm = [(j, (j - 1) % n) for j in range(n)]
-    first_next = jax.lax.ppermute(tokens[:, :1], axis_name, perm)
-    targets = jnp.concatenate([tokens[:, 1:], first_next], axis=1)
-    # The global last token has no target: weight it out.
-    is_global_last = positions == (n * s_local - 1)
-    w = jnp.broadcast_to(jnp.where(is_global_last, 0.0, 1.0), tokens.shape)
-    return next_token_loss(logits, targets, w)
+    with device_scope("loss"):
+        first_next = jax.lax.ppermute(tokens[:, :1], axis_name, perm)
+        targets = jnp.concatenate([tokens[:, 1:], first_next], axis=1)
+        # The global last token has no target: weight it out.
+        is_global_last = positions == (n * s_local - 1)
+        w = jnp.broadcast_to(jnp.where(is_global_last, 0.0, 1.0),
+                             tokens.shape)
+        return next_token_loss(logits, targets, w)
 
 
 def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",
@@ -105,18 +108,20 @@ def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",
 
         (loss_sum, count), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        total = jax.lax.psum(count, axis_name)
         # Params are replicated, so each shard's backprop yields only the
         # contribution of computational paths through that shard (ring
         # ppermutes transpose to reverse ppermutes); the full mean-loss
         # gradient is their sum over the global token count.
-        grads = jax.tree.map(
-            lambda g: jax.lax.psum(g, axis_name) / total, grads)
-        loss = jax.lax.psum(loss_sum, axis_name) / total
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = state.replace(step=state.step + 1, params=new_params,
-                                  opt_state=new_opt)
+        with device_scope("grad_reduce"):
+            total = jax.lax.psum(count, axis_name)
+            grads = jax.tree.map(
+                lambda g: jax.lax.psum(g, axis_name) / total, grads)
+            loss = jax.lax.psum(loss_sum, axis_name) / total
+        with device_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            new_state = state.replace(step=state.step + 1, params=new_params,
+                                      opt_state=new_opt)
         return new_state, {"loss": loss}
 
     specs = TrainState(step=P(), params=P(), opt_state=P(), batch_stats={})

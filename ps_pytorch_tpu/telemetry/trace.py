@@ -38,6 +38,10 @@ start, and each recorded span carries its own start on the Unix clock,
 converted through that anchor. The xplane counts from its session's start,
 which it records as Unix ns (plane ``Task Environment``, stat
 ``profile_start_time``).
+
+The device's side of a step has spans of its own kind: ``device_scope``
+(below), one of ``DEVICE_SCOPES``, opened where each layer of the jitted step
+begins.
 """
 
 import itertools
@@ -49,6 +53,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterable, List, Optional
 
+import jax
 import jax.profiler
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
@@ -58,6 +63,52 @@ _US = 1e6
 
 
 ROOT_SPAN = "train_step"      # the root span of one trainer iteration
+
+# The layers of a jitted train step, as the device trace is read back by
+# them: the one list of these names. One level: a scope is not opened inside
+# another (flax's module names stay round them and tell the layer:
+# ``block_3/attn_core/...``), but for the held experts' overflow path, whose
+# ``cond`` runs a whole part under ``moe_dispatch`` (``models/moe._when``); a
+# reader takes the innermost.
+DEVICE_SCOPES = (
+    "embed",         # token and position rows, the embedding's multiplier
+    "attn_proj",     # pre-norm, q / k / v / gate / o projections, post_attn_norm
+    "attn_pos",      # q/k norm, RoPE, to_heads and its inverse, the gate's product
+    "attn_core",     # flash / full / ring / cached attention
+    "ffn",           # a dense feed-forward: norm, its matmuls, activation
+    "moe_route",     # the expert layer's norm, router, scores, top-k, gates, counts, sort, statistics
+    "moe_dispatch",  # gather of the sorted rows, gate multiply, scatter-add (capacity path: one-hot dispatch and combine); the layer's output norm
+    "moe_experts",   # the (grouped) expert matmuls and the activation between them
+    "moe_shared",    # the shared expert
+    "head",          # ln_f and lm_head; a CNN's pool and classifier
+    "loss",          # the loss call, its weights, the auxiliary terms
+    "grad_reduce",   # every psum / pmean of gradients, counts and metrics
+    "optimizer",     # tx.update + apply_updates, the step counter
+    "router_bias",   # the selection bias's move and its two counters
+    "conv", "batchnorm", "shortcut",    # the CIFAR ResNets' blocks and stem
+)
+
+
+def device_scope(name: str):
+    """A span on the DEVICE's side of a step: ``jax.named_scope(name)`` for a
+    name of ``DEVICE_SCOPES`` (any other raises).
+
+    Beside a host span it records nothing and times nothing. It exists only
+    while the step is traced: every op traced under it carries the name in
+    its ``op_name`` (``jit(step)/jvp(block_0)/attn_core/...``, the backward
+    pass's as ``transpose(jvp(...))``, a rematerialised forward's under
+    ``rematted_computation``), the compiled program is the same program
+    without it, and a run that takes no profile pays nothing. A profile's
+    ``XLA Ops`` events carry that name as the stat ``tf_op``, and
+    ``benchmark/readers/device_scopes.py`` adds the device's time up by it
+    (the ``DEVICE_BY_SCOPE`` table, the ``dev_*`` metrics). A fused op counts
+    to the scope of the one op whose name the fusion keeps: the matmul or
+    convolution of an output fusion (the SGD update XLA fuses behind a weight
+    gradient reads under the layer, not under ``optimizer``), else its root."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"{name!r} is not a device scope; "
+                         f"telemetry.trace.DEVICE_SCOPES has {DEVICE_SCOPES}")
+    return jax.named_scope(name)
 
 
 class _Frame:
@@ -86,7 +137,6 @@ class Tracer:
         self._ids = itertools.count(1)
         self._step_window = max(int(step_window), 1)
         self._step_totals: Dict[int, Dict[str, float]] = {}
-        self._totals: Dict[str, List[float]] = {}  # name -> [count, total_s]
 
     # ---- recording ----
     def _stack(self) -> list:
@@ -176,9 +226,6 @@ class Tracer:
             if len(self._buf) == self.capacity:
                 self.dropped += 1
             self._buf.append(ev)
-            c = self._totals.setdefault(f.name, [0, 0.0])
-            c[0] += 1
-            c[1] += dur
             if f.step is not None and not f.root:
                 # A phase is the SELF time of its spans: the phases of a
                 # step add up to the time under spans once, however the
@@ -197,13 +244,6 @@ class Tracer:
             acc = (self._step_totals.pop(int(step), {}) if pop
                    else dict(self._step_totals.get(int(step), {})))
         return {k: round(v, 6) for k, v in acc.items()}
-
-    def totals(self) -> Dict[str, dict]:
-        """Cumulative {name: {count, total_s}} over the tracer's lifetime
-        (not the ring buffer, so it survives wraparound)."""
-        with self._lock:
-            return {k: {"count": c, "total_s": round(t, 6)}
-                    for k, (c, t) in sorted(self._totals.items())}
 
     def spans(self) -> List[dict]:
         with self._lock:

@@ -44,8 +44,8 @@ def test_span_nesting_and_step_summary():
     assert set(s1) == {"outer", "inner"} and all(v >= 0 for v in s1.values())
     assert set(tr.step_summary(2)) == {"outer"}
     assert tr.step_summary(99) == {}
-    totals = tr.totals()
-    assert totals["outer"]["count"] == 2 and totals["inner"]["count"] == 1
+    names = [e["name"] for e in evs]
+    assert names.count("outer") == 2 and names.count("inner") == 1
 
 
 def test_chrome_trace_json_validity(tmp_path):
@@ -75,7 +75,7 @@ def test_ring_buffer_bounds_and_drop_count():
             pass
     assert len(tr.spans()) == 4
     assert tr.dropped == 6
-    assert tr.totals()["s"]["count"] == 10   # totals survive wraparound
+    assert len(tr.spans()) + tr.dropped == 10    # every span kept or counted
 
 
 def test_ambient_span_noop_without_tracer():
@@ -87,7 +87,7 @@ def test_ambient_span_noop_without_tracer():
         assert set_default_tracer(tr) is None   # returns the prior default
         with span("landed", step=2):
             pass
-        assert tr.totals()["landed"]["count"] == 1
+        assert [e["name"] for e in tr.spans()] == ["landed"]
         set_default_tracer(None)
     finally:
         set_default_tracer(prev)
@@ -540,9 +540,9 @@ def test_root_is_not_a_phase_and_nested_spans_are_counted_once():
     mask = [e for e in ev if e["name"] == "coordinator_mask"][0]
     coord = sum(e["dur"] for e in ev if e["name"] == "coordinator")
     assert phases["coordinator"] == pytest.approx(coord - mask["dur"], abs=5e-6)
-    # totals() stays whole durations over the tracer's lifetime
-    assert tr.totals()["coordinator"]["count"] == 2
-    assert tr.totals()["train_step"]["count"] == 1
+    # the recorded spans stay whole, one a span, the root among them
+    assert sum(e["name"] == "coordinator" for e in ev) == 2
+    assert sum(e["name"] == "train_step" for e in ev) == 1
 
 
 def test_begin_step_closes_what_was_left_open():
