@@ -20,48 +20,65 @@ Design notes
   ``flash_schedule(bh, s, d, itemsize, causal, window=, bh_kv=)``. On a v5e a 256x256x64
   tile alone in a grid step costs 1.4 us however the grid is cut (the MXU's
   and the reductions' latencies with nothing to overlap them), so a step
-  holds many tiles and a tile is large: ``g`` heads a step (a leading
-  dimension of every block, ``block_h`` of them batched through each
-  product so that independent chains interleave), all of a head's K/V rows
-  (``block_kv_major``; halved only where one head does not fit the stated
-  VMEM budget) and, in the backward pass, all of its q/dO rows
-  (``block_q_major``). The compute tile is the whole row of scores for
-  S <= 1024 (a plain softmax: no running statistics at all), else 512x512.
+  holds many tiles and a tile is large. The compute tile is the whole row
+  of scores for S <= 1024 (a plain softmax: no running statistics at all),
+  else 512x512; tile, mask, group and window are the two passes' to share,
+  and what a step keeps in VMEM is each pass's own, sized by that pass's
+  blocks against the stated budget (PR 34; until then the larger pass sized
+  both, and at S = 16384 the forward inherited a kv axis it had no need of):
+  * a forward step holds a q tile of ``g`` query heads (whole groups;
+    ``block_h`` of them batched through each product so that independent
+    chains interleave) and ``block_kv_major`` K/V rows of their K/V heads:
+    all of S wherever that fits, which is every shape a cell runs;
+  * a backward step holds ``bwd_block_kv_major`` K/V rows of ``bwd_g`` K/V
+    heads, with their dK/dV outputs and f32 sums, and ``block_q_major``
+    q/dO/dQ rows of as many query heads: the heads themselves, or under
+    grouped-query heads ``bwd_g`` of the ``bwd_g * group`` they serve, one
+    set after another along the grid. The rows are cut, q rows first, until
+    one head fits.
+  Heads a step are counted in K/V heads, the one count the passes share:
+  the most that fit both.
 - Forward: grid (bh/g, S/block_q, S/block_kv_major), kv innermost with
   ``arbitrary`` semantics. Inside a step a ``fori_loop`` walks the compute
-  tiles of the K/V block up to the causal limit of the q block: tiles
-  wholly under the diagonal first, without a mask, then the ones the
-  diagonal crosses, masked with a finite -1e30 (fully masked rows stay
-  NaN-free). A dead tile costs nothing; where the grid still has a kv axis
-  the K/V index map clamps a dead block to the last live one, so it is not
-  fetched either, and m/l/acc cross its steps in VMEM scratch (otherwise
-  they are loop carries).
+  tiles of the K/V block over the span the q tile sees (``_kv_span``: up to
+  the causal limit, from the window's edge): tiles wholly inside first,
+  without a mask, then the ones the diagonal or the window's edge crosses,
+  masked with a finite -1e30 (fully masked rows stay NaN-free). A dead tile
+  costs nothing. With the K/V head whole there is no kv axis and m/l/acc are
+  loop carries; where a head does not fit, the K/V index map clamps a dead
+  block to the last live one, so it is not fetched either, and m/l/acc
+  cross the axis's steps in VMEM scratch.
 - The saved residual is one LSE per query, not the score matrix, stored
   lane-dense as [bh, S/block_q, 1, block_q]: a [.., S, 1] array pads every
   float to a 128-lane row in VMEM and HBM.
-- Backward = ONE kernel, grid (bh/g, S/block_kv_major, S/block_q_major),
-  q innermost. Per (kv tile, q tile) pair the score tile, p = exp(s - lse)
-  and dO.V^T are formed once and feed dV, dK and dQ: five products. The
-  tile is held transposed ([block_kv, block_q]) so that LSE and delta
-  broadcast along sublanes and only the dQ product contracts over its
-  leading dimension. dK/dV sum over the q tiles in loop carries (and over
-  q blocks, where the grid has several, in f32 scratch); dQ sums over the
+- Backward = ONE kernel, grid (bh_kv/bwd_g, S/bwd_block_kv_major,
+  group * S/block_q_major). The innermost (``arbitrary``) axis walks the
+  query heads of the step's K/V heads, ``bwd_g`` consecutive heads at a time
+  (a head reads the K/V head ``index // group``, as in the forward), and for
+  each set its q blocks: the K/V block's index does not change along it, so
+  K and V are fetched once for the whole group
+  and never repeated in HBM, and dK/dV sum over all its steps in f32
+  scratch (no scratch where the axis has one step). Per (kv tile, q tile)
+  pair the score tile, p = exp(s - lse) and dO.V^T are formed once and feed
+  dV, dK and dQ: five products. The tile is held transposed ([block_kv,
+  block_q]) so that LSE and delta broadcast along sublanes and only the dQ
+  product contracts over its leading dimension. A step's kv-tile loop runs
+  over the span its q rows see (``_kv_span`` again; where the rows are all
+  of S that is the whole block, and a plain loop) and, for each kv tile,
+  the q-tile loops over the span that sees it (``_q_span``), so every trip
+  is a live pair: ``FlashSchedule.bwd_visits`` counts them by the same two
+  functions. dK/dV sum over the q tiles in loop carries; dQ sums over the
   kv tiles of a step in its f32 output block (in scratch for narrower
-  outputs) and leaves once per (kv block, q block): with one kv block
-  (every shape whose head fits the budget) that is dQ itself, otherwise
-  f32 partials that XLA sums. ``delta = rowsum(dO * O)`` is a cheap XLA
-  elementwise pass outside.
+  outputs) and leaves once per (kv block, q block): with one kv block that
+  is dQ itself, otherwise ``dq_partials`` f32 partials that XLA sums.
+  ``delta = rowsum(dO * O)`` is a cheap XLA elementwise pass outside.
 - Grouped-query heads and a sliding window are parameters of the same two
-  kernels. With fewer K/V heads than query heads (``group`` query heads a
-  K/V head) a step holds whole groups: its K/V blocks are the ``g // group``
-  heads that ``index // group`` addresses, so K and V are never repeated in
-  HBM, one fetch serves the group, and dK/dV sum over the group's query
-  heads in the f32 scratch that also sums them over q blocks. With a window
-  ``W`` (query i sees keys ``i-W < j <= i``) the tile loops start at the
-  band's first tile and mask its lower edge as they mask the diagonal, grid
-  steps wholly outside the band are dead as causally dead ones are, and the
-  index maps clamp to the band from both sides; such calls are named
-  ``flash_win_*`` so that a trace tells the two kinds of layer apart.
+  kernels. With a window ``W`` (query i sees keys ``i-W < j <= i``) the
+  spans start at the band's first tile and the loops mask its lower edge as
+  they mask the diagonal, grid steps wholly outside the band are dead as
+  causally dead ones are, and the index maps clamp to the band from both
+  sides; such calls are named ``flash_win_*`` so that a trace tells the two
+  kinds of layer apart.
 - Matmuls run with ``preferred_element_type=f32``; q, k, v, dO are cast to
   f32 before the products and the probability tile to the value dtype for
   the PV product (under Mosaic an f32 operand takes one bf16 MXU pass).
@@ -121,62 +138,92 @@ def _next_smaller(s: int, rows: int, tile: int) -> int:
 
 
 class FlashSchedule(NamedTuple):
-    """What one grid step of the kernels holds; static per shape."""
-    g: int                  # heads a step (divides bh), both passes
-    block_h: int            # heads a compute tile (divides g)
+    """What one grid step of each kernel holds; static per shape. The compute
+    tile, the mask, the group and the window are shared; the heads and rows a
+    step keeps are each pass's own."""
     block_q: int            # compute tile, q rows (forward: the q block)
     block_kv: int           # compute tile, k/v rows
+    # forward: grid (bh/g, S/block_q, S/block_kv_major)
+    g: int                  # query heads a step (whole groups; divides bh)
+    block_h: int            # heads a compute tile, either pass (1 under a group)
     block_kv_major: int     # k/v rows a step keeps in VMEM
-    block_q_major: int      # backward: q/dO rows a step keeps in VMEM
-    grid: Tuple[int, int, int]        # forward
-    steps: int                        # forward grid steps a call
-    live: int                         # ... of which not causally dead
+    grid: Tuple[int, int, int]
+    steps: int                        # grid steps a call
+    live: int                         # ... of which not dead under the mask
+    vmem_bytes: int                   # the forward step's blocks
+    # backward: grid (bh_kv/bwd_g, S/bwd_block_kv_major,
+    #                 group * S/block_q_major)
+    # of ``bwd_g`` K/V heads and as many of their query heads at a time
+    bwd_block_kv_major: int  # k/v rows a step keeps in VMEM
+    block_q_major: int      # q/dO/dQ rows a step keeps in VMEM
     bwd_grid: Tuple[int, int, int]
     bwd_steps: int
     bwd_live: int
-    vmem_bytes: int                   # the larger pass's blocks
-    group: int = 1          # query heads a K/V head (a step holds g // group K/V heads)
+    bwd_vmem_bytes: int               # the backward step's blocks
+    group: int = 1          # query heads a K/V head
     window: int = 0         # keys a query sees, itself included; 0: all before it
     tiles: int = 0          # (q tile, kv tile) pairs a call's mask covers, either pass
-    live_tiles: int = 0     # ... of which the tile loops visit
+    live_tiles: int = 0     # ... of which hold a pair the mask admits
+    bwd_visits: int = 0     # pair slots the backward's tile loops visit
 
     @property
     def dead(self) -> int:
         return self.steps - self.live
 
+    @property
+    def bwd_g(self) -> int:
+        """K/V heads a step of either pass; the backward's heads a step."""
+        return self.g // self.group
+
+    @property
+    def dq_partials(self) -> int:
+        """dQ leaves the backward as this many arrays (float32 where > 1)."""
+        return self.bwd_grid[1]
+
     def describe(self) -> str:
-        kind = (f" kv_heads={self.g // self.group}" if self.group > 1 else "") \
+        kind = (f" kv_heads={self.bwd_g}" if self.group > 1 else "") \
             + (f" window={self.window}" if self.window else "")
+        grid = lambda g: "x".join(map(str, g))
         return (f"g={self.g}/{self.block_h} bq={self.block_q} "
                 f"kv={self.block_kv}/{self.block_kv_major} "
-                f"steps={self.steps} live={self.live} "
-                f"bwd_q={self.block_q}/{self.block_q_major} "
-                f"bwd_steps={self.bwd_steps} bwd_live={self.bwd_live}"
-                f"{kind} tiles={self.live_tiles}/{self.tiles}")
+                f"grid={grid(self.grid)} live={self.live}/{self.steps} "
+                f"bwd g={self.bwd_g}/{self.block_h} "
+                f"q={self.block_q}/{self.block_q_major} "
+                f"kv={self.block_kv}/{self.bwd_block_kv_major} "
+                f"grid={grid(self.bwd_grid)} "
+                f"live={self.bwd_live}/{self.bwd_steps} "
+                f"dq_partials={self.dq_partials}"
+                f"{kind} tiles={self.live_tiles}/{self.tiles} "
+                f"bwd_visits={self.live_tiles}/{self.bwd_visits}")
 
 
-def _vmem_bytes(g, bq, bkv, bkv_major, bq_major, s, d, itemsize, group=1):
-    """VMEM of one step's blocks, the larger of the two passes: inputs and
-    outputs double-buffered, the f32 accumulators, the score tiles. The
-    last dimension of a block pads to 128 lanes, an LSE row to 8 sublanes,
-    a [rows, 1] statistic to 128 lanes. A step's K/V side holds ``g //
-    group`` heads."""
+def _tile_bytes(bq, bkv):
+    return 4 * max(bq * bkv * 4, _TILE_BYTES)
+
+
+def _fwd_bytes(g, gk, bq, bkv, kvm, s, d, itemsize):
+    """VMEM of one forward step's blocks (``g`` query heads on ``gk`` K/V
+    heads): inputs and outputs double-buffered, the f32 accumulators, the
+    score tiles. The last dimension of a block pads to 128 lanes, an LSE row
+    to 8 sublanes, a [rows, 1] statistic to 128 lanes."""
     dp = -(-d // 128) * 128
-    gk = g // group
-    tiles = 4 * max(bq * bkv * 4, _TILE_BYTES)
-    kv_axis, q_axis = s > bkv_major, s > bq_major
-    fwd = (2 * (g * 2 * bq + gk * 2 * bkv_major) * dp * itemsize   # q o k v
-           + 2 * g * 8 * bq * 4                                # lse
-           + kv_axis * g * bq * (dp + 2 * 128) * 4             # acc, m, l
-           + tiles)
-    dq_size = 4 if kv_axis else itemsize
-    bwd = (2 * (g * 2 * bq_major + gk * 2 * bkv_major) * dp * itemsize  # q dO k v
-           + 2 * (g * bq_major * dq_size + gk * 2 * bkv_major * itemsize) * dp
-           + 2 * 2 * g * 8 * bq_major * 4                      # lse, delta
-           + (dq_size < 4 and bkv_major > bkv) * g * bq_major * dp * 4
-           + (q_axis or group > 1) * 2 * gk * bkv_major * dp * 4   # dk, dv sums
-           + tiles)
-    return max(fwd, bwd)
+    return (2 * (g * 2 * bq + gk * 2 * kvm) * dp * itemsize    # q o k v
+            + 2 * g * 8 * bq * 4                               # lse
+            + (s > kvm) * g * bq * (dp + 2 * 128) * 4          # acc, m, l
+            + _tile_bytes(bq, bkv))
+
+
+def _bwd_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group=1):
+    """VMEM of one backward step's blocks: ``g`` K/V heads and one query head
+    of each; padded as the forward's."""
+    dp = -(-d // 128) * 128
+    dq_size = 4 if s > kvm else itemsize
+    return (2 * g * (2 * qm + 2 * kvm) * dp * itemsize         # q dO k v
+            + 2 * g * (qm * dq_size + 2 * kvm * itemsize) * dp     # dq dk dv
+            + 2 * 2 * g * 8 * qm * 4                           # lse, delta
+            + (dq_size < 4 and kvm > bkv) * g * qm * dp * 4    # dq's sum
+            + (group * (s // qm) > 1) * 2 * g * kvm * dp * 4   # dk, dv sums
+            + _tile_bytes(bq, bkv))
 
 
 def _live_blocks(s, q_rows, kv_rows, causal, window=0):
@@ -185,11 +232,77 @@ def _live_blocks(s, q_rows, kv_rows, causal, window=0):
     block's last query and, under a window, its last key inside the window
     of the q block's first query."""
     n_q, n_kv = s // q_rows, s // kv_rows
-    live = sum(((not causal) or kj * kv_rows <= qi * q_rows + q_rows - 1)
-               and (not window
-                    or qi * q_rows - (kj * kv_rows + kv_rows - 1) < window)
+    live = sum(_live_block(qi * q_rows, q_rows, kj * kv_rows, kv_rows,
+                           causal, window)
                for qi in range(n_q) for kj in range(n_kv))
     return n_q * n_kv, live
+
+
+def _live_block(q0, q_rows, k0, kv_rows, causal, window):
+    """Does some query of q0 .. q0+q_rows-1 see some key of k0 .. k0+kv_rows-1?
+    On traced or plain integers: the kernels' own test of a dead step."""
+    live = (q0 + q_rows - 1 >= k0) if causal else True
+    if window:
+        live = live & (q0 - (k0 + kv_rows - 1) < window)
+    return live
+
+
+def _div(x, n: int):
+    """``max(x, 0) // n`` of a traced int32. Not jnp's ``//``: its floor
+    correction goes through ``sign``, whose Mosaic lowering traces a helper
+    function each time (24 ms a call on the chip's host: with eight to twelve
+    a kernel, 10 s of a GPT-2 step's first dispatch)."""
+    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
+
+
+# The tile loops' bounds, on traced int32 in the kernels and (``_INTS``) on
+# plain integers where the schedule counts what those loops visit.
+_TRACED = (_div, jnp.minimum)
+_INTS = (lambda x, n: max(x, 0) // n, min)
+
+
+def _kv_span(q0, rows, k0, bkv, n, causal, window, ops=_TRACED):
+    """Of the ``n`` tiles of ``bkv`` keys from key ``k0`` on, the span
+    [first, end) that queries q0 .. q0+rows-1 can see: a tile is live when
+    its first key <= the last query and, under a window, its last key is one
+    the first query still sees. Both kernels' kv-tile loops run over it."""
+    div, least = ops
+    end = least(div(q0 + rows - k0 + bkv - 1, bkv), n) if causal else n
+    first = least(div(q0 - window + 1 - k0, bkv), end) if window else 0
+    return first, end
+
+
+def _q_span(ks, bkv, q0, bq, n, causal, window, ops=_TRACED):
+    """Of the ``n`` tiles of ``bq`` queries from query ``q0`` on, the span
+    [first, end) that can see keys ks .. ks+bkv-1: a tile is live when its
+    last query >= the first key and, under a window, its first query still
+    sees the last key. The backward's q-tile loops run over it."""
+    div, least = ops
+    first = least(div(ks - q0, bq), n) if causal else 0
+    end = n
+    if window:
+        end = least(div(ks + bkv - 1 + window - q0 + bq - 1, bq), n)
+        first = least(first, end)
+    return first, end
+
+
+def _bwd_visits(s, bq, bkv, kvm, qm, causal, window):
+    """Pair slots the backward's loops visit for one query head: every trip
+    of a q-tile loop, and every kv tile whose K and V a step loads and casts
+    to find no q tile for. By the loops' own bounds (``_kv_span``,
+    ``_q_span``) on the steps that are not dead."""
+    visits = 0
+    for k0 in range(0, s, kvm):
+        for q0 in range(0, s, qm):
+            if not _live_block(q0, qm, k0, kvm, causal, window):
+                continue
+            lo, hi = _kv_span(q0, qm, k0, bkv, kvm // bkv, causal, window,
+                              _INTS)
+            for j in range(lo, hi):
+                t0, t1 = _q_span(k0 + j * bkv, bkv, q0, bq, qm // bq, causal,
+                                 window, _INTS)
+                visits += max(t1 - t0, 1)
+    return visits
 
 
 def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
@@ -216,35 +329,46 @@ def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
             f"flash_attention needs a power-of-two block >= 8 dividing the "
             f"sequence length; S={s} has none (use attention 'full')")
 
-    def fits(g, kvm, qm):
-        return _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group) \
+    def fwd_fits(gk, kvm):
+        return _fwd_bytes(gk * group, gk, bq, bkv, kvm, s, d, itemsize) \
             <= VMEM_BUDGET_BYTES
 
-    # K/V rows (and the backward's q/dO rows) a step keeps: all of S, halved
-    # until one head (one K/V head and its group of query heads) fits the
-    # budget; never under a compute tile. A q row is ``group`` heads deep.
-    kvm = _pick_block(s, block_kv_major) if block_kv_major else s
-    qm = s
-    while not fits(group, kvm, qm) and (kvm > bkv or qm > bq):
-        if group * qm >= kvm and qm > bq or kvm == bkv:
+    def bwd_fits(gk, kvm, qm):
+        return _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group) \
+            <= VMEM_BUDGET_BYTES
+
+    # Rows a step keeps, each pass by its own blocks: all of S, cut down
+    # until one K/V head fits the budget beside what the pass holds of its
+    # query heads (the forward a q tile of the whole group, the backward the
+    # q/dO/dQ rows of one of them); never under a compute tile.
+    top = _pick_block(s, block_kv_major) if block_kv_major else s
+    fwd_kvm = top
+    while not fwd_fits(1, fwd_kvm) and fwd_kvm > bkv:
+        fwd_kvm = _next_smaller(s, fwd_kvm, bkv)
+    kvm, qm = top, s
+    while not bwd_fits(1, kvm, qm) and (kvm > bkv or qm > bq):
+        if qm >= kvm and qm > bq or kvm == bkv:
             qm = _next_smaller(s, qm, bq)
         else:
             kvm = _next_smaller(s, kvm, bkv)
-    # Heads a step: the largest divisor of bh (in whole groups) that fits,
-    # leaves the call _MIN_STEPS steps where bh allows, and is not past the
-    # point where a step already holds _STEP_WORK live score elements.
+    # K/V heads a step, the one count the passes share (the forward holds
+    # their whole groups): the largest divisor of bh_kv that fits both,
+    # leaves a call _MIN_STEPS steps where the heads allow, and is not past
+    # the point where a step already holds _STEP_WORK live score elements.
+    n_kv = bh // group
     per_head = (s * s // 2 if causal else s * s) // ((s // kvm) * (s // qm))
-    g = group
-    for cand in range(group + 1, bh + 1):
-        if bh % cand or cand % group:
+    gk = 1
+    for cand in range(2, n_kv + 1):
+        if n_kv % cand:
             continue
-        if not fits(cand, kvm, qm) or bh // cand < min(bh, _MIN_STEPS):
+        if not (fwd_fits(cand, fwd_kvm) and bwd_fits(cand, kvm, qm)) \
+                or n_kv // cand < min(n_kv, _MIN_STEPS):
             break
-        g = cand
+        gk = cand
         if cand * per_head >= _STEP_WORK:
             break
-    return _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm,
-                     group=group, window=window)
+    return _schedule(bh, s, d, itemsize, causal, gk, bq, bkv, fwd_kvm, kvm,
+                     qm, group=group, window=window)
 
 
 def _group(bh, bh_kv):
@@ -267,25 +391,31 @@ def _window(window, s, causal):
     return int(window)
 
 
-def _schedule(bh, s, d, itemsize, causal, g, bq, bkv, kvm, qm, hb=None, *,
+def _schedule(bh, s, d, itemsize, causal, gk, bq, bkv, fwd_kvm, kvm, qm, *,
               group=1, window=0):
-    """The grids and step counts that follow from the block sizes."""
-    if hb is None:
-        # heads a compute tile: independent chains for the scheduler to
-        # interleave, while the f32 score tiles stay around _TILE_BYTES; one
-        # where heads share K/V (a tile then reads one K/V head)
-        hb = max(c for c in range(1, g + 1)
-                 if g % c == 0 and (c == 1 or group == 1
-                                    and c * bq * bkv * 4 <= _TILE_BYTES))
-    steps, live = _live_blocks(s, bq, kvm, causal, window)
+    """The grids and counts that follow from ``gk`` K/V heads a step, the
+    compute tile, the forward's K/V rows and the backward's K/V and q rows."""
+    g = gk * group
+    # heads a compute tile: independent chains for the scheduler to
+    # interleave, while the f32 score tiles stay around _TILE_BYTES; one
+    # where heads share K/V (a tile then reads one K/V head)
+    hb = max(c for c in range(1, g + 1)
+             if g % c == 0 and (c == 1 or group == 1
+                                and c * bq * bkv * 4 <= _TILE_BYTES))
+    steps, live = _live_blocks(s, bq, fwd_kvm, causal, window)
     bwd_steps, bwd_live = _live_blocks(s, qm, kvm, causal, window)
     tiles, live_tiles = _live_blocks(s, bq, bkv, causal, window)
     n = bh // g
     return FlashSchedule(
-        g, hb, bq, bkv, kvm, qm, (n, s // bq, s // kvm), n * steps, n * live,
-        (n, s // kvm, s // qm), n * bwd_steps, n * bwd_live,
-        _vmem_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group),
-        group, window, bh * tiles, bh * live_tiles)
+        bq, bkv,
+        g, hb, fwd_kvm, (n, s // bq, s // fwd_kvm), n * steps, n * live,
+        _fwd_bytes(g, gk, bq, bkv, fwd_kvm, s, d, itemsize),
+        kvm, qm,
+        (n, s // kvm, group * (s // qm)), n * group * bwd_steps,
+        n * group * bwd_live,
+        _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group),
+        group, window, bh * tiles, bh * live_tiles,
+        bh * _bwd_visits(s, bq, bkv, kvm, qm, causal, window))
 
 
 def _bdot(a, b, ca, cb):
@@ -316,14 +446,6 @@ def _rows(start, size):
     return pl.ds(pl.multiple_of(start, size), size)
 
 
-def _div(x, n: int):
-    """``max(x, 0) // n`` of a traced int32. Not jnp's ``//``: its floor
-    correction goes through ``sign``, whose Mosaic lowering traces a helper
-    function each time (24 ms a call on the chip's host: with eight to twelve
-    a kernel, 10 s of a GPT-2 step's first dispatch)."""
-    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
-
-
 def _compiler_params():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -346,19 +468,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     n_tiles = kvm // bkv
     single = sched.grid[2] == 1         # no kv axis: nothing carried over
 
-    if causal:
-        # tiles whose first key <= the last query are live; those whose
-        # last key <= the first query need no mask
-        n_live = jnp.minimum(_div(q0 + bq - k0 + bkv - 1, bkv), n_tiles)
-        n_full = jnp.minimum(_div(q0 + 1 - k0, bkv), n_live)
-    else:
-        n_live = n_full = n_tiles
-    t_lo = 0
+    # the live tiles of this step's K/V block; of them, those whose last key
+    # <= the first query need no mask at the diagonal, and those whose first
+    # key the last query still sees none at the window's edge
+    t_lo, n_live = _kv_span(q0, bq, k0, bkv, n_tiles, causal, window)
+    n_full = jnp.minimum(_div(q0 + 1 - k0, bkv), n_live) if causal \
+        else n_tiles
     if window:
-        # the band's lower edge: tiles whose last key the first query still
-        # sees are live; those whose first key the last query sees need no
-        # mask there
-        t_lo = jnp.minimum(_div(q0 - window + 1 - k0, bkv), n_live)
         t_in = jnp.clip(_div(q0 + bq - window - k0 + bkv - 1, bkv), t_lo,
                         n_live)
         n_full = jnp.clip(n_full, t_in, n_live)
@@ -501,19 +617,23 @@ def _bwd_scratch(sched, dq_dtype):
     """Which f32 accumulators the backward step needs beyond its output
     blocks: dQ's when the output block cannot hold the running sum itself
     (not f32, and more than one kv tile adds to it), dK/dV's when they
-    build up over several q blocks (steps) or over a group's query heads."""
+    build up over several steps: q blocks, a group's query heads."""
     return (dq_dtype != jnp.float32
-            and sched.block_kv_major > sched.block_kv,
-            sched.bwd_grid[2] > 1 or sched.group > 1)
+            and sched.bwd_block_kv_major > sched.block_kv,
+            sched.bwd_grid[2] > 1)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, *scratch, causal, scale, sched):
-    g, hb, bq, bkv, kvm, qm = (sched.g, sched.block_h, sched.block_q,
-                               sched.block_kv, sched.block_kv_major,
+    g, hb, bq, bkv, kvm, qm = (sched.bwd_g, sched.block_h, sched.block_q,
+                               sched.block_kv, sched.bwd_block_kv_major,
                                sched.block_q_major)
     group, window = sched.group, sched.window
-    im = pl.program_id(2)
+    # the innermost axis walks the query heads of the step's K/V heads, g at
+    # a time, and for each such set its q blocks: every step adds to the
+    # same K/V block's dK and dV
+    i = pl.program_id(2)
+    im, first_head = _q_block(i, sched), _q_heads(i, sched) * g
     k0 = pl.program_id(1) * kvm         # first key of this step's K/V block
     q0 = im * qm                        # first query of this step's q block
     n_q, n_kv = qm // bq, kvm // bkv
@@ -524,18 +644,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # f32, else in scratch; one pair a step writes its product directly
     one_pair = n_q == 1 and n_kv == 1
     dq_sum = scratch.pop(0) if dq_scratch else dq_ref
-    # dK/dV sum over the q tiles of a step in loop carries, and over the q
-    # blocks (steps) and a group's query heads, where there are several, in
-    # scratch
+    # dK/dV sum over the q tiles of a step in loop carries, and over the
+    # steps of the innermost axis, where there are several, in scratch
     dk_acc, dv_acc = scratch if dkv_scratch else (None, None)
-    # the step is dead when its last query comes before its first key, or
-    # its first query after the window of its last key
-    live = (q0 + qm - 1 >= k0) if causal else True
-    if window:
-        live = jnp.logical_and(live, q0 - (k0 + kvm - 1) < window)
+    live = _live_block(q0, qm, k0, kvm, causal, window)
+    # the kv tiles this step's q rows can see: all of them where the rows are
+    # all of S (a plain loop then, and the one that writes dK and dV whole)
+    j_lo, j_hi = (0, n_kv) if sched.bwd_grid[2] == group else \
+        _kv_span(q0, qm, k0, bkv, n_kv, causal, window)
 
     if dk_acc is not None:
-        @pl.when(im == 0)
+        @pl.when(i == 0)
         def _init():
             dk_acc[:] = jnp.zeros_like(dk_acc)
             dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -562,54 +681,33 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_sum[hs, rows, :] += dq
         return dk, dv
 
-    def _kv_tile(j, _, *, kv, hs):
-        """One kv tile of K/V head(s) ``kv`` against the q tiles of their
-        query heads: ``hs`` itself, or the ``group`` heads from ``hs`` on
-        that share the one K/V head (its tile is loaded and cast once for
-        all of them, and dK/dV leave once)."""
+    def _kv_tile(j, _, *, hs, kv):
+        """One kv tile of K/V head(s) ``kv`` against the q tiles of query
+        heads ``hs`` that see it."""
         rows = _rows(j * bkv, bkv)
         ks = k0 + j * bkv               # first key of the tile
         k = k_ref[kv, rows, :].astype(jnp.float32)
         v = v_ref[kv, rows, :].astype(jnp.float32)
-        t_hi = n_q
-        if causal:
-            # q tiles whose last query >= the first key are live; those
-            # whose first query >= the last key need no mask
-            t_live = jnp.minimum(_div(ks - q0, bq), n_q)
-            t_full = jnp.clip(_div(ks + bkv - 1 - q0 + bq - 1, bq), t_live,
-                              n_q)
-        else:
-            t_live = t_full = 0
+        # the live q tiles; of them, those whose first query >= the last key
+        # need no mask at the diagonal, and those whose last query sees the
+        # first key none at the window's far edge
+        t_live, t_hi = _q_span(ks, bkv, q0, bq, n_q, causal, window)
+        t_full = jnp.clip(_div(ks + bkv - 1 - q0 + bq - 1, bq), t_live,
+                          t_hi) if causal else 0
         if window:
-            # the band's far edge: q tiles whose first query still sees the
-            # last key are live; those whose last query sees the first key
-            # need no mask there
-            t_hi = jnp.minimum(_div(ks + bkv - 1 + window - q0 + bq - 1, bq),
-                               n_q)
-            t_live = jnp.minimum(t_live, t_hi)
-            t_full = jnp.minimum(t_full, t_hi)
             t_in = jnp.clip(_div(ks + window - q0, bq), t_full, t_hi)
-
-        def _q_tiles(hs, carry):
-            kw = dict(hs=hs, k=k, v=v, ks=ks)
-            if causal:
-                carry = jax.lax.fori_loop(
-                    t_live, t_full, partial(_pair, masked=True, **kw), carry)
-            carry = jax.lax.fori_loop(
-                t_full, t_in if window else n_q,
-                partial(_pair, masked=False, **kw), carry)
-            if window:
-                carry = jax.lax.fori_loop(
-                    t_in, t_hi, partial(_pair, masked=True, **kw), carry)
-            return carry
-
+        kw = dict(hs=hs, k=k, v=v, ks=ks)
         carry = (jnp.zeros((hb, bkv, d), jnp.float32),) * 2
-        if group == 1:
-            dk, dv = _q_tiles(hs, carry)
-        else:
-            dk, dv = jax.lax.fori_loop(
-                0, group, lambda h, c: _q_tiles(pl.ds(hs.start + h, 1), c),
-                carry)
+        if causal:
+            carry = jax.lax.fori_loop(
+                t_live, t_full, partial(_pair, masked=True, **kw), carry)
+        carry = jax.lax.fori_loop(
+            t_full, t_in if window else n_q,
+            partial(_pair, masked=False, **kw), carry)
+        if window:
+            carry = jax.lax.fori_loop(
+                t_in, t_hi, partial(_pair, masked=True, **kw), carry)
+        dk, dv = carry
         if dk_acc is None:
             dk_ref[kv, rows, :] = dk.astype(dk_ref.dtype)
             dv_ref[kv, rows, :] = dv.astype(dv_ref.dtype)
@@ -618,15 +716,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_acc[kv, rows, :] += dv
 
     def _heads(hg, _):
-        # a tile's query heads with their own K/V, or one K/V head with its
-        # group of query heads
-        hs = pl.ds(hg * hb * group, hb)
-        kv = hs if group == 1 else pl.ds(hg, 1)
-        jax.lax.fori_loop(0, n_kv, partial(_kv_tile, kv=kv, hs=hs), None)
+        # a tile's query heads with their own K/V, or one query head with
+        # the K/V head its group shares
+        hs = pl.ds(hg * hb, hb)
+        kv = hs if group == 1 else \
+            pl.ds(_div(first_head + hg, group), 1)
+        jax.lax.fori_loop(j_lo, j_hi, partial(_kv_tile, hs=hs, kv=kv), None)
 
     @pl.when(live)
     def _run():
-        jax.lax.fori_loop(0, g // (hb * group), _heads, None)
+        jax.lax.fori_loop(0, g // hb, _heads, None)
 
     if one_pair and causal:
         @pl.when(jnp.logical_not(live))
@@ -637,33 +736,53 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[:] = dq_sum[:].astype(dq_ref.dtype)
 
     if dk_acc is not None:
-        @pl.when(im == pl.num_programs(2) - 1)
+        @pl.when(i == pl.num_programs(2) - 1)
         def _finalize():
             dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
             dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _q_block(i, sched):
+    """The q block of position ``i`` on the backward's innermost axis: the
+    sets of query heads follow one another there, each with all its q
+    blocks (``_q_heads`` is the other half of ``i``)."""
+    return i if sched.group == 1 else \
+        jax.lax.rem(i, jnp.int32(sched.bwd_grid[2] // sched.group))
+
+
+def _q_heads(i, sched):
+    """Which set of ``bwd_g`` query heads, of the ``group`` sets that the
+    step's ``bwd_g`` K/V heads serve, position ``i`` works on: query heads
+    follow their K/V heads in order, so the sets are consecutive heads."""
+    return 0 if sched.group == 1 else \
+        jax.lax.div(i, jnp.int32(sched.bwd_grid[2] // sched.group))
+
+
 def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
-    g, bq, kvm, qm = (sched.g, sched.block_q, sched.block_kv_major,
+    g, bq, kvm, qm = (sched.bwd_g, sched.block_q, sched.bwd_block_kv_major,
                       sched.block_q_major)
-    gk, window = g // sched.group, sched.window
+    group, window = sched.group, sched.window
     n_kvm = s // kvm
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)         # [bh, s/bq, 1, bq]
 
-    def q_map(b, jm, im):
+    def q_heads(b, i):
+        return b * group + _q_heads(i, sched)
+
+    def q_map(b, jm, i):
+        im = _q_block(i, sched)
         # a dead block is not fetched: take the nearest live one
         if causal:
             im = jnp.maximum(im, _div(jm * kvm, qm))
         if window:
             im = jnp.minimum(im, _div(jm * kvm + kvm + window - 2, qm))
-        return (b, im, 0)
+        return (q_heads(b, i), im, 0)
 
     q_spec = pl.BlockSpec((g, qm, d), q_map)
-    kv_spec = pl.BlockSpec((gk, kvm, d), lambda b, jm, im: (b, jm, 0))
+    kv_spec = pl.BlockSpec((g, kvm, d), lambda b, jm, i: (b, jm, 0))
     row_spec = pl.BlockSpec((g, qm // bq, 1, bq),
-                            lambda b, jm, im: q_map(b, jm, im) + (0,))
+                            lambda b, jm, i: q_map(b, jm, i) + (0,))
     # one kv block: the step's dQ is dQ; several: f32 partials, summed below
     dq_dtype = q3.dtype if n_kvm == 1 else jnp.float32
     dq_scratch, dkv_scratch = _bwd_scratch(sched, dq_dtype)
@@ -672,7 +791,9 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
         grid=sched.bwd_grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
-            pl.BlockSpec((None, g, qm, d), lambda b, jm, im: (jm, b, im, 0)),
+            pl.BlockSpec((None, g, qm, d),
+                         lambda b, jm, i: (jm, q_heads(b, i),
+                                           _q_block(i, sched), 0)),
             kv_spec, kv_spec,
         ],
         out_shape=[
@@ -682,7 +803,7 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
         ],
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in
                         dq_scratch * [(g, qm, d)]
-                        + dkv_scratch * [(gk, kvm, d), (gk, kvm, d)]],
+                        + dkv_scratch * [(g, kvm, d), (g, kvm, d)]],
         compiler_params=_compiler_params(),
         name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
         interpret=interpret,

@@ -110,12 +110,14 @@ def test_schedule_case_is_what_it_says(name):
     b, h, s, d = q.shape
     sc = flash_schedule(b * h, s, d, 4, causal, **kw)
     assert (b * h) % sc.g == 0 and s % sc.block_kv_major == 0
+    assert sc.bwd_g == sc.g and s % sc.bwd_block_kv_major == 0
     if name == "g3_bh_not_pow2":
         assert sc.g == 3
     if name == "kv_block_of_4_tiles":
-        assert sc.block_kv_major == 4 * sc.block_kv
+        assert sc.block_kv_major == sc.bwd_block_kv_major == 4 * sc.block_kv
     if name.startswith("kv_axis"):
         assert sc.grid[2] == s // 64 and sc.bwd_grid[1] == s // 64
+        assert sc.dq_partials == s // 64
         assert (sc.dead > 0) == causal
 
 
@@ -166,33 +168,61 @@ def test_schedule_bf16_close(name):
             atol=3e-2 * float(jnp.abs(b).max()), err_msg=f"{name} d{leaf}")
 
 
+# What only a sequence too long for the VMEM budget reaches through
+# flash_schedule: the backward grid keeps a q axis too, so whole steps are
+# dead, dK/dV build up over steps and dQ comes as partials; and, under
+# grouped-query heads, the group's query heads as further positions of that
+# axis. name -> (heads, kv_heads, window, [(bq, bkv, forward's kv rows,
+# backward's kv rows, backward's q rows)])
+BACKWARD_AXES = {
+    "equal_heads": (4, 4, 0, [(64, 64, 64, 64, 64), (32, 64, 128, 128, 64)]),
+    "group_along_the_grid": (4, 2, 0, [(64, 64, 256, 64, 64),
+                                       (32, 64, 128, 128, 64)]),
+    "group_whole_kv_head": (6, 2, 0, [(32, 32, 256, 256, 64)]),
+    "group_window_of_3_tiles": (4, 2, 96, [(32, 32, 256, 64, 64),
+                                           (32, 32, 64, 128, 128)]),
+    "group_window_off_the_tile": (4, 1, 40, [(32, 16, 256, 64, 128),
+                                             (64, 32, 128, 32, 64)]),
+    "group_window_under_a_tile": (8, 2, 17, [(64, 32, 256, 64, 64)]),
+    "window_equal_heads": (2, 2, 100, [(32, 64, 128, 64, 128)]),
+}
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_backward_with_q_and_kv_axes(dtype):
-    # What only a sequence too long for the VMEM budget reaches through
-    # flash_schedule: the backward grid keeps a q axis too, so whole steps
-    # are causally dead, dK/dV build up over steps and dQ comes as partials.
-    q, k, v = (t.astype(dtype) for t in _qkv(b=1, h=4, s=256, seed=5))
-    for bq, bkv, kvm, qm in ((64, 64, 64, 64), (32, 64, 128, 64)):
-        sc = _schedule(4, 256, 64, q.dtype.itemsize, True, 2, bq, bkv,
-                          kvm, qm)
-        assert sc.bwd_live < sc.bwd_steps and sc.live < sc.steps
+@pytest.mark.parametrize("name", sorted(BACKWARD_AXES))
+def test_backward_with_q_and_kv_axes(name, dtype):
+    h, h_kv, window, blocks = BACKWARD_AXES[name]
+    s, d = 256, 64
+    q, _, _ = _qkv(b=1, h=h, s=s, seed=5)
+    _, k, v = _qkv(b=1, h=h_kv, s=s, seed=6)
+    q, k, v = (t.astype(dtype) for t in (q, k, v))
+    for bq, bkv, fwd_kvm, kvm, qm in blocks:
+        sc = _schedule(h, s, d, q.dtype.itemsize, True, min(h_kv, 2), bq, bkv,
+                       fwd_kvm, kvm, qm, group=h // h_kv, window=window)
+        assert (sc.bwd_live < sc.bwd_steps) == (kvm < s)
+        assert sc.dq_partials == s // kvm
+        assert sc.bwd_grid == (h_kv // min(h_kv, 2), s // kvm,
+                               (h // h_kv) * (s // qm))
+        assert (sc.live < sc.steps) == (fwd_kvm < s)
+        assert sc.bwd_visits == sc.live_tiles
 
         def flash(q, k, v):
-            r = lambda t: t.reshape(4, 256, 64)
+            r = lambda t: t.reshape(-1, s, d)
             return _flash(r(q), r(k), r(v), True, 0.125, sc,
-                             True).reshape(q.shape).astype(jnp.float32)
+                          True).reshape(q.shape).astype(jnp.float32)
         f32 = tuple(t.astype(jnp.float32) for t in (q, k, v))
         tol = 5e-4 if dtype == jnp.float32 else 3e-2
         got = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2),
                        argnums=(0, 1, 2))(q, k, v)
-        want = jax.grad(lambda *a: jnp.sum(
-            full_attention(*a, causal=True) ** 2), argnums=(0, 1, 2))(*f32)
+        want = jax.grad(lambda *a: jnp.sum(full_attention(
+            *a, causal=True, window=window or None) ** 2),
+            argnums=(0, 1, 2))(*f32)
         for a, b, leaf in zip(got, want, "qkv"):
-            assert a.dtype == dtype
+            assert a.dtype == dtype and a.shape == b.shape
             np.testing.assert_allclose(
                 a.astype(jnp.float32), b, rtol=tol,
                 atol=tol * max(1.0, float(jnp.abs(b).max())),
-                err_msg=f"{(bq, bkv, kvm, qm)} d{leaf}")
+                err_msg=f"{(bq, bkv, fwd_kvm, kvm, qm)} d{leaf}")
 
 
 # The three LM cells of BENCHMARK.json: (bh, s, d) -> what a later
@@ -212,6 +242,13 @@ CELL_STEPS = {  # item size -> forward steps, backward steps
 # candidates on a v5e at all three shapes (PERF.md section 6, PR 28).
 CELL_HEADS_2 = {"gpt2m_s1024": (4, 1), "gpt2m_s128": (64, 8),
                 "olmoe_s4096": (2, 1)}
+# ... and every block and grid of both passes there, as PR 33 ran them: the
+# compute tile, K/V rows a step, q/dO rows of a backward step, the two grids.
+CELL_BLOCKS_2 = {
+    "gpt2m_s1024": (1024, 1024, 1024, 1024, (16, 1, 1), (16, 1, 1)),
+    "gpt2m_s128": (128, 128, 128, 128, (8, 1, 1), (8, 1, 1)),
+    "olmoe_s4096": (512, 512, 4096, 4096, (8, 8, 1), (8, 1, 1)),
+}
 
 
 @pytest.mark.parametrize("itemsize", [4, 2])
@@ -221,18 +258,26 @@ def test_schedule_of_the_cells(cell, itemsize):
     sc = flash_schedule(bh, s, d, itemsize, True)
     if itemsize == 2:
         assert (sc.g, sc.block_h) == CELL_HEADS_2[cell]
+        assert (sc.block_q, sc.block_kv, sc.block_kv_major, sc.block_q_major,
+                sc.grid, sc.bwd_grid) == CELL_BLOCKS_2[cell]
+    # equal heads: the backward holds the forward's heads and K/V rows
+    assert (sc.bwd_g, sc.bwd_block_kv_major) == (sc.g, sc.block_kv_major)
     assert bh % sc.g == 0
     for block in (sc.block_q, sc.block_kv, sc.block_kv_major,
                   sc.block_q_major):
         assert s % block == 0
     assert sc.block_kv_major % sc.block_kv == 0
     assert sc.block_q_major % sc.block_q == 0
-    assert sc.vmem_bytes <= VMEM_BUDGET_BYTES < VMEM_LIMIT_BYTES
+    assert sc.vmem_bytes <= sc.bwd_vmem_bytes <= VMEM_BUDGET_BYTES \
+        < VMEM_LIMIT_BYTES
     assert sc.grid == (bh // sc.g, s // sc.block_q, s // sc.block_kv_major)
     assert sc.steps == sc.grid[0] * sc.grid[1] * sc.grid[2]
     assert (sc.steps, sc.bwd_steps) == CELL_STEPS[cell][itemsize]
     assert sc.live == sc.steps and sc.bwd_live == sc.bwd_steps
+    # one kv block, one q block: dQ leaves as dQ, every kv tile is visited
+    assert sc.dq_partials == 1 and sc.bwd_visits == sc.live_tiles
     assert sc.describe().startswith(f"g={sc.g}/{sc.block_h} bq={sc.block_q} ")
+    assert f" bwd g={sc.bwd_g}/{sc.block_h} " in sc.describe()
 
 
 def test_schedule_counts_dead_steps_and_halves_to_fit():
@@ -246,7 +291,11 @@ def test_schedule_counts_dead_steps_and_halves_to_fit():
     # a sequence whose one head does not fit whole: blocks halve, g stays 1
     big = flash_schedule(8, 32768, 128, 4, True)
     assert big.g == 1 and big.vmem_bytes <= VMEM_BUDGET_BYTES
+    assert big.bwd_g == 1 and big.bwd_vmem_bytes <= VMEM_BUDGET_BYTES
     assert big.block_kv_major < 32768 and big.bwd_live < big.bwd_steps
+    # each pass by its own blocks: the forward's K/V rows are not the
+    # backward's, which also keeps q/dO/dQ rows and the dK/dV sums
+    assert big.bwd_block_kv_major < big.block_kv_major
 
 
 def test_schedule_is_pure_and_adapts_to_itemsize():

@@ -201,6 +201,9 @@ def test_one_step_keeps_state_gradients_and_loss_float32(
     line = next(l for l in capsys.readouterr().out.splitlines()
                 if l.startswith("KERNELS "))
     assert line.endswith(" dtype=bfloat16") and "flash_attention[" in line
+    # the schedule's record: both passes, and what the backward's loops visit
+    assert " grid=" in line and " bwd g=" in line and " dq_partials=" in line
+    assert " bwd_visits=" in line and " tiles=" in line
     records = [json.loads(l) for l in metrics.read_text().splitlines()]
     assert [r.get("compute_dtype") for r in records] == ["bfloat16", None]
     assert t.registry.get("compute_dtype") == 2

@@ -1,9 +1,15 @@
-"""The OLMoE cell's grouped matmuls and the SmallThinker cell's flash kernels
-compile under Mosaic for a described v5e (no chip): what the Pallas
-interpreter cannot show — VMEM over the limit, a
-slice off the tiling, a DMA the compiler refuses. One file, one fixture: only
-the worker that runs it loads the TPU compiler (on-chip-measurement guide,
-section 2)."""
+"""The OLMoE cell's grouped matmuls and the SmallThinker and Trinity cells'
+flash kernels compile under Mosaic for a described v5e (no chip): what the
+Pallas interpreter cannot show — VMEM over the limit, a
+slice off the tiling, a DMA the compiler refuses; and those two cells' whole
+train steps, for the bytes the compiler plans on the device. One file, one
+fixture: only the worker that runs it loads the TPU compiler
+(on-chip-measurement guide, section 2)."""
+
+import importlib
+import json
+import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -64,31 +70,122 @@ def test_expert_ffn_forward_and_backward_compile_at_the_cells_shape(
     assert len(grads) == 4
 
 
-@pytest.mark.parametrize("window", [None, 4096])
+# (batch, query heads, key/value heads, S, the window layers' window)
+FLASH_CELLS = {"smallthinker_s16384_1chip": (1, 28, 4, 16384, 4096),
+               "trinity_mini_s8192_1chip": (2, 32, 4, 8192, 2048)}
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+@pytest.mark.parametrize("cell", sorted(FLASH_CELLS))
 def test_flash_grouped_query_window_compiles_at_the_cells_shape(one_chip,
-                                                                 window):
-    """smallthinker_s16384_1chip: 28 query heads over 4 key/value heads of
-    128 at S=16384, bfloat16, forward and the one backward kernel, with and
-    without the window; dK and dV come back at the 4 heads."""
-    from ps_pytorch_tpu.ops.flash_attention import flash_attention
+                                                                 cell,
+                                                                 windowed):
+    """28 query heads over 4 key/value heads of 128 at S=16384, and 2 x 32
+    over 2 x 4 at S=8192, bfloat16: the forward (a K/V head whole, no kv
+    axis) and the one backward kernel (one query head's rows a step, the
+    group along the grid), with and without the window; dK and dV come back
+    at the key/value heads, dQ as two float32 partials or as itself."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_attention, flash_schedule,
+    )
+    b, h, h_kv, s, window = FLASH_CELLS[cell]
+    window = window if windowed else None
 
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
                                        interpret=False).astype(jnp.float32))
 
     def arg(heads):
-        return jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
+        return jax.ShapeDtypeStruct((b, heads, s, 128), jnp.bfloat16,
                                     sharding=one_chip)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        arg(28), arg(4), arg(4)).compile()
+        arg(h), arg(h_kv), arg(h_kv)).compile()
     text = compiled.as_text()
     names = ("flash_win_fwd", "flash_win_bwd_dkv") if window else \
         ("flash_fwd", "flash_bwd_dkv")
     for name in names:      # a bare kernel's call is named jvp_<name>_
         assert f"{name}_" in text
     assert ("flash_win_" in text) == bool(window)
-    assert "bf16[4,16384,128]" in text and "bf16[28,16384,128]" in text
+    assert f"bf16[{b * h_kv},{s},128]" in text
+    assert f"bf16[{b * h},{s},128]" in text
+    sc = flash_schedule(b * h, s, 128, 2, True, window=window,
+                        bh_kv=b * h_kv)
+    assert sc.grid[2] == 1 and sc.bwd_g == 1
+    assert (f"f32[{sc.dq_partials},{b * h},{s},128]" in text) \
+        == (sc.dq_partials > 1)
+
+
+# Bytes the compiler plans on the device for the two long-context cells' whole
+# steps (arguments + outputs - aliased + temporaries), at PR 34's parent
+# (4a23739) by this same compile. The step's peak is not at a flash call, so
+# the dQ partials that went do not lower it; what one buffer's size does to
+# the heap's packing moves the total by a few hundred KB either way.
+STEP_BYTES_AT_THE_PARENT = {"smallthinker_s16384_1chip": 8_643_807_232,
+                            "trinity_mini_s8192_1chip": 11_152_377_344}
+HEAP_PACKING_BYTES = 2 ** 20
+CELL_FILES = {"smallthinker_s16384_1chip": ("smallthinker_21b_a3b",
+                                            "s16384_1chip"),
+              "trinity_mini_s8192_1chip": ("trinity_mini", "s8192_1chip")}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_FILES))
+def test_the_cells_whole_step_plans_no_more_memory_than_the_parents(
+        one_chip, monkeypatch, cell):
+    """The jitted train step as ``LMTrainer`` builds it from the cell's own
+    flags (benchmark/configs, benchmark/traffic), compiled for the described
+    chip from shapes alone: every flash call in it, under ``--remat`` with
+    the saved forward, and ``memory_analysis()`` against the parent's."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ps_pytorch_tpu.config import config_from_args
+    from ps_pytorch_tpu.optim.schedules import build_schedule
+    from ps_pytorch_tpu.optim.sgd import sgd
+    from ps_pytorch_tpu.parallel import ep
+    from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
+    flash = importlib.import_module("ps_pytorch_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    config, traffic = CELL_FILES[cell]
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as f:
+        argv = json.load(f)["program_args"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        argv = argv + json.load(f)["args"]
+    cfg = config_from_args(argv)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    model = build_lm_model(cfg, attention_impl="flash", ep_axis="data")
+    tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
+             weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
+    shapes = jax.eval_shape(
+        partial(ep.create_ep_train_state, model, tx, mesh,
+                (cfg.batch_size, cfg.lm_seq_len)), jax.random.key(0))
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, ep.ep_state_specs(shapes, "data"))
+    tokens = jax.ShapeDtypeStruct(
+        (cfg.batch_size, cfg.lm_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))
+    compiled = ep.make_ep_train_step(
+        model, tx, mesh, shapes, remat=cfg.remat,
+        donate=cfg.donate).lower(state, tokens).compile()
+
+    text = compiled.as_text()
+    window_layers = cfg.lm_layers - cfg.lm_dense_layers - 1
+    for name, calls in (("flash_fwd", 1), ("flash_bwd_dkv", 1),
+                        ("flash_win_fwd", window_layers),
+                        ("flash_win_bwd_dkv", window_layers)):
+        assert text.count(f"%{name}.") >= calls, name
+        assert text.count(f"/{name}/pallas_call") > 0
+    m = compiled.memory_analysis()
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert planned <= STEP_BYTES_AT_THE_PARENT[cell] + HEAP_PACKING_BYTES
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():
