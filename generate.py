@@ -41,7 +41,9 @@ def main(argv=None) -> int:
 
     from ps_pytorch_tpu.config import TrainConfig
     from ps_pytorch_tpu.models.generate import generate
-    from ps_pytorch_tpu.models.transformer import migrate_packed_qkv
+    from ps_pytorch_tpu.models.transformer import (
+        migrate_packed_qkv, refuse_hybrid,
+    )
     from ps_pytorch_tpu.runtime import checkpoint as ckpt
     from ps_pytorch_tpu.runtime.lm_eval import (
         build_lm_oracle, build_lm_template,
@@ -52,6 +54,10 @@ def main(argv=None) -> int:
         p.error(f"no model_step_<k> checkpoints in {args.train_dir}")
     with open(f"{ckpt.checkpoint_path(args.train_dir, step)}/config.json") as f:
         cfg = TrainConfig.from_json(f.read())
+    try:
+        refuse_hybrid(cfg.lm_arch, "generate.py")
+    except ValueError as e:
+        p.error(str(e))
     if cfg.lm_arch != "gpt2":
         # RoPE at the cache offset and dropless decode are not pinned by a
         # parity test yet, nor a cache for window layers or grouped-query heads;

@@ -53,7 +53,8 @@ import jax
 import jax.numpy as jnp
 
 from ps_pytorch_tpu.models.transformer import (
-    ARCHS, attention_sublayer, embed_tokens, make_norm, remat_block,
+    ACTS, ARCHS, GatedFFN, attention_sublayer, embed_tokens, make_norm,
+    remat_block,
 )
 from ps_pytorch_tpu.ops.grouped_matmul import gmm
 from ps_pytorch_tpu.telemetry.trace import device_scope
@@ -241,7 +242,6 @@ class MoEMLP(nn.Module):
 # takes the overflow path below, which is slower and drops nothing.
 HELD_ROWS_SLACK = 1.5
 HELD_ROWS_TILE = 512      # ... in whole multiples of this many rows
-_ACTS = {"silu": nn.silu, "relu": nn.relu}
 _GATE_EPS = 1e-20         # under the sum of sigmoid gates (torchtitan's)
 
 
@@ -382,7 +382,7 @@ class DroplessMoE(nn.Module):
         w_down = self.param(
             "experts_down", nn.initializers.normal(self.down_std)
             if self.down_std else init, (held, f, d))
-        act = _ACTS[self.act]
+        act = ACTS[self.act]
 
         # Assignment a = token * k + choice; sorted by expert, stable. One
         # to an expert not held takes the key past the last held group.
@@ -464,22 +464,6 @@ class DroplessMoE(nn.Module):
         return y, stats
 
 
-class GatedFFN(nn.Module):
-    """``(act(x Wgate) * (x Wup)) Wdown`` without biases: a dropless model's
-    dense layer and its shared experts (SwiGLU under ``silu``)."""
-    d_hidden: int
-    dtype: Any = jnp.float32
-    act: str = "silu"
-
-    @nn.compact
-    def __call__(self, x):
-        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
-                                         name=name)
-        h = _ACTS[self.act](dense(self.d_hidden, "gate")(x)) \
-            * dense(self.d_hidden, "up")(x)
-        return dense(x.shape[-1], "down")(h)
-
-
 class MoEBlock(nn.Module):
     """transformer.Block with the dense MLP swapped for an expert layer
     (``MoEMLP`` or ``DroplessMoE``, by the arch; with the arch's shared experts
@@ -520,7 +504,7 @@ class MoEBlock(nn.Module):
     def __call__(self, x, positions=None):
         b, s, d = x.shape
         a = ARCHS[self.arch]
-        x, normed = attention_sublayer(
+        x, normed, _ = attention_sublayer(
             self, x, positions, arch=self.arch, n_heads=self.n_heads,
             dtype=self.dtype, attention_impl=self.attention_impl,
             decode=self.decode, decode_cache_len=self.decode_cache_len,

@@ -1,0 +1,124 @@
+"""The state-space mixers of a hybrid decoder: the Mamba-1 layer and the gated
+memory unit that reads a Mamba layer's scan output.
+
+Both are the first half of a ``models/transformer.Block`` (``x + mixer(norm(
+x))``), written as functions called from the block's ``@nn.compact`` body
+like ``attention_sublayer``, so their sub-modules live in the block's scope
+under the names given here. The norm is the caller's (``make_norm``): this
+module knows no arch.
+
+Mamba-1 (arXiv:2312.00752, the family's defaults; ``d_inner = expand * d``,
+``dt_rank = ceil(d / 16)``):
+
+    [u, z] = in_proj(a)                         d -> 2 d_inner, no bias
+    u      = silu(conv1d(u))                    depthwise, causal, d_conv taps, bias
+    [dt, B, C] = x_proj(u)                      d_inner -> dt_rank + 2 d_state, no bias
+    delta  = softplus(dt_proj(dt))              dt_rank -> d_inner, bias
+    A      = -exp(A_log)                        [d_inner, d_state]
+    m      = selective_scan(u, delta, A, B, C, D)       ops/selective_scan.py
+    out    = out_proj(m * silu(z))              d_inner -> d, no bias
+
+``m`` (before the gate, ``D * u`` included) is what the layer hands on. The
+gated memory unit (SambaY, arXiv:2507.06607) is ``out_proj(silu(in_proj(a)) *
+m)`` with ``in_proj: d -> d_inner`` and ``out_proj: d_inner -> d``, no biases.
+
+Precision under a narrower compute dtype: the projections and the
+convolution run in it; ``delta`` (the bias's add and the softplus), ``A``,
+``B``, ``C`` and the scan's state are float32; ``m`` leaves in the compute
+dtype.
+"""
+
+import math
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ps_pytorch_tpu.ops.selective_scan import selective_scan
+from ps_pytorch_tpu.telemetry.trace import device_scope
+
+# The family's initialisers (mamba_ssm Mamba.__init__): delta's bias is the
+# inverse softplus of a step drawn log-uniformly from [DT_MIN, DT_MAX].
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+
+
+def dt_rank(d_model: int) -> int:
+    return math.ceil(d_model / 16)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                 * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN))
+    dt = jnp.maximum(dt, DT_FLOOR)
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _symmetric_uniform(bound: float):
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
+def causal_conv1d(u, weight, bias):
+    """Depthwise causal convolution over the sequence: ``out[t] = bias +
+    sum_k weight[k] * u[t - (K - 1) + k]`` with zeros before the sequence
+    (``torch.nn.Conv1d(groups=channels, padding=K - 1)`` cut to S). u: [B, S,
+    C]; weight: [K, C]; bias: [C]. K shifted copies, multiplied and added:
+    K is 4."""
+    taps, s = weight.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(u.dtype)
+    for k in range(taps):
+        out = out + weight[k].astype(u.dtype) * padded[:, k:k + s]
+    return out
+
+
+def mamba_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype,
+                   d_state: int, d_conv: int, expand: int):
+    """``x + Mamba(norm(x))`` and ``{"memory": m, "ssm_state_abs_max": ...}``:
+    the scan's output before the gate, and the largest |state| at the scan's
+    chunk boundaries. ``mod``: the block, whose scope holds the parameters."""
+    d = x.shape[-1]
+    d_inner, rank = expand * d, dt_rank(d)
+    dense = lambda n, name, **kw: nn.Dense(n, dtype=dtype, name=name, **kw)
+    with device_scope("ssm_proj"):
+        a = norm(x)
+        u, z = jnp.split(dense(2 * d_inner, "in_proj", use_bias=False)(a), 2,
+                         axis=-1)
+    with device_scope("ssm_conv"):
+        conv_w = mod.param("conv_weight",
+                           _symmetric_uniform(d_conv ** -0.5),
+                           (d_conv, d_inner))
+        conv_b = mod.param("conv_bias", _symmetric_uniform(d_conv ** -0.5),
+                           (d_inner,))
+        u = nn.silu(causal_conv1d(u, conv_w, conv_b))
+    with device_scope("ssm_proj"):
+        dt, b, c = jnp.split(
+            dense(rank + 2 * d_state, "x_proj", use_bias=False)(u),
+            [rank, rank + d_state], axis=-1)
+        # the bias is added in float32 below, under the softplus
+        dt = dense(d_inner, "dt_proj", use_bias=False,
+                   kernel_init=_symmetric_uniform(rank ** -0.5))(dt)
+    with device_scope("ssm_conv"):
+        dt_bias = mod.param("dt_bias", _dt_bias_init, (d_inner,))
+        delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    a_log = mod.param(
+        "A_log", lambda key, shape: jnp.log(jnp.broadcast_to(
+            jnp.arange(1, shape[1] + 1, dtype=jnp.float32), shape)),
+        (d_inner, d_state))
+    skip = mod.param("D", nn.initializers.ones, (d_inner,))
+    with device_scope("ssm_scan"):
+        m, state_max = selective_scan(u, delta, -jnp.exp(a_log), b, c, skip)
+    with device_scope("ssm_conv"):
+        gated = m * nn.silu(z)
+    with device_scope("ssm_proj"):
+        x = x + dense(d, "out_proj", use_bias=False)(gated)
+    return x, {"memory": m, "ssm_state_abs_max": state_max}
+
+
+def gmu_sublayer(x, memory, norm: nn.Module, *, dtype):
+    """``x + out_proj(silu(in_proj(norm(x))) * memory)``: the gated memory
+    unit, on a Mamba layer's scan output ``memory [B, S, d_inner]``."""
+    dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=dtype, name=name)
+    with device_scope("gmu"):
+        gate = nn.silu(dense(memory.shape[-1], "in_proj")(norm(x)))
+        return x + dense(x.shape[-1], "out_proj")(gate * memory)

@@ -12,12 +12,14 @@ crosses shards. Learned positional embeddings are indexed by GLOBAL token
 position, passed in by the caller (the sp step knows each shard's offset).
 """
 
+import math
 from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ps_pytorch_tpu.models.ssm import gmu_sublayer, mamba_sublayer
 from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES, flash_attention
 from ps_pytorch_tpu.parallel.ring import full_attention, ring_attention
 from ps_pytorch_tpu.telemetry.trace import device_scope
@@ -54,8 +56,36 @@ class Arch(NamedTuple):
     #                                 are the scores), and the ep step moves it by this much a step against the load
     route_scale: float = 1.0        # the gates times this
     shared_experts: int = 0         # experts of the routed ones' width that every token passes, beside the routed part
+    # A decoder-hybrid-decoder stack: the layer's kind follows from its index
+    # AND the depth (``layer_kind``), not from a period.
+    hybrid: bool = False        # state-space / window layers, then a cross-decoder that reads one layer's scan output and one layer's K/V
+    ssm_state: int = 0          # a Mamba layer's states a channel (d_state)
+    ssm_conv: int = 0           # ... its causal depthwise convolution's taps (d_conv)
+    ssm_expand: int = 0         # ... its channels over d_model (d_inner = expand * d; dt_rank = ceil(d / 16))
+    diff_attn: bool = False     # differential attention: heads pair up (2j, 2j+1), a pair's output is softmax(q1 k1) v - lambda softmax(q2 k2) v over its two value heads side by side, then a norm
+    gated_ffn: bool = False     # the dense block's feed-forward: GatedFFN (SwiGLU, no biases) | Dense-GELU-Dense with biases
+    tied_head: bool = False     # logits = ln_f(x) . tok_embed^T: no lm_head parameter
+    no_positions: bool = False  # no position table although rope_theta is 0: no position encoding anywhere
 
-    def layer_window(self, layer: int) -> Optional[int]:
+    def layer_kind(self, layer: int, n_layers: int) -> str:
+        """One of ``LAYER_KINDS``; "attention" for every arch but a hybrid."""
+        if not self.hybrid:
+            return "attention"
+        if n_layers % 4:
+            raise ValueError(f"a hybrid stack's depth is a multiple of 4 "
+                             f"(two kinds alternate in each half), not "
+                             f"{n_layers}")
+        half = n_layers // 2
+        if layer < half:
+            return "window" if layer % 2 else "mamba"
+        if layer <= half + 1:
+            return "full_hands_kv" if layer % 2 else "mamba_hands_memory"
+        return "cross" if layer % 2 else "gmu"
+
+    def layer_window(self, layer: int, n_layers: int = 0) -> Optional[int]:
+        if self.hybrid:
+            return self.window \
+                if self.layer_kind(layer, n_layers) == "window" else None
         if self.window_layers and \
                 self.window_layers[layer % len(self.window_layers)]:
             return self.window
@@ -66,6 +96,19 @@ class Arch(NamedTuple):
             not self.rope_layers
             or bool(self.rope_layers[layer % len(self.rope_layers)]))
 
+
+# ``Arch.layer_kind``'s values. A hybrid stack of depth L (a multiple of 4):
+# Mamba and window-attention layers alternate in the first half; layer L/2 is
+# a Mamba layer whose scan output goes to every gated memory unit, layer
+# L/2 + 1 a full causal attention layer whose K and V go to every cross layer;
+# then gated memory units and cross-attention layers alternate.
+LAYER_KINDS = ("attention", "mamba", "window", "mamba_hands_memory",
+               "full_hands_kv", "gmu", "cross")
+ATTENTION_KINDS = ("attention", "window", "full_hands_kv", "cross")
+# What a hybrid block may hand on, and what it counts (max over layers):
+HANDED = ("memory", "k", "v")
+LM_COUNTERS = "lm_counters"     # the flax collection the counters are sown in
+COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max")
 
 ARCHS = {
     "gpt2": Arch(),
@@ -132,6 +175,20 @@ ARCHS = {
                     rope_layers=(1, 1, 1, 0), gate_norm=True,
                     router_score="sigmoid", router_bias_rate=0.001,
                     route_scale=2.826, shared_experts=1),
+    # Phi-4-mini-flash-reasoning (microsoft/Phi-4-mini-flash-reasoning
+    # config.json, model_type phi4flash; SambaY, arXiv:2507.06607, with
+    # differential attention, arXiv:2410.05258): layer_norm_eps 1e-5 on
+    # LayerNorms with scale and bias, sliding_window 512 on the first half's
+    # attention layers, mb_per_layer 2 (every other layer a Mamba-1 mixer of
+    # the family's defaults: d_state 16, d_conv 4, expand 2, dt_rank d / 16),
+    # a SwiGLU feed-forward without biases in every layer, the head tied to
+    # the embedding, no position encoding anywhere. embed_std: HF's
+    # initializer_range; the embedding is the head too, so its scale is the
+    # logits' (benchmark/configs/phi4_mini_flash.json reference_check.why).
+    "phi4flash": Arch(norm_eps=1e-5, hybrid=True, window=512, ssm_state=16,
+                      ssm_conv=4, ssm_expand=2, diff_attn=True,
+                      gated_ffn=True, tied_head=True, no_positions=True,
+                      embed_std=0.02),
 }
 
 
@@ -142,7 +199,7 @@ def make_norm(arch: str, dtype, name: Optional[str] = None) -> nn.Module:
     a = ARCHS[arch]
     if a.rms_norm:
         return nn.RMSNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
-    return nn.LayerNorm(dtype=dtype, name=name)
+    return nn.LayerNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
 
 
 def rope(x, positions, theta: float):
@@ -158,27 +215,45 @@ def rope(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
+def diff_lambda_init(layer: int) -> float:
+    """Differential attention's lambda_init for the layer of that index."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
 def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
                        n_heads: int, dtype, attention_impl: str,
                        axis_name: str = "data", decode: bool = False,
                        decode_cache_len: int = 0, layer: int = 0,
-                       kv_heads: int = 0, head_dim: int = 0):
-    """``x + Wo . attention(norm(x))`` and ``norm(x)`` (an early router's
-    input); with the arch's ``attn_gate`` the attention's output is gated by
-    ``sigmoid(norm(x) Wg)`` before ``Wo``, with ``post_norm`` the sum is ``x +
-    norm(Wo . ...)``. The one q/k/v/o path of both ``Block`` and
-    ``models/moe.MoEBlock``, called from their ``@nn.compact`` body, so its
-    sub-modules are numbered in the CALLER's scope (GPT-2: ``LayerNorm_0``,
-    ``Dense_0..3``, the tree ``benchmark/reference/gpt2_medium.py`` reads).
-    ``layer`` picks the layer's kind where the arch mixes kinds (window or
-    not, RoPE or not); ``kv_heads`` (0 = ``n_heads``) key/value heads of
-    ``head_dim`` (0 = ``d / n_heads``) serve ``n_heads / kv_heads`` query
-    heads each."""
+                       kv_heads: int = 0, head_dim: int = 0,
+                       n_layers: int = 0, shared_kv=None):
+    """``x + Wo . attention(norm(x))``, ``norm(x)`` (an early router's input)
+    and what the layer can hand on or count (``k``, ``v`` in heads;
+    ``diff_lambda_max``); with the arch's ``attn_gate`` the attention's
+    output is gated by ``sigmoid(norm(x) Wg)`` before ``Wo``, with
+    ``post_norm`` the sum is ``x + norm(Wo . ...)``. The one q/k/v/o path of
+    both ``Block`` and ``models/moe.MoEBlock``, called from their
+    ``@nn.compact`` body, so its sub-modules are numbered in the CALLER's
+    scope (GPT-2: ``LayerNorm_0``, ``Dense_0..3``, the tree
+    ``benchmark/reference/gpt2_medium.py`` reads). ``layer`` (with
+    ``n_layers`` for a hybrid arch) picks the layer's kind where the arch
+    mixes kinds (window or not, RoPE or not); ``kv_heads`` (0 = ``n_heads``)
+    key/value heads of ``head_dim`` (0 = ``d / n_heads``) serve ``n_heads /
+    kv_heads`` query heads each. ``shared_kv``: another layer's ``(k, v)``;
+    the layer then has a query and an output projection only (``Dense_0..1``).
+
+    With the arch's ``diff_attn`` heads pair up as (2j, 2j+1): query pair j
+    reads key pair ``j // group`` and, as its one value, that pair's two
+    value heads side by side (2 hd wide); the pair's output is ``softmax(q1
+    k1) v - lambda softmax(q2 k2) v`` under an RMSNorm over the 2 hd features
+    (one learned scale) times ``1 - lambda_init``, ``lambda = exp(lq1 . lk1)
+    - exp(lq2 . lk2) + lambda_init`` from four learned vectors [hd] a layer.
+    The attention functions take one width, so each softmax meets the two
+    value heads in turn: the four calls of the published code."""
     a = ARCHS[arch]
     b, s, d = x.shape
     hd = head_dim or d // n_heads
     n_kv = kv_heads or n_heads
-    window = a.layer_window(layer)
+    window = a.layer_window(layer, n_layers)
     if positions is None:
         positions = jnp.arange(s)
     # Separate q/k/v projections (not one packed Dense(3d)): under
@@ -190,33 +265,60 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     with device_scope("attn_proj"):
         y = make_norm(arch, dtype)(x)
         q = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype)(y)
-        k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
-        v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
+        if shared_kv is None:
+            k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
+            v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
     with device_scope("attn_pos"):
         if a.qk_norm:
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
         to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
-        q, k, v = to_heads(q), to_heads(k), to_heads(v)
+        q = to_heads(q)
+        k, v = (to_heads(k), to_heads(v)) if shared_kv is None else shared_kv
         if a.head_qk_norm:
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
         if a.layer_rope(layer):
             q, k = rope(q, positions, a.rope_theta), rope(k, positions,
                                                           a.rope_theta)
-    with device_scope("attn_core"):
+    out = {"k": k, "v": v}
+
+    def attend(q, k, v):
         if decode:
-            o = cached_attention(mod, q, k, v, decode_cache_len,
-                                 window=window)
-        elif attention_impl == "ring":
-            o = ring_attention(q, k, v, axis_name, causal=True, window=window)
-        elif attention_impl == "flash":
+            return cached_attention(mod, q, k, v, decode_cache_len,
+                                    window=window)
+        if attention_impl == "ring":
+            return ring_attention(q, k, v, axis_name, causal=True,
+                                  window=window)
+        if attention_impl == "flash":
             # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
             # materialization — the single-chip long-context path.
-            o = flash_attention(q, k, v, causal=True, window=window)
+            return flash_attention(q, k, v, causal=True, window=window)
+        return full_attention(q, k, v, causal=True, window=window)
+
+    with device_scope("attn_core"):
+        if a.diff_attn:
+            if decode or attention_impl == "ring":
+                refuse_hybrid(arch, "decode" if decode else "ring attention")
+            halves = [v[:, 0::2], v[:, 1::2]]
+            o, o2 = (jnp.concatenate([attend(q[:, i::2], k[:, i::2], vh)
+                                      for vh in halves], axis=-1)
+                     for i in (0, 1))
         else:
-            o = full_attention(q, k, v, causal=True, window=window)
+            o = attend(q, k, v)
     with device_scope("attn_pos"):
+        if a.diff_attn:
+            lq1, lk1, lq2, lk2 = (
+                mod.param(name, nn.initializers.normal(0.1), (hd,))
+                for name in ("lambda_q1", "lambda_k1", "lambda_q2",
+                             "lambda_k2"))
+            lam_init = diff_lambda_init(layer)
+            lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) \
+                + lam_init
+            out["diff_lambda_max"] = jnp.abs(lam)
+            o = o - lam.astype(dtype) * o2
+            o = nn.RMSNorm(epsilon=a.norm_eps, dtype=dtype, name="subln")(o) \
+                * jnp.asarray(1.0 - lam_init, dtype)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
     if a.attn_gate:
         with device_scope("attn_proj"):
@@ -229,7 +331,7 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
         if a.post_norm:
             o = make_norm(arch, dtype, name="post_attn_norm")(o)
         x = x + o
-    return x, y
+    return x, y, out
 
 
 def remat_block(block_cls):
@@ -244,7 +346,9 @@ def remat_block(block_cls):
 
 def refuse_head_kinds(model, where: str) -> None:
     """``parallel/tp.py`` and ``pp.py`` lay out and rebuild the block for
-    equal head counts of ``d / heads`` and one causal mask."""
+    equal head counts of ``d / heads`` and one causal mask, and for blocks
+    that hand nothing on."""
+    refuse_hybrid(getattr(model, "arch", "gpt2"), where)
     a = ARCHS[getattr(model, "arch", "gpt2")]
     kv = getattr(model, "kv_heads", 0) or model.n_heads
     if kv != model.n_heads or getattr(model, "head_dim", 0) \
@@ -255,6 +359,36 @@ def refuse_head_kinds(model, where: str) -> None:
             f"{model.n_heads}, head_dim={getattr(model, 'head_dim', 0)}, "
             f"lm_arch={getattr(model, 'arch', 'gpt2')}): train those under "
             f"lm_parallelism sp (one device) or ep")
+
+
+# What a hybrid arch (state-space layers, tensors handed from layer to layer)
+# lacks outside ``lm_parallelism sp`` on one device, by where it is refused.
+_NO_SLOT = ("a slot that holds each state-space layer's recurrent state and "
+            "the handed-on K/V beside the per-layer cache")
+_HYBRID_LACKS = {
+    "generate.py": _NO_SLOT,
+    "serve.py": _NO_SLOT,
+    "tensor parallelism": "a layout over the model axis for the state-space "
+                          "projections, the scan's channels and the "
+                          "handed-on tensors",
+    "pipeline parallelism": "the handed-on scan output and K/V carried "
+                            "across stages, and their gradients back",
+    "expert parallelism": "an expert block for the hybrid stack (it is a "
+                          "dense model)",
+    "ring attention": "a scan whose state crosses sequence shards, and "
+                      "differential heads in the ring",
+    "decode": "differential heads and recurrent state in the decode cache",
+}
+
+
+def refuse_hybrid(arch: str, where: str) -> None:
+    """Every entry point that cannot run a hybrid arch refuses it by name
+    here, saying what is missing."""
+    if ARCHS[arch].hybrid:
+        raise ValueError(
+            f"lm_arch={arch} is not built for {where}: missing "
+            f"{_HYBRID_LACKS[where]}; train it with train_lm.py under "
+            f"lm_parallelism sp on one device")
 
 
 class EmbedRows(nn.Module):
@@ -287,7 +421,7 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
                       **init)(tokens)
         if a.embed_scale:
             x = x * jnp.asarray(d_model ** 0.5, dtype)
-        if not a.rope_theta:
+        if not a.rope_theta and not a.no_positions:
             x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
                               name="pos_embed")(positions)[None]
     return x
@@ -332,6 +466,26 @@ def cached_attention(mod: nn.Module, q, k, v, length: int,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+ACTS = {"silu": nn.silu, "relu": nn.relu}
+
+
+class GatedFFN(nn.Module):
+    """``(act(x Wgate) * (x Wup)) Wdown`` without biases: a hybrid arch's
+    feed-forward, a dropless model's dense layer and its shared experts
+    (SwiGLU under ``silu``)."""
+    d_hidden: int
+    dtype: Any = jnp.float32
+    act: str = "silu"
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         name=name)
+        h = ACTS[self.act](dense(self.d_hidden, "gate")(x)) \
+            * dense(self.d_hidden, "up")(x)
+        return dense(x.shape[-1], "down")(h)
+
+
 class Block(nn.Module):
     n_heads: int
     d_model: int
@@ -352,24 +506,51 @@ class Block(nn.Module):
     layer: int = 0                    # index in the stack: the layer's kind, where the arch mixes kinds
     kv_heads: int = 0                 # key/value heads (0 = n_heads)
     head_dim: int = 0                 # 0 = d_model / n_heads
+    n_layers: int = 0                 # the stack's depth: a hybrid arch's kinds follow from it
 
     @nn.compact
-    def __call__(self, x, positions=None):
+    def __call__(self, x, positions=None, handed=None):
         # x: [B, S_local, D]; positions: [S_local] global token positions
-        # (read by RoPE archs only; None = 0..S-1)
+        # (read by RoPE archs only; None = 0..S-1). A hybrid arch's block
+        # takes what earlier layers handed on (``HANDED``: the scan output
+        # for a gated memory unit, K and V for a cross layer) and returns
+        # ``(x, out)``: what this layer hands on and counts.
         d = x.shape[-1]
-        x, _ = attention_sublayer(
-            self, x, positions, arch=self.arch, n_heads=self.n_heads,
-            dtype=self.dtype, attention_impl=self.attention_impl,
-            axis_name=self.axis_name, decode=self.decode,
-            decode_cache_len=self.decode_cache_len, layer=self.layer,
-            kv_heads=self.kv_heads, head_dim=self.head_dim)
+        a = ARCHS[self.arch]
+        kind = a.layer_kind(self.layer, self.n_layers)
+        norm = lambda: make_norm(self.arch, self.dtype)
+        if kind in ("mamba", "mamba_hands_memory"):
+            x, out = mamba_sublayer(
+                self, x, norm(), dtype=self.dtype, d_state=a.ssm_state,
+                d_conv=a.ssm_conv, expand=a.ssm_expand)
+        elif kind == "gmu":
+            x, out = gmu_sublayer(x, handed["memory"], norm(),
+                                  dtype=self.dtype), {}
+        else:
+            x, _, out = attention_sublayer(
+                self, x, positions, arch=self.arch, n_heads=self.n_heads,
+                dtype=self.dtype, attention_impl=self.attention_impl,
+                axis_name=self.axis_name, decode=self.decode,
+                decode_cache_len=self.decode_cache_len, layer=self.layer,
+                kv_heads=self.kv_heads, head_dim=self.head_dim,
+                n_layers=self.n_layers,
+                shared_kv=(handed["k"], handed["v"]) if kind == "cross"
+                else None)
         with device_scope("ffn"):
-            y = make_norm(self.arch, self.dtype)(x)
-            y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
-            y = nn.gelu(y)
-            x = x + nn.Dense(d, dtype=self.dtype)(y)
-        return x
+            y = norm()(x)
+            if a.gated_ffn:
+                x = x + GatedFFN(self.ffn_dim or 4 * d, self.dtype,
+                                 name="mlp")(y)
+            else:
+                y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
+                y = nn.gelu(y)
+                x = x + nn.Dense(d, dtype=self.dtype)(y)
+        if not a.hybrid:
+            return x
+        hands = {"mamba_hands_memory": ("memory",),
+                 "full_hands_kv": ("k", "v")}.get(kind, ())
+        return x, {k: v for k, v in out.items()
+                   if k in hands or k in COUNTER_NAMES}
 
 
 class TransformerLM(nn.Module):
@@ -403,26 +584,54 @@ class TransformerLM(nn.Module):
         # (defaults to 0..S-1 — correct only when unsharded).
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
+        a = ARCHS[self.arch]
         x = embed_tokens(tokens, positions, arch=self.arch,
                          vocab_size=self.vocab_size, d_model=self.d_model,
                          max_seq_len=self.max_seq_len, dtype=self.dtype)
         Blk = remat_block(Block) if (self.remat and not self.decode) \
             else Block
+        # A hybrid stack: two layers hand a tensor to every later layer that
+        # reads it (autodiff sums the readers' gradients back into the one
+        # that made it, through the blocks' remat), and layers count.
+        handed, counted = {}, {}
         for i in range(self.n_layers):
-            x = Blk(self.n_heads, self.d_model, self.dtype,
-                    self.attention_impl, self.axis_name,
-                    decode=self.decode,
-                    decode_cache_len=self.decode_cache_len,
-                    arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
-                    kv_heads=self.kv_heads, head_dim=self.head_dim,
-                    name=f"block_{i}")(x, positions)
+            blk = Blk(self.n_heads, self.d_model, self.dtype,
+                      self.attention_impl, self.axis_name,
+                      decode=self.decode,
+                      decode_cache_len=self.decode_cache_len,
+                      arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
+                      kv_heads=self.kv_heads, head_dim=self.head_dim,
+                      n_layers=self.n_layers, name=f"block_{i}")
+            if not a.hybrid:
+                x = blk(x, positions)
+                continue
+            x, out = blk(x, positions, handed)
+            for k, v in out.items():
+                if k in HANDED:
+                    handed = {**handed, k: v}
+                else:
+                    counted.setdefault(k, []).append(v)
         # Logits in ``dtype``, like every other output of the model: the loss
         # that consumes them casts to float32 (parallel/{sp,tp,pp,ep}.py,
         # runtime/lm_eval.py), so under float32 nothing changes.
         with device_scope("head"):
+            for k, vs in counted.items():  # a no-op unless the caller asks
+                self.sow(LM_COUNTERS, k, jnp.max(jnp.stack(vs)))
             x = make_norm(self.arch, self.dtype, name="ln_f")(x)
+            if a.tied_head:
+                # the embedding's rows are the head's columns: one parameter,
+                # which receives both gradients
+                table = self.variables["params"]["tok_embed"]["embedding"]
+                return jax.lax.dot_general(
+                    x, table.astype(self.dtype), (((2,), (1,)), ((), ())))
             return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
                             name="lm_head")(x)
+
+
+def lm_counters(collections) -> dict:
+    """The counters ``TransformerLM`` sowed, from ``apply(...,
+    mutable=[LM_COUNTERS])``'s second result: {name: scalar}."""
+    return {k: v[0] for k, v in collections.get(LM_COUNTERS, {}).items()}
 
 
 def migrate_packed_qkv(tree):

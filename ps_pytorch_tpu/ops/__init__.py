@@ -13,6 +13,9 @@ CPU mesh while real runs compile to Mosaic.
 - ``flash_attention``: blockwise online-softmax causal attention (fwd +
   dq/dkv bwd) — no [S, S] materialization; the single-chip long-context
   attention path.
+- ``selective_scan``: a Mamba-1 layer's recurrence, chunked over the sequence
+  with the float32 state carried in VMEM, and a hand-written backward that
+  keeps chunk-boundary states only.
 """
 
 from ps_pytorch_tpu.ops.quantize import (  # noqa: F401

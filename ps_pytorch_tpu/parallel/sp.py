@@ -59,18 +59,24 @@ def create_lm_train_state(model, tx, mesh: Mesh, sample_tokens,
 
 
 def _local_nexttoken_loss(model, axis_name: str, params, tokens):
-    """Per-shard next-token loss (sum, count) — shared by the train step and
-    the grad-free eval so their framing can never diverge.
+    """Per-shard next-token loss (sum, (count, counters)) — shared by the
+    train step and the grad-free eval so their framing can never diverge.
+    ``counters``: what the model counted on the way (``lm_counters``: a
+    hybrid arch's ``ssm_state_abs_max`` and ``diff_lambda_max``; {} for the
+    others).
 
     LOCAL sums only — no collective inside (the train step differentiates
     this; differentiating through an in-loss psum double-counts cross-shard
     cotangents); normalization and the cross-shard sum happen outside.
     """
+    # models/transformer.py imports parallel/ring.py, and so this package
+    from ps_pytorch_tpu.models.transformer import LM_COUNTERS, lm_counters
     n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = tokens.shape[1]
     positions = idx * s_local + jnp.arange(s_local)
-    logits = model.apply({"params": params}, tokens, positions=positions)
+    logits, sown = model.apply({"params": params}, tokens,
+                               positions=positions, mutable=[LM_COUNTERS])
     # Next-token targets: local shift; the boundary target (first token of
     # the next shard) arrives via one ppermute hop.
     perm = [(j, (j - 1) % n) for j in range(n)]
@@ -81,7 +87,8 @@ def _local_nexttoken_loss(model, axis_name: str, params, tokens):
         is_global_last = positions == (n * s_local - 1)
         w = jnp.broadcast_to(jnp.where(is_global_last, 0.0, 1.0),
                              tokens.shape)
-        return next_token_loss(logits, targets, w)
+        loss_sum, count = next_token_loss(logits, targets, w)
+        return loss_sum, (count, lm_counters(sown))
 
 
 def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",
@@ -106,7 +113,7 @@ def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",
         def loss_fn(params):
             return _local_nexttoken_loss(model, axis_name, params, tokens)
 
-        (loss_sum, count), grads = jax.value_and_grad(
+        (loss_sum, (count, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         # Params are replicated, so each shard's backprop yields only the
         # contribution of computational paths through that shard (ring
@@ -117,12 +124,14 @@ def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",
             grads = jax.tree.map(
                 lambda g: jax.lax.psum(g, axis_name) / total, grads)
             loss = jax.lax.psum(loss_sum, axis_name) / total
+            counters = {k: jax.lax.pmax(v, axis_name)
+                        for k, v in counters.items()}
         with device_scope("optimizer"):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
             new_state = state.replace(step=state.step + 1, params=new_params,
                                       opt_state=new_opt)
-        return new_state, {"loss": loss}
+        return new_state, {"loss": loss, **counters}
 
     specs = TrainState(step=P(), params=P(), opt_state=P(), batch_stats={})
     sharded = jax.shard_map(
@@ -143,8 +152,8 @@ def make_sp_eval_fn(model, mesh: Mesh, *, axis_name: str = "data") -> Callable:
     long-context design exists to avoid."""
 
     def local_eval(params, tokens):
-        loss_sum, count = _local_nexttoken_loss(model, axis_name, params,
-                                                tokens)
+        loss_sum, (count, _) = _local_nexttoken_loss(model, axis_name,
+                                                     params, tokens)
         return jax.lax.psum(loss_sum, axis_name) / \
             jax.lax.psum(count, axis_name)
 

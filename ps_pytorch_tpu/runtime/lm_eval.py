@@ -45,6 +45,8 @@ def build_lm_model(cfg, **kw):
                dtype=DTYPES[cfg.compute_dtype])
     if cfg.network == "MoETransformerLM" or cfg.lm_parallelism == "ep":
         from ps_pytorch_tpu.models.moe import MoETransformerLM
+        from ps_pytorch_tpu.models.transformer import refuse_hybrid
+        refuse_hybrid(cfg.lm_arch, "expert parallelism")
         return MoETransformerLM(n_experts=cfg.lm_experts,
                                 top_k=cfg.lm_moe_top_k,
                                 experts_held=cfg.lm_experts_held,

@@ -35,9 +35,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ps_pytorch_tpu import resilience
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.text import TokenLoader
-from ps_pytorch_tpu.models.transformer import ARCHS, migrate_packed_qkv
+from ps_pytorch_tpu.models.transformer import (
+    ARCHS, ATTENTION_KINDS, migrate_packed_qkv, refuse_hybrid,
+)
 from ps_pytorch_tpu.ops._backend import announce_kernels
 from ps_pytorch_tpu.ops.flash_attention import flash_schedule
+from ps_pytorch_tpu.ops.selective_scan import scan_schedule
 from ps_pytorch_tpu.optim import build_schedule
 from ps_pytorch_tpu.optim.sgd import sgd
 from ps_pytorch_tpu.parallel import dist
@@ -82,6 +85,7 @@ class LMTrainer:
             # Sequence sharded over 'data', ring attention across shards.
             self.mesh = Mesh(np.array(devices), ("data",))
             if n > 1:
+                refuse_hybrid(cfg.lm_arch, "ring attention")
                 if cfg.lm_attention != "auto":
                     raise ValueError(
                         f"lm_attention={cfg.lm_attention!r} is "
@@ -165,22 +169,30 @@ class LMTrainer:
         else:  # unreachable: TrainConfig.__post_init__ validates
             raise ValueError(self.mode)
         kernels = []
+        arch = ARCHS[cfg.lm_arch]
+        calls = cfg.lm_microbatches if self.mode == "pp" else 1
+        rows = max(cfg.batch_size // (self.mesh.shape["data"] * calls), 1)
         if self.model.attention_impl == "flash":
             # the schedule is static per shape: the line is its record, one
-            # for each kind of layer the arch mixes (window or not)
-            calls = cfg.lm_microbatches if self.mode == "pp" else 1
-            rows = max(cfg.batch_size // (self.mesh.shape["data"] * calls), 1)
-            arch = ARCHS[cfg.lm_arch]
+            # for each kind of attention layer the arch mixes (window or
+            # not); under differential heads a call holds one head of each
+            # pair
+            per_call = 2 if arch.diff_attn else 1
             scheds = {flash_schedule(
-                rows * cfg.lm_heads, cfg.lm_seq_len,
+                rows * cfg.lm_heads // per_call, cfg.lm_seq_len,
                 cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads,
                 jnp.dtype(self.model.dtype).itemsize, True,
-                window=arch.layer_window(i),
-                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads))
-                for i in range(cfg.lm_layers)}
+                window=arch.layer_window(i, cfg.lm_layers),
+                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads) // per_call)
+                for i in range(cfg.lm_layers)
+                if arch.layer_kind(i, cfg.lm_layers) in ATTENTION_KINDS}
             kernels += [f"flash_attention[{sched.describe()}]"
                         for sched in sorted(scheds, key=lambda sc: sc.window)]
-        if ARCHS[cfg.lm_arch].dropless:
+        if arch.hybrid:
+            kernels.append("selective_scan[" + scan_schedule(
+                rows, cfg.lm_seq_len, arch.ssm_expand * cfg.lm_d_model,
+                arch.ssm_state).describe() + "]")
+        if arch.dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not
         # from the flag: the line here, the first JSONL record and the gauge.
@@ -444,7 +456,9 @@ class LMTrainer:
             # The ep step's routing statistics (aux; a dropless arch's
             # z_loss, expert_load_max_over_mean, moe_dropped,
             # moe_held_share; under a selection bias moe_bias_abs_max and
-            # moe_load_all_max_over_mean) come with the loss.
+            # moe_load_all_max_over_mean) and the sp step's counters (a
+            # hybrid arch's ssm_state_abs_max, diff_lambda_max) come with
+            # the loss.
             loss = own.pop("loss")
             derived = derive_step_record(
                 step_time_s=step_time, data_time_s=data_time,

@@ -487,6 +487,14 @@ ROUTING_GAUGES = (
      "assignments over tokens*top_k/experts, worst layer: what the bias acts "
      "on (archs that choose under a bias)"),
 )
+# What a hybrid LM counts inside its step (models/transformer.py
+# COUNTER_NAMES, parallel/sp.py), set by LMTrainer like the routing gauges.
+HYBRID_GAUGES = (
+    ("ssm_state_abs_max", "", "largest |h| over the state-space layers' "
+     "states at the scan's chunk boundaries: the scan's numerical health"),
+    ("diff_lambda_max", "", "largest |lambda| over the differential-"
+     "attention layers"),
+)
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),
     ("train_loss", "", "last step's training loss"),
@@ -505,7 +513,7 @@ TRAINING_GAUGES = (
     ("compute_dtype", "bytes", "item size of the dtype the built LM computes "
      "in (2 = bfloat16, 4 = float32; set by LMTrainer); the JSONL field of "
      "the same name, on a run's first record, holds the dtype's name"),
-) + ROUTING_GAUGES
+) + ROUTING_GAUGES + HYBRID_GAUGES
 TRAINING_HISTOGRAMS = (
     ("train_step_latency_s", "s", "per-step wall-time distribution"),
 )

@@ -73,9 +73,13 @@ ROOT_SPAN = "train_step"      # the root span of one trainer iteration
 DEVICE_SCOPES = (
     "embed",         # token and position rows, the embedding's multiplier
     "attn_proj",     # pre-norm, q / k / v / gate / o projections, post_attn_norm
-    "attn_pos",      # q/k norm, RoPE, to_heads and its inverse, the gate's product
+    "attn_pos",      # q/k norm, RoPE, to_heads and its inverse, the gate's product; differential heads' lambda, difference and norm
     "attn_core",     # flash / full / ring / cached attention
     "ffn",           # a dense feed-forward: norm, its matmuls, activation
+    "ssm_proj",      # a Mamba layer's norm, in / x / dt / out projections, the residual sum
+    "ssm_conv",      # ... its causal convolution, silu, softplus, the gate's product
+    "ssm_scan",      # ... the recurrence, forward and backward, with the copies into and out of its layout
+    "gmu",           # a gated memory unit whole: norm, both projections, the product with the handed-on scan output
     "moe_route",     # the expert layer's norm, router, scores, top-k, gates, counts, sort, statistics
     "moe_dispatch",  # gather of the sorted rows, gate multiply, scatter-add (capacity path: one-hot dispatch and combine); the layer's output norm
     "moe_experts",   # the (grouped) expert matmuls and the activation between them

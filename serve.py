@@ -52,7 +52,9 @@ def main(argv=None) -> int:
     from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    from ps_pytorch_tpu.models.transformer import migrate_packed_qkv
+    from ps_pytorch_tpu.models.transformer import (
+        migrate_packed_qkv, refuse_hybrid,
+    )
     from ps_pytorch_tpu.runtime import checkpoint as ckpt
     from ps_pytorch_tpu.runtime.lm_eval import (
         build_lm_oracle, build_lm_template, lm_geometry,
@@ -72,6 +74,10 @@ def main(argv=None) -> int:
         p.error(f"no valid model_step_<k> checkpoints in {args.train_dir}")
     with open(f"{ckpt.checkpoint_path(args.train_dir, step)}/config.json") as f:
         cfg = TrainConfig.from_json(f.read())
+    try:
+        refuse_hybrid(cfg.lm_arch, "serve.py")
+    except ValueError as e:
+        p.error(str(e))
     if cfg.network != "TransformerLM":
         # The engine's slot decode reuses Block.decode's fixed-length KV
         # cache, which the MoE blocks don't implement.

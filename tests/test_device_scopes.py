@@ -22,6 +22,7 @@ from jax.sharding import Mesh
 from ps_pytorch_tpu.models import build_model
 from ps_pytorch_tpu.models import moe as moe_mod
 from ps_pytorch_tpu.models import resnet as resnet_mod
+from ps_pytorch_tpu.models import ssm as ssm_mod
 from ps_pytorch_tpu.models import transformer as tr_mod
 from ps_pytorch_tpu.models.moe import MOE_STATE, MoETransformerLM
 from ps_pytorch_tpu.models.transformer import TransformerLM
@@ -52,10 +53,11 @@ def _reader():
 READER = _reader()
 S, V = 32, 97
 # Every module that opens a scope binds the function by name.
-SCOPED_MODULES = (tr_mod, moe_mod, resnet_mod, dp, sp, ep)
+SCOPED_MODULES = (tr_mod, moe_mod, ssm_mod, resnet_mod, dp, sp, ep)
 LM = {"embed", "attn_proj", "attn_pos", "attn_core", "head", "loss",
       "grad_reduce", "optimizer"}
 EXPERTS = {"moe_route", "moe_dispatch", "moe_experts"}
+STATE_SPACE = {"ssm_proj", "ssm_conv", "ssm_scan", "gmu"}
 # What no gradient passes through has no backward twin.
 NO_BACKWARD = {"grad_reduce", "optimizer", "router_bias"}
 HEAVY = {"dot", "convolution", "custom-call", "scatter", "gather", "sort"}
@@ -86,6 +88,18 @@ def _sp_gpt2():
     return step, (state, jnp.zeros((2, S), jnp.int32))
 
 
+def _sp_phi4flash():
+    """Depth 8: every kind of layer, under per-block remat."""
+    model = TransformerLM(vocab_size=V, n_layers=8, n_heads=4, kv_heads=2,
+                          head_dim=8, d_model=32, ffn_dim=48, max_seq_len=S,
+                          dtype=jnp.bfloat16, attention_impl="flash",
+                          arch="phi4flash")
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    state = sp.create_lm_train_state(model, _tx(), mesh, (2, S))
+    step = sp.make_sp_train_step(model, _tx(), mesh, remat=True, donate=False)
+    return step, (state, jnp.zeros((2, S), jnp.int32))
+
+
 def _ep(arch, **kw):
     model = MoETransformerLM(
         vocab_size=V, max_seq_len=S, arch=arch, dtype=jnp.bfloat16,
@@ -109,6 +123,7 @@ CASES = {
     "dp_resnet18": (_dp_resnet18, {"conv", "batchnorm", "shortcut", "head",
                                    "loss", "grad_reduce", "optimizer"}, False),
     "sp_gpt2": (_sp_gpt2, LM | {"ffn"}, False),
+    "sp_phi4flash_remat": (_sp_phi4flash, LM | {"ffn"} | STATE_SPACE, True),
     "ep_dropless_held_remat": (
         lambda: _ep("smallthinker", n_layers=2, attention_impl="flash"),
         LM | EXPERTS, True),
@@ -190,8 +205,8 @@ def test_every_scope_the_arch_uses_is_in_the_compiled_step(case, compiled):
             scope
     assert remat == any(part == "recompute" for _, part in found)
     if remat:       # a block's interior, of which each arch has these
-        assert {("attn_proj", "recompute"), ("moe_experts", "recompute")} \
-            <= found
+        inner = "moe_experts" if "moe_experts" in uses else "ssm_scan"
+        assert {("attn_proj", "recompute"), (inner, "recompute")} <= found
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -1,8 +1,9 @@
-"""The OLMoE cell's grouped matmuls and the SmallThinker and Trinity cells'
-flash kernels compile under Mosaic for a described v5e (no chip): what the
+"""The OLMoE cell's grouped matmuls, the SmallThinker, Trinity and Phi-4-flash
+cells' flash kernels and the Phi-4-flash cell's selective scan compile under
+Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
-slice off the tiling, a DMA the compiler refuses; and those two cells' whole
-train steps, for the bytes the compiler plans on the device. One file, one
+slice off the tiling, a DMA the compiler refuses; and the long-context cells'
+whole train steps, for the bytes the compiler plans on the device. One file, one
 fixture: only the worker that runs it loads the TPU compiler
 (on-chip-measurement guide, section 2)."""
 
@@ -186,6 +187,111 @@ def test_the_cells_whole_step_plans_no_more_memory_than_the_parents(
     planned = (m.argument_size_in_bytes + m.output_size_in_bytes
                - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert planned <= STEP_BYTES_AT_THE_PARENT[cell] + HEAP_PACKING_BYTES
+
+
+# phi4flash_s8192_1chip: a call holds one head of each differential pair, 20
+# query heads over 10 key/value heads of 64, under the window of 512 and without
+@pytest.mark.parametrize("window", [None, 512])
+def test_flash_at_head_dim_64_two_heads_a_kv_head_compiles(one_chip, window):
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_attention, flash_schedule,
+    )
+    b, h, h_kv, s, d = 1, 20, 10, 8192, 64
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
+                                       interpret=False).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+    names = ("flash_win_fwd", "flash_win_bwd_dkv") if window else \
+        ("flash_fwd", "flash_bwd_dkv")
+    for name in names:
+        assert f"{name}_" in text
+    sc = flash_schedule(b * h, s, d, 2, True, window=window, bh_kv=b * h_kv)
+    assert sc.group == 2 and sc.window == (window or 0)
+    # the band of 512 keys is one compute tile wide: a sixteenth of the
+    # causal tiles and a few more, never all of them
+    if window:
+        assert sc.live_tiles < sc.tiles // 4
+
+
+def test_selective_scan_compiles_at_the_cells_shape(one_chip):
+    """Both scan kernels at 8192 tokens x 5120 channels x 16 states, bfloat16
+    ``u`` and float32 ``delta``: scalars of B and C in SMEM blocks, five-
+    dimensional token blocks, the backward's chunk + 1 states of VMEM scratch."""
+    from ps_pytorch_tpu.ops.selective_scan import selective_scan
+    bt, s, di, n = 1, 8192, 5120, 16
+
+    def loss(*args):
+        return jnp.sum(selective_scan(*args, interpret=False)[0]
+                       .astype(jnp.float32))
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        arg((bt, s, di), jnp.bfloat16), arg((bt, s, di)), arg((di, n)),
+        arg((bt, s, n)), arg((bt, s, n)), arg((di,))).compile().as_text()
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    assert f"f32[{bt},5,64,{n},8,128]" in text      # the boundary states kept
+
+
+def test_the_phi4flash_cells_whole_step_fits_by_the_rule(one_chip,
+                                                         monkeypatch):
+    """The sp step as ``LMTrainer`` builds it from the cell's own flags,
+    compiled for the described chip from shapes alone: the configuration's
+    rule (``cut.rule``: under 14.5 GiB by ``memory_analysis()``) holds for the
+    batch the mix runs, every flash call and both scan kernels are in it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ps_pytorch_tpu.config import config_from_args
+    from ps_pytorch_tpu.optim.schedules import build_schedule
+    from ps_pytorch_tpu.optim.sgd import sgd
+    from ps_pytorch_tpu.parallel import sp
+    from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
+    flash = importlib.import_module("ps_pytorch_tpu.ops.flash_attention")
+    scan = importlib.import_module("ps_pytorch_tpu.ops.selective_scan")
+    monkeypatch.setattr(flash, "_interpret_default", lambda: False)
+    monkeypatch.setattr(scan, "_interpret_default", lambda: False)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi4_mini_flash.json")) as f:
+        argv = json.load(f)["program_args"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "s8192_reasoning_1chip.json")) as f:
+        argv = argv + json.load(f)["args"]
+    cfg = config_from_args(argv)
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+    model = build_lm_model(cfg, attention_impl="flash", axis_name="data")
+    tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
+             weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
+    shapes = jax.eval_shape(
+        partial(sp.create_lm_train_state, model, tx, mesh,
+                (cfg.batch_size, cfg.lm_seq_len)), jax.random.key(0))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=NamedSharding(mesh, P())),
+        shapes)
+    tokens = jax.ShapeDtypeStruct(
+        (cfg.batch_size, cfg.lm_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P(None, "data")))
+    compiled = sp.make_sp_train_step(
+        model, tx, mesh, remat=cfg.remat,
+        donate=cfg.donate).lower(state, tokens).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "flash_win_fwd",
+                 "flash_win_bwd_dkv", "ssm_scan_fwd", "ssm_scan_bwd"):
+        assert text.count(f"%{name}.") > 0, name
+    m = compiled.memory_analysis()
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 12 * 2 ** 30 < planned < 14.5 * 2 ** 30
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():
