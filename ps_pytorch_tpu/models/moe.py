@@ -46,6 +46,7 @@ with the reason).
 """
 
 import math
+from functools import partial
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -279,6 +280,91 @@ def _when(pred, fn, args, ints):
     return run(pred, tuple(args), tuple(ints))
 
 
+# A layer that holds every expert moves its rows by gathers, forward AND
+# backward. JAX transposes a gather into a scatter-add, which the TPU runs row
+# by row (v5e, PR 38: 72 ns a row of 2048 against a gather's 34); the transpose
+# of a PERMUTATION's gather is the gather by the inverse permutation, which
+# only the caller knows. ``order`` [T*k] is the sorted assignments (an
+# assignment being token * k + choice) and ``inv`` [T, k] = ``argsort(order)``
+# the sorted place of each. (A held share's part covers fewer rows than there
+# are assignments, and a gather of T*k rows of which most are not there loses
+# to the scatter-add of the few that are: ``DroplessMoE.part`` keeps it.)
+
+@jax.custom_vjp
+def _rows_in(tokens, order, inv):
+    """The token row of every sorted assignment: [T*k, D]."""
+    return tokens[order // inv.shape[1]]
+
+
+def _rows_in_fwd(tokens, order, inv):
+    return _rows_in(tokens, order, inv), inv
+
+
+def _rows_in_bwd(inv, ct):
+    # a token's k rows, summed in float32 (the tokens' dtype at the least)
+    rows = ct[inv].astype(jnp.promote_types(ct.dtype, jnp.float32))
+    return jnp.sum(rows, axis=1).astype(ct.dtype), None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(out, gates, order, inv):
+    """The combine: ``y[t] = sum_j gates[t * k + j] * out[inv[t, j]]``,
+    float32 [T, D], from the experts' output ``out`` [T*k, D] in sorted order
+    and the assignments' ``gates`` [T*k] (flat: a float32 [T, 8] pads to 128
+    lanes on the TPU)."""
+    return jnp.sum(out[inv].astype(jnp.float32)
+                   * gates.reshape(inv.shape)[..., None], axis=1)
+
+
+def _rows_out_fwd(out, gates, order, inv):
+    return _rows_out(out, gates, order, inv), (out, gates, order, inv)
+
+
+def _rows_out_bwd(res, ct):
+    out, gates, order, inv = res
+    ct_rows = ct[order // inv.shape[1]]               # [T*k, D] float32
+    d_out = ct_rows * gates[order][:, None]
+    # a gate's gradient where its row sits, then taken back to its assignment
+    d_gate = jnp.sum(ct_rows * out.astype(jnp.float32), axis=-1)
+    return (d_out.astype(out.dtype),
+            d_gate[inv.reshape(-1)].astype(gates.dtype), None, None)
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _top_k(scores, bias, k):
+    """``(gates, idx)`` [T, k]: the ``k`` largest of each row of ``scores``
+    [T, E], or of ``scores + bias`` under a ``bias`` [E], which chooses and
+    does not weigh (``gates`` stay ``scores``'s own, and no gradient reaches
+    it). Backward each gate's gradient goes to its column by a comparison
+    with the columns' numbers: T * k * E compares in one reduce, where the
+    transpose of ``lax.top_k``'s gather scatters."""
+    if bias is None:
+        gates, idx = jax.lax.top_k(scores, k)
+        return gates, idx
+    _, idx = jax.lax.top_k(scores + bias, k)
+    return jnp.take_along_axis(scores, idx, axis=-1), idx
+
+
+def _top_k_fwd(scores, bias, k):
+    gates, idx = _top_k(scores, bias, k)
+    return (gates, idx), (idx, jnp.arange(scores.shape[-1], dtype=idx.dtype))
+
+
+def _top_k_bwd(k, res, cts):
+    idx, columns = res
+    hit = idx[..., None] == columns                           # [T, k, E]
+    return jnp.sum(jnp.where(hit, cts[0][..., None], 0), axis=-2), None
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 class DroplessMoE(nn.Module):
     """Dropless top-k MoE FFN with gated experts, OLMoE's by default.
 
@@ -297,10 +383,16 @@ class DroplessMoE(nn.Module):
 
     The T*k assignments are sorted by expert (stable: inside an expert the
     order is the token order), the rows gathered, the three matmuls run as
-    grouped matmuls over the ragged groups (``ops/grouped_matmul.gmm``),
-    scaled by the gates and scatter-added back. Nothing here is sized T x E x
-    anything but the router's own logits and probabilities, and no assignment
-    is ever dropped.
+    grouped matmuls over the ragged groups (``ops/grouped_matmul.gmm``), and
+    each token gathers its k rows back through the sort's inverse, scaled by
+    its gates and summed in float32. With every expert held each movement of
+    rows is a gather, forward and backward (``_rows_in``, ``_rows_out``: the
+    transpose of a permutation's gather is the gather by its inverse, where
+    JAX's own derivative would scatter-add); the gates' gradient goes back to
+    the scores by a comparison (``_top_k``) and the counts are comparisons
+    too: that layer holds no scatter, which the TPU runs row by row. Nothing
+    here is sized T x E x anything but the router's own logits and
+    probabilities and those comparisons, and no assignment is ever dropped.
 
     **A share of the experts** (``n_held`` < ``n_experts``; expert
     parallelism's layer, here without its exchange): the module holds the
@@ -309,10 +401,13 @@ class DroplessMoE(nn.Module):
     assignment to an expert not held sorts past the last held group, is not
     multiplied and adds nothing, so ``y`` is this block's part of the layer's
     result (the shares' parts add up to the whole). Gather, grouped matmuls
-    and scatter-add run over the first ``HELD_ROWS_SLACK * T*k * n_held /
-    n_experts`` sorted rows; where the block drew more, the rows past them go
-    through the same three steps under a ``cond`` (``_when``): nothing held is
-    dropped, whatever the router does.
+    and combine run over the first ``HELD_ROWS_SLACK * T*k * n_held /
+    n_experts`` sorted rows, the combine a scatter-add of those rows to their
+    tokens (and the gather's gradient JAX's scatter-add: a quarter or an
+    eighth of the assignments are among the rows, and looking all T*k up
+    measured slower; PERF.md, Findings PR 38); where the block drew more, the
+    rows past them go through the same three steps under a ``cond``
+    (``_when``): nothing held is dropped, whatever the router does.
 
     Returns ``(y, stats)`` with ``stats`` keyed by ``DROPLESS_STATS``:
     ``aux`` = E * sum_e f_e P_e over ALL k choices and all E router outputs
@@ -321,7 +416,7 @@ class DroplessMoE(nn.Module):
     ``z_loss`` = mean logsumexp(r)^2, ``expert_load_max_over_mean`` = busiest
     HELD expert's assignments / (T*k/E), ``moe_held_share`` = assignments to
     held experts / (T*k), ``moe_dropped`` = held assignments whose output was
-    not added (counted from the scatter's own indices; 0 by construction).
+    not added (counted from the combine's own indices; 0 by construction).
     """
     n_experts: int
     d_model: int
@@ -361,13 +456,10 @@ class DroplessMoE(nn.Module):
                 probs = jax.nn.sigmoid(router)        # [T, E] float32
             else:
                 probs = jax.nn.softmax(router, axis=-1)
-            if self.select_bias:
-                bias = self.variable(MOE_STATE, "expert_bias", jnp.zeros,
-                                     (e,), jnp.float32).value
-                _, idx = jax.lax.top_k(probs + bias, k)
-                gates = jnp.take_along_axis(probs, idx, axis=-1)
-            else:
-                gates, idx = jax.lax.top_k(probs, k)  # [T, k]
+            bias = self.variable(
+                MOE_STATE, "expert_bias", jnp.zeros, (e,), jnp.float32
+            ).value if self.select_bias else None
+            gates, idx = _top_k(probs, bias, k)       # [T, k]
             if self.gate_norm:
                 total = jnp.sum(gates, axis=-1, keepdims=True)
                 if self.score == "sigmoid":
@@ -388,7 +480,8 @@ class DroplessMoE(nn.Module):
         # to an expert not held takes the key past the last held group.
         with device_scope("moe_route"):
             flat_e = idx.reshape(-1)                  # [T*k]
-            load = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+            load = jnp.sum(flat_e[:, None] == jnp.arange(e, dtype=flat_e.dtype),
+                           axis=0, dtype=jnp.int32)
             first = self.share * held
             group_sizes = load[first:first + held]
             key = flat_e if held == e else jnp.where(
@@ -398,13 +491,17 @@ class DroplessMoE(nn.Module):
             flat_gates = gates.reshape(-1)
             n_held_rows = jnp.sum(group_sizes)
 
-        def part(tokens, flat_gates, w_gate, w_up, w_down, order, sizes):
+        def part(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
+                 inv=None):
             """The rows ``order`` (sorted assignments), the first
             ``sum(sizes)`` of which the groups cover: gathered, through the
-            experts, gated, scatter-added to their tokens."""
+            experts, gated, and summed into their tokens: gathered back
+            through ``inv`` [T, k] where ``order`` holds every assignment and
+            ``inv`` their sorted places, else scatter-added."""
             with device_scope("moe_dispatch"):
-                tok = order // k          # source row of each sorted assignment
-                xs = tokens[tok].astype(self.dtype)   # gather [rows, D]
+                xs = tokens[order // k] if inv is None \
+                    else _rows_in(tokens, order, inv)
+                xs = xs.astype(self.dtype)            # [rows, D]
             # The float32 expert weights go to the kernels as they are: a
             # tile is cast to the rows' dtype in VMEM, and the weight gradient
             # comes back float32 from the float32 accumulator
@@ -413,8 +510,10 @@ class DroplessMoE(nn.Module):
                 h = act(gmm(xs, w_gate, sizes)) * gmm(xs, w_up, sizes)
                 out = gmm(h, w_down, sizes)
             with device_scope("moe_dispatch"):
+                if inv is not None:
+                    return _rows_out(out, flat_gates, order, inv)
                 out = out.astype(jnp.float32) * flat_gates[order][:, None]
-                return jnp.zeros((t, d), jnp.float32).at[tok].add(out)
+                return jnp.zeros((t, d), jnp.float32).at[order // k].add(out)
 
         # Rows the main part is sized for: all of them, or the held block's
         # balanced share with HELD_ROWS_SLACK, in whole row tiles.
@@ -423,8 +522,9 @@ class DroplessMoE(nn.Module):
                      // HELD_ROWS_TILE) * HELD_ROWS_TILE)
         weights = (w_gate, w_up, w_down)
         if rows == t * k:
-            sizes_main = group_sizes
-            y = part(tokens, flat_gates, *weights, order, group_sizes)
+            with device_scope("moe_route"):       # the sort's inverse
+                inv = jnp.argsort(order).reshape(t, k)
+            y = part(tokens, flat_gates, *weights, order, group_sizes, inv)
         else:
             with device_scope("moe_route"):
                 ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
@@ -439,15 +539,18 @@ class DroplessMoE(nn.Module):
             with device_scope("moe_dispatch"):
                 y = y + extra
 
-        # Counters, off the gradient path. Rows the grouped matmul covered
-        # are the first sum(sizes) of a part; each adds one to its token's
-        # count (the overflow part's, run or not run as a whole, by their
-        # number).
+        # Counters, off the gradient path. An assignment's output is added
+        # where the combine's own index for it falls on a row the grouped
+        # matmul covered, the first sum(sizes) of a part: the sorted place the
+        # gather reads, or the token a covered row is scatter-added to (the
+        # overflow part's, run or not run as a whole, by their number).
         with device_scope("moe_route"):
-            covered = jnp.arange(rows) < jnp.sum(sizes_main)
-            added = jnp.zeros((t,), jnp.int32).at[order[:rows] // k].add(
-                covered.astype(jnp.int32))
-            added = jnp.sum(added) + jnp.maximum(n_held_rows - rows, 0)
+            if rows == t * k:
+                added = jnp.sum(inv < n_held_rows, dtype=jnp.int32)
+            else:
+                covered = jnp.arange(rows) < jnp.sum(sizes_main)
+                added = jnp.sum(covered & (main // k < t), dtype=jnp.int32) \
+                    + jnp.maximum(n_held_rows - rows, 0)
             stats = {
                 "aux": e * jnp.sum((load.astype(jnp.float32) / t)
                                    * jnp.mean(probs, axis=0)),

@@ -34,6 +34,7 @@ def _load(path):
 
 
 REF = _load(REPO / "benchmark" / "reference" / "olmoe_1b_7b.py")
+PLAIN = _load(REPO / "tests" / "dropless_plain.py")
 PUBLISHED = json.loads(
     (REPO / "benchmark" / "configs" / "olmoe_1b_7b.json").read_text())
 
@@ -273,6 +274,46 @@ def test_a_rigged_router_reads_load_two_and_drops_nothing():
     assert float(stats["moe_dropped"]) == 0.0
     assert float(stats["expert_load_max_over_mean"]) == 2.0
     assert np.isfinite(np.asarray(y)).all() and float(jnp.abs(y).max()) > 0
+
+
+PLAIN_CASES = {
+    "every_expert_held": dict(top_k=4),
+    "an_empty_group": dict(top_k=4, rig_out=5),
+    "an_empty_first_group": dict(top_k=4, rig_out=0),
+    "k_1": dict(top_k=1),
+    "k_1_relu_renormalised": dict(top_k=1, act="relu", gate_norm=True),
+    "k_8_of_8": dict(top_k=8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLAIN_CASES))
+def test_the_layer_is_the_plain_take_and_scatter_add(name):
+    """Every expert held: output, the gradients in the tokens, the router
+    (through the gates) and the three expert weights are those of the form
+    the layer had before PR 38 (``tests/dropless_plain.py``: take, grouped
+    matmuls, scatter-add, JAX's own derivative), the counts exactly."""
+    layer, variables, x = PLAIN.tiny_case(**PLAIN_CASES[name])
+    stats = PLAIN.assert_the_plain_form(layer, variables, x)
+    assert float(stats["moe_held_share"]) == 1.0
+    rig_out = PLAIN_CASES[name].get("rig_out")
+    if rig_out is not None:
+        idx = jax.lax.top_k(x.reshape(-1, 16).astype(jnp.float32)
+                            @ variables["params"]["router"]["kernel"], 4)[1]
+        assert rig_out not in np.asarray(idx)
+        assert float(stats["expert_load_max_over_mean"]) >= 8 / 7
+
+
+@pytest.mark.parametrize("name", ["every_expert_held", "k_1"])
+def test_the_compiled_layer_holds_no_scatter(name):
+    """Forward and backward of the layer, as lowered and as the CPU's
+    compiler leaves them: no ``scatter`` op. The plain form's counts say the
+    search finds one where it is (its combine, the transposes of its take, of
+    ``top_k`` and of the gates' gather, its two counts)."""
+    layer, variables, x = PLAIN.tiny_case(**PLAIN_CASES[name])
+    step, plain = PLAIN.steps(layer, variables)
+    assert PLAIN.scatters(step, variables["params"], x) == (0, [])
+    lowered, compiled = PLAIN.scatters(plain, variables["params"], x)
+    assert lowered >= 5 and len(compiled) >= 3
 
 
 def test_param_count_published_and_tiny(tiny):

@@ -45,6 +45,7 @@ def _load(path):
 
 
 REF = _load(REPO / "benchmark" / "reference" / "smallthinker_21b_a3b.py")
+PLAIN = _load(REPO / "tests" / "dropless_plain.py")
 PUBLISHED = json.loads((REPO / "benchmark" / "configs"
                         / "smallthinker_21b_a3b.json").read_text())
 
@@ -255,6 +256,62 @@ def test_a_share_that_draws_more_than_its_rows_drops_nothing(
         np.testing.assert_allclose(a, b, atol=1e-5)
     assert float(got_stats["moe_dropped"]) == 0.0
     assert got_stats["moe_held_share"] == stats["moe_held_share"]
+
+
+def _held_case(monkeypatch, slack, **kw):
+    """``tests/dropless_plain.py:tiny_case`` with SmallThinker's row holding
+    experts 4..7 of 8; the main part sized for ``slack`` times the balanced
+    share in tiles of 8 rows (the 512 that ship are more than all 96
+    assignments)."""
+    monkeypatch.setattr(moe_mod, "HELD_ROWS_SLACK", slack)
+    monkeypatch.setattr(moe_mod, "HELD_ROWS_TILE", 8)
+    return PLAIN.tiny_case(**{"top_k": 3, "act": "relu", "gate_norm": True,
+                              "n_held": 4, "share": 1, **kw})
+
+
+# name: (slack, whether the overflow part runs, the layer's other fields)
+HELD_PLAIN_CASES = {
+    "overflow_not_taken": (1.5, False, {}),
+    "overflow_taken": (0.5, True, {}),
+    "overflow_takes_nearly_all": (0.05, True, {}),
+    "an_empty_held_group": (1.5, False, dict(rig_out=5)),
+    "an_empty_held_group_overflow_taken": (0.5, True, dict(rig_out=6)),
+    "k_1": (1.5, False, dict(top_k=1)),
+    "k_1_overflow_taken": (0.5, True, dict(top_k=1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HELD_PLAIN_CASES))
+def test_a_share_is_the_plain_take_and_scatter_add(monkeypatch, name):
+    """A held share, with and without its overflow part run: output, the
+    gradients in the tokens, the router and the held weights are those of the
+    form the layer had before PR 38 in one part over all sorted rows
+    (``tests/dropless_plain.py``), the counts exactly."""
+    slack, taken, kw = HELD_PLAIN_CASES[name]
+    layer, variables, x = _held_case(monkeypatch, slack, **kw)
+    stats = PLAIN.assert_the_plain_form(layer, variables, x)
+    held, rows = PLAIN.held_and_main_rows(layer, stats, slack)
+    assert 0 < held < 32 * layer.top_k and (held > rows) == taken, (held, rows)
+    assert float(stats["moe_dropped"]) == 0.0
+
+
+@pytest.mark.parametrize("slack", [1.5, 0.5])
+def test_a_compiled_share_scatters_its_rows_and_nothing_else(
+        monkeypatch, slack):
+    """Forward and backward of a held share as the CPU's compiler leaves
+    them: the scatters left are the three of a part (the combine, and the
+    transposes of the rows' take and of the gates' take: a part covers fewer
+    rows than there are assignments, where the gathers of PR 38 measured
+    slower; PERF.md, Findings PR 38), once in the main part and once under
+    the overflow ``cond``, all under ``moe_dispatch``. The counts and the
+    gates' way back to the scores (``moe_route``) hold none, where the plain
+    form has three more."""
+    layer, variables, x = _held_case(monkeypatch, slack)
+    step, plain = PLAIN.steps(layer, variables)
+    _, names = PLAIN.scatters(step, variables["params"], x)
+    assert len(names) == 6
+    assert all("moe_dispatch" in n and "moe_route" not in n for n in names)
+    assert len(PLAIN.scatters(plain, variables["params"], x)[1]) == 6
 
 
 def test_the_ep_step_descends_the_reference_loss(tiny):
@@ -682,6 +739,10 @@ def test_the_decoders_refuse_the_arch(tmp_path, script):
 
 # Taken from the parent commit (f608fe3) with /root/scratch/golden.py's
 # recipe, given in the test below; the bytes on this container's CPU backend.
+# ``olmoe_moe`` was taken again in PR 38 from the new code: the dropless layer
+# adds a token's k rows in another order (3e-7 of the parent's logits, 6e-7 of
+# its gradients; ``tests/dropless_plain.py`` holds the parent's form and the
+# tests beside it hold the layer to it). The other three rows are unedited.
 GOLDEN = {
     "gpt2_dense": ("3be40a762d856dc549057dc3ed6e97db992f221f77d36cc1589463d83b3f14e4", 29,
                    "d782c65fce5679c9300791f7642e74c11adf5d22dbe02839a0b65168516352d7",
@@ -693,8 +754,8 @@ GOLDEN = {
                     "85481556b45fdb9360fe921d1fb0fcd7a9f4454fcbec42a6ebc2b9a11e3a2568",
                     "5b95ac6bafd75f9a4a9fb14c569daf1d0a41747e2f22ac251e3aefe94e036aba"),
     "olmoe_moe": ("77b2683f8475c0519f84a406291b689e21152ad8dc5e40bf1c68139d1f09adaf", 27,
-                  "807282995f8aec0d7d852e2598aeab33d877077b6696dc2ec9b2cd180f8473d8",
-                  "b89f2bc64971ac342fbc0d1beed3370c26dc51886b66f1d293247efbb0bcbb19"),
+                  "94a34c2b7ef8697010c307af958d181d1b7aa93ddf6b95758415c69738d8d839",
+                  "581e68b6e8e6b6c72babf8b9e099e3c7dd162a42d1104702393a262c70bbf97e"),
 }
 
 
@@ -712,7 +773,8 @@ def test_the_older_archs_are_the_parents_bit_for_bit(family):
     (+ the routing terms the MoE class returns) are the parent commit's
     bytes: ``init(key(0))`` on tokens ``default_rng(7).integers(0, 97, (2,
     32))``, vocab 97, 2 layers, 4 heads, d=64, S=32; the olmoe rows with
-    ffn_dim 32, the MoE class with 8 experts top-2 (gpt2) or top-4 (olmoe)."""
+    ffn_dim 32, the MoE class with 8 experts top-2 (gpt2) or top-4 (olmoe).
+    (``olmoe_moe``: PR 38's bytes, as the comment over ``GOLDEN`` says.)"""
     arch, cls = family.split("_")
     kw = dict(vocab_size=97, n_layers=2, n_heads=4, d_model=64, max_seq_len=32)
     if arch == "olmoe":
@@ -742,8 +804,13 @@ def test_the_older_archs_are_the_parents_bit_for_bit(family):
 
 
 def test_the_dropless_layer_with_every_expert_is_the_parents():
-    """``DroplessMoE`` with OLMoE's row and every expert held: output,
-    gradients and counters are the parent commit's bytes."""
+    """``DroplessMoE`` with OLMoE's row and every expert held: the counters
+    are the parent commit's (f608fe3) to the bit; output and gradients are
+    PR 38's bytes, taken from the new code, which adds a token's k rows in
+    another order (1e-7 of the parent's; the parent's form is
+    ``tests/dropless_plain.py``, and
+    ``test_olmoe.py::test_the_layer_is_the_plain_take_and_scatter_add`` holds
+    the layer to it)."""
     layer = DroplessMoE(n_experts=8, d_model=16, d_hidden=8, top_k=4)
     x = jax.random.normal(jax.random.key(0), (2, 12, 16))
     p = layer.init(jax.random.key(1), x)["params"]
@@ -751,9 +818,9 @@ def test_the_dropless_layer_with_every_expert_is_the_parents():
     g = jax.grad(lambda p, x: jnp.sum(layer.apply({"params": p}, x)[0] ** 2),
                  argnums=(0, 1))(p, x)
     assert _sha([y]) == \
-        "c8fef3146678fff41777d0da037482c53ec3a4203d3e8c5b50265a102c86bb9f"
+        "b900ca25779415f88d93754fc7a65dbf02688bf2daa0d6a9d9492eb3810a7c0d"
     assert _sha(jax.tree.leaves(g)) == \
-        "b1bcf82e37cbd590dea5f895cd27b169df66acf2e4288d6f9b4d0c70b7b6df55"
+        "040c44166963eaebdf8d89e922860883ea035a268910047ace1c1dc4d9889a26"
     assert {k: float(v) for k, v in stats.items()} == {
         "aux": 4.017381191253662, "z_loss": 6.1970930099487305,
         "expert_load_max_over_mean": 1.1666666269302368, "moe_dropped": 0.0,

@@ -19,6 +19,7 @@ import optax
 import pytest
 
 from ps_pytorch_tpu.config import TrainConfig
+from ps_pytorch_tpu.models import moe as moe_mod
 from ps_pytorch_tpu.models import transformer as tr_mod
 from ps_pytorch_tpu.models.moe import (
     BIAS_STATS, DROPLESS_STATS, EXPERT_COUNTS, MOE_STATE, DroplessMoE,
@@ -42,6 +43,7 @@ def _load(path):
 
 REF = _load(REPO / "benchmark" / "reference" / "trinity_mini.py")
 CONTROLS = _load(REPO / "benchmark" / "controls" / "trinity_mini.py")
+PLAIN = _load(REPO / "tests" / "dropless_plain.py")
 PUBLISHED = json.loads((REPO / "benchmark" / "configs"
                         / "trinity_mini.json").read_text())
 
@@ -287,6 +289,65 @@ def test_the_shares_add_up_to_the_uncut_layer(side):
     if side == "program":       # the program's shared expert is the reference's
         got = GatedFFN(16).apply({"params": bp["shared"]}, m)
         np.testing.assert_allclose(got, shared, atol=1e-6)
+
+
+def _bias_case(monkeypatch, slack, **kw):
+    """``tests/dropless_plain.py:tiny_case`` with Trinity's row (sigmoid
+    scores, a top-3 chosen under a bias that is not all zeros, gates
+    renormalised and scaled) holding experts 4..7 of 8; the main part sized
+    for ``slack`` times the balanced share in tiles of 8 rows."""
+    monkeypatch.setattr(moe_mod, "HELD_ROWS_SLACK", slack)
+    monkeypatch.setattr(moe_mod, "HELD_ROWS_TILE", 8)
+    return PLAIN.tiny_case(**{
+        "top_k": 3, "gate_norm": True, "n_held": 4, "share": 1,
+        "score": "sigmoid", "select_bias": True,
+        "route_scale": TINY["route_scale"], **kw})
+
+
+# name: (slack, whether the overflow part runs, the layer's other fields)
+BIAS_PLAIN_CASES = {
+    "overflow_not_taken": (1.5, False, {}),
+    "overflow_taken": (0.5, True, {}),
+    "the_other_share_overflow_taken": (0.5, True, dict(share=0)),
+    "k_1_overflow_taken": (0.5, True, dict(top_k=1)),
+    "every_expert_held": (1.5, None, dict(n_held=0, share=0)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BIAS_PLAIN_CASES))
+def test_the_layer_is_the_plain_take_and_scatter_add(monkeypatch, name):
+    """Trinity's row of the dropless layer: output, the gradients in the
+    tokens, the router (through the sigmoid gates, their sum and the scale)
+    and the held weights are those of the form the layer had before PR 38
+    (``tests/dropless_plain.py``); ``moe_dropped``, the load figures and the
+    counts it hands the bias's step under ``EXPERT_COUNTS`` exactly."""
+    slack, taken, kw = BIAS_PLAIN_CASES[name]
+    layer, variables, x = _bias_case(monkeypatch, slack, **kw)
+    stats = PLAIN.assert_the_plain_form(layer, variables, x)
+    t_k = 32 * layer.top_k
+    assert int(stats[EXPERT_COUNTS]["expert_bias"].sum()) == t_k
+    assert float(stats["moe_dropped"]) == 0.0
+    if taken is not None:
+        held, rows = PLAIN.held_and_main_rows(layer, stats, slack)
+        assert 0 < held < t_k and (held > rows) == taken, (held, rows)
+
+
+def test_the_compiled_route_holds_no_scatter(monkeypatch):
+    """Forward and backward of a held share chosen under the bias, as the
+    CPU's compiler leaves them: no ``scatter`` op under ``moe_route`` (the
+    gates are gathered from the scores forward and their gradient goes back
+    by a comparison; the counts the bias's step reads are comparisons); the
+    six left are the two parts' rows, as in ``test_smallthinker.py``. With
+    every expert held there is none at all."""
+    layer, variables, x = _bias_case(monkeypatch, 0.5)
+    _, names = PLAIN.scatters(PLAIN.steps(layer, variables)[0],
+                              variables["params"], x)
+    assert len(names) == 6
+    assert all("moe_dispatch" in n and "moe_route" not in n for n in names)
+    layer, variables, x = _bias_case(monkeypatch, 0.5, n_held=0, share=0)
+    step, plain = PLAIN.steps(layer, variables)
+    assert PLAIN.scatters(step, variables["params"], x) == (0, [])
+    assert len(PLAIN.scatters(plain, variables["params"], x)[1]) >= 3
 
 
 BIAS_CASES = {
