@@ -53,9 +53,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ps_pytorch_tpu.models.gdn import gdn_sublayer
 from ps_pytorch_tpu.models.transformer import (
-    ACTS, ARCHS, GatedFFN, attention_sublayer, embed_tokens, make_norm,
-    remat_block,
+    ACTS, ARCHS, COUNTER_NAMES, LM_COUNTERS, GatedFFN, attention_sublayer,
+    embed_tokens, make_norm, refuse_hybrid, remat_block,
 )
 from ps_pytorch_tpu.ops.grouped_matmul import gmm
 from ps_pytorch_tpu.telemetry.trace import device_scope
@@ -572,7 +573,9 @@ class MoEBlock(nn.Module):
     (``MoEMLP`` or ``DroplessMoE``, by the arch; with the arch's shared experts
     beside the routed part), or, where ``dense_ffn_dim`` is set, for a
     ``GatedFFN`` of that width: one of a dropless model's leading dense layers,
-    whose ``aux`` is None."""
+    whose ``aux`` is None. The first half is the mixer of the layer's kind
+    (``Arch.layer_kind``): attention, or a Gated DeltaNet layer, whose counter
+    (``COUNTER_NAMES``) a dropless layer's ``aux`` carries to the model."""
     n_heads: int
     d_model: int
     n_experts: int
@@ -607,11 +610,22 @@ class MoEBlock(nn.Module):
     def __call__(self, x, positions=None):
         b, s, d = x.shape
         a = ARCHS[self.arch]
-        x, normed, _ = attention_sublayer(
-            self, x, positions, arch=self.arch, n_heads=self.n_heads,
-            dtype=self.dtype, attention_impl=self.attention_impl,
-            decode=self.decode, decode_cache_len=self.decode_cache_len,
-            layer=self.layer, kv_heads=self.kv_heads, head_dim=self.head_dim)
+        counted = {}
+        if a.layer_kind(self.layer) == "gdn":
+            if self.decode:
+                refuse_hybrid(self.arch, "decode")
+            x, normed, counted = gdn_sublayer(
+                self, x, make_norm(self.arch, self.dtype), dtype=self.dtype,
+                key_heads=a.gdn_key_heads, value_heads=a.gdn_value_heads,
+                key_dim=a.gdn_key_dim, value_dim=a.gdn_value_dim,
+                conv=a.gdn_conv, norm_eps=a.norm_eps)
+        else:
+            x, normed, _ = attention_sublayer(
+                self, x, positions, arch=self.arch, n_heads=self.n_heads,
+                dtype=self.dtype, attention_impl=self.attention_impl,
+                decode=self.decode, decode_cache_len=self.decode_cache_len,
+                layer=self.layer, kv_heads=self.kv_heads,
+                head_dim=self.head_dim)
         # The block's second half is a dense layer's scope or an expert
         # layer's three: the norms and the residual sum go to the first and
         # the last of them.
@@ -640,8 +654,13 @@ class MoEBlock(nn.Module):
             if a.shared_experts:
                 # every token's, whatever share of the routed experts is held
                 with device_scope("moe_shared"):
-                    m = m + GatedFFN(a.shared_experts * width, self.dtype,
-                                     a.expert_act, name="shared")(y)
+                    shared = GatedFFN(a.shared_experts * width, self.dtype,
+                                      a.expert_act, name="shared")(y)
+                    if a.shared_gate:
+                        shared = shared * nn.sigmoid(nn.Dense(
+                            1, use_bias=False, dtype=self.dtype,
+                            name="shared_gate")(y))
+                    m = m + shared
         else:
             m, aux = MoEMLP(self.n_experts, self.d_model,
                             self.ffn_dim or 4 * self.d_model,
@@ -655,6 +674,14 @@ class MoEBlock(nn.Module):
             if a.post_norm:
                 m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(m)
             x = x + m
+        if counted:
+            # what the mixer counted rides in a dropless layer's statistics
+            if not isinstance(aux, dict):
+                raise NotImplementedError(
+                    f"--lm-arch {self.arch}: block {self.layer}'s mixer counts "
+                    f"{sorted(counted)}, and only an expert layer of a "
+                    f"dropless arch hands counters on")
+            aux.update(counted)
         return x, aux
 
 
@@ -670,7 +697,10 @@ class MoETransformerLM(nn.Module):
     averaged over layers, the busiest layer's ``expert_load_max_over_mean``,
     ``moe_dropped`` summed, ``moe_held_share`` averaged) and, where the arch
     chooses under a bias, ``EXPERT_COUNTS``: each layer's assignments to every
-    router output, a tree of the ``MOE_STATE`` collection's shape."""
+    router output, a tree of the ``MOE_STATE`` collection's shape. What the
+    mixers count (``COUNTER_NAMES``: a linear-attention layer's
+    ``gdn_state_abs_max``) is sown in ``LM_COUNTERS``, the largest over the
+    layers, as ``TransformerLM`` does."""
     vocab_size: int = 256
     n_layers: int = 2
     n_heads: int = 4
@@ -734,6 +764,11 @@ class MoETransformerLM(nn.Module):
                 if ARCHS[self.arch].router_bias_rate:
                     aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
                                                 for name, a in per_layer}
+                # what the mixers counted rode in their layers' statistics
+                for k in COUNTER_NAMES:
+                    vs = [a[k] for _, a in per_layer if k in a]
+                    if vs:      # a no-op unless the caller asks
+                        self.sow(LM_COUNTERS, k, jnp.max(jnp.stack(vs)))
             else:
                 aux_total = jnp.float32(0.0)
                 for _, aux in per_layer:

@@ -13,6 +13,7 @@ position, passed in by the caller (the sp step knows each shard's offset).
 """
 
 import math
+from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
@@ -32,7 +33,9 @@ class Arch(NamedTuple):
     row for both LM classes (``--lm-arch``)."""
     rms_norm: bool = False      # RMSNorm(eps) | LayerNorm (flax eps 1e-6)
     norm_eps: float = 1e-6
+    zero_centred_norm: bool = False     # the RMSNorm's scale is 1 + w, w from 0, the statistics and the product float32 (ZeroCentredRMSNorm)
     rope_theta: float = 0.0     # 0: learned position table | RoPE base
+    rope_share: float = 1.0     # the share of a head's features RoPE rotates, the first ones (partial_rotary_factor); the rest pass unrotated
     qk_norm: bool = False       # norm over all d features of q and k, before the heads split
     head_qk_norm: bool = False  # norm over ONE head's features of q and k, after the split (one scale [head_dim] for every head)
     attn_gate: bool = False     # attention's output times sigmoid(norm(x) Wg), elementwise, before Wo
@@ -42,7 +45,9 @@ class Arch(NamedTuple):
     embed_scale: bool = False   # the embedded token times sqrt(d)
     z_loss_coef: float = 0.0    # router z-loss in the ep step's loss
     aux_coef: float = 0.01      # load-balance loss in the ep step's loss
-    # Layers of several kinds: layer l is of kind l % len(pattern).
+    # Layers of several kinds: layer l is of kind l % len(pattern), for the
+    # window, the position encoding and the token mixer alike.
+    mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn"; (): attention in every layer
     window: int = 0             # keys a window layer's query sees, itself included
     window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
     rope_layers: Tuple[int, ...] = ()     # per layer of the period: 1 = RoPE | 0 = no position encoding; (): every layer
@@ -56,6 +61,13 @@ class Arch(NamedTuple):
     #                                 are the scores), and the ep step moves it by this much a step against the load
     route_scale: float = 1.0        # the gates times this
     shared_experts: int = 0         # experts of the routed ones' width that every token passes, beside the routed part
+    shared_gate: bool = False       # the shared experts' output times sigmoid(y w_s), w_s: d -> 1
+    # A Gated DeltaNet (linear-attention) layer's sizes, the arch's as Mamba's are.
+    gdn_key_heads: int = 0      # key (and query) heads
+    gdn_value_heads: int = 0    # value heads, a multiple: value heads r j .. r j + r - 1 read key head j
+    gdn_key_dim: int = 0        # a key head's features
+    gdn_value_dim: int = 0      # a value head's features
+    gdn_conv: int = 0           # taps of the causal depthwise convolution over q, k, v
     # A decoder-hybrid-decoder stack: the layer's kind follows from its index
     # AND the depth (``layer_kind``), not from a period.
     hybrid: bool = False        # state-space / window layers, then a cross-decoder that reads one layer's scan output and one layer's K/V
@@ -67,11 +79,14 @@ class Arch(NamedTuple):
     tied_head: bool = False     # logits = ln_f(x) . tok_embed^T: no lm_head parameter
     no_positions: bool = False  # no position table although rope_theta is 0: no position encoding anywhere
 
-    def layer_kind(self, layer: int, n_layers: int) -> str:
-        """One of ``LAYER_KINDS``; "attention" for every arch but a hybrid."""
+    def layer_kind(self, layer: int, n_layers: int = 0) -> str:
+        """One of ``LAYER_KINDS``: by the period's ``mixer_layers`` ("attention"
+        where it has none), or for a hybrid by the index and the depth (which
+        only a hybrid's caller has to give)."""
         if not self.hybrid:
-            return "attention"
-        if n_layers % 4:
+            return self.mixer_layers[layer % len(self.mixer_layers)] \
+                if self.mixer_layers else "attention"
+        if not n_layers or n_layers % 4:
             raise ValueError(f"a hybrid stack's depth is a multiple of 4 "
                              f"(two kinds alternate in each half), not "
                              f"{n_layers}")
@@ -97,18 +112,19 @@ class Arch(NamedTuple):
             or bool(self.rope_layers[layer % len(self.rope_layers)]))
 
 
-# ``Arch.layer_kind``'s values. A hybrid stack of depth L (a multiple of 4):
+# ``Arch.layer_kind``'s values. "gdn": a Gated DeltaNet layer (models/gdn.py),
+# by the arch's period. A hybrid stack of depth L (a multiple of 4):
 # Mamba and window-attention layers alternate in the first half; layer L/2 is
 # a Mamba layer whose scan output goes to every gated memory unit, layer
 # L/2 + 1 a full causal attention layer whose K and V go to every cross layer;
 # then gated memory units and cross-attention layers alternate.
-LAYER_KINDS = ("attention", "mamba", "window", "mamba_hands_memory",
+LAYER_KINDS = ("attention", "gdn", "mamba", "window", "mamba_hands_memory",
                "full_hands_kv", "gmu", "cross")
 ATTENTION_KINDS = ("attention", "window", "full_hands_kv", "cross")
 # What a hybrid block may hand on, and what it counts (max over layers):
 HANDED = ("memory", "k", "v")
 LM_COUNTERS = "lm_counters"     # the flax collection the counters are sown in
-COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max")
+COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max", "gdn_state_abs_max")
 
 ARCHS = {
     "gpt2": Arch(),
@@ -189,7 +205,50 @@ ARCHS = {
                       ssm_conv=4, ssm_expand=2, diff_attn=True,
                       gated_ffn=True, tied_head=True, no_positions=True,
                       embed_std=0.02),
+    # Qwen3-Next-80B-A3B-Instruct (Qwen/Qwen3-Next-80B-A3B-Instruct
+    # config.json, model_type qwen3_next): rms_norm_eps 1e-6 on zero-centred
+    # RMSNorms (scale 1 + w); three Gated DeltaNet layers to one softmax
+    # attention layer (full_attention_interval 4: layer i is attention where
+    # (i + 1) % 4 == 0), the linear layers with 16 key and 32 value heads of
+    # 128 and a 4-tap convolution; attention with q/k norm a head, its output
+    # gated, RoPE (theta 1e7) on the first quarter of a head's features
+    # (partial_rotary_factor 0.25); softmax scores, top-k gates renormalised
+    # (norm_topk_prob), one shared expert under a sigmoid gate, load-balance
+    # loss 0.001 (the family's router_aux_loss_coef), no z-loss. embed_std as
+    # olmoe's, for the same reason (the zero-centred norm changes nothing in
+    # it: a freshly initialised stream is still what the router reads).
+    # expert_down_std: 0.02 over sqrt(2 x 48 layers), smallthinker's rule at
+    # this depth, taken over on that row's reading (a near-tie between the
+    # last chosen expert and the next is a flip between a bfloat16 model and
+    # a float32 reference; lecun_normal was not read on this model). With it
+    # the chip tells the program as run (0.07-0.09) from float8 parameters
+    # (0.88): benchmark/configs/qwen3_next_80b_a3b.json reference_check.why.
+    # The linear layers' initialisers are models/gdn.py's.
+    "qwen3next": Arch(rms_norm=True, norm_eps=1e-6, zero_centred_norm=True,
+                      rope_theta=1e7, rope_share=0.25, head_qk_norm=True,
+                      attn_gate=True, dropless=True, embed_std=1.0,
+                      aux_coef=0.001, gate_norm=True, expert_down_std=0.002,
+                      shared_experts=1, shared_gate=True,
+                      mixer_layers=("gdn", "gdn", "gdn", "attention"),
+                      gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128,
+                      gdn_value_dim=128, gdn_conv=4),
 }
+
+
+class ZeroCentredRMSNorm(nn.Module):
+    """``x rsqrt(mean(x^2) + eps) (1 + w)``, ``w`` from 0, in float32 whatever
+    ``dtype`` the result leaves in: weight decay pulls the scale to 1, not to
+    0. The parameter is named ``scale`` as ``nn.RMSNorm``'s is."""
+    epsilon: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                              + self.epsilon)
+        return (x * (1.0 + w)).astype(self.dtype)
 
 
 def make_norm(arch: str, dtype, name: Optional[str] = None) -> nn.Module:
@@ -197,6 +256,8 @@ def make_norm(arch: str, dtype, name: Optional[str] = None) -> nn.Module:
     post-attention, q/k, final). Unnamed, flax numbers it in its caller's
     scope, which is what keeps GPT-2's ``LayerNorm_0..1``."""
     a = ARCHS[arch]
+    if a.zero_centred_norm:
+        return ZeroCentredRMSNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
     if a.rms_norm:
         return nn.RMSNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
     return nn.LayerNorm(epsilon=a.norm_eps, dtype=dtype, name=name)
@@ -213,6 +274,15 @@ def rope(x, positions, theta: float):
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def rope_on_a_share(x, positions, theta: float, share: float):
+    """``rope`` on the first ``share`` of a head's features
+    (``partial_rotary_factor``), paired among themselves; the rest pass as
+    they are."""
+    rotated = int(x.shape[-1] * share)
+    return jnp.concatenate([rope(x[..., :rotated], positions, theta),
+                            x[..., rotated:]], axis=-1)
 
 
 def diff_lambda_init(layer: int) -> float:
@@ -279,8 +349,9 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
         if a.layer_rope(layer):
-            q, k = rope(q, positions, a.rope_theta), rope(k, positions,
-                                                          a.rope_theta)
+            turn = rope if a.rope_share == 1.0 else partial(
+                rope_on_a_share, share=a.rope_share)
+            q, k = (turn(t, positions, a.rope_theta) for t in (q, k))
     out = {"k": k, "v": v}
 
     def attend(q, k, v):
@@ -347,7 +418,7 @@ def remat_block(block_cls):
 def refuse_head_kinds(model, where: str) -> None:
     """``parallel/tp.py`` and ``pp.py`` lay out and rebuild the block for
     equal head counts of ``d / heads`` and one causal mask, and for blocks
-    that hand nothing on."""
+    of one kind of mixer (attention) that hand nothing on."""
     refuse_hybrid(getattr(model, "arch", "gpt2"), where)
     a = ARCHS[getattr(model, "arch", "gpt2")]
     kv = getattr(model, "kv_heads", 0) or model.n_heads
@@ -381,14 +452,39 @@ _HYBRID_LACKS = {
 }
 
 
+# ... and what an arch with linear-attention ("gdn") layers lacks outside
+# ``lm_parallelism ep``.
+_NO_STATE_SLOT = ("a slot that holds each linear-attention layer's matrix "
+                  "state and its convolution's last inputs beside the "
+                  "per-layer cache")
+_GDN_LACKS = {
+    "generate.py": _NO_STATE_SLOT,
+    "serve.py": _NO_STATE_SLOT,
+    "decode": _NO_STATE_SLOT,
+    "tensor parallelism": "a layout over the model axis for the linear-"
+                          "attention layers' key and value heads, their "
+                          "convolution's channels and their gates",
+    "pipeline parallelism": "stages built from blocks of more than one kind "
+                            "of mixer",
+    "ring attention": "a delta rule whose state crosses sequence shards",
+}
+
+
 def refuse_hybrid(arch: str, where: str) -> None:
-    """Every entry point that cannot run a hybrid arch refuses it by name
-    here, saying what is missing."""
-    if ARCHS[arch].hybrid:
+    """Every entry point that cannot run a hybrid arch, or one with
+    linear-attention layers, refuses it by name here, saying what is
+    missing."""
+    a = ARCHS[arch]
+    if a.hybrid:
         raise ValueError(
             f"lm_arch={arch} is not built for {where}: missing "
             f"{_HYBRID_LACKS[where]}; train it with train_lm.py under "
             f"lm_parallelism sp on one device")
+    if "gdn" in a.mixer_layers and where in _GDN_LACKS:
+        raise ValueError(
+            f"lm_arch={arch} is not built for {where}: missing "
+            f"{_GDN_LACKS[where]}; train it with train_lm.py under "
+            f"lm_parallelism ep")
 
 
 class EmbedRows(nn.Module):

@@ -32,10 +32,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import keystr, tree_flatten_with_path
 
 from ps_pytorch_tpu.models.moe import (
-    BIAS_STATS, DROPLESS_STATS, EXPERT_COUNTS, MOE_STATE, lm_variables,
-    update_expert_bias,
+    EXPERT_COUNTS, MOE_STATE, lm_variables, update_expert_bias,
 )
-from ps_pytorch_tpu.models.transformer import ARCHS
+from ps_pytorch_tpu.models.transformer import (
+    ARCHS, LM_COUNTERS, lm_counters,
+)
 from ps_pytorch_tpu.ops.next_token_loss import next_token_loss
 from ps_pytorch_tpu.parallel.dp import TrainState
 from ps_pytorch_tpu.parallel.tp import _opt_state_specs
@@ -117,7 +118,9 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     step's assignments to every router output, summed over ``axis``, and adds
     ``BIAS_STATS`` to the metrics: 'moe_bias_abs_max' (the largest |bias|
     after the move) and 'moe_load_all_max_over_mean' (the busiest of ALL the
-    router's outputs over the mean, worst layer).
+    router's outputs over the mean, worst layer). What the model counted on
+    the way (``lm_counters``: an arch with linear-attention layers'
+    'gdn_state_abs_max') comes with the loss too, as under ``parallel/sp.py``.
 
     tokens [B, S] int32, batch sharded over ``axis``. ``model`` must be
     built with ``ep_axis=axis`` and ``n_groups=1`` (each device dispatches
@@ -148,9 +151,10 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
 
     def local_step(state, tokens):
         def loss_fn(params):
-            logits, aux = model.apply(
-                lm_variables(params, state.batch_stats), tokens)
-            stats = aux if arch.dropless else {"aux": aux}
+            (logits, aux), sown = model.apply(
+                lm_variables(params, state.batch_stats), tokens,
+                mutable=[LM_COUNTERS])
+            stats = dict(aux) if arch.dropless else {"aux": aux}
             # The last position has no target: weight 0 under a filler (the
             # sequence's first token), so the logits keep all S rows.
             seq = tokens.shape[1]
@@ -162,9 +166,10 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 reg = arch.aux_coef * stats["aux"] \
                     + arch.z_loss_coef * stats.get("z_loss", 0.0)
                 # LOCAL sums; collectives on the grads, not in the loss.
-                return ce_sum + reg * count, (count, ce_sum, stats)
+                return ce_sum + reg * count, (count, ce_sum, stats,
+                                              lm_counters(sown))
 
-        (_, (count, ce_sum, stats)), grads = jax.value_and_grad(
+        (_, (count, ce_sum, stats, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         counts = stats.pop(EXPERT_COUNTS, None)
 
@@ -183,7 +188,9 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             loss = jax.lax.psum(ce_sum, axis) / total
             metrics = {"loss": loss,
                        **{k: _STAT_REDUCE[k](v, axis)
-                          for k, v in stats.items()}}
+                          for k, v in stats.items()},
+                       **{k: jax.lax.pmax(v, axis)
+                          for k, v in counters.items()}}
         with device_scope("optimizer"):
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
@@ -209,8 +216,6 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     sharded = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(specs, P(axis, None)),
-        out_specs=(specs, {k: P() for k in ("loss",) + (
-            DROPLESS_STATS if arch.dropless else ("aux",))
-            + (BIAS_STATS if biased else ())}),
+        out_specs=(specs, P()),     # every metric a replicated scalar
         check_vma=False)
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

@@ -14,8 +14,9 @@ held-out next-token-loss oracle):
 - ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
   + ``parallel/ep.py``); also how an MoE model is run on one chip.
   ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe,
-  smallthinker, trinity; ``--lm-experts-held`` trains one chip's share of the
-  experts, ``--lm-dense-layers`` starts the stack with dense layers).
+  smallthinker, trinity, qwen3next; ``--lm-experts-held`` trains one chip's
+  share of the experts, ``--lm-dense-layers`` starts the stack with dense
+  layers).
 
 The reference has no LM surface at all — this is the §5.7 long-context
 capability expressed as a first-class entry point (``train_lm.py``), not
@@ -192,6 +193,11 @@ class LMTrainer:
             kernels.append("selective_scan[" + scan_schedule(
                 rows, cfg.lm_seq_len, arch.ssm_expand * cfg.lm_d_model,
                 arch.ssm_state).describe() + "]")
+        if "gdn" in arch.mixer_layers:
+            from ps_pytorch_tpu.ops.gated_delta_rule import gdr_schedule
+            kernels.append("gated_delta_rule[" + gdr_schedule(
+                rows, cfg.lm_seq_len, arch.gdn_value_heads, arch.gdn_key_dim,
+                arch.gdn_value_dim).describe() + "]")
         if arch.dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not
@@ -456,9 +462,10 @@ class LMTrainer:
             # The ep step's routing statistics (aux; a dropless arch's
             # z_loss, expert_load_max_over_mean, moe_dropped,
             # moe_held_share; under a selection bias moe_bias_abs_max and
-            # moe_load_all_max_over_mean) and the sp step's counters (a
-            # hybrid arch's ssm_state_abs_max, diff_lambda_max) come with
-            # the loss.
+            # moe_load_all_max_over_mean) and what the model counted (a
+            # hybrid arch's ssm_state_abs_max and diff_lambda_max under sp,
+            # a linear-attention arch's gdn_state_abs_max under ep) come
+            # with the loss.
             loss = own.pop("loss")
             derived = derive_step_record(
                 step_time_s=step_time, data_time_s=data_time,

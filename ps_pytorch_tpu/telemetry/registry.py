@@ -494,6 +494,10 @@ HYBRID_GAUGES = (
      "states at the scan's chunk boundaries: the scan's numerical health"),
     ("diff_lambda_max", "", "largest |lambda| over the differential-"
      "attention layers"),
+    ("gdn_state_abs_max", "", "largest |S| over the linear-attention layers' "
+     "matrix states at the delta rule's chunk boundaries: bounded under unit "
+     "keys and beta <= 1, so growth is a wrong kernel or a learning rate too "
+     "high"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

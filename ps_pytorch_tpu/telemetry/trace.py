@@ -80,6 +80,9 @@ DEVICE_SCOPES = (
     "ssm_conv",      # ... its causal convolution, silu, softplus, the gate's product
     "ssm_scan",      # ... the recurrence, forward and backward, with the copies into and out of its layout
     "gmu",           # a gated memory unit whole: norm, both projections, the product with the handed-on scan output
+    "gdn_proj",      # a Gated DeltaNet layer's norm, qkvz / ba / out projections, the residual sum
+    "gdn_mix",       # ... its causal convolution and silu, beta, the gate, the l2 norms and q's scale, the gated output norm
+    "gdn_core",      # ... the delta rule, forward and backward, with the copies into and out of its layout
     "moe_route",     # the expert layer's norm, router, scores, top-k, gates, counts, sort, statistics
     "moe_dispatch",  # gather of the sorted rows, gate multiply, scatter-add (capacity path: one-hot dispatch and combine); the layer's output norm
     "moe_experts",   # the (grouped) expert matmuls and the activation between them

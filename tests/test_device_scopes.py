@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import Mesh
 
 from ps_pytorch_tpu.models import build_model
+from ps_pytorch_tpu.models import gdn as gdn_mod
 from ps_pytorch_tpu.models import moe as moe_mod
 from ps_pytorch_tpu.models import resnet as resnet_mod
 from ps_pytorch_tpu.models import ssm as ssm_mod
@@ -53,11 +54,12 @@ def _reader():
 READER = _reader()
 S, V = 32, 97
 # Every module that opens a scope binds the function by name.
-SCOPED_MODULES = (tr_mod, moe_mod, ssm_mod, resnet_mod, dp, sp, ep)
+SCOPED_MODULES = (tr_mod, moe_mod, ssm_mod, gdn_mod, resnet_mod, dp, sp, ep)
 LM = {"embed", "attn_proj", "attn_pos", "attn_core", "head", "loss",
       "grad_reduce", "optimizer"}
 EXPERTS = {"moe_route", "moe_dispatch", "moe_experts"}
 STATE_SPACE = {"ssm_proj", "ssm_conv", "ssm_scan", "gmu"}
+LINEAR_ATTENTION = {"gdn_proj", "gdn_mix", "gdn_core"}
 # What no gradient passes through has no backward twin.
 NO_BACKWARD = {"grad_reduce", "optimizer", "router_bias"}
 HEAVY = {"dot", "convolution", "custom-call", "scatter", "gather", "sort"}
@@ -131,6 +133,11 @@ CASES = {
         lambda: _ep("trinity", n_layers=2, experts_share=1, dense_layers=1,
                     dense_ffn_dim=40),
         LM | EXPERTS | {"ffn", "moe_shared", "router_bias"}, True),
+    # one period: three linear-attention layers (the row's 16 key and 32 value
+    # heads of 128) and one attention layer
+    "ep_qwen3next_remat": (
+        lambda: _ep("qwen3next", n_layers=4, attention_impl="flash"),
+        LM | EXPERTS | LINEAR_ATTENTION | {"moe_shared"}, True),
 }
 
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = .*?\s([a-z][\w\-]*)\(")
@@ -205,8 +212,9 @@ def test_every_scope_the_arch_uses_is_in_the_compiled_step(case, compiled):
             scope
     assert remat == any(part == "recompute" for _, part in found)
     if remat:       # a block's interior, of which each arch has these
-        inner = "moe_experts" if "moe_experts" in uses else "ssm_scan"
-        assert {("attn_proj", "recompute"), (inner, "recompute")} <= found
+        inner = {"moe_experts", "ssm_scan", "gdn_core"} & uses
+        assert {("attn_proj", "recompute")} \
+            | {(scope, "recompute") for scope in inner} <= found
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

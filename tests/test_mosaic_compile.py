@@ -1,5 +1,6 @@
-"""The OLMoE cell's grouped matmuls, the SmallThinker, Trinity and Phi-4-flash
-cells' flash kernels and the Phi-4-flash cell's selective scan compile under
+"""The OLMoE cell's grouped matmuls, the SmallThinker, Trinity, Phi-4-flash and
+Qwen3-Next cells' flash kernels, the Phi-4-flash cell's selective scan and the
+Qwen3-Next cell's gated delta rule compile under
 Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses; and the long-context cells'
@@ -288,6 +289,124 @@ def test_the_phi4flash_cells_whole_step_fits_by_the_rule(one_chip,
     for name in ("flash_fwd", "flash_bwd_dkv", "flash_win_fwd",
                  "flash_win_bwd_dkv", "ssm_scan_fwd", "ssm_scan_bwd"):
         assert text.count(f"%{name}.") > 0, name
+    m = compiled.memory_analysis()
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert 12 * 2 ** 30 < planned < 14.5 * 2 ** 30
+
+
+# qwen3next_s16384_1chip: the one full layer of the period, 16 query heads on
+# 2 key/value heads of 256 at S=16384
+def test_flash_at_head_dim_256_eight_heads_a_kv_head_compiles(one_chip):
+    """Twice the widest head so far: a K/V head whole would be 16.8 MB of K
+    and V, so the forward's schedule splits the keys in two and the backward's
+    dQ comes back as four float32 partials."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_attention, flash_schedule,
+    )
+    b, h, h_kv, s, d = 1, 16, 2, 16384, 256
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+    assert "flash_fwd_" in text and "flash_bwd_dkv_" in text
+    assert "flash_win_" not in text
+    sc = flash_schedule(b * h, s, d, 2, True, bh_kv=b * h_kv)
+    assert sc.group == 8 and sc.bwd_g == 1
+    assert sc.grid == (2, 32, 2) and sc.dq_partials == 4
+    assert f"f32[{sc.dq_partials},{b * h},{s},{d}]" in text
+
+
+def test_gated_delta_rule_compiles_at_the_cells_shape(one_chip):
+    """The three kernels of ``ops/gated_delta_rule.py`` at 16384 tokens, 16
+    key and 32 value heads of 128, bfloat16 q, k, v under float32 g and beta,
+    forward and backward: the substitution's 128 systems a register row, the
+    decays as SMEM scalars, 64-row matmul operands, the kept states."""
+    from ps_pytorch_tpu.ops.gated_delta_rule import (
+        gated_delta_rule, gdr_schedule,
+    )
+    b, s, hk, hv, d = 1, 16384, 16, 32, 128
+
+    def loss(*args):
+        return jnp.sum(gated_delta_rule(*args, interpret=False)[0]
+                       .astype(jnp.float32))
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        arg((b, s, hk, d), jnp.bfloat16), arg((b, s, hk, d), jnp.bfloat16),
+        arg((b, s, hv, d), jnp.bfloat16), arg((b, s, hv)),
+        arg((b, s, hv))).compile()
+    text = compiled.as_text()
+    for name in ("gdr_tril", "gdr_fwd", "gdr_bwd"):
+        assert f"{name}" in text, name
+    sc = gdr_schedule(b, s, hv, d, d)
+    assert f"f32[{b * hv},{sc.chunks},{d},{d}]" in text   # the entering states
+    assert sc.kept_bytes == 512 * 2 ** 20
+
+
+def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
+                                                         monkeypatch):
+    """The ep step as ``LMTrainer`` builds it from the cell's own flags,
+    compiled for the described chip from shapes alone: the configuration's
+    rule (``cut.rule``: under 14.5 GiB by ``memory_analysis()`` with 64 of
+    the 512 experts held) holds, and the flash calls at head dim 256, the
+    three delta-rule kernels and the grouped matmuls are all in it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ps_pytorch_tpu.config import config_from_args
+    from ps_pytorch_tpu.optim.schedules import build_schedule
+    from ps_pytorch_tpu.optim.sgd import sgd
+    from ps_pytorch_tpu.parallel import ep
+    from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
+    for name in ("flash_attention", "gated_delta_rule"):
+        monkeypatch.setattr(
+            importlib.import_module("ps_pytorch_tpu.ops." + name),
+            "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "qwen3_next_80b_a3b.json")) as f:
+        argv = json.load(f)["program_args"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "s16384_hybrid_1chip.json")) as f:
+        argv = argv + json.load(f)["args"]
+    cfg = config_from_args(argv)
+    assert (cfg.lm_experts, cfg.lm_experts_held) == (512, 64)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    model = build_lm_model(cfg, attention_impl="flash", ep_axis="data")
+    tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
+             weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
+    shapes = jax.eval_shape(
+        partial(ep.create_ep_train_state, model, tx, mesh,
+                (cfg.batch_size, cfg.lm_seq_len)), jax.random.key(0))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(shapes.params)) == 1_028_320_320
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, ep.ep_state_specs(shapes, "data"))
+    tokens = jax.ShapeDtypeStruct(
+        (cfg.batch_size, cfg.lm_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))
+    compiled = ep.make_ep_train_step(
+        model, tx, mesh, shapes, remat=cfg.remat,
+        donate=cfg.donate).lower(state, tokens).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "gdr_tril", "gdr_fwd",
+                 "gdr_bwd", "moe_gmm_fwd"):
+        assert text.count(f"%{name}.") > 0, name
+    assert "flash_win_" not in text
     m = compiled.memory_analysis()
     planned = (m.argument_size_in_bytes + m.output_size_in_bytes
                - m.alias_size_in_bytes + m.temp_size_in_bytes)

@@ -29,6 +29,9 @@ from ps_pytorch_tpu.ops.selective_scan import (
 )
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
+# what this arch's layers count, of COUNTER_NAMES
+HYBRID_COUNTERS = ("ssm_state_abs_max", "diff_lambda_max")
+assert set(HYBRID_COUNTERS) < set(COUNTER_NAMES)
 
 
 def _load(path):
@@ -293,7 +296,7 @@ def test_counters_are_sown_and_the_sp_step_returns_them(tiny):
     model, variables, tokens = tiny
     logits, sown = model.apply(variables, tokens, mutable=[LM_COUNTERS])
     counters = lm_counters(sown)
-    assert set(counters) == set(COUNTER_NAMES)
+    assert set(counters) == set(HYBRID_COUNTERS)
     assert float(counters["ssm_state_abs_max"]) > 0
     # lambda = exp(.) - exp(.) + lambda_init of the cross layer (the deepest)
     assert 0.3 < float(counters["diff_lambda_max"]) < 1.5
@@ -305,7 +308,7 @@ def test_counters_are_sown_and_the_sp_step_returns_them(tiny):
                                   jax.random.key(0))
     step = make_sp_train_step(model, tx, mesh, remat=True, donate=False)
     new, metrics = step(state, tokens)
-    assert set(metrics) == {"loss", *COUNTER_NAMES}
+    assert set(metrics) == {"loss", *HYBRID_COUNTERS}
     assert all(np.isfinite(float(v)) for v in metrics.values())
     # and a model without such layers returns the loss alone
     gpt2 = TransformerLM(vocab_size=VOCAB, n_layers=1, n_heads=2, d_model=16,

@@ -13,6 +13,9 @@ from ps_pytorch_tpu.models import transformer as tr_mod
 from ps_pytorch_tpu.models.transformer import ARCHS, COUNTER_NAMES
 
 S, WINDOW, VOCAB = 24, 5, 53        # tests/test_phi4flash.py's tiny size
+# what this arch's layers count, of COUNTER_NAMES
+HYBRID_COUNTERS = ("ssm_state_abs_max", "diff_lambda_max")
+assert set(HYBRID_COUNTERS) < set(COUNTER_NAMES)
 
 
 @pytest.fixture(autouse=True)
@@ -72,7 +75,7 @@ def test_lm_trainer_trains_logs_the_counters_and_resumes(
     for r in records:
         assert 0 < r["ssm_state_abs_max"] < 100
         assert 0.3 < r["diff_lambda_max"] < 1.5
-    for name in COUNTER_NAMES:
+    for name in HYBRID_COUNTERS:
         assert resumed.registry.get(name) == records[-1][name]
 
     other = LMTrainer(cfg.replace(lm_layers=4))
