@@ -197,7 +197,9 @@ class LMTrainer:
             from ps_pytorch_tpu.ops.gated_delta_rule import gdr_schedule
             kernels.append("gated_delta_rule[" + gdr_schedule(
                 rows, cfg.lm_seq_len, arch.gdn_value_heads, arch.gdn_key_dim,
-                arch.gdn_value_dim).describe() + "]")
+                arch.gdn_value_dim, k_heads=arch.gdn_key_heads,
+                itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
+                + "]")
         if arch.dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not

@@ -10,7 +10,9 @@ fixture: only the worker that runs it loads the TPU compiler
 
 import importlib
 import json
+import math
 import os
+import re
 from functools import partial
 
 import jax
@@ -324,11 +326,63 @@ def test_flash_at_head_dim_256_eight_heads_a_kv_head_compiles(one_chip):
     assert f"f32[{sc.dq_partials},{b * h},{s},{d}]" in text
 
 
+_HLO_ITEMSIZE = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "pred": 1,
+                 "s8": 1, "u8": 1}
+_HLO_SHAPE = re.compile(r"\b(" + "|".join(_HLO_ITEMSIZE) + r")\[([\d,]*)\]")
+
+
+def _entry_ops(text):
+    """[(name, opcode, is a Mosaic call, result shapes [(dtype, dims)],
+    operand + output bytes)] of the ops of a compiled module's entry
+    computation that move data (parameters, constants, bitcasts, tuples and
+    the ``-done`` half of an asynchronous copy left out; a ``-start`` counts
+    its destination)."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    size = lambda shapes: sum(
+        _HLO_ITEMSIZE[t] * math.prod(int(d) for d in dims.split(",") if d)
+        for t, dims in shapes)
+    results, ops = {}, []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)",
+                     line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        shapes = _HLO_SHAPE.findall(result)
+        if opcode.endswith("-start"):
+            shapes = shapes[:1]
+        results[name] = shapes
+        if opcode in ("parameter", "constant", "bitcast", "tuple",
+                      "get-tuple-element") or opcode.endswith("-done"):
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        ops.append((name, opcode, "tpu_custom_call" in rest, shapes,
+                    size(shapes) + sum(size(results.get(o, ()))
+                                       for o in operands)))
+    return ops
+
+
 def test_gated_delta_rule_compiles_at_the_cells_shape(one_chip):
     """The three kernels of ``ops/gated_delta_rule.py`` at 16384 tokens, 16
     key and 32 value heads of 128, bfloat16 q, k, v under float32 g and beta,
-    forward and backward: the substitution's 128 systems a register row, the
-    decays as SMEM scalars, 64-row matmul operands, the kept states."""
+    forward and backward: ``gdr_solve``'s strided reads and 128 x 128
+    transposes that lay 128 chunks' systems along the lanes and back, the
+    substitution's register rows; in the walks a chunk's tensors formed in
+    VMEM (the masked exponentials, the columns cut from rows, the transposed
+    float32 products of the inverse's derivative), the decays as SMEM
+    scalars, 64-row matmul operands, the kept states.
+
+    And what XLA is left with round them: no op outside the Mosaic calls
+    hands on a float32 ``[..., 64, 64]`` a chunk or a float32 ``[32, 16384,
+    128]`` row copy, and one forward and backward move under 6 GB through
+    HBM by the compiled module's own shapes, kernels and all (5.05 found:
+    the four calls 0.2 + 1.1 + 0.2 + 1.4, the backward solving again, and
+    the copies into and out of the kernels' layout 2.2).
+    Before PR 40 the same count read 17.1 GB in 208 XLA ops beside 3.7 in
+    four Mosaic calls, with ``_prepare`` and ``jax.vjp`` of it in XLA
+    (PERF.md section 6; about 25 GB by the whole step's 89.5 GB over three
+    layers and a recomputed forward)."""
     from ps_pytorch_tpu.ops.gated_delta_rule import (
         gated_delta_rule, gdr_schedule,
     )
@@ -346,11 +400,30 @@ def test_gated_delta_rule_compiles_at_the_cells_shape(one_chip):
         arg((b, s, hv, d), jnp.bfloat16), arg((b, s, hv)),
         arg((b, s, hv))).compile()
     text = compiled.as_text()
-    for name in ("gdr_tril", "gdr_fwd", "gdr_bwd"):
+    for name in ("gdr_solve", "gdr_fwd", "gdr_bwd"):
         assert f"{name}" in text, name
-    sc = gdr_schedule(b, s, hv, d, d)
+    assert "gdr_tril" not in text
+    sc = gdr_schedule(b, s, hv, d, d, k_heads=hk, itemsize=2)
     assert f"f32[{b * hv},{sc.chunks},{d},{d}]" in text   # the entering states
     assert sc.kept_bytes == 512 * 2 ** 20
+
+    ops = _entry_ops(text)
+    mosaic = [op for op in ops if op[2]]
+    # the schedule's byte counts are the calls' own operands and results
+    # (but the counter's 2 MB of state maxima, which the schedule leaves
+    # out); the backward solves again here (in the cell's step, under
+    # per-block remat, XLA shares the recomputed forward's solve with it)
+    assert sorted(op[4] for op in mosaic) == [
+        sc.solve_bytes, sc.solve_bytes,
+        sc.fwd_bytes + b * hv * d * d * 4, sc.bwd_bytes]
+    chunk_wide = re.compile(r"(^|,)64,64$")
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        if not is_mosaic:
+            for dtype, dims in shapes:
+                assert not (dtype == "f32" and (
+                    chunk_wide.search(dims)
+                    or dims == f"{b * hv},{s},{d}")), (name, opcode, dims)
+    assert sum(op[4] for op in ops) < 6e9
 
 
 def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
@@ -403,7 +476,7 @@ def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
         model, tx, mesh, shapes, remat=cfg.remat,
         donate=cfg.donate).lower(state, tokens).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_bwd_dkv", "gdr_tril", "gdr_fwd",
+    for name in ("flash_fwd", "flash_bwd_dkv", "gdr_solve", "gdr_fwd",
                  "gdr_bwd", "moe_gmm_fwd"):
         assert text.count(f"%{name}.") > 0, name
     assert "flash_win_" not in text

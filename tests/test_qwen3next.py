@@ -164,11 +164,19 @@ def _agrees(args, tol=2e-5):
             < tol * max(float(jnp.abs(r).max()), 1.0), name
 
 
-@pytest.mark.parametrize("s", [37, 64, 100, 256], ids=lambda s: f"S_{s}")
+@pytest.mark.parametrize("s", [37, 64, 100, 256, 576, 704],
+                         ids=lambda s: f"S_{s}")
 def test_chunked_rule_agrees_with_the_recurrence(s):
     """S under one chunk, one chunk, S that is no whole number of chunks (the
-    tail is padded with tokens the state passes unchanged), four chunks."""
-    _agrees(_rule_inputs(s=s, b=1 if s == 256 else 2))
+    tail is padded with tokens the state passes unchanged), four chunks; then
+    the two where the walk crosses grid steps, so that the states and ``dS``
+    pass through the kernels' scratch: nine chunks (``group`` 3, three grid
+    steps) and eleven (``group`` 1, eleven)."""
+    groups = {576: (3, 3), 704: (1, 11)}
+    if s in groups:
+        assert gdr_schedule(1, s, 4, 16, 16, k_heads=2)[2:4] \
+            == (groups[s][0], (2, groups[s][1]))
+    _agrees(_rule_inputs(s=s, b=1 if s >= 256 else 2))
 
 
 @pytest.mark.parametrize("s", [128, 160, 200], ids=lambda s: f"S_{s}")
@@ -228,6 +236,33 @@ def test_state_is_float32_under_bfloat16_inputs():
                  .max()) > 0        # it holds more bits than bfloat16 has
 
 
+def test_bfloat16_gradients_stay_near_the_float32_recurrence():
+    """bfloat16 q, k, v under float32 g and beta, two chunks: all five
+    gradients of the kernels against autodiff of the float32 recurrence on
+    the same (rounded) inputs. What is rounded on the way: the matmuls'
+    operands (``T``, ``beta v``, ``beta exp(gamma) k``, the state's copy,
+    ``V'``, the gradients that feed the MXU) and the gradients handed out in
+    bfloat16, each 2^-9 of its size, through two chunks of 64: 2^-6 of a
+    gradient's largest entry, the limit the output has in the test above,
+    holds all five with room (read 0.2-0.7% over three seeds; the gates'
+    gradients are float32 and are held to the same)."""
+    q, k, v, g, beta = _rule_inputs(b=1, s=128)
+    bf = lambda t: t.astype(jnp.bfloat16)
+    args = (bf(q), bf(k), bf(v), g, beta)
+    w = jax.random.normal(jax.random.key(9), v.shape)
+    loss = lambda f: lambda *a: jnp.sum(f(*a)[0].astype(jnp.float32) * w)
+    got = jax.grad(loss(gated_delta_rule), argnums=tuple(range(5)))(*args)
+    ref = jax.grad(loss(gated_delta_rule_reference),
+                   argnums=tuple(range(5)))(*args)
+    for name, a, r in zip(("q", "k", "v", "g", "beta"), got, ref):
+        assert a.dtype == (jnp.float32 if name in ("g", "beta")
+                           else jnp.bfloat16), name
+        a, r = a.astype(jnp.float32), r.astype(jnp.float32)
+        assert bool(jnp.isfinite(a).all()), name
+        assert float(jnp.abs(a - r).max()) \
+            < 2 ** -6 * float(jnp.abs(r).max()), name
+
+
 def _kept_states(q, k, v, g, beta):
     """The entering states the forward keeps for the backward."""
     _, vjp = jax.vjp(lambda *a: gated_delta_rule(*a)[0], q, k, v, g, beta)
@@ -252,20 +287,45 @@ def test_value_heads_2j_and_2j_plus_1_read_key_head_j():
 
 
 def test_the_triangular_inverse_is_forward_substitution():
-    """``gdr_tril`` against a dense inverse, on random systems and where
-    every key is the same (X all ones under the diagonal: its inverse is
-    bidiagonal, and a product of powers of X would lose every digit)."""
-    c = 64
+    """What solves a chunk's system (``gdr_solve``: ``X`` formed from a
+    chunk's k rows, gamma and beta, the chunks along the lanes, forward
+    substitution, and back) against a dense inverse of ``I + X`` built here
+    from the same rows, on random systems (three chunks of two key heads,
+    two value heads each: twelve systems beside the padded ones, which must
+    come back as the identity) and where every key is the same (X all ones
+    under the diagonal: its inverse is bidiagonal, and a product of powers
+    of X would lose every digit)."""
+    c, dk, n, hk, r = 64, 16, 3, 2, 2
     strict = jnp.tril(jnp.ones((c, c)), -1)
-    x = jax.random.normal(jax.random.key(0), (3, 5, c, c)) * 0.3 * strict
-    t = gdr._tril_inverse(x, True)
-    want = jnp.linalg.inv(jnp.eye(c) + x)
-    np.testing.assert_allclose(t, want, atol=1e-4, rtol=1e-4)
-    ones = gdr._tril_inverse(strict[None], True)[0]
-    np.testing.assert_array_equal(ones, jnp.eye(c) - jnp.eye(c, k=-1))
+    ks = jax.random.split(jax.random.key(0), 3)
+    k = jax.random.normal(ks[0], (hk, n * c, dk)) * 0.5
+    g = -0.05 * jax.nn.softplus(jax.random.normal(ks[1], (hk * r, n, c)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[2], (hk * r, n, c)))
+    gam = jnp.cumsum(g, axis=-1)
+
+    def systems(t):         # [Hk, n' C, r C] -> [Hk, r, n', C, C]
+        return t.reshape(hk, -1, c, r, c).transpose(0, 3, 1, 2, 4)
+
+    t = systems(gdr._solved(k, gam, beta, True))
+    kc = k.reshape(hk, 1, n, c, dk)
+    kk = jnp.einsum("hrncd,hrnkd->hrnck", kc, kc, precision="highest")
+    gm, bt = gam.reshape(hk, r, n, c), beta.reshape(hk, r, n, c)
+    x = strict * bt[..., None] * kk \
+        * jnp.exp(strict * (gm[..., :, None] - gm[..., None, :]))
+    assert float(jnp.abs(x).max()) > 1.0        # no easy systems
+    np.testing.assert_allclose(t[:, :, :n], jnp.linalg.inv(jnp.eye(c) + x),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(
+        t[:, :, n:], jnp.broadcast_to(jnp.eye(c), t[:, :, n:].shape))
+    same = jnp.zeros((hk, n * c, dk)).at[..., 0].set(1.0)
+    ones = systems(gdr._solved(same, jnp.zeros_like(gam),
+                               jnp.ones_like(beta), True))[:, :, :n]
+    np.testing.assert_array_equal(
+        ones, jnp.broadcast_to(jnp.eye(c) - jnp.eye(c, k=-1), ones.shape))
     # and its derivative is -T^T dT T^T under the mask
+    x, t = x.reshape(-1, c, c), t[:, :, :n].reshape(-1, c, c)
     w = jax.random.normal(jax.random.key(1), x.shape)
-    got = jax.grad(lambda a: jnp.sum(gdr._tril_inverse(a, True) * w))(x)
+    got = jax.vmap(gdr._solve_pullback)(t, w)
     ref = jax.grad(lambda a: jnp.sum(
         jnp.linalg.inv(jnp.eye(c) + a * strict) * w))(x)
     np.testing.assert_allclose(got, ref * strict, atol=2e-4, rtol=1e-3)
@@ -275,8 +335,26 @@ def test_schedule_says_what_a_call_holds():
     sc = gdr_schedule(1, 16384, 32, 128, 128)
     assert (sc.chunk, sc.chunks, sc.group, sc.grid) == (64, 256, 8, (32, 32))
     assert sc.kept_bytes == 32 * 256 * 128 * 128 * 4      # 512 MiB a layer
-    assert sc.tril_grid == 64
     assert "chunk=64 chunks=256 group=8 grid=32x32" in sc.describe()
+    # the cell's calls: 16 key heads, two value heads a grid step, bfloat16
+    # rows. gdr_solve reads k (64 MiB), gamma and beta (2 each) and writes T
+    # (128, float32); a forward call reads q, k a key head (64 MiB each), v
+    # (128), gamma, beta, T and a decay a chunk (32 KiB), and writes o (128)
+    # and the entering states; a backward call reads those, the states and
+    # dO and writes the five gradients. Nothing but the states is kept
+    # beside the inputs.
+    sc = gdr_schedule(1, 16384, 32, 128, 128, k_heads=16, itemsize=2)
+    mib, lam = 2 ** 20, 32 * 2 ** 10
+    assert (sc.grid, sc.heads_a_step, sc.solve_grid) == ((16, 32), 2, (16, 2))
+    assert (sc.kept_bytes, sc.kept_other_bytes) == (512 * mib, 0)
+    assert sc.solve_bytes == (64 + 4 + 128) * mib
+    assert sc.fwd_bytes == (2 * 64 + 2 * 128 + 4 + 128 + 512) * mib + lam
+    assert sc.bwd_bytes == (4 * 64 + 3 * 128 + 8 + 128 + 512) * mib + lam
+    assert sc.describe().endswith(
+        f"heads=2 solve_grid=16x2 kept={512 * mib}+0 solve_bytes={196 * mib} "
+        f"fwd_bytes={1028 * mib + lam} bwd_bytes={1288 * mib + lam}")
+    # a short sequence is solved as one whole block of 128 chunks
+    assert gdr_schedule(2, 100, 4, 16, 16, k_heads=2).solve_grid == (4, 1)
     assert gdr_schedule(2, 100, 4, 16, 16).chunks == 2
     with pytest.raises(ValueError, match="multiple of Hk"):
         gated_delta_rule(*_rule_inputs(hk=3, hv=4, s=8))

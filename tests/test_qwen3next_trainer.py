@@ -57,7 +57,7 @@ def test_lm_trainer_trains_logs_the_counter_and_resumes(tmp_path, capsys):
     kernels = next(line for line in capsys.readouterr().out.splitlines()
                    if line.startswith("KERNELS"))
     assert kernels.count("flash_attention[") == 1     # one kind of attention layer
-    assert "gated_delta_rule[chunk=64 chunks=2 group=2 grid=8x1" in kernels
+    assert "gated_delta_rule[chunk=64 chunks=2 group=2 grid=4x1 heads=2 solve_grid=4x1 " in kernels
     assert "grouped_matmul mode=interpret dtype=float32" in kernels
     first.train()
     resumed = LMTrainer(cfg.replace(max_steps=8, eval_freq=0))
