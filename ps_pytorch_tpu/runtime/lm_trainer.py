@@ -200,6 +200,12 @@ class LMTrainer:
                 arch.gdn_value_dim, k_heads=arch.gdn_key_heads,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
+            from ps_pytorch_tpu.ops.gdn_mix import mix_schedule
+            kernels.append("gdn_mix[" + mix_schedule(
+                rows, cfg.lm_seq_len, arch.gdn_key_heads,
+                arch.gdn_value_heads, arch.gdn_key_dim, arch.gdn_conv,
+                itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
+                + "]")
         if arch.dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not

@@ -1,6 +1,6 @@
 """The OLMoE cell's grouped matmuls, the SmallThinker, Trinity, Phi-4-flash and
 Qwen3-Next cells' flash kernels, the Phi-4-flash cell's selective scan and the
-Qwen3-Next cell's gated delta rule compile under
+Qwen3-Next cell's gated delta rule and mixer chains compile under
 Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses; and the long-context cells'
@@ -426,13 +426,94 @@ def test_gated_delta_rule_compiles_at_the_cells_shape(one_chip):
     assert sum(op[4] for op in ops) < 6e9
 
 
+def _mixer_chains_compiled(one_chip, conv, norm):
+    """One forward and one backward of the mixer's two elementwise chains at
+    the cell's shape (``B`` 1, ``S`` 16384, 16 key and 32 value heads of 128,
+    4 taps, bfloat16), compiled for the described chip with every cotangent
+    an argument; q, k, v, ``o`` and their cotangents cross the module's
+    boundary head-major, as ``ops/gated_delta_rule.py`` takes and hands them
+    (its ``heads_first``)."""
+    b, s, hk, hv, d, taps = 1, 16384, 16, 32, 128, 4
+    to_rule = lambda a: jnp.moveaxis(a, 2, 1)       # [B, S, H, d] -> [B, H, S, d]
+    from_rule = lambda a: jnp.moveaxis(a, 1, 2)
+
+    def both(qkv, w, o, z, scale, dq, dk, dv, dout):
+        out, pull = jax.vjp(conv, qkv, w)
+        gated, pull_norm = jax.vjp(
+            lambda o, z, scale: norm(from_rule(o), z, scale), o, z, scale)
+        return (tuple(map(to_rule, out)) + pull(tuple(map(from_rule,
+                                                          (dq, dk, dv))))
+                + (gated,) + pull_norm(dout))
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = (2 * hk + hv) * d
+    return jax.jit(both).lower(
+        arg((b, s, c)), arg((taps, c), jnp.float32), arg((b, hv, s, d)),
+        arg((b, s, hv * d)), arg((d,), jnp.float32), arg((b, hk, s, d)),
+        arg((b, hk, s, d)), arg((b, hv, s, d)),
+        arg((b, s, hv * d))).compile().as_text()
+
+
+def test_gdn_mix_compiles_at_the_cells_shape(one_chip):
+    """The eight calls of ``ops/gdn_mix.py`` (the convolution chain's three
+    segments each way, the gated norm each way) under Mosaic: blocks of 2048
+    tokens of one head with the 16 rows of the tile before and after, the
+    loop over 512 rows at a row offset known only as a multiple of eight, the
+    sublane shifts of the taps, the lane reductions a head, the weight's and
+    the scale's gradients summed in their output blocks across the grid.
+
+    And what XLA is left with: no op outside the Mosaic calls hands on a
+    float32 array a sequence long (a ``[16384, 8192]`` or ``[16384, 4096]``
+    row copy, a shifted product), and one forward and backward of both
+    chains move under 3.2 GB through HBM: the calls' blocks 2.43 GB by
+    ``mix_schedule`` (the module's operand shapes would count ``qkv`` whole
+    for every call that reads a quarter of it; the floor is 2.42) and XLA's
+    ops by the module's own shapes 0.54, all of it ``d qkv``'s three segments
+    written side by side, which in the cell's step the matmuls that read
+    ``[dq | dk | dv | dz]`` fuse. The chain as ``jax.numpy`` ops on
+    ``causal_conv1d`` and ``nn.RMSNorm`` (``tests/test_gdn_mix.py``; what
+    ``models/gdn.py`` held until PR 41) reads 12.7 GB in 55 ops by this same
+    harness."""
+    from ps_pytorch_tpu.ops import gdn_mix
+    b, s, hk, hv, d, taps = 1, 16384, 16, 32, 128, 4
+    text = _mixer_chains_compiled(
+        one_chip,
+        lambda qkv, w: gdn_mix.conv_silu_l2norm(
+            qkv, w, key_heads=hk, value_heads=hv, key_dim=d, value_dim=d,
+            interpret=False),
+        lambda o, z, scale: gdn_mix.gated_rms_norm(o, z, scale, eps=1e-6,
+                                                   interpret=False))
+    ops = _entry_ops(text)
+    mosaic = [op for op in ops if op[2]]
+    assert sorted(op[0].split(".")[0].split("jvp_")[-1].strip("_")
+                  for op in mosaic) == sorted(
+        [f"gdn_conv_{way}_{seg}" for way in ("fwd", "bwd") for seg in "qkv"]
+        + ["gdn_norm_fwd", "gdn_norm_bwd"])
+    # q, k, v leave the kernels as the delta rule reads them: no copy between
+    assert f"bf16[{b},{hk},{s},{d}]" in text
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        if not is_mosaic:
+            for dtype, dims in shapes:
+                assert not (dtype == "f32" and math.prod(
+                    int(n) for n in dims.split(",") if n) >= s * 128), \
+                    (name, opcode, dims)
+    sc = gdn_mix.mix_schedule(b, s, hk, hv, d, taps, itemsize=2)
+    in_kernels = (sc.conv_fwd_bytes + sc.conv_bwd_bytes + sc.norm_fwd_bytes
+                  + sc.norm_bwd_bytes)
+    assert 2.42e9 < in_kernels < 2.5e9
+    assert in_kernels + sum(op[4] for op in ops if not op[2]) < 3.2e9
+
+
 def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
                                                          monkeypatch):
     """The ep step as ``LMTrainer`` builds it from the cell's own flags,
     compiled for the described chip from shapes alone: the configuration's
     rule (``cut.rule``: under 14.5 GiB by ``memory_analysis()`` with 64 of
-    the 512 experts held) holds, and the flash calls at head dim 256, the
-    three delta-rule kernels and the grouped matmuls are all in it."""
+    the 512 experts held) holds (13.57 GiB since PR 41; 14.03 before), and the
+    flash calls at head dim 256, the three delta-rule kernels, the mixer's
+    eight and the grouped matmuls are all in it."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from ps_pytorch_tpu.config import config_from_args
@@ -440,7 +521,7 @@ def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
     from ps_pytorch_tpu.optim.sgd import sgd
     from ps_pytorch_tpu.parallel import ep
     from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
-    for name in ("flash_attention", "gated_delta_rule"):
+    for name in ("flash_attention", "gated_delta_rule", "gdn_mix"):
         monkeypatch.setattr(
             importlib.import_module("ps_pytorch_tpu.ops." + name),
             "_interpret_default", lambda: False)
@@ -477,7 +558,9 @@ def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
         donate=cfg.donate).lower(state, tokens).compile()
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_bwd_dkv", "gdr_solve", "gdr_fwd",
-                 "gdr_bwd", "moe_gmm_fwd"):
+                 "gdr_bwd", "moe_gmm_fwd", "gdn_conv_fwd_q", "gdn_conv_fwd_k",
+                 "gdn_conv_fwd_v", "gdn_conv_bwd_q", "gdn_conv_bwd_k",
+                 "gdn_conv_bwd_v", "gdn_norm_fwd", "gdn_norm_bwd"):
         assert text.count(f"%{name}.") > 0, name
     assert "flash_win_" not in text
     m = compiled.memory_analysis()

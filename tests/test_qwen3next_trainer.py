@@ -46,8 +46,8 @@ def test_lm_trainer_trains_logs_the_counter_and_resumes(tmp_path, capsys):
     """Three steps and a checkpoint, a second trainer that resumes from it bit
     for bit and goes on; the loss falls; every record and the registry carry
     ``gdn_state_abs_max`` beside the routing statistics; the ``KERNELS`` line
-    prints the delta rule's schedule beside the flash record and the grouped
-    matmul."""
+    prints the delta rule's schedule and the mixer ops' beside the flash
+    record and the grouped matmul."""
     from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
 
     metrics = tmp_path / "metrics.jsonl"
@@ -58,6 +58,7 @@ def test_lm_trainer_trains_logs_the_counter_and_resumes(tmp_path, capsys):
                    if line.startswith("KERNELS"))
     assert kernels.count("flash_attention[") == 1     # one kind of attention layer
     assert "gated_delta_rule[chunk=64 chunks=2 group=2 grid=4x1 heads=2 solve_grid=4x1 " in kernels
+    assert " gdn_mix[lanes=32 rows=96 chunk=96 halo=16 conv_grid=2x4x1 norm_grid=2x2x1 conv_fwd_bytes=" in kernels
     assert "grouped_matmul mode=interpret dtype=float32" in kernels
     first.train()
     resumed = LMTrainer(cfg.replace(max_steps=8, eval_freq=0))
@@ -115,7 +116,9 @@ def test_remat_changes_no_step_and_bfloat16_reaches_the_layers(tmp_path):
         {"params": narrow.state.params})
     block = state["intermediates"]["block_0"]
     assert block["out_proj"]["__call__"][0].dtype == jnp.bfloat16
-    assert block["gdn_norm"]["__call__"][0].dtype == jnp.bfloat16
+    # the output norm is ``ops/gdn_mix.gated_rms_norm`` since PR 41; the
+    # module under its name only hands that op the float32 scale
+    assert block["gdn_norm"]["__call__"][0].dtype == jnp.float32
     assert all(a.dtype == jnp.float32
                for a in jax.tree.leaves(narrow.state.params))
     narrow.train()
