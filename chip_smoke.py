@@ -280,59 +280,8 @@ def _check_quantize(n, interpret):
     return {"max_quanta": float((err / quantum).max())}
 
 
-def _check_fused(opt, network, interpret):
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from ps_pytorch_tpu.data.datasets import sample_shape
-    from ps_pytorch_tpu.models import build_model
-    from ps_pytorch_tpu.ops import FusedAdam, FusedSGD
-    from ps_pytorch_tpu.optim import adam, sgd
-
-    dataset = "synthetic_mnist" if network == "LeNet" else "synthetic_cifar10"
-    model = build_model(network, 10, "float32")
-    params = jax.jit(lambda key: model.init(
-        key, jnp.zeros((1,) + sample_shape(dataset)), train=False)["params"]
-    )(jax.random.key(4))
-
-    @jax.jit
-    def random_like(key, tree):   # one program, not one dispatch per leaf
-        leaves, treedef = jax.tree.flatten(tree)
-        return jax.tree.unflatten(treedef, [
-            jax.random.normal(k, l.shape, l.dtype)
-            for k, l in zip(jax.random.split(key, len(leaves)), leaves)])
-
-    # test_ops.py::test_fused_{sgd,adam}_matches_optax_transform tolerances.
-    if opt == "sgd":
-        kw = dict(lr=0.05, momentum=0.9, weight_decay=5e-4, nesterov=True)
-        tx, fused, atol = sgd(**kw), FusedSGD(interpret=interpret, **kw), 1e-6
-    else:
-        kw = dict(lr=1e-3, weight_decay=1e-2, amsgrad=True)
-        tx, fused, atol = adam(**kw), FusedAdam(interpret=interpret, **kw), 1e-7
-
-    @jax.jit
-    def ref_step(params, state, grads):
-        updates, state = tx.update(grads, state, params)
-        return optax.apply_updates(params, updates), state
-
-    fused_step = jax.jit(fused.apply)
-    s_ref, s_fused = jax.jit(tx.init)(params), jax.jit(fused.init)(params)
-    p_ref = p_fused = params
-    worst = 0.0
-    for step in range(2):   # step 0 initialises the moments, step 1 uses them
-        grads = random_like(jax.random.key(5 + step), params)
-        p_ref, s_ref = ref_step(p_ref, s_ref, grads)
-        p_fused, s_fused = fused_step(p_fused, s_fused, grads)
-        for a, b in zip(jax.tree.leaves(p_fused), jax.tree.leaves(p_ref)):
-            worst = max(worst, _close(a, b, 1e-6, atol, f"fused_{opt}"))
-    return {"max_err": worst,
-            "params": sum(l.size for l in jax.tree.leaves(params))}
-
-
 def kernel_leg(*, interpret=False, attn_shape=(8, 8, 2048, 64),
-               conv_shape=(1024, 32, 32, 64), quant_n=9_231_114,
-               opt_network="ResNet18"):
+               conv_shape=(1024, 32, 32, 64), quant_n=9_231_114):
     """Every check runs; the leg fails afterwards with each failed kernel's
     own message (for a kernel Mosaic refuses, the compiler's)."""
     import jax.numpy as jnp
@@ -344,8 +293,6 @@ def kernel_leg(*, interpret=False, attn_shape=(8, 8, 2048, 64),
         ("conv_taps9", lambda: _check_conv("taps9", conv_shape, interpret)),
         ("conv_im2col", lambda: _check_conv("im2col", conv_shape, interpret)),
         ("quantize_int8", lambda: _check_quantize(quant_n, interpret)),
-        ("fused_sgd", lambda: _check_fused("sgd", opt_network, interpret)),
-        ("fused_adam", lambda: _check_fused("adam", opt_network, interpret)),
     ]
     t_start = time.time()
     info, failed = {"interpret": interpret}, []

@@ -10,8 +10,6 @@ prompts are UTF-8 bytes, output is decoded bytes.
 
     python train_lm.py --lm-corpus-file corpus.txt --train-dir ./lm ...
     python generate.py --train-dir ./lm --prompt "def train(" --n-new 256
-
-Legacy (pre-q/k/v-split) checkpoints migrate on load like everywhere else.
 """
 
 import argparse
@@ -41,9 +39,7 @@ def main(argv=None) -> int:
 
     from ps_pytorch_tpu.config import TrainConfig
     from ps_pytorch_tpu.models.generate import generate
-    from ps_pytorch_tpu.models.transformer import (
-        migrate_packed_qkv, refuse_hybrid,
-    )
+    from ps_pytorch_tpu.models.transformer import refuse_hybrid
     from ps_pytorch_tpu.runtime import checkpoint as ckpt
     from ps_pytorch_tpu.runtime.lm_eval import (
         build_lm_oracle, build_lm_template,
@@ -73,8 +69,7 @@ def main(argv=None) -> int:
     moe = cfg.network == "MoETransformerLM"
     template = build_lm_template(cfg)
     _, to_tree = build_lm_oracle(cfg)
-    state, _, _ = ckpt.load_checkpoint(args.train_dir, step, template,
-                                       migrate=migrate_packed_qkv)
+    state, _, _ = ckpt.load_checkpoint(args.train_dir, step, template)
     params = to_tree(state.params)
 
     prompt_bytes = args.prompt.encode("utf-8")

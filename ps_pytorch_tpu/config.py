@@ -22,7 +22,7 @@ from typing import Any, Optional
 
 
 # The rows of models/transformer.py:ARCHS (kept here as names only, so that
-# building a config imports no model code; tests/test_olmoe.py holds the two
+# building a config imports no model code; tests/test_arch_olmoe.py holds the two
 # lists equal).
 LM_ARCHS = ("gpt2", "olmoe", "smallthinker", "trinity", "phi4flash",
             "qwen3next")
@@ -90,7 +90,6 @@ class TrainConfig:
     # -- numerics / TPU --
     compute_dtype: str = "bfloat16"  # MXU-native compute dtype; params stay float32
     device_normalize: bool = True    # loaders ship raw uint8; the jitted step normalizes in-graph (4x less host->device traffic)
-    fused_optimizer: bool = False    # Pallas single-pass SGD update (ops/fused_sgd.py)
     conv_impl: str = "xla"           # xla | pallas | pallas_im2col (ResNet/VGG stride-1 3x3s via ops/pallas_conv.py; A/B'd on chip before any default change)
     donate: bool = True              # donate buffers to the jitted step
     remat: bool = False              # jax.checkpoint the forward for memory

@@ -728,51 +728,3 @@ def lm_counters(collections) -> dict:
     """The counters ``TransformerLM`` sowed, from ``apply(...,
     mutable=[LM_COUNTERS])``'s second result: {name: scalar}."""
     return {k: v[0] for k, v in collections.get(LM_COUNTERS, {}).items()}
-
-
-def migrate_packed_qkv(tree):
-    """Migrate a pre-q/k/v-split checkpoint tree to the current layout.
-
-    Until the TP work landed, each Block projected q/k/v with ONE packed
-    ``Dense(3d)`` (auto-named ``Dense_0``); splitting it into three
-    ``Dense(d)`` renumbered every Block's Dense params (Dense_0..3 ->
-    Dense_0..5) and made old checkpoints structurally unloadable
-    (advisor r3 finding). This walker rewrites any node that still has the
-    legacy shape: the packed ``[d, 3d]`` kernel is split column-wise into
-    q/k/v ``[d, d]`` kernels (the packed layout WAS their concatenation,
-    so the split is exact, not approximate) and the attention-output/MLP
-    entries shift from Dense_1..3 to Dense_3..5. Optimizer momentum trees
-    mirror the param structure and carry the same packed kernels, so the
-    generic walk migrates them identically — momentum is per-parameter,
-    and column slices of the packed buffer ARE the per-projection buffers.
-
-    -> (migrated_tree, n_nodes_rewritten); n == 0 means nothing legacy was
-    found (the caller should re-raise its original restore error).
-    """
-    n_changed = 0
-
-    def walk(node):
-        nonlocal n_changed
-        if not isinstance(node, dict):
-            return node
-        node = {k: walk(v) for k, v in node.items()}
-        d0 = node.get("Dense_0")
-        dense_keys = {k for k in node if k.startswith("Dense_")}
-        if (isinstance(d0, dict)
-                and dense_keys == {"Dense_0", "Dense_1", "Dense_2", "Dense_3"}
-                and getattr(d0.get("kernel"), "ndim", 0) == 2
-                and d0["kernel"].shape[1] == 3 * d0["kernel"].shape[0]):
-            kern = d0["kernel"]
-            d = kern.shape[0]
-            out = dict(node)
-            out["Dense_0"] = {**d0, "kernel": kern[:, :d]}
-            out["Dense_1"] = {**d0, "kernel": kern[:, d:2 * d]}
-            out["Dense_2"] = {**d0, "kernel": kern[:, 2 * d:]}
-            out["Dense_3"] = node["Dense_1"]
-            out["Dense_4"] = node["Dense_2"]
-            out["Dense_5"] = node["Dense_3"]
-            n_changed += 1
-            return out
-        return node
-
-    return walk(tree), n_changed

@@ -8,8 +8,6 @@ CPU mesh while real runs compile to Mosaic.
   TPU-native leg of the reference's gradient-compression capability
   (``compression.py``): gradients are shrunk on-chip before a DCN hop instead
   of Blosc-packed on the host.
-- ``fused_sgd``: single-pass fused momentum-SGD parameter update (one HBM
-  read+write per buffer instead of XLA's multi-kernel chain).
 - ``flash_attention``: blockwise online-softmax causal attention (fwd +
   dq/dkv bwd) — no [S, S] materialization; the single-chip long-context
   attention path.
@@ -21,6 +19,4 @@ CPU mesh while real runs compile to Mosaic.
 from ps_pytorch_tpu.ops.quantize import (  # noqa: F401
     dequantize_int8, quantize_int8, quantized_nbytes,
 )
-from ps_pytorch_tpu.ops.fused_sgd import FusedSGD, fused_sgd_step  # noqa: F401
-from ps_pytorch_tpu.ops.fused_adam import FusedAdam  # noqa: F401
 from ps_pytorch_tpu.ops.flash_attention import flash_attention  # noqa: F401

@@ -14,8 +14,6 @@ def interpret_default() -> bool:
 def cnn_kernels(cfg) -> List[str]:
     """The Pallas kernels a CNN trainer's config switches on."""
     names = []
-    if cfg.fused_optimizer:
-        names.append(f"fused_{cfg.optimizer}")
     if cfg.conv_impl != "xla":
         names.append(f"conv3x3:{cfg.conv_impl}")
     if cfg.compress_grad and cfg.grad_codec == "int8":

@@ -20,18 +20,9 @@ def build_optimizer(cfg):
     families accept either."""
     lr = build_schedule(cfg)
     if cfg.optimizer == "sgd":
-        if getattr(cfg, "fused_optimizer", False):
-            from ps_pytorch_tpu.ops.fused_sgd import FusedSGD
-            return FusedSGD(lr=lr, momentum=cfg.momentum,
-                            weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
         return sgd(lr=lr, momentum=cfg.momentum,
                    weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
     if cfg.optimizer == "adam":
-        if getattr(cfg, "fused_optimizer", False):
-            from ps_pytorch_tpu.ops.fused_adam import FusedAdam
-            return FusedAdam(lr=lr, b1=cfg.adam_beta1, b2=cfg.adam_beta2,
-                             eps=cfg.adam_eps, weight_decay=cfg.weight_decay,
-                             amsgrad=cfg.amsgrad)
         return adam(lr=lr, b1=cfg.adam_beta1, b2=cfg.adam_beta2,
                     eps=cfg.adam_eps, weight_decay=cfg.weight_decay,
                     amsgrad=cfg.amsgrad)

@@ -2,8 +2,7 @@
 
 The reference tuned a single constant lr by grid-sweeping seven values over
 relaunched MPI jobs (``tune.sh:1-36``); its optimizers had no schedule
-surface at all. Both of this framework's optimizer families (optax
-transforms and the fused Pallas kernels) already accept ``step -> lr``
+surface at all. This framework's optax transforms accept ``step -> lr``
 callables, so schedules are pure functions here — traced into the jitted
 step, no host-side mutation, no retrace per step (the step index is a
 traced scalar).

@@ -125,11 +125,8 @@ def make_loss_fn(model, has_bn: bool, input_norm=None):
 
 
 def apply_optimizer(tx, params, opt_state, grads):
-    """update+apply for optax transforms, or the fused single-pass kernel
-    when the optimizer exposes ``apply`` (ops/fused_sgd.FusedSGD)."""
+    """update+apply for an optax transform, under the ``optimizer`` scope."""
     with device_scope("optimizer"):
-        if hasattr(tx, "apply"):
-            return tx.apply(params, opt_state, grads)
         updates, new_opt = tx.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), new_opt
 

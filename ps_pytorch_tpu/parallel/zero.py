@@ -126,8 +126,7 @@ def make_zero_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         idx = jax.lax.axis_index("data")
         pshard = jax.lax.dynamic_slice(pflat, (idx * chunk,), (chunk,))
 
-        # Works for optax transforms and the fused Pallas kernel alike (the
-        # slice is just a 1-leaf pytree to either).
+        # The slice is a 1-leaf pytree to the optax transform.
         new_pshard, new_opt = apply_optimizer(tx, pshard, opt_local, gshard)
 
         stepped = msum > 0

@@ -124,19 +124,12 @@ def _save_checkpoint(train_dir: str, step: int, state: Any,
     return final
 
 
-def load_checkpoint(train_dir: str, step: int, target: Any,
-                    migrate=None) -> Tuple[Any, dict, str]:
-    """-> (state_like_target, meta, config_json).
-
-    ``migrate``: optional ``raw_state_dict -> (state_dict, n_changed)``
-    applied when the stored tree's STRUCTURE no longer matches ``target``
-    (a pre-format-change checkpoint); the restore is retried on the
-    migrated tree iff it changed anything. Structure mismatches are how
-    flax surfaces layout changes (from_state_dict raises on key
-    differences), so this is the one hook point old checkpoints funnel
-    through."""
+def load_checkpoint(train_dir: str, step: int, target: Any
+                    ) -> Tuple[Any, dict, str]:
+    """-> (state_like_target, meta, config_json). A stored tree whose
+    structure does not match ``target`` raises (flax's from_state_dict)."""
     with _span("checkpoint_load", step=step):
-        return _load_checkpoint(train_dir, step, target, migrate)
+        return _load_checkpoint(train_dir, step, target)
 
 
 def verify_checkpoint(train_dir: str, step: int) -> bool:
@@ -180,8 +173,8 @@ def _check_manifest(path: str) -> None:
                 f"(manifest {digest[:12]}…, file {got[:12]}…)")
 
 
-def _load_checkpoint(train_dir: str, step: int, target: Any,
-                     migrate) -> Tuple[Any, dict, str]:
+def _load_checkpoint(train_dir: str, step: int, target: Any
+                     ) -> Tuple[Any, dict, str]:
     path = checkpoint_path(train_dir, step)
     _check_manifest(path)
     with open(os.path.join(path, "meta.json")) as f:
@@ -193,18 +186,8 @@ def _load_checkpoint(train_dir: str, step: int, target: Any,
         blob = w_decompress(blob).tobytes()
     with open(os.path.join(path, "config.json")) as f:
         config_json = f.read()
-    raw = serialization.msgpack_restore(blob)
-    try:
-        state = serialization.from_state_dict(target, raw)
-    except Exception:
-        if migrate is None:
-            raise
-        migrated, n_changed = migrate(raw)
-        if not n_changed:
-            raise
-        state = serialization.from_state_dict(target, migrated)
-        print(f"[ckpt] migrated legacy checkpoint layout at step {step} "
-              f"({n_changed} tree nodes rewritten)")
+    state = serialization.from_state_dict(
+        target, serialization.msgpack_restore(blob))
     return state, meta, config_json
 
 
@@ -247,7 +230,7 @@ def latest_valid_step(train_dir: str) -> Optional[int]:
     return None
 
 
-def load_latest_valid(train_dir: str, target: Any, migrate=None
+def load_latest_valid(train_dir: str, target: Any
                       ) -> Optional[Tuple[Any, dict, str, int]]:
     """Restore the newest checkpoint that both verifies AND deserializes,
     walking backwards past corrupt ones -> (state, meta, config_json,
@@ -267,7 +250,7 @@ def load_latest_valid(train_dir: str, target: Any, migrate=None
             continue
         try:
             state, meta, config_json = load_checkpoint(
-                train_dir, step, target, migrate=migrate)
+                train_dir, step, target)
             return state, meta, config_json, step
         except CheckpointCorruptError as e:
             print(f"[ckpt] step {step} corrupt on load ({e}); falling back")

@@ -118,10 +118,8 @@ class Evaluator:
         from ps_pytorch_tpu.parallel import dist
         from ps_pytorch_tpu.runtime.lm_eval import perplexity
 
-        from ps_pytorch_tpu.models.transformer import migrate_packed_qkv
         state, _, _ = ckpt.load_checkpoint(self.train_dir, step,
-                                           self.template,
-                                           migrate=migrate_packed_qkv)
+                                           self.template)
         params = self._lm_to_tree(state.params)
         losses = []
         for t in self._lm_val.epoch(0):

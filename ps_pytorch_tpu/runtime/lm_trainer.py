@@ -37,7 +37,7 @@ from ps_pytorch_tpu import resilience
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.data.text import TokenLoader
 from ps_pytorch_tpu.models.transformer import (
-    ARCHS, ATTENTION_KINDS, migrate_packed_qkv, refuse_hybrid,
+    ARCHS, ATTENTION_KINDS, refuse_hybrid,
 )
 from ps_pytorch_tpu.ops._backend import announce_kernels
 from ps_pytorch_tpu.ops.flash_attention import flash_schedule
@@ -67,9 +67,6 @@ class LMTrainer:
         self.cfg = cfg
         devices = jax.devices()
         n = len(devices)
-        # The SP step consumes an optax transform (tx.update); the fused
-        # Pallas optimizers (apply-style) are a CNN-step dispatch — use the
-        # plain golden-tested transform here regardless of the flag.
         self.tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
                       weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
         self.mode = cfg.lm_parallelism
@@ -408,12 +405,7 @@ class LMTrainer:
         try:
             # Valid-latest restore: manifest-failing (corrupt) checkpoints
             # are skipped back to the previous committed step.
-            # migrate: checkpoints written before the q/k/v projection
-            # split (packed [d,3d] Dense_0, Block Dense_0..3) are rewritten
-            # to the current layout in-memory — exact column split, see
-            # models/transformer.py:migrate_packed_qkv.
-            got = ckpt.load_latest_valid(
-                self.cfg.train_dir, template, migrate=migrate_packed_qkv)
+            got = ckpt.load_latest_valid(self.cfg.train_dir, template)
         except Exception as e:
             # Most likely a non-LM (CNN) checkpoint sharing the default
             # ./train_dir — surface that instead of a msgpack key error.

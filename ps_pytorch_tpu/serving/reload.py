@@ -36,11 +36,10 @@ class CheckpointWatcher:
     poll doesn't re-load it."""
 
     def __init__(self, train_dir: str, template: Any, *, to_tree=None,
-                 migrate=None, start_step: int = -1):
+                 start_step: int = -1):
         self.train_dir = train_dir
         self.template = template
         self.to_tree = to_tree or (lambda p: p)
-        self.migrate = migrate
         self.loaded_step = int(start_step)
         self.reloads = 0
         self.skipped_corrupt = 0
@@ -61,8 +60,7 @@ class CheckpointWatcher:
         newest = ckpt.latest_step(self.train_dir)
         if newest is None or newest <= self.loaded_step:
             return None
-        got = ckpt.load_latest_valid(self.train_dir, self.template,
-                                     migrate=self.migrate)
+        got = ckpt.load_latest_valid(self.train_dir, self.template)
         if got is None:
             # Everything newer (indeed everything) is corrupt: keep serving
             # what we have. Count the newest step once, not every poll —

@@ -52,9 +52,7 @@ def main(argv=None) -> int:
     from ps_pytorch_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
 
-    from ps_pytorch_tpu.models.transformer import (
-        migrate_packed_qkv, refuse_hybrid,
-    )
+    from ps_pytorch_tpu.models.transformer import refuse_hybrid
     from ps_pytorch_tpu.runtime import checkpoint as ckpt
     from ps_pytorch_tpu.runtime.lm_eval import (
         build_lm_oracle, build_lm_template, lm_geometry,
@@ -94,8 +92,7 @@ def main(argv=None) -> int:
                 f"grouped-query heads is not built)")
     template = build_lm_template(cfg)
     _, to_tree = build_lm_oracle(cfg)
-    got = ckpt.load_latest_valid(args.train_dir, template,
-                                 migrate=migrate_packed_qkv)
+    got = ckpt.load_latest_valid(args.train_dir, template)
     if got is None:
         p.error(f"no restorable checkpoint in {args.train_dir}")
     state, meta, _, step = got
@@ -120,7 +117,7 @@ def main(argv=None) -> int:
     # --serve-reload-s, but POST /admin/reload (the rolling-reload driver)
     # force-polls regardless.
     watcher = CheckpointWatcher(args.train_dir, template, to_tree=to_tree,
-                                migrate=migrate_packed_qkv, start_step=step)
+                                start_step=step)
     # Watchdog over the serve loop: the stall detector notices a wedged
     # drive thread (health.beat() runs once per loop iteration) and the
     # state shows up under /healthz's "health" key.

@@ -21,16 +21,8 @@ import pytest  # noqa: E402
 
 def pytest_configure(config):
     config.addinivalue_line(
-        "markers", "slow: long-running integration test (multi-process launch)")
-
-
-def pytest_collection_modifyitems(items):
-    # Chaos/resilience drills build whole trainers and run multi-step
-    # fault-injected loops — by far the most expensive module. Run them
-    # after the core invariants so a time-bounded run reports the
-    # fundamentals first. (Stable sort: relative order inside each group
-    # is unchanged.)
-    items.sort(key=lambda it: it.fspath.basename == "test_resilience.py")
+        "markers", "slow: a multi-process launch, or a whole train step compiled "
+        "at a cell's full size (the chip run measures that it fits)")
 
 
 @pytest.fixture(scope="session")

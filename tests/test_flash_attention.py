@@ -5,6 +5,12 @@ is tested against (test_ring_attention.py), so all three attention paths
 (full / ring / flash) are pinned to one definition of correctness.
 Runs in Pallas interpreter mode on the CPU mesh; the TPU path compiles the
 identical kernels under Mosaic.
+
+Last in the file: the kernels
+under fewer key/value heads than query heads and under a window, output and
+all three gradients against the same oracle; the schedule's band against a
+brute-force count; and both passes of the long-context cells' schedules,
+pinned.
 """
 
 from functools import partial
@@ -398,3 +404,186 @@ def test_model_flash_impl_matches_full():
     flat_f, _ = jax.flatten_util.ravel_pytree(g_full)
     flat_x, _ = jax.flatten_util.ravel_pytree(g_flash)
     np.testing.assert_allclose(flat_x, flat_f, rtol=1e-3, atol=1e-4)
+
+
+# ---- fewer key/value heads than query heads, and a window -----------------------
+
+# (b, heads, kv_heads, s, d, window, block kwargs): fewer key/value heads and
+# a window that is, and is not, a multiple of the tile; S below the window; a
+# grid that keeps a kv axis so that whole steps lie outside the band.
+FLASH_CASES = {
+    "window_of_3_tiles": (1, 4, 2, 256, 64, 96,
+                          dict(block_q=32, block_kv=32, block_kv_major=64)),
+    "window_off_the_tile": (2, 6, 2, 128, 64, 40,
+                            dict(block_q=32, block_kv=16)),
+    "window_under_a_tile": (1, 8, 2, 128, 64, 17,
+                            dict(block_q=64, block_kv=32, block_kv_major=64)),
+    "s_below_the_window": (1, 4, 2, 64, 64, 100, {}),
+    "grouped_query_alone": (1, 6, 3, 128, 64, None,
+                            dict(block_q=32, block_kv=32)),
+    "one_kv_head_default_schedule": (1, 4, 1, 2048, 64, 300, {}),
+    "window_alone": (1, 2, 2, 256, 64, 5, dict(block_q=64, block_kv=128)),
+}
+
+
+def _flash_case(name):
+    b, h, h_kv, s, d, window, kw = FLASH_CASES[name]
+    ks = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(ks[0], (b, h, s, d))
+    k = jax.random.normal(ks[1], (b, h_kv, s, d))
+    v = jax.random.normal(ks[2], (b, h_kv, s, d))
+    return q, k, v, jax.random.normal(ks[3], q.shape), window, kw
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_CASES))
+def test_flash_with_fewer_kv_heads_and_a_window_against_full(name):
+    """Output and all three gradients; dK and dV come back at the key/value
+    heads' shape, summed over each head's group of query heads."""
+    q, k, v, w, window, kw = _flash_case(name)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            window=window, **kw)
+    full = lambda q, k, v: full_attention(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(flash(q, k, v), full(q, k, v), rtol=2e-5,
+                               atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(full(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b, leaf in zip(got, want, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4,
+                                   err_msg=f"{name} d{leaf}")
+
+
+def test_flash_bfloat16_grouped_window_close():
+    q, k, v, w, window, kw = _flash_case("window_of_3_tiles")
+    qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    f32 = tuple(t.astype(jnp.float32) for t in (qb, kb, vb))
+    loss = lambda fn: lambda q, k, v: jnp.sum(
+        fn(q, k, v).astype(jnp.float32) * w)
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, **kw)), argnums=(0, 1, 2))(
+        qb, kb, vb)
+    want = jax.grad(loss(lambda q, k, v: full_attention(
+        q, k, v, causal=True, window=window)), argnums=(0, 1, 2))(*f32)
+    for a, b, leaf in zip(got, want, "qkv"):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b, rtol=3e-2,
+            atol=3e-2 * float(jnp.abs(b).max()), err_msg=f"d{leaf}")
+
+
+SCHEDULE_BANDS = {
+    # name -> (bh, bh_kv, s, d, itemsize, window, block kwargs)
+    "the_cell_window_layer": (28, 4, 16384, 128, 2, 4096, {}),
+    "the_cell_global_layer": (28, 4, 16384, 128, 2, None, {}),
+    "trinity_window_layer": (64, 8, 8192, 128, 2, 2048, {}),
+    "trinity_global_layer": (64, 8, 8192, 128, 2, None, {}),
+    "window_off_the_tile": (8, 2, 1024, 64, 4, 300,
+                            dict(block_q=128, block_kv=64)),
+    "kv_axis": (4, 2, 256, 64, 4, 96,
+                dict(block_q=32, block_kv=32, block_kv_major=64)),
+    "window_that_never_closes": (16, 16, 4096, 128, 2, 4096, {}),
+    "one_head_too_long_to_hold": (8, 2, 32768, 128, 4, 5000, {}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SCHEDULE_BANDS))
+def test_schedule_live_tiles_are_the_bands(name):
+    """``live_tiles`` is a brute-force count of the compute tiles that hold
+    at least one (query, key) pair the mask admits; ``live`` and ``bwd_live``
+    the same over each pass's own grid of blocks; and the pair slots the
+    backward's loops visit (``bwd_visits``) are those tiles and no others,
+    counted here by walking every step's kv tiles and asking the band."""
+    bh, bh_kv, s, d, itemsize, window, kw = SCHEDULE_BANDS[name]
+    sc = flash_schedule(bh, s, d, itemsize, True, window=window, bh_kv=bh_kv,
+                        **kw)
+    w = window if window is not None and window < s else s
+
+    def sees(q0, q_rows, k0, kv_rows):
+        # some query of the block sees some key of the block
+        return (k0 <= q0 + q_rows - 1) & (q0 - (k0 + kv_rows - 1) < w)
+
+    def band(q_rows, kv_rows):
+        qi = np.arange(s // q_rows)[:, None] * q_rows
+        kj = np.arange(s // kv_rows)[None, :] * kv_rows
+        return int(sees(qi, q_rows, kj, kv_rows).sum())
+
+    assert sc.group == bh // bh_kv and sc.g % sc.group == 0
+    assert sc.window == (0 if w == s else w)
+    assert sc.live_tiles == bh * band(sc.block_q, sc.block_kv)
+    assert sc.tiles == bh * (s // sc.block_q) * (s // sc.block_kv)
+    assert sc.live == bh // sc.g * band(sc.block_q, sc.block_kv_major)
+    # a backward step: bwd_g K/V heads with one query head each
+    assert sc.bwd_g == sc.g // sc.group
+    assert sc.bwd_steps == sc.bwd_grid[0] * sc.bwd_grid[1] * sc.bwd_grid[2]
+    assert sc.bwd_live == bh // sc.bwd_g * band(sc.block_q_major,
+                                                sc.bwd_block_kv_major)
+    # what the backward visits: in every live step, every kv tile its q rows
+    # see, and for each of those the q tiles that see it (at least the visit)
+    kvm, qm, bq, bkv = (sc.bwd_block_kv_major, sc.block_q_major, sc.block_q,
+                        sc.block_kv)
+    visits = 0
+    for k0 in range(0, s, kvm):
+        for q0 in range(0, s, qm):
+            if not sees(q0, qm, k0, kvm):
+                continue
+            for ks in range(k0, k0 + kvm, bkv):
+                if sees(q0, qm, ks, bkv):
+                    visits += max(1, sum(bool(sees(qt, bq, ks, bkv))
+                                         for qt in range(q0, q0 + qm, bq)))
+    assert sc.bwd_visits == bh * visits == sc.live_tiles
+    assert f"tiles={sc.live_tiles}/{sc.tiles}" in sc.describe()
+    assert f"bwd_visits={sc.live_tiles}/{sc.bwd_visits}" in sc.describe()
+    assert ("window=" in sc.describe()) == bool(sc.window)
+    assert ("kv_heads=" in sc.describe()) == (sc.group > 1)
+
+
+# Both passes of the two long-context cells, pinned (PR 34): the forward
+# keeps a K/V head whole beside a q tile of its whole group (no kv axis); a
+# backward step one query head's 4096 q/dO/dQ rows against 8192 K/V rows,
+# the group along the innermost axis. name -> (g, block_h, K/V rows, grid),
+# (bwd_g, block_h, K/V rows, q rows, grid), dQ partials, live tiles
+CELL_SCHEDULES = {
+    "the_cell_global_layer": ((7, 1, 16384, (4, 32, 1)),
+                              (1, 1, 8192, 4096, (4, 2, 28)), 2, 14784),
+    "the_cell_window_layer": ((7, 1, 16384, (4, 32, 1)),
+                              (1, 1, 8192, 4096, (4, 2, 28)), 2, 7056),
+    "trinity_global_layer": ((8, 1, 8192, (8, 16, 1)),
+                             (1, 1, 8192, 4096, (8, 1, 16)), 1, 8704),
+    "trinity_window_layer": ((8, 1, 8192, (8, 16, 1)),
+                             (1, 1, 8192, 4096, (8, 1, 16)), 1, 4480),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELL_SCHEDULES))
+def test_the_long_context_cells_schedules_are_pinned(name):
+    from ps_pytorch_tpu.ops.flash_attention import VMEM_BUDGET_BYTES
+    bh, bh_kv, s, d, itemsize, window, kw = SCHEDULE_BANDS[name]
+    sc = flash_schedule(bh, s, d, itemsize, True, window=window, bh_kv=bh_kv)
+    fwd, bwd, partials, live_tiles = CELL_SCHEDULES[name]
+    assert (sc.block_q, sc.block_kv) == (512, 512)
+    assert (sc.g, sc.block_h, sc.block_kv_major, sc.grid) == fwd
+    assert (sc.bwd_g, sc.block_h, sc.bwd_block_kv_major,
+            sc.block_q_major, sc.bwd_grid) == bwd
+    # no kv axis: no dead forward step, m/l/acc never leave the step
+    assert sc.grid[2] == 1 and sc.dead == 0
+    assert sc.dq_partials == partials
+    assert sc.bwd_visits == sc.live_tiles == live_tiles
+    assert sc.vmem_bytes <= VMEM_BUDGET_BYTES
+    assert sc.bwd_vmem_bytes <= VMEM_BUDGET_BYTES
+    assert f"bwd_visits={live_tiles}/{live_tiles}" in sc.describe()
+
+
+def test_the_window_layers_visit_at_most_half_the_global_layers_tiles():
+    """At the cell's shape the band of 4096 keys is 44% of the causal
+    triangle in pairs, 48% in 512 x 512 tiles; K and V keep their 4 heads."""
+    glob = flash_schedule(28, 16384, 128, 2, True, bh_kv=4)
+    win = flash_schedule(28, 16384, 128, 2, True, window=4096, bh_kv=4)
+    assert 2 * win.live_tiles <= glob.live_tiles
+    assert 2 * win.bwd_visits <= glob.bwd_visits
+    assert win.live == glob.live and win.bwd_live < glob.bwd_live
+    assert win.g == glob.g == 7 and win.group == 7
+    assert win.bwd_g == glob.bwd_g == 1
+    same = dict(live=0, bwd_live=0, live_tiles=0, bwd_visits=0)
+    assert win._replace(window=0, **same) == glob._replace(**same)

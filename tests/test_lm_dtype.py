@@ -87,7 +87,7 @@ def test_default_config_computes_in_bfloat16(arch):
 @pytest.mark.parametrize("arch", sorted(ARCH))
 def test_float32_config_is_the_model_as_it_was(arch):
     """``compute_dtype="float32"`` builds what the classes build with no dtype
-    given (the parent's model: ``tests/test_olmoe.py`` holds its golden
+    given (the parent's model: ``tests/test_arch_gpt2.py`` holds its golden
     hashes): same parameter tree and values, logits bit for bit. The
     bfloat16 build initialises the very same float32 parameters."""
     cfg = _cfg(arch, compute_dtype="float32")

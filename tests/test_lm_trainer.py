@@ -255,78 +255,6 @@ def test_lm_parallelism_resume_same_mode(tmp_path):
     assert int(t2.state.step) == 8
 
 
-def _pack_legacy_qkv(tree):
-    """Inverse of models.transformer.migrate_packed_qkv: turn a CURRENT
-    state dict into the pre-split layout (packed [d,3d] Dense_0, Block
-    Dense params renumbered 0..3) so tests can fabricate the legacy
-    checkpoints the migration exists for."""
-    def walk(node):
-        if not isinstance(node, dict):
-            return node
-        node = {k: walk(v) for k, v in node.items()}
-        dense = {k for k in node if k.startswith("Dense_")}
-        if dense == {f"Dense_{i}" for i in range(6)} \
-                and "kernel" in node["Dense_0"]:
-            packed = np.concatenate([np.asarray(node[f"Dense_{i}"]["kernel"])
-                                     for i in range(3)], axis=1)
-            out = {k: v for k, v in node.items() if k not in dense}
-            out["Dense_0"] = {"kernel": packed}
-            out["Dense_1"] = node["Dense_3"]
-            out["Dense_2"] = node["Dense_4"]
-            out["Dense_3"] = node["Dense_5"]
-            return out
-        return node
-    return walk(tree)
-
-
-def test_legacy_packed_qkv_checkpoint_migrates(tmp_path):
-    """A checkpoint written before the q/k/v projection split (packed
-    Dense(3d), advisor r3 finding) must restore EXACTLY through the
-    load-path migration — params and optimizer momentum both."""
-    from flax import serialization
-
-    from ps_pytorch_tpu.models.transformer import migrate_packed_qkv
-    from ps_pytorch_tpu.runtime import checkpoint as ckpt
-    from ps_pytorch_tpu.runtime.lm_trainer import LMTrainer
-
-    cfg = _cfg(tmp_path, max_steps=6, eval_freq=6)
-    t = LMTrainer(cfg)
-    t.train()                                       # writes model_step_6
-
-    # Rewrite the checkpoint in the legacy layout, bit-preserving values.
-    path = ckpt.checkpoint_path(cfg.train_dir, 6)
-    with open(f"{path}/state.msgpack", "rb") as f:
-        raw = serialization.msgpack_restore(f.read())
-    legacy = _pack_legacy_qkv(raw)
-    assert legacy != raw                            # packing really happened
-    with open(f"{path}/state.msgpack", "wb") as f:
-        f.write(serialization.msgpack_serialize(legacy))
-    # Pre-split checkpoints also predate the integrity manifest; drop it so
-    # the dir is a faithful legacy layout (and exercises the manifest-less
-    # verify path) instead of tripping the sha256 check on the rewrite.
-    pathlib.Path(path, "manifest.json").unlink()
-
-    # Direct restore path: migration must reproduce the original tree
-    # exactly (the split is a column slice, not a recomputation).
-    migrated, n = migrate_packed_qkv(legacy)
-    assert n > 0
-    np.testing.assert_array_equal(
-        np.asarray(migrated["params"]["block_0"]["Dense_1"]["kernel"]),
-        np.asarray(raw["params"]["block_0"]["Dense_1"]["kernel"]))
-
-    # End-to-end: a fresh trainer resumes FROM THE LEGACY FILE and
-    # continues training.
-    t2 = LMTrainer(cfg.replace(max_steps=8))
-    t2.train()
-    assert t2.start_step == 6
-    assert int(t2.state.step) == 8
-
-    # A MODERN tree reports nothing to migrate — the hook can never
-    # rewrite a current checkpoint by accident.
-    _, n_modern = migrate_packed_qkv(raw)
-    assert n_modern == 0
-
-
 # ---- the olmoe arch through the same contract (dropless MoE: one device) ----
 
 _OLMOE = dict(lm_arch="olmoe", lm_parallelism="ep", lm_experts=8,

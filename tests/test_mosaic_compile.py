@@ -3,9 +3,14 @@ Qwen3-Next cells' flash kernels, the Phi-4-flash cell's selective scan and the
 Qwen3-Next cell's gated delta rule and mixer chains compile under
 Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
-slice off the tiling, a DMA the compiler refuses; and the long-context cells'
-whole train steps, for the bytes the compiler plans on the device. One file, one
-fixture: only the worker that runs it loads the TPU compiler
+slice off the tiling, a DMA the compiler refuses; and, under ``-m slow``, the
+long-context cells' whole train steps, for the bytes the compiler plans on the
+device. The kernel-shape compiles are tier-1's: no chip run says which kernel
+or which tile. The four whole-step compiles (370 s together at PR 41) are
+marked ``slow`` since PR 42: that the cell's step fits the chip is what the
+driver measures on a v5e in that very cell on every PR (``peak_hbm``; a step
+that does not fit fails the cell). Run them when a PR moves a step's plan.
+One file, one fixture: only the worker that runs it loads the TPU compiler
 (on-chip-measurement guide, section 2)."""
 
 import importlib
@@ -133,6 +138,7 @@ CELL_FILES = {"smallthinker_s16384_1chip": ("smallthinker_21b_a3b",
               "trinity_mini_s8192_1chip": ("trinity_mini", "s8192_1chip")}
 
 
+@pytest.mark.slow    # a whole step at the cell's size: see the module's docstring
 @pytest.mark.parametrize("cell", sorted(CELL_FILES))
 def test_the_cells_whole_step_plans_no_more_memory_than_the_parents(
         one_chip, monkeypatch, cell):
@@ -244,6 +250,7 @@ def test_selective_scan_compiles_at_the_cells_shape(one_chip):
     assert f"f32[{bt},5,64,{n},8,128]" in text      # the boundary states kept
 
 
+@pytest.mark.slow    # a whole step at the cell's size
 def test_the_phi4flash_cells_whole_step_fits_by_the_rule(one_chip,
                                                          monkeypatch):
     """The sp step as ``LMTrainer`` builds it from the cell's own flags,
@@ -506,6 +513,7 @@ def test_gdn_mix_compiles_at_the_cells_shape(one_chip):
     assert in_kernels + sum(op[4] for op in ops if not op[2]) < 3.2e9
 
 
+@pytest.mark.slow    # a whole step at the cell's size
 def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
                                                          monkeypatch):
     """The ep step as ``LMTrainer`` builds it from the cell's own flags,

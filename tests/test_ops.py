@@ -2,17 +2,11 @@
 
 - quantize: round-trip error bound, unbiasedness of stochastic rounding,
   wire-size accounting.
-- fused_sgd: golden agreement with the optax transform (optim/sgd.py, itself
-  golden-tested against the reference's torch math) over multiple steps,
-  including weight-decay / Nesterov / dampening; integration into the SPMD
-  train step.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
-import pytest
 
 
 # ---------------------------------------------------------------- quantize --
@@ -58,125 +52,3 @@ def test_quantize_zero_block():
     x = jnp.zeros((4096,), jnp.float32)
     out = dequantize_int8(quantize_int8(x, jax.random.key(0)))
     assert float(jnp.max(jnp.abs(out))) == 0.0
-
-
-# --------------------------------------------------------------- fused sgd --
-
-@pytest.mark.parametrize("wd,nesterov,damp", [
-    (0.0, False, 0.0), (5e-4, False, 0.0), (5e-4, True, 0.0),
-    (0.0, False, 0.1),
-])
-def test_fused_sgd_matches_optax_transform(rng, wd, nesterov, damp):
-    from ps_pytorch_tpu.ops.fused_sgd import FusedSGD
-    from ps_pytorch_tpu.optim import sgd
-
-    params = {"w": jnp.asarray(rng.normal(size=(130, 7)).astype(np.float32)),
-              "b": jnp.asarray(rng.normal(size=(11,)).astype(np.float32))}
-    tx = sgd(lr=0.05, momentum=0.9, dampening=damp, weight_decay=wd,
-             nesterov=nesterov)
-    fused = FusedSGD(lr=0.05, momentum=0.9, dampening=damp, weight_decay=wd,
-                     nesterov=nesterov)
-    s_ref, s_fused = tx.init(params), fused.init(params)
-    p_ref, p_fused = params, params
-    for step in range(4):
-        grads = jax.tree.map(
-            lambda p: jnp.asarray(rng.normal(size=p.shape).astype(np.float32)),
-            params)
-        updates, s_ref = tx.update(grads, s_ref, p_ref)
-        p_ref = optax.apply_updates(p_ref, updates)
-        p_fused, s_fused = fused.apply(p_fused, s_fused, grads)
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_ref[k]),
-                                       np.asarray(p_fused[k]),
-                                       rtol=1e-6, atol=1e-6)
-
-
-def test_fused_sgd_in_spmd_step(mesh8, rng):
-    """Full train step with the fused optimizer on the 8-device mesh matches
-    the optax-path step."""
-    from ps_pytorch_tpu.config import TrainConfig
-    from ps_pytorch_tpu.models import build_model
-    from ps_pytorch_tpu.optim import build_optimizer
-    from ps_pytorch_tpu.parallel import create_train_state, make_train_step
-
-    x = jnp.asarray(rng.normal(size=(64, 28, 28, 1)).astype(np.float32))
-    y = jnp.asarray(rng.integers(0, 10, 64).astype(np.int32))
-    mask = jnp.ones(8, jnp.float32)
-    results = []
-    for fused in (False, True):
-        cfg = TrainConfig(dataset="synthetic_mnist", network="LeNet",
-                          batch_size=64, lr=0.1, momentum=0.9,
-                          compute_dtype="float32", fused_optimizer=fused)
-        model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype)
-        tx = build_optimizer(cfg)
-        state = create_train_state(model, tx, mesh8, (1, 28, 28, 1),
-                                   jax.random.key(0))
-        step_fn = make_train_step(model, tx, mesh8, state, donate=False)
-        for i in range(2):
-            state, m = step_fn(state, x, y, mask, jax.random.key(i))
-        results.append((state, float(m["loss"])))
-    (s0, l0), (s1, l1) = results
-    assert l0 == pytest.approx(l1, abs=1e-6)
-    for a, b in zip(jax.tree.leaves(s0.params), jax.tree.leaves(s1.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
-
-
-# -------------------------------------------------------------- fused adam --
-
-@pytest.mark.parametrize("wd,amsgrad", [(0.0, False), (5e-4, False),
-                                        (5e-4, True)])
-def test_fused_adam_matches_optax_transform(rng, wd, amsgrad):
-    from ps_pytorch_tpu.ops.fused_adam import FusedAdam
-    from ps_pytorch_tpu.optim import adam
-
-    params = {"w": jnp.asarray(rng.normal(size=(130, 7)).astype(np.float32)),
-              "b": jnp.asarray(rng.normal(size=(11,)).astype(np.float32))}
-    tx = adam(lr=1e-2, weight_decay=wd, amsgrad=amsgrad)
-    fused = FusedAdam(lr=1e-2, weight_decay=wd, amsgrad=amsgrad)
-    s_ref, s_fused = tx.init(params), fused.init(params)
-    p_ref, p_fused = params, params
-    for step in range(4):
-        grads = jax.tree.map(
-            lambda p: jnp.asarray(rng.normal(size=p.shape).astype(np.float32)),
-            params)
-        updates, s_ref = tx.update(grads, s_ref, p_ref)
-        p_ref = optax.apply_updates(p_ref, updates)
-        p_fused, s_fused = fused.apply(p_fused, s_fused, grads)
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_ref[k]),
-                                       np.asarray(p_fused[k]),
-                                       rtol=1e-6, atol=1e-7)
-    # Moment buffers agree too (they feed future steps).
-    for a, b in zip(jax.tree.leaves(s_ref.exp_avg_sq),
-                    jax.tree.leaves(s_fused.exp_avg_sq)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-8)
-
-
-def test_fused_adam_in_spmd_step(mesh8, rng):
-    from ps_pytorch_tpu.config import TrainConfig
-    from ps_pytorch_tpu.models import build_model
-    from ps_pytorch_tpu.optim import build_optimizer
-    from ps_pytorch_tpu.parallel import create_train_state, make_train_step
-
-    x = jnp.asarray(rng.normal(size=(64, 28, 28, 1)).astype(np.float32))
-    y = jnp.asarray(rng.integers(0, 10, 64).astype(np.int32))
-    mask = jnp.ones(8, jnp.float32)
-    results = []
-    for fused in (False, True):
-        cfg = TrainConfig(dataset="synthetic_mnist", network="LeNet",
-                          batch_size=64, optimizer="adam", lr=1e-2,
-                          compute_dtype="float32", fused_optimizer=fused)
-        model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype)
-        tx = build_optimizer(cfg)
-        state = create_train_state(model, tx, mesh8, (1, 28, 28, 1),
-                                   jax.random.key(0))
-        step_fn = make_train_step(model, tx, mesh8, state, donate=False)
-        for i in range(2):
-            state, m = step_fn(state, x, y, mask, jax.random.key(i))
-        results.append(state)
-    for a, b in zip(jax.tree.leaves(results[0].params),
-                    jax.tree.leaves(results[1].params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)

@@ -58,14 +58,11 @@ def test_build_schedule_from_config():
         TrainConfig(lr_schedule="linear")
 
 
-@pytest.mark.parametrize("fused", [False, True])
-def test_scheduled_sgd_updates_shrink(fused):
+def test_scheduled_sgd_updates_shrink():
     """With a decaying schedule, later update magnitudes must shrink under
-    constant gradients — through the real build_optimizer wiring, both
-    optimizer families."""
+    constant gradients — through the real build_optimizer wiring."""
     cfg = TrainConfig(lr=0.5, lr_schedule="step", lr_decay_steps=2,
-                      lr_decay_factor=0.1, momentum=0.0,
-                      fused_optimizer=fused)
+                      lr_decay_factor=0.1, momentum=0.0)
     tx = build_optimizer(cfg)
     params = {"w": jnp.ones((8,), jnp.float32)}
     state = tx.init(params)

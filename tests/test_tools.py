@@ -329,50 +329,3 @@ def test_kill_and_resume(tmp_path):
     first_step = next(int(l.split()[1]) for l in text.splitlines()
                       if l.startswith("STEP "))
     assert first_step == resumed_from + 1, dump
-
-
-# ----------------------------------------------------------- scaling_run --
-
-def test_scaling_run_train_argv_modes():
-    """Per-mode launch argv: kofn gets K=N-1, async divides the batch and
-    carries the staleness limit, the injected straggler targets the last
-    process only when N>1 (scaling_run.py feeds tools/launch.py with these)."""
-    import argparse
-
-    from ps_pytorch_tpu.tools.scaling_run import _train_argv
-
-    args = argparse.Namespace(
-        network="LeNet", dataset="synthetic_mnist", batch_size=1024,
-        steps=12, staleness_limit=8, inject_step_delay=0.25)
-    sync = _train_argv("sync", 4, args)
-    assert ["--batch-size", "1024"] == sync[sync.index("--batch-size"):
-                                            sync.index("--batch-size") + 2]
-    kofn = _train_argv("kofn", 4, args)
-    assert "3" == kofn[kofn.index("--num-aggregate") + 1]
-    asyn = _train_argv("async", 4, args)
-    assert "256" == asyn[asyn.index("--batch-size") + 1]
-    assert "8" == asyn[asyn.index("--staleness-limit") + 1]
-    assert "3" == asyn[asyn.index("--inject-delay-process") + 1]
-    solo = _train_argv("sync", 1, args)
-    assert "--inject-step-delay" not in solo
-
-
-def test_scaling_run_markdown_shape():
-    from ps_pytorch_tpu.tools.scaling_run import to_markdown
-
-    result = {
-        "network": "LeNet", "dataset": "synthetic_mnist",
-        "global_batch": 1024, "steps_per_run": 12,
-        "platform": "cpu-simulate",
-        "modes": {"sync": [
-            {"run": "1", "steps": 10, "step_time_normal_s": 1.0,
-             "step_time_ideal_s": 1.0, "speedup_normal": 1.0,
-             "speedup_ideal": 1.0},
-            {"run": "2", "steps": 10, "step_time_normal_s": 0.6,
-             "step_time_ideal_s": 0.5, "speedup_normal": 1.67,
-             "speedup_ideal": 2.0},
-        ]},
-    }
-    md = to_markdown(result)
-    assert "cpu-simulate" in md and "## mode = sync" in md
-    assert "[1.0, 1.67]" in md and "[1.0, 2.0]" in md

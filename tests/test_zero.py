@@ -83,35 +83,3 @@ def test_zero_all_masked_is_noop(mesh8, rng):
     s2, m = step(s, x, y, jnp.zeros(8, jnp.float32), jax.random.key(0))
     for a, b in zip(jax.tree.leaves(s.params), jax.tree.leaves(s2.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_zero_with_fused_optimizer(mesh8, rng):
-    """--shard-update + --fused-optimizer: the Pallas kernel updates each
-    replica's slice; must match the optax zero path."""
-    from ps_pytorch_tpu.config import TrainConfig
-    from ps_pytorch_tpu.models import build_model
-    from ps_pytorch_tpu.optim import build_optimizer
-    from ps_pytorch_tpu.parallel.zero import (
-        create_zero_train_state, make_zero_train_step,
-    )
-
-    x = jnp.asarray(rng.normal(size=(64, 28, 28, 1)).astype(np.float32))
-    y = jnp.asarray(rng.integers(0, 10, 64).astype(np.int32))
-    mask = jnp.ones(8, jnp.float32)
-    results = []
-    for fused in (False, True):
-        cfg = TrainConfig(dataset="synthetic_mnist", network="LeNet",
-                          batch_size=64, lr=0.1, momentum=0.9,
-                          compute_dtype="float32", fused_optimizer=fused)
-        model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype)
-        tx = build_optimizer(cfg)
-        s = create_zero_train_state(model, tx, mesh8, (1, 28, 28, 1),
-                                    jax.random.key(0))
-        step = make_zero_train_step(model, tx, mesh8, s, donate=False)
-        for i in range(2):
-            s, m = step(s, x, y, mask, jax.random.key(i))
-        results.append(s)
-    for a, b in zip(jax.tree.leaves(results[0].params),
-                    jax.tree.leaves(results[1].params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
