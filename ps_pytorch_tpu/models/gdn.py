@@ -44,7 +44,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ps_pytorch_tpu.models.ssm import _dt_bias_init, _symmetric_uniform
+from ps_pytorch_tpu.models.ssm import (
+    _dt_bias_init, _NormScale, _symmetric_uniform,
+)
 from ps_pytorch_tpu.telemetry.trace import device_scope
 
 A_MAX = 16.0        # A = exp(A_log) is drawn from U(0, A_MAX) a value head
@@ -59,15 +61,6 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     library's.)"""
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-4, A_MAX)
                    ).astype(dtype)
-
-
-class _NormScale(nn.Module):
-    """The output norm's one parameter, under the name and shape
-    ``nn.RMSNorm`` gave it (``gdn_norm/scale [dv]``, ones)."""
-
-    @nn.compact
-    def __call__(self, features):
-        return self.param("scale", nn.initializers.ones, (features,))
 
 
 def gdn_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype,

@@ -54,11 +54,12 @@ import jax
 import jax.numpy as jnp
 
 from ps_pytorch_tpu.models.gdn import gdn_sublayer
+from ps_pytorch_tpu.models.ssm import mamba2_sublayer
 from ps_pytorch_tpu.models.transformer import (
     ACTS, ARCHS, COUNTER_NAMES, LM_COUNTERS, GatedFFN, attention_sublayer,
     embed_tokens, make_norm, refuse_hybrid, remat_block,
 )
-from ps_pytorch_tpu.ops.grouped_matmul import gmm
+from ps_pytorch_tpu.ops.grouped_matmul import gmm, gmm_t
 from ps_pytorch_tpu.telemetry.trace import device_scope
 
 # What a dropless model returns beside its logits (and the ep step passes
@@ -373,8 +374,10 @@ class DroplessMoE(nn.Module):
         I = top-k of p,  g_i = p_i     (``gate_norm``: g_i = p_i / sum_I p)
         y = sum_{i in I} g_i * (act(h Wgate_i) * (h Wup_i)) Wdown_i
 
-    with ``act`` SiLU (SwiGLU) or ReLU, and the router reading ``router_x``
-    where the caller hands one (an arch whose router sits before attention)
+    with ``act`` SiLU (SwiGLU), ReLU or its square (not ``gated``: ``act(h
+    Wup_i) Wdown_i``, two grouped matmuls, no ``experts_gate`` and
+    ``experts_up`` stored transposed, [held, f, d]), and the
+    router reading ``router_x`` where the caller hands one (an arch whose router sits before attention)
     in place of ``h``. ``score`` "sigmoid": p = sigmoid(r), each output by
     itself. ``select_bias``: I = top-k of p + b, with b the layer's
     ``expert_bias`` [E] in the ``MOE_STATE`` collection: it chooses and does
@@ -432,6 +435,7 @@ class DroplessMoE(nn.Module):
     score: str = "softmax"            # softmax | sigmoid
     select_bias: bool = False         # top-k of score + expert_bias (MOE_STATE)
     route_scale: float = 1.0
+    gated: bool = True                # False: act(h Wup_i) Wdown_i, no gate projection
 
     @nn.compact
     def __call__(self, x, router_x=None):
@@ -470,8 +474,16 @@ class DroplessMoE(nn.Module):
                 gates = gates * self.route_scale
 
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", init, (held, d, f))
-        w_up = self.param("experts_up", init, (held, d, f))
+        w_gate = self.param("experts_gate", init, (held, d, f)) \
+            if self.gated else None
+        # Without a gate the up projection is stored [held, f, d], as a
+        # checkpoint stores a linear layer, and multiplied by ``gmm_t``: an
+        # expert width off the 128 lanes (1856) can be the last dimension of
+        # rows, not of weights that a kernel fetches by its own DMA.
+        w_up = self.param("experts_up", init, (held, d, f)) if self.gated \
+            else self.param("experts_up", nn.initializers.variance_scaling(
+                1.0, "fan_in", "truncated_normal", in_axis=-1, out_axis=-2,
+                batch_axis=(0,)), (held, f, d))
         w_down = self.param(
             "experts_down", nn.initializers.normal(self.down_std)
             if self.down_std else init, (held, f, d))
@@ -492,13 +504,14 @@ class DroplessMoE(nn.Module):
             flat_gates = gates.reshape(-1)
             n_held_rows = jnp.sum(group_sizes)
 
-        def part(tokens, flat_gates, w_gate, w_up, w_down, order, sizes,
-                 inv=None):
+        def part(tokens, flat_gates, *rest, inv=None):
             """The rows ``order`` (sorted assignments), the first
             ``sum(sizes)`` of which the groups cover: gathered, through the
             experts, gated, and summed into their tokens: gathered back
             through ``inv`` [T, k] where ``order`` holds every assignment and
-            ``inv`` their sorted places, else scatter-added."""
+            ``inv`` their sorted places, else scatter-added. ``rest``: the
+            experts' weights (``weights`` below), then ``order``, ``sizes``."""
+            *w_in, w_down, order, sizes = rest
             with device_scope("moe_dispatch"):
                 xs = tokens[order // k] if inv is None \
                     else _rows_in(tokens, order, inv)
@@ -508,7 +521,10 @@ class DroplessMoE(nn.Module):
             # comes back float32 from the float32 accumulator
             # (ops/grouped_matmul.py).
             with device_scope("moe_experts"):
-                h = act(gmm(xs, w_gate, sizes)) * gmm(xs, w_up, sizes)
+                if self.gated:
+                    h = act(gmm(xs, w_in[0], sizes)) * gmm(xs, w_in[1], sizes)
+                else:
+                    h = act(gmm_t(xs, w_in[0], sizes))
                 out = gmm(h, w_down, sizes)
             with device_scope("moe_dispatch"):
                 if inv is not None:
@@ -521,11 +537,12 @@ class DroplessMoE(nn.Module):
         rows = t * k if held == e else min(
             t * k, -(-int(HELD_ROWS_SLACK * t * k * held / e)
                      // HELD_ROWS_TILE) * HELD_ROWS_TILE)
-        weights = (w_gate, w_up, w_down)
+        weights = (w_gate, w_up, w_down) if self.gated else (w_up, w_down)
         if rows == t * k:
             with device_scope("moe_route"):       # the sort's inverse
                 inv = jnp.argsort(order).reshape(t, k)
-            y = part(tokens, flat_gates, *weights, order, group_sizes, inv)
+            y = part(tokens, flat_gates, *weights, order, group_sizes,
+                     inv=inv)
         else:
             with device_scope("moe_route"):
                 ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
@@ -575,7 +592,10 @@ class MoEBlock(nn.Module):
     ``GatedFFN`` of that width: one of a dropless model's leading dense layers,
     whose ``aux`` is None. The first half is the mixer of the layer's kind
     (``Arch.layer_kind``): attention, or a Gated DeltaNet layer, whose counter
-    (``COUNTER_NAMES``) a dropless layer's ``aux`` carries to the model."""
+    (``COUNTER_NAMES``) a dropless layer's ``aux`` carries to the model. Under
+    an arch with a ``layer_pattern`` the block is ONE of the halves: a mixer
+    alone (attention or Mamba-2), whose ``aux`` is what it counted ({} for
+    attention), or the expert layer alone ("experts")."""
     n_heads: int
     d_model: int
     n_experts: int
@@ -610,22 +630,31 @@ class MoEBlock(nn.Module):
     def __call__(self, x, positions=None):
         b, s, d = x.shape
         a = ARCHS[self.arch]
-        counted = {}
-        if a.layer_kind(self.layer) == "gdn":
-            if self.decode:
-                refuse_hybrid(self.arch, "decode")
+        counted, normed = {}, None
+        kind = a.layer_kind(self.layer)
+        if self.decode and kind in ("gdn", "mamba2"):
+            refuse_hybrid(self.arch, "decode")
+        if kind == "gdn":
             x, normed, counted = gdn_sublayer(
                 self, x, make_norm(self.arch, self.dtype), dtype=self.dtype,
                 key_heads=a.gdn_key_heads, value_heads=a.gdn_value_heads,
                 key_dim=a.gdn_key_dim, value_dim=a.gdn_value_dim,
                 conv=a.gdn_conv, norm_eps=a.norm_eps)
-        else:
+        elif kind == "mamba2":
+            x, normed, counted = mamba2_sublayer(
+                self, x, make_norm(self.arch, self.dtype), dtype=self.dtype,
+                heads=a.ssm_heads, head_dim=a.ssm_head_dim,
+                groups=a.ssm_groups, d_state=a.ssm_state, d_conv=a.ssm_conv,
+                chunk=a.ssm_chunk, norm_eps=a.norm_eps)
+        elif kind != "experts":
             x, normed, _ = attention_sublayer(
                 self, x, positions, arch=self.arch, n_heads=self.n_heads,
                 dtype=self.dtype, attention_impl=self.attention_impl,
                 decode=self.decode, decode_cache_len=self.decode_cache_len,
                 layer=self.layer, kv_heads=self.kv_heads,
                 head_dim=self.head_dim)
+        if a.layer_pattern and kind != "experts":
+            return x, counted           # a mixer alone
         # The block's second half is a dense layer's scope or an expert
         # layer's three: the norms and the residual sum go to the first and
         # the last of them.
@@ -647,7 +676,8 @@ class MoEBlock(nn.Module):
                                  down_std=a.expert_down_std,
                                  score=a.router_score,
                                  select_bias=a.router_bias_rate > 0,
-                                 route_scale=a.route_scale, name="moe")(
+                                 route_scale=a.route_scale,
+                                 gated=a.expert_gated, name="moe")(
                 y, normed if a.early_router else None)
             if EXPERT_COUNTS in aux:
                 aux[EXPERT_COUNTS] = {"moe": aux[EXPERT_COUNTS]}
@@ -655,7 +685,8 @@ class MoEBlock(nn.Module):
                 # every token's, whatever share of the routed experts is held
                 with device_scope("moe_shared"):
                     shared = GatedFFN(a.shared_experts * width, self.dtype,
-                                      a.expert_act, name="shared")(y)
+                                      a.expert_act, a.expert_gated,
+                                      name="shared")(y)
                     if a.shared_gate:
                         shared = shared * nn.sigmoid(nn.Dense(
                             1, use_bias=False, dtype=self.dtype,
@@ -759,11 +790,18 @@ class MoETransformerLM(nn.Module):
                 per_layer.append((f"block_{i}", aux))
         with device_scope("moe_route"):     # the layers' statistics as one
             if ARCHS[self.arch].dropless:
-                aux_total = {k: over(jnp.stack([a[k] for _, a in per_layer]))
+                # (a block that is a mixer alone has its counters only)
+                routed = [(n, a) for n, a in per_layer if "aux" in a]
+                if not routed:
+                    raise ValueError(
+                        f"--lm-arch {self.arch} at depth {self.n_layers} "
+                        f"holds no expert layer, and the ep step trains an "
+                        f"expert stack")
+                aux_total = {k: over(jnp.stack([a[k] for _, a in routed]))
                              for k, over in _OVER_LAYERS.items()}
                 if ARCHS[self.arch].router_bias_rate:
                     aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
-                                                for name, a in per_layer}
+                                                for name, a in routed}
                 # what the mixers counted rode in their layers' statistics
                 for k in COUNTER_NAMES:
                     vs = [a[k] for _, a in per_layer if k in a]

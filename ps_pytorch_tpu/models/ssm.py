@@ -1,5 +1,5 @@
-"""The state-space mixers of a hybrid decoder: the Mamba-1 layer and the gated
-memory unit that reads a Mamba layer's scan output.
+"""The state-space mixers: a hybrid decoder's Mamba-1 layer and the gated
+memory unit that reads a Mamba layer's scan output, and the Mamba-2 layer.
 
 Both are the first half of a ``models/transformer.Block`` (``x + mixer(norm(
 x))``), written as functions called from the block's ``@nn.compact`` body
@@ -26,6 +26,23 @@ Precision under a narrower compute dtype: the projections and the
 convolution run in it; ``delta`` (the bias's add and the softplus), ``A``,
 ``B``, ``C`` and the scan's state are float32; ``m`` leaves in the compute
 dtype.
+
+Mamba-2 (arXiv:2405.21060; ``d_inner = heads * head_dim`` whatever ``d`` is,
+``G`` groups of ``N`` states):
+
+    [z, xBC, dt] = in_proj(a)                   d -> d_inner + (d_inner + 2 G N) + heads, no bias
+    xBC    = silu(conv1d(xBC))                  depthwise, causal, d_conv taps, bias
+    [x, B, C] = xBC                             x: heads of head_dim; B, C: G groups of N, head h reads group h // (heads / G)
+    delta  = softplus(dt + dt_bias)             a head, float32, no clamp
+    A      = -exp(A_log)                        a scalar a head
+    y      = ssd(x, delta, A, B, C, D)          ops/ssd.py; D a scalar a head
+    y      = GroupRMSNorm(y * silu(z))          the gate FIRST, then the norm over each of G groups of d_inner / G; one scale [d_inner]
+    out    = out_proj(y)                        d_inner -> d, no bias
+
+Precision: the projections and the convolution in the compute dtype; ``delta``,
+``A``, the state and the gated norm (its product with ``silu(z)``, its
+statistics and its scale) float32; ``x``, ``B``, ``C`` enter the kernels in
+the compute dtype and ``y`` leaves in it.
 """
 
 import math
@@ -113,6 +130,68 @@ def mamba_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype,
     with device_scope("ssm_proj"):
         x = x + dense(d, "out_proj", use_bias=False)(gated)
     return x, {"memory": m, "ssm_state_abs_max": state_max}
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log U(1, 16)`` a head: mamba_ssm's Mamba2 ``A_init_range``."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+class _NormScale(nn.Module):
+    """A gated output norm's one parameter, under the name and shape
+    ``nn.RMSNorm`` would give it (``<name>/scale [features]``, ones): the
+    Mamba-2 layer's ``ssm_norm`` and ``models/gdn.py``'s ``gdn_norm``."""
+
+    @nn.compact
+    def __call__(self, features):
+        return self.param("scale", nn.initializers.ones, (features,))
+
+
+def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
+                    head_dim: int, groups: int, d_state: int, d_conv: int,
+                    chunk: int, norm_eps: float):
+    """``x + Mamba2(norm(x))``, ``norm(x)`` and ``{"ssd_state_abs_max":
+    ...}``: the largest |state| at the chunk boundaries. ``mod``: the block,
+    whose scope holds the parameters."""
+    # where the arch asks for it: the other archs' start-up does not pay for it
+    from ps_pytorch_tpu.ops.ssd import ssd
+
+    bt, s, d = x.shape
+    d_inner, bc = heads * head_dim, groups * d_state
+    dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=dtype, name=name)
+    with device_scope("ssm_proj"):
+        a = norm(x)
+        z, xbc, dt = jnp.split(
+            dense(2 * d_inner + 2 * bc + heads, "in_proj")(a),
+            [d_inner, 2 * d_inner + 2 * bc], axis=-1)
+    with device_scope("ssm_conv"):
+        init = _symmetric_uniform(d_conv ** -0.5)
+        conv_w = mod.param("conv_weight", init, (d_conv, d_inner + 2 * bc))
+        conv_b = mod.param("conv_bias", init, (d_inner + 2 * bc,))
+        u, b, c = jnp.split(nn.silu(causal_conv1d(xbc, conv_w, conv_b)),
+                            [d_inner, d_inner + bc], axis=-1)
+        dt_bias = mod.param("dt_bias", _dt_bias_init, (heads,))
+        delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+    a_log = mod.param("A_log", _a_log_init, (heads,))
+    skip = mod.param("D", nn.initializers.ones, (heads,))
+    with device_scope("ssd_core"):
+        y, state_max = ssd(u.reshape(bt, s, heads, head_dim), delta,
+                           -jnp.exp(a_log),
+                           b.reshape(bt, s, groups, d_state),
+                           c.reshape(bt, s, groups, d_state), skip,
+                           chunk=chunk)
+    with device_scope("ssm_conv"):
+        scale = _NormScale(name="ssm_norm")(d_inner)
+        g = y.reshape(bt, s, d_inner).astype(jnp.float32) \
+            * nn.silu(z.astype(jnp.float32))
+        g = g.reshape(bt, s, groups, d_inner // groups)
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                              + norm_eps)
+        g = (g.reshape(bt, s, d_inner) * scale).astype(dtype)
+    with device_scope("ssm_proj"):
+        x = x + dense(d, "out_proj")(g)
+    return x, a, {"ssd_state_abs_max": state_max}
 
 
 def gmu_sublayer(x, memory, norm: nn.Module, *, dtype):

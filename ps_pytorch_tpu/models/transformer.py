@@ -48,12 +48,14 @@ class Arch(NamedTuple):
     # Layers of several kinds: layer l is of kind l % len(pattern), for the
     # window, the position encoding and the token mixer alike.
     mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn"; (): attention in every layer
+    layer_pattern: str = ""     # no period but a letter a layer, each layer ONE pre-norm residual sublayer (``PATTERN_KINDS``): "M" a Mamba-2 mixer | "*" an attention mixer | "E" an expert layer; the depth is at most its length
     window: int = 0             # keys a window layer's query sees, itself included
     window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
     rope_layers: Tuple[int, ...] = ()     # per layer of the period: 1 = RoPE | 0 = no position encoding; (): every layer
     # The dropless expert layer.
     gate_norm: bool = False     # top-k gates renormalised to sum to 1
-    expert_act: str = "silu"    # the gate projection's activation: silu | relu
+    expert_act: str = "silu"    # the gate projection's activation: silu | relu | relu2 (relu squared)
+    expert_gated: bool = True   # experts (and shared experts) down(act(gate x) * up x) | False: down(act(up x)), two matmuls
     early_router: bool = False  # the router reads the block's first norm (before attention), not the second
     expert_down_std: float = 0.0    # experts' down projection init: normal(std) | flax's lecun_normal
     router_score: str = "softmax"   # scores of the router's logits: softmax over the experts | sigmoid of each
@@ -74,6 +76,11 @@ class Arch(NamedTuple):
     ssm_state: int = 0          # a Mamba layer's states a channel (d_state)
     ssm_conv: int = 0           # ... its causal depthwise convolution's taps (d_conv)
     ssm_expand: int = 0         # ... its channels over d_model (d_inner = expand * d; dt_rank = ceil(d / 16))
+    # A Mamba-2 layer's sizes (with ssm_state and ssm_conv): d_inner = ssm_heads * ssm_head_dim, whatever d is.
+    ssm_heads: int = 0          # heads, each with one decay and a [ssm_head_dim, ssm_state] state
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0         # B and C are shared by the ssm_heads / ssm_groups heads of a group; the gated norm's groups too
+    ssm_chunk: int = 0          # tokens a chunk of the state-space-dual form
     diff_attn: bool = False     # differential attention: heads pair up (2j, 2j+1), a pair's output is softmax(q1 k1) v - lambda softmax(q2 k2) v over its two value heads side by side, then a norm
     gated_ffn: bool = False     # the dense block's feed-forward: GatedFFN (SwiGLU, no biases) | Dense-GELU-Dense with biases
     tied_head: bool = False     # logits = ln_f(x) . tok_embed^T: no lm_head parameter
@@ -83,6 +90,12 @@ class Arch(NamedTuple):
         """One of ``LAYER_KINDS``: by the period's ``mixer_layers`` ("attention"
         where it has none), or for a hybrid by the index and the depth (which
         only a hybrid's caller has to give)."""
+        if self.layer_pattern:
+            if layer >= len(self.layer_pattern):
+                raise ValueError(
+                    f"layer {layer}: the arch's pattern names "
+                    f"{len(self.layer_pattern)} layers, the published depth")
+            return PATTERN_KINDS[self.layer_pattern[layer]]
         if not self.hybrid:
             return self.mixer_layers[layer % len(self.mixer_layers)] \
                 if self.mixer_layers else "attention"
@@ -118,13 +131,17 @@ class Arch(NamedTuple):
 # a Mamba layer whose scan output goes to every gated memory unit, layer
 # L/2 + 1 a full causal attention layer whose K and V go to every cross layer;
 # then gated memory units and cross-attention layers alternate.
-LAYER_KINDS = ("attention", "gdn", "mamba", "window", "mamba_hands_memory",
-               "full_hands_kv", "gmu", "cross")
+LAYER_KINDS = ("attention", "gdn", "mamba2", "experts", "mamba", "window",
+               "mamba_hands_memory", "full_hands_kv", "gmu", "cross")
+# ``Arch.layer_pattern``'s letters (the published ``hybrid_override_pattern``'s):
+# such a layer is the mixer alone or the expert layer alone, "experts" no mixer.
+PATTERN_KINDS = {"M": "mamba2", "*": "attention", "E": "experts"}
 ATTENTION_KINDS = ("attention", "window", "full_hands_kv", "cross")
 # What a hybrid block may hand on, and what it counts (max over layers):
 HANDED = ("memory", "k", "v")
 LM_COUNTERS = "lm_counters"     # the flax collection the counters are sown in
-COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max", "gdn_state_abs_max")
+COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max", "gdn_state_abs_max",
+                 "ssd_state_abs_max")
 
 ARCHS = {
     "gpt2": Arch(),
@@ -232,6 +249,33 @@ ARCHS = {
                       mixer_layers=("gdn", "gdn", "gdn", "attention"),
                       gdn_key_heads=16, gdn_value_heads=32, gdn_key_dim=128,
                       gdn_value_dim=128, gdn_conv=4),
+    # NVIDIA-Nemotron-3-Nano-30B-A3B (nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-
+    # BF16 config.json, model_type nemotron_h; Nemotron-H, arXiv:2504.03624;
+    # Mamba-2, arXiv:2405.21060): 52 layers, each ONE pre-norm residual
+    # sublayer by the letter of hybrid_override_pattern: 23 Mamba-2 mixers (64
+    # heads of 64 with a [64, 128] state, B and C in 8 groups, a biased 4-tap
+    # convolution, chunks of 128, the gate BEFORE a norm in 8 groups), 6
+    # attention mixers (no position encoding anywhere: rope_theta is carried
+    # and not read) and 23 expert layers: sigmoid scores, top-6 chosen by
+    # score + bias (trinity's bias, moved 0.001 a step), gates the scores over
+    # their sum (norm_topk_prob) times routed_scaling_factor 2.5, experts
+    # down(relu(up x)^2) without a gate projection, one shared expert of the
+    # same form and twice the width (moe_shared_expert_intermediate_size 3712
+    # = 2 x 1856: shared_experts 2); no auxiliary loss; layer_norm_epsilon
+    # 1e-5. embed_std as olmoe's and expert_down_std 0.02 / sqrt(2 x 52
+    # layers) as smallthinker's, for their reasons
+    # (benchmark/configs/nemotron3_nano_30b_a3b.json reference_check.why). The
+    # Mamba-2 layers' initialisers are models/ssm.py's.
+    "nemotronh": Arch(rms_norm=True, norm_eps=1e-5, no_positions=True,
+                      dropless=True, embed_std=1.0, aux_coef=0.0,
+                      gate_norm=True, expert_act="relu2", expert_gated=False,
+                      expert_down_std=0.00196, router_score="sigmoid",
+                      router_bias_rate=0.001, route_scale=2.5,
+                      shared_experts=2,
+                      layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*"
+                                    "EMEMEMEM*EMEMEMEME",
+                      ssm_state=128, ssm_conv=4, ssm_heads=64,
+                      ssm_head_dim=64, ssm_groups=8, ssm_chunk=128),
 }
 
 
@@ -432,59 +476,75 @@ def refuse_head_kinds(model, where: str) -> None:
             f"lm_parallelism sp (one device) or ep")
 
 
-# What a hybrid arch (state-space layers, tensors handed from layer to layer)
-# lacks outside ``lm_parallelism sp`` on one device, by where it is refused.
-_NO_SLOT = ("a slot that holds each state-space layer's recurrent state and "
-            "the handed-on K/V beside the per-layer cache")
-_HYBRID_LACKS = {
-    "generate.py": _NO_SLOT,
-    "serve.py": _NO_SLOT,
-    "tensor parallelism": "a layout over the model axis for the state-space "
-                          "projections, the scan's channels and the "
-                          "handed-on tensors",
-    "pipeline parallelism": "the handed-on scan output and K/V carried "
-                            "across stages, and their gradients back",
-    "expert parallelism": "an expert block for the hybrid stack (it is a "
-                          "dense model)",
-    "ring attention": "a scan whose state crosses sequence shards, and "
-                      "differential heads in the ring",
-    "decode": "differential heads and recurrent state in the decode cache",
+# What an arch whose mixers carry a recurrent state lacks outside the one
+# ``lm_parallelism`` that trains it, by the kind of state (``_state_kind``) and
+# by where it is refused. A new kind of state adds a row.
+_NO_SLOT = "a slot that holds {} beside the per-layer cache"
+_STATE_LACKS = {
+    # state-space layers and tensors handed from layer to layer
+    "hybrid": {
+        "trains under": "sp on one device",
+        **dict.fromkeys(("generate.py", "serve.py"), _NO_SLOT.format(
+            "each state-space layer's recurrent state and the handed-on K/V")),
+        "tensor parallelism": "a layout over the model axis for the "
+                              "state-space projections, the scan's channels "
+                              "and the handed-on tensors",
+        "pipeline parallelism": "the handed-on scan output and K/V carried "
+                                "across stages, and their gradients back",
+        "expert parallelism": "an expert block for the hybrid stack (it is a "
+                              "dense model)",
+        "ring attention": "a scan whose state crosses sequence shards, and "
+                          "differential heads in the ring",
+        "decode": "differential heads and recurrent state in the decode cache",
+    },
+    # linear-attention ("gdn") layers
+    "gdn": {
+        "trains under": "ep",
+        **dict.fromkeys(("generate.py", "serve.py", "decode"), _NO_SLOT.format(
+            "each linear-attention layer's matrix state and its "
+            "convolution's last inputs")),
+        "tensor parallelism": "a layout over the model axis for the linear-"
+                              "attention layers' key and value heads, their "
+                              "convolution's channels and their gates",
+        "pipeline parallelism": "stages built from blocks of more than one "
+                                "kind of mixer",
+        "ring attention": "a delta rule whose state crosses sequence shards",
+    },
+    # Mamba-2 ("mamba2") layers in blocks of one sublayer
+    "mamba2": {
+        "trains under": "ep",
+        **dict.fromkeys(("generate.py", "serve.py", "decode"), _NO_SLOT.format(
+            "each Mamba-2 layer's head-wise state and its convolution's "
+            "last inputs")),
+        "tensor parallelism": "a layout over the model axis for the Mamba-2 "
+                              "layers' heads, their groups' B and C and "
+                              "their convolution's channels, and for blocks "
+                              "that are a mixer or an expert layer alone",
+        "pipeline parallelism": "stages built from blocks that are a mixer "
+                                "or an expert layer alone, of three kinds",
+        "ring attention": "a state-space-dual walk whose state crosses "
+                          "sequence shards",
+    },
 }
 
 
-# ... and what an arch with linear-attention ("gdn") layers lacks outside
-# ``lm_parallelism ep``.
-_NO_STATE_SLOT = ("a slot that holds each linear-attention layer's matrix "
-                  "state and its convolution's last inputs beside the "
-                  "per-layer cache")
-_GDN_LACKS = {
-    "generate.py": _NO_STATE_SLOT,
-    "serve.py": _NO_STATE_SLOT,
-    "decode": _NO_STATE_SLOT,
-    "tensor parallelism": "a layout over the model axis for the linear-"
-                          "attention layers' key and value heads, their "
-                          "convolution's channels and their gates",
-    "pipeline parallelism": "stages built from blocks of more than one kind "
-                            "of mixer",
-    "ring attention": "a delta rule whose state crosses sequence shards",
-}
+def _state_kind(a: Arch) -> Optional[str]:
+    if a.hybrid:
+        return "hybrid"
+    if "gdn" in a.mixer_layers:
+        return "gdn"
+    return "mamba2" if "M" in a.layer_pattern else None
 
 
 def refuse_hybrid(arch: str, where: str) -> None:
-    """Every entry point that cannot run a hybrid arch, or one with
-    linear-attention layers, refuses it by name here, saying what is
-    missing."""
-    a = ARCHS[arch]
-    if a.hybrid:
+    """Every entry point that cannot run an arch whose mixers carry a
+    recurrent state refuses it by name here, saying what is missing."""
+    lacks = _STATE_LACKS.get(_state_kind(ARCHS[arch]), {})
+    if where in lacks:
         raise ValueError(
             f"lm_arch={arch} is not built for {where}: missing "
-            f"{_HYBRID_LACKS[where]}; train it with train_lm.py under "
-            f"lm_parallelism sp on one device")
-    if "gdn" in a.mixer_layers and where in _GDN_LACKS:
-        raise ValueError(
-            f"lm_arch={arch} is not built for {where}: missing "
-            f"{_GDN_LACKS[where]}; train it with train_lm.py under "
-            f"lm_parallelism ep")
+            f"{lacks[where]}; train it with train_lm.py under "
+            f"lm_parallelism {lacks['trains under']}")
 
 
 class EmbedRows(nn.Module):
@@ -562,23 +622,30 @@ def cached_attention(mod: nn.Module, q, k, v, length: int,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-ACTS = {"silu": nn.silu, "relu": nn.relu}
+ACTS = {"silu": nn.silu, "relu": nn.relu,
+        "relu2": lambda x: jnp.square(nn.relu(x))}
 
 
 class GatedFFN(nn.Module):
     """``(act(x Wgate) * (x Wup)) Wdown`` without biases: a hybrid arch's
     feed-forward, a dropless model's dense layer and its shared experts
-    (SwiGLU under ``silu``)."""
+    (SwiGLU under ``silu``). Not ``gated``: ``act(x Wup) Wdown``, no gate
+    projection (an arch whose experts have none)."""
     d_hidden: int
     dtype: Any = jnp.float32
     act: str = "silu"
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
-        h = ACTS[self.act](dense(self.d_hidden, "gate")(x)) \
-            * dense(self.d_hidden, "up")(x)
+        act = ACTS[self.act]
+        if self.gated:
+            h = act(dense(self.d_hidden, "gate")(x)) \
+                * dense(self.d_hidden, "up")(x)
+        else:
+            h = act(dense(self.d_hidden, "up")(x))
         return dense(x.shape[-1], "down")(h)
 
 

@@ -86,13 +86,22 @@ def _fit(dim: int, target: int, align: int) -> int:
 def _tiles(m: int, k: int, n: int, dtype, weights_dtype=None):
     """(tm, tk, tn) for [m, k] rows of ``dtype``; with ``weights_dtype``, tn
     also keeps two [k, tn] weight buffers of that dtype, and their copy in
-    ``dtype`` where the two differ, inside WEIGHTS_VMEM_BYTES."""
+    ``dtype`` where the two differ, inside WEIGHTS_VMEM_BYTES. An ``n``
+    or ``k`` that is no multiple of 128 lanes (an expert width of 1856) has no
+    tile but itself and is taken whole, whatever the table and the budget say:
+    VMEM_LIMIT_BYTES is then the only bound (1856 under K = 2688 and bfloat16
+    rows: 50 MB of the 64 MiB; tests/test_mosaic_compile.py compiles it).
+    Such an ``n`` can be the rows' last dimension (a block may span a whole
+    dimension) but not the weights': the weights come in by a hand-written
+    DMA, whose slices keep to the 128 lanes, so a caller stores them with
+    that dimension second to last and multiplies by ``gmm_t``."""
     tm, tk, tn = _TILES[jnp.dtype(dtype).itemsize]
     if weights_dtype is not None:
         rows, w = jnp.dtype(dtype), jnp.dtype(weights_dtype)
         column = k * (2 * w.itemsize + (w != rows) * rows.itemsize)
         tn = min(tn, max(WEIGHTS_VMEM_BYTES // column // 128, 1) * 128)
-    return _fit(m, tm, 8), _fit(k, tk, 128), _fit(n, tn, 128)
+    lanes = lambda dim, target: dim if dim % 128 else _fit(dim, target, 128)
+    return _fit(m, tm, 8), lanes(k, tk), lanes(n, tn)
 
 
 def _dot(a, b, *, trans_a=False, trans_b=False):
@@ -358,3 +367,44 @@ def _gmm_bwd(res, dout):
 
 
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+@jax.custom_vjp
+def gmm_t(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``gmm`` against weights stored transposed, as a checkpoint stores a
+    linear layer: rows of ``lhs`` [M, K] times their group's ``rhs[e]^T``,
+    ``rhs`` [E, N, K] -> [M, N]. The same three kernels with their roles
+    exchanged (the forward reads the weights transposed as ``gmm``'s gradient
+    to the rows does, and the reverse), so every array that is sliced along
+    its last dimension has K there: for an N that is no multiple of 128."""
+    if lhs.ndim != 2 or rhs.ndim != 3 or lhs.shape[1] != rhs.shape[2] \
+            or rhs.shape[0] != group_sizes.shape[0]:
+        raise ValueError(f"gmm_t wants lhs [M, K], rhs [E, N, K], group_sizes "
+                         f"[E]; got {lhs.shape}, {rhs.shape}, "
+                         f"{group_sizes.shape}")
+    (m, k), n = lhs.shape, rhs.shape[1]
+    return _gmm_call(lhs, rhs, group_sizes,
+                     tiles=_tiles(m, k, n, lhs.dtype, rhs.dtype),
+                     trans_rhs=True, name="moe_gmm_fwd",
+                     interpret=interpret_default())
+
+
+def _gmm_t_fwd(lhs, rhs, group_sizes):
+    return gmm_t(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _gmm_t_bwd(res, dout):
+    lhs, rhs, group_sizes = res
+    (m, k), n = lhs.shape, rhs.shape[1]
+    interpret = interpret_default()
+    dlhs = _gmm_call(dout, rhs, group_sizes,
+                     tiles=_tiles(m, n, k, dout.dtype, rhs.dtype),
+                     trans_rhs=False, name="moe_gmm_dlhs",
+                     interpret=interpret)
+    drhs = _drhs_call(dout, lhs, group_sizes,
+                      tiles=_tiles(m, n, k, dout.dtype),
+                      out_dtype=jnp.dtype(rhs.dtype), interpret=interpret)
+    return dlhs, drhs, None
+
+
+gmm_t.defvjp(_gmm_t_fwd, _gmm_t_bwd)

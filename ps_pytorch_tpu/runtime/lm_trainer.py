@@ -14,7 +14,7 @@ held-out next-token-loss oracle):
 - ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
   + ``parallel/ep.py``); also how an MoE model is run on one chip.
   ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe,
-  smallthinker, trinity, qwen3next; ``--lm-experts-held`` trains one chip's
+  smallthinker, trinity, qwen3next, nemotronh; ``--lm-experts-held`` trains one chip's
   share of the experts, ``--lm-dense-layers`` starts the stack with dense
   layers).
 
@@ -201,6 +201,13 @@ class LMTrainer:
             kernels.append("gdn_mix[" + mix_schedule(
                 rows, cfg.lm_seq_len, arch.gdn_key_heads,
                 arch.gdn_value_heads, arch.gdn_key_dim, arch.gdn_conv,
+                itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
+                + "]")
+        if "M" in arch.layer_pattern:
+            from ps_pytorch_tpu.ops.ssd import ssd_schedule
+            kernels.append("ssd[" + ssd_schedule(
+                rows, cfg.lm_seq_len, arch.ssm_heads, arch.ssm_head_dim,
+                arch.ssm_state, arch.ssm_groups, chunk=arch.ssm_chunk,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
         if arch.dropless:
@@ -464,7 +471,8 @@ class LMTrainer:
             # moe_held_share; under a selection bias moe_bias_abs_max and
             # moe_load_all_max_over_mean) and what the model counted (a
             # hybrid arch's ssm_state_abs_max and diff_lambda_max under sp,
-            # a linear-attention arch's gdn_state_abs_max under ep) come
+            # a linear-attention arch's gdn_state_abs_max and a Mamba-2
+            # arch's ssd_state_abs_max under ep) come
             # with the loss.
             loss = own.pop("loss")
             derived = derive_step_record(

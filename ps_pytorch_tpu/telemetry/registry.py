@@ -498,6 +498,9 @@ HYBRID_GAUGES = (
      "matrix states at the delta rule's chunk boundaries: bounded under unit "
      "keys and beta <= 1, so growth is a wrong kernel or a learning rate too "
      "high"),
+    ("ssd_state_abs_max", "", "largest |h| over the Mamba-2 layers' head-wise "
+     "states at the state-space-dual form's chunk boundaries: the walk's "
+     "numerical health"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

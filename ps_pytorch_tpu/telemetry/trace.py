@@ -76,9 +76,10 @@ DEVICE_SCOPES = (
     "attn_pos",      # q/k norm, RoPE, to_heads and its inverse, the gate's product; differential heads' lambda, difference and norm
     "attn_core",     # flash / full / ring / cached attention
     "ffn",           # a dense feed-forward: norm, its matmuls, activation
-    "ssm_proj",      # a Mamba layer's norm, in / x / dt / out projections, the residual sum
-    "ssm_conv",      # ... its causal convolution, silu, softplus, the gate's product
-    "ssm_scan",      # ... the recurrence, forward and backward, with the copies into and out of its layout
+    "ssm_proj",      # a Mamba (-1 or -2) layer's norm, in / x / dt / out projections, the residual sum
+    "ssm_conv",      # ... its causal convolution, silu, softplus, the gate's product (Mamba-2: the gated group norm)
+    "ssm_scan",      # ... Mamba-1's recurrence, forward and backward, with the copies into and out of its layout
+    "ssd_core",      # ... Mamba-2's state-space-dual form: both kernels, the running sum of dt A, the copies into and out of the kernels' layout
     "gmu",           # a gated memory unit whole: norm, both projections, the product with the handed-on scan output
     "gdn_proj",      # a Gated DeltaNet layer's norm, qkvz / ba / out projections, the residual sum
     "gdn_mix",       # ... its causal convolution and silu, beta, the gate, the l2 norms and q's scale, the gated output norm

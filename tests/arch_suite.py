@@ -826,7 +826,7 @@ def install(namespace, case):
             assert "Traceback" not in message
             for word in words:
                 assert word in message, (word, message)
-            if case.tiny_row.hybrid or "gdn" in case.tiny_row.mixer_layers:
+            if tr_mod._state_kind(case.tiny_row):   # ... and no other arch
                 refuse_hybrid("gpt2", words[0].rsplit(" for ", 1)[1])
         common["test_every_other_entry_point_refuses_the_arch_by_name"] = \
             test_every_other_entry_point_refuses_the_arch_by_name

@@ -1,13 +1,14 @@
 """``ops/grouped_matmul.py:gmm`` (interpreted): forward and both gradients
 against a loop over the groups, ragged and empty groups and rows past the last
-one; bfloat16 rows on float32 weights."""
+one; bfloat16 rows on float32 weights; ``gmm_t`` against weights stored
+transposed; a width off the 128 lanes is taken whole."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ps_pytorch_tpu.ops.grouped_matmul import gmm
+from ps_pytorch_tpu.ops.grouped_matmul import _tiles, gmm, gmm_t
 
 GROUPS = {
     "ragged": [5, 0, 19, 3, 0, 13],
@@ -40,6 +41,47 @@ def test_gmm_and_both_gradients_against_a_loop(name):
     assert not np.asarray(got[off[-1]:]).any()
     for a, b in zip(vjp(cot), vjp_want(cot)):
         np.testing.assert_allclose(a, b, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows", ["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(GROUPS))
+def test_gmm_t_is_gmm_on_the_transposed_weights(name, rows):
+    """Weights stored [E, N, K], as a checkpoint stores a linear layer: the
+    forward and both gradients are ``gmm``'s on ``swapaxes(rhs, 1, 2)``, the
+    weights' gradient in the stored layout; float32 to the last bits,
+    bfloat16 rows within their own rounding."""
+    sizes = jnp.asarray(GROUPS[name], jnp.int32)
+    m, k, n, g = 40, 16, 24, len(GROUPS[name])
+    ks = jax.random.split(jax.random.key(7), 3)
+    lhs = jax.random.normal(ks[0], (m, k)).astype(rows)
+    rhs_t = jax.random.normal(ks[1], (g, n, k))
+    cot = jax.random.normal(ks[2], (m, n)).astype(rows)
+    got, vjp = jax.vjp(lambda a, b: gmm_t(a, b, sizes), lhs, rhs_t)
+    want, vjp_want = jax.vjp(
+        lambda a, b: gmm(a, jnp.swapaxes(b, 1, 2), sizes), lhs, rhs_t)
+    tol = 1e-5 if rows == "float32" else 0.1
+    f32 = lambda a: np.asarray(a, np.float32)
+    assert got.dtype == lhs.dtype and got.shape == (m, n)
+    np.testing.assert_allclose(f32(got), f32(want), atol=tol)
+    for a, b in zip(vjp(cot), vjp_want(cot)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(f32(a), f32(b), atol=tol)
+    with pytest.raises(ValueError, match="rhs \\[E, N, K\\]"):
+        gmm_t(lhs, jnp.swapaxes(rhs_t, 1, 2), sizes)
+
+
+def test_a_width_off_the_128_lanes_is_taken_whole():
+    """1856 = 14.5 x 128 has no tile but itself, as N or as K, whatever the
+    table's target and the weights' budget say; widths on the lanes tile as
+    they did."""
+    assert _tiles(18432, 2688, 1856, jnp.bfloat16, jnp.float32) \
+        == (256, 896, 1856)
+    assert _tiles(18432, 1856, 2688, jnp.bfloat16, jnp.float32) \
+        == (256, 1856, 896)
+    assert _tiles(18432, 2688, 1856, jnp.float32, jnp.float32)[2] == 1856
+    assert _tiles(18432, 1856, 2688, jnp.bfloat16) == (256, 1856, 896)
+    assert _tiles(32768, 2048, 1024, jnp.bfloat16, jnp.float32) \
+        == (256, 2048, 1024)
 
 
 @pytest.mark.parametrize("k_tiles", [1, 2])

@@ -1,6 +1,7 @@
-"""The OLMoE cell's grouped matmuls, the SmallThinker, Trinity, Phi-4-flash and
-Qwen3-Next cells' flash kernels, the Phi-4-flash cell's selective scan and the
-Qwen3-Next cell's gated delta rule and mixer chains compile under
+"""The OLMoE cell's grouped matmuls, the SmallThinker, Trinity, Phi-4-flash,
+Qwen3-Next and Nemotron-3-Nano cells' flash kernels, the Phi-4-flash cell's
+selective scan, the Qwen3-Next cell's gated delta rule and mixer chains and
+the Nemotron-3-Nano cell's state-space-dual kernels compile under
 Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses; and, under ``-m slow``, the
@@ -575,6 +576,182 @@ def test_the_qwen3next_cells_whole_step_fits_by_the_rule(one_chip,
     planned = (m.argument_size_in_bytes + m.output_size_in_bytes
                - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert 12 * 2 ** 30 < planned < 14.5 * 2 ** 30
+
+
+def test_ungated_expert_ffn_at_width_1856_compiles_at_the_cells_shape(
+        one_chip, monkeypatch):
+    """nemotron3nano_s16384_1chip: ``relu(x Wup^T)^2 Wdown`` over ragged
+    groups and its gradients, 18,432 bfloat16 rows on 16 experts' float32
+    weights, BOTH stored [16, 1856, 2688]: the six calls of ``gmm_t`` and
+    ``gmm``. 1856 is no multiple of 128: it is a whole tile (two float32
+    weight buffers of [1856, 2688] and their bfloat16 copy are 50 MB of the
+    64 MiB limit), and no weight array is sliced along it by a DMA."""
+    from ps_pytorch_tpu.ops.grouped_matmul import gmm_t
+    monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+    rows, d, f, held = 18432, 2688, 1856, 16
+
+    def loss(xs, wu, wd, gs):
+        h = jnp.square(jax.nn.relu(gmm_t(xs, wu, gs)))
+        return jnp.sum(gmm(h, wd, gs).astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg((rows, d), jnp.bfloat16), arg((held, f, d), jnp.float32),
+        arg((held, f, d), jnp.float32), arg((held,), jnp.int32)).compile()
+    text = compiled.as_text()
+    for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        assert text.count(f"{name}") >= 2, name
+    assert _tiles(rows, d, f, jnp.bfloat16, jnp.float32) == (256, 896, f)
+    assert 2 * d * f * 4 + d * f * 2 < VMEM_LIMIT_BYTES
+
+
+# nemotron3nano_s16384_1chip: the one attention layer, 32 query heads on 2
+# key/value heads of 128 at S=16384: 16 query heads a K/V head
+def test_flash_at_sixteen_query_heads_a_kv_head_compiles(one_chip):
+    """Twice the widest group so far (8 in Trinity and Qwen3-Next): the
+    forward holds a K/V head whole and walks its 16 query heads, the backward
+    puts the group along its grid; dK and dV come back at the 2 K/V heads."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_attention, flash_schedule,
+    )
+    b, h, h_kv, s, d = 1, 32, 2, 16384, 128
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True,
+                                       interpret=False).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(h_kv), arg(h_kv)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv"):
+        assert f"{name}_" in text
+    assert "flash_win_" not in text
+    assert f"bf16[{b * h_kv},{s},{d}]" in text
+    sched = flash_schedule(b * h, s, d, 2, True, bh_kv=b * h_kv)
+    assert (sched.group, sched.g, sched.window) == (16, 16, 0)
+
+
+# ... and its four Mamba-2 layers: 64 heads of 64 with a [64, 128] state, B and
+# C in 8 groups, chunks of 128
+SSD_CELL = dict(b=1, s=16384, heads=64, p=64, groups=8, n=128)
+
+
+def test_ssd_compiles_at_the_cells_shape(one_chip):
+    """``ssd_fwd`` and ``ssd_bwd`` at the cell's shape, bfloat16 rows with a
+    float32 dt: two heads share a slab's 128 lanes, eight a grid step; no XLA
+    op round them hands on a float32 tensor a chunk wide ([.., 128, 128]
+    masks or scores) or a copy of x, B or C in another layout, and one
+    forward and backward move under 3.5 GB (the inputs, y, the gradients and
+    the kept states, 268 MB of them)."""
+    from ps_pytorch_tpu.ops.ssd import ssd, ssd_schedule
+    c = SSD_CELL
+    b, s, heads, p, groups, n = (c[k] for k in ("b", "s", "heads", "p",
+                                                  "groups", "n"))
+
+    def loss(x, dt, a, bb, cc, d):
+        y, top = ssd(x, dt, a, bb, cc, d, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)) + top
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        arg((b, s, heads, p)), arg((b, s, heads), f32), arg((heads,), f32),
+        arg((b, s, groups, n)), arg((b, s, groups, n)),
+        arg((heads,), f32)).compile()
+    text = compiled.as_text()
+    sched = ssd_schedule(b, s, heads, p, n, groups)
+    assert (sched.chunk, sched.chunks, sched.heads_a_step,
+            sched.heads_a_slab) == (128, 128, 8, 2)
+    assert sched.kept_bytes == 128 * 64 * 64 * 128 * 4      # 268 MB a layer
+    ops = _entry_ops(text)
+    mosaic = [op for op in ops if op[2]]
+    assert ["ssd_fwd" in mosaic[0][0], "ssd_bwd" in mosaic[1][0],
+            len(mosaic)] == [True, True, 2]
+    # the schedule's byte counts are the calls' own operands and results (but
+    # the counter's 2 MB of state maxima, which the schedule leaves out)
+    assert [op[4] for op in mosaic] == [
+        sched.fwd_bytes + b * heads * p * n * 4, sched.bwd_bytes]
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        if not is_mosaic:       # no mask or score a chunk wide, no float32 row
+            for dtype, dims in shapes:
+                assert not (dtype == "f32" and (
+                    dims.endswith(",128,128,128")
+                    or dims.startswith(f"{b},{s},"))), (name, opcode, dims)
+    assert sum(op[4] for op in ops) < 3.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.8 * 2 ** 30
+
+
+@pytest.mark.slow    # a whole step at the cell's size
+def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
+                                                             monkeypatch):
+    """The ep step as ``LMTrainer`` builds it from the cell's own flags,
+    compiled for the described chip from shapes alone: the configuration's
+    rule (``cut.rule``: under 14.5 GiB by ``memory_analysis()`` with 16 of
+    the 128 experts held) holds, and the flash calls at 16 query heads a K/V
+    head, both state-space-dual kernels and the grouped matmuls are in it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from ps_pytorch_tpu.config import config_from_args
+    from ps_pytorch_tpu.optim.schedules import build_schedule
+    from ps_pytorch_tpu.optim.sgd import sgd
+    from ps_pytorch_tpu.parallel import ep
+    from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
+    for name in ("flash_attention", "ssd"):
+        monkeypatch.setattr(
+            importlib.import_module("ps_pytorch_tpu.ops." + name),
+            "_interpret_default", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3_nano_30b_a3b.json")) as f:
+        argv = json.load(f)["program_args"]
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "s16384_ssd_1chip.json")) as f:
+        argv = argv + json.load(f)["args"]
+    cfg = config_from_args(argv)
+    assert (cfg.lm_experts, cfg.lm_experts_held) == (128, 16)
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    model = build_lm_model(cfg, attention_impl="flash", ep_axis="data")
+    tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
+             weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
+    shapes = jax.eval_shape(
+        partial(ep.create_ep_train_state, model, tx, mesh,
+                (cfg.batch_size, cfg.lm_seq_len)), jax.random.key(0))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(shapes.params)) == 986_254_336
+    state = jax.tree.map(
+        lambda a, spec: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, ep.ep_state_specs(shapes, "data"))
+    tokens = jax.ShapeDtypeStruct(
+        (cfg.batch_size, cfg.lm_seq_len), jnp.int32,
+        sharding=NamedSharding(mesh, P("data", None)))
+    compiled = ep.make_ep_train_step(
+        model, tx, mesh, shapes, remat=cfg.remat,
+        donate=cfg.donate).lower(state, tokens).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv", "ssd_fwd", "ssd_bwd",
+                 "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        assert text.count(f"%{name}.") > 0, name
+    assert "flash_win_" not in text
+    m = compiled.memory_analysis()
+    planned = (m.argument_size_in_bytes + m.output_size_in_bytes
+               - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print("PLANNED", planned / 2 ** 30, "GiB; arguments",
+          m.argument_size_in_bytes / 2 ** 30, "temporaries",
+          m.temp_size_in_bytes / 2 ** 30, "code",
+          m.generated_code_size_in_bytes / 1e6, "MB")
+    assert 10 * 2 ** 30 < planned < 14.5 * 2 ** 30
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():
