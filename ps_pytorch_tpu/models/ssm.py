@@ -31,18 +31,30 @@ Mamba-2 (arXiv:2405.21060; ``d_inner = heads * head_dim`` whatever ``d`` is,
 ``G`` groups of ``N`` states):
 
     [z, xBC, dt] = in_proj(a)                   d -> d_inner + (d_inner + 2 G N) + heads, no bias
-    xBC    = silu(conv1d(xBC))                  depthwise, causal, d_conv taps, bias
-    [x, B, C] = xBC                             x: heads of head_dim; B, C: G groups of N, head h reads group h // (heads / G)
+    xBC    = silu(conv1d(xBC))                  depthwise, causal, d_conv taps, bias                        }
+    [x, B, C] = xBC                             x: heads of head_dim; B, C: G groups of N, head h reads group h // (heads / G)     } conv_bias_silu
     delta  = softplus(dt + dt_bias)             a head, float32, no clamp
     A      = -exp(A_log)                        a scalar a head
     y      = ssd(x, delta, A, B, C, D)          ops/ssd.py; D a scalar a head
-    y      = GroupRMSNorm(y * silu(z))          the gate FIRST, then the norm over each of G groups of d_inner / G; one scale [d_inner]
+    y      = GroupRMSNorm(y * silu(z))          the gate FIRST, then the norm over each of G groups of d_inner / G; one scale [d_inner]    } gated_group_norm
     out    = out_proj(y)                        d_inner -> d, no bias
 
-Precision: the projections and the convolution in the compute dtype; ``delta``,
-``A``, the state and the gated norm (its product with ``silu(z)``, its
-statistics and its scale) float32; ``x``, ``B``, ``C`` enter the kernels in
-the compute dtype and ``y`` leaves in it.
+The two bracketed chains are one op each, ``ops/ssm_mix.py`` (Pallas, since
+PR 45; the convolution's kernels are ``ops/gdn_mix.py``'s): each makes one
+pass over HBM forward and one backward and keeps its inputs alone for the
+backward. The plain chain they replace (``jax.numpy`` ops on
+``causal_conv1d``, differentiated by JAX) lives on in
+``tests/test_ssm_mix.py`` as what they are held to; ``causal_conv1d`` itself
+stays ``mamba_sublayer``'s.
+
+Precision: the projections in the compute dtype; ``delta``, ``A`` and the
+state float32; inside an op's tile everything is float32: the convolution's
+products, their sum and the bias (the plain chain rounded each tap's
+multiply-add to the compute dtype), the SiLU, the gate's product, the norm's
+statistics and its scale, and the gradients of the convolution's weight and
+bias and of the scale, summed over the tokens. ``x``, ``B``, ``C``, the normed
+rows and the gradients that leave an op are rounded to the compute dtype
+once; ``y`` leaves ``ssd`` in it.
 """
 
 import math
@@ -156,6 +168,7 @@ def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
     whose scope holds the parameters."""
     # where the arch asks for it: the other archs' start-up does not pay for it
     from ps_pytorch_tpu.ops.ssd import ssd
+    from ps_pytorch_tpu.ops.ssm_mix import conv_bias_silu, gated_group_norm
 
     bt, s, d = x.shape
     d_inner, bc = heads * head_dim, groups * d_state
@@ -169,8 +182,8 @@ def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
         init = _symmetric_uniform(d_conv ** -0.5)
         conv_w = mod.param("conv_weight", init, (d_conv, d_inner + 2 * bc))
         conv_b = mod.param("conv_bias", init, (d_inner + 2 * bc,))
-        u, b, c = jnp.split(nn.silu(causal_conv1d(xbc, conv_w, conv_b)),
-                            [d_inner, d_inner + bc], axis=-1)
+        u, b, c = conv_bias_silu(xbc, conv_w, conv_b,
+                                 widths=(d_inner, bc, bc))
         dt_bias = mod.param("dt_bias", _dt_bias_init, (heads,))
         delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
     a_log = mod.param("A_log", _a_log_init, (heads,))
@@ -182,13 +195,9 @@ def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
                            c.reshape(bt, s, groups, d_state), skip,
                            chunk=chunk)
     with device_scope("ssm_conv"):
-        scale = _NormScale(name="ssm_norm")(d_inner)
-        g = y.reshape(bt, s, d_inner).astype(jnp.float32) \
-            * nn.silu(z.astype(jnp.float32))
-        g = g.reshape(bt, s, groups, d_inner // groups)
-        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                              + norm_eps)
-        g = (g.reshape(bt, s, d_inner) * scale).astype(dtype)
+        g = gated_group_norm(y.reshape(bt, s, d_inner), z,
+                             _NormScale(name="ssm_norm")(d_inner),
+                             groups=groups, eps=norm_eps)
     with device_scope("ssm_proj"):
         x = x + dense(d, "out_proj")(g)
     return x, a, {"ssd_state_abs_max": state_max}

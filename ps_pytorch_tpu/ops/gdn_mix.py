@@ -46,6 +46,14 @@ and batch rows (eight partial rows a tap, which XLA adds). The three
 segments (q, k, v) are three calls a direction, each with its own constants;
 the gated norm is one.
 
+The convolution's kernel pair serves the Mamba-2 mixer too
+(``ops/ssm_mix.py:conv_bias_silu``): what differs between the two uses is a
+``ConvChain``'s static fields and nothing else: a bias (the row after the
+taps' in the weight operand, its gradient a partial row beside theirs), the
+segments and which of them are normalised, and whether the outputs leave
+head-major or token-major (a block spec). Without a bias the kernels trace to
+the same bodies as before there was one.
+
 ``mix_schedule`` says what the calls hold and move; the trainer prints it on
 its ``KERNELS`` line. On one v5e at 16384 tokens, 16 key and 32 value heads
 of 128, in the cell's step (PERF.md section 5, PR 41): the three forward
@@ -110,13 +118,23 @@ class MixSchedule(NamedTuple):
                 f"norm_bwd_bytes={self.norm_bwd_bytes}")
 
 
+def _rows_a_step(s: int, lanes: int):
+    """(rows a step, rows of them worked on at a time) at ``lanes`` channels
+    a step. A step wider than LANES (a Mamba-2 group: ``ops/ssm_mix.py``)
+    takes as many fewer rows, so a step and a trip of its loop hold the same
+    elements."""
+    wide = max(lanes // LANES, 1)
+    rows = min(max(ROWS // wide, HALO), -(-s // HALO) * HALO)
+    # the most rows up to CHUNK that divide a step's, whole registers of them
+    chunk = next(c for c in range(min(rows, max(CHUNK // wide, SUB)), 0, -SUB)
+                 if rows % c == 0)
+    return rows, chunk
+
+
 def _tiles(s: int, key_heads: int, value_heads: int, d: int):
     """(rows a step, rows of them worked on at a time, heads a step)."""
-    rows = min(ROWS, -(-s // HALO) * HALO)
     heads = math.gcd(key_heads, value_heads, max(LANES // d, 1))
-    # the most rows up to CHUNK that divide a step's, whole registers of them
-    chunk = next(c for c in range(min(rows, CHUNK), 0, -SUB) if rows % c == 0)
-    return rows, chunk, heads
+    return (*_rows_a_step(s, heads * d), heads)
 
 
 def mix_schedule(batch: int, s: int, key_heads: int, value_heads: int,
@@ -187,17 +205,27 @@ def _weighted(xs, w):
     return _tree_sum(x * wk for x, wk in zip(xs, w))
 
 
-def _taps(w, xbuf, r0, n, lanes):
+def _taps(w, bias, xbuf, r0, n, lanes):
     """The convolution's ``len(w)`` shifted copies of rows ``r0 .. r0 + n -
     1`` of the tile (tap k reads ``K - 1 - k`` rows back) and their weighted
-    sum. ``xbuf`` holds the tile behind HALO rows of the one before."""
+    sum, under the ``bias`` row where there is one. ``xbuf`` holds the tile
+    behind HALO rows of the one before."""
     taps = len(w)
     xs = _shifted(xbuf, r0 + HALO - SUB, n, lanes,
                   [SUB - (taps - 1) + k for k in range(taps)])
-    return xs, _weighted(xs, w)
+    c = _weighted(xs, w)
+    return xs, c if bias is None else c + bias
 
 
-def _conv_fwd_kernel(w_ref, u_ref, before_ref, o_ref, xbuf, *, taps, heads,
+def _weight_rows(w_ref, lanes, biased):
+    """The taps' rows of the weight operand and, where the convolution has a
+    bias, the row after them that holds it."""
+    taps = w_ref.shape[0] - biased
+    return ([w_ref[k:k + 1, lanes] for k in range(taps)],
+            w_ref[taps:taps + 1, lanes] if biased else None)
+
+
+def _conv_fwd_kernel(w_ref, u_ref, before_ref, o_ref, xbuf, *, biased, heads,
                      d, chunk, unit, scale):
     rows = u_ref.shape[0]
     xbuf[:HALO] = jnp.where(pl.program_id(2) == 0, 0.0,
@@ -205,10 +233,10 @@ def _conv_fwd_kernel(w_ref, u_ref, before_ref, o_ref, xbuf, *, taps, heads,
     xbuf[HALO:] = u_ref[...].astype(_F32)
     for h in range(heads):
         lanes = pl.ds(h * d, d)
-        w = [w_ref[k:k + 1, lanes] for k in range(taps)]
+        w, bias = _weight_rows(w_ref, lanes, biased)
 
-        def step(r0, carry, h=h, lanes=lanes, w=w):
-            _, c = _taps(w, xbuf, r0, chunk, lanes)
+        def step(r0, carry, h=h, lanes=lanes, w=w, bias=bias):
+            _, c = _taps(w, bias, xbuf, r0, chunk, lanes)
             y = c * _sigmoid(c)
             if unit:
                 y = y * (jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True)
@@ -219,11 +247,11 @@ def _conv_fwd_kernel(w_ref, u_ref, before_ref, o_ref, xbuf, *, taps, heads,
         _each_chunk(rows, chunk, step)
 
 
-def _pull_back(w, xbuf, r0, n, lanes, g, unit, scale):
+def _pull_back(w, bias, xbuf, r0, n, lanes, g, unit, scale):
     """``(shifted copies, dc)`` of rows ``r0 .. r0 + n - 1``: the chain
     formed again from ``xbuf`` and the cotangent ``g`` pulled back through
     the norm and the SiLU."""
-    xs, c = _taps(w, xbuf, r0, n, lanes)
+    xs, c = _taps(w, bias, xbuf, r0, n, lanes)
     sig = _sigmoid(c)
     y = c * sig
     if unit:
@@ -235,9 +263,10 @@ def _pull_back(w, xbuf, r0, n, lanes, g, unit, scale):
 
 
 def _conv_bwd_kernel(w_ref, u_ref, before_ref, after_ref, g_ref, g_after_ref,
-                     du_ref, dw_ref, xbuf, dcbuf, *, taps, heads, d, chunk,
+                     du_ref, dw_ref, xbuf, dcbuf, *, biased, heads, d, chunk,
                      s, unit, scale):
     rows = u_ref.shape[0]
+    taps = w_ref.shape[0] - biased
     tile = pl.program_id(2)
 
     @pl.when((pl.program_id(1) == 0) & (tile == 0))
@@ -256,24 +285,27 @@ def _conv_bwd_kernel(w_ref, u_ref, before_ref, after_ref, g_ref, g_after_ref,
                                    after_ref[...].astype(_F32), 0.0)
     for h in range(heads):
         lanes = pl.ds(h * d, d)
-        w = [w_ref[k:k + 1, lanes] for k in range(taps)]
+        w, bias = _weight_rows(w_ref, lanes, biased)
 
-        def pull(r0, sums, h=h, lanes=lanes, w=w):
+        def pull(r0, sums, h=h, lanes=lanes, w=w, bias=bias):
             g = g_ref[h, pl.ds(r0, chunk), :].astype(_F32)
             if ragged:
                 g = jnp.where(inside(r0, chunk), g, 0.0)
-            xs, dc = _pull_back(w, xbuf, r0, chunk, lanes, g, unit, scale)
+            xs, dc = _pull_back(w, bias, xbuf, r0, chunk, lanes, g, unit,
+                                scale)
             dcbuf[pl.ds(r0, chunk), lanes] = dc
-            return tuple(acc + _fold(dc * x) for acc, x in zip(sums, xs))
+            # a tap's gradient is dc on its shifted copy; the bias's, dc
+            return tuple(acc + _fold(dc * x) for acc, x in zip(sums, xs)) \
+                + ((sums[taps] + _fold(dc),) if biased else ())
 
         sums = _each_chunk(rows, chunk, pull, tuple(
-            jnp.zeros((SUB, d), _F32) for _ in range(taps)))
-        for k in range(taps):
-            dw_ref[k, :, lanes] += sums[k]
+            jnp.zeros((SUB, d), _F32) for _ in range(taps + biased)))
+        for k, total in enumerate(sums):
+            dw_ref[k, :, lanes] += total
         # the next tile's first rows: their dc reaches this tile's last rows
         g = jnp.where(inside(rows, HALO), g_after_ref[h].astype(_F32), 0.0)
-        dcbuf[rows:, lanes] = _pull_back(w, xbuf, rows, HALO, lanes, g, unit,
-                                         scale)[1]
+        dcbuf[rows:, lanes] = _pull_back(w, bias, xbuf, rows, HALO, lanes, g,
+                                         unit, scale)[1]
 
         def push(r0, carry, lanes=lanes, w=w):
             du = _weighted(_shifted(dcbuf, r0, chunk, lanes,
@@ -285,11 +317,24 @@ def _conv_bwd_kernel(w_ref, u_ref, before_ref, after_ref, g_ref, g_after_ref,
 
 
 def _segments(key_heads, value_heads, d):
-    """(first head, heads, normalised?, the length a head is scaled to) of
-    q, k and v in the projection's ``[q | k | v]``."""
-    return ((0, key_heads, True, 1.0 / math.sqrt(d)),
-            (key_heads, key_heads, True, 1.0),
-            (2 * key_heads, value_heads, False, 1.0))
+    """(name, first head, heads, normalised?, the length a head is scaled to)
+    of q, k and v in the projection's ``[q | k | v]``."""
+    return (("q", 0, key_heads, True, 1.0 / math.sqrt(d)),
+            ("k", key_heads, key_heads, True, 1.0),
+            ("v", 2 * key_heads, value_heads, False, 1.0))
+
+
+class ConvChain(NamedTuple):
+    """What tells one use of the convolution's kernels from another, all of
+    it static: the calls' names, the segments of the channels (a call each
+    way a segment), a head's width and the heads a grid step, whether the
+    weight operand's last row is a bias, and how the outputs leave."""
+    name: str           # the calls are <name>_fwd_<segment>, <name>_bwd_<segment>
+    segments: tuple     # ((name, first head, heads, normalised?, scale), ...)
+    d: int
+    heads: int
+    biased: bool
+    token_major: bool   # [B, S, heads d] (one head a step), else [B, heads, S, d]
 
 
 def _halo_blocks(s, rows):
@@ -301,7 +346,7 @@ def _halo_blocks(s, rows):
             lambda i: jnp.minimum((i + 1) * per, last))
 
 
-def _conv_specs(s, taps, rows, lanes, first, order):
+def _conv_specs(s, w_rows, rows, lanes, first, order):
     """Block specs of one segment's calls: the weight's, the tile's, and the
     HALO rows before and after it. ``first``: the segment's first channel
     tile; ``order`` maps a grid step to (batch, channel tile, sequence
@@ -311,7 +356,7 @@ def _conv_specs(s, taps, rows, lanes, first, order):
     def spec(shape, index):
         return pl.BlockSpec(shape, lambda *g: index(*order(*g)))
 
-    return (spec((taps, lanes), lambda b, j, i: (0, first + j)),
+    return (spec((w_rows, lanes), lambda b, j, i: (0, first + j)),
             spec((None, rows, lanes), lambda b, j, i: (b, i, first + j)),
             spec((None, HALO, lanes),
                  lambda b, j, i: (b, before(i), first + j)),
@@ -319,97 +364,123 @@ def _conv_specs(s, taps, rows, lanes, first, order):
                  lambda b, j, i: (b, after(i), first + j)))
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
-def _conv_fwd_call(qkv, w, segment, tiles, d, interpret, name):
-    first, n_heads, unit, scale = segment
-    rows, chunk, heads = tiles
+def _heads_layout(chain, bt, n_heads, s):
+    """How a segment's output (and its cotangent) lies in HBM, as the
+    kernels' ``[heads, rows, d]`` blocks see it: ``(shape, spec)`` with
+    ``spec(rows, at)`` the block of ``rows`` tokens that ``at(*grid step) ->
+    (batch, channel tile, row block)`` names. Head-major ``[B, H, S, d]``;
+    token-major the same rows under ONE head as wide as the segment, ``[B, 1,
+    S, H d]``, a reshape of ``[B, S, H d]``."""
+    heads, d = chain.heads, chain.d
+    if chain.token_major:
+        place = lambda b, j, i: (b, 0, i, j)
+        shape = (bt, 1, s, n_heads * d)
+    else:
+        place = lambda b, j, i: (b, j, i, 0)
+        shape = (bt, n_heads, s, d)
+    return shape, lambda rows, at: pl.BlockSpec(
+        (None, heads, rows, d), lambda *g: place(*at(*g)))
+
+
+@partial(jax.jit, static_argnums=(2, 3, 4, 5))
+def _conv_fwd_call(qkv, w, chain, segment, tiles, interpret):
+    name, first, n_heads, unit, scale = segment
+    rows, chunk = tiles
+    heads, d = chain.heads, chain.d
     bt, s, _ = qkv.shape
-    taps, lanes = w.shape[0], heads * d
     same = lambda b, j, i: (b, j, i)
-    w_spec, tile, before, _ = _conv_specs(s, taps, rows, lanes,
+    w_spec, tile, before, _ = _conv_specs(s, w.shape[0], rows, heads * d,
                                           first // heads, same)
+    shape, out = _heads_layout(chain, bt, n_heads, s)
     return pl.pallas_call(
-        partial(_conv_fwd_kernel, taps=taps, heads=heads, d=d, chunk=chunk,
-                unit=unit, scale=scale),
+        partial(_conv_fwd_kernel, biased=chain.biased, heads=heads, d=d,
+                chunk=chunk, unit=unit, scale=scale),
         grid=(bt, n_heads // heads, -(-s // rows)),
         in_specs=[w_spec, tile, before],
-        out_specs=pl.BlockSpec((None, heads, rows, d),
-                               lambda b, j, i: (b, j, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bt, n_heads, s, d), qkv.dtype),
-        scratch_shapes=[pltpu.VMEM((HALO + rows, lanes), _F32)],
+        out_specs=out(rows, same),
+        out_shape=jax.ShapeDtypeStruct(shape, qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((HALO + rows, heads * d), _F32)],
         compiler_params=_compiler_params("parallel"),
-        interpret=interpret, name=name)(w, qkv, qkv)
+        interpret=interpret, name=f"{chain.name}_fwd_{name}")(w, qkv, qkv)
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _conv_bwd_call(qkv, w, g, segment, tiles, d, interpret, name):
-    """-> (d qkv of the segment's channels ``[B, S, heads d]``, its dW as
-    eight partial rows a tap ``[K, SUB, heads d]``)."""
-    first, n_heads, unit, scale = segment
-    rows, chunk, heads = tiles
+@partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _conv_bwd_call(qkv, w, g, chain, segment, tiles, interpret):
+    """-> (d qkv of the segment's channels ``[B, S, heads d]``, the gradient
+    of its rows of the weight operand as eight partial rows each ``[K, SUB,
+    heads d]``)."""
+    name, first, n_heads, unit, scale = segment
+    rows, chunk = tiles
+    heads, d = chain.heads, chain.d
     bt, s, _ = qkv.shape
-    taps, lanes = w.shape[0], heads * d
+    lanes = heads * d
     # the channel tile outermost: a tile's dW block stays while the batch
     # rows and sequence tiles under it are summed
     order = lambda j, b, i: (b, j, i)
     after = _halo_blocks(s, rows)[1]
+    _, cotangent = _heads_layout(chain, bt, n_heads, s)
     return pl.pallas_call(
-        partial(_conv_bwd_kernel, taps=taps, heads=heads, d=d, chunk=chunk,
-                s=s, unit=unit, scale=scale),
+        partial(_conv_bwd_kernel, biased=chain.biased, heads=heads, d=d,
+                chunk=chunk, s=s, unit=unit, scale=scale),
         grid=(n_heads // heads, bt, -(-s // rows)),
         in_specs=[
-            *_conv_specs(s, taps, rows, lanes, first // heads, order),
-            pl.BlockSpec((None, heads, rows, d),
-                         lambda j, b, i: (b, j, i, 0)),
-            pl.BlockSpec((None, heads, HALO, d),
-                         lambda j, b, i: (b, j, after(i), 0)),
+            *_conv_specs(s, w.shape[0], rows, lanes, first // heads, order),
+            cotangent(rows, order),
+            cotangent(HALO, lambda j, b, i: (b, j, after(i))),
         ],
         out_specs=[
             pl.BlockSpec((None, rows, lanes), lambda j, b, i: (b, i, j)),
-            pl.BlockSpec((taps, SUB, lanes), lambda j, b, i: (0, 0, j)),
+            pl.BlockSpec((w.shape[0], SUB, lanes), lambda j, b, i: (0, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bt, s, n_heads * d), qkv.dtype),
-            jax.ShapeDtypeStruct((taps, SUB, n_heads * d), _F32),
+            jax.ShapeDtypeStruct((w.shape[0], SUB, n_heads * d), _F32),
         ],
         scratch_shapes=[pltpu.VMEM((HALO + rows + HALO, lanes), _F32),
                         pltpu.VMEM((rows + HALO, lanes), _F32)],
         compiler_params=_compiler_params("arbitrary"),
-        interpret=interpret, name=name)(w, qkv, qkv, qkv, g, g)
+        interpret=interpret,
+        name=f"{chain.name}_bwd_{name}")(w, qkv, qkv, qkv, g, g)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _conv(qkv, w, key_heads, value_heads, interpret):
-    return _conv_fwd(qkv, w, key_heads, value_heads, interpret)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def conv_chain(qkv, w, chain, interpret):
+    """The convolution chain over ``qkv [B, S, C]`` under the weight operand
+    ``w`` (the taps' rows ``[K, C]`` float32, then the bias's where the chain
+    has one): a tuple of the segments' outputs as ``chain`` lays them. Keeps
+    its two inputs for the backward and nothing else."""
+    return _conv_fwd(qkv, w, chain, interpret)[0]
 
 
-def _conv_tiles(qkv, key_heads, value_heads):
-    d = qkv.shape[2] // (2 * key_heads + value_heads)
-    return d, _tiles(qkv.shape[1], key_heads, value_heads, d)
+def _conv_tiles(qkv, chain):
+    return _rows_a_step(qkv.shape[1], chain.heads * chain.d)
 
 
-def _conv_fwd(qkv, w, key_heads, value_heads, interpret):
-    d, tiles = _conv_tiles(qkv, key_heads, value_heads)
-    out = tuple(
-        _conv_fwd_call(qkv, w, segment, tiles, d, interpret,
-                       f"gdn_conv_fwd_{name}")
-        for name, segment in zip("qkv", _segments(key_heads, value_heads, d)))
+def _conv_fwd(qkv, w, chain, interpret):
+    tiles = _conv_tiles(qkv, chain)
+    out = tuple(_conv_fwd_call(qkv, w, chain, segment, tiles, interpret)
+                for segment in chain.segments)
     return out, (qkv, w)
 
 
-def _conv_bwd(key_heads, value_heads, interpret, res, cts):
+def _conv_bwd(chain, interpret, res, cts):
     qkv, w = res
-    d, tiles = _conv_tiles(qkv, key_heads, value_heads)
+    tiles = _conv_tiles(qkv, chain)
     du, dw = zip(*(
-        _conv_bwd_call(qkv, w, g.astype(qkv.dtype), segment, tiles, d,
-                       interpret, f"gdn_conv_bwd_{name}")
-        for name, g, segment in zip("qkv", cts,
-                                    _segments(key_heads, value_heads, d))))
+        _conv_bwd_call(qkv, w, g.astype(qkv.dtype), chain, segment, tiles,
+                       interpret)
+        for g, segment in zip(cts, chain.segments)))
     return (jnp.concatenate(du, axis=-1),
             jnp.concatenate(dw, axis=-1).sum(axis=1))
 
 
-_conv.defvjp(_conv_fwd, _conv_bwd)
+conv_chain.defvjp(_conv_fwd, _conv_bwd)
+
+
+def refuse_taps(op: str, taps: int):
+    if taps > SUB:
+        raise ValueError(f"{op}: {taps} taps: a tap reads under {SUB} rows "
+                         "back")
 
 
 def conv_silu_l2norm(qkv, weight, *, key_heads: int, value_heads: int,
@@ -433,11 +504,12 @@ def conv_silu_l2norm(qkv, weight, *, key_heads: int, value_heads: int,
             f"conv_silu_l2norm: qkv {qkv.shape} [B, S, (2 Hk + Hv) d] with "
             f"Hk, Hv, d = {key_heads}, {value_heads}, {key_dim}; weight "
             f"{weight.shape} [K, (2 Hk + Hv) d]")
-    if weight.shape[0] > SUB:
-        raise ValueError(f"conv_silu_l2norm: {weight.shape[0]} taps: a tap "
-                         f"reads under {SUB} rows back")
-    out = _conv(qkv, weight.astype(_F32), key_heads, value_heads,
-                bool(interpret))
+    refuse_taps("conv_silu_l2norm", weight.shape[0])
+    chain = ConvChain(
+        "gdn_conv", _segments(key_heads, value_heads, key_dim), key_dim,
+        _tiles(qkv.shape[1], key_heads, value_heads, key_dim)[2],
+        biased=False, token_major=False)
+    out = conv_chain(qkv, weight.astype(_F32), chain, bool(interpret))
     return tuple(jnp.moveaxis(a, 1, 2) for a in out)
 
 

@@ -210,6 +210,13 @@ class LMTrainer:
                 arch.ssm_state, arch.ssm_groups, chunk=arch.ssm_chunk,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
+            from ps_pytorch_tpu.ops.ssm_mix import ssm_mix_schedule
+            kernels.append("ssm_mix[" + ssm_mix_schedule(
+                rows, cfg.lm_seq_len, arch.ssm_heads * arch.ssm_head_dim,
+                arch.ssm_groups * arch.ssm_state, arch.ssm_groups,
+                arch.ssm_conv,
+                itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
+                + "]")
         if arch.dropless:
             kernels.append("grouped_matmul")
         # What the run really computes in is read from the built model, not

@@ -357,6 +357,11 @@ def test_the_kernels_line_and_bfloat16_reach_the_layers(tmp_path):
     assert kernels.count("flash_attention[") == 1     # one kind of attention layer
     assert " ssd[chunk=32 chunks=3 group=3 grid=4x1 heads=2 slab=2 kept=" \
         in kernels
+    # the mixer's elementwise chains: x of 32 and B, C of 32 in tiles of 32
+    # lanes, both groups of 16 a step of the norm
+    assert " ssm_mix[lanes=32 rows=96 chunk=96 halo=16 conv_grid=2x3x1 " \
+        "norm_lanes=32 norm_rows=96 norm_chunk=96 norm_grid=2x1x1 " \
+        "conv_fwd_bytes=" in kernels
     assert "gated_delta_rule" not in kernels and "selective_scan" not in kernels
     assert "grouped_matmul mode=interpret dtype=float32" in kernels
 
@@ -374,6 +379,52 @@ def test_the_kernels_line_and_bfloat16_reach_the_layers(tmp_path):
     assert block["out_proj"]["__call__"][0].dtype == jnp.bfloat16
     assert block["in_proj"]["__call__"][0].dtype == jnp.bfloat16
     assert block["ssm_norm"]["__call__"][0].dtype == jnp.float32
+
+
+def _equations(jaxpr, under=""):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (but a
+    kernel's body), each with the name stack it lies under."""
+    for eqn in jaxpr.eqns:
+        stack = f"{under}/{eqn.source_info.name_stack}"
+        yield eqn, stack
+        if eqn.primitive.name != "pallas_call":
+            for value in eqn.params.values():
+                for inner in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from _equations(inner, stack)
+
+
+def test_the_mixers_chains_are_kernels_in_the_traced_step(tmp_path):
+    """The bfloat16 step as ``LMTrainer`` jits it, traced (nothing compiled):
+    its jaxpr holds the Mamba-2 mixer's Pallas calls by name, both ways, and
+    under ``ssm_conv`` no float32 array a sequence long and ``xBC``, x or z
+    wide: the convolution's products, the SiLU, the gate and the norm's
+    statistics live inside the calls. (The softplus on ``dt`` stays XLA's:
+    float32, a head wide.)"""
+    built = suite.step(CASE, True)
+    trainer, _ = suite._new_trainer(CASE, built.cfg.replace(
+        compute_dtype="bfloat16", train_dir=str(tmp_path), metrics_file=""))
+    tokens = suite.place(trainer, np.zeros(
+        (built.cfg.batch_size, built.cfg.lm_seq_len), np.int32))
+    with CASE.patched(), suite.one_device():
+        jaxpr = jax.make_jaxpr(trainer.step_fn)(trainer.state, tokens)
+    calls, wide = set(), []
+    d_inner = TINY_ROW["ssm_heads"] * TINY_ROW["ssm_head_dim"]
+    widths = {d_inner, d_inner + 2 * TINY_ROW["ssm_groups"]
+              * TINY_ROW["ssm_state"]}
+    for eqn, stack in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            calls.add(eqn.params["name"])
+        elif "ssm_conv" in stack:
+            wide += [(eqn.primitive.name, v.aval.shape) for v in eqn.outvars
+                     if v.aval.dtype == jnp.float32 and v.aval.ndim >= 2
+                     and v.aval.shape[-2] == S and v.aval.shape[-1] in widths]
+    assert {f"ssm_conv_{way}_{seg}" for way in ("fwd", "bwd")
+            for seg in "xbc"} | {"ssm_norm_fwd", "ssm_norm_bwd", "ssd_fwd",
+                                 "ssd_bwd"} <= calls
+    assert wide == []
 
 
 # ---- planted mistakes ------------------------------------------------------------------

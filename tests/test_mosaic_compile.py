@@ -689,6 +689,66 @@ def test_ssd_compiles_at_the_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.8 * 2 ** 30
 
 
+def test_ssm_mix_compiles_at_the_cells_shape(one_chip):
+    """The eight calls of ``ops/ssm_mix.py`` at the cell's shape (16384
+    tokens, 6144 channels of ``xBC``, 8 groups of 512, 4 taps and a bias,
+    bfloat16) under Mosaic: the convolution's kernels (``ops/gdn_mix.py``'s,
+    here with the bias's row and outputs that leave token-major in blocks of
+    one lane tile) over the segments x, B and C each way, and the gate with
+    its group norm each way, a block 512 tokens of one group's four lane
+    tiles, the scale's gradient summed in its output block.
+
+    And what XLA is left with: no op outside the Mosaic calls hands on a
+    float32 array a sequence long (a ``[16384, 6144]`` or ``[16384, 4096]``
+    row copy, a shifted product), x, B and C leave the kernels as ``ssd``
+    reads them, the norm's calls move exactly the schedule's bytes by their
+    own operands, and one forward and backward of both chains move under 2.6
+    GB: the calls' blocks 2.08 by ``ssm_mix_schedule`` (the floor is 2.07)
+    and XLA's ops, by the module's own shapes, ``d xBC``'s three segments
+    written side by side (0.4) and the parameters' rows."""
+    from ps_pytorch_tpu.ops import ssm_mix
+    b, s, d_inner, bc, groups, taps = 1, 16384, 4096, 1024, 8, 4
+    c = d_inner + 2 * bc
+
+    def both(xbc, w, bias, y, z, scale, dx, db, dc, dout):
+        out, pull = jax.vjp(
+            lambda *a: ssm_mix.conv_bias_silu(
+                *a, widths=(d_inner, bc, bc), interpret=False), xbc, w, bias)
+        normed, pull_norm = jax.vjp(
+            lambda *a: ssm_mix.gated_group_norm(
+                *a, groups=groups, eps=1e-5, interpret=False), y, z, scale)
+        return out + pull((dx, db, dc)) + (normed,) + pull_norm(dout)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = lambda width: arg((b, s, width))
+    text = jax.jit(both).lower(
+        rows(c), arg((taps, c), jnp.float32), arg((c,), jnp.float32),
+        rows(d_inner), rows(d_inner), arg((d_inner,), jnp.float32),
+        rows(d_inner), rows(bc), rows(bc), rows(d_inner)).compile().as_text()
+    ops = _entry_ops(text)
+    mosaic = {op[0].split(".")[0].split("jvp_")[-1].strip("_"): op
+              for op in ops if op[2]}
+    assert sorted(mosaic) == sorted(
+        [f"ssm_conv_{way}_{seg}" for way in ("fwd", "bwd") for seg in "xbc"]
+        + ["ssm_norm_fwd", "ssm_norm_bwd"])
+    sc = ssm_mix.ssm_mix_schedule(b, s, d_inner, bc, groups, taps, itemsize=2)
+    assert mosaic["ssm_norm_fwd"][4] == sc.norm_fwd_bytes
+    assert mosaic["ssm_norm_bwd"][4] == sc.norm_bwd_bytes
+    # token-major, in the rows' dtype: a reshape away from what ssd reads
+    assert mosaic["ssm_conv_fwd_x"][3] == [("bf16", f"{b},1,{s},{d_inner}")]
+    assert mosaic["ssm_conv_fwd_b"][3] == [("bf16", f"{b},1,{s},{bc}")]
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        for dtype, dims in shapes:
+            assert not (dtype == "f32" and math.prod(
+                int(n) for n in dims.split(",") if n) >= s * 128), \
+                (name, opcode, dims)
+    in_kernels = sum(sc[-4:])
+    assert 2.07e9 < in_kernels < 2.07e9 * 1.01
+    assert in_kernels + sum(op[4] for op in ops if not op[2]) < 2.6e9
+
+
 @pytest.mark.slow    # a whole step at the cell's size
 def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
                                                              monkeypatch):
@@ -696,7 +756,9 @@ def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
     compiled for the described chip from shapes alone: the configuration's
     rule (``cut.rule``: under 14.5 GiB by ``memory_analysis()`` with 16 of
     the 128 experts held) holds, and the flash calls at 16 query heads a K/V
-    head, both state-space-dual kernels and the grouped matmuls are in it."""
+    head, both state-space-dual kernels, the mixer's eight elementwise calls
+    (PR 45) and the grouped matmuls are in it, at no more planned memory than
+    before those calls (12.064 GiB at PR 44)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from ps_pytorch_tpu.config import config_from_args
@@ -704,7 +766,7 @@ def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
     from ps_pytorch_tpu.optim.sgd import sgd
     from ps_pytorch_tpu.parallel import ep
     from ps_pytorch_tpu.runtime.lm_eval import build_lm_model
-    for name in ("flash_attention", "ssd"):
+    for name in ("flash_attention", "ssd", "ssm_mix"):
         monkeypatch.setattr(
             importlib.import_module("ps_pytorch_tpu.ops." + name),
             "_interpret_default", lambda: False)
@@ -741,7 +803,10 @@ def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
         donate=cfg.donate).lower(state, tokens).compile()
     text = compiled.as_text()
     for name in ("flash_fwd", "flash_bwd_dkv", "ssd_fwd", "ssd_bwd",
-                 "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+                 "moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs",
+                 "ssm_conv_fwd_x", "ssm_conv_fwd_b", "ssm_conv_fwd_c",
+                 "ssm_conv_bwd_x", "ssm_conv_bwd_b", "ssm_conv_bwd_c",
+                 "ssm_norm_fwd", "ssm_norm_bwd"):
         assert text.count(f"%{name}.") > 0, name
     assert "flash_win_" not in text
     m = compiled.memory_analysis()
@@ -751,7 +816,7 @@ def test_the_nemotron3nano_cells_whole_step_fits_by_the_rule(one_chip,
           m.argument_size_in_bytes / 2 ** 30, "temporaries",
           m.temp_size_in_bytes / 2 ** 30, "code",
           m.generated_code_size_in_bytes / 1e6, "MB")
-    assert 10 * 2 ** 30 < planned < 14.5 * 2 ** 30
+    assert 10 * 2 ** 30 < planned < 12.07 * 2 ** 30
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():

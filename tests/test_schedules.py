@@ -94,3 +94,26 @@ def test_trainer_accepts_schedule_end_to_end(tmp_path):
     state = t.train()
     assert int(state.step[()] if hasattr(state.step, "__getitem__")
                else state.step) == 6
+
+
+@pytest.mark.parametrize("s,rows,norm_rows", [(16384, 2048, 512),
+                                              (1000, 1008, 512),
+                                              (40, 48, 48)])
+def test_the_mamba2_mixers_kernel_record_names_every_field(s, rows,
+                                                           norm_rows):
+    """The other kind of schedule: the static record of a kernel's tiles that
+    ``LMTrainer`` prints on its ``KERNELS`` line. ``ssm_mix_schedule``'s
+    (``ops/ssm_mix.py``, PR 45) says every field as ``name=value``, a grid
+    as ``axbxc``, and its rows a step follow the sequence: 2048 (the norm's
+    512, at four lane tiles a group), or a short sequence whole, in blocks of
+    the 16 halo rows."""
+    from ps_pytorch_tpu.ops.ssm_mix import ssm_mix_schedule
+    sc = ssm_mix_schedule(2, s, 4096, 1024, 8, 4)
+    assert (sc.rows, sc.norm_rows) == (rows, norm_rows)
+    assert sc.rows % sc.halo == 0 and sc.rows % sc.chunk == 0 \
+        and sc.norm_rows % sc.norm_chunk == 0
+    said = dict(item.split("=") for item in sc.describe().split())
+    assert list(said) == list(sc._fields)
+    for name, value in sc._asdict().items():
+        assert said[name] == ("x".join(map(str, value))
+                              if isinstance(value, tuple) else str(value))
