@@ -54,10 +54,11 @@ import jax
 import jax.numpy as jnp
 
 from ps_pytorch_tpu.models.gdn import gdn_sublayer
+from ps_pytorch_tpu.models.remat import kept, remat_block
 from ps_pytorch_tpu.models.ssm import mamba2_sublayer
 from ps_pytorch_tpu.models.transformer import (
     ACTS, ARCHS, COUNTER_NAMES, LM_COUNTERS, GatedFFN, attention_sublayer,
-    embed_tokens, make_norm, refuse_hybrid, remat_block,
+    embed_tokens, make_norm, refuse_hybrid,
 )
 from ps_pytorch_tpu.ops.grouped_matmul import gmm, gmm_t
 from ps_pytorch_tpu.telemetry.trace import device_scope
@@ -355,6 +356,8 @@ def _top_k(scores, bias, k):
 
 def _top_k_fwd(scores, bias, k):
     gates, idx = _top_k(scores, bias, k)
+    # both: a rematerialised block then runs no ``top_k`` a second time
+    gates, idx = kept(gates, "moe_gates"), kept(idx, "moe_idx")
     return (gates, idx), (idx, jnp.arange(scores.shape[-1], dtype=idx.dtype))
 
 
@@ -493,14 +496,15 @@ class DroplessMoE(nn.Module):
         # to an expert not held takes the key past the last held group.
         with device_scope("moe_route"):
             flat_e = idx.reshape(-1)                  # [T*k]
-            load = jnp.sum(flat_e[:, None] == jnp.arange(e, dtype=flat_e.dtype),
-                           axis=0, dtype=jnp.int32)
+            load = kept(jnp.sum(
+                flat_e[:, None] == jnp.arange(e, dtype=flat_e.dtype),
+                axis=0, dtype=jnp.int32), "moe_load")
             first = self.share * held
             group_sizes = load[first:first + held]
             key = flat_e if held == e else jnp.where(
                 (flat_e >= first) & (flat_e < first + held),
                 flat_e - first, held)
-            order = jnp.argsort(key, stable=True)
+            order = kept(jnp.argsort(key, stable=True), "moe_order")
             flat_gates = gates.reshape(-1)
             n_held_rows = jnp.sum(group_sizes)
 
@@ -540,7 +544,7 @@ class DroplessMoE(nn.Module):
         weights = (w_gate, w_up, w_down) if self.gated else (w_up, w_down)
         if rows == t * k:
             with device_scope("moe_route"):       # the sort's inverse
-                inv = jnp.argsort(order).reshape(t, k)
+                inv = kept(jnp.argsort(order), "moe_inv").reshape(t, k)
             y = part(tokens, flat_gates, *weights, order, group_sizes,
                      inv=inv)
         else:
@@ -702,8 +706,9 @@ class MoEBlock(nn.Module):
                             top_k=self.top_k, dtype=self.dtype,
                             name="moe")(y)
         with device_scope(leave):
-            if a.post_norm:
-                m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(m)
+            if a.post_norm:     # its backward reads its input (``remat_block``)
+                m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(
+                    kept(m, "mlp_out"))
             x = x + m
         if counted:
             # what the mixer counted rides in a dropless layer's statistics

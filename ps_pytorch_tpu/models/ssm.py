@@ -63,6 +63,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ps_pytorch_tpu.models.remat import kept
 from ps_pytorch_tpu.ops.selective_scan import selective_scan
 from ps_pytorch_tpu.telemetry.trace import device_scope
 
@@ -178,6 +179,8 @@ def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
         z, xbc, dt = jnp.split(
             dense(2 * d_inner + 2 * bc + heads, "in_proj")(a),
             [d_inner, 2 * d_inner + 2 * bc], axis=-1)
+        # all the kernels' backward reads of the block's recomputed forward
+        z, xbc, dt = kept(z, "ssm_z"), kept(xbc, "ssm_xbc"), kept(dt, "ssm_dt")
     with device_scope("ssm_conv"):
         init = _symmetric_uniform(d_conv ** -0.5)
         conv_w = mod.param("conv_weight", init, (d_conv, d_inner + 2 * bc))

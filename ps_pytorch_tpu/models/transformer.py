@@ -20,8 +20,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ps_pytorch_tpu.models.remat import kept, remat_block
 from ps_pytorch_tpu.models.ssm import gmu_sublayer, mamba_sublayer
-from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES, flash_attention
+from ps_pytorch_tpu.ops.flash_attention import flash_attention
 from ps_pytorch_tpu.parallel.ring import full_attention, ring_attention
 from ps_pytorch_tpu.telemetry.trace import device_scope
 
@@ -383,19 +384,29 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             k = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
             v = nn.Dense(n_kv * hd, use_bias=False, dtype=dtype)(y)
     with device_scope("attn_pos"):
+        # a q/k norm's backward reads its input (``remat_block``)
+        unnormed = lambda q, k: (kept(q, "attn_q_unnormed"),
+                                 kept(k, "attn_k_unnormed"))
         if a.qk_norm:
+            q, k = unnormed(q, k)
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
         to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
         q = to_heads(q)
         k, v = (to_heads(k), to_heads(v)) if shared_kv is None else shared_kv
         if a.head_qk_norm:
+            q, k = unnormed(q, k)
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
         if a.layer_rope(layer):
             turn = rope if a.rope_share == 1.0 else partial(
                 rope_on_a_share, share=a.rope_share)
             q, k = (turn(t, positions, a.rope_theta) for t in (q, k))
+        # as ``attend`` takes them, a pair's heads once (``remat_block``); a
+        # cross layer's K/V are another block's output
+        q = kept(q, "attn_q")
+        if shared_kv is None:
+            k, v = kept(k, "attn_k"), kept(v, "attn_v")
     out = {"k": k, "v": v}
 
     def attend(q, k, v):
@@ -437,26 +448,17 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
         o = o.transpose(0, 2, 1, 3).reshape(b, s, n_heads * hd)
     if a.attn_gate:
         with device_scope("attn_proj"):
-            gate = nn.Dense(n_heads * hd, use_bias=False, dtype=dtype,
-                            name="gate")(y)
+            gate = kept(nn.Dense(n_heads * hd, use_bias=False, dtype=dtype,
+                                 name="gate")(y), "attn_gate")
         with device_scope("attn_pos"):
             o = o * nn.sigmoid(gate)
     with device_scope("attn_proj"):
-        o = nn.Dense(d, use_bias=False, dtype=dtype)(o)
+        # what the block's second half starts from, bar a norm and a sum
+        o = kept(nn.Dense(d, use_bias=False, dtype=dtype)(o), "attn_out")
         if a.post_norm:
             o = make_norm(arch, dtype, name="post_attn_norm")(o)
         x = x + o
     return x, y, out
-
-
-def remat_block(block_cls):
-    """Per-block rematerialisation for both LM classes: the backward pass
-    keeps a block's input and recomputes its interior, except the flash
-    forward kernel's output and log-sum-exp (0.25 KiB a query head and token
-    at head dim 128), which are kept: recomputing them is the most expensive
-    part of the block at long sequences and the cheapest to save."""
-    return nn.remat(block_cls, policy=jax.checkpoint_policies
-                    .save_only_these_names(*SAVED_NAMES))
 
 
 def refuse_head_kinds(model, where: str) -> None:
@@ -726,7 +728,8 @@ class TransformerLM(nn.Module):
     attention_impl: str = "full"
     axis_name: str = "data"
     # Per-BLOCK rematerialization: backward stores only block-boundary
-    # activations and recomputes each block's interior. Checkpointing any
+    # activations and recomputes each block's interior, bar what
+    # ``remat_block`` keeps by name. Checkpointing any
     # coarser (e.g. the whole loss) saves no peak memory — the recompute
     # holds all residuals at once anyway. Param tree is unchanged, so
     # remat can be toggled on an existing checkpoint.

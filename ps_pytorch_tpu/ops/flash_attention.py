@@ -825,7 +825,8 @@ def _flash(q3, k3, v3, causal, scale, sched, interpret):
 # What the forward kernel leaves for the backward, by name: a remat policy
 # that saves these (``jax.checkpoint_policies.save_only_these_names``) spares
 # the recomputed block its forward kernel at one [bh, S, D] output and one
-# float32 row a query.
+# float32 row a query. The policy's whole list, these two first, is
+# ``models/remat.py:KEPT_NAMES``.
 SAVED_NAMES = ("flash_o", "flash_lse")
 
 

@@ -381,30 +381,53 @@ def first_step(case, remat):
     return _once(("first_step", case.arch, remat), build)
 
 
+def _lowered_again(case, remat, tag, modules, name, value):
+    """The step of ``step(case, remat)`` built a second time with ``name``
+    patched to ``value`` in ``modules`` (each binds the function by name),
+    lowered and never compiled: the one extra build of a patched form."""
+    built = step(case, remat)
+    tokens = place(built.trainer, np.zeros(
+        (built.cfg.batch_size, built.cfg.lm_seq_len), np.int32))
+    with pytest.MonkeyPatch.context() as patch:
+        for mod in modules:
+            patch.setattr(mod, name, value)
+        trainer, _ = _new_trainer(case, built.cfg.replace(
+            train_dir=str(_scratch_dir() / f"{case.arch}_{tag}"),
+            metrics_file=""))
+        with case.patched(), one_device():
+            return trainer.step_fn.lower(trainer.state, tokens)
+
+
 def unscoped_lowering(case):
     """(the remat step as lowered, the same step built with ``device_scope``
-    patched to nothing and lowered, that lowering with every op's name): the
-    one extra build, traced and never compiled. The first two are texts
-    without locations, which is all a scope adds."""
+    patched to nothing and lowered, that lowering with every op's name). The
+    first two are texts without locations, which is all a scope adds."""
     def build():
         from ps_pytorch_tpu.models import gdn, moe, ssm
         from ps_pytorch_tpu.parallel import dp, ep, sp
-        built = step(case, True)
-        tokens = place(built.trainer, np.zeros(
-            (built.cfg.batch_size, built.cfg.lm_seq_len), np.int32))
-        modules = (tr_mod, moe, ssm, gdn, dp, sp, ep)
-        with pytest.MonkeyPatch.context() as patch:
-            for mod in modules:     # each binds the function by name
-                patch.setattr(mod, "device_scope",
-                              lambda name: contextlib.nullcontext())
-            trainer, _ = _new_trainer(case, built.cfg.replace(
-                train_dir=str(_scratch_dir() / f"{case.arch}_unscoped"),
-                metrics_file=""))
-            with case.patched(), one_device():
-                lowered = trainer.step_fn.lower(trainer.state, tokens)
-                return (built.step_fn.stablehlo_text, lowered.as_text(),
-                        lowered.as_text(dialect="hlo", debug_info=True))
+        lowered = _lowered_again(
+            case, True, "unscoped", (tr_mod, moe, ssm, gdn, dp, sp, ep),
+            "device_scope", lambda name: contextlib.nullcontext())
+        return (step(case, True).step_fn.stablehlo_text, lowered.as_text(),
+                lowered.as_text(dialect="hlo", debug_info=True))
     return _once(("unscoped", case.arch), build)
+
+
+def unnamed_lowering(case):
+    """(the step WITHOUT remat as lowered, the same step built with ``kept``
+    patched to hand its argument back and lowered): texts without locations,
+    each function's symbol replaced by the order it first appears in
+    (``@_where_94``: JAX numbers the functions it outlines by a count that a
+    name moves). The second is the form the step had before a block's
+    interior carried names."""
+    def build():
+        from ps_pytorch_tpu.models import moe, ssm
+        lowered = _lowered_again(
+            case, False, "unnamed", (tr_mod, moe, ssm), "kept",
+            lambda x, name: x)
+        return tuple(in_order_of_appearance(r"@[\w.\-]+", text) for text in (
+            step(case, False).step_fn.stablehlo_text, lowered.as_text()))
+    return _once(("unnamed", case.arch), build)
 
 
 def trained(case):
@@ -455,14 +478,19 @@ METADATA = re.compile(
 ANNOTATION = re.compile(r'custom_call_target="xla\.sdy\.\w+Shape"')
 
 
+def in_order_of_appearance(pattern, text):
+    """``text`` with each distinct match of ``pattern`` (a sigil and a name)
+    replaced by the sigil and the order the name first appears in."""
+    ids = {}
+    return re.sub(pattern, lambda m: ids.setdefault(
+        m.group(0), f"{m.group(0)[0]}{len(ids)}"), text)
+
+
 def program(text):
     """A compiled text without its metadata, every name (``%fusion.12``: JAX
     derives some from the name stack) replaced by the order it first appears
     in: equal for two compilations of one program."""
-    ids = {}
-    return re.sub(r"%[\w.\-]+",
-                  lambda m: ids.setdefault(m.group(0), f"%{len(ids)}"),
-                  METADATA.sub("", text))
+    return in_order_of_appearance(r"%[\w.\-]+", METADATA.sub("", text))
 
 
 def scope_of(op_name):
@@ -719,6 +747,38 @@ def install(namespace, case):
         fn = step(case, True).step_fn
         check_scopes(fn.lowered_text, fn.compiled_text, case.scopes, True,
                      case.remat_scopes)
+
+    def test_a_rematerialised_block_runs_nothing_it_keeps_a_second_time():
+        """What ``remat_block`` keeps by name (``KEPT_NAMES``) is not made
+        again: among the recomputed ops of the lowered remat step there is no
+        ``top_k`` and no sort (the route's integers are kept), no matmul under
+        ``attn_proj`` (q, k, v, the gate's and the output projection's
+        results are kept; the input norm stays, which ``check_scopes``
+        holds), none under ``ssm_proj`` where the arch's state-space layers
+        are Mamba-2 (``in_proj``'s three slices are kept), and no down
+        projection where a norm follows the feed-forward half (its result is
+        kept, so nothing behind the hidden rows runs again; the routed rows'
+        scatter-add sits in a called function, whose ops the lowered text
+        names relative to the call: the chip's trace shows it).
+        Without ``--remat`` a name is nothing: the step lowers to the text it
+        had before any interior was named."""
+        again = [(op, name) for op, name in
+                 named_ops(step(case, True).step_fn.lowered_text)
+                 if scope_of(name)[1] == "recompute"]
+        assert len(again) > 20
+        assert [(op, name) for op, name in again
+                if op == "sort" or name.rsplit("/", 1)[-1] in ("top_k", "sort")
+                ] == []
+        dots = [name for op, name in again if op == "dot"]
+        assert dots     # whatever else a block's backward reads is made again
+        scopes = {scope_of(name)[0] for name in dots}
+        assert "attn_proj" not in scopes
+        if case.tiny_row.ssm_heads:     # Mamba-2's sizes
+            assert "ssm_proj" not in scopes
+        if case.tiny_row.post_norm:
+            assert [name for name in dots if "/down/" in name] == []
+        named, unnamed = unnamed_lowering(case)
+        assert named == unnamed
 
     def test_no_heavy_op_is_without_a_scope():
         fn = step(case, True).step_fn
