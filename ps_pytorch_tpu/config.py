@@ -25,7 +25,7 @@ from typing import Any, Optional
 # building a config imports no model code; tests/test_arch_olmoe.py holds the two
 # lists equal).
 LM_ARCHS = ("gpt2", "olmoe", "smallthinker", "trinity", "phi4flash",
-            "qwen3next", "nemotronh")
+            "qwen3next", "nemotronh", "evabyte")
 # ... of which those that route dropless (an MoE model: lm_parallelism=ep).
 _DROPLESS_ARCHS = ("olmoe", "smallthinker", "trinity", "qwen3next",
                    "nemotronh")
@@ -117,7 +117,7 @@ class TrainConfig:
     lm_corpus_tokens: int = 1_000_000
     lm_corpus_file: str = ""         # byte-level REAL corpus from any local file ("" = synthetic Markov stream)
     lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE model, experts sharded over 'data'; also how an MoE model is chosen on ONE chip)
-    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) | trinity (RMSNorm on each sublayer's input and output; three window-2048 RoPE layers to one full-causal layer without position encoding; q/k norm a head; gated attention output; embedding times sqrt(d); lm_dense_layers dense SwiGLU layers, then dropless sigmoid-scored top-k SwiGLU experts chosen under a bias the step moves against the load, gates renormalised and scaled, one shared expert; needs lm_parallelism=ep) | phi4flash (a decoder-hybrid-decoder; LayerNorm eps 1e-5, no position encoding, SwiGLU FFN, head tied to the embedding; layer kinds by index and depth, lm_layers a multiple of 4: Mamba-1 state-space layers and window-512 differential-attention layers alternate in the first half, then one Mamba layer whose scan output and one full differential-attention layer whose K/V every later layer reads, then gated memory units and cross-attention layers alternate; needs lm_parallelism=sp on ONE device) | qwen3next (zero-centred RMSNorm; three Gated DeltaNet linear-attention layers (16 key / 32 value heads of 128, a 4-tap convolution, the chunked gated delta rule) to one softmax-attention layer with q/k norm a head, a gated output and RoPE on a quarter of the head; dropless softmax-scored top-k SwiGLU experts, gates renormalised, one shared expert under a sigmoid gate; needs lm_parallelism=ep) | nemotronh (RMSNorm eps 1e-5, no position encoding; every layer ONE pre-norm residual sublayer by the letter of the published 52-letter pattern MEMEM*E...: a Mamba-2 mixer (64 heads of 64 with a [64, 128] state, B and C in 8 groups, a biased 4-tap convolution, the chunked state-space-dual kernel, the gate before a norm in 8 groups), an attention mixer, or an expert layer alone: dropless sigmoid-scored top-k experts down(relu(up x)^2) without a gate projection, chosen under a bias the step moves against the load, gates renormalised and scaled by 2.5, one shared expert of twice the width; lm_layers at most 52; needs lm_parallelism=ep) — models/transformer.py ARCHS
+    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) | trinity (RMSNorm on each sublayer's input and output; three window-2048 RoPE layers to one full-causal layer without position encoding; q/k norm a head; gated attention output; embedding times sqrt(d); lm_dense_layers dense SwiGLU layers, then dropless sigmoid-scored top-k SwiGLU experts chosen under a bias the step moves against the load, gates renormalised and scaled, one shared expert; needs lm_parallelism=ep) | phi4flash (a decoder-hybrid-decoder; LayerNorm eps 1e-5, no position encoding, SwiGLU FFN, head tied to the embedding; layer kinds by index and depth, lm_layers a multiple of 4: Mamba-1 state-space layers and window-512 differential-attention layers alternate in the first half, then one Mamba layer whose scan output and one full differential-attention layer whose K/V every later layer reads, then gated memory units and cross-attention layers alternate; needs lm_parallelism=sp on ONE device) | qwen3next (zero-centred RMSNorm; three Gated DeltaNet linear-attention layers (16 key / 32 value heads of 128, a 4-tap convolution, the chunked gated delta rule) to one softmax-attention layer with q/k norm a head, a gated output and RoPE on a quarter of the head; dropless softmax-scored top-k SwiGLU experts, gates renormalised, one shared expert under a sigmoid gate; needs lm_parallelism=ep) | nemotronh (RMSNorm eps 1e-5, no position encoding; every layer ONE pre-norm residual sublayer by the letter of the published 52-letter pattern MEMEM*E...: a Mamba-2 mixer (64 heads of 64 with a [64, 128] state, B and C in 8 groups, a biased 4-tap convolution, the chunked state-space-dual kernel, the gate before a norm in 8 groups), an attention mixer, or an expert layer alone: dropless sigmoid-scored top-k experts down(relu(up x)^2) without a gate projection, chosen under a bias the step moves against the load, gates renormalised and scaled by 2.5, one shared expert of twice the width; lm_layers at most 52; needs lm_parallelism=ep) | evabyte (byte-level; zero-centred RMSNorm eps 1e-5, RoPE theta 1e5, SwiGLU FFN; every layer an EVA layer: exact causal attention inside a window of 2048 that is a block of the diagonal, ONE softmax shared with the 16-token chunk summaries of every earlier window (a softmax-weighted pooling under two learned vectors a head), by the fused kernels of ops/eva_attention.py under lm_attention=flash; 8 prediction heads on one trunk, head i predicting token t + 1 + i, float32 logits; lm_seq_len a multiple of 16; needs lm_parallelism=sp on ONE device) — models/transformer.py ARCHS
     lm_kv_heads: int = 0             # key/value heads, each serving lm_heads / lm_kv_heads query heads (0 = lm_heads); sp on one device or ep, attention full | flash
     lm_head_dim: int = 0             # head size (0 = lm_d_model / lm_heads)
     lm_ffn_dim: int = 0              # FFN / expert width (0 = 4 * lm_d_model)

@@ -12,13 +12,15 @@ import flax.linen as nn
 import jax
 from jax.ad_checkpoint import checkpoint_name
 
+from ps_pytorch_tpu.ops.eva_attention import SAVED_NAMES as EVA_SAVED_NAMES
 from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES
 
 
 # What a rematerialised block keeps beside its input (``remat_block``): the one
 # list, each name given where the tensor is made (``kept``; the flash forward's
-# two in ``ops/flash_attention.py``).
-KEPT_NAMES = SAVED_NAMES + (
+# two in ``ops/flash_attention.py``, the EVA forward's four in
+# ``ops/eva_attention.py``).
+KEPT_NAMES = SAVED_NAMES + EVA_SAVED_NAMES + (
     "attn_q", "attn_k", "attn_v", "attn_q_unnormed", "attn_k_unnormed",
     "attn_gate", "attn_out", "ssm_z", "ssm_xbc", "ssm_dt", "moe_gates",
     "moe_idx", "moe_order", "moe_inv", "moe_load", "mlp_out")
@@ -44,6 +46,9 @@ def remat_block(block_cls):
     - ``flash_o``, ``flash_lse``: the flash forward kernel's output and
       log-sum-exp, (2 hd + 4) H: spares the kernel's second run, the dearest
       part of a block at long sequences;
+    - ``eva_o``, ``eva_lse``, ``eva_ks``, ``eva_vs``: an EVA layer's core
+      output and log-sum-exp, as flash's, and its chunk summaries, (2 hd / c)
+      2 H more: spares both forward kernels' second run;
     - ``attn_q``, ``attn_k``, ``attn_v``: the flash backward's other three
       operands as ``attend`` takes them (a differential pair's heads once, a
       cross layer's q alone), 2 hd (H + 2 Hkv): spares the q/k/v projections,

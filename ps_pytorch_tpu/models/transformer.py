@@ -48,7 +48,7 @@ class Arch(NamedTuple):
     aux_coef: float = 0.01      # load-balance loss in the ep step's loss
     # Layers of several kinds: layer l is of kind l % len(pattern), for the
     # window, the position encoding and the token mixer alike.
-    mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn"; (): attention in every layer
+    mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn" | "eva"; (): attention in every layer
     layer_pattern: str = ""     # no period but a letter a layer, each layer ONE pre-norm residual sublayer (``PATTERN_KINDS``): "M" a Mamba-2 mixer | "*" an attention mixer | "E" an expert layer; the depth is at most its length
     window: int = 0             # keys a window layer's query sees, itself included
     window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
@@ -71,6 +71,14 @@ class Arch(NamedTuple):
     gdn_key_dim: int = 0        # a key head's features
     gdn_value_dim: int = 0      # a value head's features
     gdn_conv: int = 0           # taps of the causal depthwise convolution over q, k, v
+    # An EVA layer's sizes (ops/eva_attention.py): exact causal attention inside
+    # a window that is a BLOCK of the diagonal, one softmax shared with the
+    # chunk summaries of every earlier window.
+    eva_window: int = 0         # tokens a window
+    eva_chunk: int = 0          # tokens a summary pools
+    eva_std: float = 0.0        # the pooling's two learned vectors a head (adaptive_phi, adaptive_mu_k): normal(std) clipped to +-std
+    pred_heads: int = 1         # prediction heads on the one trunk: head i at position t predicts token t + 1 + i; logits [B, S, pred_heads, V] where > 1
+    f32_logits: bool = False    # the head's matmul hands out float32 (its operands stay in the compute dtype)
     # A decoder-hybrid-decoder stack: the layer's kind follows from its index
     # AND the depth (``layer_kind``), not from a period.
     hybrid: bool = False        # state-space / window layers, then a cross-decoder that reads one layer's scan output and one layer's K/V
@@ -111,6 +119,12 @@ class Arch(NamedTuple):
             return "full_hands_kv" if layer % 2 else "mamba_hands_memory"
         return "cross" if layer % 2 else "gmu"
 
+    @property
+    def counts(self) -> bool:
+        """Do the dense LM's blocks return ``(x, out)``: what a layer hands
+        on and counts (``HANDED``, ``COUNTER_NAMES``), beside the stream."""
+        return self.hybrid or "eva" in self.mixer_layers
+
     def layer_window(self, layer: int, n_layers: int = 0) -> Optional[int]:
         if self.hybrid:
             return self.window \
@@ -126,14 +140,20 @@ class Arch(NamedTuple):
             or bool(self.rope_layers[layer % len(self.rope_layers)]))
 
 
-# ``Arch.layer_kind``'s values. "gdn": a Gated DeltaNet layer (models/gdn.py),
-# by the arch's period. A hybrid stack of depth L (a multiple of 4):
-# Mamba and window-attention layers alternate in the first half; layer L/2 is
-# a Mamba layer whose scan output goes to every gated memory unit, layer
-# L/2 + 1 a full causal attention layer whose K and V go to every cross layer;
-# then gated memory units and cross-attention layers alternate.
-LAYER_KINDS = ("attention", "gdn", "mamba2", "experts", "mamba", "window",
-               "mamba_hands_memory", "full_hands_kv", "gmu", "cross")
+# ``Arch.layer_kind``'s values. By the arch's period (``mixer_layers``):
+# "attention", softmax attention over every key before the query or a sliding
+# window of them; "gdn", a Gated DeltaNet layer (models/gdn.py); "eva", an EVA
+# layer (ops/eva_attention.py: the q/k/v/o path of ``attention_sublayer`` round
+# another core). By the letter of a pattern (``PATTERN_KINDS``): "mamba2", a
+# Mamba-2 mixer alone (models/ssm.py); "experts", an expert layer alone, no
+# mixer; "attention" again. A hybrid stack of depth L (a multiple of 4):
+# "mamba" and "window" (window-attention) layers alternate in the first half;
+# layer L/2 is "mamba_hands_memory", a Mamba layer whose scan output goes to
+# every gated memory unit, layer L/2 + 1 "full_hands_kv", a full causal
+# attention layer whose K and V go to every cross layer; then "gmu" (gated
+# memory units) and "cross" (cross-attention) layers alternate.
+LAYER_KINDS = ("attention", "gdn", "eva", "mamba2", "experts", "mamba",
+               "window", "mamba_hands_memory", "full_hands_kv", "gmu", "cross")
 # ``Arch.layer_pattern``'s letters (the published ``hybrid_override_pattern``'s):
 # such a layer is the mixer alone or the expert layer alone, "experts" no mixer.
 PATTERN_KINDS = {"M": "mamba2", "*": "attention", "E": "experts"}
@@ -142,7 +162,7 @@ ATTENTION_KINDS = ("attention", "window", "full_hands_kv", "cross")
 HANDED = ("memory", "k", "v")
 LM_COUNTERS = "lm_counters"     # the flax collection the counters are sown in
 COUNTER_NAMES = ("ssm_state_abs_max", "diff_lambda_max", "gdn_state_abs_max",
-                 "ssd_state_abs_max")
+                 "ssd_state_abs_max", "eva_pool_weight_max")
 
 ARCHS = {
     "gpt2": Arch(),
@@ -277,6 +297,21 @@ ARCHS = {
                                     "EMEMEMEM*EMEMEMEME",
                       ssm_state=128, ssm_conv=4, ssm_heads=64,
                       ssm_head_dim=64, ssm_groups=8, ssm_chunk=128),
+    # EvaByte (EvaByte/EvaByte config.json, model_type evabyte; EVA,
+    # arXiv:2302.04542, in the reduced form of the model's public eva.py):
+    # byte-level, 320 ids; rms_norm_eps 1e-5 on zero-centred RMSNorms
+    # (norm_add_unit_offset: scale 1 + w); every layer an EVA layer
+    # (attention_class eva: window_size 2048, chunk_size 16, RoPE theta 1e5
+    # over the whole head) and a SwiGLU feed-forward without biases; an untied
+    # head of num_pred_heads 8 x 320 outputs whose logits leave in float32
+    # (fp32_logits). embed_std and eva_std: the published init_std. What
+    # config.json has no key for (the form of phi and mu, the pooling's scale,
+    # the block and not a band, the heads' equal weight in the loss) is
+    # benchmark/configs/evabyte_6_5b.json's ``assumed``.
+    "evabyte": Arch(rms_norm=True, norm_eps=1e-5, zero_centred_norm=True,
+                    rope_theta=1e5, gated_ffn=True, embed_std=0.01275,
+                    mixer_layers=("eva",), eva_window=2048, eva_chunk=16,
+                    eva_std=0.01275, pred_heads=8, f32_logits=True),
 }
 
 
@@ -333,6 +368,40 @@ def rope_on_a_share(x, positions, theta: float, share: float):
 def diff_lambda_init(layer: int) -> float:
     """Differential attention's lambda_init for the layer of that index."""
     return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def _clipped_normal(std: float):
+    """normal(std) clipped to +-std."""
+    def init(key, shape, dtype=jnp.float32):
+        return jnp.clip(jax.random.normal(key, shape, dtype) * std, -std, std)
+    return init
+
+
+def eva_core(mod: nn.Module, q, k, v, a: Arch, *, fused: bool):
+    """An EVA layer's core on q, k, v [B, H, S, hd] as the scores take them
+    (rotated): ``(o, the largest pooling weight)``. Two learned vectors a
+    head in the block's scope, ``adaptive_phi`` (the pooling's query) and
+    ``adaptive_mu_k`` (added to every pooled key). ``fused``: the Pallas
+    kernels; else the plain form, float32 scores over ``[tokens |
+    summaries]`` (what the kernels are held to, and what initialises the
+    model). The summaries run under the device scope ``eva_pool``, the core
+    under ``attn_core``; the fused op opens both itself, forward and
+    backward."""
+    # where the arch asks for it: the other archs' start-up does not pay for it
+    from ps_pytorch_tpu.ops import eva_attention as eva
+
+    shape = q.shape[1:2] + q.shape[3:]
+    phi = mod.param("adaptive_phi", _clipped_normal(a.eva_std), shape)
+    mu = mod.param("adaptive_mu_k", _clipped_normal(a.eva_std), shape)
+    sizes = dict(window=a.eva_window, chunk=a.eva_chunk)
+    if fused:
+        return eva.eva_attention(q, k, v, phi, mu, **sizes)
+    with device_scope("eva_pool"):
+        ks, vs, alpha = eva.eva_pool_reference(k, v, phi, mu,
+                                               chunk=a.eva_chunk)
+    with device_scope("attn_core"):
+        o = eva.eva_core_reference(q, k, v, ks, vs, **sizes)
+    return o.astype(q.dtype), jax.lax.stop_gradient(jnp.max(alpha))
 
 
 def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
@@ -422,15 +491,20 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             return flash_attention(q, k, v, causal=True, window=window)
         return full_attention(q, k, v, causal=True, window=window)
 
-    with device_scope("attn_core"):
-        if a.diff_attn:
-            if decode or attention_impl == "ring":
-                refuse_hybrid(arch, "decode" if decode else "ring attention")
+    eva = a.layer_kind(layer, n_layers) == "eva"
+    if (eva or a.diff_attn) and (decode or attention_impl == "ring"):
+        refuse_hybrid(arch, "decode" if decode else "ring attention")
+    if eva:
+        o, out["eva_pool_weight_max"] = eva_core(
+            mod, q, k, v, a, fused=attention_impl == "flash")
+    elif a.diff_attn:
+        with device_scope("attn_core"):
             halves = [v[:, 0::2], v[:, 1::2]]
             o, o2 = (jnp.concatenate([attend(q[:, i::2], k[:, i::2], vh)
                                       for vh in halves], axis=-1)
                      for i in (0, 1))
-        else:
+    else:
+        with device_scope("attn_core"):
             o = attend(q, k, v)
     with device_scope("attn_pos"):
         if a.diff_attn:
@@ -527,14 +601,32 @@ _STATE_LACKS = {
         "ring attention": "a state-space-dual walk whose state crosses "
                           "sequence shards",
     },
+    # EVA ("eva") layers: a window of keys plus a growing list of summaries
+    "eva": {
+        "trains under": "sp on one device",
+        **dict.fromkeys(("generate.py", "serve.py", "decode"), _NO_SLOT.format(
+            "one window's keys and values and the list of chunk summaries, "
+            "which grows by one every chunk of tokens")),
+        "tensor parallelism": "a layout over the model axis for the EVA "
+                              "kernels' heads and their two learned vectors "
+                              "a head",
+        "pipeline parallelism": "stages that carry more than one prediction "
+                                "head's targets to the last stage's loss",
+        "expert parallelism": "an expert block round the EVA mixer (it is a "
+                              "dense model)",
+        "ring attention": "summaries of earlier windows that cross sequence "
+                          "shards, and targets further than one token past "
+                          "a shard's end",
+    },
 }
 
 
 def _state_kind(a: Arch) -> Optional[str]:
     if a.hybrid:
         return "hybrid"
-    if "gdn" in a.mixer_layers:
-        return "gdn"
+    for kind in ("gdn", "eva"):
+        if kind in a.mixer_layers:
+            return kind
     return "mamba2" if "M" in a.layer_pattern else None
 
 
@@ -679,7 +771,8 @@ class Block(nn.Module):
         # (read by RoPE archs only; None = 0..S-1). A hybrid arch's block
         # takes what earlier layers handed on (``HANDED``: the scan output
         # for a gated memory unit, K and V for a cross layer) and returns
-        # ``(x, out)``: what this layer hands on and counts.
+        # ``(x, out)``: what this layer hands on and counts (an arch of EVA
+        # layers too: ``Arch.counts``).
         d = x.shape[-1]
         a = ARCHS[self.arch]
         kind = a.layer_kind(self.layer, self.n_layers)
@@ -710,7 +803,7 @@ class Block(nn.Module):
                 y = nn.Dense(self.ffn_dim or 4 * d, dtype=self.dtype)(y)
                 y = nn.gelu(y)
                 x = x + nn.Dense(d, dtype=self.dtype)(y)
-        if not a.hybrid:
+        if not a.counts:
             return x
         hands = {"mamba_hands_memory": ("memory",),
                  "full_hands_kv": ("k", "v")}.get(kind, ())
@@ -768,7 +861,7 @@ class TransformerLM(nn.Module):
                       arch=self.arch, ffn_dim=self.ffn_dim, layer=i,
                       kv_heads=self.kv_heads, head_dim=self.head_dim,
                       n_layers=self.n_layers, name=f"block_{i}")
-            if not a.hybrid:
+            if not a.counts:
                 x = blk(x, positions)
                 continue
             x, out = blk(x, positions, handed)
@@ -790,8 +883,15 @@ class TransformerLM(nn.Module):
                 table = self.variables["params"]["tok_embed"]["embedding"]
                 return jax.lax.dot_general(
                     x, table.astype(self.dtype), (((2,), (1,)), ((), ())))
-            return nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
-                            name="lm_head")(x)
+            # several prediction heads share the trunk and the one matmul:
+            # [B, S, pred_heads, V], head i's logits for token t + 1 + i
+            wide = {"dot_general": partial(
+                jax.lax.dot_general, preferred_element_type=jnp.float32)} \
+                if a.f32_logits else {}
+            logits = nn.Dense(self.vocab_size * a.pred_heads, use_bias=False,
+                              dtype=self.dtype, name="lm_head", **wide)(x)
+            return logits if a.pred_heads == 1 else logits.reshape(
+                logits.shape[:2] + (a.pred_heads, self.vocab_size))
 
 
 def lm_counters(collections) -> dict:

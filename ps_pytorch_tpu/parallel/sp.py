@@ -58,20 +58,56 @@ def create_lm_train_state(model, tx, mesh: Mesh, sample_tokens,
     return jax.jit(init_fn, out_shardings=shardings)(rng)
 
 
+def _targets_and_weights(tokens, first_next, positions, total: int,
+                         heads: int = 1):
+    """Next-token targets and their weights for ``heads`` prediction heads:
+    head ``i`` at position ``t`` predicts token ``t + 1 + i``. One head:
+    ``[B, S]`` arrays, the local shift with the next shard's first token
+    (``first_next``) as the last target, and the global last position (of
+    ``total``) weighted out. Several: ``[B, S, heads]``; head ``i``'s last
+    ``i + 1`` positions have no target inside the sequence and weigh 0 (the
+    caller holds the whole sequence: ``first_next`` is then a target of
+    weight 0)."""
+    targets, weights = [], []
+    for i in range(heads):
+        shifted = [tokens[:, 1 + i:], first_next]
+        if i:
+            shifted.append(jnp.zeros((tokens.shape[0], i), tokens.dtype))
+        targets.append(jnp.concatenate(shifted, axis=1))
+        no_target = positions == (total - 1) if i == 0 \
+            else positions >= (total - 1 - i)
+        weights.append(jnp.broadcast_to(jnp.where(no_target, 0.0, 1.0),
+                                        tokens.shape))
+    if heads == 1:
+        return targets[0], weights[0]
+    return jnp.stack(targets, axis=-1), jnp.stack(weights, axis=-1)
+
+
 def _local_nexttoken_loss(model, axis_name: str, params, tokens):
     """Per-shard next-token loss (sum, (count, counters)) — shared by the
     train step and the grad-free eval so their framing can never diverge.
     ``counters``: what the model counted on the way (``lm_counters``: a
-    hybrid arch's ``ssm_state_abs_max`` and ``diff_lambda_max``; {} for the
-    others).
+    hybrid arch's ``ssm_state_abs_max`` and ``diff_lambda_max``, an EVA
+    arch's ``eva_pool_weight_max``; {} for the others) and, where the arch
+    has several prediction heads, ``next_token_loss_head0``: head 0's own
+    mean loss, the next-token loss that compares with other models (the
+    step's loss is the mean over every head's targets, all weighted alike).
 
     LOCAL sums only — no collective inside (the train step differentiates
     this; differentiating through an in-loss psum double-counts cross-shard
     cotangents); normalization and the cross-shard sum happen outside.
     """
     # models/transformer.py imports parallel/ring.py, and so this package
-    from ps_pytorch_tpu.models.transformer import LM_COUNTERS, lm_counters
+    from ps_pytorch_tpu.models.transformer import (
+        ARCHS, LM_COUNTERS, lm_counters,
+    )
+    heads = ARCHS[getattr(model, "arch", "gpt2")].pred_heads
     n = jax.lax.axis_size(axis_name)
+    if heads > 1 and n > 1:
+        raise ValueError(
+            f"lm_arch={model.arch} has {heads} prediction heads: head i's "
+            f"target is i + 1 tokens ahead and the boundary ppermute "
+            f"carries one token; train it under sp on one device")
     idx = jax.lax.axis_index(axis_name)
     s_local = tokens.shape[1]
     positions = idx * s_local + jnp.arange(s_local)
@@ -82,13 +118,18 @@ def _local_nexttoken_loss(model, axis_name: str, params, tokens):
     perm = [(j, (j - 1) % n) for j in range(n)]
     with device_scope("loss"):
         first_next = jax.lax.ppermute(tokens[:, :1], axis_name, perm)
-        targets = jnp.concatenate([tokens[:, 1:], first_next], axis=1)
-        # The global last token has no target: weight it out.
-        is_global_last = positions == (n * s_local - 1)
-        w = jnp.broadcast_to(jnp.where(is_global_last, 0.0, 1.0),
-                             tokens.shape)
+        # The global last token has no target: weighted out.
+        targets, w = _targets_and_weights(tokens, first_next, positions,
+                                          n * s_local, heads)
         loss_sum, count = next_token_loss(logits, targets, w)
-        return loss_sum, (count, lm_counters(sown))
+        counters = lm_counters(sown)
+        if heads > 1:
+            # one shard holds the sequence, so the local mean is the mean
+            own, n_own = next_token_loss(
+                jax.lax.stop_gradient(logits[:, :, 0]), targets[..., 0],
+                w[..., 0])
+            counters["next_token_loss_head0"] = own / n_own
+        return loss_sum, (count, counters)
 
 
 def make_sp_train_step(model, tx, mesh: Mesh, *, axis_name: str = "data",

@@ -85,6 +85,8 @@ def build_lm_oracle(cfg) -> Tuple[Callable, Callable]:
     @jax.jit
     def loss_fn(params, tokens, moe_state=None):
         logits = apply(params, tokens, moe_state).astype(jnp.float32)
+        if logits.ndim == 4:    # several prediction heads: head 0 is the next token's
+            logits = logits[:, :, 0]
         return optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :-1], tokens[:, 1:]).mean()
 

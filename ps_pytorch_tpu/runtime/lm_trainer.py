@@ -186,6 +186,13 @@ class LMTrainer:
                 if arch.layer_kind(i, cfg.lm_layers) in ATTENTION_KINDS}
             kernels += [f"flash_attention[{sched.describe()}]"
                         for sched in sorted(scheds, key=lambda sc: sc.window)]
+        if "eva" in arch.mixer_layers and self.model.attention_impl == "flash":
+            from ps_pytorch_tpu.ops.eva_attention import eva_schedule
+            kernels.append("eva_attention[" + eva_schedule(
+                rows * cfg.lm_heads, cfg.lm_seq_len,
+                cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads,
+                jnp.dtype(self.model.dtype).itemsize, arch.eva_window,
+                arch.eva_chunk).describe() + "]")
         if arch.hybrid:
             kernels.append("selective_scan[" + scan_schedule(
                 rows, cfg.lm_seq_len, arch.ssm_expand * cfg.lm_d_model,
@@ -477,7 +484,8 @@ class LMTrainer:
             # z_loss, expert_load_max_over_mean, moe_dropped,
             # moe_held_share; under a selection bias moe_bias_abs_max and
             # moe_load_all_max_over_mean) and what the model counted (a
-            # hybrid arch's ssm_state_abs_max and diff_lambda_max under sp,
+            # hybrid arch's ssm_state_abs_max and diff_lambda_max and an EVA
+            # arch's eva_pool_weight_max and next_token_loss_head0 under sp,
             # a linear-attention arch's gdn_state_abs_max and a Mamba-2
             # arch's ssd_state_abs_max under ep) come
             # with the loss.

@@ -501,6 +501,12 @@ HYBRID_GAUGES = (
     ("ssd_state_abs_max", "", "largest |h| over the Mamba-2 layers' head-wise "
      "states at the state-space-dual form's chunk boundaries: the walk's "
      "numerical health"),
+    ("eva_pool_weight_max", "", "largest pooling weight over the EVA layers' "
+     "chunks and heads: 1/chunk is a mean, 1.0 a chunk read through one "
+     "token"),
+    ("next_token_loss_head0", "", "head 0's own mean loss where the arch has "
+     "several prediction heads: the next-token loss that compares with other "
+     "models (train_loss is the mean over every head)"),
 )
 TRAINING_GAUGES = (
     ("train_step", "step", "current training step"),

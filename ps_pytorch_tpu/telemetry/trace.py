@@ -74,7 +74,8 @@ DEVICE_SCOPES = (
     "embed",         # token and position rows, the embedding's multiplier
     "attn_proj",     # pre-norm, q / k / v / gate / o projections, post_attn_norm
     "attn_pos",      # q/k norm, RoPE, to_heads and its inverse, the gate's product; differential heads' lambda, difference and norm
-    "attn_core",     # flash / full / ring / cached attention
+    "attn_core",     # flash / full / ring / cached attention; an EVA layer's core (both kernels, the copies round them)
+    "eva_pool",      # an EVA layer's chunk summaries: the pooling forward and backward, phi's and mu's sums
     "ffn",           # a dense feed-forward: norm, its matmuls, activation
     "ssm_proj",      # a Mamba (-1 or -2) layer's norm, in / x / dt / out projections, the residual sum
     "ssm_conv",      # ... its causal convolution, silu, softplus, the gate's product (Mamba-2: the gated group norm)

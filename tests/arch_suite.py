@@ -93,6 +93,7 @@ class ArchCase:
     scopes: frozenset = LM_SCOPES           # the device scopes of the compiled step
     remat_scopes: frozenset = frozenset()   # a block's interior, recomputed under --remat
     another_depth: int = 1  # a depth a checkpoint of this one's is refused at
+    pred_heads: int = 1     # prediction heads: logits [B, S, pred_heads, V] where > 1
     refusals: tuple = ()    # (id, entry(case, tmp_path), words the message must hold)
     published_row: dict = dataclasses.field(default_factory=dict)  # ARCHS field -> published key
     shares: Optional[Callable] = None       # side -> (parts, uncut): rows with held experts
@@ -404,9 +405,11 @@ def unscoped_lowering(case):
     first two are texts without locations, which is all a scope adds."""
     def build():
         from ps_pytorch_tpu.models import gdn, moe, ssm
+        from ps_pytorch_tpu.ops import eva_attention
         from ps_pytorch_tpu.parallel import dp, ep, sp
         lowered = _lowered_again(
-            case, True, "unscoped", (tr_mod, moe, ssm, gdn, dp, sp, ep),
+            case, True, "unscoped",
+            (tr_mod, moe, ssm, gdn, eva_attention, dp, sp, ep),
             "device_scope", lambda name: contextlib.nullcontext())
         return (step(case, True).step_fn.stablehlo_text, lowered.as_text(),
                 lowered.as_text(dialect="hlo", debug_info=True))
@@ -631,7 +634,8 @@ def install(namespace, case):
         _, _, tokens = tiny(case)
         got, stats = logits(case, attention)
         want = reference_logits(case)
-        assert got.shape == want.shape == (*tokens.shape, case.vocab)
+        heads = (case.pred_heads,) if case.pred_heads > 1 else ()
+        assert got.shape == want.shape == (*tokens.shape, *heads, case.vocab)
         assert float(jnp.abs(want).max()) > 2
         assert float(jnp.abs(got - want).max()) < case.logit_tol
         if row_is_ep:

@@ -1,13 +1,14 @@
 """The OLMoE cell's grouped matmuls, the SmallThinker, Trinity, Phi-4-flash,
 Qwen3-Next and Nemotron-3-Nano cells' flash kernels, the Phi-4-flash cell's
 selective scan, the Qwen3-Next cell's gated delta rule and mixer chains and
-the Nemotron-3-Nano cell's state-space-dual kernels compile under
+the Nemotron-3-Nano cell's state-space-dual kernels and the EvaByte cell's EVA
+core and pooling compile under
 Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses; and, under ``-m slow``, the
 long-context cells' whole train steps, for the bytes the compiler plans on the
 device. The kernel-shape compiles are tier-1's: no chip run says which kernel
-or which tile. The five whole-step compiles (one parametrised test over
+or which tile. The six whole-step compiles (one parametrised test over
 ``STEP_CELLS``; 260-350 s together at PR 46) are marked ``slow`` since PR 42: that the cell's step fits the chip is what the
 driver measures on a v5e in that very cell on every PR (``peak_hbm``; a step
 that does not fit fails the cell). Run them when a PR moves a step's plan.
@@ -129,7 +130,7 @@ def test_flash_grouped_query_window_compiles_at_the_cells_shape(one_chip,
 
 # The Pallas modules whose calls pick the interpreter where no chip is attached.
 KERNEL_MODULES = ("flash_attention", "selective_scan", "gated_delta_rule",
-                  "gdn_mix", "ssd", "ssm_mix")
+                  "gdn_mix", "ssd", "ssm_mix", "eva_attention")
 
 
 def whole_step(one_chip, monkeypatch, config, traffic):
@@ -251,6 +252,10 @@ STEP_CELLS = {
          "ssm_conv_bwd_x": 4, "ssm_conv_bwd_b": 4, "ssm_conv_bwd_c": 4,
          "ssm_norm_fwd": 8, "ssm_norm_bwd": 4}, "flash_win_",
         14_501_435_392),
+    "evabyte_s16384_1chip": StepCell(
+        "evabyte_6_5b", "s16384_bytes_1chip", (8, 0), 821_366_784,
+        {"eva_pool_fwd": 4, "eva_fwd": 4, "eva_bwd": 4, "eva_pool_bwd": 4},
+        "flash_", 12_189_031_936),
 }
 HEAP_PACKING_BYTES = 2 ** 20
 
@@ -716,6 +721,58 @@ def test_ssm_mix_compiles_at_the_cells_shape(one_chip):
     in_kernels = sum(sc[-4:])
     assert 2.07e9 < in_kernels < 2.07e9 * 1.01
     assert in_kernels + sum(op[4] for op in ops if not op[2]) < 2.6e9
+
+
+def test_eva_attention_compiles_at_the_cells_shape(one_chip):
+    """evabyte_s16384_1chip: 32 heads of 128 at S=16384, bfloat16, windows of
+    2048 in chunks of 16: the pooling pair (512 tokens a step, the chunks'
+    softmax as a [32, 512] matrix), the core's forward (a q tile of 512, its
+    window's K/V and the head's 1024 summaries whole) and the one backward (a
+    window a step, the summaries' float32 gradients resident a head) compile
+    for a described v5e. What the calls move is the schedule's count, the
+    core's backward hands the pooling's backward float32 ``[32, 1024, 128]``
+    sums and no XLA op round the kernels holds a float32 ``[32, 16384, 128]``:
+    dK and dV go from the core into the pooling's backward in bfloat16 and are
+    added to there, in place."""
+    from ps_pytorch_tpu.ops.eva_attention import eva_attention, eva_schedule
+    b, h, s, d, window, chunk = 1, 32, 16384, 128, 2048, 16
+
+    def loss(q, k, v, phi, mu):
+        o, top = eva_attention(q, k, v, phi, mu, window=window, chunk=chunk,
+                               interpret=False)
+        return jnp.sum(o.astype(jnp.float32)) + top
+
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    rows = arg((b, h, s, d), jnp.bfloat16)
+    vec = arg((h, d), jnp.float32)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        rows, rows, rows, vec, vec).compile()
+    ops = _entry_ops(compiled.as_text())
+    mosaic = {op[0].split(".")[0].split("jvp_")[-1].strip("_"): op
+              for op in ops if op[2]}
+    assert sorted(mosaic) == ["eva_bwd", "eva_fwd", "eva_pool_bwd",
+                              "eva_pool_fwd"]
+    sc = eva_schedule(b * h, s, d, 2, window, chunk)
+    assert (sc.block_q, sc.block_s, sc.pool_rows) == (512, 128, 512)
+    assert mosaic["eva_fwd"][4] == sc.fwd_bytes
+    assert mosaic["eva_bwd"][4] == sc.bwd_bytes
+    sums = ("f32", f"{b * h},{s // chunk},{d}")
+    assert mosaic["eva_bwd"][3].count(sums) == 2
+    # the pooling's calls also move phi, mu, the step's largest weight and
+    # dphi's row a step (an [8, 128] float32 tile each, 4 MiB a call): under
+    # a fiftieth of the rows
+    assert sc.pool_fwd_bytes <= mosaic["eva_pool_fwd"][4] \
+        < 1.02 * sc.pool_fwd_bytes
+    assert sc.pool_bwd_bytes <= mosaic["eva_pool_bwd"][4] \
+        < 1.02 * sc.pool_bwd_bytes
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        for dtype, dims in shapes:
+            assert not (dtype == "f32" and math.prod(
+                int(n) for n in dims.split(",") if n) >= h * s * d), \
+                (name, opcode, dims)
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 ** 30
 
 
 def test_tiles_keep_the_weight_buffers_inside_their_budget():

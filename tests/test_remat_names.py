@@ -12,6 +12,7 @@ import pytest
 
 from ps_pytorch_tpu.models import moe, remat, ssm, transformer
 from ps_pytorch_tpu.models.moe import MoETransformerLM
+from ps_pytorch_tpu.ops.eva_attention import SAVED_NAMES as EVA_SAVED_NAMES
 from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES
 
 MODELS = (transformer, moe, ssm)
@@ -33,8 +34,10 @@ def test_every_name_of_the_tuple_is_given_once_in_the_models():
         with open(mod.__file__) as f:
             given += [name for name in re.findall(r'"(\w+)"', f.read())
                       if name in remat.KEPT_NAMES]
-    assert sorted(given) == sorted(set(remat.KEPT_NAMES) - set(SAVED_NAMES))
-    assert remat.KEPT_NAMES[:len(SAVED_NAMES)] == SAVED_NAMES
+    # the forward kernels' own names are given inside their ``custom_vjp``s
+    kernels = SAVED_NAMES + EVA_SAVED_NAMES
+    assert sorted(given) == sorted(set(remat.KEPT_NAMES) - set(kernels))
+    assert remat.KEPT_NAMES[:len(kernels)] == kernels
     assert len(set(remat.KEPT_NAMES)) == len(remat.KEPT_NAMES)
 
 
