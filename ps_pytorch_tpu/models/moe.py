@@ -67,7 +67,7 @@ from ps_pytorch_tpu.telemetry.trace import device_scope
 # on), with how each is taken over the layers.
 _OVER_LAYERS = {"aux": jnp.mean, "z_loss": jnp.mean,
                 "expert_load_max_over_mean": jnp.max, "moe_dropped": jnp.sum,
-                "moe_held_share": jnp.mean}
+                "moe_held_share": jnp.mean, "moe_tail_rows_share": jnp.mean}
 DROPLESS_STATS = tuple(_OVER_LAYERS)
 # The collection of what no gradient moves: ``expert_bias`` [experts] in each
 # expert layer whose arch chooses under a bias. It travels in
@@ -249,6 +249,17 @@ HELD_ROWS_TILE = 512      # ... in whole multiples of this many rows
 _GATE_EPS = 1e-20         # under the sum of sigmoid gates (torchtitan's)
 
 
+def held_rows(assignments: int, held: int, experts: int) -> int:
+    """Rows the main part of a layer with ``held`` of ``experts`` experts is
+    sized for, of ``assignments`` (T * k): all of them, or the held block's
+    balanced share with ``HELD_ROWS_SLACK``, in whole row tiles."""
+    if held == experts:
+        return assignments
+    return min(assignments,
+               -(-int(HELD_ROWS_SLACK * assignments * held / experts)
+                 // HELD_ROWS_TILE) * HELD_ROWS_TILE)
+
+
 def _when(pred, fn, args, ints):
     """``fn(*args, *ints)`` where ``pred``, else zeros of its shape, with
     neither pass run nor any residual kept where it is false: the backward
@@ -423,7 +434,10 @@ class DroplessMoE(nn.Module):
     ``z_loss`` = mean logsumexp(r)^2, ``expert_load_max_over_mean`` = busiest
     HELD expert's assignments / (T*k/E), ``moe_held_share`` = assignments to
     held experts / (T*k), ``moe_dropped`` = held assignments whose output was
-    not added (counted from the combine's own indices; 0 by construction).
+    not added (counted from the combine's own indices; 0 by construction),
+    ``moe_tail_rows_share`` = rows of the main part that no held group owns /
+    rows it is sized for: the share of the grouped matmuls' row tiles that are
+    visited and not multiplied (0 where every expert is held).
     """
     n_experts: int
     d_model: int
@@ -536,11 +550,7 @@ class DroplessMoE(nn.Module):
                 out = out.astype(jnp.float32) * flat_gates[order][:, None]
                 return jnp.zeros((t, d), jnp.float32).at[order // k].add(out)
 
-        # Rows the main part is sized for: all of them, or the held block's
-        # balanced share with HELD_ROWS_SLACK, in whole row tiles.
-        rows = t * k if held == e else min(
-            t * k, -(-int(HELD_ROWS_SLACK * t * k * held / e)
-                     // HELD_ROWS_TILE) * HELD_ROWS_TILE)
+        rows = held_rows(t * k, held, e)    # the main part's
         weights = (w_gate, w_up, w_down) if self.gated else (w_up, w_down)
         if rows == t * k:
             with device_scope("moe_route"):       # the sort's inverse
@@ -581,6 +591,8 @@ class DroplessMoE(nn.Module):
                     jnp.max(group_sizes).astype(jnp.float32) * e / (t * k),
                 "moe_dropped": (n_held_rows - added).astype(jnp.float32),
                 "moe_held_share": n_held_rows.astype(jnp.float32) / (t * k),
+                "moe_tail_rows_share": 1.0 - jnp.minimum(
+                    n_held_rows, rows).astype(jnp.float32) / rows,
             }
         if self.select_bias:
             stats[EXPERT_COUNTS] = {"expert_bias": load}
@@ -731,7 +743,8 @@ class MoETransformerLM(nn.Module):
     scalar sum of the layers' load-balance losses; for a dropless arch a dict
     keyed by ``DROPLESS_STATS`` over the expert layers (``aux`` and ``z_loss``
     averaged over layers, the busiest layer's ``expert_load_max_over_mean``,
-    ``moe_dropped`` summed, ``moe_held_share`` averaged) and, where the arch
+    ``moe_dropped`` summed, ``moe_held_share`` and ``moe_tail_rows_share``
+    averaged) and, where the arch
     chooses under a bias, ``EXPERT_COUNTS``: each layer's assignments to every
     router output, a tree of the ``MOE_STATE`` collection's shape. What the
     mixers count (``COUNTER_NAMES``: a linear-attention layer's

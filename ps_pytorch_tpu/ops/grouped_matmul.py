@@ -19,7 +19,12 @@ three uses)
   read it; the grid has the static worst case ``M/tm + E`` visits and the
   ones past the real count are skipped (their block indices repeat the last
   real step's, so they move no data).
-- Rows past the groups are a pseudo-group E whose visits store zeros.
+- Rows past the groups are a pseudo-group E whose visits store zeros and do
+  nothing else: no matmul, and the rows' block index repeats the last
+  multiplying visit's, so no row of theirs is fetched. A layer that holds a
+  share of its experts sizes its rows for 1.5 times what the share draws at
+  balance (``models/moe.py:HELD_ROWS_SLACK``), so a third of its row tiles
+  are such (v5e, PR 48: PERF.md, Findings).
 - ``moe_gmm_fwd``: grid (N tiles, visits, K tiles), f32 accumulator over K.
   ``moe_gmm_dlhs`` is the same kernel reading ``rhs`` transposed (the
   contraction runs over its last axis): no [E, N, K] copy of the weights is
@@ -50,6 +55,7 @@ PR 25 has the A/B.
 """
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +110,41 @@ def _tiles(m: int, k: int, n: int, dtype, weights_dtype=None):
     return _fit(m, tm, 8), lanes(k, tk), lanes(n, tn)
 
 
+class GmmSchedule(NamedTuple):
+    """What the three kernels of one expert matmul ``[rows, d] x [groups, d,
+    f]`` do, from its shape alone (the matmul back to ``d`` runs the same
+    three with ``fwd`` and ``dlhs`` exchanged and ``drhs``'s last two, as
+    does one against weights stored transposed, ``gmm_t``)."""
+    fwd: tuple          # (tm, tk, tn) of moe_gmm_fwd
+    dlhs: tuple         # ... of moe_gmm_dlhs: [rows, f], the weights transposed
+    drhs: tuple         # ... of moe_gmm_drhs
+    row_tiles: int      # row tiles of tm the call is sized for
+    row_tiles_at_balance: int   # ... the groups own at balance; the rest
+                                # are visited and not multiplied
+    visits: int         # fwd's and dlhs's grid along the visits (worst case)
+    weight_bytes: int   # weights a fwd or dlhs call streams: every group's, once
+
+    def describe(self) -> str:
+        return " ".join(
+            f"{name}={'/'.join(map(str, v)) if isinstance(v, tuple) else v}"
+            for name, v in self._asdict().items())
+
+
+def gmm_schedule(rows: int, rows_at_balance: int, d: int, f: int, groups: int,
+                 itemsize: int = 2, weights_itemsize: int = 4) -> GmmSchedule:
+    """The ``KERNELS`` line's record of an expert layer's grouped matmuls:
+    ``rows`` the layer sizes its sorted rows for, ``rows_at_balance`` of them
+    the held groups' at balance (``models/moe.py:held_rows``)."""
+    dtype, weights = (
+        {2: jnp.bfloat16, 4: jnp.float32}[size]
+        for size in (itemsize, weights_itemsize))
+    fwd = _tiles(rows, d, f, dtype, weights)
+    return GmmSchedule(
+        fwd, _tiles(rows, f, d, dtype, weights), _tiles(rows, d, f, dtype),
+        rows // fwd[0], -(-rows_at_balance // fwd[0]),
+        rows // fwd[0] + groups, groups * d * f * weights_itemsize)
+
+
 def _dot(a, b, *, trans_a=False, trans_b=False):
     ca = 0 if trans_a else 1
     cb = 1 if trans_b else 0
@@ -115,7 +156,8 @@ def _visits(group_sizes, m: int, tm: int, *, remainder: bool,
             visit_empty: bool):
     """The kernels' schedule: -> (offsets [G+1], group_ids [V], tile_ids [V],
     n_visits [1]), int32, V = M/tm + G - 1 the static worst case. With
-    ``remainder`` the rows past the groups are one more group (G = E + 1)."""
+    ``remainder`` the rows past the groups are one more group (G = E + 1),
+    visited last."""
     gs = group_sizes.astype(jnp.int32)
     if remainder:
         gs = jnp.concatenate([gs, (m - jnp.sum(gs))[None]])
@@ -134,6 +176,20 @@ def _visits(group_sizes, m: int, tm: int, *, remainder: bool,
     tile_ids = first_tile[group_ids] + i - (tile_ends - tiles)[group_ids]
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
     return offsets, group_ids, tile_ids.astype(jnp.int32), n_visits[None]
+
+
+def _lhs_block(i, ki, offsets, tile_ids, n_visits, *, n_groups: int,
+               row_tiles: int, tm: int, last_k: int):
+    """The block of rows visit ``i`` of ``moe_gmm_fwd|dlhs`` reads at K step
+    ``ki``. A visit that multiplies nothing (the rows past the groups, whose
+    tiles are the schedule's last, and the grid's steps past its end) must
+    move no data: it keeps the last multiplying visit's final block instead
+    of fetching its own rows or walking K again."""
+    covered = offsets[n_groups]
+    own = n_visits[0] - jnp.where(covered < row_tiles * tm,
+                                  row_tiles - covered // tm, 0)
+    at = jnp.minimum(i, jnp.maximum(own - 1, 0))
+    return tile_ids[at], jnp.where(i < own, ki, last_k)
 
 
 def _rows_of_group(offs_ref, gid_ref, tid_ref, i, shape, tm):
@@ -165,6 +221,10 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, slot_ref, next_ref,
     live = i < nv_ref[0]
     g = gid_ref[i]
     s = slot_ref[i]
+    # Rows past the groups (group n_groups) are multiplied by nothing: their
+    # visits fetch no weights and no rows (``_lhs_block``), run no matmul and
+    # only store zeros over whatever the accumulator still holds.
+    own = live & (g < n_groups)
     first = (i == 0) | (g != gid_ref[jnp.maximum(i - 1, 0)])
     w = cast[0] if cast else None   # the group's weights in the rows' dtype
 
@@ -182,8 +242,8 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, slot_ref, next_ref,
     # started on the first visit of the group BEFORE it, so that it has that
     # group's every visit to arrive in; the automatic pipeline looks one
     # grid step ahead, which a float32 tile under a few rows of bfloat16
-    # outlasts. Rows past the groups (group n_groups) store zeros: no weights.
-    @pl.when(live & first & (k == 0) & (g < n_groups))
+    # outlasts.
+    @pl.when(own & first & (k == 0))
     def _weights():
         @pl.when(i == 0)
         def _():
@@ -197,11 +257,11 @@ def _gmm_kernel(offs_ref, gid_ref, tid_ref, nv_ref, slot_ref, next_ref,
             for c in range(w.shape[0]):
                 w[c] = k_tile(wbuf.at[s], c).astype(w.dtype)
 
-    @pl.when(live & (k == 0))
+    @pl.when(own & (k == 0))
     def _init():
         acc[:] = jnp.zeros_like(acc)
 
-    @pl.when(live)
+    @pl.when(own)
     def _tile():
         rhs = w[k] if cast else k_tile(wbuf.at[s], k)
         acc[:] += _dot(lhs_ref[...], rhs, trans_b=trans_rhs)
@@ -230,12 +290,10 @@ def _gmm_call(lhs, rhs, group_sizes, *, tiles, trans_rhs: bool, name: str,
     tm, tk, tn = tiles
     offs, gid, tid, nv = _visits(group_sizes, m, tm, remainder=True,
                                  visit_empty=False)
-    last_k = k // tk - 1
 
     def lhs_map(ni, i, ki, offs, gid, tid, nv, slot, nxt):
-        # A skipped visit must not move data: keep the last real visit's
-        # final K block instead of walking K again.
-        return tid[i], jnp.where(i < nv[0], ki, last_k)
+        return _lhs_block(i, ki, offs, tid, nv, n_groups=e,
+                          row_tiles=m // tm, tm=tm, last_k=k // tk - 1)
 
     w_tile = (tn, tk) if trans_rhs else (tk, tn)
     w_block = (tn, k) if trans_rhs else (k, tn)

@@ -46,7 +46,8 @@ _EXPERT_KEY = "experts_"   # models/moe.py stacked expert param names
 # How each of the model's routing statistics crosses the data axis.
 _STAT_REDUCE = {"aux": jax.lax.pmean, "z_loss": jax.lax.pmean,
                 "expert_load_max_over_mean": jax.lax.pmax,
-                "moe_dropped": jax.lax.psum, "moe_held_share": jax.lax.pmean}
+                "moe_dropped": jax.lax.psum, "moe_held_share": jax.lax.pmean,
+                "moe_tail_rows_share": jax.lax.pmean}
 
 
 def ep_param_specs(params, axis: str = "data"):
@@ -104,8 +105,9 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                        remat: bool = False, donate: bool = True) -> Callable:
     """-> step_fn(state, tokens) -> (state, {'loss', 'aux'}); a dropless
     arch adds the rest of ``DROPLESS_STATS``: 'z_loss',
-    'expert_load_max_over_mean', 'moe_dropped', 'moe_held_share', and its
-    loss the arch's z-loss term, scaled by the token count as ``aux`` is.
+    'expert_load_max_over_mean', 'moe_dropped', 'moe_held_share',
+    'moe_tail_rows_share', and its loss the arch's z-loss term, scaled by the
+    token count as ``aux`` is.
     The load-balance term's coefficient is the arch's too
     (``Arch.aux_coef``). A model that holds a share of its
     experts (``experts_held``) trains that share's part of each layer, on one

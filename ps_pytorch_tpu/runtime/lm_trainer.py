@@ -225,7 +225,15 @@ class LMTrainer:
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
         if arch.dropless:
-            kernels.append("grouped_matmul")
+            from ps_pytorch_tpu.models.moe import held_rows
+            from ps_pytorch_tpu.ops.grouped_matmul import gmm_schedule
+            held = cfg.lm_experts_held or cfg.lm_experts
+            assignments = rows * cfg.lm_seq_len * cfg.lm_moe_top_k
+            kernels.append("grouped_matmul[" + gmm_schedule(
+                held_rows(assignments, held, cfg.lm_experts),
+                assignments * held // cfg.lm_experts, cfg.lm_d_model,
+                cfg.lm_ffn_dim or 4 * cfg.lm_d_model, held,
+                jnp.dtype(self.model.dtype).itemsize).describe() + "]")
         # What the run really computes in is read from the built model, not
         # from the flag: the line here, the first JSONL record and the gauge.
         self.compute_dtype = jnp.dtype(self.model.dtype)
@@ -482,8 +490,9 @@ class LMTrainer:
                          epoch):
             # The ep step's routing statistics (aux; a dropless arch's
             # z_loss, expert_load_max_over_mean, moe_dropped,
-            # moe_held_share; under a selection bias moe_bias_abs_max and
-            # moe_load_all_max_over_mean) and what the model counted (a
+            # moe_held_share, moe_tail_rows_share; under a selection bias
+            # moe_bias_abs_max and moe_load_all_max_over_mean) and what the
+            # model counted (a
             # hybrid arch's ssm_state_abs_max and diff_lambda_max and an EVA
             # arch's eva_pool_weight_max and next_token_loss_head0 under sp,
             # a linear-attention arch's gdn_state_abs_max and a Mamba-2

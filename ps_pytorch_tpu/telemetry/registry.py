@@ -467,7 +467,7 @@ TRAINING_COUNTERS = (
      "the host loop keeps ahead of the chip)"),
 )
 # An MoE step's routing statistics (parallel/ep.py: aux; a dropless arch adds
-# the next four, one that chooses under a bias the last two), set by LMTrainer on every logged step under the names the
+# the next five, one that chooses under a bias the last two), set by LMTrainer on every logged step under the names the
 # JSONL record gives them.
 ROUTING_GAUGES = (
     ("aux", "", "MoE load-balance loss, experts * sum_e(share of assignments "
@@ -480,6 +480,10 @@ ROUTING_GAUGES = (
      "was not added (a dropless router must read 0)"),
     ("moe_held_share", "", "assignments to the experts held here over "
      "tokens*top_k, mean over layers (1 where every expert is held)"),
+    ("moe_tail_rows_share", "", "rows of a layer's main part that no held "
+     "group owns over the rows it is sized for, mean over layers: the share "
+     "of the grouped matmuls' row tiles visited and not multiplied (0 where "
+     "every expert is held)"),
     ("moe_bias_abs_max", "", "largest |expert_bias| over layers and experts "
      "after the step's move: how far the balancing has shifted the top-k's "
      "choice (archs that choose under a bias)"),

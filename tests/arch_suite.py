@@ -708,6 +708,11 @@ def install(namespace, case):
             if case.tiny_row.router_bias_rate:
                 want |= set(BIAS_STATS)
             assert float(m["moe_dropped"]) == 0.0
+            # rows the grouped matmuls visit and do not multiply: some under
+            # a held share (its part is sized past what it draws), else none
+            tail = float(m["moe_tail_rows_share"])
+            assert 0 < tail < 1 if case.flags.get("lm_experts_held") \
+                else tail == 0
         assert set(m) == want
         assert all(np.isfinite(float(v)) for v in m.values())
         for name, (low, high) in case.counters.items():

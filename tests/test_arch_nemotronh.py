@@ -363,7 +363,10 @@ def test_the_kernels_line_and_bfloat16_reach_the_layers(tmp_path):
         "norm_lanes=32 norm_rows=96 norm_chunk=96 norm_grid=2x1x1 " \
         "conv_fwd_bytes=" in kernels
     assert "gated_delta_rule" not in kernels and "selective_scan" not in kernels
-    assert "grouped_matmul mode=interpret dtype=float32" in kernels
+    # 512 rows sized for 144 at balance (4 of 16 experts, 576 assignments)
+    assert " grouped_matmul[fwd=512/32/16 dlhs=512/16/32 drhs=512/32/16 " \
+        "row_tiles=1 row_tiles_at_balance=1 visits=5 weight_bytes=8192] " \
+        "mode=interpret dtype=float32" in kernels
 
     narrow = build_lm_model(CASE.train_config(compute_dtype="bfloat16",
                                               train_dir=str(tmp_path)))

@@ -283,7 +283,10 @@ def test_the_kernels_line_and_bfloat16_reach_the_layers(tmp_path):
     assert kernels.count("flash_attention[") == 1     # one kind of attention layer
     assert "gated_delta_rule[chunk=64 chunks=2 group=2 grid=4x1 heads=2 solve_grid=4x1 " in kernels
     assert " gdn_mix[lanes=32 rows=96 chunk=96 halo=16 conv_grid=2x4x1 norm_grid=2x2x1 conv_fwd_bytes=" in kernels
-    assert "grouped_matmul mode=interpret dtype=float32" in kernels
+    # 512 rows sized for 144 at balance (4 of 16 experts, 576 assignments)
+    assert " grouped_matmul[fwd=512/32/16 dlhs=512/16/32 drhs=512/32/16 " \
+        "row_tiles=1 row_tiles_at_balance=1 visits=5 weight_bytes=8192] " \
+        "mode=interpret dtype=float32" in kernels
 
     narrow = build_lm_model(CASE.train_config(compute_dtype="bfloat16",
                                               train_dir=str(tmp_path)))

@@ -84,9 +84,13 @@ def test_the_layer_is_the_plain_take_and_scatter_add(monkeypatch, name):
         assert int(stats[EXPERT_COUNTS]["expert_bias"].sum()) == t_k
     if taken is None:
         assert float(stats["moe_held_share"]) == 1.0
+        assert float(stats["moe_tail_rows_share"]) == 0.0
     else:
         held, rows = PLAIN.held_and_main_rows(layer, stats, slack)
         assert 0 < held < t_k and (held > rows) == taken, (held, rows)
+        # the main part's rows that no held group owns, of those it is sized for
+        assert float(stats["moe_tail_rows_share"]) == pytest.approx(
+            max(rows - held, 0) / rows, rel=1e-6)
     rig_out = fields.get("rig_out")
     if rig_out is not None:
         idx = jax.lax.top_k(x.reshape(-1, 16).astype(jnp.float32)
@@ -250,4 +254,4 @@ def test_the_dropless_layer_with_every_expert_is_the_parents():
     assert {k: float(v) for k, v in stats.items()} == {
         "aux": 4.017381191253662, "z_loss": 6.1970930099487305,
         "expert_load_max_over_mean": 1.1666666269302368, "moe_dropped": 0.0,
-        "moe_held_share": 1.0}
+        "moe_held_share": 1.0, "moe_tail_rows_share": 0.0}

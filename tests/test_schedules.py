@@ -117,3 +117,47 @@ def test_the_mamba2_mixers_kernel_record_names_every_field(s, rows,
     for name, value in sc._asdict().items():
         assert said[name] == ("x".join(map(str, value))
                               if isinstance(value, tuple) else str(value))
+
+
+# (assignments T*k a layer, router outputs, experts held, d, f) of the five
+# sparse cells -> (rows the layer sizes, fwd tiles, row tiles, of them owned
+# at balance, visits)
+GMM_CELLS = {
+    "smallthinker_s16384_1chip": ((98304, 64, 16, 2560, 768),
+                                  (36864, (256, 1280, 768), 144, 96, 160)),
+    "trinity_mini_s8192_1chip": ((131072, 128, 16, 2048, 1024),
+                                 (24576, (256, 2048, 1024), 96, 64, 112)),
+    "nemotron3nano_s16384_1chip": ((98304, 128, 16, 2688, 1856),
+                                   (18432, (256, 896, 1856), 72, 48, 88)),
+    "qwen3next_s16384_1chip": ((163840, 512, 64, 2048, 512),
+                               (30720, (256, 2048, 512), 120, 80, 184)),
+    "olmoe_s4096_1chip": ((32768, 64, 64, 2048, 1024),
+                          (32768, (256, 2048, 1024), 128, 128, 192)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GMM_CELLS))
+def test_the_grouped_matmuls_kernel_record_at_the_cells_shapes(cell):
+    """``gmm_schedule`` (``ops/grouped_matmul.py``, PR 48), the ``KERNELS``
+    line's record of an expert layer's grouped matmuls, as ``LMTrainer``
+    calls it, at bfloat16 rows on float32 weights: a layer that holds a share
+    sizes a third of its row tiles past what its groups own at balance
+    (``models/moe.py:held_rows``, ``HELD_ROWS_SLACK``: those tiles are visited
+    and not multiplied), OLMoE's none; every field is said as ``name=value``,
+    a tile as ``tm/tk/tn``."""
+    from ps_pytorch_tpu.models.moe import held_rows
+    from ps_pytorch_tpu.ops.grouped_matmul import gmm_schedule
+    (assignments, experts, held, d, f), (rows, fwd, tiles, owned, visits) = \
+        GMM_CELLS[cell]
+    assert held_rows(assignments, held, experts) == rows
+    sc = gmm_schedule(rows, assignments * held // experts, d, f, held)
+    assert (sc.fwd, sc.row_tiles, sc.row_tiles_at_balance, sc.visits) == \
+        (fwd, tiles, owned, visits)
+    assert sc.dlhs == (fwd[0], fwd[2], fwd[1])  # the same weights, read transposed
+    assert sc.drhs[0] == fwd[0] and sc.weight_bytes == held * d * f * 4
+    assert 3 * (tiles - owned) == (tiles if held < experts else 0)
+    said = dict(item.split("=") for item in sc.describe().split())
+    assert list(said) == list(sc._fields)
+    for name, value in sc._asdict().items():
+        assert said[name] == ("/".join(map(str, value))
+                              if isinstance(value, tuple) else str(value))
