@@ -27,7 +27,6 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 from ps_pytorch_tpu.data import augment
-from ps_pytorch_tpu.telemetry.trace import span as _span
 
 # dataset -> (H, W, C, num_classes, train_size_hint)
 DATASET_SHAPES = {
@@ -241,13 +240,7 @@ class DataLoader:
     def _assemble(self, b: int, order: np.ndarray, epoch: int,
                   aug_rng) -> Tuple[np.ndarray, np.ndarray]:
         """Assemble local batch ``b`` of one epoch — the unit of work both
-        the single prefetch thread and the worker pool run — under the
-        ambient span ``loader_assemble`` (a no-op without a tracer)."""
-        with _span("loader_assemble", epoch=epoch, batch=b):
-            return self._assemble_batch(b, order, epoch, aug_rng)
-
-    def _assemble_batch(self, b: int, order: np.ndarray, epoch: int,
-                        aug_rng) -> Tuple[np.ndarray, np.ndarray]:
+        the single prefetch thread and the worker pool run."""
         sel = order[b * self.local_batch:(b + 1) * self.local_batch]
         norm_out = not self.device_normalize
         if self._rrc is not None:

@@ -57,20 +57,111 @@ from ps_pytorch_tpu.telemetry import (
     Tracer, declare_resilience_metrics,
     declare_training_metrics, derive_step_record,
     device_memory_record, host_rss_bytes, set_default_tracer,
-    set_device_memory_gauges,
+    set_device_memory_gauges, startup_line,
+)
+from ps_pytorch_tpu.utils.compile_cache import (
+    COMPILE_COUNTERS, count_compiles,
 )
 from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
 
 class LMTrainer:
     def __init__(self, cfg: TrainConfig):
+        # The tracer exists before anything is built, as in the CNN Trainer:
+        # the constructor is one tree under the span ``setup``
+        # (telemetry/trace.py), and a compile anywhere in it is counted in
+        # the span that caused it (utils/compile_cache.py, into the registry
+        # too).
+        count_compiles()
+        self.registry = declare_training_metrics(Registry())
+        self.tracer = Tracer(registry=self.registry,
+                             counters=COMPILE_COUNTERS)
+        self._prev_tracer = set_default_tracer(self.tracer)
+        try:
+            with self.tracer.setup_span():
+                self._build(cfg)
+        except BaseException:
+            set_default_tracer(self._prev_tracer)
+            raise
+
+    def _build(self, cfg: TrainConfig) -> None:
+        """The constructor's work, each piece under the child of ``setup``
+        that names it (the table of ``PERF.md`` §3)."""
+        span = self.tracer.span
         self.cfg = cfg
-        devices = jax.devices()
+        with span("backend_init") as found:
+            # TPU start where the caller has not paid it already
+            devices = jax.devices()
+            found["devices"] = len(devices)
+            self.tracer.pid = jax.process_index()
+        self.mode = cfg.lm_parallelism
+        with span("model_build"):
+            deg = self._build_model(cfg, devices)
+        shape = (cfg.batch_size, cfg.lm_seq_len)
+        self.eval_fn = None     # tp/pp/ep: oracle eval (see evaluate())
+        if self.mode == "sp":
+            create, make = create_lm_train_state, make_sp_train_step
+        elif self.mode == "tp":
+            from ps_pytorch_tpu.parallel.tp import (
+                create_tp_train_state as create, make_tp_train_step as make,
+            )
+        elif self.mode == "pp":
+            from ps_pytorch_tpu.parallel.pp import (
+                create_pp_train_state as create, make_pp_train_step as make,
+            )
+        else:
+            from ps_pytorch_tpu.parallel.ep import (
+                create_ep_train_state as create, make_ep_train_step as make,
+            )
+        with span("state_init") as made:
+            # tracing, compiling and running the initialiser; optimizer state
+            # (pp stacks the blocks by stage: its initialiser takes the degree)
+            stages = (deg,) if self.mode == "pp" else ()
+            self.state = create(self.model, self.tx, self.mesh, *stages,
+                                shape, jax.random.key(cfg.seed))
+            made["params"] = sum(
+                leaf.size for leaf in jax.tree.leaves(self.state.params))
+            made["bytes"] = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.state))
+        with span("step_build"):
+            if self.mode == "sp":
+                self.step_fn = make(self.model, self.tx, self.mesh,
+                                    remat=cfg.remat, donate=cfg.donate)
+                self.eval_fn = make_sp_eval_fn(self.model, self.mesh)
+            else:
+                more = {"num_microbatches": cfg.lm_microbatches} \
+                    if self.mode == "pp" else {}
+                self.step_fn = make(self.model, self.tx, self.mesh,
+                                    self.state, remat=cfg.remat,
+                                    donate=cfg.donate, **more)
+
+        # Checkpoints are self-describing: record the model family and the
+        # RESOLVED mesh degree (lm_model_axis=0 means "all devices", which
+        # the standalone evaluator cannot know) into the config that
+        # save_checkpoint embeds.
+        resolved = {"network": ("MoETransformerLM" if self.mode == "ep"
+                                else "TransformerLM")}
+        if self.mode in ("tp", "pp"):
+            resolved["lm_model_axis"] = deg
+        self.cfg = cfg = cfg.replace(**resolved)
+
+        with span("data_build") as built:
+            from ps_pytorch_tpu.data.text import lm_streams
+            train_stream, self.val_tokens = lm_streams(cfg)
+            self.train_loader = TokenLoader(train_stream, cfg.batch_size,
+                                            cfg.lm_seq_len, seed=cfg.seed)
+            built["bytes"] = train_stream.nbytes + self.val_tokens.nbytes
+        with span("ops_plane_build"):
+            self._build_ops_plane(cfg, devices)
+
+    def _build_model(self, cfg: TrainConfig, devices) -> Optional[int]:
+        """The optimizer, the mesh of ``--lm-parallelism``, the model with
+        the attention it resolves to, and the ``KERNELS`` line of the
+        schedules behind it. -> the model axis' degree under tp / pp."""
         n = len(devices)
+        deg = None
         self.tx = sgd(lr=build_schedule(cfg), momentum=cfg.momentum,
                       weight_decay=cfg.weight_decay, nesterov=cfg.nesterov)
-        self.mode = cfg.lm_parallelism
-        key = jax.random.key(cfg.seed)
 
         # Resolve the attention kernel (--lm-attention). "flash" (the fused
         # Pallas kernel, ops/flash_attention.py) is sequence-LOCAL: legal
@@ -99,14 +190,6 @@ class LMTrainer:
                                  f"sharding)")
             self.model = build_lm_model(cfg, attention_impl=impl,
                                         axis_name="data")
-            self.state = create_lm_train_state(
-                self.model, self.tx, self.mesh,
-                (cfg.batch_size, cfg.lm_seq_len), key)
-            self.step_fn = make_sp_train_step(self.model, self.tx,
-                                              self.mesh,
-                                              remat=cfg.remat,
-                                              donate=cfg.donate)
-            self.eval_fn = make_sp_eval_fn(self.model, self.mesh)
         elif self.mode in ("tp", "pp"):
             from ps_pytorch_tpu.parallel.mesh import make_mesh
             deg = cfg.lm_model_axis or n
@@ -123,47 +206,15 @@ class LMTrainer:
                 raise ValueError("lm_attention='flash' is not supported "
                                  "under tp (GSPMD cannot partition the "
                                  "fused kernel over heads); use full")
+            if self.mode == "pp" and cfg.lm_layers % deg:
+                raise ValueError(f"lm_layers={cfg.lm_layers} not "
+                                 f"divisible into {deg} stages")
             self.model = build_lm_model(cfg, attention_impl=local_impl)
-            if self.mode == "tp":
-                from ps_pytorch_tpu.parallel.tp import (
-                    create_tp_train_state, make_tp_train_step,
-                )
-                self.state = create_tp_train_state(
-                    self.model, self.tx, self.mesh,
-                    (cfg.batch_size, cfg.lm_seq_len), key)
-                self.step_fn = make_tp_train_step(
-                    self.model, self.tx, self.mesh, self.state,
-                    remat=cfg.remat, donate=cfg.donate)
-            else:
-                from ps_pytorch_tpu.parallel.pp import (
-                    create_pp_train_state, make_pp_train_step,
-                )
-                if cfg.lm_layers % deg:
-                    raise ValueError(f"lm_layers={cfg.lm_layers} not "
-                                     f"divisible into {deg} stages")
-                self.state = create_pp_train_state(
-                    self.model, self.tx, self.mesh, deg,
-                    (cfg.batch_size, cfg.lm_seq_len), key)
-                self.step_fn = make_pp_train_step(
-                    self.model, self.tx, self.mesh, self.state,
-                    num_microbatches=cfg.lm_microbatches,
-                    remat=cfg.remat, donate=cfg.donate)
-            self.eval_fn = None   # oracle eval (see evaluate())
         elif self.mode == "ep":
-            from ps_pytorch_tpu.parallel.ep import (
-                create_ep_train_state, make_ep_train_step,
-            )
             from ps_pytorch_tpu.parallel.mesh import make_mesh
             self.mesh = make_mesh(data=n, model=1, devices=devices)
             self.model = build_lm_model(cfg, attention_impl=local_impl,
                                         ep_axis="data")
-            self.state = create_ep_train_state(
-                self.model, self.tx, self.mesh,
-                (cfg.batch_size, cfg.lm_seq_len), key)
-            self.step_fn = make_ep_train_step(
-                self.model, self.tx, self.mesh, self.state,
-                remat=cfg.remat, donate=cfg.donate)
-            self.eval_fn = None
         else:  # unreachable: TrainConfig.__post_init__ validates
             raise ValueError(self.mode)
         kernels = []
@@ -238,32 +289,20 @@ class LMTrainer:
         # from the flag: the line here, the first JSONL record and the gauge.
         self.compute_dtype = jnp.dtype(self.model.dtype)
         announce_kernels(kernels, dtype=self.compute_dtype)
+        return deg
 
-        # Checkpoints are self-describing: record the model family and the
-        # RESOLVED mesh degree (lm_model_axis=0 means "all devices", which
-        # the standalone evaluator cannot know) into the config that
-        # save_checkpoint embeds.
-        resolved = {"network": ("MoETransformerLM" if self.mode == "ep"
-                                else "TransformerLM")}
-        if self.mode in ("tp", "pp"):
-            resolved["lm_model_axis"] = deg
-        self.cfg = cfg = cfg.replace(**resolved)
-
-        from ps_pytorch_tpu.data.text import lm_streams
-        train_stream, self.val_tokens = lm_streams(cfg)
-        self.train_loader = TokenLoader(train_stream, cfg.batch_size,
-                                        cfg.lm_seq_len, seed=cfg.seed)
+    def _build_ops_plane(self, cfg: TrainConfig, devices) -> None:
+        """Metrics logger, profiler window, the MFU's inputs, fault injector,
+        health monitor, flight recorder, exporter: the same telemetry surface
+        as the CNN Trainer (schema parity — the analyze tooling must read
+        vision and LM runs identically)."""
         self.metrics = MetricsLogger(cfg.metrics_file, cfg.log_every,
                                      process_index=jax.process_index(),
                                      num_processes=jax.process_count())
-        # Same telemetry surface as the CNN Trainer (schema parity — the
-        # analyze tooling must read vision and LM runs identically).
-        self.tracer = Tracer(pid=jax.process_index())
-        self._prev_tracer = set_default_tracer(self.tracer)
         # --profile-dir / --profile-steps: the same window as the CNN Trainer.
         self._profile = ProfileWindow(cfg.profile_dir, cfg.profile_steps)
         self._flops_per_step: Optional[int] = None
-        self._n_chips = n
+        self._n_chips = len(devices)
         self._peak_per_chip = peak_flops_bf16(devices[0].device_kind)
         self.start_step = 0
         # Fault plane (same spec/grammar as the CNN trainer): step-keyed
@@ -275,7 +314,6 @@ class LMTrainer:
         # Live ops plane, same surfaces as the CNN Trainer. The LM step
         # metrics carry loss only (no in-graph grad norm yet), so the
         # watchdogs see loss at log cadence plus wall-clock stall.
-        self.registry = declare_training_metrics(Registry())
         self.health: Optional[HealthMonitor] = None
         if cfg.health_spec:
             self.health = HealthMonitor(cfg.health_spec,
@@ -414,6 +452,13 @@ class LMTrainer:
                     f"--no-resume / a fresh --train-dir")
 
     def maybe_resume(self) -> bool:
+        with self.tracer.span("resume") as found:
+            restored = self._restore_latest()
+            if restored:
+                found["restored_step"] = self.start_step
+        return restored
+
+    def _restore_latest(self) -> bool:
         if ckpt.latest_step(self.cfg.train_dir) is None:
             return False
         # A checkpoint of another model (a CNN's, another arch's) would fail
@@ -483,8 +528,10 @@ class LMTrainer:
         step = self.start_step
         halted = False
         tracer = self.tracer
-        # only this run's first record says what it computes in
-        once = {"compute_dtype": self.compute_dtype.name}
+        # only this run's first record says what it computes in and holds its
+        # set-up (the STARTUP line)
+        once = {"compute_dtype": self.compute_dtype.name, "setup": None}
+        first_step = step + 1
 
         def write_record(step, own, *, step_time, data_time, dispatch_ahead,
                          epoch):
@@ -506,10 +553,14 @@ class LMTrainer:
                 flops_per_step=self._flops_per_step,
                 peak_flops_per_chip=self._peak_per_chip,
                 n_chips=self._n_chips)
+            if once:
+                once["setup"] = tracer.startup_summary(first_step)
+                print(startup_line(once["setup"]))
             self.metrics.log_step(
                 step, epoch, loss=loss, acc=0.0, participating=1.0,
                 step_time=step_time, data_time=data_time,
                 dispatch_ahead=dispatch_ahead,
+                compiles=tracer.counted_through(step, "programs"),
                 phases=tracer.step_summary(step), **own, **derived, **once)
             once.clear()
             for k, v in own.items():

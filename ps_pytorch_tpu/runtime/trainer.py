@@ -44,7 +44,10 @@ from ps_pytorch_tpu.telemetry import (
     declare_kvrep_metrics, declare_resilience_metrics,
     declare_training_metrics,
     derive_step_record, device_memory_record, host_rss_bytes,
-    set_default_tracer, set_device_memory_gauges,
+    set_default_tracer, set_device_memory_gauges, startup_line,
+)
+from ps_pytorch_tpu.utils.compile_cache import (
+    COMPILE_COUNTERS, count_compiles,
 )
 from ps_pytorch_tpu.utils.flops import forward_flops, peak_flops_bf16
 
@@ -63,24 +66,57 @@ def host_prng_key(seed: int) -> np.ndarray:
 class Trainer:
     def __init__(self, cfg: TrainConfig, mesh=None, coordinator: Optional[Coordinator] = None,
                  download: bool = False, injector=None):
+        # The tracer exists before anything is built: the constructor is one
+        # tree under the span ``setup`` (telemetry/trace.py), and a compile
+        # anywhere in it is counted in the span that caused it
+        # (utils/compile_cache.py, into the registry too). Installed as the
+        # ambient default, so the library layers' span() calls (data build,
+        # checkpoint restore and writes, coordinator rounds, KV transport)
+        # land on this host's timeline as well.
+        count_compiles()
+        self.registry = declare_training_metrics(Registry())
+        self.tracer = Tracer(registry=self.registry,
+                             counters=COMPILE_COUNTERS)
+        # The previous default is restored when train() exits so a trainer
+        # never leaks its tracer into unrelated code running afterwards.
+        self._prev_tracer = set_default_tracer(self.tracer)
+        try:
+            with self.tracer.setup_span():
+                self._build(cfg, mesh, coordinator, download, injector)
+        except BaseException:
+            set_default_tracer(self._prev_tracer)
+            raise
+
+    def _build(self, cfg, mesh, coordinator, download, injector) -> None:
+        """The constructor's work, each piece under the child of ``setup``
+        that names it (the table of ``PERF.md`` §3)."""
+        span = self.tracer.span
         self.cfg = cfg
-        self.mesh = mesh if mesh is not None else make_mesh(data=cfg.data_axis,
-                                                            model=cfg.model_axis)
-        self.n_data = self.mesh.shape["data"]
-        self.model = build_model(cfg.network, cfg.num_classes, cfg.compute_dtype,
-                                 conv_impl=cfg.conv_impl)
-        self.tx = build_optimizer(cfg)
-        announce_kernels(cnn_kernels(cfg))
-        host_id, num_hosts = local_data_shard()
-        self.train_loader, self.test_loader = prepare_data(
-            cfg, host_id=host_id, num_hosts=num_hosts, download=download)
+        with span("backend_init") as found:
+            # TPU start where the caller has not paid it already
+            found["devices"] = len(jax.devices())
+            self.tracer.pid = jax.process_index()
+        with span("model_build"):
+            self.mesh = mesh if mesh is not None else make_mesh(
+                data=cfg.data_axis, model=cfg.model_axis)
+            self.n_data = self.mesh.shape["data"]
+            self.model = build_model(cfg.network, cfg.num_classes,
+                                     cfg.compute_dtype, conv_impl=cfg.conv_impl)
+            self.tx = build_optimizer(cfg)
+            announce_kernels(cnn_kernels(cfg))
+        with span("data_build") as built:
+            host_id, num_hosts = local_data_shard()
+            self.train_loader, self.test_loader = prepare_data(
+                cfg, host_id=host_id, num_hosts=num_hosts, download=download)
+            built["bytes"] = sum(
+                a.nbytes for loader in (self.train_loader, self.test_loader)
+                for a in (loader.x, loader.y, loader._padded) if a is not None)
         sample = (1,) + sample_shape(cfg.dataset)
         from ps_pytorch_tpu.data.augment import input_norm_for
         input_norm = input_norm_for(cfg)
-        # Live ops plane: registry + watchdogs exist BEFORE the step builds,
+        # Live ops plane: the watchdogs exist BEFORE the step builds,
         # because the nonfinite skip action is an in-graph gate
         # (make_train_step's skip_nonfinite) decided by the health spec.
-        self.registry = declare_training_metrics(Registry())
         self.health: Optional[HealthMonitor] = None
         if cfg.health_spec:
             self.health = HealthMonitor(cfg.health_spec,
@@ -90,26 +126,39 @@ class Trainer:
             from ps_pytorch_tpu.parallel.zero import (
                 create_zero_train_state, make_zero_train_step, zero_state_specs,
             )
-            self.state = create_zero_train_state(
-                self.model, self.tx, self.mesh, sample, jax.random.key(cfg.seed))
-            self.step_fn = make_zero_train_step(
-                self.model, self.tx, self.mesh, self.state,
-                sync_batchnorm=cfg.sync_batchnorm, remat=cfg.remat,
-                donate=cfg.donate, input_norm=input_norm,
-                skip_nonfinite=skip_nonfinite)
-            self._state_specs = zero_state_specs
+            create, make, self._state_specs = (
+                create_zero_train_state, make_zero_train_step, zero_state_specs)
         else:
-            self.state = create_train_state(self.model, self.tx, self.mesh,
-                                            sample, jax.random.key(cfg.seed))
-            self.step_fn = make_train_step(self.model, self.tx, self.mesh,
-                                           self.state,
-                                           sync_batchnorm=cfg.sync_batchnorm,
-                                           remat=cfg.remat, donate=cfg.donate,
-                                           input_norm=input_norm,
-                                           skip_nonfinite=skip_nonfinite)
             from ps_pytorch_tpu.parallel.dp import state_specs
-            self._state_specs = state_specs
-        self.eval_fn = make_eval_step(self.model, input_norm)
+            create, make, self._state_specs = (
+                create_train_state, make_train_step, state_specs)
+        with span("state_init") as made:
+            # tracing, compiling and running the initialiser; optimizer state
+            self.state = create(self.model, self.tx, self.mesh, sample,
+                                jax.random.key(cfg.seed))
+            made["params"] = sum(
+                leaf.size for leaf in jax.tree.leaves(self.state.params))
+            made["bytes"] = sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.state))
+        with span("step_build"):
+            self.step_fn = make(self.model, self.tx, self.mesh, self.state,
+                                sync_batchnorm=cfg.sync_batchnorm,
+                                remat=cfg.remat, donate=cfg.donate,
+                                input_norm=input_norm,
+                                skip_nonfinite=skip_nonfinite)
+            self.eval_fn = make_eval_step(self.model, input_norm)
+        with span("control_plane_build"):
+            self._build_control_plane(cfg, coordinator, injector)
+        with span("ops_plane_build"):
+            self._build_ops_plane(cfg)
+        self.start_step = 0
+        if cfg.resume:
+            self._maybe_resume()
+
+    def _build_control_plane(self, cfg, coordinator, injector) -> None:
+        """Fault injector, the coordination store and its shims, election and
+        membership, the coordinator, heartbeat and liveness, the preemption
+        guard."""
         # Fault plane: an injector passed in (the auto-resume loop threads
         # ONE across restarts so once-only faults stay fired) wins over one
         # built from --fault-spec.
@@ -214,16 +263,13 @@ class Trainer:
         # SIGTERM/preemption: the handler only flags; the loop writes an
         # emergency checkpoint at the next step boundary.
         self._preempt = resilience.PreemptionGuard()
+
+    def _build_ops_plane(self, cfg) -> None:
+        """Metrics logger, flight recorder, exporter, the MFU's inputs, the
+        cross-host timeline, the profiler window."""
         self.metrics = MetricsLogger(cfg.metrics_file, cfg.log_every,
                                      process_index=jax.process_index(),
                                      num_processes=jax.process_count())
-        # Host-side span tracer; installed as the ambient default so the
-        # library layers' span() calls (checkpoint writes, coordinator
-        # rounds, KV transport) land on this host's timeline too.
-        self.tracer = Tracer(pid=jax.process_index())
-        # The previous default is restored when train() exits so a trainer
-        # never leaks its tracer into unrelated code running afterwards.
-        self._prev_tracer = set_default_tracer(self.tracer)
         # Flight recorder: armed whenever any ops-plane surface is on; its
         # rings cost O(capacity) and only dump() touches the disk.
         self.flightrec: Optional[FlightRecorder] = None
@@ -280,9 +326,6 @@ class Trainer:
         # jax.profiler trace window (SURVEY §5.1: the reference's hand-rolled
         # timers + our structured lines, plus real profiler integration).
         self._profile = ProfileWindow(cfg.profile_dir, cfg.profile_steps)
-        self.start_step = 0
-        if cfg.resume:
-            self._maybe_resume()
 
     def _maybe_resume(self) -> None:
         """NEW vs the reference (which always restarts at step 1,
@@ -291,21 +334,23 @@ class Trainer:
         Resume is VALID-latest, not latest: a checkpoint whose manifest
         hashes fail (torn write, bitrot, injected ckpt_corrupt) is skipped
         and the walk continues to the previous committed step."""
-        if ckpt.latest_step(self.cfg.train_dir) is None:
-            return
-        template = fetch_replicated(self.mesh, self.state) \
-            if dist.is_multiprocess() else self.state
-        got = ckpt.load_latest_valid(self.cfg.train_dir, template)
-        if got is None:
-            return
-        state, meta, _, step = got
-        self.state = place_state(self.mesh, state, self._state_specs(state))
-        self.start_step = int(meta["step"])
-        # Replay the data stream to the restore point so a resumed run sees
-        # the SAME batch sequence an uninterrupted run would (bit-for-bit
-        # resume needs params AND stream position; the PRNG key is already
-        # step-derived).
-        self.train_loader.fast_forward(self.start_step)
+        with self.tracer.span("resume") as found:
+            if ckpt.latest_step(self.cfg.train_dir) is None:
+                return
+            template = fetch_replicated(self.mesh, self.state) \
+                if dist.is_multiprocess() else self.state
+            got = ckpt.load_latest_valid(self.cfg.train_dir, template)
+            if got is None:
+                return
+            state, meta, _, step = got
+            self.state = place_state(self.mesh, state,
+                                     self._state_specs(state))
+            self.start_step = found["restored_step"] = int(meta["step"])
+            # Replay the data stream to the restore point so a resumed run
+            # sees the SAME batch sequence an uninterrupted run would
+            # (bit-for-bit resume needs params AND stream position; the PRNG
+            # key is already step-derived).
+            self.train_loader.fast_forward(self.start_step)
         print(f"RESUME from {ckpt.checkpoint_path(self.cfg.train_dir, step)} "
               f"at step {self.start_step}")
 
@@ -551,6 +596,9 @@ class Trainer:
         # step is queued, or where the device is drained anyway (a
         # checkpoint, the loop's last step, an exception on its way out):
         # runtime/step_queue.py, shared with LMTrainer.
+        # Only this run's first record holds its set-up (the STARTUP line).
+        once = {"setup": None}
+        first_step = step + 1
 
         def write_record(step, own, *, step_time, data_time, dispatch_ahead):
             extra = derive_step_record(
@@ -561,13 +609,18 @@ class Trainer:
                 n_chips=self._n_chips)
             if self._resilience_active():
                 extra.update(self.resilience_stats())
+            if once:
+                once["setup"] = tracer.startup_summary(first_step)
+                print(startup_line(once["setup"]))
             self.metrics.log_step(
                 step, (step - 1) // steps_per_epoch,
                 loss=own["loss"], acc=own["accuracy"],
                 participating=own["participating"],
                 step_time=step_time, data_time=data_time,
                 dispatch_ahead=dispatch_ahead,
-                phases=tracer.step_summary(step), **extra)
+                compiles=tracer.counted_through(step, "programs"),
+                phases=tracer.step_summary(step), **extra, **once)
+            once.clear()
 
         queued = QueuedSteps(
             tracer, step, write_record,

@@ -34,5 +34,5 @@ from ps_pytorch_tpu.telemetry.slo import (  # noqa: F401
 )
 from ps_pytorch_tpu.telemetry.trace import (  # noqa: F401
     ProfileWindow, Tracer, get_default_tracer, latest_tracer, self_times,
-    set_default_tracer, span,
+    set_default_tracer, span, startup_line,
 )

@@ -465,6 +465,15 @@ TRAINING_COUNTERS = (
     ("dispatch_ahead_steps", "steps",
      "steps queued while the device still ran the step before (Trainer: "
      "the host loop keeps ahead of the chip)"),
+    # Fed by Tracer.add from JAX's compile events (utils/compile_cache.py),
+    # from the trainer's first line on: past step 1 of a run a rise in any
+    # of them is a recompile in the step loop.
+    ("jax_programs_compiled_total", "programs",
+     "XLA programs the backend compiled or loaded from the compile cache"),
+    ("jax_compile_cache_misses_total", "programs",
+     "XLA programs compiled anew, not loaded from the compile cache"),
+    ("jax_compile_seconds_total", "seconds",
+     "seconds in the backend's compile or cache load, net of nested ones"),
 )
 # An MoE step's routing statistics (parallel/ep.py: aux; a dropless arch adds
 # the next five, one that chooses under a bias the last two), set by LMTrainer on every logged step under the names the

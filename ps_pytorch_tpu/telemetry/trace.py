@@ -11,12 +11,23 @@ next to the ``jax.profiler`` device trace.
 
 Two ways in:
 
-- explicit: ``tracer = Tracer(pid=jax.process_index())`` and
-  ``with tracer.span("data_wait", step=7): ...`` — trainers own a tracer.
+- explicit: ``tracer = Tracer()`` and ``with tracer.span("data_wait",
+  step=7): ...`` — trainers own a tracer. A trainer creates it as the first
+  thing its constructor does, so the constructor itself is one tree: a
+  top-level span ``setup`` (NOT a root: a root is an iteration) whose
+  children are the phases of the build (``backend_init``, ``data_build``,
+  ``model_build``, ``state_init``, ``step_build``, ``ops_plane_build``, ...),
+  and ``pid``, a plain attribute, is set once ``jax.process_index()`` is
+  known.
 - ambient: library layers that must not grow a tracer parameter
   (checkpoint.py, transport.py, coordinator.py) call the module-level
   ``span(...)``, which records into the current default tracer and is a
   no-op when none is installed — instrumentation without API churn.
+  ``Tracer.add(key, value)`` is the same for a count or for seconds that
+  something outside the program's control flow reports (JAX's compile
+  events, ``utils/compile_cache.py``): it adds into the args of the innermost
+  span open on the CALLING thread, so the span that caused a compile says so
+  itself; with no span open there, into the tracer's ``tally``.
 
 Spans tagged with ``step=`` additionally feed a per-step phase accumulator
 (``step_summary``), which is what the MetricsLogger v2 record and the
@@ -26,7 +37,9 @@ What a span records (the tracing contract every reader relies on): ``id``,
 the ``parent`` id from the opening thread's stack (None at top level),
 ``name``, ``t0`` / ``dur`` (``time.monotonic`` seconds), ``tid`` and the
 ``step`` the spans of one iteration share (inherited from the parent when
-not given). A trainer's iteration is one ROOT span (``begin_step`` /
+not given), and ``args``: what the opener gave, what the code inside learned
+(``bytes=``, ``params=``) and what ``Tracer.add`` counted there
+(``jit_trace_s``, ``programs``, ...). A trainer's iteration is one ROOT span (``begin_step`` /
 ``end_step``, named ``train_step``) whose children are the iteration's
 phases, so the root's self time (``self_times``) is the part of the
 iteration no span explains. Every span is also a ``jax.profiler``
@@ -63,6 +76,7 @@ _US = 1e6
 
 
 ROOT_SPAN = "train_step"      # the root span of one trainer iteration
+SETUP_SPAN = "setup"          # a trainer's constructor, first line to last
 
 # The layers of a jitted train step, as the device trace is read back by
 # them: the one list of these names. One level: a scope is not opened inside
@@ -135,9 +149,21 @@ class Tracer:
     """
 
     def __init__(self, pid: int = 0, process_name: str = "",
-                 capacity: int = 65536, step_window: int = 256):
+                 capacity: int = 65536, step_window: int = 256,
+                 registry=None, counters: Optional[Dict[str, str]] = None):
         self.pid = int(pid)
-        self.process_name = process_name or f"host{self.pid}"
+        self._process_name = process_name
+        # {a key of ``add``: the counter of ``registry`` it also feeds}
+        self.registry = registry
+        self._counters = dict(counters or {}) if registry is not None else {}
+        # What ``add`` was given: in all, with no span open on the caller's
+        # thread, and under each step until a record takes it.
+        self.totals: Dict[str, float] = {}
+        self.tally: Dict[str, float] = {}
+        self._step_counts: Dict[int, Dict[str, float]] = {}
+        # ``startup_summary``'s fold, and the tally as it stood then
+        self.startup: Optional[dict] = None
+        self.startup_tally: Dict[str, float] = {}
         self.capacity = max(int(capacity), 1)
         self.dropped = 0
         self._buf: deque = deque(maxlen=self.capacity)
@@ -146,6 +172,10 @@ class Tracer:
         self._ids = itertools.count(1)
         self._step_window = max(int(step_window), 1)
         self._step_totals: Dict[int, Dict[str, float]] = {}
+
+    @property
+    def process_name(self) -> str:
+        return self._process_name or f"host{self.pid}"
 
     # ---- recording ----
     def _stack(self) -> list:
@@ -205,6 +235,46 @@ class Tracer:
         finally:
             self._close(f)
 
+    def add(self, key: str, value: float) -> None:
+        """Adds ``value`` under ``key`` into the args of the innermost span
+        open on the CALLING thread (it is recorded with them at the span's
+        exit), into ``tally`` where none is open there, and in both cases
+        into ``totals`` and the registry counter the constructor's
+        ``counters`` names for the key. For what the program's control flow
+        does not see happen: a compile inside a call
+        (``utils/compile_cache.py``)."""
+        stack = getattr(self._tls, "stack", None)
+        with self._lock:
+            self.totals[key] = self.totals.get(key, 0) + value
+            into = stack[-1].args if stack else self.tally
+            into[key] = into.get(key, 0) + value
+            if stack and stack[-1].step is not None:
+                acc = self._step_counts.setdefault(int(stack[-1].step), {})
+                acc[key] = acc.get(key, 0) + value
+                if len(self._step_counts) > self._step_window:
+                    self._step_counts.pop(min(self._step_counts), None)
+        counter = self._counters.get(key)
+        if counter is not None:
+            self.registry.inc(counter, value)
+
+    def counted_through(self, step: int, key: str) -> float:
+        """What ``add`` was given under ``key`` in the iterations up to
+        ``step`` that no earlier call took: ``programs`` is a step record's
+        ``compiles`` (0 on a steady step, at the cost of one truth test)."""
+        if not self._step_counts:
+            return 0
+        with self._lock:
+            due = [s for s in self._step_counts
+                   if s <= step and key in self._step_counts[s]]
+            return sum(self._step_counts[s].pop(key) for s in due)
+
+    def setup_span(self):
+        """The span ``setup`` round a trainer's constructor, with how old
+        the process was when it opened (``process_age_s``)."""
+        age = process_age_s()
+        return self.span(SETUP_SPAN,
+                         **({} if age is None else {"process_age_s": age}))
+
     def begin_step(self, step: int) -> None:
         """Open the root span of one trainer iteration on this thread (the
         trace's ``StepTraceAnnotation``). The spans opened until ``end_step``
@@ -219,6 +289,18 @@ class Tracer:
         if root is not None:
             self._tls.root = None
             self._close(root)
+            if self.startup is None:
+                self.startup_summary(root.step)
+
+    def startup_summary(self, step: int) -> dict:
+        """``setup_summary`` of a run whose first iteration is ``step``,
+        folded once: when that iteration closes (``end_step``), or before,
+        for a record written inside it."""
+        if self.startup is None:
+            with self._lock:
+                self.startup_tally = dict(self.tally)
+            self.startup = setup_summary(self, step)
+        return self.startup
 
     def _record(self, f: _Frame, dur: float) -> None:
         top = f.top
@@ -319,6 +401,88 @@ def self_times(spans: Iterable[dict]) -> Dict[int, float]:
                 edge = e
         out[ev["id"]] = ev["dur"] - covered
     return out
+
+
+# ---- set-up: the record that outlives the ring ----
+
+def process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process (``/proc/self/stat`` field
+    22, clock ticks after boot, against ``CLOCK_BOOTTIME``): the interpreter,
+    the imports and whatever the caller did before it built a trainer. None
+    where the platform has neither."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the second field, the command's name, may hold spaces
+            started = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - started / os.sysconf("SC_CLK_TCK")
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+
+
+def setup_summary(tracer: "Tracer", step: int) -> dict:
+    """A run's set-up folded into one dictionary once its first iteration,
+    ``step``, has run; a long run pushes the spans themselves out of the
+    ring. ``process_age_s`` and ``build_s`` are ``setup``'s (None without
+    one); ``phases`` the self seconds by name of ``setup``, of a top-level
+    ``resume`` and of everything under them; ``step1`` what iteration
+    ``step``'s ``flops_trace`` took and what ``add`` counted under its
+    ``host_dispatch``; ``compile`` the tracer's ``totals``: every program
+    since the tracer was created, the caller's between the build and
+    ``train()`` among them."""
+    spans = tracer.spans()
+    selfs = self_times(spans)
+    by_id = {s["id"]: s for s in spans}
+
+    def top(s):
+        while s.get("parent") in by_id:
+            s = by_id[s["parent"]]
+        return s
+
+    setup = next((s for s in spans if s["name"] == SETUP_SPAN
+                  and s.get("parent") is None), None)
+    phases: Dict[str, float] = {}
+    for s in spans:
+        t = top(s)
+        if t.get("parent") is None and t["name"] in (SETUP_SPAN, "resume"):
+            phases[s["name"]] = phases.get(s["name"], 0.0) + selfs[s["id"]]
+    first = {s["name"]: s for s in spans if s.get("step") == step
+             and s["name"] in ("flops_trace", "host_dispatch")}
+    counted = first.get("host_dispatch", {}).get("args", {})
+    step1 = {"flops_trace_s": first.get("flops_trace", {}).get("dur")}
+    step1.update({k: counted.get(k, 0) for k in (
+        "jit_trace_s", "jit_lower_s", "backend_compile_s", "cache_load_s",
+        "cache_hits", "cache_misses")})
+    totals = tracer.totals
+    out = {"process_age_s": (setup or {}).get("args", {}).get("process_age_s"),
+           "build_s": setup["dur"] if setup else None,
+           "phases": phases, "step1": step1,
+           "compile": {"programs": totals.get("programs", 0),
+                       "seconds": totals.get("backend_compile_s", 0.0),
+                       "cache_hits": totals.get("cache_hits", 0),
+                       "cache_misses": totals.get("cache_misses", 0)}}
+    return _rounded(out)
+
+
+def _rounded(x):
+    if isinstance(x, dict):
+        return {k: _rounded(v) for k, v in x.items()}
+    return round(x, 6) if isinstance(x, float) else x
+
+
+def startup_line(summary: dict) -> str:
+    """``setup_summary`` as one ``STARTUP`` line in the ``KERNELS`` line's
+    style. ``step1=load`` where the step's program came out of the compile
+    cache (most of its backend seconds were the retrieval), ``compile``
+    where the backend compiled it."""
+    def group(d):
+        return " ".join(f"{k}={v}" for k, v in d.items())
+    s1 = summary["step1"]
+    loaded = 2 * s1["cache_load_s"] > s1["backend_compile_s"]
+    return (f"STARTUP process_age_s={summary['process_age_s']} "
+            f"build_s={summary['build_s']} phases[{group(summary['phases'])}] "
+            f"step1={'load' if loaded else 'compile'}[{group(s1)}] "
+            f"compile[{group(summary['compile'])}]")
 
 
 # ---- the profiler window of a trainer's loop ----
