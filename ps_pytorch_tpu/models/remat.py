@@ -50,8 +50,9 @@ def remat_block(block_cls):
       output and log-sum-exp, as flash's, and its chunk summaries, (2 hd / c)
       2 H more: spares both forward kernels' second run;
     - ``attn_q``, ``attn_k``, ``attn_v``: the flash backward's other three
-      operands as ``attend`` takes them (a differential pair's heads once, a
-      cross layer's q alone), 2 hd (H + 2 Hkv): spares the q/k/v projections,
+      operands as ``attend`` takes them (a differential layer's as its two
+      calls do: a pair's heads apart, its value 2 hd wide; a cross layer's q
+      alone), 2 hd (H + 2 Hkv): spares the q/k/v projections,
       RoPE and the transposing copies into heads;
     - ``attn_q_unnormed``, ``attn_k_unnormed``: a q/k norm's input where the
       arch has one (its backward reads it: without it the projections run

@@ -90,7 +90,7 @@ class Arch(NamedTuple):
     ssm_head_dim: int = 0
     ssm_groups: int = 0         # B and C are shared by the ssm_heads / ssm_groups heads of a group; the gated norm's groups too
     ssm_chunk: int = 0          # tokens a chunk of the state-space-dual form
-    diff_attn: bool = False     # differential attention: heads pair up (2j, 2j+1), a pair's output is softmax(q1 k1) v - lambda softmax(q2 k2) v over its two value heads side by side, then a norm
+    diff_attn: bool = False     # differential attention: heads pair up (2j, 2j+1), a pair's output is softmax(q1 k1) v - lambda softmax(q2 k2) v over its two value heads side by side (one value 2 hd wide: two attention calls a layer), then a norm
     gated_ffn: bool = False     # the dense block's feed-forward: GatedFFN (SwiGLU, no biases) | Dense-GELU-Dense with biases
     tied_head: bool = False     # logits = ln_f(x) . tok_embed^T: no lm_head parameter
     no_positions: bool = False  # no position table although rope_theta is 0: no position encoding anywhere
@@ -411,8 +411,9 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
                        kv_heads: int = 0, head_dim: int = 0,
                        n_layers: int = 0, shared_kv=None):
     """``x + Wo . attention(norm(x))``, ``norm(x)`` (an early router's input)
-    and what the layer can hand on or count (``k``, ``v`` in heads;
-    ``diff_lambda_max``); with the arch's ``attn_gate`` the attention's
+    and what the layer can hand on or count (``k``, ``v`` in heads, as the
+    attention calls take them; ``diff_lambda_max``); with the arch's
+    ``attn_gate`` the attention's
     output is gated by ``sigmoid(norm(x) Wg)`` before ``Wo``, with
     ``post_norm`` the sum is ``x + norm(Wo . ...)``. The one q/k/v/o path of
     both ``Block`` and ``models/moe.MoEBlock``, called from their
@@ -431,8 +432,15 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     k1) v - lambda softmax(q2 k2) v`` under an RMSNorm over the 2 hd features
     (one learned scale) times ``1 - lambda_init``, ``lambda = exp(lq1 . lk1)
     - exp(lq2 . lk2) + lambda_init`` from four learned vectors [hd] a layer.
-    The attention functions take one width, so each softmax meets the two
-    value heads in turn: the four calls of the published code."""
+    The attention functions take a value wider than the keys, so each softmax
+    is formed once: two calls a layer, ``attend(q[i], k[i], v)`` over the
+    pairs' heads ``i`` and the value [B, Hkv / 2, S, 2 hd], which is the ``v``
+    projection's features as they lie. The one transposing copy into heads
+    leaves a pair's heads apart, [2, B, pairs, S, hd], and ``q[i]``, ``k[i]``
+    are that array's two blocks: nothing is sliced by stride or joined,
+    forward or backward. The blocks are what is kept under remat and what
+    ``k`` hands on (a pair of arrays, ``v`` beside it), so the cross layer's
+    calls read another layer's K and V as they are."""
     a = ARCHS[arch]
     b, s, d = x.shape
     hd = head_dim or d // n_heads
@@ -460,9 +468,15 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             q, k = unnormed(q, k)
             q = make_norm(arch, dtype, name="q_norm")(q)
             k = make_norm(arch, dtype, name="k_norm")(k)
-        to_heads = lambda t: t.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
+        to_heads = to_values = lambda t, w=hd: t.reshape(
+            b, s, -1, w).transpose(0, 2, 1, 3)
+        if a.diff_attn:
+            # a pair's heads apart, [2, B, pairs, S, hd]; its value 2 hd wide
+            to_heads = lambda t: t.reshape(b, s, -1, 2, hd).transpose(
+                3, 0, 2, 1, 4)
+            to_values = partial(to_values, w=2 * hd)
         q = to_heads(q)
-        k, v = (to_heads(k), to_heads(v)) if shared_kv is None else shared_kv
+        k, v = (to_heads(k), to_values(v)) if shared_kv is None else shared_kv
         if a.head_qk_norm:
             q, k = unnormed(q, k)
             q = make_norm(arch, dtype, name="q_norm")(q)
@@ -471,8 +485,11 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             turn = rope if a.rope_share == 1.0 else partial(
                 rope_on_a_share, share=a.rope_share)
             q, k = (turn(t, positions, a.rope_theta) for t in (q, k))
-        # as ``attend`` takes them, a pair's heads once (``remat_block``); a
-        # cross layer's K/V are another block's output
+        # as ``attend`` takes them (``remat_block``): a differential layer's
+        # two calls each take a block of q and of k; a cross layer's K/V are
+        # another block's output
+        if a.diff_attn:
+            q, k = tuple(q), tuple(k)
         q = kept(q, "attn_q")
         if shared_kv is None:
             k, v = kept(k, "attn_k"), kept(v, "attn_v")
@@ -499,10 +516,7 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             mod, q, k, v, a, fused=attention_impl == "flash")
     elif a.diff_attn:
         with device_scope("attn_core"):
-            halves = [v[:, 0::2], v[:, 1::2]]
-            o, o2 = (jnp.concatenate([attend(q[:, i::2], k[:, i::2], vh)
-                                      for vh in halves], axis=-1)
-                     for i in (0, 1))
+            o, o2 = (attend(q[i], k[i], v) for i in (0, 1))
     else:
         with device_scope("attn_core"):
             o = attend(q, k, v)

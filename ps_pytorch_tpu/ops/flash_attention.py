@@ -17,7 +17,7 @@ Reference counterpart: the reference has no attention at all (CNN zoo,
 
 Design notes
 - What a grid step holds is a schedule computed from the shape alone:
-  ``flash_schedule(bh, s, d, itemsize, causal, window=, bh_kv=)``. On a v5e a 256x256x64
+  ``flash_schedule(bh, s, d, itemsize, causal, window=, bh_kv=, dv=)``. On a v5e a 256x256x64
   tile alone in a grid step costs 1.4 us however the grid is cut (the MXU's
   and the reductions' latencies with nothing to overlap them), so a step
   holds many tiles and a tile is large. The compute tile is the whole row
@@ -79,6 +79,17 @@ Design notes
   causally dead ones are, and the index maps clamp to the band from both
   sides; such calls are named ``flash_win_*`` so that a trace tells the two
   kinds of layer apart.
+- The value has a width of its own, ``dv = v.shape[-1]``, read from the
+  operand: the forward's accumulator, output block and ``P.V`` product, the
+  backward's dO, ``delta``, ``dO.V^T`` (which contracts over it) and dV are
+  ``dv`` wide, the scores, dQ and dK ``d`` wide, the scale ``d ** -0.5``. One
+  algorithm with one more size, no second path: where ``dv == d`` (every
+  caller but a differential layer, whose one value is a pair's two value heads
+  side by side, ``models/transformer.py:attention_sublayer``) the schedule
+  record, the traced kernels and the Mosaic modules are what they were before
+  the width was told apart (PR 50), and the record prints no ``dv=``. A
+  step's VMEM is sized with both widths; at ``d = 64, dv = 128`` both pad to
+  128 lanes, so a step holds what a call of 64-wide values held.
 - Matmuls run with ``preferred_element_type=f32``; q, k, v, dO are cast to
   f32 before the products and the probability tile to the value dtype for
   the PV product (under Mosaic an f32 operand takes one bf16 MXU pass).
@@ -165,6 +176,7 @@ class FlashSchedule(NamedTuple):
     tiles: int = 0          # (q tile, kv tile) pairs a call's mask covers, either pass
     live_tiles: int = 0     # ... of which hold a pair the mask admits
     bwd_visits: int = 0     # pair slots the backward's tile loops visit
+    dv: int = 0             # the value's (and the output's) width where it is not the keys'
 
     @property
     def dead(self) -> int:
@@ -182,7 +194,8 @@ class FlashSchedule(NamedTuple):
 
     def describe(self) -> str:
         kind = (f" kv_heads={self.bwd_g}" if self.group > 1 else "") \
-            + (f" window={self.window}" if self.window else "")
+            + (f" window={self.window}" if self.window else "") \
+            + (f" dv={self.dv}" if self.dv else "")
         grid = lambda g: "x".join(map(str, g))
         return (f"g={self.g}/{self.block_h} bq={self.block_q} "
                 f"kv={self.block_kv}/{self.block_kv_major} "
@@ -201,28 +214,36 @@ def _tile_bytes(bq, bkv):
     return 4 * max(bq * bkv * 4, _TILE_BYTES)
 
 
-def _fwd_bytes(g, gk, bq, bkv, kvm, s, d, itemsize):
+def _lanes(d):
+    """The last dimension of a block pads to 128 lanes."""
+    return -(-d // 128) * 128
+
+
+def _fwd_bytes(g, gk, bq, bkv, kvm, s, d, itemsize, dv=0):
     """VMEM of one forward step's blocks (``g`` query heads on ``gk`` K/V
-    heads): inputs and outputs double-buffered, the f32 accumulators, the
-    score tiles. The last dimension of a block pads to 128 lanes, an LSE row
-    to 8 sublanes, a [rows, 1] statistic to 128 lanes."""
-    dp = -(-d // 128) * 128
-    return (2 * (g * 2 * bq + gk * 2 * kvm) * dp * itemsize    # q o k v
+    heads; q and k ``d`` wide, v and o ``dv``, 0 for ``d``): inputs and
+    outputs double-buffered, the f32 accumulators, the score tiles. The last
+    dimension of a block pads to 128 lanes, an LSE row to 8 sublanes, a
+    [rows, 1] statistic to 128 lanes."""
+    dp, dvp = _lanes(d), _lanes(dv or d)
+    return (2 * (g * bq + gk * kvm) * (dp + dvp) * itemsize    # q o k v
             + 2 * g * 8 * bq * 4                               # lse
-            + (s > kvm) * g * bq * (dp + 2 * 128) * 4          # acc, m, l
+            + (s > kvm) * g * bq * (dvp + 2 * 128) * 4         # acc, m, l
             + _tile_bytes(bq, bkv))
 
 
-def _bwd_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group=1):
+def _bwd_bytes(g, bq, bkv, kvm, qm, s, d, itemsize, group=1, dv=0):
     """VMEM of one backward step's blocks: ``g`` K/V heads and one query head
-    of each; padded as the forward's."""
-    dp = -(-d // 128) * 128
+    of each (q, k and their gradients ``d`` wide, v, dO and dV ``dv``);
+    padded as the forward's."""
+    dp, dvp = _lanes(d), _lanes(dv or d)
     dq_size = 4 if s > kvm else itemsize
-    return (2 * g * (2 * qm + 2 * kvm) * dp * itemsize         # q dO k v
-            + 2 * g * (qm * dq_size + 2 * kvm * itemsize) * dp     # dq dk dv
+    return (2 * g * (qm + kvm) * (dp + dvp) * itemsize         # q dO k v
+            + 2 * g * (qm * dq_size * dp
+                       + kvm * itemsize * (dp + dvp))          # dq dk dv
             + 2 * 2 * g * 8 * qm * 4                           # lse, delta
             + (dq_size < 4 and kvm > bkv) * g * qm * dp * 4    # dq's sum
-            + (group * (s // qm) > 1) * 2 * g * kvm * dp * 4   # dk, dv sums
+            + (group * (s // qm) > 1) * g * kvm * (dp + dvp) * 4  # dk, dv sums
             + _tile_bytes(bq, bkv))
 
 
@@ -310,14 +331,17 @@ def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
                    block_kv: Optional[int] = None,
                    block_kv_major: Optional[int] = None, *,
                    window: Optional[int] = None,
-                   bh_kv: Optional[int] = None) -> FlashSchedule:
+                   bh_kv: Optional[int] = None,
+                   dv: Optional[int] = None) -> FlashSchedule:
     """The schedule of both kernels for [bh, s, d] queries of ``itemsize``
-    bytes over [bh_kv, s, d] keys and values (``None``: as many heads as the
-    queries), under a ``window`` of keys (``None`` or >= s: every key before
+    bytes over [bh_kv, s, d] keys and [bh_kv, s, dv] values (``bh_kv``
+    ``None``: as many heads as the queries; ``dv`` ``None``: as wide as the
+    keys), under a ``window`` of keys (``None`` or >= s: every key before
     the query): pure, from the shape alone. ``block_q`` / ``block_kv`` /
     ``block_kv_major`` are upper bounds (the tests' override). Raises
     ValueError when S has no power-of-two block divisor >= 8."""
     group, window = _group(bh, bh_kv), _window(window, s, causal)
+    dv = 0 if dv in (None, d) else int(dv)
     # The compute tile: a whole row of scores where that is at most
     # _ROW_TILE wide (a plain softmax, no running statistics), else
     # _TILE x _TILE (measured on a v5e: PERF.md, PR 26).
@@ -330,11 +354,11 @@ def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
             f"sequence length; S={s} has none (use attention 'full')")
 
     def fwd_fits(gk, kvm):
-        return _fwd_bytes(gk * group, gk, bq, bkv, kvm, s, d, itemsize) \
+        return _fwd_bytes(gk * group, gk, bq, bkv, kvm, s, d, itemsize, dv) \
             <= VMEM_BUDGET_BYTES
 
     def bwd_fits(gk, kvm, qm):
-        return _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group) \
+        return _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group, dv) \
             <= VMEM_BUDGET_BYTES
 
     # Rows a step keeps, each pass by its own blocks: all of S, cut down
@@ -368,7 +392,7 @@ def flash_schedule(bh: int, s: int, d: int, itemsize: int, causal: bool,
         if cand * per_head >= _STEP_WORK:
             break
     return _schedule(bh, s, d, itemsize, causal, gk, bq, bkv, fwd_kvm, kvm,
-                     qm, group=group, window=window)
+                     qm, group=group, window=window, dv=dv)
 
 
 def _group(bh, bh_kv):
@@ -392,9 +416,10 @@ def _window(window, s, causal):
 
 
 def _schedule(bh, s, d, itemsize, causal, gk, bq, bkv, fwd_kvm, kvm, qm, *,
-              group=1, window=0):
+              group=1, window=0, dv=0):
     """The grids and counts that follow from ``gk`` K/V heads a step, the
-    compute tile, the forward's K/V rows and the backward's K/V and q rows."""
+    compute tile, the forward's K/V rows and the backward's K/V and q rows
+    (``dv``: the value's width where it is not ``d``, else 0)."""
     g = gk * group
     # heads a compute tile: independent chains for the scheduler to
     # interleave, while the f32 score tiles stay around _TILE_BYTES; one
@@ -409,13 +434,13 @@ def _schedule(bh, s, d, itemsize, causal, gk, bq, bkv, fwd_kvm, kvm, qm, *,
     return FlashSchedule(
         bq, bkv,
         g, hb, fwd_kvm, (n, s // bq, s // fwd_kvm), n * steps, n * live,
-        _fwd_bytes(g, gk, bq, bkv, fwd_kvm, s, d, itemsize),
+        _fwd_bytes(g, gk, bq, bkv, fwd_kvm, s, d, itemsize, dv),
         kvm, qm,
         (n, s // kvm, group * (s // qm)), n * group * bwd_steps,
         n * group * bwd_live,
-        _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group),
+        _bwd_bytes(gk, bq, bkv, kvm, qm, s, d, itemsize, group, dv),
         group, window, bh * tiles, bh * live_tiles,
-        bh * _bwd_visits(s, bq, bkv, kvm, qm, causal, window))
+        bh * _bwd_visits(s, bq, bkv, kvm, qm, causal, window), dv)
 
 
 def _bdot(a, b, ca, cb):
@@ -461,7 +486,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     g, hb, bq, bkv, kvm = (sched.g, sched.block_h, sched.block_q,
                            sched.block_kv, sched.block_kv_major)
     group, window = sched.group, sched.window
-    d = q_ref.shape[-1]
+    dv = v_ref.shape[-1]
     jm = pl.program_id(2)
     q0 = pl.program_id(1) * bq          # first query of this step
     k0 = jm * kvm                       # first key of this step's K/V block
@@ -491,7 +516,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
     def _pv(hs, p, t):
         v = v_ref[_kv(hs), _rows(t * bkv, bkv), :]
-        return _bdot(p.astype(v.dtype), v, 2, 1)        # [hb, bq, d]
+        return _bdot(p.astype(v.dtype), v, 2, 1)        # [hb, bq, dv]
 
     def _tile(t, carry, *, hs, q, masked):
         m_prev, l_prev, acc = carry
@@ -524,7 +549,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         if single:
             carry = (jnp.full((hb, bq, 1), NEG_INF, jnp.float32),
                      jnp.zeros((hb, bq, 1), jnp.float32),
-                     jnp.zeros((hb, bq, d), jnp.float32))
+                     jnp.zeros((hb, bq, dv), jnp.float32))
         else:
             carry = tuple(ref[hs] for ref in scratch)
         if window:
@@ -569,6 +594,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
 def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
+    dv = v3.shape[-1]
     g, bq, kvm = sched.g, sched.block_q, sched.block_kv_major
     gk, window = g // sched.group, sched.window
 
@@ -586,21 +612,21 @@ def _fwd_call(q3, k3, v3, causal, scale, sched, interpret):
         in_specs=[
             pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
             pl.BlockSpec((gk, kvm, d), kv_map),
-            pl.BlockSpec((gk, kvm, d), kv_map),
+            pl.BlockSpec((gk, kvm, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((g, bq, d), lambda b, i, jm: (b, i, 0)),
+            pl.BlockSpec((g, bq, dv), lambda b, i, jm: (b, i, 0)),
             pl.BlockSpec((g, 1, 1, bq), lambda b, i, jm: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, s // bq, 1, bq), jnp.float32),
         ],
         # m, l, acc between the steps of a kv axis
         scratch_shapes=[] if sched.grid[2] == 1 else [
             pltpu.VMEM((g, bq, 1), jnp.float32),
             pltpu.VMEM((g, bq, 1), jnp.float32),
-            pltpu.VMEM((g, bq, d), jnp.float32),
+            pltpu.VMEM((g, bq, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(),
         name="flash_win_fwd" if window else "flash_fwd",
@@ -637,7 +663,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k0 = pl.program_id(1) * kvm         # first key of this step's K/V block
     q0 = im * qm                        # first query of this step's q block
     n_q, n_kv = qm // bq, kvm // bkv
-    d = q_ref.shape[-1]
+    d, dv = q_ref.shape[-1], v_ref.shape[-1]
     dq_scratch, dkv_scratch = _bwd_scratch(sched, dq_ref.dtype)
     scratch = list(scratch)
     # dQ sums over the kv tiles of a step: in the output block where that is
@@ -663,7 +689,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_sum[:] = jnp.zeros_like(dq_sum)
 
     def _pair(t, carry, *, hs, k, v, ks, masked):
-        dk, dv = carry
+        dk, dv_ = carry
         rows = _rows(t * bq, bq)
         q = q_ref[hs, rows, :].astype(jnp.float32) * scale
         do = do_ref[hs, rows, :].astype(jnp.float32)
@@ -671,7 +697,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if masked:
             s = _mask(s, q0 + t * bq, ks, 2, window)
         p = jnp.exp(s - lse_ref[hs, t])
-        dv = dv + _bdot(p, do, 2, 1)
+        dv_ = dv_ + _bdot(p, do, 2, 1)                      # [hb, bkv, dv]
         ds = p * (_bdot(v, do, 2, 2) - delta_ref[hs, t])
         dk = dk + _bdot(ds, q, 2, 1)
         dq = _bdot(ds, k * scale, 1, 1)                     # [hb, bq, d]
@@ -679,7 +705,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_ref[hs, rows, :] = dq.astype(dq_ref.dtype)
         else:
             dq_sum[hs, rows, :] += dq
-        return dk, dv
+        return dk, dv_
 
     def _kv_tile(j, _, *, hs, kv):
         """One kv tile of K/V head(s) ``kv`` against the q tiles of query
@@ -697,7 +723,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if window:
             t_in = jnp.clip(_div(ks + window - q0, bq), t_full, t_hi)
         kw = dict(hs=hs, k=k, v=v, ks=ks)
-        carry = (jnp.zeros((hb, bkv, d), jnp.float32),) * 2
+        # one value twice where the widths are equal: the kernel traced for
+        # ``dv == d`` is, equation for equation, the one-width kernel
+        zeros = jnp.zeros((hb, bkv, d), jnp.float32)
+        carry = (zeros, zeros if dv == d
+                 else jnp.zeros((hb, bkv, dv), jnp.float32))
         if causal:
             carry = jax.lax.fori_loop(
                 t_live, t_full, partial(_pair, masked=True, **kw), carry)
@@ -707,13 +737,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if window:
             carry = jax.lax.fori_loop(
                 t_in, t_hi, partial(_pair, masked=True, **kw), carry)
-        dk, dv = carry
+        dk, dv_ = carry
         if dk_acc is None:
             dk_ref[kv, rows, :] = dk.astype(dk_ref.dtype)
-            dv_ref[kv, rows, :] = dv.astype(dv_ref.dtype)
+            dv_ref[kv, rows, :] = dv_.astype(dv_ref.dtype)
         else:
             dk_acc[kv, rows, :] += dk
-            dv_acc[kv, rows, :] += dv
+            dv_acc[kv, rows, :] += dv_
 
     def _heads(hg, _):
         # a tile's query heads with their own K/V, or one query head with
@@ -760,6 +790,7 @@ def _q_heads(i, sched):
 
 def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
     bh, s, d = q3.shape
+    dv = v3.shape[-1]
     g, bq, kvm, qm = (sched.bwd_g, sched.block_q, sched.bwd_block_kv_major,
                       sched.block_q_major)
     group, window = sched.group, sched.window
@@ -779,8 +810,9 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
             im = jnp.minimum(im, _div(jm * kvm + kvm + window - 2, qm))
         return (q_heads(b, i), im, 0)
 
-    q_spec = pl.BlockSpec((g, qm, d), q_map)
-    kv_spec = pl.BlockSpec((g, kvm, d), lambda b, jm, i: (b, jm, 0))
+    kv_map = lambda b, jm, i: (b, jm, 0)
+    q_spec, do_spec = (pl.BlockSpec((g, qm, w), q_map) for w in (d, dv))
+    k_spec, v_spec = (pl.BlockSpec((g, kvm, w), kv_map) for w in (d, dv))
     row_spec = pl.BlockSpec((g, qm // bq, 1, bq),
                             lambda b, jm, i: q_map(b, jm, i) + (0,))
     # one kv block: the step's dQ is dQ; several: f32 partials, summed below
@@ -789,12 +821,12 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
     dq, dk, dv = pl.pallas_call(
         partial(_bwd_kernel, causal=causal, scale=scale, sched=sched),
         grid=sched.bwd_grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
         out_specs=[
             pl.BlockSpec((None, g, qm, d),
                          lambda b, jm, i: (jm, q_heads(b, i),
                                            _q_block(i, sched), 0)),
-            kv_spec, kv_spec,
+            k_spec, v_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_kvm, bh, s, d), dq_dtype),
@@ -803,7 +835,7 @@ def _bwd_call(q3, k3, v3, o3, lse, do3, causal, scale, sched, interpret):
         ],
         scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in
                         dq_scratch * [(g, qm, d)]
-                        + dkv_scratch * [(g, kvm, d), (g, kvm, d)]],
+                        + dkv_scratch * [(g, kvm, d), (g, kvm, dv)]],
         compiler_params=_compiler_params(),
         name="flash_win_bwd_dkv" if window else "flash_bwd_dkv",
         interpret=interpret,
@@ -853,12 +885,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_kv: Optional[int] = None,
                     block_kv_major: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Fused attention over q [B, H, S, D] and k, v [B, Hkv, S, D], H a
-    multiple of Hkv (query head h reads key/value head ``h // (H / Hkv)``);
-    drop-in for ``ring.full_attention`` (same signature semantics, same
-    output). ``window``: query i sees keys ``i - window < j <= i`` (needs
-    ``causal``). The schedule comes from ``flash_schedule`` at the inputs'
-    shape; the block arguments bound it from above (the tests' override).
+    """Fused attention over q [B, H, S, D], k [B, Hkv, S, D] and v [B, Hkv,
+    S, Dv], H a multiple of Hkv (query head h reads key/value head ``h // (H
+    / Hkv)``); the output is [B, H, S, Dv], the scale ``D ** -0.5``. Drop-in
+    for ``ring.full_attention`` (same signature semantics, same output).
+    ``window``: query i sees keys ``i - window < j <= i`` (needs ``causal``).
+    The schedule comes from ``flash_schedule`` at the inputs' shape; the
+    block arguments bound it from above (the tests' override).
 
     Raises ValueError when S has no power-of-two block divisor >= 8: a
     caller that asked for the fused kernel is told it cannot have it.
@@ -866,14 +899,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if interpret is None:
         interpret = _interpret_default()
     b, h, s, d = q.shape
-    h_kv = k.shape[1]
+    h_kv, dv = k.shape[1], v.shape[-1]
     sched = flash_schedule(b * h, s, d, q.dtype.itemsize, causal,
                            block_q, block_kv, block_kv_major,
-                           window=window, bh_kv=b * h_kv)
+                           window=window, bh_kv=b * h_kv, dv=dv)
     if scale is None:
         scale = float(d) ** -0.5
     q3 = q.reshape(b * h, s, d)
     k3 = k.reshape(b * h_kv, s, d)
-    v3 = v.reshape(b * h_kv, s, d)
+    v3 = v.reshape(b * h_kv, s, dv)
     o3 = _flash(q3, k3, v3, causal, float(scale), sched, bool(interpret))
-    return o3.reshape(b, h, s, d)
+    return o3.reshape(b, h, s, dv)

@@ -225,14 +225,15 @@ class LMTrainer:
             # the schedule is static per shape: the line is its record, one
             # for each kind of attention layer the arch mixes (window or
             # not); under differential heads a call holds one head of each
-            # pair
+            # pair and, as its value, the pair's two value heads side by side
             per_call = 2 if arch.diff_attn else 1
+            hd = cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads
             scheds = {flash_schedule(
-                rows * cfg.lm_heads // per_call, cfg.lm_seq_len,
-                cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads,
+                rows * cfg.lm_heads // per_call, cfg.lm_seq_len, hd,
                 jnp.dtype(self.model.dtype).itemsize, True,
                 window=arch.layer_window(i, cfg.lm_layers),
-                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads) // per_call)
+                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads) // per_call,
+                dv=per_call * hd)
                 for i in range(cfg.lm_layers)
                 if arch.layer_kind(i, cfg.lm_layers) in ATTENTION_KINDS}
             kernels += [f"flash_attention[{sched.describe()}]"

@@ -59,14 +59,17 @@ def _flash_flops(eqn) -> int:
     """The kernels of ``ops/flash_attention.py``. They walk the live tiles in
     ``fori_loop``s whose trip counts depend on the grid position, which grid x
     body cannot count; they are charged the dense S x S products, like
-    ``full_attention``: two in ``flash_fwd``, five in ``flash_bwd_dkv``
-    (q is the first [bh, S, D] operand), under a window too
-    (``flash_win_*``): the program's own logged ``mfu`` therefore overstates
-    a window layer's work; the benchmark's charges the band
+    ``full_attention``: two in ``flash_fwd`` (the scores over the keys'
+    width, the weighted sum over the value's), five in ``flash_bwd_dkv``
+    (the scores, dQ and dK over the keys' width, dP and dV over the value's;
+    q is the first [bh, S, D] operand, v the third, [bh_kv, S, Dv]), under a
+    window too (``flash_win_*``): the program's own logged ``mfu`` therefore
+    overstates a window layer's work; the benchmark's charges the band
     (``benchmark/reference/smallthinker_21b_a3b.py``)."""
     bh, s, d = eqn.invars[0].aval.shape
-    products = 2 if eqn.params["name"].endswith("_fwd") else 5
-    return products * 2 * bh * s * s * d
+    dv = eqn.invars[2].aval.shape[-1]
+    widths = d + dv if eqn.params["name"].endswith("_fwd") else 3 * d + 2 * dv
+    return widths * 2 * bh * s * s
 
 
 def _conv_flops(eqn) -> int:

@@ -6,8 +6,11 @@ reference ``benchmark/reference/phi4_mini_flash.py`` at a tiny float32 size:
 the common suite (``tests/arch_suite.py``) and what is Phi-4-flash's alone:
 the loss and every parameter's gradient outside a step (the scan's
 hand-written backward, the summed gradients of what is handed on, the tied
-head), the kinds of layer by index and depth, the counters sown, and what its
-controls cover. The scan itself is ``tests/test_selective_scan.py``'s."""
+head), the kinds of layer by index and depth, the counters sown, what its
+controls cover, and the form of a differential layer under ``flash`` (two
+calls, a value twice the keys' width, nothing sliced by stride or joined; what
+a layer hands on is what the calls take). The scan itself is
+``tests/test_selective_scan.py``'s."""
 
 import jax
 import jax.numpy as jnp
@@ -17,7 +20,7 @@ import pytest
 import arch_suite as suite
 from ps_pytorch_tpu.config import TrainConfig
 from ps_pytorch_tpu.models.transformer import (
-    ARCHS, COUNTER_NAMES, LAYER_KINDS, LM_COUNTERS, TransformerLM,
+    ARCHS, COUNTER_NAMES, LAYER_KINDS, LM_COUNTERS, Block, TransformerLM,
     lm_counters,
 )
 
@@ -214,6 +217,134 @@ def test_the_kernels_line_prints_each_schedule():
     layer and the scan's schedule."""
     kernels = suite.step(CASE, True).kernels
     # S = 24 is over the tiny window of 5: a record for the window layers and
-    # one for the full and cross layers, each a call of half the heads
-    assert kernels.count("flash_attention[") == 2 and "window=5" in kernels
+    # one for the full and cross layers, each a call of half the heads over
+    # the pairs' values, two heads of 8 side by side
+    assert kernels.count("flash_attention[") == 2
+    assert kernels.count(" dv=16 tiles=") == 2
+    assert kernels.count(" kv_heads=1 dv=16 ") == 1
+    assert kernels.count(" kv_heads=1 window=5 dv=16 ") == 1
     assert "selective_scan[chunk=24 chunks=1 grid=2x1x1" in kernels
+
+
+# ---- a differential layer under flash: two calls, nothing sliced or joined -----
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls, a Pallas kernel's
+    body apart (what a kernel does inside is the kernel's)."""
+    from ps_pytorch_tpu.utils.flops import _sub_jaxprs
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in _sub_jaxprs(eqn):
+                yield from _eqns(sub)
+
+
+def _flash_grads(remat):
+    model, variables, tokens = suite.tiny(CASE)
+    model = model.clone(attention_impl="flash", remat=remat)
+    rest = {k: v for k, v in variables.items() if k != "params"}
+    return jax.value_and_grad(lambda p: suite.model_loss(
+        model, {**rest, "params": p}, tokens)), variables["params"]
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_a_differential_layer_makes_two_calls_and_no_slices(remat):
+    """The jaxpr of the loss and its gradient under ``flash``: each of the
+    four attention layers (two window, one full, one cross) calls the forward
+    kernel twice and the backward kernel twice, on a value twice as wide as
+    the keys, and under ``attn_core`` nothing is joined, padded, gathered or
+    sliced by stride, forward or backward."""
+    fn, params = _flash_grads(remat)
+    with CASE.patched():
+        eqns = list(_eqns(jax.make_jaxpr(fn)(params).jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("flash_")]
+    names = [e.params["name"] for e in calls]
+    # the forward runs once a layer under remat too: its output is kept
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_win_fwd": 4, "flash_win_bwd_dkv": 4, "flash_fwd": 4,
+        "flash_bwd_dkv": 4}
+    for e in calls:
+        q, k, v = (var.aval.shape for var in e.invars[:3])
+        assert (q, k, v) == ((4, S, 8), (2, S, 8), (2, S, 16))   # B x heads
+        assert e.outvars[0].aval.shape == (
+            (4, S, 16) if e.params["name"].endswith("_fwd") else (1, 4, S, 8))
+    core = [e for e in eqns if "attn_core" in str(e.source_info.name_stack)]
+    assert {e.primitive.name for e in core} >= {"pallas_call"}
+    for e in core:
+        assert e.primitive.name not in (
+            "concatenate", "pad", "gather", "scatter-add", "scatter",
+            "dynamic_update_slice", "transpose"), e
+        if e.primitive.name == "slice":
+            assert not e.params["strides"] or set(e.params["strides"]) == {1}
+    # the model's own slices and joins, whatever the scope: a pair's heads
+    # come apart as two blocks of one array, and nothing is put together
+    model_eqns = [e for e in eqns if "attn_" in str(e.source_info.name_stack)]
+    assert not [e for e in model_eqns if e.primitive.name == "concatenate"]
+    assert not [e for e in model_eqns if e.primitive.name == "slice"
+                and e.params["strides"] and set(e.params["strides"]) != {1}]
+
+
+def test_loss_and_gradients_under_flash_are_those_under_full():
+    fn, params = _flash_grads(False)
+    with CASE.patched():
+        loss, grads = jax.jit(fn)(params)
+    want_loss, want = suite.grads(CASE, False)
+    assert abs(float(loss) - float(want_loss)) < 1e-5
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree.leaves(want)):
+        scale = float(jnp.abs(w).max())
+        assert float(jnp.abs(g - w).max()) < 2e-4 * max(scale, 1e-2), \
+            jax.tree_util.keystr(path)
+
+
+def _block(model, layer, **kw):
+    return Block(model.n_heads, model.d_model, model.dtype, arch=model.arch,
+                 ffn_dim=model.ffn_dim, layer=layer, kv_heads=model.kv_heads,
+                 head_dim=model.head_dim, n_layers=model.n_layers, **kw)
+
+
+def test_what_a_layer_hands_on_is_what_the_calls_take(tiny):
+    """Layer 5 hands on K as its pairs' heads apart, two arrays [B, pairs, S,
+    hd], and V as the pairs' values [B, pairs, S, 2 hd]: the projections'
+    features as they lie. The cross layer's two calls read exactly those
+    arrays (a reshape to the kernels' three dimensions apart: no copy)."""
+    model, variables, tokens = tiny
+    p = variables["params"]
+    x = jax.random.normal(jax.random.key(2), (2, S, model.d_model))
+    with CASE.patched():
+        _, out = _block(model, 5).apply({"params": p["block_5"]}, x, None, {})
+    k, v = out["k"], out["v"]
+    assert [t.shape for t in k] == [(2, 1, S, 8)] * 2 and v.shape == (2, 1, S, 16)
+    ln = p["block_5"]["LayerNorm_0"]
+    mean = x.mean(-1, keepdims=True)
+    y = (x - mean) / jnp.sqrt(((x - mean) ** 2).mean(-1, keepdims=True)
+                              + ROW.norm_eps) * ln["scale"] + ln["bias"]
+    k_proj = y @ p["block_5"]["Dense_1"]["kernel"]      # [B, S, 2 heads x 8]
+    v_proj = y @ p["block_5"]["Dense_2"]["kernel"]
+    for i in (0, 1):                                    # head i of the pair
+        np.testing.assert_allclose(k[i][:, 0], k_proj[..., 8 * i:8 * i + 8],
+                                   rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(v[:, 0], v_proj, rtol=1e-5, atol=1e-5)
+
+    handed = {"k": k, "v": v, "memory": jnp.zeros((2, S, 64))}
+    cross = _block(model, 7, attention_impl="flash")
+    with CASE.patched():
+        jaxpr = jax.make_jaxpr(lambda h: cross.apply(
+            {"params": p["block_7"]}, x, None, h))(handed).jaxpr
+    made = {var: e for e in jaxpr.eqns for var in e.outvars}
+
+    def source(var):
+        while var in made and made[var].primitive.name == "reshape":
+            var = made[var].invars[0]
+        return var
+    flash = [e for e in jaxpr.eqns if e.primitive.name == "custom_vjp_call"]
+    assert len(flash) == 2
+    leaves = dict(zip(jaxpr.invars, ("k0", "k1", "memory", "v")))
+    assert [[leaves.get(source(var)) for var in e.invars[1:3]]
+            for e in flash] == [["k0", "v"], ["k1", "v"]]
+    # and they are read as what they are: the pair's heads exchanged is
+    # another layer
+    got = [cross.apply({"params": p["block_7"]}, x, None, h)[0]
+           for h in (handed, {**handed, "k": k[::-1]})]
+    assert float(jnp.abs(got[0] - got[1]).max()) > 1e-3

@@ -9,8 +9,10 @@ identical kernels under Mosaic.
 Last in the file: the kernels
 under fewer key/value heads than query heads and under a window, output and
 all three gradients against the same oracle; the schedule's band against a
-brute-force count; and both passes of the long-context cells' schedules,
-pinned.
+brute-force count; both passes of the long-context cells' schedules,
+pinned; and a value wider than the keys (a differential pair's two value
+heads side by side), against the same oracle on the same ``(q, k, v)``, with
+the one-width schedules held where they were.
 """
 
 from functools import partial
@@ -21,8 +23,8 @@ import numpy as np
 import pytest
 
 from ps_pytorch_tpu.ops.flash_attention import (
-    VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, _flash, _schedule, flash_attention,
-    flash_schedule,
+    VMEM_BUDGET_BYTES, VMEM_LIMIT_BYTES, FlashSchedule, _flash, _schedule,
+    flash_attention, flash_schedule,
 )
 from ps_pytorch_tpu.parallel.ring import full_attention
 
@@ -587,3 +589,140 @@ def test_the_window_layers_visit_at_most_half_the_global_layers_tiles():
     assert win.bwd_g == glob.bwd_g == 1
     same = dict(live=0, bwd_live=0, live_tiles=0, bwd_visits=0)
     assert win._replace(window=0, **same) == glob._replace(**same)
+
+
+# ---- a value wider than the keys ---------------------------------------------------
+
+# q, k [1, heads | kv_heads, 256, 64] and v [1, kv_heads, 256, 128], the
+# widths of the Phi-4-flash cell's calls: name -> (heads, kv_heads, window,
+# (bq, bkv, forward's kv rows, backward's kv rows, backward's q rows)), every
+# one with several compute tiles a step and, bar the last (the cell's form: a
+# K/V head whole), several kv blocks a call, the grouped ones with dK/dV
+# summed over the steps of a group and of q blocks.
+WIDE_VALUE = {
+    "causal_group_1": (2, 2, 0, (64, 64, 128, 128, 256)),
+    "causal_group_2": (4, 2, 0, (32, 64, 128, 128, 64)),
+    "window_group_1": (2, 2, 100, (32, 64, 128, 64, 128)),
+    "window_group_2": (4, 2, 40, (64, 32, 128, 64, 128)),
+    "window_group_2_whole_kv_head": (4, 2, 96, (32, 32, 256, 256, 64)),
+}
+
+
+def _wide_case(name, dtype):
+    h, h_kv, window, blocks = WIDE_VALUE[name]
+    s, d, dv = 256, 64, 128
+    ks = jax.random.split(jax.random.key(11), 4)
+    q, k, v = (jax.random.normal(key, (1, n, s, w)).astype(dtype)
+               for key, n, w in zip(ks, (h, h_kv, h_kv), (d, d, dv)))
+    sc = _schedule(h, s, d, q.dtype.itemsize, True, 1, *blocks,
+                   group=h // h_kv, window=window, dv=dv)
+
+    def flash(q, k, v):
+        r = lambda t: t.reshape((-1,) + t.shape[2:])
+        return _flash(r(q), r(k), r(v), True, d ** -0.5, sc,
+                      True).reshape(1, h, s, dv)
+    full = partial(full_attention, causal=True, window=window or None)
+    return (q, k, v), jax.random.normal(ks[3], (1, h, s, dv)), sc, flash, full
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("name", sorted(WIDE_VALUE))
+def test_a_value_twice_the_keys_width_against_full(name, dtype):
+    """Output [B, H, S, dv] and all three gradients (dQ and dK as wide as the
+    keys, dV as wide as the value) against ``full_attention`` on the same
+    ``(q, k, v)``; the scale is the keys' width's."""
+    qkv, w, sc, flash, full = _wide_case(name, dtype)
+    bkv = sc.block_kv
+    assert sc.dv == 128 and " dv=128 " in sc.describe()
+    assert sc.block_kv_major > bkv or sc.bwd_block_kv_major > bkv
+    # several kv blocks a call in either pass, bar the cell's own form
+    assert (sc.grid[2] > 1 and sc.bwd_grid[1] > 1) \
+        == ("whole_kv_head" not in name)
+    f32 = tuple(t.astype(jnp.float32) for t in qkv)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    got, want = flash(*qkv), full(*f32)
+    assert got.dtype == dtype and got.shape == want.shape == w.shape
+    np.testing.assert_allclose(got.astype(jnp.float32), want, rtol=tol,
+                               atol=tol)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(*qkv)
+    want = jax.grad(loss(full), argnums=(0, 1, 2))(*f32)
+    tol = 5e-4 if dtype == jnp.float32 else 3e-2
+    for a, b, t, leaf in zip(got, want, qkv, "qkv"):
+        assert a.dtype == dtype and a.shape == b.shape == t.shape
+        np.testing.assert_allclose(
+            a.astype(jnp.float32), b, rtol=tol,
+            atol=tol * max(1.0, float(jnp.abs(b).max())),
+            err_msg=f"{name} d{leaf}")
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_a_wide_value_by_the_default_schedule(window):
+    """``flash_attention`` reads the value's width from ``v``: the public
+    call, the schedule its own (a whole row of scores a tile at this S)."""
+    ks = jax.random.split(jax.random.key(12), 3)
+    q, k, v = (jax.random.normal(key, (2, n, 128, w))
+               for key, n, w in zip(ks, (4, 2, 2), (16, 16, 32)))
+    got = flash_attention(q, k, v, causal=True, window=window)
+    want = full_attention(q, k, v, causal=True, window=window)
+    assert got.shape == (2, 4, 128, 32)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # the same call with the value cut to the keys' width is the old call
+    np.testing.assert_allclose(
+        flash_attention(q, k, v[..., :16], causal=True, window=window),
+        got[..., :16], rtol=2e-5, atol=2e-5)
+
+
+ONE_WIDTH_SHAPES = {
+    **{f"{cell}_itemsize_{i}": (bh, None, s, d, i, None)
+       for cell, (bh, s, d) in CELL_SHAPES.items() for i in (4, 2)},
+    **{name: SCHEDULE_BANDS[name][:6] for name in CELL_SCHEDULES},
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_WIDTH_SHAPES))
+def test_the_keys_width_given_as_the_values_is_the_one_width_schedule(name):
+    """``dv`` defaults to ``d``, and given as ``d`` it leaves nothing behind:
+    the record is equal field for field and prints no ``dv=``, at every shape
+    ``test_schedule_of_the_cells`` and
+    ``test_the_long_context_cells_schedules_are_pinned`` pin."""
+    bh, bh_kv, s, d, itemsize, window = ONE_WIDTH_SHAPES[name]
+    one = flash_schedule(bh, s, d, itemsize, True, window=window, bh_kv=bh_kv)
+    assert flash_schedule(bh, s, d, itemsize, True, window=window,
+                          bh_kv=bh_kv, dv=d) == one
+    assert one.dv == 0 and "dv=" not in one.describe()
+    # a wider value is another record, sized to the same budget
+    wide = flash_schedule(bh, s, d, itemsize, True, window=window,
+                          bh_kv=bh_kv, dv=2 * d)
+    assert wide.dv == 2 * d and wide != one
+    assert wide.vmem_bytes <= VMEM_BUDGET_BYTES
+    assert wide.bwd_vmem_bytes <= VMEM_BUDGET_BYTES
+
+
+# phi4flash_s8192_1chip since PR 50: a call is one head of each differential
+# pair, 20 query heads of 64 over 10 K/V heads and the pairs' values, 10 heads
+# of 128. A block's last dimension pads to 128 lanes, so a step holds what
+# the call of 64-wide values held (PR 35) and the schedule is that call's.
+PHI4FLASH_CALLS = {
+    0: FlashSchedule(
+        512, 512, 2, 1, 8192, (10, 16, 1), 160, 160, 13697024,
+        8192, 4096, (10, 1, 4), 40, 40, 38273024,
+        group=2, window=0, tiles=5120, live_tiles=2720, bwd_visits=2720,
+        dv=128),
+    512: FlashSchedule(
+        512, 512, 2, 1, 8192, (10, 16, 1), 160, 160, 13697024,
+        8192, 4096, (10, 1, 4), 40, 40, 38273024,
+        group=2, window=512, tiles=5120, live_tiles=620, bwd_visits=620,
+        dv=128),
+}
+
+
+@pytest.mark.parametrize("window", sorted(PHI4FLASH_CALLS))
+def test_the_differential_cells_call_is_pinned(window):
+    sc = flash_schedule(20, 8192, 64, 2, True, window=window or None,
+                        bh_kv=10, dv=128)
+    assert sc == PHI4FLASH_CALLS[window]
+    assert sc._replace(dv=0) == flash_schedule(
+        20, 8192, 64, 2, True, window=window or None, bh_kv=10)
+    kind = " kv_heads=1" + (f" window={window}" if window else "") + " dv=128 "
+    assert kind + f"tiles={sc.live_tiles}/5120 " in sc.describe()

@@ -103,6 +103,27 @@ def test_pallas_kernel_counts_once_per_grid_point():
     assert n_grad == n_full // 2 * 7
 
 
+@pytest.mark.parametrize("dv", [64, 128, 32])
+def test_flash_charges_the_keys_and_the_values_widths_apart(dv):
+    """A value of its own width: the scores, dQ and dK are products over the
+    keys' width, the weighted sum, dP and dV over the value's: ``d + dv``
+    forward and ``3 d + 2 dv`` backward, what ``full_attention`` is charged
+    on the same ``(q, k, v)``."""
+    from ps_pytorch_tpu.ops.flash_attention import flash_attention
+    from ps_pytorch_tpu.parallel.ring import full_attention
+    q, v = jnp.zeros((1, 4, 256, 64)), jnp.zeros((1, 2, 256, dv))
+    k = jnp.zeros((1, 2, 256, 64))
+    per_width = 2 * 4 * 256 * 256
+    n_flash = forward_flops(
+        lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+    assert n_flash == per_width * (64 + dv) == forward_flops(
+        lambda q, k, v: full_attention(q, k, v, causal=True), q, k, v)
+    n_grad = forward_flops(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True).sum(),
+        argnums=(0, 1, 2)), q, k, v)
+    assert n_grad == per_width * (64 + dv + 3 * 64 + 2 * dv)
+
+
 def test_strided_conv_backward_multiple_is_sane():
     """grad-input and grad-weight of a conv each cost ~1x forward, so
     value_and_grad should be ~3x forward — for STRIDED convs too (the

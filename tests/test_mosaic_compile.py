@@ -214,12 +214,14 @@ class StepCell(NamedTuple):
 # runs ONCE a layer (a rematerialised block keeps its output and log-sum-exp,
 # models/remat.py: were the names lost it would run twice), one call a window
 # layer and one the global layer in SmallThinker and Trinity, a call a head of
-# a differential pair's halves in the hybrid; every other forward kernel runs
+# a differential pair in the hybrid; every other forward kernel runs
 # twice a layer (the recomputed forward makes its residuals again), every
 # backward kernel once; the grouped matmuls are a call an expert matmul.
 # A plan moves by a few hundred KB with the heap's packing; PERF.md section 4
 # has the history (PR 46 is the first since PR 34 to raise one: a
-# rematerialised block keeps more).
+# rematerialised block keeps more; PR 50 raises the hybrid's by 1.2 GiB
+# though its layers keep less: its first schedule now fits XLA's limit and
+# stands, where the parent's was made again under a tighter one).
 STEP_CELLS = {
     "smallthinker_s16384_1chip": StepCell(
         "smallthinker_21b_a3b", "s16384_1chip", (64, 16), 559_290_880,
@@ -233,9 +235,9 @@ STEP_CELLS = {
          "moe_gmm_drhs": 24}, "ssm_", 13_372_787_712),
     "phi4flash_s8192_1chip": StepCell(
         "phi4_mini_flash", "s8192_reasoning_1chip", (8, 0), 915_283_456,
-        {"flash_fwd": 8, "flash_bwd_dkv": 8, "flash_win_fwd": 8,
-         "flash_win_bwd_dkv": 8, "ssm_scan_fwd": 6, "ssm_scan_bwd": 3},
-        "moe_gmm_", 14_090_002_432),
+        {"flash_fwd": 4, "flash_bwd_dkv": 4, "flash_win_fwd": 4,
+         "flash_win_bwd_dkv": 4, "ssm_scan_fwd": 6, "ssm_scan_bwd": 3},
+        "moe_gmm_", 15_383_074_304),
     "qwen3next_s16384_1chip": StepCell(
         "qwen3_next_80b_a3b", "s16384_hybrid_1chip", (512, 64), 1_028_320_320,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "gdr_solve": 6, "gdr_fwd": 6,
@@ -269,9 +271,10 @@ def test_the_cells_whole_step_fits_by_the_rule(one_chip, monkeypatch, cell):
     the one found), every kernel the cell runs is in it as a Pallas call, as
     many times as ``STEP_CELLS`` says, the configuration's rule (``cut.rule``:
     under 14.5 GiB by ``memory_analysis()``) holds at no more planned memory
-    than PR 46 found, and the compiler rematerialises nothing by itself (an
-    instruction named ``.remat``: in the Qwen3-Next step three copies of the
-    2048 -> 12,288 projection until PR 41, the sign of a plan too full)."""
+    than the table's figure, and the compiler rematerialises nothing by
+    itself (an instruction named ``.remat``: in the Qwen3-Next step three
+    copies of the 2048 -> 12,288 projection until PR 41, the sign of a plan
+    too full)."""
     import numpy as np
     c = STEP_CELLS[cell]
     cfg, params, text, planned = whole_step(one_chip, monkeypatch, c.config,
@@ -292,9 +295,14 @@ def test_the_cells_whole_step_fits_by_the_rule(one_chip, monkeypatch, cell):
 
 
 # phi4flash_s8192_1chip: a call holds one head of each differential pair, 20
-# query heads over 10 key/value heads of 64, under the window of 512 and without
+# query heads over 10 key/value heads of 64, under the window of 512 and
+# without; the value is the pair's two value heads side by side, 128 wide
+# (since PR 50; 64 wide, a call each, until then: the form any other arch of
+# 64-wide heads under a group of 2 would run)
+@pytest.mark.parametrize("dv", [64, 128])
 @pytest.mark.parametrize("window", [None, 512])
-def test_flash_at_head_dim_64_two_heads_a_kv_head_compiles(one_chip, window):
+def test_flash_at_head_dim_64_two_heads_a_kv_head_compiles(one_chip, window,
+                                                           dv):
     from ps_pytorch_tpu.ops.flash_attention import (
         flash_attention, flash_schedule,
     )
@@ -304,17 +312,23 @@ def test_flash_at_head_dim_64_two_heads_a_kv_head_compiles(one_chip, window):
         return jnp.sum(flash_attention(q, k, v, causal=True, window=window,
                                        interpret=False).astype(jnp.float32))
 
-    def arg(heads):
-        return jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16,
+    def arg(heads, width=d):
+        return jax.ShapeDtypeStruct((b, heads, s, width), jnp.bfloat16,
                                     sharding=one_chip)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+        arg(h), arg(h_kv), arg(h_kv, dv)).compile().as_text()
     names = ("flash_win_fwd", "flash_win_bwd_dkv") if window else \
         ("flash_fwd", "flash_bwd_dkv")
     for name in names:
         assert f"{name}_" in text
-    sc = flash_schedule(b * h, s, d, 2, True, window=window, bh_kv=b * h_kv)
+    # the output and dV as wide as the value, dQ and dK as the keys
+    for shape in (f"bf16[{h},{s},{dv}]", f"bf16[{h_kv},{s},{dv}]",
+                  f"bf16[1,{h},{s},{d}]", f"bf16[{h_kv},{s},{d}]"):
+        assert shape in text, shape
+    sc = flash_schedule(b * h, s, d, 2, True, window=window, bh_kv=b * h_kv,
+                        dv=dv)
+    assert sc.dv == (0 if dv == d else dv)
     assert sc.group == 2 and sc.window == (window or 0)
     # the band of 512 keys is one compute tile wide: a sixteenth of the
     # causal tiles and a few more, never all of them
