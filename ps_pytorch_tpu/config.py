@@ -25,10 +25,10 @@ from typing import Any, Optional
 # building a config imports no model code; tests/test_arch_olmoe.py holds the two
 # lists equal).
 LM_ARCHS = ("gpt2", "olmoe", "smallthinker", "trinity", "phi4flash",
-            "qwen3next", "nemotronh", "evabyte")
+            "qwen3next", "nemotronh", "evabyte", "granite4h")
 # ... of which those that route dropless (an MoE model: lm_parallelism=ep).
 _DROPLESS_ARCHS = ("olmoe", "smallthinker", "trinity", "qwen3next",
-                   "nemotronh")
+                   "nemotronh", "granite4h")
 
 
 @dataclass
@@ -117,7 +117,7 @@ class TrainConfig:
     lm_corpus_tokens: int = 1_000_000
     lm_corpus_file: str = ""         # byte-level REAL corpus from any local file ("" = synthetic Markov stream)
     lm_parallelism: str = "sp"       # sp (sequence/ring) | tp (tensor) | pp (pipeline) | ep (MoE model, experts sharded over 'data'; also how an MoE model is chosen on ONE chip)
-    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) | trinity (RMSNorm on each sublayer's input and output; three window-2048 RoPE layers to one full-causal layer without position encoding; q/k norm a head; gated attention output; embedding times sqrt(d); lm_dense_layers dense SwiGLU layers, then dropless sigmoid-scored top-k SwiGLU experts chosen under a bias the step moves against the load, gates renormalised and scaled, one shared expert; needs lm_parallelism=ep) | phi4flash (a decoder-hybrid-decoder; LayerNorm eps 1e-5, no position encoding, SwiGLU FFN, head tied to the embedding; layer kinds by index and depth, lm_layers a multiple of 4: Mamba-1 state-space layers and window-512 differential-attention layers alternate in the first half, then one Mamba layer whose scan output and one full differential-attention layer whose K/V every later layer reads, then gated memory units and cross-attention layers alternate; needs lm_parallelism=sp on ONE device) | qwen3next (zero-centred RMSNorm; three Gated DeltaNet linear-attention layers (16 key / 32 value heads of 128, a 4-tap convolution, the chunked gated delta rule) to one softmax-attention layer with q/k norm a head, a gated output and RoPE on a quarter of the head; dropless softmax-scored top-k SwiGLU experts, gates renormalised, one shared expert under a sigmoid gate; needs lm_parallelism=ep) | nemotronh (RMSNorm eps 1e-5, no position encoding; every layer ONE pre-norm residual sublayer by the letter of the published 52-letter pattern MEMEM*E...: a Mamba-2 mixer (64 heads of 64 with a [64, 128] state, B and C in 8 groups, a biased 4-tap convolution, the chunked state-space-dual kernel, the gate before a norm in 8 groups), an attention mixer, or an expert layer alone: dropless sigmoid-scored top-k experts down(relu(up x)^2) without a gate projection, chosen under a bias the step moves against the load, gates renormalised and scaled by 2.5, one shared expert of twice the width; lm_layers at most 52; needs lm_parallelism=ep) | evabyte (byte-level; zero-centred RMSNorm eps 1e-5, RoPE theta 1e5, SwiGLU FFN; every layer an EVA layer: exact causal attention inside a window of 2048 that is a block of the diagonal, ONE softmax shared with the 16-token chunk summaries of every earlier window (a softmax-weighted pooling under two learned vectors a head), by the fused kernels of ops/eva_attention.py under lm_attention=flash; 8 prediction heads on one trunk, head i predicting token t + 1 + i, float32 logits; lm_seq_len a multiple of 16; needs lm_parallelism=sp on ONE device) — models/transformer.py ARCHS
+    lm_arch: str = "gpt2"            # gpt2 (LayerNorm, learned positions, GELU 4d FFN; MoE: capacity top-1/2) | olmoe (RMSNorm, RoPE, q/k norm, dropless top-k SwiGLU experts, z-loss; needs lm_parallelism=ep) | smallthinker (RMSNorm; three window-4096 RoPE layers to one full-causal layer without position encoding; dropless top-k ReLU-gated experts, gates renormalised, router before attention; needs lm_parallelism=ep) | trinity (RMSNorm on each sublayer's input and output; three window-2048 RoPE layers to one full-causal layer without position encoding; q/k norm a head; gated attention output; embedding times sqrt(d); lm_dense_layers dense SwiGLU layers, then dropless sigmoid-scored top-k SwiGLU experts chosen under a bias the step moves against the load, gates renormalised and scaled, one shared expert; needs lm_parallelism=ep) | phi4flash (a decoder-hybrid-decoder; LayerNorm eps 1e-5, no position encoding, SwiGLU FFN, head tied to the embedding; layer kinds by index and depth, lm_layers a multiple of 4: Mamba-1 state-space layers and window-512 differential-attention layers alternate in the first half, then one Mamba layer whose scan output and one full differential-attention layer whose K/V every later layer reads, then gated memory units and cross-attention layers alternate; needs lm_parallelism=sp on ONE device) | qwen3next (zero-centred RMSNorm; three Gated DeltaNet linear-attention layers (16 key / 32 value heads of 128, a 4-tap convolution, the chunked gated delta rule) to one softmax-attention layer with q/k norm a head, a gated output and RoPE on a quarter of the head; dropless softmax-scored top-k SwiGLU experts, gates renormalised, one shared expert under a sigmoid gate; needs lm_parallelism=ep) | nemotronh (RMSNorm eps 1e-5, no position encoding; every layer ONE pre-norm residual sublayer by the letter of the published 52-letter pattern MEMEM*E...: a Mamba-2 mixer (64 heads of 64 with a [64, 128] state, B and C in 8 groups, a biased 4-tap convolution, the chunked state-space-dual kernel, the gate before a norm in 8 groups), an attention mixer, or an expert layer alone: dropless sigmoid-scored top-k experts down(relu(up x)^2) without a gate projection, chosen under a bias the step moves against the load, gates renormalised and scaled by 2.5, one shared expert of twice the width; lm_layers at most 52; needs lm_parallelism=ep) | evabyte (byte-level; zero-centred RMSNorm eps 1e-5, RoPE theta 1e5, SwiGLU FFN; every layer an EVA layer: exact causal attention inside a window of 2048 that is a block of the diagonal, ONE softmax shared with the 16-token chunk summaries of every earlier window (a softmax-weighted pooling under two learned vectors a head), by the fused kernels of ops/eva_attention.py under lm_attention=flash; 8 prediction heads on one trunk, head i predicting token t + 1 + i, float32 logits; lm_seq_len a multiple of 16; needs lm_parallelism=sp on ONE device) | granite4h (RMSNorm eps 1e-5, no position encoding, head tied to the embedding; every layer a mixer AND an expert half: layer i attends where i % 10 == 5 (grouped-query heads, scores times 1/128) and is a Mamba-2 mixer otherwise (128 heads of 64 with a [64, 128] state, B, C and the gated norm in ONE group, a biased 4-tap convolution, chunks of 256); dropless top-k SwiGLU experts under a softmax over the chosen logits beside one shared SwiGLU expert of width 1536; the embedding times 12, each sublayer's output times 0.22, the logits over 16; lm_mixer_shares holds a share of the heads and of the shared expert; needs lm_parallelism=ep) — models/transformer.py ARCHS
     lm_kv_heads: int = 0             # key/value heads, each serving lm_heads / lm_kv_heads query heads (0 = lm_heads); sp on one device or ep, attention full | flash
     lm_head_dim: int = 0             # head size (0 = lm_d_model / lm_heads)
     lm_ffn_dim: int = 0              # FFN / expert width (0 = 4 * lm_d_model)
@@ -128,6 +128,7 @@ class TrainConfig:
     lm_microbatches: int = 4         # pp: GPipe microbatch count
     lm_experts: int = 8              # ep: expert count (divisible by device count)
     lm_moe_top_k: int = 1            # ep: experts per token. gpt2 arch (capacity routing): 1 = switch, 2 = GShard top-2; olmoe / smallthinker arch (dropless): 1..lm_experts
+    lm_mixer_shares: int = 1         # dropless archs: chips a layer's mixers and shared expert are divided over; this model holds share 0 of that many of every mixer's heads (a Mamba-2 layer's heads with B and C whole, the query heads with their key/value heads) and of the shared expert's channels, column-parallel in and row-parallel out, and each sublayer computes its own part of the result (one chip of a tensor-parallel deployment, without the exchange); lm_heads and lm_kv_heads stay the model's counts
     lm_experts_held: int = 0         # dropless archs: experts this model holds of each layer's lm_experts, the first block of that many (0 = all); the router stays lm_experts wide and the layer computes its own experts' part of the result (one chip of an expert-parallel deployment, without the exchange)
 
     # -- fault injection (tests / straggler drills; SURVEY §5.3: the
@@ -255,6 +256,19 @@ class TrainConfig:
                 raise ValueError(f"lm_experts_held={self.lm_experts_held} "
                                  f"(must divide lm_experts="
                                  f"{self.lm_experts})")
+        if self.lm_mixer_shares != 1:
+            kv = self.lm_kv_heads or self.lm_heads
+            if self.lm_arch not in _DROPLESS_ARCHS:
+                raise ValueError(
+                    f"lm_mixer_shares={self.lm_mixer_shares} needs a "
+                    f"dropless arch ({' | '.join(_DROPLESS_ARCHS)}): only "
+                    f"models/moe.MoEBlock holds a share of its mixers")
+            if self.lm_mixer_shares < 1 or self.lm_heads \
+                    % self.lm_mixer_shares or kv % self.lm_mixer_shares:
+                raise ValueError(
+                    f"lm_mixer_shares={self.lm_mixer_shares} (must divide "
+                    f"lm_heads={self.lm_heads} and the {kv} key/value heads: "
+                    f"a share holds whole heads)")
         if self.lm_ffn_dim < 0:
             raise ValueError(f"lm_ffn_dim={self.lm_ffn_dim} (must be >= 0; "
                              "0 = 4 * lm_d_model)")

@@ -78,6 +78,9 @@ MOE_STATE = "moe_state"
 EXPERT_COUNTS = "expert_counts"
 # ... from which the ep step derives these two beside DROPLESS_STATS.
 BIAS_STATS = ("moe_bias_abs_max", "moe_load_all_max_over_mean")
+# An arch with ``load_all_stat`` and no bias has its expert layers report the
+# second themselves (``DroplessMoE.load_all_stat``), the worst layer's.
+LOAD_ALL_STAT = BIAS_STATS[1]
 
 
 def lm_variables(params, moe_state=None):
@@ -437,7 +440,10 @@ class DroplessMoE(nn.Module):
     not added (counted from the combine's own indices; 0 by construction),
     ``moe_tail_rows_share`` = rows of the main part that no held group owns /
     rows it is sized for: the share of the grouped matmuls' row tiles that are
-    visited and not multiplied (0 where every expert is held).
+    visited and not multiplied (0 where every expert is held); with
+    ``load_all_stat`` also ``moe_load_all_max_over_mean`` = the busiest of ALL
+    E outputs' assignments / (T*k/E): it tells a router that drifts towards
+    the held experts from one that is unbalanced everywhere.
     """
     n_experts: int
     d_model: int
@@ -453,6 +459,7 @@ class DroplessMoE(nn.Module):
     select_bias: bool = False         # top-k of score + expert_bias (MOE_STATE)
     route_scale: float = 1.0
     gated: bool = True                # False: act(h Wup_i) Wdown_i, no gate projection
+    load_all_stat: bool = False       # stats also hold LOAD_ALL_STAT: the busiest of ALL E outputs over the mean
 
     @nn.compact
     def __call__(self, x, router_x=None):
@@ -594,6 +601,9 @@ class DroplessMoE(nn.Module):
                 "moe_tail_rows_share": 1.0 - jnp.minimum(
                     n_held_rows, rows).astype(jnp.float32) / rows,
             }
+            if self.load_all_stat:
+                stats[LOAD_ALL_STAT] = \
+                    jnp.max(load).astype(jnp.float32) * e / (t * k)
         if self.select_bias:
             stats[EXPERT_COUNTS] = {"expert_bias": load}
         with device_scope("moe_dispatch"):
@@ -611,7 +621,22 @@ class MoEBlock(nn.Module):
     (``COUNTER_NAMES``) a dropless layer's ``aux`` carries to the model. Under
     an arch with a ``layer_pattern`` the block is ONE of the halves: a mixer
     alone (attention or Mamba-2), whose ``aux`` is what it counted ({} for
-    attention), or the expert layer alone ("experts")."""
+    attention), or the expert layer alone ("experts").
+
+    **A share of the mixers** (``mixer_shares`` > 1; tensor parallelism's
+    block, here without its exchange): the block holds share 0 of that many
+    of its mixer's heads (a Mamba-2 layer's ``ssm_heads / mixer_shares`` with
+    B and C whole, ``n_heads / mixer_shares`` query heads on ``kv_heads /
+    mixer_shares`` key/value heads) and of the shared expert's channels,
+    column-parallel in and row-parallel out, so each half's result is this
+    chip's PART of the sublayer's output, as the held experts' is. With
+    ``mixer_axis`` bound (inside a ``shard_map`` over that mesh axis, every
+    device with its own share's parameters) the mixer's and the shared
+    expert's parts, and a Mamba-2 layer's norm statistic, are summed over it;
+    with none nothing is, no collective runs, and what the absent chips would
+    add is left out. (The routed experts' part stays this block's own either
+    way: their exchange is not built.) ``n_heads`` and ``kv_heads`` stay the
+    model's counts."""
     n_heads: int
     d_model: int
     n_experts: int
@@ -641,6 +666,16 @@ class MoEBlock(nn.Module):
     experts_held: int = 0             # dropless: experts held here (0 = all)
     experts_share: int = 0            # ... which block of them, 0-based
     dense_ffn_dim: int = 0            # > 0: no experts here, a GatedFFN of this width
+    mixer_shares: int = 1             # chips a layer's mixers and shared expert are divided over
+    mixer_axis: Optional[str] = None  # the mesh axis those shares lie along, where one is bound
+
+    def _held(self, count: int, what: str) -> int:
+        """This block's share of ``count`` heads or channels."""
+        if count % self.mixer_shares:
+            raise ValueError(
+                f"mixer_shares={self.mixer_shares} does not divide the "
+                f"{count} {what} of lm_arch={self.arch}")
+        return count // self.mixer_shares
 
     @nn.compact
     def __call__(self, x, positions=None):
@@ -648,6 +683,12 @@ class MoEBlock(nn.Module):
         a = ARCHS[self.arch]
         counted, normed = {}, None
         kind = a.layer_kind(self.layer)
+        if self.mixer_shares > 1 and (kind == "gdn" or a.ssm_groups > 1):
+            raise NotImplementedError(
+                f"--lm-arch {self.arch}: a share of the mixers is built for "
+                f"attention and for Mamba-2 layers whose B and C are ONE "
+                f"group (a share of linear-attention heads, or of the heads "
+                f"of {a.ssm_groups} groups, is not)")
         if self.decode and kind in ("gdn", "mamba2"):
             refuse_hybrid(self.arch, "decode")
         if kind == "gdn":
@@ -659,16 +700,22 @@ class MoEBlock(nn.Module):
         elif kind == "mamba2":
             x, normed, counted = mamba2_sublayer(
                 self, x, make_norm(self.arch, self.dtype), dtype=self.dtype,
-                heads=a.ssm_heads, head_dim=a.ssm_head_dim,
+                heads=self._held(a.ssm_heads, "Mamba-2 heads"),
+                head_dim=a.ssm_head_dim,
                 groups=a.ssm_groups, d_state=a.ssm_state, d_conv=a.ssm_conv,
-                chunk=a.ssm_chunk, norm_eps=a.norm_eps)
+                chunk=a.ssm_chunk, norm_eps=a.norm_eps,
+                out_scale=a.residual_scale, axis_name=self.mixer_axis)
         elif kind != "experts":
             x, normed, _ = attention_sublayer(
-                self, x, positions, arch=self.arch, n_heads=self.n_heads,
+                self, x, positions, arch=self.arch,
+                n_heads=self._held(self.n_heads, "query heads"),
                 dtype=self.dtype, attention_impl=self.attention_impl,
                 decode=self.decode, decode_cache_len=self.decode_cache_len,
-                layer=self.layer, kv_heads=self.kv_heads,
-                head_dim=self.head_dim)
+                layer=self.layer,
+                kv_heads=self._held(self.kv_heads or self.n_heads,
+                                    "key/value heads"),
+                head_dim=self.head_dim or d // self.n_heads,
+                mixer_axis=self.mixer_axis)
         if a.layer_pattern and kind != "experts":
             return x, counted           # a mixer alone
         # The block's second half is a dense layer's scope or an expert
@@ -693,16 +740,23 @@ class MoEBlock(nn.Module):
                                  score=a.router_score,
                                  select_bias=a.router_bias_rate > 0,
                                  route_scale=a.route_scale,
-                                 gated=a.expert_gated, name="moe")(
+                                 gated=a.expert_gated,
+                                 load_all_stat=a.load_all_stat
+                                 and not a.router_bias_rate, name="moe")(
                 y, normed if a.early_router else None)
             if EXPERT_COUNTS in aux:
                 aux[EXPERT_COUNTS] = {"moe": aux[EXPERT_COUNTS]}
-            if a.shared_experts:
-                # every token's, whatever share of the routed experts is held
+            shared_width = a.shared_width or a.shared_experts * width
+            if shared_width:
+                # every token's, whatever share of the routed experts is
+                # held; of its channels the block's share of the mixers
                 with device_scope("moe_shared"):
-                    shared = GatedFFN(a.shared_experts * width, self.dtype,
-                                      a.expert_act, a.expert_gated,
-                                      name="shared")(y)
+                    shared = GatedFFN(
+                        self._held(shared_width, "shared channels"),
+                        self.dtype, a.expert_act, a.expert_gated,
+                        name="shared")(y)
+                    if self.mixer_axis is not None:
+                        shared = jax.lax.psum(shared, self.mixer_axis)
                     if a.shared_gate:
                         shared = shared * nn.sigmoid(nn.Dense(
                             1, use_bias=False, dtype=self.dtype,
@@ -721,6 +775,8 @@ class MoEBlock(nn.Module):
             if a.post_norm:     # its backward reads its input (``remat_block``)
                 m = make_norm(self.arch, self.dtype, name="post_mlp_norm")(
                     kept(m, "mlp_out"))
+            if a.residual_scale != 1.0:
+                m = m * jnp.asarray(a.residual_scale, self.dtype)
             x = x + m
         if counted:
             # what the mixer counted rides in a dropless layer's statistics
@@ -749,7 +805,8 @@ class MoETransformerLM(nn.Module):
     router output, a tree of the ``MOE_STATE`` collection's shape. What the
     mixers count (``COUNTER_NAMES``: a linear-attention layer's
     ``gdn_state_abs_max``) is sown in ``LM_COUNTERS``, the largest over the
-    layers, as ``TransformerLM`` does."""
+    layers, as ``TransformerLM`` does. With the arch's ``tied_head`` the
+    logits are ``ln_f(x) . tok_embed^T``, over its ``logits_divisor``."""
     vocab_size: int = 256
     n_layers: int = 2
     n_heads: int = 4
@@ -770,6 +827,8 @@ class MoETransformerLM(nn.Module):
     experts_share: int = 0            # ... which block of them, 0-based
     dense_layers: int = 0             # leading blocks with a dense gated FFN
     dense_ffn_dim: int = 0            # ... of this width (0 = 4 * d_model)
+    mixer_shares: int = 1             # chips a layer's mixers and shared expert are divided over (this model holds share 0)
+    mixer_axis: Optional[str] = None  # the mesh axis those shares lie along, where one is bound (MoEBlock)
     # Per-block remat (see models/transformer.py TransformerLM.remat); the
     # recompute replays the block's all_to_alls, which is SPMD-legal.
     remat: bool = False
@@ -803,6 +862,8 @@ class MoETransformerLM(nn.Module):
                          experts_share=self.experts_share,
                          dense_ffn_dim=(self.dense_ffn_dim or 4 * self.d_model)
                          if i < self.dense_layers else 0,
+                         mixer_shares=self.mixer_shares,
+                         mixer_axis=self.mixer_axis,
                          name=f"block_{i}")(x, positions)
             if aux is not None:
                 per_layer.append((f"block_{i}", aux))
@@ -820,6 +881,9 @@ class MoETransformerLM(nn.Module):
                 if ARCHS[self.arch].router_bias_rate:
                     aux_total[EXPERT_COUNTS] = {name: a[EXPERT_COUNTS]
                                                 for name, a in routed}
+                elif ARCHS[self.arch].load_all_stat:
+                    aux_total[LOAD_ALL_STAT] = jnp.max(jnp.stack(
+                        [a[LOAD_ALL_STAT] for _, a in routed]))
                 # what the mixers counted rode in their layers' statistics
                 for k in COUNTER_NAMES:
                     vs = [a[k] for _, a in per_layer if k in a]
@@ -829,8 +893,18 @@ class MoETransformerLM(nn.Module):
                 aux_total = jnp.float32(0.0)
                 for _, aux in per_layer:
                     aux_total = aux_total + aux
+        a = ARCHS[self.arch]
         with device_scope("head"):
             x = make_norm(self.arch, self.dtype, name="ln_f")(x)
-            logits = nn.Dense(self.vocab_size, use_bias=False,
-                              dtype=self.dtype, name="lm_head")(x)
+            if a.tied_head:
+                # the embedding's rows are the head's columns: one parameter,
+                # which receives both gradients (as ``TransformerLM``'s)
+                table = self.variables["params"]["tok_embed"]["embedding"]
+                logits = jax.lax.dot_general(
+                    x, table.astype(self.dtype), (((2,), (1,)), ((), ())))
+            else:
+                logits = nn.Dense(self.vocab_size, use_bias=False,
+                                  dtype=self.dtype, name="lm_head")(x)
+            if a.logits_divisor != 1.0:
+                logits = logits / jnp.asarray(a.logits_divisor, self.dtype)
         return logits, aux_total

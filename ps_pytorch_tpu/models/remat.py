@@ -22,7 +22,8 @@ from ps_pytorch_tpu.ops.flash_attention import SAVED_NAMES
 # ``ops/eva_attention.py``).
 KEPT_NAMES = SAVED_NAMES + EVA_SAVED_NAMES + (
     "attn_q", "attn_k", "attn_v", "attn_q_unnormed", "attn_k_unnormed",
-    "attn_gate", "attn_out", "ssm_z", "ssm_xbc", "ssm_dt", "moe_gates",
+    "attn_gate", "attn_out", "ssm_z", "ssm_xbc", "ssm_dt", "ssm_out",
+    "moe_gates",
     "moe_idx", "moe_order", "moe_inv", "moe_load", "mlp_out")
 
 
@@ -69,6 +70,11 @@ def remat_block(block_cls):
       is one sublayer, and its recomputed forward was this matmul for the
       kernels' backward (the three slices and not the array: the slices are
       what a Pallas consumer makes XLA write out);
+    - ``ssm_out``: a Mamba-2 layer's ``out_proj`` result, 2 d, as
+      ``attn_out``: what a block that is a mixer AND an expert half needs of
+      the first half in its second (the one matmul the recomputed first half
+      would still hold for it); a block that is the mixer alone reads it
+      nowhere in its backward and keeps nothing for it;
     - ``moe_gates``, ``moe_idx`` (inside ``_top_k``'s forward rule),
       ``moe_order``, ``moe_inv``: 16 k in all, and ``moe_load``, 4 E a layer:
       spares ``top_k``, both sorts and the [T k, E] count; the router's

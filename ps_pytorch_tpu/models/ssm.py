@@ -39,6 +39,18 @@ Mamba-2 (arXiv:2405.21060; ``d_inner = heads * head_dim`` whatever ``d`` is,
     y      = GroupRMSNorm(y * silu(z))          the gate FIRST, then the norm over each of G groups of d_inner / G; one scale [d_inner]    } gated_group_norm
     out    = out_proj(y)                        d_inner -> d, no bias
 
+**A share of the heads** (``mamba2_sublayer``'s ``heads`` is the count HELD;
+tensor parallelism's layer): column-parallel in, row-parallel out. ``in_proj``
+holds the held heads' columns of z, x and dt and B and C WHOLE (a group's B
+and C serve heads on every chip that holds some of the group), the
+convolution their channels, ``out_proj`` the held heads' rows, so its result is
+this chip's PART of the sublayer's output. The gated norm's statistic spans a
+group's heads wherever they lie: with a mesh axis bound (``axis_name``) the
+sum of squares and the count are summed over it (a scalar a token and group)
+and so is the partial output; with none both are this chip's own, the norm is
+over the held channels alone, no collective runs and none is emulated, and
+what the absent heads would add is left out.
+
 The two bracketed chains are one op each, ``ops/ssm_mix.py`` (Pallas, since
 PR 45; the convolution's kernels are ``ops/gdn_mix.py``'s): each makes one
 pass over HBM forward and one backward and keeps its inputs alone for the
@@ -58,6 +70,8 @@ once; ``y`` leaves ``ssd`` in it.
 """
 
 import math
+from functools import partial
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -161,12 +175,32 @@ class _NormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (features,))
 
 
+def _gated_group_norm_over(y, z, scale, *, groups: int, eps: float,
+                           axis_name: str):
+    """``ops/ssm_mix.gated_group_norm`` with each group's mean square taken
+    over ``axis_name`` too: the sum of squares and the count of the channels
+    held here, each summed over the axis. Plain ``jax.numpy`` in float32:
+    the form that runs where shares of a layer's heads meet, which one chip
+    never does."""
+    shape = y.shape
+    g = (y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))).reshape(
+        *shape[:-1], groups, -1)
+    squares = jax.lax.psum(jnp.sum(g * g, axis=-1, keepdims=True), axis_name)
+    count = jax.lax.psum(g.shape[-1], axis_name)
+    g = g * jax.lax.rsqrt(squares / count + eps)
+    return (g.reshape(shape) * scale).astype(z.dtype)
+
+
 def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
                     head_dim: int, groups: int, d_state: int, d_conv: int,
-                    chunk: int, norm_eps: float):
-    """``x + Mamba2(norm(x))``, ``norm(x)`` and ``{"ssd_state_abs_max":
-    ...}``: the largest |state| at the chunk boundaries. ``mod``: the block,
-    whose scope holds the parameters."""
+                    chunk: int, norm_eps: float, out_scale: float = 1.0,
+                    axis_name: Optional[str] = None):
+    """``x + out_scale * Mamba2(norm(x))``, ``norm(x)`` and
+    ``{"ssd_state_abs_max": ...}``: the largest |state| at the chunk
+    boundaries. ``mod``: the block, whose scope holds the parameters.
+    ``heads``: the heads held here, with B and C of all ``groups`` whole;
+    ``axis_name``: the mesh axis the shares of a layer's heads lie along,
+    where one is bound (the module's docstring)."""
     # where the arch asks for it: the other archs' start-up does not pay for it
     from ps_pytorch_tpu.ops.ssd import ssd
     from ps_pytorch_tpu.ops.ssm_mix import conv_bias_silu, gated_group_norm
@@ -198,11 +232,19 @@ def mamba2_sublayer(mod: nn.Module, x, norm: nn.Module, *, dtype, heads: int,
                            c.reshape(bt, s, groups, d_state), skip,
                            chunk=chunk)
     with device_scope("ssm_conv"):
-        g = gated_group_norm(y.reshape(bt, s, d_inner), z,
-                             _NormScale(name="ssm_norm")(d_inner),
-                             groups=groups, eps=norm_eps)
+        mix_norm = gated_group_norm if axis_name is None else partial(
+            _gated_group_norm_over, axis_name=axis_name)
+        g = mix_norm(y.reshape(bt, s, d_inner), z,
+                     _NormScale(name="ssm_norm")(d_inner),
+                     groups=groups, eps=norm_eps)
     with device_scope("ssm_proj"):
-        x = x + dense(d, "out_proj")(g)
+        # what a block's second half starts from, bar a scale and a sum
+        out = kept(dense(d, "out_proj")(g), "ssm_out")
+        if axis_name is not None:       # the shares' parts of the output
+            out = jax.lax.psum(out, axis_name)
+        if out_scale != 1.0:
+            out = out * jnp.asarray(out_scale, dtype)
+        x = x + out
     return x, a, {"ssd_state_abs_max": state_max}
 
 

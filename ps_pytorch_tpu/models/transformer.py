@@ -44,11 +44,15 @@ class Arch(NamedTuple):
     dropless: bool = False      # MoE FFN: routed SwiGLU, sort + grouped matmul | capacity GELU
     embed_std: float = 0.0      # token embedding init: normal(std) | flax's default (1/sqrt(d))
     embed_scale: bool = False   # the embedded token times sqrt(d)
+    embed_multiplier: float = 0.0   # > 0: the embedded token times this (embedding_multiplier)
+    attn_scale: float = 0.0     # > 0: the scores' scale (attention_multiplier) | a head's features ** -0.5
+    residual_scale: float = 1.0     # each sublayer's output times this before the residual sum (residual_multiplier)
+    logits_divisor: float = 1.0     # the head's logits over this (logits_scaling)
     z_loss_coef: float = 0.0    # router z-loss in the ep step's loss
     aux_coef: float = 0.01      # load-balance loss in the ep step's loss
     # Layers of several kinds: layer l is of kind l % len(pattern), for the
     # window, the position encoding and the token mixer alike.
-    mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn" | "eva"; (): attention in every layer
+    mixer_layers: Tuple[str, ...] = ()  # per layer of the period: the mixer, "attention" | "gdn" | "eva" | "mamba2"; (): attention in every layer
     layer_pattern: str = ""     # no period but a letter a layer, each layer ONE pre-norm residual sublayer (``PATTERN_KINDS``): "M" a Mamba-2 mixer | "*" an attention mixer | "E" an expert layer; the depth is at most its length
     window: int = 0             # keys a window layer's query sees, itself included
     window_layers: Tuple[int, ...] = ()   # per layer of the period: 1 = window | 0 = every key before (): no window
@@ -64,7 +68,9 @@ class Arch(NamedTuple):
     #                                 are the scores), and the ep step moves it by this much a step against the load
     route_scale: float = 1.0        # the gates times this
     shared_experts: int = 0         # experts of the routed ones' width that every token passes, beside the routed part
+    shared_width: int = 0           # > 0: ONE shared expert of this width, whatever the routed ones' is (shared_intermediate_size)
     shared_gate: bool = False       # the shared experts' output times sigmoid(y w_s), w_s: d -> 1
+    load_all_stat: bool = False     # the expert layers report the busiest of ALL the router's outputs too (an arch that chooses under a bias reports it from the bias's counts)
     # A Gated DeltaNet (linear-attention) layer's sizes, the arch's as Mamba's are.
     gdn_key_heads: int = 0      # key (and query) heads
     gdn_value_heads: int = 0    # value heads, a multiple: value heads r j .. r j + r - 1 read key head j
@@ -312,6 +318,40 @@ ARCHS = {
                     rope_theta=1e5, gated_ffn=True, embed_std=0.01275,
                     mixer_layers=("eva",), eva_window=2048, eva_chunk=16,
                     eva_std=0.01275, pred_heads=8, f32_logits=True),
+    # Granite-4.0-H-Small (ibm-granite/granite-4.0-h-small config.json,
+    # model_type granitemoehybrid; Mamba-2, arXiv:2405.21060): 40 layers, each
+    # a mixer AND an expert half; layer i attends where i % 10 == 5 and is a
+    # Mamba-2 mixer otherwise (layer_types: 36 to 4): 128 heads of 64 with a
+    # [64, 128] state, B, C and the gated norm in ONE group over all heads, a
+    # biased 4-tap convolution, chunks of 256; attention without position
+    # encoding (position_embedding_type nope: rope_theta is carried and not
+    # read) at the scores' scale attention_multiplier 1/128, not 128 ** -0.5;
+    # 72 SwiGLU experts, the top 10 logits under a softmax of their own (the
+    # softmax over all 72 renormalised over the ten: gate_norm) beside ONE
+    # shared SwiGLU expert of shared_intermediate_size 1536; rms_norm_eps
+    # 1e-5; the head tied to the embedding; four scalars: the embedding times
+    # 12, each sublayer's output times 0.22 before the residual sum, the
+    # logits over 16, the scores' scale. aux_coef: the family's default
+    # router_aux_loss_coef. embed_std: the embedding is the head too, so its
+    # scale is the logits' (phi4flash's reason) and, times 12, the share of
+    # the stream that is the token itself: at 0.05 that part has RMS 0.6
+    # beside twenty sublayer outputs of 0.22 each, and a token's own logit is
+    # about 6 (benchmark/configs/granite_4_0_h_small.json assumed.embed_std).
+    # expert_down_std 0.02 / sqrt(2 x 40 layers), smallthinker's rule at this
+    # depth, for its reason. The Mamba-2 layers' initialisers are
+    # models/ssm.py's. How many of the heads and of the shared expert's
+    # channels a model holds is its ``mixer_shares`` (--lm-mixer-shares).
+    "granite4h": Arch(rms_norm=True, norm_eps=1e-5, no_positions=True,
+                      dropless=True, embed_std=0.05, tied_head=True,
+                      aux_coef=0.001, gate_norm=True,
+                      expert_down_std=0.00224, shared_width=1536,
+                      load_all_stat=True, embed_multiplier=12.0,
+                      attn_scale=0.0078125, residual_scale=0.22,
+                      logits_divisor=16.0,
+                      mixer_layers=("mamba2",) * 5 + ("attention",)
+                      + ("mamba2",) * 4,
+                      ssm_state=128, ssm_conv=4, ssm_heads=128,
+                      ssm_head_dim=64, ssm_groups=1, ssm_chunk=256),
 }
 
 
@@ -409,7 +449,8 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
                        axis_name: str = "data", decode: bool = False,
                        decode_cache_len: int = 0, layer: int = 0,
                        kv_heads: int = 0, head_dim: int = 0,
-                       n_layers: int = 0, shared_kv=None):
+                       n_layers: int = 0, shared_kv=None,
+                       mixer_axis: Optional[str] = None):
     """``x + Wo . attention(norm(x))``, ``norm(x)`` (an early router's input)
     and what the layer can hand on or count (``k``, ``v`` in heads, as the
     attention calls take them; ``diff_lambda_max``); with the arch's
@@ -425,6 +466,14 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     key/value heads of ``head_dim`` (0 = ``d / n_heads``) serve ``n_heads /
     kv_heads`` query heads each. ``shared_kv``: another layer's ``(k, v)``;
     the layer then has a query and an output projection only (``Dense_0..1``).
+    The scores' scale is the arch's ``attn_scale`` where it gives one (``hd **
+    -0.5`` otherwise), on every path alike; ``Wo``'s result is multiplied by
+    the arch's ``residual_scale`` before the sum. A model that holds a share
+    of the heads (``n_heads`` and ``kv_heads`` the counts HELD, q/k/v their
+    columns and ``Wo`` their rows) gives its PART of the sublayer's output;
+    with ``mixer_axis`` bound the parts are summed over that mesh axis, with
+    none the part is this chip's own and what the absent heads would add is
+    left out.
 
     With the arch's ``diff_attn`` heads pair up as (2j, 2j+1): query pair j
     reads key pair ``j // group`` and, as its one value, that pair's two
@@ -495,6 +544,12 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
             k, v = kept(k, "attn_k"), kept(v, "attn_v")
     out = {"k": k, "v": v}
 
+    scale = a.attn_scale or None    # None: a head's features ** -0.5
+    if scale is not None and (decode or attention_impl == "ring"):
+        raise ValueError(
+            f"lm_arch={arch}: the decode cache and ring attention score at a "
+            f"head's features ** -0.5, not at the arch's attn_scale={scale}")
+
     def attend(q, k, v):
         if decode:
             return cached_attention(mod, q, k, v, decode_cache_len,
@@ -505,8 +560,10 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
         if attention_impl == "flash":
             # Fused blockwise kernel (ops/flash_attention.py): no [S, S]
             # materialization — the single-chip long-context path.
-            return flash_attention(q, k, v, causal=True, window=window)
-        return full_attention(q, k, v, causal=True, window=window)
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   scale=scale)
+        return full_attention(q, k, v, causal=True, window=window,
+                              scale=scale)
 
     eva = a.layer_kind(layer, n_layers) == "eva"
     if (eva or a.diff_attn) and (decode or attention_impl == "ring"):
@@ -543,8 +600,12 @@ def attention_sublayer(mod: nn.Module, x, positions, *, arch: str,
     with device_scope("attn_proj"):
         # what the block's second half starts from, bar a norm and a sum
         o = kept(nn.Dense(d, use_bias=False, dtype=dtype)(o), "attn_out")
+        if mixer_axis is not None:      # the shares' parts of the output
+            o = jax.lax.psum(o, mixer_axis)
         if a.post_norm:
             o = make_norm(arch, dtype, name="post_attn_norm")(o)
+        if a.residual_scale != 1.0:
+            o = o * jnp.asarray(a.residual_scale, dtype)
         x = x + o
     return x, y, out
 
@@ -615,6 +676,25 @@ _STATE_LACKS = {
         "ring attention": "a state-space-dual walk whose state crosses "
                           "sequence shards",
     },
+    # Mamba-2 mixers by the period (``mixer_layers``), each block a mixer AND
+    # an expert half, of which a model may hold a share (``mixer_shares``)
+    "mamba2_mixers": {
+        "trains under": "ep",
+        **dict.fromkeys(("generate.py", "serve.py", "decode"), _NO_SLOT.format(
+            "each Mamba-2 layer's head-wise state and its convolution's "
+            "last inputs")),
+        "tensor parallelism": "the exchange across the model axis: a share's "
+                              "partial outputs and its gated norm's sum of "
+                              "squares are reduced over a NAMED axis inside "
+                              "the block (mixer_axis), which parallel/tp.py's "
+                              "GSPMD layout neither binds nor places the "
+                              "shares' parameters for",
+        "pipeline parallelism": "stages built from blocks of more than one "
+                                "kind of mixer, and a head tied to the first "
+                                "stage's embedding",
+        "ring attention": "a state-space-dual walk whose state crosses "
+                          "sequence shards",
+    },
     # EVA ("eva") layers: a window of keys plus a growing list of summaries
     "eva": {
         "trains under": "sp on one device",
@@ -641,6 +721,8 @@ def _state_kind(a: Arch) -> Optional[str]:
     for kind in ("gdn", "eva"):
         if kind in a.mixer_layers:
             return kind
+    if "mamba2" in a.mixer_layers:
+        return "mamba2_mixers"
     return "mamba2" if "M" in a.layer_pattern else None
 
 
@@ -685,6 +767,8 @@ def embed_tokens(tokens, positions, *, arch: str, vocab_size: int,
                       **init)(tokens)
         if a.embed_scale:
             x = x * jnp.asarray(d_model ** 0.5, dtype)
+        if a.embed_multiplier:
+            x = x * jnp.asarray(a.embed_multiplier, dtype)
         if not a.rope_theta and not a.no_positions:
             x = x + EmbedRows(max_seq_len, d_model, dtype=dtype,
                               name="pos_embed")(positions)[None]

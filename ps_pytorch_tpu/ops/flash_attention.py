@@ -82,7 +82,7 @@ Design notes
 - The value has a width of its own, ``dv = v.shape[-1]``, read from the
   operand: the forward's accumulator, output block and ``P.V`` product, the
   backward's dO, ``delta``, ``dO.V^T`` (which contracts over it) and dV are
-  ``dv`` wide, the scores, dQ and dK ``d`` wide, the scale ``d ** -0.5``. One
+  ``dv`` wide, the scores, dQ and dK ``d`` wide, the default scale ``d ** -0.5``. One
   algorithm with one more size, no second path: where ``dv == d`` (every
   caller but a differential layer, whose one value is a pair's two value heads
   side by side, ``models/transformer.py:attention_sublayer``) the schedule
@@ -887,7 +887,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Fused attention over q [B, H, S, D], k [B, Hkv, S, D] and v [B, Hkv,
     S, Dv], H a multiple of Hkv (query head h reads key/value head ``h // (H
-    / Hkv)``); the output is [B, H, S, Dv], the scale ``D ** -0.5``. Drop-in
+    / Hkv)``); the output is [B, H, S, Dv]; the scores are ``q . k`` times
+    ``scale`` (``D ** -0.5`` where none is given: an arch with a multiplier
+    of its own, ``Arch.attn_scale``, passes it). Drop-in
     for ``ring.full_attention`` (same signature semantics, same output).
     ``window``: query i sees keys ``i - window < j <= i`` (needs ``causal``).
     The schedule comes from ``flash_schedule`` at the inputs' shape; the

@@ -47,7 +47,8 @@ _EXPERT_KEY = "experts_"   # models/moe.py stacked expert param names
 _STAT_REDUCE = {"aux": jax.lax.pmean, "z_loss": jax.lax.pmean,
                 "expert_load_max_over_mean": jax.lax.pmax,
                 "moe_dropped": jax.lax.psum, "moe_held_share": jax.lax.pmean,
-                "moe_tail_rows_share": jax.lax.pmean}
+                "moe_tail_rows_share": jax.lax.pmean,
+                "moe_load_all_max_over_mean": jax.lax.pmax}
 
 
 def ep_param_specs(params, axis: str = "data"):
@@ -123,6 +124,8 @@ def make_ep_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     router's outputs over the mean, worst layer). What the model counted on
     the way (``lm_counters``: an arch with linear-attention layers'
     'gdn_state_abs_max') comes with the loss too, as under ``parallel/sp.py``.
+    An arch with ``load_all_stat`` and no bias reports
+    'moe_load_all_max_over_mean' from its layers' own counts.
 
     tokens [B, S] int32, batch sharded over ``axis``. ``model`` must be
     built with ``ep_axis=axis`` and ``n_groups=1`` (each device dispatches

@@ -50,6 +50,7 @@ def build_lm_model(cfg, **kw):
         return MoETransformerLM(n_experts=cfg.lm_experts,
                                 top_k=cfg.lm_moe_top_k,
                                 experts_held=cfg.lm_experts_held,
+                                mixer_shares=cfg.lm_mixer_shares,
                                 dense_layers=cfg.lm_dense_layers,
                                 dense_ffn_dim=cfg.lm_dense_ffn_dim, **geo, **kw)
     from ps_pytorch_tpu.models.transformer import TransformerLM

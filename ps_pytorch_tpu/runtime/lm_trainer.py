@@ -14,9 +14,10 @@ held-out next-token-loss oracle):
 - ``ep``: an MoE model with experts sharded over 'data' (``models/moe.py``
   + ``parallel/ep.py``); also how an MoE model is run on one chip.
   ``--lm-arch`` picks capacity routing (gpt2) or dropless (olmoe,
-  smallthinker, trinity, qwen3next, nemotronh; ``--lm-experts-held`` trains one chip's
-  share of the experts, ``--lm-dense-layers`` starts the stack with dense
-  layers).
+  smallthinker, trinity, qwen3next, nemotronh, granite4h; ``--lm-experts-held`` trains one chip's
+  share of the experts, ``--lm-mixer-shares`` one chip's share of the mixers'
+  heads and of the shared expert, ``--lm-dense-layers`` starts the stack with
+  dense layers).
 
 The reference has no LM surface at all — this is the §5.7 long-context
 capability expressed as a first-class entry point (``train_lm.py``), not
@@ -221,6 +222,10 @@ class LMTrainer:
         arch = ARCHS[cfg.lm_arch]
         calls = cfg.lm_microbatches if self.mode == "pp" else 1
         rows = max(cfg.batch_size // (self.mesh.shape["data"] * calls), 1)
+        # the schedules are of the heads HELD (all of them but under ep with
+        # --lm-mixer-shares)
+        shares = getattr(self.model, "mixer_shares", 1)
+        self.mixer_held_share = (cfg.lm_heads // shares) / cfg.lm_heads
         if self.model.attention_impl == "flash":
             # the schedule is static per shape: the line is its record, one
             # for each kind of attention layer the arch mixes (window or
@@ -229,10 +234,11 @@ class LMTrainer:
             per_call = 2 if arch.diff_attn else 1
             hd = cfg.lm_head_dim or cfg.lm_d_model // cfg.lm_heads
             scheds = {flash_schedule(
-                rows * cfg.lm_heads // per_call, cfg.lm_seq_len, hd,
-                jnp.dtype(self.model.dtype).itemsize, True,
+                rows * cfg.lm_heads // (per_call * shares), cfg.lm_seq_len,
+                hd, jnp.dtype(self.model.dtype).itemsize, True,
                 window=arch.layer_window(i, cfg.lm_layers),
-                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads) // per_call,
+                bh_kv=rows * (cfg.lm_kv_heads or cfg.lm_heads)
+                // (per_call * shares),
                 dv=per_call * hd)
                 for i in range(cfg.lm_layers)
                 if arch.layer_kind(i, cfg.lm_layers) in ATTENTION_KINDS}
@@ -262,16 +268,17 @@ class LMTrainer:
                 arch.gdn_value_heads, arch.gdn_key_dim, arch.gdn_conv,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
-        if "M" in arch.layer_pattern:
+        if "M" in arch.layer_pattern or "mamba2" in arch.mixer_layers:
             from ps_pytorch_tpu.ops.ssd import ssd_schedule
+            heads = arch.ssm_heads // shares
             kernels.append("ssd[" + ssd_schedule(
-                rows, cfg.lm_seq_len, arch.ssm_heads, arch.ssm_head_dim,
+                rows, cfg.lm_seq_len, heads, arch.ssm_head_dim,
                 arch.ssm_state, arch.ssm_groups, chunk=arch.ssm_chunk,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
                 + "]")
             from ps_pytorch_tpu.ops.ssm_mix import ssm_mix_schedule
             kernels.append("ssm_mix[" + ssm_mix_schedule(
-                rows, cfg.lm_seq_len, arch.ssm_heads * arch.ssm_head_dim,
+                rows, cfg.lm_seq_len, heads * arch.ssm_head_dim,
                 arch.ssm_groups * arch.ssm_state, arch.ssm_groups,
                 arch.ssm_conv,
                 itemsize=jnp.dtype(self.model.dtype).itemsize).describe()
@@ -442,7 +449,7 @@ class LMTrainer:
                   "lm_heads", "lm_kv_heads", "lm_head_dim", "lm_ffn_dim",
                   "lm_dense_layers", "lm_dense_ffn_dim",
                   "lm_parallelism", "lm_experts", "lm_experts_held",
-                  "lm_model_axis", "lm_moe_top_k"):
+                  "lm_mixer_shares", "lm_model_axis", "lm_moe_top_k"):
             if k == "lm_model_axis" and saved.get(k) == 0:
                 continue
             if k in saved and saved[k] != getattr(self.cfg, k):
@@ -539,7 +546,8 @@ class LMTrainer:
             # The ep step's routing statistics (aux; a dropless arch's
             # z_loss, expert_load_max_over_mean, moe_dropped,
             # moe_held_share, moe_tail_rows_share; under a selection bias
-            # moe_bias_abs_max and moe_load_all_max_over_mean) and what the
+            # moe_bias_abs_max and moe_load_all_max_over_mean, the second also
+            # under an arch with load_all_stat) and what the
             # model counted (a
             # hybrid arch's ssm_state_abs_max and diff_lambda_max and an EVA
             # arch's eva_pool_weight_max and next_token_loss_head0 under sp,
@@ -547,6 +555,10 @@ class LMTrainer:
             # arch's ssd_state_abs_max under ep) come
             # with the loss.
             loss = own.pop("loss")
+            if self.mixer_held_share < 1:
+                # heads held over heads, from the model's own sizes: no
+                # device op, and no field where every head is held
+                own["mixer_held_share"] = self.mixer_held_share
             derived = derive_step_record(
                 step_time_s=step_time, data_time_s=data_time,
                 examples=cfg.batch_size,

@@ -498,7 +498,11 @@ ROUTING_GAUGES = (
      "choice (archs that choose under a bias)"),
     ("moe_load_all_max_over_mean", "", "busiest of ALL router outputs' "
      "assignments over tokens*top_k/experts, worst layer: what the bias acts "
-     "on (archs that choose under a bias)"),
+     "on (archs that choose under a bias, and granite4h from its layers' own "
+     "counts)"),
+    ("mixer_held_share", "", "heads of each mixer (and channels of the "
+     "shared expert) held here over the model's, 1 / lm_mixer_shares; set "
+     "only where a share is held"),
 )
 # What a hybrid LM counts inside its step (models/transformer.py
 # COUNTER_NAMES, parallel/sp.py), set by LMTrainer like the routing gauges.

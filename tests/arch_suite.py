@@ -40,7 +40,9 @@ import pytest
 
 from ps_pytorch_tpu.config import LM_ARCHS, TrainConfig
 from ps_pytorch_tpu.models import transformer as tr_mod
-from ps_pytorch_tpu.models.moe import BIAS_STATS, DROPLESS_STATS, MOE_STATE
+from ps_pytorch_tpu.models.moe import (
+    BIAS_STATS, DROPLESS_STATS, LOAD_ALL_STAT, MOE_STATE,
+)
 from ps_pytorch_tpu.models.transformer import ARCHS, refuse_hybrid
 from ps_pytorch_tpu.telemetry.trace import DEVICE_SCOPES
 
@@ -707,6 +709,8 @@ def install(namespace, case):
             want |= set(DROPLESS_STATS)
             if case.tiny_row.router_bias_rate:
                 want |= set(BIAS_STATS)
+            elif case.tiny_row.load_all_stat:   # the layers' own counts
+                want |= {LOAD_ALL_STAT}
             assert float(m["moe_dropped"]) == 0.0
             # rows the grouped matmuls visit and do not multiply: some under
             # a held share (its part is sized past what it draws), else none

@@ -464,10 +464,12 @@ def test_the_row_holds_the_published_switches():
 def test_the_state_tables_are_one_and_other_archs_pass():
     """One table of what a recurrent state lacks, keyed by the kind of state;
     an arch without one is refused nowhere."""
-    assert set(tr_mod._STATE_LACKS) == {"hybrid", "gdn", "mamba2", "eva"}
+    assert set(tr_mod._STATE_LACKS) == {"hybrid", "gdn", "mamba2",
+                                        "mamba2_mixers", "eva"}
     assert [tr_mod._state_kind(ARCHS[a]) for a in
-            ("phi4flash", "qwen3next", "nemotronh", "evabyte", "olmoe",
-             "gpt2")] == ["hybrid", "gdn", "mamba2", "eva", None, None]
+            ("phi4flash", "qwen3next", "nemotronh", "granite4h", "evabyte",
+             "olmoe", "gpt2")] == ["hybrid", "gdn", "mamba2", "mamba2_mixers",
+                                   "eva", None, None]
     for where in ("generate.py", "serve.py", "decode", "tensor parallelism",
                   "pipeline parallelism", "ring attention",
                   "expert parallelism"):

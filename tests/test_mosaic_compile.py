@@ -7,8 +7,11 @@ Mosaic for a described v5e (no chip): what the
 Pallas interpreter cannot show — VMEM over the limit, a
 slice off the tiling, a DMA the compiler refuses; and, under ``-m slow``, the
 long-context cells' whole train steps, for the bytes the compiler plans on the
-device. The kernel-shape compiles are tier-1's: no chip run says which kernel
-or which tile. The six whole-step compiles (one parametrised test over
+device; and the Granite-4.0-H-Small cell's kernels at ONE chip's share of
+every layer (4 query heads on one K/V head, 16 Mamba-2 heads in one group at
+chunks of 256, a gated norm one group 1,024 wide, 9 experts of 4096 x 768).
+The kernel-shape compiles are tier-1's: no chip run says which kernel
+or which tile. The seven whole-step compiles (one parametrised test over
 ``STEP_CELLS``; 260-350 s together at PR 46) are marked ``slow`` since PR 42: that the cell's step fits the chip is what the
 driver measures on a v5e in that very cell on every PR (``peak_hbm``; a step
 that does not fit fails the cell). Run them when a PR moves a step's plan.
@@ -221,7 +224,9 @@ class StepCell(NamedTuple):
 # has the history (PR 46 is the first since PR 34 to raise one: a
 # rematerialised block keeps more; PR 50 raises the hybrid's by 1.2 GiB
 # though its layers keep less: its first schedule now fits XLA's limit and
-# stands, where the parent's was made again under a tighter one).
+# stands, where the parent's was made again under a tighter one). The Granite
+# step is at S=8192, rule (b) of its configuration: at S=16384 it plans 16.71
+# GB, 4.06 GiB of it the overflow path's two float32 buffers (PR 51).
 STEP_CELLS = {
     "smallthinker_s16384_1chip": StepCell(
         "smallthinker_21b_a3b", "s16384_1chip", (64, 16), 559_290_880,
@@ -258,6 +263,14 @@ STEP_CELLS = {
         "evabyte_6_5b", "s16384_bytes_1chip", (8, 0), 821_366_784,
         {"eva_pool_fwd": 4, "eva_fwd": 4, "eva_bwd": 4, "eva_pool_bwd": 4},
         "flash_", 12_189_031_936),
+    "granite4h_small_tp8_1chip": StepCell(
+        "granite_4_0_h_small", "tp8_share_1chip", (72, 9), 1_055_938_224,
+        {"flash_fwd": 1, "flash_bwd_dkv": 1, "ssd_fwd": 18, "ssd_bwd": 9,
+         "moe_gmm_fwd": 120, "moe_gmm_dlhs": 60, "moe_gmm_drhs": 60,
+         "ssm_conv_fwd_x": 18, "ssm_conv_fwd_b": 18, "ssm_conv_fwd_c": 18,
+         "ssm_conv_bwd_x": 9, "ssm_conv_bwd_b": 9, "ssm_conv_bwd_c": 9,
+         "ssm_norm_fwd": 18, "ssm_norm_bwd": 9}, "flash_win_",
+        14_250_000_000),
 }
 HEAP_PACKING_BYTES = 2 ** 20
 
@@ -803,3 +816,139 @@ def test_tiles_keep_the_weight_buffers_inside_their_budget():
                 < VMEM_LIMIT_BYTES
     # without weights to hold (moe_gmm_drhs) the table's target stands
     assert _tiles(M, 8192, 4096, jnp.bfloat16) == (256, 2048, 2048)
+
+
+# granite4h_small_tp8_1chip (S=8192): ONE chip's eighth of every layer. The attention
+# layer's 4 query heads on 1 key/value head of 128 at the scores' own scale
+# 1/128 ...
+def test_flash_at_a_share_of_four_heads_on_one_kv_head_compiles(one_chip):
+    """A share's heads: the forward holds the one K/V head whole and walks
+    its 4 query heads, the backward puts them along its grid; the scale is
+    the caller's (1/128, not 128 ** -0.5), which changes no schedule."""
+    from ps_pytorch_tpu.ops.flash_attention import (
+        flash_attention, flash_schedule,
+    )
+    b, h, h_kv, s, d = 1, 4, 1, 8192, 128
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True, scale=1 / 128,
+                                       interpret=False).astype(jnp.float32))
+
+    def arg(heads):
+        return jax.ShapeDtypeStruct((b, heads, s, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(h), arg(h_kv), arg(h_kv)).compile().as_text()
+    for name in ("flash_fwd", "flash_bwd_dkv"):
+        assert f"{name}_" in text
+    assert f"bf16[{b * h_kv},{s},{d}]" in text
+    sched = flash_schedule(b * h, s, d, 2, True, bh_kv=b * h_kv)
+    assert (sched.group, sched.g, sched.window) == (4, 4, 0)
+
+
+# ... its nine Mamba-2 layers' 16 of 128 heads of 64 with a [64, 128] state, B
+# and C in ONE group, chunks of 256 ...
+GRANITE_SSD = dict(b=1, s=8192, heads=16, p=64, groups=1, n=128, chunk=256)
+
+
+def test_ssd_compiles_at_a_share_of_sixteen_heads_in_one_group(one_chip):
+    """``ssd_fwd`` and ``ssd_bwd`` at the share's shape and the published
+    chunk of 256: all 16 heads read the one group's B and C, two heads share
+    a slab's 128 lanes; 32 chunks of the cell's S=8192, each keeping 16
+    entering states of [64, 128] float32."""
+    from ps_pytorch_tpu.ops.ssd import ssd, ssd_schedule
+    c = GRANITE_SSD
+    b, s, heads, p, groups, n, chunk = (c[k] for k in (
+        "b", "s", "heads", "p", "groups", "n", "chunk"))
+
+    def loss(x, dt, a, bb, cc, d):
+        y, top = ssd(x, dt, a, bb, cc, d, chunk=chunk, interpret=False)
+        return jnp.sum(y.astype(jnp.float32)) + top
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        arg((b, s, heads, p)), arg((b, s, heads), f32), arg((heads,), f32),
+        arg((b, s, groups, n)), arg((b, s, groups, n)),
+        arg((heads,), f32)).compile()
+    sched = ssd_schedule(b, s, heads, p, n, groups, chunk=chunk)
+    print("GRANITE_SSD", sched.describe())
+    assert (sched.chunk, sched.chunks, sched.heads_a_slab) == (256, 32, 2)
+    assert sched.kept_bytes == 32 * heads * p * n * 4       # 16.8 MB a layer
+    ops = _entry_ops(compiled.as_text())
+    mosaic = [op for op in ops if op[2]]
+    assert ["ssd_fwd" in mosaic[0][0], "ssd_bwd" in mosaic[1][0],
+            len(mosaic)] == [True, True, 2]
+    assert [op[4] for op in mosaic] == [
+        sched.fwd_bytes + b * heads * p * n * 4, sched.bwd_bytes]
+
+
+def test_ssm_mix_compiles_at_a_share_in_one_group(one_chip):
+    """The eight calls of ``ops/ssm_mix.py`` at the share's shape: 1,280
+    channels of ``xBC`` (x 1,024, B and C 128 each: a lane tile a segment of
+    B or C), and the gated norm over ONE group 1,024 wide, eight lane tiles a
+    block (the Nemotron cell's groups are four)."""
+    from ps_pytorch_tpu.ops import ssm_mix
+    b, s, d_inner, bc, groups, taps = 1, 8192, 1024, 128, 1, 4
+    c = d_inner + 2 * bc
+
+    def both(xbc, w, bias, y, z, scale, dx, db, dc, dout):
+        out, pull = jax.vjp(
+            lambda *a: ssm_mix.conv_bias_silu(
+                *a, widths=(d_inner, bc, bc), interpret=False), xbc, w, bias)
+        normed, pull_norm = jax.vjp(
+            lambda *a: ssm_mix.gated_group_norm(
+                *a, groups=groups, eps=1e-5, interpret=False), y, z, scale)
+        return out + pull((dx, db, dc)) + (normed,) + pull_norm(dout)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = lambda width: arg((b, s, width))
+    text = jax.jit(both).lower(
+        rows(c), arg((taps, c), jnp.float32), arg((c,), jnp.float32),
+        rows(d_inner), rows(d_inner), arg((d_inner,), jnp.float32),
+        rows(d_inner), rows(bc), rows(bc), rows(d_inner)).compile().as_text()
+    ops = _entry_ops(text)
+    mosaic = {op[0].split(".")[0].split("jvp_")[-1].strip("_"): op
+              for op in ops if op[2]}
+    assert sorted(mosaic) == sorted(
+        [f"ssm_conv_{way}_{seg}" for way in ("fwd", "bwd") for seg in "xbc"]
+        + ["ssm_norm_fwd", "ssm_norm_bwd"])
+    sc = ssm_mix.ssm_mix_schedule(b, s, d_inner, bc, groups, taps, itemsize=2)
+    print("GRANITE_SSM_MIX", sc.describe())
+    assert (sc.lanes, sc.norm_lanes, sc.norm_grid[1]) == (128, 1024, 1)
+    assert mosaic["ssm_norm_fwd"][4] == sc.norm_fwd_bytes
+    assert mosaic["ssm_norm_bwd"][4] == sc.norm_bwd_bytes
+    for name, opcode, is_mosaic, shapes, _ in ops:
+        for dtype, dims in shapes:
+            assert not (dtype == "f32" and math.prod(
+                int(n) for n in dims.split(",") if n) >= s * 128), \
+                (name, opcode, dims)
+
+
+# ... and its ten expert halves: 15,360 rows sized for 10,240 at balance over
+# 9 held experts of 4096 x 768
+def test_expert_ffn_at_nine_held_experts_of_4096_by_768_compiles(
+        one_chip, monkeypatch):
+    """The gated grouped matmuls at the widest model dimension so far (4096;
+    2048 in the other held cells) and 1,138 rows a group at balance."""
+    monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+    m, d, f, e = 15360, 4096, 768, 9
+
+    def loss(xs, wg, wu, wd, gs):
+        h = jax.nn.silu(gmm(xs, wg, gs)) * gmm(xs, wu, gs)
+        return jnp.sum(gmm(h, wd, gs).astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        arg((m, d), jnp.bfloat16), arg((e, d, f), jnp.float32),
+        arg((e, d, f), jnp.float32), arg((e, f, d), jnp.float32),
+        arg((e,), jnp.int32)).compile().as_text()
+    for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
+        assert name in text
