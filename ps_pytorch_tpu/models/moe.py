@@ -60,6 +60,7 @@ from ps_pytorch_tpu.models.transformer import (
     ACTS, ARCHS, COUNTER_NAMES, LM_COUNTERS, GatedFFN, attention_sublayer,
     embed_tokens, make_norm, refuse_hybrid,
 )
+from ps_pytorch_tpu.ops import moe_rows
 from ps_pytorch_tpu.ops.grouped_matmul import gmm, gmm_t
 from ps_pytorch_tpu.telemetry.trace import device_scope
 
@@ -271,7 +272,7 @@ def _when(pred, fn, args, ints):
     ``cond`` differentiated by JAX keeps both branches' residuals, zeros for
     the one not taken: the full-size buffers this exists to avoid.) Both
     ``cond`` ops and their zeros (which XLA hoists out of the branch) stand
-    for a part's scatter-add and are under its device scope; the scopes ``fn``
+    for a part's combine and are under its device scope; the scopes ``fn``
     opens inside are the innermost: the one place where scopes nest."""
     @jax.custom_vjp
     def run(pred, args, ints):
@@ -304,8 +305,10 @@ def _when(pred, fn, args, ints):
 # only the caller knows. ``order`` [T*k] is the sorted assignments (an
 # assignment being token * k + choice) and ``inv`` [T, k] = ``argsort(order)``
 # the sorted place of each. (A held share's part covers fewer rows than there
-# are assignments, and a gather of T*k rows of which most are not there loses
-# to the scatter-add of the few that are: ``DroplessMoE.part`` keeps it.)
+# are assignments, and a gather of T*k rows of which most are not there lost
+# to the scatter-add of the few that are (PR 38); since PR 52 that part's
+# sums are ``ops/moe_rows.py``'s kernel, which follows the sort's runs, and
+# its takes XLA's gathers of the part's rows: ``DroplessMoE.part``.)
 
 @jax.custom_vjp
 def _rows_in(tokens, order, inv):
@@ -421,12 +424,19 @@ class DroplessMoE(nn.Module):
     router, its softmax, the top-k and the gates stay ``n_experts`` wide; an
     assignment to an expert not held sorts past the last held group, is not
     multiplied and adds nothing, so ``y`` is this block's part of the layer's
-    result (the shares' parts add up to the whole). Gather, grouped matmuls
+    result (the shares' parts add up to the whole). Take, grouped matmuls
     and combine run over the first ``HELD_ROWS_SLACK * T*k * n_held /
-    n_experts`` sorted rows, the combine a scatter-add of those rows to their
-    tokens (and the gather's gradient JAX's scatter-add: a quarter or an
-    eighth of the assignments are among the rows, and looking all T*k up
-    measured slower; PERF.md, Findings PR 38); where the block drew more, the
+    n_experts`` sorted rows. The combine and the take's transpose are ONE
+    Pallas kernel (``ops/moe_rows.py``, ``moe_rows_sum``: the sort is stable,
+    so a held expert's rows for a tile of tokens are one run, fetched by DMA
+    in segments and picked out and gated on the MXU in float32; where the
+    sort put each (token, held expert) is counted from the choices, no second
+    sort; the rows past the held groups' count are never fetched), with no
+    scatter of rows, no float32 ``[rows, d]`` product and no zeros in HBM.
+    The take and the cotangent's take are XLA's gathers over the part's rows
+    (Mosaic fetches no single row of a tiled array by its index: PERF.md,
+    Findings PR 52), and the gates' gradients go back to ``[T, k]`` by the
+    one scatter of scalars a part has left. Where the block drew more, the
     rows past them go through the same three steps under a ``cond``
     (``_when``): nothing held is dropped, whatever the router does.
 
@@ -526,20 +536,33 @@ class DroplessMoE(nn.Module):
                 (flat_e >= first) & (flat_e < first + held),
                 flat_e - first, held)
             order = kept(jnp.argsort(key, stable=True), "moe_order")
-            flat_gates = gates.reshape(-1)
             n_held_rows = jnp.sum(group_sizes)
 
-        def part(tokens, flat_gates, *rest, inv=None):
+        def part(tokens, gates, *rest, inv=None, add=None):
             """The rows ``order`` (sorted assignments), the first
-            ``sum(sizes)`` of which the groups cover: gathered, through the
-            experts, gated, and summed into their tokens: gathered back
-            through ``inv`` [T, k] where ``order`` holds every assignment and
-            ``inv`` their sorted places, else scatter-added. ``rest``: the
-            experts' weights (``weights`` below), then ``order``, ``sizes``."""
+            ``sum(sizes)`` of which the groups cover: taken, through the
+            experts, gated, and summed into their tokens. Where ``order``
+            holds every assignment and ``inv`` [T, k] their sorted places both
+            movements are gathers (``_rows_in``, ``_rows_out``); a held share's
+            part takes by XLA's gather and sums by ``ops/moe_rows.py``'s
+            kernel, forward and backward, over the rows before its count.
+            ``rest``: the experts' weights (``weights`` below), then ``order``,
+            ``sizes`` and, for a held share, ``local`` [T, k] (the choices as
+            held experts' numbers), ``has``, ``place``, ``lo``
+            (``moe_rows.held_places``) and ``span`` = (the part's first
+            sorted place, its count of held rows). Float32 [T, D]; with
+            ``add`` (the other part's) that added in float32 too and the sum
+            rounded once, to the tokens' dtype."""
+            if inv is None:
+                *rest, local, has, place, lo, span = rest
+                sched = rows_sched(rest[-2].shape[0])
+                with device_scope("moe_route"):
+                    plan = moe_rows.rows_plan(has, place, lo, start=span[0],
+                                              count=span[1])
             *w_in, w_down, order, sizes = rest
             with device_scope("moe_dispatch"):
-                xs = tokens[order // k] if inv is None \
-                    else _rows_in(tokens, order, inv)
+                xs = moe_rows.take(tokens, order, plan, k, sched) \
+                    if inv is None else _rows_in(tokens, order, inv)
                 xs = xs.astype(self.dtype)            # [rows, D]
             # The float32 expert weights go to the kernels as they are: a
             # tile is cast to the rows' dtype in VMEM, and the weight gradient
@@ -553,43 +576,53 @@ class DroplessMoE(nn.Module):
                 out = gmm(h, w_down, sizes)
             with device_scope("moe_dispatch"):
                 if inv is not None:
-                    return _rows_out(out, flat_gates, order, inv)
-                out = out.astype(jnp.float32) * flat_gates[order][:, None]
-                return jnp.zeros((t, d), jnp.float32).at[order // k].add(out)
+                    return _rows_out(out, gates.reshape(-1), order, inv)
+                return moe_rows.combine(
+                    out, gates, add, local, order, plan, sched,
+                    jnp.float32 if add is None else tokens.dtype)
 
         rows = held_rows(t * k, held, e)    # the main part's
         weights = (w_gate, w_up, w_down) if self.gated else (w_up, w_down)
+
+        def rows_sched(n):      # of a held share's part of n sorted rows
+            return moe_rows.rows_schedule(n, 0, t, k, d, self.dtype, held)
+
         if rows == t * k:
             with device_scope("moe_route"):       # the sort's inverse
                 inv = kept(jnp.argsort(order), "moe_inv").reshape(t, k)
-            y = part(tokens, flat_gates, *weights, order, group_sizes,
-                     inv=inv)
+            y = part(tokens, gates, *weights, order, group_sizes, inv=inv)
         else:
             with device_scope("moe_route"):
                 ends = jnp.minimum(jnp.cumsum(group_sizes), rows)
                 sizes_main = jnp.diff(ends, prepend=0)
                 main = order[:rows]
-            y = part(tokens, flat_gates, *weights, main, sizes_main)
-            with device_scope("moe_route"):
+                # where the sort put each (token, held expert), by counting
+                local = idx - first
+                has, _ = moe_rows.held_tables(local, gates, held)
+                place, lo = moe_rows.held_places(
+                    has, group_sizes, rows_sched(rows).tokens_tile)
+                routing = (local, has, place, lo)
+                in_main = jnp.minimum(n_held_rows, rows)
                 overflows = n_held_rows > rows
                 over, sizes_over = order[rows:], group_sizes - sizes_main
-            extra = _when(overflows, part, (tokens, flat_gates, *weights),
-                          (over, sizes_over))
-            with device_scope("moe_dispatch"):
-                y = y + extra
+                span_over = jnp.stack([jnp.full_like(in_main, rows),
+                                       n_held_rows - in_main])
+            extra = _when(overflows, part, (tokens, gates, *weights),
+                          (over, sizes_over, *routing, span_over))
+            y = part(tokens, gates, *weights, main, sizes_main, *routing,
+                     jnp.stack([jnp.zeros_like(in_main), in_main]), add=extra)
 
         # Counters, off the gradient path. An assignment's output is added
         # where the combine's own index for it falls on a row the grouped
         # matmul covered, the first sum(sizes) of a part: the sorted place the
-        # gather reads, or the token a covered row is scatter-added to (the
-        # overflow part's, run or not run as a whole, by their number).
+        # gather or the kernel reads (the overflow part's, run or not run as
+        # a whole, by their number).
         with device_scope("moe_route"):
             if rows == t * k:
                 added = jnp.sum(inv < n_held_rows, dtype=jnp.int32)
             else:
-                covered = jnp.arange(rows) < jnp.sum(sizes_main)
-                added = jnp.sum(covered & (main // k < t), dtype=jnp.int32) \
-                    + jnp.maximum(n_held_rows - rows, 0)
+                added = jnp.sum(has & (place < in_main), dtype=jnp.int32) \
+                    + n_held_rows - in_main
             stats = {
                 "aux": e * jnp.sum((load.astype(jnp.float32) / t)
                                    * jnp.mean(probs, axis=0)),

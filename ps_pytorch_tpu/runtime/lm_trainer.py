@@ -288,11 +288,17 @@ class LMTrainer:
             from ps_pytorch_tpu.ops.grouped_matmul import gmm_schedule
             held = cfg.lm_experts_held or cfg.lm_experts
             assignments = rows * cfg.lm_seq_len * cfg.lm_moe_top_k
+            sized = held_rows(assignments, held, cfg.lm_experts)
             kernels.append("grouped_matmul[" + gmm_schedule(
-                held_rows(assignments, held, cfg.lm_experts),
-                assignments * held // cfg.lm_experts, cfg.lm_d_model,
+                sized, assignments * held // cfg.lm_experts, cfg.lm_d_model,
                 cfg.lm_ffn_dim or 4 * cfg.lm_d_model, held,
                 jnp.dtype(self.model.dtype).itemsize).describe() + "]")
+            if sized < assignments:     # a held share: its rows' own kernel
+                from ps_pytorch_tpu.ops.moe_rows import rows_schedule
+                kernels.append("moe_rows[" + rows_schedule(
+                    sized, assignments * held // cfg.lm_experts,
+                    rows * cfg.lm_seq_len, cfg.lm_moe_top_k, cfg.lm_d_model,
+                    self.model.dtype, held).describe() + "]")
         # What the run really computes in is read from the built model, not
         # from the flag: the line here, the first JSONL record and the gauge.
         self.compute_dtype = jnp.dtype(self.model.dtype)

@@ -139,11 +139,18 @@ def scatters(step, *args):
     ``op_name`` (the scopes it sits under) of each that this backend's
     compiler leaves."""
     lowered = jax.jit(step).lower(*args)
-    compiled = [re.search(r'op_name="([^"]*)"', line).group(1)
-                for line in lowered.compile().as_text().splitlines()
-                if re.search(r" scatter\(", line)]
     return (len(re.findall(r"\bstablehlo\.scatter\b", lowered.as_text())),
-            compiled)
+            [name for name, _ in compiled_scatters(step, *args)])
+
+
+def compiled_scatters(step, *args):
+    """``(op_name, result shape)`` of each ``scatter`` op this backend's
+    compiler leaves in ``step(*args)``; a shape as HLO writes it,
+    ``f32[96]``."""
+    text = jax.jit(step).lower(*args).compile().as_text()
+    return [(re.search(r'op_name="([^"]*)"', line).group(1),
+             re.search(r"= (\w+\[[\d,]*\])", line).group(1))
+            for line in text.splitlines() if re.search(r" scatter\(", line)]
 
 
 def _worst_relative(got, want):

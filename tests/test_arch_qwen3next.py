@@ -286,6 +286,8 @@ def test_the_kernels_line_and_bfloat16_reach_the_layers(tmp_path):
     # 512 rows sized for 144 at balance (4 of 16 experts, 576 assignments)
     assert " grouped_matmul[fwd=512/32/16 dlhs=512/16/32 drhs=512/32/16 " \
         "row_tiles=1 row_tiles_at_balance=1 visits=5 weight_bytes=8192] " \
+        "moe_rows[tokens_tile=192 seg=8 segs=32 cols=32 tiles=1 rows=512 " \
+        "rows_at_balance=144 fwd_bytes=67584 bwd_bytes=43008] " \
         "mode=interpret dtype=float32" in kernels
 
     narrow = build_lm_model(CASE.train_config(compute_dtype="bfloat16",

@@ -2,7 +2,7 @@
 (``tests/dropless_plain.py``: take, grouped matmuls, scatter-add, JAX's own
 derivative) under each arch's row of fields, every expert held or a share of
 them, with and without the overflow part run, an empty group, k = 1; the
-scatters the compiler leaves; the counters of a rigged router; a bias that
+scatters the compiler leaves (of a held share's rows: none); the counters of a rigged router; a bias that
 relieves an overloaded expert; and golden bytes from before PR 38."""
 
 import hashlib
@@ -116,21 +116,24 @@ def test_the_compiled_layer_holds_no_scatter(monkeypatch, name):
 
 @pytest.mark.parametrize("name", ["relu_share-overflow_not_taken",
                                   "relu_share-overflow_taken"])
-def test_a_compiled_share_scatters_its_rows_and_nothing_else(
-        monkeypatch, name):
-    """Forward and backward of a held share as the CPU's compiler leaves
-    them: the scatters left are the three of a part (the combine, and the
-    transposes of the rows' take and of the gates' take: a part covers fewer
-    rows than there are assignments, where the gathers of PR 38 measured
-    slower; PERF.md, Findings PR 38), once in the main part and once under
-    the overflow ``cond``, all under ``moe_dispatch``. The counts and the
-    gates' way back to the scores (``moe_route``) hold none, where the plain
-    form has three more."""
+def test_a_compiled_share_holds_no_scatter_of_rows(monkeypatch, name):
+    """Forward and backward of a held share as lowered and as the CPU's
+    compiler leaves them: NO scatter of rows (the combine and the take's
+    transpose are ``ops/moe_rows.py``'s kernel since PR 52, where the plain
+    form and the layer before it scatter-added ``[rows, d]`` twice a part)
+    and none under ``moe_route``. What is left is one scatter a part, under
+    ``moe_dispatch``, of SCALARS: the gates' gradients of the part's rows on
+    their way back to ``[T, k]`` (a kernel that formed them token by token
+    would have to fetch a token's rows by their index, which Mosaic refuses;
+    PERF.md, Findings PR 52), once in the main part and once under the
+    overflow ``cond``."""
     layer, variables, x = _case(monkeypatch, name)
     step, plain = PLAIN.steps(layer, variables)
-    _, names = PLAIN.scatters(step, variables["params"], x)
-    assert len(names) == 6
-    assert all("moe_dispatch" in n and "moe_route" not in n for n in names)
+    left = PLAIN.compiled_scatters(step, variables["params"], x)
+    assert len(left) == 2, left
+    for op_name, shape in left:
+        assert "moe_dispatch" in op_name and "moe_route" not in op_name
+        assert "," not in shape, shape      # one dimension: no row
     assert len(PLAIN.scatters(plain, variables["params"], x)[1]) == 6
 
 
@@ -138,13 +141,14 @@ def test_the_compiled_route_holds_no_scatter(monkeypatch):
     """Forward and backward of a held share chosen under the bias, as the
     CPU's compiler leaves them: no ``scatter`` op under ``moe_route`` (the
     gates are gathered from the scores forward and their gradient goes back
-    by a comparison; the counts the bias's step reads are comparisons); the
-    six left are the two parts' rows, as above. With every expert held there
-    is none at all."""
+    by a comparison; the counts the bias's step reads are comparisons, and the
+    places the rows' kernel reads are counted, not sorted or scattered); the
+    two left are the two parts' gate gradients, as above. With every expert
+    held there is none at all."""
     layer, variables, x = _case(monkeypatch, "bias_share-overflow_taken")
     _, names = PLAIN.scatters(PLAIN.steps(layer, variables)[0],
                               variables["params"], x)
-    assert len(names) == 6
+    assert len(names) == 2
     assert all("moe_dispatch" in n and "moe_route" not in n for n in names)
     layer, variables, x = _case(monkeypatch, "bias_share-overflow_taken",
                                 n_held=0, share=0)

@@ -155,6 +155,8 @@ def whole_step(one_chip, monkeypatch, config, traffic):
             importlib.import_module("ps_pytorch_tpu.ops." + name),
             "_interpret_default", lambda: False)
     monkeypatch.setattr(grouped_matmul, "interpret_default", lambda: False)
+    monkeypatch.setattr(importlib.import_module("ps_pytorch_tpu.ops.moe_rows"),
+                        "interpret_default", lambda: False)
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
@@ -219,7 +221,11 @@ class StepCell(NamedTuple):
 # layer and one the global layer in SmallThinker and Trinity, a call a head of
 # a differential pair in the hybrid; every other forward kernel runs
 # twice a layer (the recomputed forward makes its residuals again), every
-# backward kernel once; the grouped matmuls are a call an expert matmul.
+# backward kernel once; the grouped matmuls are a call an expert matmul;
+# ``moe_rows_sum`` four a routed layer that holds a share (the main part's
+# combine and its take's transpose, and the same two under the overflow
+# ``cond``; PR 52, whose step plans 0.1-0.5 GB less in all five such cells:
+# no float32 ``[rows, d]`` product, no zeros, no float32 ``[T, d]`` pair).
 # A plan moves by a few hundred KB with the heap's packing; PERF.md section 4
 # has the history (PR 46 is the first since PR 34 to raise one: a
 # rematerialised block keeps more; PR 50 raises the hybrid's by 1.2 GiB
@@ -232,12 +238,12 @@ STEP_CELLS = {
         "smallthinker_21b_a3b", "s16384_1chip", (64, 16), 559_290_880,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "flash_win_fwd": 3,
          "flash_win_bwd_dkv": 3, "moe_gmm_fwd": 48, "moe_gmm_dlhs": 24,
-         "moe_gmm_drhs": 24}, "ssm_", 9_299_046_912),
+         "moe_gmm_drhs": 24, "moe_rows_sum": 16}, "ssm_", 8_810_382_336),
     "trinity_mini_s8192_1chip": StepCell(
         "trinity_mini", "s8192_1chip", (128, 16), 705_473_792,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "flash_win_fwd": 4,
          "flash_win_bwd_dkv": 4, "moe_gmm_fwd": 48, "moe_gmm_dlhs": 24,
-         "moe_gmm_drhs": 24}, "ssm_", 13_372_787_712),
+         "moe_gmm_drhs": 24, "moe_rows_sum": 16}, "ssm_", 13_179_736_576),
     "phi4flash_s8192_1chip": StepCell(
         "phi4_mini_flash", "s8192_reasoning_1chip", (8, 0), 915_283_456,
         {"flash_fwd": 4, "flash_bwd_dkv": 4, "flash_win_fwd": 4,
@@ -247,18 +253,18 @@ STEP_CELLS = {
         "qwen3_next_80b_a3b", "s16384_hybrid_1chip", (512, 64), 1_028_320_320,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "gdr_solve": 6, "gdr_fwd": 6,
          "gdr_bwd": 3, "moe_gmm_fwd": 48, "moe_gmm_dlhs": 24,
-         "moe_gmm_drhs": 24, "gdn_conv_fwd_q": 6, "gdn_conv_fwd_k": 6,
+         "moe_gmm_drhs": 24, "moe_rows_sum": 16, "gdn_conv_fwd_q": 6, "gdn_conv_fwd_k": 6,
          "gdn_conv_fwd_v": 6, "gdn_conv_bwd_q": 3, "gdn_conv_bwd_k": 3,
          "gdn_conv_bwd_v": 3, "gdn_norm_fwd": 6, "gdn_norm_bwd": 3},
-        "flash_win_", 14_540_864_000),
+        "flash_win_", 14_384_855_552),
     "nemotron3nano_s16384_1chip": StepCell(
         "nemotron3_nano_30b_a3b", "s16384_ssd_1chip", (128, 16), 986_254_336,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "ssd_fwd": 8, "ssd_bwd": 4,
          "moe_gmm_fwd": 32, "moe_gmm_dlhs": 16, "moe_gmm_drhs": 16,
-         "ssm_conv_fwd_x": 8, "ssm_conv_fwd_b": 8, "ssm_conv_fwd_c": 8,
+         "moe_rows_sum": 16, "ssm_conv_fwd_x": 8, "ssm_conv_fwd_b": 8, "ssm_conv_fwd_c": 8,
          "ssm_conv_bwd_x": 4, "ssm_conv_bwd_b": 4, "ssm_conv_bwd_c": 4,
          "ssm_norm_fwd": 8, "ssm_norm_bwd": 4}, "flash_win_",
-        14_501_435_392),
+        14_328_864_768),
     "evabyte_s16384_1chip": StepCell(
         "evabyte_6_5b", "s16384_bytes_1chip", (8, 0), 821_366_784,
         {"eva_pool_fwd": 4, "eva_fwd": 4, "eva_bwd": 4, "eva_pool_bwd": 4},
@@ -267,10 +273,10 @@ STEP_CELLS = {
         "granite_4_0_h_small", "tp8_share_1chip", (72, 9), 1_055_938_224,
         {"flash_fwd": 1, "flash_bwd_dkv": 1, "ssd_fwd": 18, "ssd_bwd": 9,
          "moe_gmm_fwd": 120, "moe_gmm_dlhs": 60, "moe_gmm_drhs": 60,
-         "ssm_conv_fwd_x": 18, "ssm_conv_fwd_b": 18, "ssm_conv_fwd_c": 18,
+         "moe_rows_sum": 40, "ssm_conv_fwd_x": 18, "ssm_conv_fwd_b": 18, "ssm_conv_fwd_c": 18,
          "ssm_conv_bwd_x": 9, "ssm_conv_bwd_b": 9, "ssm_conv_bwd_c": 9,
          "ssm_norm_fwd": 18, "ssm_norm_bwd": 9}, "flash_win_",
-        14_250_000_000),
+        14_134_619_136),
 }
 HEAP_PACKING_BYTES = 2 ** 20
 
@@ -952,3 +958,51 @@ def test_expert_ffn_at_nine_held_experts_of_4096_by_768_compiles(
         arg((e,), jnp.int32)).compile().as_text()
     for name in ("moe_gmm_fwd", "moe_gmm_dlhs", "moe_gmm_drhs"):
         assert name in text
+
+
+# (tokens, top-k, held experts, d, rows the layer sizes) of the five cells
+# that hold a share of their experts
+ROWS_CELLS = {
+    "granite4h_small_tp8_1chip": (8192, 10, 9, 4096, 15360),
+    "smallthinker_s16384_1chip": (16384, 6, 16, 2560, 36864),
+    "qwen3next_s16384_1chip": (16384, 10, 64, 2048, 30720),
+    "nemotron3nano_s16384_1chip": (16384, 6, 16, 2688, 18432),
+    "trinity_mini_s8192_1chip": (16384, 8, 16, 2048, 24576),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROWS_CELLS))
+def test_the_held_rows_kernel_compiles_at_the_cells_shape(one_chip,
+                                                          monkeypatch, cell):
+    """``ops/moe_rows.py``: a held share's take and combine, forward and
+    backward, as ``DroplessMoE.part`` calls them on the main part's rows in
+    bfloat16: ``moe_rows_sum`` with the gates (the combine) and with none
+    (the take's transpose), its DMAs at multiples of 8 rows, d = 2688 in
+    seven blocks of 384 lanes, the runs' starts and its own lists in SMEM, all under
+    ``VMEM_LIMIT_BYTES``; and no scatter makes a ``[., d]`` array."""
+    from ps_pytorch_tpu.ops import moe_rows
+    monkeypatch.setattr(moe_rows, "interpret_default", lambda: False)
+    t, k, held, d, rows = ROWS_CELLS[cell]
+    sched = moe_rows.rows_schedule(rows, rows * 2 // 3, t, k, d,
+                                   jnp.bfloat16, held)
+
+    def loss(tokens, gates, idx, local, has, sizes, count):
+        place, lo = moe_rows.held_places(has, sizes, sched.tokens_tile)
+        plan = moe_rows.rows_plan(has, place, lo, start=0, count=count)
+        xs = moe_rows.take(tokens, idx, plan, k, sched)
+        y = moe_rows.combine(xs * 2, gates, jnp.ones((t, d)), local, idx,
+                             plan, sched, jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        arg((t, d), jnp.bfloat16), arg((t, k), jnp.float32),
+        arg((rows,), jnp.int32), arg((t, k), jnp.int32),
+        arg((t, held), jnp.bool_), arg((held,), jnp.int32),
+        arg((), jnp.int32)).compile().as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) >= 2 and "moe_rows_sum" in text
+    made = re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(", text)
+    assert all("," not in shape for shape in made), made

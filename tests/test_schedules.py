@@ -161,3 +161,52 @@ def test_the_grouped_matmuls_kernel_record_at_the_cells_shapes(cell):
     for name, value in sc._asdict().items():
         assert said[name] == ("/".join(map(str, value))
                               if isinstance(value, tuple) else str(value))
+
+
+# (tokens, top-k, router outputs, experts held, d) of the five cells that hold
+# a share of their experts -> ``rows_schedule``'s record, letter for letter
+ROWS_CELLS = {
+    "granite4h_small_tp8_1chip": (
+        (8192, 10, 72, 9, 4096),
+        "tokens_tile=256 seg=16 segs=16 cols=512 tiles=32 rows=15360 "
+        "rows_at_balance=10240 fwd_bytes=285212672 bwd_bytes=150994944"),
+    "smallthinker_s16384_1chip": (
+        (16384, 6, 64, 16, 2560),
+        "tokens_tile=256 seg=16 segs=16 cols=512 tiles=64 rows=36864 "
+        "rows_at_balance=24576 fwd_bytes=377487360 bwd_bytes=209715200"),
+    "qwen3next_s16384_1chip": (
+        (16384, 10, 512, 64, 2048),
+        "tokens_tile=256 seg=16 segs=16 cols=512 tiles=64 rows=30720 "
+        "rows_at_balance=20480 fwd_bytes=285212672 bwd_bytes=150994944"),
+    "nemotron3nano_s16384_1chip": (
+        (16384, 6, 128, 16, 2688),
+        "tokens_tile=256 seg=16 segs=16 cols=384 tiles=64 rows=18432 "
+        "rows_at_balance=12288 fwd_bytes=330301440 bwd_bytes=154140672"),
+    "trinity_mini_s8192_1chip": (
+        (16384, 8, 128, 16, 2048),
+        "tokens_tile=256 seg=16 segs=16 cols=512 tiles=64 rows=24576 "
+        "rows_at_balance=16384 fwd_bytes=268435456 bwd_bytes=134217728"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROWS_CELLS))
+def test_the_held_rows_kernel_record_at_the_cells_shapes(cell):
+    """``rows_schedule`` (``ops/moe_rows.py``, PR 52), the ``KERNELS`` line's
+    ``moe_rows[...]`` record of the kernel that sums a held share's rows into
+    their tokens, as ``LMTrainer`` calls it at bfloat16 rows: 256 tokens an
+    output block, 16-row segments sixteen to a step's buffer, d in blocks of
+    512 lanes (2688 = 7 x 384), the grid the token tiles, and the bytes the forward's call (the combine, which also adds the other
+    part's float32 sum) and the backward's (the take's transpose) move at
+    balance. A third of the rows sized are past what the groups own at
+    balance: no DMA is started for them."""
+    from ps_pytorch_tpu.models.moe import held_rows
+    from ps_pytorch_tpu.ops.moe_rows import rows_schedule
+    (tokens, k, experts, held, d), said = ROWS_CELLS[cell]
+    rows = held_rows(tokens * k, held, experts)
+    sc = rows_schedule(rows, tokens * k * held // experts, tokens, k, d,
+                       jnp.bfloat16, held)
+    assert sc.describe() == said
+    assert 3 * (sc.rows - sc.rows_at_balance) == sc.rows
+    assert sc.tiles * sc.tokens_tile == tokens and d % sc.cols == 0
+    assert list(dict(item.split("=") for item in said.split())) == \
+        list(sc._fields)
